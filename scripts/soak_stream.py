@@ -8,10 +8,12 @@ thread, periodic checkpoints with per-reader stream cursors — while
 tracking host RSS for leaks (an unbounded queue, an unfreed buffer, or a
 growing cursor map would show as monotonic RSS growth over hours).
 
-Writes `--out` (default SOAK_r04.json): rounds completed, wall time,
+Writes `--out` (default SOAK.json): rounds completed, wall time,
 RSS first/median/last, stream epochs, skipped counter, loss finiteness.
+(The r4/r5 runs' figures are in PERF.md's Findings; their record files
+were removed in PR 21.)
 
-Run: python scripts/soak_stream.py --rounds 6000 [--out SOAK_r04.json]
+Run: python scripts/soak_stream.py --rounds 6000 [--out SOAK.json]
 """
 from __future__ import annotations
 
@@ -38,7 +40,7 @@ def rss_mb() -> float:
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--rounds", type=int, default=6000)
-    p.add_argument("--out", default="SOAK_r04.json")
+    p.add_argument("--out", default="SOAK.json")
     p.add_argument("--sources", type=int, default=4)
     p.add_argument("--shards", type=int, default=32)
     p.add_argument("--per-shard", type=int, default=256)

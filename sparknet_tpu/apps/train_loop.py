@@ -141,6 +141,38 @@ def probe_value(state: TrainState, net: CompiledNet):
     return float(np.asarray(leaf).reshape(-1)[0])
 
 
+def build_trainer(cfg: RunConfig, spec: NetSpec, mesh=None):
+    """cfg + spec -> the layer-IR trainer `train()` runs: the trainer
+    implementation, round-pipeline levers and kernel selection all come
+    from `cfg`, over `mesh` (default: the data mesh of cfg.n_devices).
+    Sets the precision policy and resolves the solver first — both shape
+    the compiled round. Building is cheap: nothing compiles until the
+    first round. Separate from `train()` so a caller can look at the
+    program the loop will run (`chip_smoke.py` lowers it to check the
+    Pallas kernels are in it)."""
+    precision.set_policy(cfg.precision)
+    resolve_solver(cfg)
+    compute_health = cfg.health is not None and cfg.health.enabled
+    elastic_tau = (cfg.elastic is not None and cfg.elastic.enabled
+                   and cfg.elastic.tau_adapt)
+    trainer_kw: Dict[str, Any] = {}
+    trainer_cls = ParallelTrainer
+    if resolve_trainer_impl(cfg) == "named":
+        trainer_cls = ShardedTrainer
+        trainer_kw["state_sharding"] = cfg.state_sharding
+    return trainer_cls(CompiledNet.compile(spec), cfg.solver,
+                       mesh if mesh is not None
+                       else make_mesh(cfg.n_devices), tau=cfg.tau,
+                       mode=cfg.mode, compute_health=compute_health,
+                       elastic_tau=elastic_tau,
+                       donate_batches=cfg.donate_batches,
+                       fused_boundary=cfg.fused_boundary,
+                       ops=OpsImpl(lrn=cfg.lrn_impl,
+                                   pool=cfg.pool_impl,
+                                   interpret=cfg.ops_interpret),
+                       **trainer_kw)
+
+
 def train(cfg: RunConfig, spec: NetSpec, train_ds: ArrayDataset,
           test_ds: Optional[ArrayDataset] = None,
           logger: Optional[Logger] = None,
@@ -149,37 +181,17 @@ def train(cfg: RunConfig, spec: NetSpec, train_ds: ArrayDataset,
     """Run the full distributed training loop per cfg (layer-IR backend).
     Returns final state."""
     log = logger or default_logger(cfg.workdir)
-    precision.set_policy(cfg.precision)
-    resolve_solver(cfg)
     # persistent compile cache (process-global): the initial round
     # compile AND every elastic trainer_factory rebuild hit it — a
     # relaunched/resized worker with a warm cache skips XLA entirely
     from ..utils.compile_cache import init_compile_cache
-    cache = init_compile_cache(cfg.compile_cache_dir)
-    if cache:
-        log.log(f"persistent compile cache: {cache}")
-    net = CompiledNet.compile(spec)
-    mesh = make_mesh(cfg.n_devices)
-    n_dev = int(np.prod(mesh.devices.shape))
-    compute_health = cfg.health is not None and cfg.health.enabled
-    elastic_tau = (cfg.elastic is not None and cfg.elastic.enabled
-                   and cfg.elastic.tau_adapt)
+    log.log(f"persistent compile cache: "
+            f"{init_compile_cache(cfg.compile_cache_dir)}")
+    trainer = build_trainer(cfg, spec)
+    net = trainer.net
     impl = resolve_trainer_impl(cfg)
-    trainer_kw: Dict[str, Any] = {}
-    trainer_cls = ParallelTrainer
-    if impl == "named":
-        trainer_cls = ShardedTrainer
-        trainer_kw["state_sharding"] = cfg.state_sharding
-    trainer = trainer_cls(net, cfg.solver, mesh, tau=cfg.tau,
-                          mode=cfg.mode, compute_health=compute_health,
-                          elastic_tau=elastic_tau,
-                          donate_batches=cfg.donate_batches,
-                          fused_boundary=cfg.fused_boundary,
-                          ops=OpsImpl(lrn=cfg.lrn_impl,
-                                      pool=cfg.pool_impl,
-                                      interpret=cfg.ops_interpret),
-                          **trainer_kw)
-    log.log(f"mesh: {n_dev} devices; tau={cfg.tau} mode={cfg.mode} "
+    log.log(f"mesh: {trainer.n_devices} devices; tau={cfg.tau} "
+            f"mode={cfg.mode} "
             f"local_batch={cfg.local_batch} precision={cfg.precision} "
             f"trainer={impl}"
             + (f" state_sharding={cfg.state_sharding}"

@@ -370,7 +370,7 @@ class ShardedTarLoader:
         if not path.startswith(("gs://", "s3://")):
             try:
                 from . import jpeg_plane
-                if jpeg_plane.supports_tar_index():
+                if jpeg_plane.available():
                     idx = jpeg_plane.tar_index(path)
             except ImportError:
                 idx = None

@@ -2,45 +2,92 @@
 
 Covers the reference's native-imaging role (JVM libjpeg via twelvemonkeys,
 reference `preprocessing/ScaleAndConvert.scala`): JPEG decode + force-resize
-+ planar CHW, plus a fused crop/mean-subtract/NHWC batch kernel. Auto-builds
-with g++ on first use (cached .so); `available()` gates all callers, with
-PIL/numpy fallbacks elsewhere.
++ planar CHW, plus a fused crop/mean-subtract/NHWC batch kernel.
+
+The shared library is built with g++ on first use and named by a hash of
+the two committed files it is built from (`native/jpeg_plane.cpp`,
+`native/build.sh`) and of this host's CPU (`build.sh` compiles with
+`-march=native`). So a binary is loaded only if it was built from the
+committed source on the running host: a checkout copied from another
+machine, or whose source changed, finds no library under its name and
+rebuilds. `available()` gates all callers, with PIL/numpy fallbacks
+elsewhere; a build that fails says so once, loudly.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libjpeg_plane.so"))
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native"))
+_SOURCES = ("jpeg_plane.cpp", "build.sh")
 
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+
+
+def _cpu_identity() -> bytes:
+    """What `-march=native` keys on: the architecture and the first CPU's
+    model and feature flags."""
+    lines = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for ln in f:
+                if ln.startswith(("model name", "flags", "Features")):
+                    lines.append(ln.strip())
+                elif not ln.strip():
+                    break  # end of the first CPU's block
+    except OSError:
+        lines.append(platform.processor())
+    return "\n".join(lines).encode()
+
+
+def so_path() -> str:
+    """The library's path for THIS source revision on THIS host."""
+    h = hashlib.sha256(_cpu_identity())
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_NATIVE_DIR,
+                        f"libjpeg_plane-{h.hexdigest()[:16]}.so")
+
+
+def _build(path: str) -> None:
+    """Run native/build.sh for `path`, then drop the libraries of other
+    revisions/hosts. Raises on any failure."""
+    p = subprocess.run(["sh", os.path.join(_NATIVE_DIR, "build.sh"), path],
+                       capture_output=True, text=True, timeout=300)
+    if p.returncode != 0:
+        raise OSError(f"native/build.sh exited {p.returncode}: "
+                      f"{p.stderr.strip()[-2000:]}")
+    for old in glob.glob(os.path.join(_NATIVE_DIR, "libjpeg_plane*.so")):
+        if old != path:
+            os.remove(old)
 
 
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _build_failed
     if _lib is not None or _build_failed:
         return _lib
-    if not os.path.exists(_SO_PATH):
-        script = os.path.join(_NATIVE_DIR, "build.sh")
-        if not os.path.exists(script):
-            _build_failed = True
-            return None
-        try:
-            subprocess.run(["sh", script], check=True, capture_output=True,
-                           timeout=120)
-        except (subprocess.SubprocessError, OSError):
-            _build_failed = True
-            return None
     try:
-        lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+        path = so_path()
+        if not os.path.exists(path):
+            _build(path)
+        lib = ctypes.CDLL(path)
+    except (OSError, subprocess.SubprocessError) as e:
         _build_failed = True
+        warnings.warn(
+            f"NATIVE JPEG PLANE UNAVAILABLE — ingest falls back to the "
+            f"PIL/numpy path (several times slower per core): {e}",
+            RuntimeWarning, stacklevel=2)
         return None
     lib.jp_decode_resize_chw.restype = ctypes.c_int
     lib.jp_decode_resize_chw.argtypes = [
@@ -52,29 +99,22 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_long), ctypes.c_int, ctypes.c_int,
         ctypes.c_int, ctypes.POINTER(ctypes.c_uint8),
         ctypes.POINTER(ctypes.c_int)]
-    lib.jp_crop_mean_nhwc.restype = None
-    lib.jp_crop_mean_nhwc.argtypes = [
+    crop_args = [
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
         ctypes.c_int, ctypes.c_int, ctypes.POINTER(ctypes.c_float),
         ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-        ctypes.c_int, ctypes.POINTER(ctypes.c_float)]
-    try:
-        lib.jp_crop_mean_nhwc_bf16.restype = None
-        lib.jp_crop_mean_nhwc_bf16.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int, ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_int),
-            ctypes.c_int, ctypes.POINTER(ctypes.c_uint16)]
-    except AttributeError:
-        lib.jp_crop_mean_nhwc_bf16 = None  # pre-bf16 .so build
-    try:
-        lib.jp_tar_index.restype = ctypes.c_long
-        lib.jp_tar_index.argtypes = [
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_long),
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_char_p, ctypes.c_long]
-    except AttributeError:
-        lib.jp_tar_index = None  # pre-index .so build
+        ctypes.c_int]
+    lib.jp_crop_mean_nhwc.restype = None
+    lib.jp_crop_mean_nhwc.argtypes = crop_args + [
+        ctypes.POINTER(ctypes.c_float)]
+    lib.jp_crop_mean_nhwc_bf16.restype = None
+    lib.jp_crop_mean_nhwc_bf16.argtypes = crop_args + [
+        ctypes.POINTER(ctypes.c_uint16)]
+    lib.jp_tar_index.restype = ctypes.c_long
+    lib.jp_tar_index.argtypes = [
+        ctypes.c_char_p, ctypes.c_long,
+        ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_long),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_char_p, ctypes.c_long]
     _lib = lib
     return _lib
 
@@ -118,12 +158,6 @@ def decode_resize_chw_batch(jpegs: list, height: int, width: int
     return out, ok == 0
 
 
-def supports_bf16_out() -> bool:
-    lib = _load()
-    return lib is not None and \
-        getattr(lib, "jp_crop_mean_nhwc_bf16", None) is not None
-
-
 def crop_mean_nhwc(images_chw_u8: np.ndarray,
                    mean_chw: Optional[np.ndarray],
                    ys: np.ndarray, xs: np.ndarray, crop: int,
@@ -150,8 +184,6 @@ def crop_mean_nhwc(images_chw_u8: np.ndarray,
             ys.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
             xs.ctypes.data_as(ctypes.POINTER(ctypes.c_int)), crop)
     if out_dtype == "bfloat16":
-        assert supports_bf16_out(), \
-            "libjpeg_plane.so predates bf16 output — rerun native/build.sh"
         import ml_dtypes
         out = np.empty((n, crop, crop, c), dtype=ml_dtypes.bfloat16)
         lib.jp_crop_mean_nhwc_bf16(
@@ -173,12 +205,6 @@ class TruncatedTarError(OSError):
     silently, so falling back would train on partial data."""
 
 
-def supports_tar_index() -> bool:
-    lib = _load()
-    return lib is not None and \
-        getattr(lib, "jp_tar_index", None) is not None
-
-
 def tar_index(path: str, name_cap: int = 128):
     """Parse a local tar's member table in C (no GIL-held Python walk):
     returns (data_offsets int64[n], sizes int64[n], isfile bool[n],
@@ -187,8 +213,6 @@ def tar_index(path: str, name_cap: int = 128):
     (GNU long names / pax) — callers fall back to tarfile."""
     lib = _load()
     assert lib is not None, "native plane unavailable"
-    if getattr(lib, "jp_tar_index", None) is None:
-        return None  # pre-index .so build
     max_n = max(64, os.path.getsize(path) // 512 // 2 + 2)
     offsets = np.zeros(max_n, dtype=np.int64)
     sizes = np.zeros(max_n, dtype=np.int64)

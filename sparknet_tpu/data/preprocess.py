@@ -149,11 +149,9 @@ class ImagePreprocessor(DefaultPreprocessor):
         else:
             ys = np.full(n, (h - self.crop) // 2, np.int32)
             xs = np.full(n, (w - self.crop) // 2, np.int32)
-        dt = self.out_dtype
-        if dt == "bfloat16" and not jpeg_plane.supports_bf16_out():
-            dt = "float32"  # stale .so: fall back, cast later in the loop
         return jpeg_plane.crop_mean_nhwc(raw, self.mean_image, ys, xs,
-                                         self.crop, out_dtype=dt)
+                                         self.crop,
+                                         out_dtype=self.out_dtype)
 
 
 def compute_mean_image(images_chw: np.ndarray) -> np.ndarray:

@@ -11,9 +11,17 @@ serve stack):
     processes on THIS host, each with its own binary frame port
     (spkn://) and heartbeat file — the CPU-truth provider the fleet
     tests and `bench.py --fleet` run end to end. Children share the
-    persistent compile cache, so a grow on a warm host skips every
-    bucket compile (the r9 cold-start lever is what makes autoscaling
-    cheap enough to be worth doing).
+    persistent compile cache (`utils/compile_cache.py`: the directory
+    their environment names, else the fixed in-checkout one), so a grow
+    on a warm host skips every bucket compile (the r9 cold-start lever
+    is what makes autoscaling cheap enough to be worth doing).
+    ONE PROCESS PER REPLICA NEEDS ONE CHIP PER REPLICA: an accelerator
+    belongs to one process at a time, and every child takes the default
+    device — on a host whose chip this (or any) process already holds,
+    the second process fails or hangs at backend start-up. On a one-chip
+    host a fleet is ONE process (in-process replicas behind the router);
+    this provider is for CPU hosts and for hosts with a free chip per
+    child.
   - `PodReplicaProvider`: a STUB riding the `tpu_pod_launch.sh`
     protocol — grow assembles the launcher's create/setup/run command
     sequence for a fresh single-host TPU VM serving the model, retire
@@ -95,7 +103,6 @@ class SubprocessReplicaProvider(ReplicaProvider):
                  workdir: Optional[str] = None,
                  max_batch: int = 8,
                  outputs: Sequence[str] = ("prob",),
-                 compile_cache_dir: Optional[str] = None,
                  heartbeat_every_s: float = 0.5,
                  spawn_timeout_s: float = 120.0,
                  extra_args: Sequence[str] = (),
@@ -110,7 +117,6 @@ class SubprocessReplicaProvider(ReplicaProvider):
         os.makedirs(self.workdir, exist_ok=True)
         self.max_batch = int(max_batch)
         self.outputs = tuple(outputs or ())
-        self.compile_cache_dir = compile_cache_dir
         self.heartbeat_every_s = float(heartbeat_every_s)
         self.spawn_timeout_s = float(spawn_timeout_s)
         self.extra_args = tuple(extra_args)
@@ -140,8 +146,6 @@ class SubprocessReplicaProvider(ReplicaProvider):
                "--heartbeat-every", str(self.heartbeat_every_s)]
         if self.outputs:
             cmd += ["--outputs", ",".join(self.outputs)]
-        if self.compile_cache_dir:
-            cmd += ["--compile-cache", self.compile_cache_dir]
         if self.checkpoint_dir:
             cmd += ["--checkpoint-dir",
                     self.checkpoint_dir.replace("{model}", model),

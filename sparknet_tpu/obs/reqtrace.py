@@ -696,8 +696,10 @@ def _selfcheck(keep: Optional[str] = None, delay_ms: float = 40.0) -> int:
     shard_dir = os.path.join(tmp, "shards")
     os.makedirs(shard_dir, exist_ok=True)
     ready = os.path.join(tmp, "ready.txt")
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # one process per chip: the replica child never contends for an
+    # accelerator this process may hold — it runs on the CPU, and the
+    # OK line below says so
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.Popen(
         [sys.executable, "-c", _CHILD_SRC, shard_dir, ready,
          str(delay_ms)],
@@ -757,8 +759,8 @@ def _selfcheck(keep: Optional[str] = None, delay_ms: float = 40.0) -> int:
             raise RuntimeError("chrome trace is single-process")
         with open(os.path.join(tmp, f"trace-{tid}.json"), "w") as f:
             json.dump(t["chrome"], f)
-        print(f"selfcheck OK: trace {tid} crossed "
-              f"{s['procs']} processes ({s['hops']} wire hop(s)); "
+        print(f"selfcheck OK (replica child backend: cpu): trace {tid} "
+              f"crossed {s['procs']} processes ({s['hops']} wire hop(s)); "
               f"total {s['total_ms']:.1f} ms = queue {s['queue_ms']:.2f}"
               f" + form {s['form_ms']:.2f} + forward "
               f"{s['forward_ms']:.1f} + wire {s['wire_ms']:.2f} + other "

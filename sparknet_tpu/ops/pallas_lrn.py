@@ -84,13 +84,7 @@ def _out_struct(x2: jnp.ndarray) -> jax.ShapeDtypeStruct:
     (vma), which shard_map's check_vma requires on pallas_call outputs: the
     trainer runs this kernel INSIDE shard_map, where plain ShapeDtypeStruct
     (vma=None) is rejected."""
-    try:
-        vma = jax.typeof(x2).vma
-    except AttributeError:
-        vma = None
-    if vma:
-        return jax.ShapeDtypeStruct(x2.shape, x2.dtype, vma=vma)
-    return jax.ShapeDtypeStruct(x2.shape, x2.dtype)
+    return jax.ShapeDtypeStruct(x2.shape, x2.dtype, vma=jax.typeof(x2).vma)
 
 
 def _call(kernel, n_out: int, x2: jnp.ndarray, *others, interpret: bool):
@@ -225,6 +219,29 @@ def _bwd_kernel3(x_ref, dy_ref, dx_ref, *, half: int, alpha_n: float,
                  _window_sum_mid(ratio, half)).astype(x_ref.dtype)
 
 
+#: Mosaic's default scoped-VMEM allowance per kernel on v5e (the chip has
+#: 128 MiB; the compiler's refusal message names this limit).
+_DEFAULT_SCOPED_VMEM = 16 << 20
+#: block-sized f32 values the backward kernel keeps live (x, dy, scale,
+#: scale^-beta, ratio and its shifted window adds) — fitted to the
+#: compiler's own figure: it asked 17.68 MB for norm2's f32 backward, whose
+#: six pipeline buffers are 10.2 MB of that.
+_NMIN_F32_TEMPS = 6
+
+
+def _nmin_vmem_limit(br: int, c: int, itemsize: int, n_blocks: int) -> int:
+    """Scoped VMEM one N-minor call needs: every operand and result block
+    double-buffered by the pipeline, plus the kernel's f32 temporaries.
+    The row block is chosen by `_row_block` alone (the profiled bf16
+    kernel: norm2's (13, 256, 128) blocks need 15.3 MB and stay inside the
+    default); f32 activations double the blocks, so the need is STATED to
+    the compiler instead of shrinking the block — norm2 in f32 was refused
+    at the default 16 MiB (`Scoped allocation with size 17.68M`)."""
+    block = br * c * LANES
+    need = 2 * n_blocks * block * itemsize + _NMIN_F32_TEMPS * block * 4
+    return max(_DEFAULT_SCOPED_VMEM, need)
+
+
 def _nmin_call(kernel, x3: jnp.ndarray, *others, interpret: bool):
     r, c, n = x3.shape
     br = _row_block(r)
@@ -236,6 +253,9 @@ def _nmin_call(kernel, x3: jnp.ndarray, *others, interpret: bool):
         in_specs=[spec] * (1 + len(others)),
         out_specs=spec,
         out_shape=_out_struct(x3),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=_nmin_vmem_limit(
+                br, c, x3.dtype.itemsize, 2 + len(others))),
         interpret=interpret,
     )(x3, *others)
 

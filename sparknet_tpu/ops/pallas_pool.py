@@ -125,13 +125,9 @@ def _bwd_call(x4, y4, dy4, k: int, s: int, interpret: bool,
 
     kern = functools.partial(_bwd_kernel, H=H, OH=OH, OW=OW, k=k, s=s,
                              Hb=Hb, XB=XB, QB=QB)
-    out = jax.ShapeDtypeStruct(x4.shape, x4.dtype)
-    try:
-        vma = jax.typeof(x4).vma
-        if vma:
-            out = jax.ShapeDtypeStruct(x4.shape, x4.dtype, vma=vma)
-    except AttributeError:
-        pass
+    # the vma rides along: the trainer runs this inside shard_map, whose
+    # check_vma rejects a pallas_call output without it (ops/pallas_lrn.py)
+    out = jax.ShapeDtypeStruct(x4.shape, x4.dtype, vma=jax.typeof(x4).vma)
     return pl.pallas_call(
         kern,
         grid=(N // LANES, C // Ct, pl.cdiv(H, Hb)),
@@ -167,16 +163,6 @@ def _to_nmin(x):
 
 def _from_nmin(x4):
     return jnp.transpose(x4, (3, 0, 1, 2))
-
-
-def kernel_api_available() -> bool:
-    """The backward kernel needs pl.Element/pl.BoundedSlice block specs
-    (jax >= 0.5-era Pallas). On older jax `pool2d`'s dispatch gate
-    (`_can_pallas_pool`) answers False so 'auto' degrades to the XLA
-    lowering instead of dying with an AttributeError at trace time.
-    Deliberately SEPARATE from `pallas_maxpool_supported`, which stays a
-    pure shape/geometry predicate."""
-    return hasattr(pl, "Element")
 
 
 def pallas_maxpool_supported(shape: Tuple[int, ...], dtype, kernel: int,

@@ -101,18 +101,14 @@ def pool2d(x: jnp.ndarray, mode: str, kernel: int, stride: int,
 
 def _can_pallas_pool(x, kernel: int, stride: int, pad: int,
                      interpret: bool = False) -> bool:
-    """Shape/backend/toolchain gate for the kernel path. No blanket
-    except: a broken pallas_pool import must surface as itself, not
-    masquerade as an 'unsupported shape' error (r3 review). interpret=True
-    waives the backend requirement (CPU parity tests), never the shape or
-    kernel-API gates. The backend check runs BEFORE the pallas_pool
-    import so 'auto' off-TPU stays as import-free as 'xla' — the default
-    path must run on a jax whose pallas import is broken."""
+    """Shape/backend gate for the kernel path. No blanket except: a
+    broken pallas_pool import must surface as itself, not masquerade as
+    an 'unsupported shape' error (r3 review). interpret=True waives the
+    backend requirement (CPU parity tests), never the shape gate."""
     if not (interpret or jax.default_backend() == "tpu"):
         return False
-    from .pallas_pool import kernel_api_available, pallas_maxpool_supported
-    return (kernel_api_available() and
-            pallas_maxpool_supported(x.shape, x.dtype, kernel, stride, pad))
+    from .pallas_pool import pallas_maxpool_supported
+    return pallas_maxpool_supported(x.shape, x.dtype, kernel, stride, pad)
 
 
 def global_pool2d(x: jnp.ndarray, mode: str) -> jnp.ndarray:

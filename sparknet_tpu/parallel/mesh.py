@@ -14,53 +14,19 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental location
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
 def shard_map_unchecked(f, **kw):
-    """`shard_map` with replication checking OFF — required whenever the
-    body may trace a `pallas_call`, which has no shard_map replication
-    rule (jax's own error message names `check_rep=False` as the
-    workaround). Kwarg name varies by jax version: `check_rep`
-    (<= 0.5-ish) vs `check_vma` (newer)."""
-    try:
-        return shard_map(f, check_rep=False, **kw)
-    except TypeError:
-        return shard_map(f, check_vma=False, **kw)
-
-
-def axis_size(axis_name: str) -> int:
-    """STATIC size of a mesh axis from inside shard_map (usable in
-    `range()` / `jnp.arange()`): `lax.axis_size` where it exists (jax >=
-    0.4.38-ish), else the axis-env frame, which older jax returns as the
-    bare int."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    import jax.core as jcore
-    frame = jcore.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
-
-
-def pvary(tree, axis_names):
-    """Mark replicated values device-varying over `axis_names` (shard_map
-    vma typing). jax >= 0.9 spells it `lax.pcast`, 0.5-0.8 `lax.pvary`;
-    older jax has no vma tracking at all — identity."""
-    from jax import lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(tree, axis_names, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(tree, axis_names)
-    return tree
+    """`shard_map` with replication (vma) checking OFF — required whenever
+    the body may trace a `pallas_call`, which has no shard_map replication
+    rule."""
+    return shard_map(f, check_vma=False, **kw)
 
 
 def make_mesh(n_devices: Optional[int] = None,

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import axis_size, pvary, shard_map
+from .mesh import shard_map
 
 from ..ops.attention import (block_accumulate, finalize_accumulator,
                              init_accumulator)
@@ -44,7 +44,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     Device i initially holds KV shard i; after step t it holds shard
     (i - t) mod n — offsets for causal masking are derived from that.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     lq = q.shape[1]
     perm = [(j, (j + 1) % n) for j in range(n)]
@@ -63,9 +63,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     o, m, l = init_accumulator(q.shape)
     # zeros/full constants are replicated; mark them device-varying so the
-    # scan carry type matches the per-device accumulation results (vma
-    # compat shim: pcast in jax >= 0.9, pvary in 0.5-0.8, no-op before)
-    o, m, l = pvary((o, m, l), (axis_name,))
+    # scan carry type matches the per-device accumulation results
+    o, m, l = lax.pcast((o, m, l), (axis_name,), to="varying")
     (o, m, l, _, _), _ = lax.scan(body, (o, m, l, k, v), jnp.arange(n))
     return finalize_accumulator(o, m, l, q.dtype)
 
@@ -79,7 +78,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     exact attention locally, then back.
     """
     from ..ops.attention import attention
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert q.shape[2] % n == 0, (
         f"heads {q.shape[2]} not divisible by seq-axis size {n}")
     # [B, L/n, H, D] -> gather seq, scatter heads -> [B, L, H/n, D]
@@ -100,16 +99,9 @@ def make_ring_attention(mesh: Mesh, *, axis_name: str = SEQ_AXIS,
     fn = ring_attention if impl == "ring" else ulysses_attention
     inner = functools.partial(fn, axis_name=axis_name, causal=causal)
     spec = P(None, axis_name, None, None)
-    kw = {}
-    import inspect
-    if "check_rep" in inspect.signature(shard_map).parameters:
-        # old-jax (<= 0.4.x) replication checking miscounts the scan carry
-        # under grad (jax advises check_rep=False as the workaround); newer
-        # jax's vma tracking handles it via the pvary marking above
-        kw["check_rep"] = False
     mapped = jax.jit(shard_map(
         lambda q, k, v: inner(q, k, v),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **kw))
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec))
 
     def apply(q, k, v):
         sharding = NamedSharding(mesh, spec)

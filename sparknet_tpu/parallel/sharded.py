@@ -243,10 +243,14 @@ class ShardedTrainer(ParallelTrainer):
         # column shard) with NO replica axis to squeeze; momentum is this
         # worker's [1, ...] row (replicated mode) or the gathered logical
         # momentum (ZeRO modes)
-        params = state.params
+        # both arrive replicated over the data axis and leave the τ scan
+        # worker-local: typed device-varying up front, so the scan carry's
+        # type is the same going in and coming out (shard_map vma typing)
+        params = lax.pcast(state.params, (DATA_AXIS,), to="varying")
         momentum = (jax.tree.map(lambda x: x[0], state.momentum)
                     if self.state_sharding == "replicated"
-                    else state.momentum)
+                    else lax.pcast(state.momentum, (DATA_AXIS,),
+                                   to="varying"))
         it = state.it
         rng = rng[0]
         my_tau = (tau_vec[lax.axis_index(DATA_AXIS)]

@@ -257,12 +257,8 @@ class ParallelTrainer:
         (a drifting batch shape/dtype, a layout change). The train loop
         exports this as the `sparknet_train_round_compiled_variants`
         gauge so jit-cache churn shows up on a scrape instead of as an
-        unexplained slow round. 0 when this jax version does not expose
-        the cache size."""
-        try:
-            return int(self._round._cache_size())
-        except Exception:
-            return 0
+        unexplained slow round."""
+        return int(self._round._cache_size())
 
     # -- state construction --------------------------------------------------
 

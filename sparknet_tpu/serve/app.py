@@ -210,10 +210,11 @@ def main(argv=None) -> None:
                    help="override the quant parity tolerance (sets both "
                    "rtol and atol of the load-time allclose gate)")
     p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compile-cache directory "
-                   "(default: $SPARKNET_COMPILE_CACHE / "
-                   "$JAX_COMPILATION_CACHE_DIR if set) — warm replica "
-                   "cold-starts skip every bucket compile")
+                   help="persistent XLA compile-cache directory — warm "
+                   "replica cold-starts skip every bucket compile. "
+                   "IGNORED where $JAX_COMPILATION_CACHE_DIR is set "
+                   "(that directory is the cache); default "
+                   "<checkout>/.cache/jax")
     p.add_argument("--outputs", default=None,
                    help="comma-separated blob names to return "
                    "(default: the net's output schema)")

@@ -181,8 +181,8 @@ class ServeConfig:
     # persistent XLA compile cache (utils/compile_cache.py): directory
     # for jax's compilation cache, so replica cold-starts / hot-swap
     # retraces / bucket first-forwards re-use executables across
-    # PROCESSES. None = only $SPARKNET_COMPILE_CACHE /
-    # $JAX_COMPILATION_CACHE_DIR, if set.
+    # PROCESSES. $JAX_COMPILATION_CACHE_DIR, where set, wins over this
+    # field; None = the fixed <checkout>/.cache/jax.
     compile_cache_dir: Optional[str] = None
     # per-model latency objective (ms). Advisory: stamped into /status
     # and BENCH_SERVE rows (p99 <= slo at the sustainable rate is the
@@ -287,9 +287,9 @@ class InferenceServer:
         # persistent compile cache: process-global, so first-server-wins
         # on the directory; a replica cold-start with a warm cache dir
         # re-uses every bucket executable instead of recompiling them.
-        # Called UNCONDITIONALLY (the train loop's rule): with no knob,
-        # $SPARKNET_COMPILE_CACHE / $JAX_COMPILATION_CACHE_DIR still
-        # apply — and get the cache-everything floors dropped
+        # Called UNCONDITIONALLY (the train loop's rule): the directory
+        # is $JAX_COMPILATION_CACHE_DIR, else the knob, else the fixed
+        # in-checkout default — with the cache-everything floors dropped
         init_compile_cache(cfg.compile_cache_dir)
         self.buckets = tuple(sorted(cfg.buckets or
                                     default_buckets(cfg.max_batch)))

@@ -7,14 +7,19 @@ what that costs but nothing SAVES it. This module wires jax's persistent
 compilation cache (`jax_compilation_cache_dir`) through one init point and
 gives the telemetry the `cache_hit` signal:
 
-  - `init_compile_cache(dir)` — point jax at a persistent on-disk cache
-    (local path; a pod shares one via NFS or a per-host mirror). Resolves,
-    in order: the explicit argument, `$SPARKNET_COMPILE_CACHE`, then
-    whatever `jax_compilation_cache_dir` already holds (jax binds it to
-    `$JAX_COMPILATION_CACHE_DIR` natively). The entry-size / min-compile-
-    time floors are dropped to "cache everything": serve-bucket forwards
-    on small nets compile in well under jax's default 1 s floor, and those
-    are exactly the compiles a replica cold-start repays.
+  - `init_compile_cache(dir)` — point jax at a persistent on-disk cache.
+    The directory is placed from OUTSIDE first: where
+    `$JAX_COMPILATION_CACHE_DIR` is set, that directory is the cache, and
+    no argument, config field or flag moves it (the path is part of the
+    cache's key, and whoever runs the process — a pod launcher, a
+    benchmark driver — has to find the entries again). Unset, an explicit
+    argument is used if given; otherwise the cache lives at
+    `<checkout>/.cache/jax`, resolved from this package's own path — a
+    fixed place, never a temp name, so a second run hits what the first
+    one compiled. The entry-size / min-compile-time floors are dropped to
+    "cache everything": serve-bucket forwards on small nets compile in
+    well under jax's default 1 s floor, and those are exactly the
+    compiles a replica cold-start repays.
 
   - `track_compiles()` — a context manager counting the fresh XLA backend
     compiles and persistent-cache hits/misses that happen INSIDE the
@@ -39,6 +44,7 @@ from __future__ import annotations
 
 import os
 import threading
+import warnings
 from typing import Optional
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -84,26 +90,38 @@ def ensure_listeners() -> None:
         _listening = True
 
 
-def init_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
+#: the variable jax itself binds `jax_compilation_cache_dir` to; read at
+#: call time so whoever launches the process decides where the cache is
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed in-checkout default (`.gitignore` lists `/.cache/`)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "jax")
+
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Where the persistent cache goes: `$JAX_COMPILATION_CACHE_DIR` when
+    set (an explicit `cache_dir` is then ignored, and a warning says so —
+    once per calling site, by the warnings filter's default), else
+    `cache_dir`, else `DEFAULT_CACHE_DIR`."""
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env and cache_dir:
+        warnings.warn(
+            f"compile cache: ${CACHE_DIR_ENV}={env!r} is set, so the "
+            f"explicit directory {str(cache_dir)!r} is ignored",
+            RuntimeWarning, stacklevel=3)
+    return os.path.abspath(os.path.expanduser(
+        str(env or cache_dir or DEFAULT_CACHE_DIR)))
+
+
+def init_compile_cache(cache_dir: Optional[str] = None) -> str:
     """Initialize the persistent compilation cache (idempotent; safe to
-    call from the train loop, the serve CLI, and tests alike). Returns
-    the active cache directory, or None when no directory is configured
-    anywhere — in which case only the compile-counting listeners are
-    installed and every XLA-compiling region reads as a cache MISS
-    (honest: there is no cache to hit)."""
+    call from the train loop, the serve CLI, the benchmarks and tests
+    alike). Returns the active cache directory (`resolve_cache_dir`)."""
     ensure_listeners()
     import jax
 
     global _cache_dir
-    d = cache_dir or os.environ.get("SPARKNET_COMPILE_CACHE") or None
-    if d is None:
-        try:
-            d = jax.config.jax_compilation_cache_dir  # env-bound option
-        except AttributeError:
-            d = None
-    if not d:
-        return _cache_dir
-    d = os.path.abspath(os.path.expanduser(str(d)))
+    d = resolve_cache_dir(cache_dir)
     with _lock:
         if _cache_dir is not None:
             # FIRST caller wins: the cache is process-global jax state,
@@ -123,23 +141,11 @@ def init_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
         # dir configured; a server/CLI initializing AFTER model build
         # (any jax touch) would silently get no cache. reset_cache()
         # drops the latch so the next compile re-reads the config.
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception:
-            pass  # older/newer jax without the hook: init-early still works
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc)
+        _cc.reset_cache()
         _cache_dir = d
     return d
-
-
-def cache_dir() -> Optional[str]:
-    """The active persistent-cache directory (None = not initialized)."""
-    return _cache_dir
-
-
-def is_initialized() -> bool:
-    return _cache_dir is not None
 
 
 class track_compiles:
@@ -175,9 +181,9 @@ class track_compiles:
             return True  # nothing was compiled fresh
         # fresh XLA work happened: a hit requires the persistent cache
         # to have actually been CONSULTED for it (hit/miss events fired)
-        # with zero misses. `is_initialized()` alone is not enough — a
-        # configured-but-latched-off cache (init after first compile on
-        # a jax without the reset hook) would otherwise read as a hit
+        # with zero misses. An initialized directory alone is not enough — a
+        # configured cache jax is not consulting (e.g. switched off by
+        # `jax_enable_compilation_cache`) would otherwise read as a hit
         # exactly when the cache silently failed.
         return (self.cache_misses == 0
                 and self.cache_hits + self.cache_misses > 0)

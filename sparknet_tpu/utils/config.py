@@ -182,8 +182,9 @@ class RunConfig:
     # jax reuses compiled executables from ACROSS processes — replica
     # cold-start, elastic trainer_factory rebuilds after a resize, and
     # hot-swap retraces all skip recompilation when the cache is warm.
-    # None = only $SPARKNET_COMPILE_CACHE / $JAX_COMPILATION_CACHE_DIR,
-    # if set; compile events grow a cache_hit label either way
+    # $JAX_COMPILATION_CACHE_DIR, where set, IS the cache and this field
+    # is ignored; unset, this directory is used, and None means the fixed
+    # <checkout>/.cache/jax. Compile events carry a cache_hit label
     # (sparknet_compile_events_total{what,cache_hit}).
     compile_cache_dir: Optional[str] = None
     # checkpoint. checkpoint_dir accepts a local path OR a gs://|s3://
@@ -280,9 +281,8 @@ class RunConfig:
     # fetch/flush round metrics every K rounds (losses stay on device in
     # between). The loop's ONLY per-round host sync is the deferred loss
     # fetch; when rounds are shorter than the dispatch/fetch round trip
-    # (very fast models, or a high-latency dev tunnel where a fetch costs
-    # ~100 ms), K>1 amortizes that sync K-fold. Log content is identical,
-    # just flushed in batches.
+    # (very fast models), K>1 amortizes that sync K-fold. Log content is
+    # identical, just flushed in batches.
     log_every: int = 1
     seed: int = 0
     # jax.profiler capture: trace ONE steady-state round (start_round+1,

@@ -11,15 +11,17 @@ import numpy as np
 
 from ..model.net import CompiledNet
 
-#: peak dense bf16 TFLOP/s per chip by device_kind substring (public specs).
-PEAK_BF16_TFLOPS = (
-    ("v6", 918.0),   # Trillium
-    ("v5p", 459.0),
-    ("v5", 197.0),   # v5e / "TPU v5 lite"
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 46.0),
-)
+#: peak dense bf16 TFLOP/s of ONE chip, keyed by the EXACT string
+#: `jax.devices()[0].device_kind` prints, each with its source. A device
+#: that is not here is an error (`peak_bf16_flops`), never a default: a
+#: utilization divided by a guessed peak is a made-up number.
+PEAK_BF16_TFLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip.
+    # "TPU v5 lite" is what the v5e reports (chip_smoke.py, PR 21).
+    "TPU v5 lite": 197.0,
+    # Google Cloud documentation, "TPU v4": 275 TFLOP/s bf16 per chip
+    "TPU v4": 275.0,
+}
 
 #: fwd+bwd FLOPs as a multiple of forward FLOPs: backward computes both the
 #: data gradient and the weight gradient, each a conv/matmul of forward cost.
@@ -47,10 +49,12 @@ def train_flops_per_image(net: CompiledNet) -> float:
 
 
 def peak_bf16_flops(device_kind: str) -> float:
-    """Peak dense bf16 FLOP/s for a device_kind string (e.g. 'TPU v5 lite');
-    0.0 when unknown (callers then omit MFU rather than fabricate it)."""
-    kind = device_kind.lower()
-    for key, tflops in PEAK_BF16_TFLOPS:
-        if key in kind:
-            return tflops * 1e12
-    return 0.0
+    """Peak dense bf16 FLOP/s for an exact device_kind string (e.g.
+    'TPU v5 lite'). Raises KeyError for a device the table does not hold."""
+    try:
+        return PEAK_BF16_TFLOPS[device_kind] * 1e12
+    except KeyError:
+        raise KeyError(
+            f"no peak bf16 FLOP/s on record for device_kind "
+            f"{device_kind!r} (known: {sorted(PEAK_BF16_TFLOPS)}); add it "
+            f"to utils/flops.PEAK_BF16_TFLOPS with its source") from None

@@ -43,10 +43,32 @@ def test_conv_flops_shape_math():
     assert f == pytest.approx(total)
 
 
+def test_default_bench_refuses_to_time_a_cpu():
+    """`python bench.py` measures the chip: off it, one clear line and a
+    non-zero exit — never a CPU number under a device metric's name."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
+                       capture_output=True, text=True, timeout=300,
+                       cwd=repo, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode != 0
+    assert "times a TPU" in p.stderr and "'cpu'" in p.stderr, p.stderr[-800:]
+    assert "images_per_sec" not in p.stdout
+
+
 def test_peak_lookup():
     assert flops.peak_bf16_flops("TPU v5 lite") == pytest.approx(197e12)
     assert flops.peak_bf16_flops("TPU v4") == pytest.approx(275e12)
-    assert flops.peak_bf16_flops("cpu") == 0.0  # unknown -> omit MFU
+
+
+@pytest.mark.parametrize("kind", ["cpu", "no such device", "TPU v5",
+                                  "tpu v5 lite"])
+def test_unknown_device_kind_raises(kind):
+    """An unknown device is an error, never a 0.0 that silently drops MFU;
+    keys are exact — "v5" inside another kind's name matches nothing."""
+    with pytest.raises(KeyError, match="no peak bf16"):
+        flops.peak_bf16_flops(kind)
 
 
 def test_bench_round_timing_core():

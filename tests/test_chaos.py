@@ -179,20 +179,29 @@ def test_kill9_mid_upload_resumes_bitexact_from_bucket(tmp_path,
     monkeypatch.setenv("no_proxy", "*")
 
     def launch(ckdir, workdir):
+        # the child's output goes to a FILE: the chaos run below polls the
+        # store without reading the child, and a pipe nobody drains blocks
+        # the child once 64 KB are in it (a warm compile cache makes XLA's
+        # CPU loader that talkative) — the kill window then never comes
         os.makedirs(workdir, exist_ok=True)
-        return subprocess.Popen(
-            [sys.executable, "-c", CHILD_BUCKET_CKPT, root, ckdir,
-             os.path.join(workdir, "prog.jsonl"), str(BUCKET_ROUNDS)],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-            cwd=os.path.join(os.path.dirname(__file__), ".."),
-            env=dict(os.environ))
+        with open(os.path.join(workdir, "child.out"), "w") as out:
+            return subprocess.Popen(
+                [sys.executable, "-c", CHILD_BUCKET_CKPT, root, ckdir,
+                 os.path.join(workdir, "prog.jsonl"), str(BUCKET_ROUNDS)],
+                stdout=out, stderr=subprocess.STDOUT,
+                cwd=os.path.join(os.path.dirname(__file__), ".."),
+                env=dict(os.environ))
+
+    def finish(p, workdir):
+        p.wait(timeout=420)
+        with open(os.path.join(workdir, "child.out")) as f:
+            out = f.read()
+        assert p.returncode == 0 and "CHILD DONE" in out, out[-4000:]
 
     try:
         # uninterrupted reference run, local checkpoint dir
         ck_a = str(tmp_path / "ck_a")
-        p = launch(ck_a, str(tmp_path / "run_a"))
-        out, _ = p.communicate(timeout=420)
-        assert p.returncode == 0 and "CHILD DONE" in out, out
+        finish(launch(ck_a, str(tmp_path / "run_a")), str(tmp_path / "run_a"))
 
         # chaos run against the bucket: kill WHILE an upload session for
         # the checkpoint prefix is live AND at least one step committed
@@ -217,9 +226,8 @@ def test_kill9_mid_upload_resumes_bitexact_from_bucket(tmp_path,
 
         # relaunch: must resume from the newest COMMITTED bucket step and
         # finish; the torn upload is swept/ignored
-        p = launch(ck_b, str(tmp_path / "run_b2"))
-        out, _ = p.communicate(timeout=420)
-        assert p.returncode == 0 and "CHILD DONE" in out, out
+        finish(launch(ck_b, str(tmp_path / "run_b2")),
+               str(tmp_path / "run_b2"))
         text = open(str(tmp_path / "run_b2" / "train.txt")).read()
         assert "resumed from checkpoint round" in text
 

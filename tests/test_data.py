@@ -716,7 +716,7 @@ def test_tar_index_matches_tarfile_path_exactly(tmp_path):
     same bytes, same labels, same cursor numbering (resume depends on it),
     including unlabeled-entry skips and mid-shard seeks."""
     from sparknet_tpu.data import jpeg_plane
-    if not jpeg_plane.supports_tar_index():
+    if not jpeg_plane.available():
         pytest.skip("native plane unavailable")
     loader_idx = _stream_fixture(tmp_path, n_shards=2, per_shard=8)
     loader_tar = _stream_fixture(tmp_path, n_shards=2, per_shard=8)
@@ -748,7 +748,7 @@ def test_tar_index_extension_headers_fall_back(tmp_path):
     import tarfile as _tarfile
     from PIL import Image
     from sparknet_tpu.data import jpeg_plane
-    if not jpeg_plane.supports_tar_index():
+    if not jpeg_plane.available():
         pytest.skip("native plane unavailable")
     root = tmp_path / "ln"
     root.mkdir()
@@ -773,7 +773,7 @@ def test_truncated_shard_fails_loudly(tmp_path):
     silently drop the tail: the C index refuses (last member extends past
     EOF) and the tarfile fallback then reports the corruption."""
     from sparknet_tpu.data import jpeg_plane
-    if not jpeg_plane.supports_tar_index():
+    if not jpeg_plane.available():
         pytest.skip("native plane unavailable")
     loader = _stream_fixture(tmp_path, n_shards=1, per_shard=8)
     path = loader.shard_paths[0]

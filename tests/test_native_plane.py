@@ -1,5 +1,6 @@
 """C++ data plane tests (skipped if the native lib can't build)."""
 import io
+import os
 
 import numpy as np
 import pytest
@@ -8,6 +9,30 @@ from sparknet_tpu.data import jpeg_plane
 
 pytestmark = pytest.mark.skipif(not jpeg_plane.available(),
                                 reason="native plane unavailable")
+
+
+def test_library_is_named_by_its_source_and_this_host(tmp_path,
+                                                       monkeypatch):
+    """A binary is loaded only if it was built from the committed
+    native/jpeg_plane.cpp + build.sh on THIS host: the name carries a hash
+    of all three, so another machine's or revision's .so is never found."""
+    import re
+    import shutil
+    path = jpeg_plane.so_path()
+    assert re.fullmatch(r"libjpeg_plane-[0-9a-f]{16}\.so",
+                        os.path.basename(path))
+    assert jpeg_plane._load()._name == path  # what is loaded is that file
+    with monkeypatch.context() as m:
+        m.setattr(jpeg_plane, "_cpu_identity", lambda: b"another host")
+        assert os.path.basename(jpeg_plane.so_path()) != \
+            os.path.basename(path)
+    edited = tmp_path / "native"  # the same sources, one byte more
+    shutil.copytree(os.path.dirname(path), edited,
+                    ignore=shutil.ignore_patterns("*.so"))
+    with open(edited / "jpeg_plane.cpp", "ab") as f:
+        f.write(b"\n")
+    monkeypatch.setattr(jpeg_plane, "_NATIVE_DIR", str(edited))
+    assert os.path.basename(jpeg_plane.so_path()) != os.path.basename(path)
 
 
 def make_jpeg(arr):
@@ -88,8 +113,6 @@ def test_bf16_out_bit_identical_to_ml_dtypes(rng):
     into +/-Inf through the RNE add), Inf, and values that round up to Inf."""
     import ml_dtypes
 
-    if not jpeg_plane.supports_bf16_out():
-        pytest.skip("libjpeg_plane.so predates bf16 output")
     imgs = rng.integers(0, 256, (1, 1, 16, 16), dtype=np.uint8)
     mean = rng.standard_normal((1, 16, 16)).astype(np.float32) * 300
     # plant specials: out = u8 - mean, so mean=NaN -> NaN, mean=-Inf -> Inf,

@@ -32,8 +32,6 @@ def _xla_bwd(x, dy, k, s):
 @pytest.mark.parametrize("H,C,k,s", [(13, 8, 3, 2), (12, 8, 2, 2),
                                      (9, 16, 3, 1)])
 def test_kernel_matches_oracle_and_xla(rng, H, C, k, s):
-    if not pp.kernel_api_available():
-        pytest.skip("pallas pool kernel needs pl.Element (newer jax)")
     N = 128
     x = _tie_heavy(rng, (N, H, H, C))
     OH = (H - k) // s + 1
@@ -69,8 +67,8 @@ def test_pool2d_impl_pallas_rejects_unsupported(rng):
 
 
 def test_pool2d_auto_consults_the_gate_and_degrades_to_xla():
-    """r6 made `auto` a real dispatch: it consults the full gate (backend/
-    kernel-API/shape) and takes the Pallas kernel where it passes —
+    """r6 made `auto` a real dispatch: it consults the full gate
+    (backend/shape) and takes the Pallas kernel where it passes —
     `RunConfig.pool_impl="xla"` is the explicit opt-out. This pins both
     halves: the gate IS consulted, and a False answer lands on the XLA
     lowering (never a crash). The r3 'auto stays on select-and-scatter'
@@ -87,8 +85,8 @@ def test_pool2d_auto_consults_the_gate_and_degrades_to_xla():
         assert y.shape == (128, 6, 6, 8)    # gate said no -> XLA lowering
     finally:
         pooling._can_pallas_pool = orig
-    # on this backend/toolchain the real gate answers False (CPU without
-    # interpret, or a Pallas too old for the kernel API): auto == xla
+    # on this backend the real gate answers False (CPU without
+    # interpret): auto == xla
     if not pooling._can_pallas_pool(x, 3, 2, 0):
         y_auto = pool2d(x, "MAX", 3, 2, 0)
         y_xla = pool2d(x, "MAX", 3, 2, 0, impl="xla")

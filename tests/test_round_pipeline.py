@@ -346,14 +346,7 @@ def test_net_level_pallas_lrn_parity_bf16():
 
 
 def test_net_level_pallas_pool_parity_bf16():
-    """Same wiring pin for the MAX-pool backward kernel. Needs the
-    Element/BoundedSlice Pallas API (jax >= 0.5); on older jax the gate
-    makes 'auto'/explicit-pallas unavailable and the arm is skipped —
-    the XLA fallback is then the ONLY path, which the gate test below
-    still pins."""
-    from sparknet_tpu.ops.pallas_pool import kernel_api_available
-    if not kernel_api_available():
-        pytest.skip("pallas pool kernel needs pl.Element (newer jax)")
+    """Same wiring pin for the MAX-pool backward kernel."""
     net, batch, params = _parity_net_and_batch()
     with precision.policy("bfloat16"):
         l_pal, g_pal = _loss_and_grads(
@@ -373,9 +366,9 @@ def test_net_level_pallas_pool_parity_bf16():
 
 
 def test_pool_auto_gate_degrades_to_xla_not_crash():
-    """'auto' must NEVER die on a backend where the kernel API is absent
-    or the shape gate fails — it silently takes the XLA lowering (the
-    explicit fallback); only impl='pallas' is allowed to raise."""
+    """'auto' must NEVER die where the shape gate fails — it silently takes
+    the XLA lowering (the explicit fallback); only impl='pallas' is allowed
+    to raise."""
     from sparknet_tpu.ops.pooling import pool2d
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (2, 7, 7, 16)).astype(np.float32))  # N=2: fails the 128-lane gate

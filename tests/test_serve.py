@@ -664,7 +664,8 @@ def test_manager_loads_sharded_manifest_checkpoints(net, tmp_path):
 
 # -- r12 freshness-era poll behavior -----------------------------------------
 
-def test_manager_store_outage_is_store_error_not_corrupt(net, tmp_path):
+def test_manager_store_outage_is_store_error_not_corrupt(net, tmp_path,
+                                                         monkeypatch):
     """A store that stops answering mid-poll is TRANSIENT trouble: it
     lands under swaps_total{outcome="store_error"}, cools down NO step
     (the checkpoint is probably fine), raises no swap_failures (a fleet
@@ -672,6 +673,7 @@ def test_manager_store_outage_is_store_error_not_corrupt(net, tmp_path):
     poll with full-jitter backoff inside one interval."""
     from fake_stores import bucket_store, stop_serving
 
+    from sparknet_tpu.data import gcs
     from sparknet_tpu.obs import MetricsRegistry
     reg = MetricsRegistry()
     with bucket_store("gs") as (url, srv):
@@ -682,6 +684,12 @@ def test_manager_store_outage_is_store_error_not_corrupt(net, tmp_path):
         assert m.load_initial() == 1
         _save_trainstate_like(net, d, step=2)
         stop_serving(srv)
+        # the stopped server still holds its port, so every attempt waits
+        # out the client's timeout: at the production 60 s x 5 attempts
+        # this one test slept 300 s of tier-1's 870. Same outage, same
+        # retry loop, a clock that fits (this endpoint's client only).
+        monkeypatch.setattr(gcs._shared_client(), "timeout", 0.5)
+        monkeypatch.setattr(gcs, "BACKOFF_S", 0.01)
         t0 = time.monotonic()
         assert m.poll(now=t0) is False
     assert m.step == 1
