@@ -1,0 +1,8 @@
+"""Start-to-done time of the averaging all-reduces per round, fullest device."""
+from __future__ import annotations
+
+
+def read(run):
+    if run.trace is None or run.ctx.cell["chips"] < 2:
+        return None
+    return run.ctx.load("metric_math.py").traced_rounds_ms(run, "collective_s")

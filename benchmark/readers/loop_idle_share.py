@@ -1,0 +1,6 @@
+"""1 - device busy / traced window under run_loop, in %."""
+from __future__ import annotations
+
+
+def read(run):
+    return run.ctx.load("metric_math.py").idle_share(run)
