@@ -238,15 +238,22 @@ def prepare_round_batches(source, rnd: int, tau: int, seed: int,
     stateless = isinstance(source, RoundSampler) or \
         getattr(source, "stateless_rounds", False)
     data_rnd = rnd + retry * _RETRY_DATA_OFFSET if retry and stateless else rnd
-    batches = source.next_round(round_index=data_rnd)
+    # the four spans below are the host work of one round as the prefetch
+    # thread does it, in turn, inside `round_prep`: with `trace_out` they
+    # need no device profiler, in a `profile_dir` capture they sit beside
+    # the device's ops
+    with obs_trace.span("sample", round=rnd):
+        batches = source.next_round(round_index=data_rnd)
     if batch_transform is not None:
-        slices = [batch_transform.convert_batch(
-            {k: v[t] for k, v in batches.items()}, train=True,
-            rng=np.random.default_rng((seed, data_rnd, retry, t)
-                                      if retry else (seed, rnd, t)))
-            for t in range(tau)]
-        batches = {k: np.stack([s[k] for s in slices])
-                   for k in slices[0]}
+        with obs_trace.span("preprocess", round=rnd):
+            slices = [batch_transform.convert_batch(
+                {k: v[t] for k, v in batches.items()}, train=True,
+                rng=np.random.default_rng((seed, data_rnd, retry, t)
+                                          if retry else (seed, rnd, t)))
+                for t in range(tau)]
+        with obs_trace.span("stack", round=rnd):
+            batches = {k: np.stack([s[k] for s in slices])
+                       for k in slices[0]}
     if health is not None and health.enabled and first_pass:
         # injection is inert when the supervisor is off: poisoning a run
         # with nothing watching would recreate exactly the silent-NaN
@@ -256,7 +263,8 @@ def prepare_round_batches(source, rnd: int, tau: int, seed: int,
         elif rnd in health.inject_spike_rounds:
             batches = poison_batch(batches, "spike",
                                    scale=health.inject_spike_scale)
-    return precision.cast_host_inputs(batches, compute_dt)
+    with obs_trace.span("cast", round=rnd):
+        return precision.cast_host_inputs(batches, compute_dt)
 
 
 def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
@@ -376,6 +384,9 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
         # jitted round's cache size (churn = recompiles) live-read
         dev_tel = obs_device.DeviceTelemetry(registry)
         obs_device.attach_compile_metrics(registry)
+        # sparknet_train_round_{temp,argument,output}_bytes, once a
+        # profile_dir run has asked the round program for its report
+        obs_device.attach_program_gauges(registry)
         if hasattr(trainer, "compiled_variants"):
             g_variants = registry.gauge(
                 "sparknet_train_round_compiled_variants",
@@ -481,7 +492,10 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                 status=lambda: {**vitals,
                                 "rollbacks": (monitor.rollbacks
                                               if monitor else 0),
-                                "phase_means": timers.summary()})
+                                "phase_means": timers.summary(),
+                                # {} until a profile_dir run has asked
+                                "program_memory":
+                                    obs_device.program_memory()})
         except OSError as e:
             # a taken port (co-located processes sharing a fixed
             # status_port) degrades observability, never training —
@@ -1059,23 +1073,25 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                 log.log(f"test accuracy: {acc:.4f}", rnd)
                 log.metrics(rnd, test_accuracy=acc)
 
-            with timers.phase("sample"):
-                batches = (pending.result() if pending is not None
-                           else prepare_round(rnd, retry,
-                                              rnd > high_water))
-            pending = None
-            if rnd + 1 < cfg.max_rounds:
-                pending = prefetch.submit(prepare_round, rnd + 1, retry,
-                                          rnd + 1 > high_water)
-            high_water = max(high_water, rnd)
-            sub = jax.random.fold_in(base_rng, rnd)
-            if retry:  # deterministic-but-different retried window
-                sub = jax.random.fold_in(sub, retry)
-            before = timers.total.get("train_round", 0.0)
-            # trace ONE steady-state round (the first would trace compile)
+            # trace ONE steady-state round (the first would trace compile):
+            # the wait for its rows, the prefetch thread preparing the next
+            # (`round_prep` and its phases), and the dispatch
             profile_this = cfg.profile_dir and rnd == start_round + 1
             with profiling.maybe_trace(cfg.profile_dir if profile_this
                                        else None):
+                with timers.phase("sample"):
+                    batches = (pending.result() if pending is not None
+                               else prepare_round(rnd, retry,
+                                                  rnd > high_water))
+                pending = None
+                if rnd + 1 < cfg.max_rounds:
+                    pending = prefetch.submit(prepare_round, rnd + 1, retry,
+                                              rnd + 1 > high_water)
+                high_water = max(high_water, rnd)
+                sub = jax.random.fold_in(base_rng, rnd)
+                if retry:  # deterministic-but-different retried window
+                    sub = jax.random.fold_in(sub, retry)
+                before = timers.total.get("train_round", 0.0)
                 with timers.phase("train_round"):
                     tr_kw: Dict[str, Any] = {}
                     if supports_lr and lr_scale != 1.0:
@@ -1101,6 +1117,15 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                         flush_deferred(wait=False)
             if profile_this:
                 log.log(f"profiler trace written to {cfg.profile_dir}", rnd)
+                # a profiled run is the one place the loop asks the round
+                # program for its own account (a compile-cache hit, or a
+                # second compile): never in an unprofiled run
+                report = (obs_device.program_report("train_round")
+                          if hasattr(trainer, "program_report") else None)
+                if report is not None:
+                    log.log("train_round program, bytes per device: "
+                            + ", ".join(f"{k} {v}" for k, v in
+                                        report["memory"].items()), rnd)
             # steady state (log_every=1), this measures one device round:
             # dispatch of rnd + wait for rnd-1 (overlap of exactly one
             # round); with log_every=K the sync cost amortizes over K

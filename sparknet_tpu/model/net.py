@@ -169,7 +169,13 @@ class CompiledNet:
         for layer in self.spec.layers_for_phase(phase):
             _, apply_fn, _ = LAYER_IMPLS[layer.type]
             inputs = tuple(blobs[b] for b in layer.bottoms)
-            outputs = apply_fn(layer, params.get(layer.name), inputs, ctx)
+            # the scope carries the layer's TYPE and NAME into every op's
+            # metadata (forward, and under transpose(jvp(...)) backward):
+            # obs.device.program_report reads them back from the compiled
+            # text, so a reader of a device trace needs no model table
+            with jax.named_scope(f"{layer.type}/{layer.name}"):
+                outputs = apply_fn(layer, params.get(layer.name), inputs,
+                                   ctx)
             for t, v in zip(layer.tops, outputs):
                 blobs[t] = v
                 all_tops.add(t)

@@ -42,6 +42,7 @@ registries die normally.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 import weakref
@@ -205,3 +206,228 @@ class DeviceTelemetry:
             self._g_live.set(float(len(jax.live_arrays())))
         except Exception:
             pass
+
+
+
+# -- a compiled program's account of itself ----------------------------------
+#
+# Every instruction of a compiled module carries the `jax.named_scope`s it
+# was traced under as `metadata={op_name="jit(train_round)/.../tau_step/
+# transpose(jvp(Convolution/conv1))/dot_general"}`, under the instruction
+# name a device trace prints (`%fusion.769`). `program_report(name)` parses
+# that text once, on demand, so a reader of a trace can say which layer and
+# which part of the step an op belongs to without a model table.
+
+#: name -> zero-argument provider of the program's report (the newest
+#: registration: one trainer at most is kept alive by it)
+_programs: Dict[str, Any] = {}
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s*\(.*\{\s*$")
+_INSTRUCTION = re.compile(
+    r"^\s+(ROOT\s+)?(%?[\w.\-]+)\s*=\s*(.*)$")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(calls|to_apply|select|scatter|body|condition|branch_computations|"
+    r"true_computation|false_computation)=\{?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
+_LAYER = re.compile(r"(?:^|[/(])([A-Z][A-Za-z0-9]*)/([A-Za-z0-9_.\-]+)")
+#: instructions that run a computation of their own: their time is their
+#: body's, listed instruction by instruction
+CONTAINERS = ("while", "conditional", "call")
+#: the keys under which an instruction names a computation that is NOT a
+#: sequence of device ops of its own (a fusion's body, a reduction's adder)
+_INLINED = ("calls", "to_apply", "select", "scatter")
+STEP_SCOPE, OPTIMIZER_SCOPE = "tau_step", "solver_update"
+PHASES = ("forward", "backward", "optimizer", "outside_step")
+
+
+def register_program(name: str, provider) -> None:
+    """Make `program_report(name)` answer from `provider()` — called by a
+    trainer when it compiles (the latest registration wins)."""
+    _programs[name] = provider
+
+
+def program_report(name: str) -> Optional[Dict[str, Any]]:
+    """The compiled program's account of itself, by program name
+    (`"train_round"`): `{"memory": {"argument", "output", "alias", "temp"}
+    (bytes per device, XLA's memory analysis), "ops": {"%fusion.769":
+    {"scope", "phase", "layer_type", "layer", "opcode"}, ...}}` — see
+    `report_of_compiled` for the attribution rule. None when no such
+    program is registered or it has not been dispatched yet.
+
+    NEVER on the round path: the first call lowers and compiles the program
+    again (a persistent-compile-cache hit where the cache is on, a second
+    compile where it is not) and parses its text; nothing calls it in an
+    untraced run. The memory numbers then show as the gauges
+    `sparknet_<name>_{temp,argument,output}_bytes` on the registries
+    `attach_program_gauges` was given, and in `program_memory()`."""
+    provider = _programs.get(name)
+    report = provider() if provider is not None else None
+    if report is not None:
+        _program_memory[name] = report["memory"]
+    return report
+
+
+def scope_of(op_name: str) -> Dict[str, Any]:
+    """`{"scope", "phase", "layer_type", "layer"}` of one `op_name` path.
+    Inside `tau_step`: under `solver_update` is optimizer, a path through
+    `transpose(` is backward, everything else (the loss's own arithmetic
+    with it) forward; outside `tau_step` (the scan's slicing of the stack,
+    the peeled step's copy, `tau_boundary`, the health reductions) is
+    `outside_step`. The layer is the `<Type>/<name>` scope `CompiledNet.
+    apply` opened."""
+    parts = op_name.split("/")
+    if parts and parts[0].startswith("jit("):
+        parts = parts[1:]
+    scope = "/".join(parts[:-1])  # the last component is the primitive
+    if STEP_SCOPE not in parts:
+        phase = "outside_step"
+    elif OPTIMIZER_SCOPE in parts:
+        phase = "optimizer"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    else:
+        phase = "forward"
+    m = _LAYER.search(scope)
+    return {"scope": scope, "phase": phase,
+            "layer_type": m.group(1) if m else None,
+            "layer": m.group(2) if m else None}
+
+
+def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
+    """Every instruction that runs as a device op of its own (those of the
+    entry computation, of loop bodies and conditions and of called
+    computations — not the insides of a fusion), by instruction name, with
+    the scope it is attributed to. The rule:
+
+      * an instruction belongs to the scope of its own `op_name`;
+      * a fusion that contains a `convolution` or a `dot` belongs to THAT
+        instruction's scope — the matmul sets its time, whatever XLA fused
+        behind it (a bias add, the solver's subtract); where it holds
+        several, the first in the fused computation's order wins and
+        `"matmuls"` says how many there were;
+      * any other fusion belongs to the scope of its root (the fusion's
+        own `op_name`, which XLA takes from its root; the root's where the
+        fusion has none);
+      * an instruction with no `op_name` of its own (a copy or a prefetch
+        the compiler put in) takes that of the nearest instruction of its
+        computation that made one of its operands, else of the nearest that
+        uses it, else `outside_step` with no layer.
+    """
+    comps: Dict[str, List[Dict[str, Any]]] = {}
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m and "=" not in line.split("(", 1)[0]:
+                current = comps.setdefault(m.group(1).lstrip("%"), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = m.group(3)
+        body = rest.split(", metadata=", 1)[0]
+        op = _OPCODE.search(" " + body)
+        name = _OP_NAME.search(rest)
+        paren = body.find("(", op.start()) if op else -1
+        current.append({
+            "name": "%" + m.group(2).lstrip("%"), "root": bool(m.group(1)),
+            "opcode": op.group(1) if op else "",
+            "op_name": name.group(1) if name else None,
+            "called": {k: [c.strip().lstrip("%") for c in v.split(",")]
+                       for k, v in _CALLED.findall(body)},
+            "operands": re.findall(r"%[\w.\-]+", body[paren:].split(")")[0])
+            if paren >= 0 else []})
+    inlined = {c for ins in comps.values() for i in ins
+               for k, cs in i["called"].items() if k in _INLINED
+               and i["opcode"] not in CONTAINERS for c in cs}
+    ops: Dict[str, Dict[str, Any]] = {}
+    for cname, instructions in comps.items():
+        if cname in inlined:
+            continue
+        by_name = {i["name"]: i for i in instructions}
+        users: Dict[str, List[str]] = {}
+        own: Dict[str, Optional[str]] = {}   # by the first three rules
+        extras: Dict[str, Dict[str, Any]] = {}
+        for i in instructions:
+            for o in i["operands"]:
+                users.setdefault(o, []).append(i["name"])
+            op_name = i["op_name"]
+            if i["opcode"] == "fusion":
+                fused = comps.get((i["called"].get("calls") or [""])[0], [])
+                mm = [f for f in fused
+                      if f["opcode"] in ("convolution", "dot")
+                      and f["op_name"]]
+                root = [f for f in fused if f["root"] and f["op_name"]]
+                if mm:
+                    op_name = mm[0]["op_name"]
+                    if len(mm) > 1:
+                        extras[i["name"]] = {"matmuls": len(mm)}
+                elif op_name is None and root:
+                    op_name = root[0]["op_name"]
+            own[i["name"]] = op_name
+        for i in instructions:
+            if i["opcode"] == "parameter":
+                continue
+            op_name = (own[i["name"]]  # else the compiler's own: a copy
+                       or _inherit(i, by_name, own, lambda x: x["operands"])
+                       or _inherit(i, by_name, own,
+                                   lambda x: users.get(x["name"], [])))
+            ops[i["name"]] = {**scope_of(op_name or ""),
+                              "opcode": i["opcode"],
+                              **extras.get(i["name"], {})}
+    return ops
+
+
+def _inherit(instruction, by_name, own, neighbours) -> Optional[str]:
+    """The attributed `op_name` (`own`) of the nearest instruction reached
+    from `instruction` along `neighbours` (its operands, or its users) that
+    has one, first neighbour first; None when the walk ends at parameters
+    or the root."""
+    seen, queue = {instruction["name"]}, [instruction]
+    while queue:
+        for name in neighbours(queue.pop(0)):
+            nxt = by_name.get(name)
+            if nxt is None or name in seen or nxt["opcode"] == "parameter":
+                continue
+            if own[name]:
+                return own[name]
+            seen.add(name)
+            queue.append(nxt)
+    return None
+
+
+def report_of_compiled(compiled) -> Dict[str, Any]:
+    """The report of one `jax.stages.Compiled` (what a program's provider
+    returns): its memory analysis and `parse_hlo_ops` of its text."""
+    mem = compiled.memory_analysis()
+    return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
+                       for k in ("argument", "output", "alias", "temp")},
+            "ops": parse_hlo_ops(compiled.as_text())}
+
+
+#: program -> its report's memory part, once `program_report` has run
+_program_memory: Dict[str, Dict[str, int]] = {}
+
+
+def attach_program_gauges(registry: MetricsRegistry,
+                          name: str = "train_round") -> None:
+    """Show `sparknet_<name>_{temp,argument,output}_bytes` on this
+    registry's /metrics: live-read gauges with no sample until
+    `program_report(name)` has run (they never ask for it themselves)."""
+    for key in ("temp", "argument", "output"):
+        registry.gauge(
+            f"sparknet_{name}_{key}_bytes",
+            f"the compiled {name} program's {key} bytes per device (XLA "
+            f"memory analysis, read by program_report)"
+        ).set_fn(lambda key=key: _program_memory[name][key])
+
+
+def program_memory() -> Dict[str, Dict[str, int]]:
+    """{program: its report's memory part} for every program whose report
+    has been asked for so far — a read of what is cached, never a compile
+    (the /status route)."""
+    return dict(_program_memory)

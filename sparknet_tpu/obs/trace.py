@@ -1,7 +1,7 @@
-"""Host-side span tracer: Chrome-trace-event JSON with per-thread lanes.
+"""Host-side span tracer: one `span()`, two sinks, one clock.
 
 `jax.profiler` (utils/profiling.py) answers "what did the DEVICE do";
-nothing answered "where did the host's wall clock go" across the threads
+this module answers "where did the host's wall clock go" across the threads
 this codebase actually runs: the round loop, the one-deep prefetch thread
 (`round-prep`), the async checkpoint writer (`ckpt-write`), and the serve
 worker. This tracer is that cross-thread picture, in the Dapper tradition
@@ -17,21 +17,46 @@ traces from different processes (a trainer and a server watching its
 checkpoints) merge on one timeline — the same reason the metrics JSONL now
 carries a wall-clock `ts` field.
 
-Tracing is off by default and costs one None-check per span when off (the
-<= 2% telemetry-overhead budget in BENCH_OBS.json includes it ON). One
-process-wide active tracer: spans are emitted by library code (checkpoint
-writer, serve worker) that cannot know which run is being traced, so
-activation is global — `start_tracing()` / `stop_tracing()`, or the
-`tracing(path)` context manager the train loop uses for `--trace-out`.
+The second sink is the profiler itself. While a `jax.profiler` session is
+live — whoever started it: `RunConfig.profile_dir` (utils/profiling.py), the
+benchmark's `--trace 1`, an operator's `jax.profiler.start_trace` — every
+span also opens `jax.profiler.TraceAnnotation("sparknet:" + name, **args)`,
+so it lies on the PROFILER's clock in the same xplane as the device's ops
+(host plane, the recording thread's line), and is kept in a bounded
+in-memory record of that session on `time.perf_counter()`: name, start,
+end, thread name, an id and the id of the span that encloses it on the same
+thread (`round` / `step` in `args` is the identifier the spans of one round
+share). `session_spans()` reads the live or the last session's record; it
+stays readable after the session ends, until the next one begins. So a
+`profile_dir` capture now holds this module's events (and the layers'
+`named_scope`s on the device ops), and `trace_out` is the host-only,
+profiler-free view of the same spans.
+
+Tracing is off by default. `span()` is on when a tracer was started
+(`start_tracing`) OR a profiler session is live
+(`TraceAnnotation.is_enabled()`): no configuration field, flag or environment
+variable of its own, and this module alone decides. Off, a span costs one
+None-check and one `is_enabled()` call (the <= 2% telemetry-overhead budget
+in BENCH_OBS.json includes it ON). One process-wide active tracer: spans are
+emitted by library code (checkpoint writer, serve worker) that cannot know
+which run is being traced, so activation is global — `start_tracing()` /
+`stop_tracing()`, or the `tracing(path)` context manager the train loop uses
+for `--trace-out`.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
+
+#: prefix of this module's annotations in a profiler trace
+ANNOTATION_PREFIX = "sparknet:"
 
 #: events kept per tracer; beyond this new spans are counted but dropped
 #: (a runaway soak must not OOM the host to produce a trace)
@@ -51,14 +76,22 @@ class Tracer:
         self._epoch0 = time.time() - time.perf_counter()
 
     def now_us(self) -> float:
-        return (self._epoch0 + time.perf_counter()) * 1e6
+        return self.us(time.perf_counter())
+
+    def us(self, t: float) -> float:
+        """A `time.perf_counter()` reading on this tracer's epoch clock."""
+        return (self._epoch0 + t) * 1e6
 
     def add_complete(self, name: str, t0_us: float, dur_us: float,
-                     args: Optional[Dict[str, Any]] = None) -> None:
+                     args: Optional[Dict[str, Any]] = None,
+                     **extra: Any) -> None:
+        """One complete ("X") event on the calling thread's lane; `extra`
+        keys ride on the event (a session record's span carries its
+        perf_counter times, id and parent there)."""
         th = threading.current_thread()
         ev = {"name": name, "ph": "X", "cat": "host",
               "ts": round(t0_us, 3), "dur": round(dur_us, 3),
-              "pid": self.pid, "tid": th.ident}
+              "pid": self.pid, "tid": th.ident, **extra}
         if args:
             ev["args"] = args
         with self._lock:
@@ -111,6 +144,14 @@ class Tracer:
 
 _active: Optional[Tracer] = None
 
+#: the record of the live profiler session, or of the last one (a Tracer:
+#: bounded by MAX_EVENTS, thread-safe); replaced when the next session begins
+_session: Optional[Tracer] = None
+_session_open = False
+_session_lock = threading.Lock()
+_ids = itertools.count(1)
+_tls = threading.local()
+
 
 def active_tracer() -> Optional[Tracer]:
     return _active
@@ -130,23 +171,79 @@ def stop_tracing() -> Optional[Tracer]:
     return t
 
 
+def _session_record(live: bool) -> Optional[Tracer]:
+    """The record spans go to while a profiler session is live (a fresh one
+    at the first span that sees the session), else None. The last record is
+    kept when its session ends."""
+    global _session, _session_open
+    if not live:
+        if _session_open:
+            _session_open = False
+        return None
+    if not _session_open:
+        with _session_lock:
+            if not _session_open:
+                _session = Tracer()
+                _session_open = True
+    return _session
+
+
+def session_spans() -> List[Dict[str, Any]]:
+    """The host spans of the live profiler session, or of the last one:
+    `{"name", "t0", "t1", "thread", "id", "parent", "args"}` each, times on
+    `time.perf_counter()`, `parent` the id of the span that enclosed it on
+    the same thread (None at the top), in order of completion. Empty when no
+    span ever ran inside a profiler session."""
+    rec = _session
+    if rec is None:
+        return []
+    return [{"name": e["name"], "t0": e["t0"], "t1": e["t1"],
+             "thread": e["thread"], "id": e["id"], "parent": e["parent"],
+             "args": e.get("args", {})}
+            for e in rec.events() if e["ph"] == "X"]
+
+
 @contextmanager
 def span(name: str, **args: Any) -> Iterator[None]:
-    """Record the with-block as one complete event on the current thread's
-    lane. Near-free when tracing is off (one global read + None check)."""
+    """The program's one host span: the with-block as one complete event on
+    the current thread's lane of the active tracer, and — while a
+    `jax.profiler` session is live — as a `sparknet:<name>` annotation in
+    the profiler's own trace plus one entry of the session record (module
+    docstring). Near-free when both are off (one global read + None check,
+    one `is_enabled()` call)."""
     tr = _active
-    if tr is None:
-        yield
+    rec = _session_record(_Annotation.is_enabled())
+    if rec is None:
+        if tr is None:
+            yield
+            return
+        t0 = tr.now_us()
+        try:
+            yield
+        finally:
+            # re-read: a tracer stopped mid-span (loop teardown while the
+            # checkpoint writer drains) must not resurrect into the report
+            if _active is tr:
+                tr.add_complete(name, t0, tr.now_us() - t0, args or None)
         return
-    t0 = tr.now_us()
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    sid = next(_ids)
+    parent = stack[-1] if stack else None
+    stack.append(sid)
+    t0 = time.perf_counter()
     try:
-        yield
+        with _Annotation(ANNOTATION_PREFIX + name, **args):
+            yield
     finally:
-        # re-read: a tracer stopped mid-span (loop teardown while the
-        # checkpoint writer drains) must not resurrect into the report
-        tr2 = _active
-        if tr2 is tr:
-            tr.add_complete(name, t0, tr.now_us() - t0, args or None)
+        t1 = time.perf_counter()
+        stack.pop()
+        rec.add_complete(name, rec.us(t0), (t1 - t0) * 1e6, args or None,
+                         t0=t0, t1=t1, id=sid, parent=parent,
+                         thread=threading.current_thread().name)
+        if tr is not None and _active is tr:
+            tr.add_complete(name, tr.us(t0), (t1 - t0) * 1e6, args or None)
 
 
 @contextmanager

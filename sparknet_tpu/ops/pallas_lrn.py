@@ -87,7 +87,8 @@ def _out_struct(x2: jnp.ndarray) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(x2.shape, x2.dtype, vma=jax.typeof(x2).vma)
 
 
-def _call(kernel, n_out: int, x2: jnp.ndarray, *others, interpret: bool):
+def _call(kernel, n_out: int, x2: jnp.ndarray, *others, interpret: bool,
+          name: str):
     c = x2.shape[-1]
     grid = (x2.shape[0] // BLOCK_ROWS,)
     spec = pl.BlockSpec((BLOCK_ROWS, c), lambda i: (i, 0),
@@ -100,6 +101,7 @@ def _call(kernel, n_out: int, x2: jnp.ndarray, *others, interpret: bool):
         out_specs=[spec] * n_out if n_out > 1 else spec,
         out_shape=[out] * n_out if n_out > 1 else out,
         interpret=interpret,
+        name=name,  # the kernel's stable name in a device trace
     )(x2, *others)
 
 
@@ -131,7 +133,7 @@ def _lrn_fwd_impl(x, local_size, alpha, beta, k, interpret):
     x2, m = _pad_rows(x.reshape(-1, shape[-1]))
     kern = functools.partial(_fwd_kernel, half=half, alpha_n=alpha_n,
                              beta=beta, k=k)
-    y2, scale2 = _call(kern, 2, x2, interpret=interpret)
+    y2, scale2 = _call(kern, 2, x2, interpret=interpret, name="lrn_fwd")
     return y2[:m].reshape(shape), scale2[:m].reshape(shape)
 
 
@@ -154,7 +156,8 @@ def _lrn_vjp_bwd(local_size, alpha, beta, k, interpret, res, dy):
     dy2, _ = _pad_rows(dy.reshape(-1, shape[-1]))
     kern = functools.partial(_bwd_kernel, half=half, alpha_n=alpha_n,
                              beta=beta)
-    dx2 = _call(kern, 1, x2, scale2, dy2, interpret=interpret)
+    dx2 = _call(kern, 1, x2, scale2, dy2, interpret=interpret,
+                name="lrn_bwd")
     return (dx2[:m].reshape(shape),)
 
 
@@ -242,7 +245,8 @@ def _nmin_vmem_limit(br: int, c: int, itemsize: int, n_blocks: int) -> int:
     return max(_DEFAULT_SCOPED_VMEM, need)
 
 
-def _nmin_call(kernel, x3: jnp.ndarray, *others, interpret: bool):
+def _nmin_call(kernel, x3: jnp.ndarray, *others, interpret: bool,
+               name: str):
     r, c, n = x3.shape
     br = _row_block(r)
     spec = pl.BlockSpec((br, c, LANES), lambda i, j: (i, 0, j),
@@ -257,6 +261,7 @@ def _nmin_call(kernel, x3: jnp.ndarray, *others, interpret: bool):
             vmem_limit_bytes=_nmin_vmem_limit(
                 br, c, x3.dtype.itemsize, 2 + len(others))),
         interpret=interpret,
+        name=name,
     )(x3, *others)
 
 
@@ -276,8 +281,8 @@ def _lrn_nmin(x: jnp.ndarray, local_size: int, alpha: float, beta: float,
     half = (local_size - 1) // 2
     kern = functools.partial(_fwd_kernel3, half=half,
                              alpha_n=alpha / local_size, beta=beta, k=k)
-    return _from_nmin(_nmin_call(kern, _to_nmin(x), interpret=interpret),
-                      x.shape)
+    return _from_nmin(_nmin_call(kern, _to_nmin(x), interpret=interpret,
+                                 name="lrn_fwd"), x.shape)
 
 
 def _lrn_nmin_fwd(x, local_size, alpha, beta, k, interpret):
@@ -289,7 +294,8 @@ def _lrn_nmin_bwd(local_size, alpha, beta, k, interpret, res, dy):
     half = (local_size - 1) // 2
     kern = functools.partial(_bwd_kernel3, half=half,
                              alpha_n=alpha / local_size, beta=beta, k=k)
-    dx3 = _nmin_call(kern, _to_nmin(x), _to_nmin(dy), interpret=interpret)
+    dx3 = _nmin_call(kern, _to_nmin(x), _to_nmin(dy), interpret=interpret,
+                     name="lrn_bwd")
     return (_from_nmin(dx3, x.shape),)
 
 

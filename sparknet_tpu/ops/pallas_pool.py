@@ -151,6 +151,7 @@ def _bwd_call(x4, y4, dy4, k: int, s: int, interpret: bool,
         compiler_params=None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=interpret,
+        name="maxpool_bwd",
     )(x4, y4, dy4)
 
 

@@ -70,7 +70,7 @@ from ..model.net import CompiledNet, PyTree
 from ..solver import SolverConfig
 from .mesh import DATA_AXIS, MODEL_AXIS, shard_map_unchecked
 from .trainer import (ParallelTrainer, TrainState, _find_accuracy_blob,
-                      reduce_momentum_rows)
+                      named, reduce_momentum_rows)
 
 STATE_SHARDINGS = ("replicated", "momentum", "full")
 
@@ -230,12 +230,11 @@ class ShardedTrainer(ParallelTrainer):
                 return new_state, loss, health
 
         self._round = jax.jit(
-            round_fn, donate_argnums=(0, 1) if self.donate_batches
-            else (0,))
-        self._eval = jax.jit(
-            self._smap(self._eval_impl, mesh=self.mesh,
-                       in_specs=(self._pspec_compute, P(DATA_AXIS)),
-                       out_specs=P()))
+            named("train_round", round_fn),
+            donate_argnums=(0, 1) if self.donate_batches else (0,))
+        self._eval = jax.jit(named("eval_round", self._smap(
+            self._eval_impl, mesh=self.mesh,
+            in_specs=(self._pspec_compute, P(DATA_AXIS)), out_specs=P())))
 
     def _round_impl(self, state: TrainState, batches, rng, lr_scale,
                     tau_vec=None):

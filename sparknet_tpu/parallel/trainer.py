@@ -42,6 +42,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..model.layers import OpsImpl, tp_shards_layer
 from ..model.net import CompiledNet, PyTree
+from ..obs import device as obs_device
+from ..obs import trace as obs_trace
 from ..solver import SgdSolver, SolverConfig, SolverState
 from .mesh import (DATA_AXIS, MODEL_AXIS, local_device_rows, make_mesh,
                    place_global_state, put_device_axis, scan_unroll,
@@ -211,12 +213,23 @@ class ParallelTrainer:
         self.last_health: Optional[Dict[str, jax.Array]] = None
         self._lr_scale_dev: Optional[Tuple[float, jax.Array]] = None
         #: optional PhaseTimers (utils/metrics.py): when the train loop
-        #: installs one, train_round splits its wall time into "h2d" (the
+        #: installs one, train_round's phases — "round_keys", "h2d" (the
         #: host->device batch placement in _shard_batches) and "dispatch"
-        #: (the compiled round's enqueue) — the per-round step-time
-        #: breakdown's two finest columns. None costs nothing.
+        #: (the compiled round's enqueue) — also accumulate there (the
+        #: step-time breakdown's t_h2d_ms column, /status phase_means).
+        #: The spans themselves need no timers: see `_phase`.
         self.phase_timers = None
+        #: rounds dispatched by this trainer: the `step` its spans carry
+        self._dispatched = 0
+        #: argument shapes + shardings of the first dispatch, remembered
+        #: once for `program_report` (obs/device.py) — nothing per round
+        self._round_avals = None
+        self._report = None
         self._compile()
+        # the newest trainer answers `program_report("train_round")`: a
+        # reader of a trace holds no trainer (strong: the reader may run
+        # when its caller has let the trainer go)
+        obs_device.register_program("train_round", self.program_report)
 
     #: checkpoint/state-layout tag ("replica": every leaf carries the
     #: leading [n_devices] axis; the NamedSharding trainer overrides with
@@ -240,16 +253,18 @@ class ParallelTrainer:
         dev = self._dev_spec
         state_specs = TrainState(params=dev, momentum=dev, it=dev)
         extra_specs = (P(),) if self.elastic_tau else ()
+        # stable program names: the modules are `jit_train_round` and
+        # `jit_eval_round` in a device trace and in the compiled text
         self._round = jax.jit(
-            self._smap(self._round_impl, mesh=self.mesh,
-                       in_specs=(state_specs, P(None, DATA_AXIS),
-                                 P(DATA_AXIS), P()) + extra_specs,
-                       out_specs=(state_specs, P(), self._health_specs())),
+            named("train_round", self._smap(
+                self._round_impl, mesh=self.mesh,
+                in_specs=(state_specs, P(None, DATA_AXIS),
+                          P(DATA_AXIS), P()) + extra_specs,
+                out_specs=(state_specs, P(), self._health_specs()))),
             donate_argnums=(0, 1) if self.donate_batches else (0,))
-        self._eval = jax.jit(
-            self._smap(self._eval_impl, mesh=self.mesh,
-                       in_specs=(dev, P(DATA_AXIS)),
-                       out_specs=P()))
+        self._eval = jax.jit(named("eval_round", self._smap(
+            self._eval_impl, mesh=self.mesh,
+            in_specs=(dev, P(DATA_AXIS)), out_specs=P())))
 
     def compiled_variants(self) -> int:
         """Entries in the jitted round's executable cache — 1 in steady
@@ -522,7 +537,11 @@ class ParallelTrainer:
                         else lax.pmean(lp, self._tp_axis))
                     for l, lp in grads.items()}
 
+        @jax.named_scope("tau_step")
         def local_step(carry, inputs):
+            # `tau_step` (scanned and peeled alike) is the scope
+            # obs.device.program_report splits into forward / backward /
+            # optimizer; what the round runs outside it is `outside_step`
             params, sstate = carry
             if my_tau is None:
                 batch, step_rng = inputs
@@ -594,7 +613,13 @@ class ParallelTrainer:
         else:
             (params, sstate), (losses, grad_sqs) = lax.scan(
                 local_step, init, xs, unroll=scan_unroll(self.tau))
+        return self._tau_boundary(params, sstate, losses, grad_sqs, my_tau)
 
+    @jax.named_scope("tau_boundary")
+    def _tau_boundary(self, params, sstate, losses, grad_sqs, my_tau):
+        """What the round does once the τ steps are done: the weight
+        average over the data axis, the round's mean loss and the health
+        reductions — one scope (`tau_boundary`) in the compiled program."""
         # pre-average view: after the pmean one poisoned worker's NaN is
         # every worker's NaN, so ATTRIBUTION must read the worker-local
         # state (τ-step losses, pre-average params, momentum) first
@@ -726,44 +751,69 @@ class ParallelTrainer:
         adapting never recompiles). None = full τ everywhere, which is
         numerically identical to a non-elastic trainer's round.
         """
-        # one rng row per DATA group, same on every host; TP replicas in a
-        # model group share the row (dropout masks must agree on the
-        # gathered activations)
-        rngs = jax.random.split(rng, self.n_data)
-        rngs = place_global_state(rngs, self.mesh, P(DATA_AXIS))
-        if self._lr_scale_dev is None or \
-                self._lr_scale_dev[0] != float(lr_scale):
-            self._lr_scale_dev = (float(lr_scale),
-                                  jnp.asarray(lr_scale, jnp.float32))
-        if self.elastic_tau:
-            vec = (tuple(int(min(self.tau, max(1, t)))
-                         for t in tau_by_worker)
-                   if tau_by_worker is not None
-                   else (self.tau,) * self.n_data)
-            assert len(vec) == self.n_data, (
-                f"tau_by_worker has {len(vec)} entries for "
-                f"{self.n_data} data groups")
-            if self._tau_vec_dev is None or self._tau_vec_dev[0] != vec:
-                self._tau_vec_dev = (vec, jnp.asarray(vec, jnp.int32))
-            extra = (self._tau_vec_dev[1],)
-        else:
-            if tau_by_worker is not None:
-                raise ValueError("tau_by_worker requires a trainer built "
-                                 "with elastic_tau=True")
-            extra = ()
-        timers = self.phase_timers
-        if timers is not None:
-            with timers.phase("h2d"):
+        step = self._dispatched
+        self._dispatched += 1
+        with obs_trace.span("train_round", step=step):
+            with self._phase("round_keys", step):
+                # one rng row per DATA group, same on every host; TP
+                # replicas in a model group share the row (dropout masks
+                # must agree on the gathered activations)
+                rngs = jax.random.split(rng, self.n_data)
+                rngs = place_global_state(rngs, self.mesh, P(DATA_AXIS))
+            if self._lr_scale_dev is None or \
+                    self._lr_scale_dev[0] != float(lr_scale):
+                self._lr_scale_dev = (float(lr_scale),
+                                      jnp.asarray(lr_scale, jnp.float32))
+            if self.elastic_tau:
+                vec = (tuple(int(min(self.tau, max(1, t)))
+                             for t in tau_by_worker)
+                       if tau_by_worker is not None
+                       else (self.tau,) * self.n_data)
+                assert len(vec) == self.n_data, (
+                    f"tau_by_worker has {len(vec)} entries for "
+                    f"{self.n_data} data groups")
+                if self._tau_vec_dev is None or self._tau_vec_dev[0] != vec:
+                    self._tau_vec_dev = (vec, jnp.asarray(vec, jnp.int32))
+                extra = (self._tau_vec_dev[1],)
+            else:
+                if tau_by_worker is not None:
+                    raise ValueError("tau_by_worker requires a trainer "
+                                     "built with elastic_tau=True")
+                extra = ()
+            with self._phase("h2d", step):
                 sharded = self._shard_batches(batches)
-            with timers.phase("dispatch"):
-                new_state, loss, health = self._round(
-                    state, sharded, rngs, self._lr_scale_dev[1], *extra)
-        else:
-            new_state, loss, health = self._round(
-                state, self._shard_batches(batches), rngs,
-                self._lr_scale_dev[1], *extra)
+            args = (state, sharded, rngs, self._lr_scale_dev[1]) + extra
+            if self._round_avals is None:
+                # an uncommitted scalar (lr_scale) goes wherever jit puts
+                # it: no sharding of its own
+                self._round_avals = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype,
+                        sharding=x.sharding if x.committed else None),
+                    args)
+            with self._phase("dispatch", step):
+                new_state, loss, health = self._round(*args)
         self.last_health = health or None  # {} when compute_health=False
         return new_state, loss
+
+    def _phase(self, name: str, step: int):
+        """One phase of `train_round`: the installed PhaseTimers' phase
+        (which emits the span itself) or a plain span — the same names
+        either way, one code path."""
+        timers = self.phase_timers
+        return (timers.phase(name, step=step) if timers is not None
+                else obs_trace.span(name, step=step))
+
+    def program_report(self) -> Optional[Dict[str, Any]]:
+        """The compiled round's account of itself (`obs.device.
+        program_report("train_round")`): lowered and compiled again for the
+        argument shapes and shardings of the first dispatch (a persistent-
+        cache hit where the cache is on), its text parsed once. None before
+        the first dispatch. Never on the round path."""
+        if self._report is None and self._round_avals is not None:
+            self._report = obs_device.report_of_compiled(
+                self._round.lower(*self._round_avals).compile())
+        return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":
         """A NEW trainer over the first `n_devices` visible devices — the
@@ -885,6 +935,16 @@ class ParallelTrainer:
 
     def _shard_batches(self, batches):
         return self.place_batches(batches)
+
+
+def named(name: str, fn):
+    """`fn` under `name`: `jax.jit` names its module after the function it
+    is given, and a `shard_map` wrapper has no name of its own."""
+    @functools.wraps(fn)
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def reduce_momentum_rows(rows: np.ndarray, policy: str) -> np.ndarray:

@@ -139,9 +139,12 @@ class SgdSolver:
 
     # -- single-step update (pure) ------------------------------------------
 
+    @jax.named_scope("solver_update")
     def update(self, params: PyTree, state: SolverState, grads: PyTree,
                lr_scale: Any = 1.0) -> Tuple[PyTree, SolverState]:
         """Apply one Caffe-SGD update given precomputed grads (pure fn).
+        Traced under the scope `solver_update`: what a compiled step's
+        report (obs.device.program_report) counts as the optimizer.
 
         `lr_scale` is a runtime (traceable) multiplier on the policy rate —
         the health supervisor's LR-backoff knob. It is an input, not a

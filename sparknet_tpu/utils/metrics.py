@@ -57,8 +57,8 @@ class PhaseTimers:
                 "entries per host-side phase", labels=("phase",))
 
     @contextmanager
-    def phase(self, name: str):
-        with _trace.span(name):
+    def phase(self, name: str, **span_args):
+        with _trace.span(name, **span_args):
             t0 = time.perf_counter()
             try:
                 yield
