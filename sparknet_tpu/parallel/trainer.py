@@ -164,6 +164,11 @@ class ParallelTrainer:
         # all-reduce to start strictly after every local step retired;
         # peeled, XLA's latency-hiding scheduler can overlap the early
         # layers' boundary collective with the tail of the final update.
+        # Scanned and peeled steps alike read their rows by STEP INDEX
+        # out of the whole [tau, ...] stack (`rows_at` in _round_math):
+        # the scan runs indices 0..τ-2 and the peeled step reads τ-1, so
+        # the stack is never sliced along tau (a `x[:-1]` of it is a copy
+        # of τ-1 steps' rows that lives as long as the round does).
         # The peeled round runs the SAME ops on the same values in the
         # same order — pinned bitwise against the unfused two-step
         # (scan-then-average) on the TINY_MLP multi-round trajectory
@@ -587,32 +592,59 @@ class ParallelTrainer:
                 grad_sq = jnp.where(active, grad_sq, 0.0)
             return (new_params, new_sstate), (loss, grad_sq)
 
+        def rows_at(i):
+            # the ONLY place the round reads its rows: step i's
+            # [local_b, ...] slab, indexed out of the closed-over
+            # (donated) [tau, ...] stack. Never slice the stack along
+            # tau: all-but-one step's rows is a copy (3.96 GB of a
+            # 4.04 GB CaffeNet stack at tau=50) that lives as long as
+            # the round and holds the HBM the next round needs. Stays
+            # outside `tau_step`: a row read is the round's work, not
+            # the step's.
+            return jax.tree.map(
+                lambda x: lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
+                batches)
+
+        def step_at(carry, step_idx, step_rng, rows):
+            return local_step(
+                carry, ((rows, step_rng) if my_tau is None
+                        else (rows, step_rng, step_idx)))
+
+        def scanned_step(carry, idx_rng):
+            step_idx, step_rng = idx_rng
+            return step_at(carry, step_idx, step_rng, rows_at(step_idx))
+
         step_rngs = jax.random.split(rng, self.tau)
-        xs = ((batches, step_rngs) if my_tau is None
-              else (batches, step_rngs, jnp.arange(self.tau)))
-        init = (params, SolverState(momentum=momentum, it=it))
+        # fused τ-boundary (ctor comment): scan steps 0..τ-2, then run
+        # step τ-1 PEELED inline so the boundary average below shares
+        # its trace region — same math, same order, bitwise. Unfused,
+        # the scan runs all τ steps.
+        n_scanned = self.tau - 1 if self.fused_boundary else self.tau
+        carry = (params, SolverState(momentum=momentum, it=it))
+        step_idxs = jnp.arange(n_scanned)
         if self.fused_boundary:
-            # fused τ-boundary (ctor comment): τ-1 scanned steps, then
-            # the final step PEELED inline so the boundary average below
-            # shares its trace region — same math, same order, bitwise
-            carry = init
-            if self.tau > 1:
-                carry, (losses, grad_sqs) = lax.scan(
-                    local_step, carry,
-                    jax.tree.map(lambda x: x[:-1], xs),
-                    unroll=scan_unroll(self.tau - 1))
-                carry, (loss_t, gs_t) = local_step(
-                    carry, jax.tree.map(lambda x: x[-1], xs))
-                losses = jnp.concatenate([losses, loss_t[None]])
-                grad_sqs = jnp.concatenate([grad_sqs, gs_t[None]])
-            else:  # τ=1: the whole round is scan-free
-                carry, (loss_t, gs_t) = local_step(
-                    carry, jax.tree.map(lambda x: x[-1], xs))
-                losses, grad_sqs = loss_t[None], gs_t[None]
-            params, sstate = carry
-        else:
-            (params, sstate), (losses, grad_sqs) = lax.scan(
-                local_step, init, xs, unroll=scan_unroll(self.tau))
+            # the peeled step's rows are read BEFORE the scan and pinned
+            # there (the barrier ties them to the indices the scan runs
+            # on). Left to itself XLA sinks this read below the loop, and
+            # with it there the TPU compiler fills the loop body's VMEM
+            # with other prefetches: every scanned step measured 0.5 ms
+            # slower on a v5e (PERF.md §6, PR 26). Costs one step's rows
+            # (79 MB at CaffeNet's size) held through the scan.
+            last_rows = rows_at(self.tau - 1)
+            step_idxs, last_rows = lax.optimization_barrier(
+                (step_idxs, last_rows))
+        outs = []  # (losses, grad_sqs) of each stretch of steps
+        if n_scanned:  # τ=1 fused: the whole round is scan-free
+            carry, scanned = lax.scan(
+                scanned_step, carry, (step_idxs, step_rngs[:n_scanned]),
+                unroll=scan_unroll(n_scanned))
+            outs.append(scanned)
+        if self.fused_boundary:
+            carry, (loss_t, gs_t) = step_at(
+                carry, self.tau - 1, step_rngs[-1], last_rows)
+            outs.append((loss_t[None], gs_t[None]))
+        params, sstate = carry
+        losses, grad_sqs = map(jnp.concatenate, zip(*outs))
         return self._tau_boundary(params, sstate, losses, grad_sqs, my_tau)
 
     @jax.named_scope("tau_boundary")

@@ -169,7 +169,9 @@ class RunConfig:
     # momentum average/re-shard under the named trainer) traces in the
     # same region as the last optimizer update — on TPU the rolled
     # scan's loop boundary otherwise serializes the full-params
-    # all-reduce behind every local step. collect_async moves the
+    # all-reduce behind every local step. Scanned and peeled steps both
+    # read their rows by step index out of the whole (donated) round
+    # stack, so peeling copies nothing. collect_async moves the
     # deferred loss/health fetch onto a background collector thread so
     # the round loop NEVER blocks on boundary results: t_collect_ms in
     # the step-time breakdown reads ~0 (the off-thread fetch lands as

@@ -19,6 +19,7 @@ whole-program cases steer it from the test; the kernel cases call the kernels
 directly.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -33,8 +34,8 @@ from sparknet_tpu import precision
 from sparknet_tpu.model.net import CompiledNet
 from sparknet_tpu.ops.pallas_lrn import lrn_pallas
 from sparknet_tpu.ops.pallas_pool import maxpool_pallas
-from sparknet_tpu.parallel import ParallelTrainer, ShardedTrainer
-from sparknet_tpu.parallel.mesh import DATA_AXIS
+from sparknet_tpu.parallel import ParallelTrainer, ShardedTrainer, make_mesh
+from sparknet_tpu.parallel.mesh import DATA_AXIS, place_global_state
 from sparknet_tpu.parallel.trainer import TrainState
 from sparknet_tpu.solver import SolverConfig
 from sparknet_tpu.zoo import caffenet
@@ -134,11 +135,11 @@ def _caffenet(batch=BATCH):
                                         n_classes=CLASSES))
 
 
-def _trainer(cls, devices, mesh=None, **kw):
+def _trainer(cls, devices, mesh=None, tau=TAU, **kw):
     mesh = mesh or Mesh(np.array(devices), (DATA_AXIS,))
     return cls(_caffenet(), SolverConfig(
         base_lr=0.01, momentum=0.9, weight_decay=5e-4, lr_policy="step",
-        gamma=0.1, stepsize=100000), mesh, tau=TAU, donate_batches=True,
+        gamma=0.1, stepsize=100000), mesh, tau=tau, donate_batches=True,
         fused_boundary=True, **kw)
 
 
@@ -182,10 +183,11 @@ def _round_avals(trainer, compute_dt):
     batch = NamedSharding(mesh, P(None, DATA_AXIS))
     key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), n))
     return (_state_avals(trainer),
-            {"data": jax.ShapeDtypeStruct((TAU, n * BATCH, CROP, CROP, 3),
-                                          compute_dt, sharding=batch),
-             "label": jax.ShapeDtypeStruct((TAU, n * BATCH, 1), jnp.int32,
-                                           sharding=batch)},
+            {"data": jax.ShapeDtypeStruct(
+                (trainer.tau, n * BATCH, CROP, CROP, 3), compute_dt,
+                sharding=batch),
+             "label": jax.ShapeDtypeStruct((trainer.tau, n * BATCH, 1),
+                                           jnp.int32, sharding=batch)},
             jax.ShapeDtypeStruct(key.shape, key.dtype,
                                  sharding=NamedSharding(mesh, P(DATA_AXIS))),
             jax.ShapeDtypeStruct((), jnp.float32,
@@ -213,6 +215,54 @@ def test_caffenet_round_compiles_for_v5e(v5e, as_tpu, cls, n_chips, policy):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+
+
+@pytest.mark.slow
+def test_caffenet_tau50_round_reads_its_rows_in_place(v5e, as_tpu):
+    """The benchmark's round (`caffenet-tau50`: bf16, batch 256, τ=50,
+    donated, fused boundary, health off) holds no copy of its stack: the
+    peeled last step used to slice the other 49 steps' rows out of it
+    (`slice` of bf16[49,256,227,227,3], 3.96 GB of 4.79 GB of temporaries)."""
+    precision.set_policy("bfloat16")
+    tau = 50
+    trainer = _trainer(ParallelTrainer, v5e[:1], tau=tau,
+                       compute_health=False)
+    compiled = trainer._round.lower(
+        *_round_avals(trainer, jnp.bfloat16)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5e9, f"round temporaries {temp / 1e9:.2f} GB"
+    assert f"bf16[{tau - 1},{BATCH},{CROP},{CROP},3]" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("cls", [ParallelTrainer, ShardedTrainer],
+                         ids=["shard_map", "named"])
+@pytest.mark.parametrize("fused", [True, False], ids=["peeled", "scanned"])
+def test_round_never_slices_its_stack_along_tau(as_tpu, cls, fused):
+    """Tier-1 size of the pin above, from the lowered text of a rolled
+    (TPU-branch) round on the CPU backend: the only reads of the [τ, ...]
+    stack are one step's rows at a time, so no op anywhere produces a
+    [τ-1, ...] array of the batch's trailing shape."""
+    from test_parallel import TINY_MLP
+    from sparknet_tpu import net_from_prototxt
+
+    tau, n, local_b = 4, 2, 8
+    trainer = cls(CompiledNet.compile(net_from_prototxt(TINY_MLP)),
+                  SolverConfig(base_lr=0.01, momentum=0.9,
+                               lr_policy="fixed"),
+                  make_mesh(n), tau=tau, fused_boundary=fused)
+    batches = trainer._shard_batches({
+        "data": np.zeros((tau, n * local_b, 6), np.float32),
+        "label": np.zeros((tau, n * local_b, 1), np.int32)})
+    rngs = place_global_state(jax.random.split(jax.random.PRNGKey(0), n),
+                              trainer.mesh, P(DATA_AXIS))
+    text = trainer._round.lower(trainer.init_state(jax.random.PRNGKey(1)),
+                                batches, rngs, jnp.float32(1.0)).as_text()
+    assert "stablehlo.while" in text  # the scan is rolled, as on the chip
+    # one step's rows are read ([1, b, 6] before the squeeze) ...
+    assert re.search(rf"tensor<1x{local_b}x6xf32>", text)
+    # ... and never all-but-one step's
+    assert not re.search(rf"tensor<{tau - 1}x\d+x(6xf32|1xi32)>", text), (
+        "the round slices its stack along tau")
 
 
 @pytest.mark.slow

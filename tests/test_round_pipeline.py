@@ -77,9 +77,9 @@ def solver_cfg():
                         lr_policy="fixed")
 
 
-def make_round_batches(seed):
+def make_round_batches(seed, tau=TAU):
     r = np.random.default_rng(seed)
-    data = r.standard_normal((TAU, N_DEV * LOCAL_B, 6)).astype(np.float32)
+    data = r.standard_normal((tau, N_DEV * LOCAL_B, 6)).astype(np.float32)
     label = (data.sum(-1, keepdims=True) > 0).astype(np.int32)
     return {"data": data, "label": label}
 
@@ -451,7 +451,11 @@ def test_train_loop_levers_do_not_change_the_trajectory(tmp_path):
 # -- r8: fused τ-boundary + async collect ------------------------------------
 
 
-def test_fused_boundary_bitwise_multi_round(net, solver_cfg, trainer_cls):
+# τ=2 is the scan of ONE step before the peeled one: the smallest round in
+# which reading rows by index and slicing the stack could differ
+@pytest.mark.parametrize("tau", [TAU, 2])
+def test_fused_boundary_bitwise_multi_round(net, solver_cfg, trainer_cls,
+                                            tau):
     """The r8 fused τ-boundary (final scan step peeled so the boundary
     pmean — and the ZeRO re-shard under the named trainer — traces in the
     same region as the last optimizer update) must be a pure
@@ -460,14 +464,14 @@ def test_fused_boundary_bitwise_multi_round(net, solver_cfg, trainer_cls):
     trajectory — losses, params, momentum, AND the health scalars —
     under BOTH trainer impls (the conftest trainer_cls matrix)."""
     mesh = make_mesh(N_DEV)
-    ref = trainer_cls(net, solver_cfg, mesh, tau=TAU)
-    fused = trainer_cls(net, solver_cfg, mesh, tau=TAU,
+    ref = trainer_cls(net, solver_cfg, mesh, tau=tau)
+    fused = trainer_cls(net, solver_cfg, mesh, tau=tau,
                         fused_boundary=True)
     assert ref.fused_boundary is False and fused.fused_boundary is True
     s_ref = ref.init_state(jax.random.PRNGKey(0))
     s_fus = fused.init_state(jax.random.PRNGKey(0))
     for rnd in range(4):
-        batches = make_round_batches(rnd)
+        batches = make_round_batches(rnd, tau)
         key = jax.random.PRNGKey(rnd)
         s_ref, l_ref = ref.train_round(s_ref, batches, key)
         s_fus, l_fus = fused.train_round(s_fus, batches, key)
@@ -482,37 +486,40 @@ def test_fused_boundary_bitwise_multi_round(net, solver_cfg, trainer_cls):
                 (rnd, k)
 
 
+@pytest.mark.parametrize("kw,tau,tbw", [
+    ({}, 1, None),
+    ({"elastic_tau": True}, TAU, [1, TAU, 2, TAU]),
+    ({"elastic_tau": True}, 2, [1, 2, 2, 1]),
+], ids=["tau1", "elastic-tau3", "elastic-tau2"])
 def test_fused_boundary_tau1_and_elastic_masked(net, solver_cfg,
-                                                trainer_cls):
+                                                trainer_cls, kw, tau, tbw):
     """Edge geometry: τ=1 compiles the fused round scan-free, and an
     elastic_tau-masked round (per-worker budgets, the peeled final step
     masked off for short-budget workers) still pins bitwise against the
     unfused trainer fed the same tau vector."""
     mesh = make_mesh(N_DEV)
-    for kw, tau, tbw in (({}, 1, None),
-                         ({"elastic_tau": True}, TAU, [1, TAU, 2, TAU])):
-        ref = trainer_cls(net, solver_cfg, mesh, tau=tau, **kw)
-        fused = trainer_cls(net, solver_cfg, mesh, tau=tau,
-                            fused_boundary=True, **kw)
-        s_ref = ref.init_state(jax.random.PRNGKey(1))
-        s_fus = fused.init_state(jax.random.PRNGKey(1))
-        r = np.random.default_rng(5)
-        batches = {
-            "data": r.standard_normal(
-                (tau, N_DEV * LOCAL_B, 6)).astype(np.float32)}
-        batches["label"] = (batches["data"].sum(-1, keepdims=True)
-                            > 0).astype(np.int32)
-        extra = {"tau_by_worker": tbw} if tbw is not None else {}
-        s_ref, l_ref = ref.train_round(s_ref, batches,
-                                       jax.random.PRNGKey(2), **extra)
-        s_fus, l_fus = fused.train_round(s_fus, batches,
-                                         jax.random.PRNGKey(2), **extra)
-        assert float(l_ref) == float(l_fus), (tau, tbw)
-        for (ka, a), (_, b) in zip(
-                jax.tree_util.tree_leaves_with_path(s_ref),
-                jax.tree_util.tree_leaves_with_path(s_fus)):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), \
-                (tau, tbw, ka)
+    ref = trainer_cls(net, solver_cfg, mesh, tau=tau, **kw)
+    fused = trainer_cls(net, solver_cfg, mesh, tau=tau,
+                        fused_boundary=True, **kw)
+    s_ref = ref.init_state(jax.random.PRNGKey(1))
+    s_fus = fused.init_state(jax.random.PRNGKey(1))
+    r = np.random.default_rng(5)
+    batches = {
+        "data": r.standard_normal(
+            (tau, N_DEV * LOCAL_B, 6)).astype(np.float32)}
+    batches["label"] = (batches["data"].sum(-1, keepdims=True)
+                        > 0).astype(np.int32)
+    extra = {"tau_by_worker": tbw} if tbw is not None else {}
+    s_ref, l_ref = ref.train_round(s_ref, batches,
+                                   jax.random.PRNGKey(2), **extra)
+    s_fus, l_fus = fused.train_round(s_fus, batches,
+                                     jax.random.PRNGKey(2), **extra)
+    assert float(l_ref) == float(l_fus), (tau, tbw)
+    for (ka, a), (_, b) in zip(
+            jax.tree_util.tree_leaves_with_path(s_ref),
+            jax.tree_util.tree_leaves_with_path(s_fus)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), \
+            (tau, tbw, ka)
 
 
 def test_fused_boundary_resize_carries_knob(net, solver_cfg, trainer_cls):
