@@ -23,6 +23,7 @@ the rollback budget is spent (utils/health.py).
 """
 from __future__ import annotations
 
+import json
 import math
 import time
 import warnings
@@ -68,14 +69,30 @@ _RETRY_DATA_OFFSET = 1 << 20
 
 
 def resolve_spec(cfg: RunConfig, **input_shapes) -> NetSpec:
-    """cfg.model -> NetSpec: a zoo builder name, or a .prototxt path
+    """cfg.model -> NetSpec: a zoo builder name, a .prototxt path
     (capability parity: the reference's apps loaded prototxt data files,
-    `apps/CifarApp.scala:83-88`)."""
+    `apps/CifarApp.scala:83-88`), or a .json path: a sequence model's
+    published config.json keys as run here plus the `share` block that says
+    which part of an expert-parallel deployment this worker holds
+    (`zoo.glm4_moe_lite`). Rows a step are cfg.local_batch; positions the
+    `tokens` shape given here, else the file's `seq_len`."""
     from .. import zoo
     from ..model.prototxt import net_from_prototxt_file
     if cfg.model.endswith(".prototxt"):
         return net_from_prototxt_file(
             cfg.model, input_shapes=input_shapes or None)
+    if cfg.model.endswith(".json"):
+        with open(cfg.model) as f:
+            config = json.load(f)
+        kind = config.get("model_type")
+        if kind not in zoo.SEQUENCE_MODELS:
+            raise ValueError(
+                f"{cfg.model}: model_type {kind!r} is not one of "
+                f"{sorted(zoo.SEQUENCE_MODELS)}")
+        positions = (input_shapes["tokens"][1] if "tokens" in input_shapes
+                     else config["seq_len"])
+        return zoo.SEQUENCE_MODELS[kind](config, rows=cfg.local_batch,
+                                         positions=int(positions))
     builders = {
         "cifar10_quick": lambda: zoo.cifar10_quick(batch=cfg.local_batch),
         "caffenet": lambda: zoo.caffenet(batch=cfg.local_batch,
@@ -387,6 +404,9 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
         # sparknet_train_round_{temp,argument,output}_bytes, once a
         # profile_dir run has asked the round program for its report
         obs_device.attach_program_gauges(registry)
+        # sparknet_moe_*{layer}: the expert layers' counters of the last
+        # finished round (a net without such layers adds none)
+        obs_device.attach_round_counter_gauges(registry, trainer)
         if hasattr(trainer, "compiled_variants"):
             g_variants = registry.gauge(
                 "sparknet_train_round_compiled_variants",
@@ -495,7 +515,12 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                                 "phase_means": timers.summary(),
                                 # {} until a profile_dir run has asked
                                 "program_memory":
-                                    obs_device.program_memory()})
+                                    obs_device.program_memory(),
+                                # {} for a net whose layers count nothing
+                                "round_counters": (
+                                    trainer.counter_values()
+                                    if hasattr(trainer, "counter_values")
+                                    else {})})
         except OSError as e:
             # a taken port (co-located processes sharing a fixed
             # status_port) degrades observability, never training —

@@ -63,6 +63,9 @@ class OpsImpl:
     interpret: run the Pallas kernels under the Pallas INTERPRETER — the
           CPU parity-test mode ("auto" then resolves to the kernels on
           CPU too, so tier-1 pins the exact layer-path wiring TPU runs).
+          The sequence layers (model/seq_layers.py) take their exact
+          paths under it instead of jax's attention and grouped-matmul
+          kernels.
     """
 
     lrn: str = "auto"
@@ -350,13 +353,15 @@ def _flat_dim(shape: Tuple[int, ...]) -> int:
 
 
 def infer_innerproduct(layer: LayerSpec, in_shapes):
+    if layer.inner_product.axis == -1:
+        return (tuple(in_shapes[0][:-1]) + (layer.inner_product.num_output,),)
     n = in_shapes[0][0]
     return ((n, layer.inner_product.num_output),)
 
 
 def init_innerproduct(key, layer: LayerSpec, in_shapes) -> Params:
     p = layer.inner_product
-    fan_in = _flat_dim(in_shapes[0])
+    fan_in = in_shapes[0][-1] if p.axis == -1 else _flat_dim(in_shapes[0])
     wkey, bkey = jax.random.split(key)
     # Stored (in, out): feeds the MXU directly as x @ w.
     params = {"w": fill(wkey, p.weight_filler, (fan_in, p.num_output), fan_in)}
@@ -367,12 +372,13 @@ def init_innerproduct(key, layer: LayerSpec, in_shapes) -> Params:
 
 def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
     (x,) = inputs
-    if x.ndim == 4:
-        # Caffe flattens NCHW-ordered; transpose so imported Caffe weights
-        # (and exported ones) line up element-for-element.
-        x = jnp.transpose(x, (0, 3, 1, 2))
-    x, w, mm_precision, mm_out = resolve_weight(
-        params, x.reshape(x.shape[0], -1), ctx)
+    if layer.inner_product.axis != -1:  # else the last axis alone
+        if x.ndim == 4:
+            # Caffe flattens NCHW-ordered; transpose so imported Caffe
+            # weights (and exported ones) line up element-for-element.
+            x = jnp.transpose(x, (0, 3, 1, 2))
+        x = x.reshape(x.shape[0], -1)
+    x, w, mm_precision, mm_out = resolve_weight(params, x, ctx)
     y = jnp.dot(x, w, precision=mm_precision,
                 preferred_element_type=mm_out)
     if "b" in params:
@@ -382,7 +388,7 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
         # [rank*out/m, (rank+1)*out/m); gather the full feature axis so
         # downstream layers see the logical blob. autodiff turns the gather
         # into the matching reduce-scatter of the cotangent.
-        y = jax.lax.all_gather(y, ctx.tp_axis, axis=1, tiled=True)
+        y = jax.lax.all_gather(y, ctx.tp_axis, axis=y.ndim - 1, tiled=True)
     return (y,)
 
 
@@ -413,10 +419,33 @@ def infer_softmaxwithloss(layer: LayerSpec, in_shapes):
 
 def apply_softmaxwithloss(layer: LayerSpec, params, inputs, ctx: ApplyCtx):
     logits, label = inputs
+    if layer.loss is not None:
+        return (_masked_softmax_loss(layer.loss, logits, label),)
     label = _squeeze_label(label)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, label[:, None], axis=-1)[:, 0]
     return (jnp.mean(nll),)
+
+
+def _masked_softmax_loss(p, logits, label):
+    """SoftmaxWithLoss with a LossParam: logits [..., V] against labels of
+    the leading shape ([rows, positions] for a sequence model), the mean
+    over the positions that have a target, times `loss_weight`."""
+    label = label.astype(jnp.int32)
+    if label.ndim == logits.ndim:  # Caffe's [N, 1] labels
+        label = label[..., 0]
+    ignore = p.ignore_label
+    if p.label_shift:
+        ignore = -1 if ignore is None else ignore
+        label = shifted(label, p.label_shift, fill=ignore)
+    has_target = (jnp.ones(label.shape, bool) if ignore is None
+                  else label != ignore)
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(
+        logits, jnp.where(has_target, label, 0)[..., None], axis=-1)[..., 0]
+    nll = jax.nn.logsumexp(logits, axis=-1) - picked
+    count = jnp.maximum(jnp.sum(has_target), 1).astype(jnp.float32)
+    return p.loss_weight * jnp.sum(jnp.where(has_target, nll, 0.0)) / count
 
 
 def infer_accuracy(layer: LayerSpec, in_shapes):
@@ -498,3 +527,7 @@ LAYER_IMPLS = {
     "Concat": (None, apply_concat, infer_concat),
     "Flatten": (None, apply_flatten, infer_flatten),
 }
+
+from .seq_layers import SEQ_LAYER_IMPLS, param_defaults, shifted  # noqa: E402
+
+LAYER_IMPLS.update(SEQ_LAYER_IMPLS)

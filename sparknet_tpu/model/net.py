@@ -13,6 +13,7 @@ Layout: 4D inputs are declared NCHW in prototxt but consumed NHWC on device;
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -27,6 +28,19 @@ PyTree = Dict[str, Params]
 #: CompiledNet.compile memo: identical NetSpecs (frozen, hashable) compile
 #: once per process — the spec-level half of the compile-cache story
 _SPEC_MEMO: Dict[NetSpec, "CompiledNet"] = {}
+
+
+def _recompute_blocks(layers) -> List[list]:
+    """`layers` cut into runs: consecutive layers with one `block` tag
+    together, every untagged layer alone."""
+    runs: List[list] = []
+    for layer in layers:
+        if (runs and layer.block is not None
+                and runs[-1][0].block == layer.block):
+            runs[-1].append(layer)
+        else:
+            runs.append([layer])
+    return runs
 
 
 def _to_nhwc_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -120,7 +134,7 @@ class CompiledNet:
         for layer in self.spec.layers:
             init, _, infer = LAYER_IMPLS[layer.type]
             in_shapes = tuple(shapes[b] for b in layer.bottoms)
-            if init is not None:
+            if init is not None and layer.param_from is None:
                 key, sub = jax.random.split(key)
                 params[layer.name] = init(sub, layer, in_shapes)
             for t, s in zip(layer.tops, infer(layer, in_shapes)):
@@ -129,7 +143,18 @@ class CompiledNet:
 
     def param_layers(self) -> List[str]:
         return [l.name for l in self.spec.layers
-                if LAYER_IMPLS[l.type][0] is not None]
+                if LAYER_IMPLS[l.type][0] is not None
+                and l.param_from is None]
+
+    def counter_blobs(self) -> Dict[str, Tuple[str, ...]]:
+        """{blob: the names of its entries} for every top that is a
+        layer's vector of counters (an expert layer's, an MTP module's):
+        what a trainer sums over a round's steps and returns with the
+        round's scalars. {} for a net of layers that count nothing."""
+        from .seq_layers import COUNTER_TOPS
+        return {l.tops[COUNTER_TOPS[l.type][0]]: COUNTER_TOPS[l.type][1]
+                for l in self.spec.layers_for_phase("TRAIN")
+                if l.type in COUNTER_TOPS}
 
     # -- execution ----------------------------------------------------------
 
@@ -166,19 +191,39 @@ class CompiledNet:
                        quant=quant)
         blobs: Dict[str, jnp.ndarray] = dict(batch)
         all_tops = set()
-        for layer in self.spec.layers_for_phase(phase):
-            _, apply_fn, _ = LAYER_IMPLS[layer.type]
-            inputs = tuple(blobs[b] for b in layer.bottoms)
-            # the scope carries the layer's TYPE and NAME into every op's
-            # metadata (forward, and under transpose(jvp(...)) backward):
-            # obs.device.program_report reads them back from the compiled
-            # text, so a reader of a device trace needs no model table
-            with jax.named_scope(f"{layer.type}/{layer.name}"):
-                outputs = apply_fn(layer, params.get(layer.name), inputs,
-                                   ctx)
-            for t, v in zip(layer.tops, outputs):
-                blobs[t] = v
-                all_tops.add(t)
+
+        def run(layers, params, blobs) -> Dict[str, jnp.ndarray]:
+            """The tops `layers` produce, in order, from `blobs`."""
+            blobs, tops = dict(blobs), {}
+            for layer in layers:
+                _, apply_fn, _ = LAYER_IMPLS[layer.type]
+                inputs = tuple(blobs[b] for b in layer.bottoms)
+                # the scope carries the layer's TYPE and NAME into every
+                # op's metadata (forward, and under transpose(jvp(...))
+                # backward): obs.device.program_report reads them back from
+                # the compiled text, so a reader of a device trace needs no
+                # model table
+                with jax.named_scope(f"{layer.type}/{layer.name}"):
+                    outputs = apply_fn(
+                        layer, params.get(layer.param_from or layer.name),
+                        inputs, ctx)
+                for t, v in zip(layer.tops, outputs):
+                    blobs[t] = tops[t] = v
+            return tops
+
+        for layers in _recompute_blocks(self.spec.layers_for_phase(phase)):
+            if train and layers[0].block is not None:
+                # one recomputation block: its inputs and parameters are
+                # all the backward pass keeps of it
+                needs = {b: blobs[b] for l in layers for b in l.bottoms
+                         if b in blobs}
+                owners = {l.param_from or l.name for l in layers}
+                tops = jax.checkpoint(functools.partial(run, layers))(
+                    {k: v for k, v in params.items() if k in owners}, needs)
+            else:
+                tops = run(layers, params, blobs)
+            blobs.update(tops)
+            all_tops.update(tops)
         for name in batch:
             if name not in all_tops:
                 blobs.pop(name, None)

@@ -1,0 +1,470 @@
+"""The sequence-model layer set: what a sparse-expert decoder is built from.
+
+Embed, RMSNorm, MLAttention (multi-head latent attention), GatedMLP (SwiGLU),
+MoE (routed experts of which this chip holds a share), MTP (a multi-token-
+prediction module) and Eltwise (the residual sum). Same three functions a
+layer type as `layers.py` (`init_`, `apply_`, `infer_`); registered there in
+`LAYER_IMPLS`. Activations are `[rows, positions, d]`; matrices are stored
+(in, out) in float32 and cast by the precision policy at use.
+
+Every layer's parameters carry names of their own (`q_a`, `kv_a_norm`,
+`router_bias`, ...): `param_defaults` gives the per-name lr/decay multipliers
+the solver applies where the spec gives none.
+
+The two places where a plain formulation would not fit a chip at 8k positions
+go through kernels jax ships: the attention core through
+splash attention (its backward keeps nothing of size positions^2), the
+experts' products through megablox's grouped matmul (only the rows routed to
+an expert meet its weights). Off the chip, at test sizes and under
+`ops_interpret`, both take the exact path: `ops.attention.attention` and
+`lax.ragged_dot`.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .. import precision
+from ..ops import attention as attention_ops
+from .spec import LayerSpec, MLAttentionParam, MoEParam, ParamSpec
+
+Params = Dict[str, jnp.ndarray]
+
+#: splash attention's tiles (queries, keys a block, keys a product; positions
+#: must divide by the largest) and the grouped matmul's (rows, contraction,
+#: columns): the fastest of those tried on a v5e at [2, 20, 8192, 256] and
+#: [65536 x 2048] x [8, 2048, 1536] (PERF.md section 6, PR 27)
+ATTN_BLOCKS = (512, 1024, 512)
+GMM_TILING = (512, 512, 512)
+#: the counters an expert layer returns beside its result, in this order
+MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
+                "expert_tokens_min")
+
+
+def param_defaults(pname: str) -> ParamSpec:
+    """lr_mult / decay_mult of a parameter the spec says nothing about, by
+    its name: norms' scales are not decayed; the router's selection bias is
+    a buffer the gradient does not train (and is not decayed)."""
+    if pname == "router_bias":
+        return ParamSpec(lr_mult=0.0, decay_mult=0.0)
+    if pname.endswith("norm") or pname == "scale":
+        return ParamSpec(lr_mult=1.0, decay_mult=0.0)
+    return ParamSpec()
+
+
+def use_kernels(ctx) -> bool:
+    return not ctx.ops.interpret and jax.default_backend() == "tpu"
+
+
+def _normal(key, shape, std):
+    return std * jax.random.normal(key, shape, jnp.float32)
+
+
+def _dot(x, w):
+    """x [..., k] @ w [k, n] under the precision policy."""
+    return jnp.dot(precision.cast_in(x), precision.cast_in(w),
+                   precision=precision.matmul_precision(),
+                   preferred_element_type=precision.preferred_out())
+
+
+def _rms(x, scale, eps):
+    """RMSNorm in float32, returned in the compute dtype."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(precision.compute_dtype())
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    g, u = _dot(x, w_gate), _dot(x, w_up)
+    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    return _dot(h.astype(g.dtype), w_down)
+
+
+# -- Embed -------------------------------------------------------------------
+
+def infer_embed(layer: LayerSpec, in_shapes):
+    return (tuple(in_shapes[0]) + (layer.embed.dim,),)
+
+
+def init_embed(key, layer: LayerSpec, in_shapes) -> Params:
+    p = layer.embed
+    return {"w": _normal(key, (p.num_embeddings, p.dim), p.std)}
+
+
+def shifted(ids, k: int, fill=0):
+    """ids[:, i + k] at position i, `fill` where the row has ended."""
+    if k == 0:
+        return ids
+    return jnp.concatenate(
+        [ids[:, k:], jnp.full((ids.shape[0], k), fill, ids.dtype)], axis=1)
+
+
+def apply_embed(layer: LayerSpec, params: Params, inputs, ctx):
+    (ids,) = inputs
+    ids = shifted(ids.astype(jnp.int32), layer.embed.shift)
+    return (precision.cast_in(jnp.take(params["w"], ids, axis=0)),)
+
+
+# -- RMSNorm -----------------------------------------------------------------
+
+def infer_same(layer: LayerSpec, in_shapes):
+    return (in_shapes[0],)
+
+
+def init_rmsnorm(key, layer: LayerSpec, in_shapes) -> Params:
+    return {"scale": jnp.ones((in_shapes[0][-1],), jnp.float32)}
+
+
+def apply_rmsnorm(layer: LayerSpec, params: Params, inputs, ctx):
+    return (_rms(inputs[0], params["scale"], layer.rmsnorm.eps),)
+
+
+# -- Eltwise -----------------------------------------------------------------
+
+def apply_eltwise(layer: LayerSpec, params, inputs, ctx):
+    """Caffe's Eltwise SUM, with its `coeff`s: the residual add, and the
+    sum of the weighted losses."""
+    p = layer.eltwise
+    if p is not None and p.operation != "SUM":
+        raise ValueError(f"layer {layer.name!r}: Eltwise operation "
+                         f"{p.operation!r} is not built (SUM is)")
+    coeff = p.coeff if p and p.coeff else (1.0,) * len(inputs)
+    assert len(coeff) == len(inputs), (layer.name, coeff)
+    return (sum(x if c == 1.0 else c * x for c, x in zip(coeff, inputs)),)
+
+
+# -- GatedMLP ----------------------------------------------------------------
+
+def init_gatedmlp(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.gated_mlp, in_shapes[0][-1]
+    kg, ku, kd = jax.random.split(key, 3)
+    return {"gate": _normal(kg, (d, p.intermediate_size), p.std),
+            "up": _normal(ku, (d, p.intermediate_size), p.std),
+            "down": _normal(kd, (p.intermediate_size, d), p.std)}
+
+
+def apply_gatedmlp(layer: LayerSpec, params: Params, inputs, ctx):
+    return (_swiglu(inputs[0], params["gate"], params["up"], params["down"]),)
+
+
+# -- MLAttention -------------------------------------------------------------
+
+def init_mla(key, p: MLAttentionParam, d: int) -> Params:
+    ks = jax.random.split(key, 5)
+    qk = p.qk_nope_head_dim + p.qk_rope_head_dim
+    return {
+        "q_a": _normal(ks[0], (d, p.q_lora_rank), p.std),
+        "q_a_norm": jnp.ones((p.q_lora_rank,), jnp.float32),
+        "q_b": _normal(ks[1], (p.q_lora_rank, p.num_heads * qk), p.std),
+        "kv_a": _normal(ks[2], (d, p.kv_lora_rank + p.qk_rope_head_dim), p.std),
+        "kv_a_norm": jnp.ones((p.kv_lora_rank,), jnp.float32),
+        "kv_b": _normal(ks[3], (p.kv_lora_rank, p.num_heads * (
+            p.qk_nope_head_dim + p.v_head_dim)), p.std),
+        "o": _normal(ks[4], (p.num_heads * p.v_head_dim, d), p.std)}
+
+
+def init_mlattention(key, layer: LayerSpec, in_shapes) -> Params:
+    return init_mla(key, layer.mla, in_shapes[0][-1])
+
+
+def rotary(x, theta: float):
+    """Rotary position embedding over the whole last axis of x
+    [rows, positions, ..., d], position = index along axis 1. Pairs are
+    (x[2i], x[2i+1]) (interleaved, as the public DeepSeek-V3-family code
+    reads its weights); the result is laid out half-split, which no dot
+    product of two vectors rotated alike can tell."""
+    d, n = x.shape[-1], x.shape[1]
+    inv = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    ang = jnp.asarray(np.arange(n)[:, None] * inv[None, :], jnp.float32)
+    ang = ang.reshape((1, n) + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., 0::2], x32[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=8)
+def _splash(heads: int, positions: int):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    bq, bkv, bkv_compute = ATTN_BLOCKS
+    sizes = sk.BlockSizes(block_q=bq, block_kv=bkv, block_kv_compute=bkv_compute,
+                          block_q_dkv=bq, block_kv_dkv=bkv,
+                          block_kv_dkv_compute=bkv_compute,
+                          use_fused_bwd_kernel=True)  # dq with dk, dv: one pass
+    mask = sm.MultiHeadMask([sm.CausalMask((positions, positions))] * heads)
+    with jax.ensure_compile_time_eval():  # the mask tables are constants
+        return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1)
+
+
+def attention_core(q, k, v, ctx):
+    """Causal softmax(q k^T / sqrt(d)) v over [rows, positions, heads, d].
+    The kernel where it applies (positions a multiple of its tile, head
+    sizes of whole lanes); else the exact path, which materialises the
+    scores."""
+    n, d = q.shape[1], q.shape[-1]
+    if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0 and d % 128 == 0
+            and v.shape[-1] % 128 == 0 and q.dtype == jnp.bfloat16):
+        heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+        scale = jnp.asarray(1.0 / np.sqrt(d), q.dtype)
+        o = jax.vmap(_splash(q.shape[2], n))(
+            heads_first(q * scale), heads_first(k), heads_first(v))
+        return heads_first(o)
+    return attention_ops.attention(q, k, v, causal=True)
+
+
+def mla(p: MLAttentionParam, params: Params, x, ctx):
+    r, n, _ = x.shape
+    h, nope, rope = p.num_heads, p.qk_nope_head_dim, p.qk_rope_head_dim
+    c_q = _rms(_dot(x, params["q_a"]), params["q_a_norm"], p.eps)
+    q = _dot(c_q, params["q_b"]).reshape(r, n, h, nope + rope)
+    kv_a = _dot(x, params["kv_a"])
+    c_kv = _rms(kv_a[..., :p.kv_lora_rank], params["kv_a_norm"], p.eps)
+    kv = _dot(c_kv, params["kv_b"]).reshape(r, n, h, nope + p.v_head_dim)
+    q_rope = rotary(q[..., nope:], p.rope_theta)
+    k_rope = rotary(kv_a[..., p.kv_lora_rank:], p.rope_theta)  # one, shared
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope[:, :, None, :], (r, n, h, rope))], axis=-1)
+    with jax.named_scope("core"):
+        o = attention_core(q, k, kv[..., nope:], ctx)
+    return _dot(o.reshape(r, n, h * p.v_head_dim), params["o"])
+
+
+def apply_mlattention(layer: LayerSpec, params: Params, inputs, ctx):
+    return (mla(layer.mla, params, inputs[0], ctx),)
+
+
+# -- MoE ---------------------------------------------------------------------
+
+def moe_capacity(p: MoEParam, tokens: int, tile: int = GMM_TILING[0]) -> int:
+    """Rows of the buffer the held experts' slots are gathered into."""
+    k_here = min(p.num_experts_per_tok, p.experts_held[1])
+    rows = tokens * k_here  # every slot that can land here
+    if p.capacity_factor is not None:
+        even = tokens * p.num_experts_per_tok * p.experts_held[1] \
+            / p.n_routed_experts
+        rows = min(rows, int(np.ceil(p.capacity_factor * even)))
+    return -(-rows // tile) * tile
+
+
+def init_moe_params(key, p: MoEParam, d: int) -> Params:
+    ks = jax.random.split(key, 8)
+    held, w = p.experts_held[1], p.intermediate_size
+    out = {"router": _normal(ks[0], (d, p.n_routed_experts), p.std),
+           "router_bias": _normal(ks[1], (p.n_routed_experts,), p.std),
+           "experts_gate": _normal(ks[2], (held, d, w), p.std),
+           "experts_up": _normal(ks[3], (held, d, w), p.std),
+           "experts_down": _normal(ks[4], (held, w, d), p.std)}
+    if p.n_shared_experts:
+        ws = w * p.n_shared_experts
+        out.update(shared_gate=_normal(ks[5], (d, ws), p.std),
+                   shared_up=_normal(ks[6], (d, ws), p.std),
+                   shared_down=_normal(ks[7], (ws, d), p.std))
+    return out
+
+
+def init_moe(key, layer: LayerSpec, in_shapes) -> Params:
+    return init_moe_params(key, layer.moe, in_shapes[0][-1])
+
+
+def route(p: MoEParam, params: Params, xf):
+    """(chosen experts [tokens, k] int32, their weights [tokens, k] f32):
+    sigmoid scores in float32, the top k of score + bias, weights the
+    chosen scores normalised and scaled (`noaux_tc`, one group)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        xf.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(params["router_bias"]),
+                       p.num_experts_per_tok)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if p.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * p.routed_scaling_factor
+
+
+def _grouped_dot(x, w, group_sizes, ctx):
+    """x[rows of group g] @ w[g] for every group; rows past the groups' end
+    give zeros."""
+    w = precision.cast_in(w)
+    if use_kernels(ctx) and x.dtype == jnp.bfloat16:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        return megablox.gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+                            tiling=GMM_TILING)
+    return lax.ragged_dot(x, w, group_sizes,
+                          precision=precision.matmul_precision(),
+                          preferred_element_type=precision.preferred_out())
+
+
+# Dispatch and combine are each other's transpose. Written as gathers both
+# ways: left to autodiff, the backward of a row gather is a scatter-add of
+# thousands of rows, which a TPU runs one row at a time.
+
+@jax.custom_vjp
+def _gather_rows(xf, row_tok, slot_row, slot_ok):
+    """Buffer row r <- token row_tok[r]."""
+    return jnp.take(xf, row_tok, axis=0)
+
+
+def _gather_rows_fwd(xf, row_tok, slot_row, slot_ok):
+    return jnp.take(xf, row_tok, axis=0), (slot_row, slot_ok, xf.shape[0])
+
+
+def _gather_rows_bwd(res, g):
+    slot_row, slot_ok, tokens = res
+    gs = jnp.where(slot_ok[:, None], jnp.take(g, slot_row, axis=0), 0)
+    dxf = jnp.sum(gs.astype(jnp.float32).reshape(tokens, -1, g.shape[-1]),
+                  axis=1)
+    return dxf.astype(g.dtype), None, None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, w, slot_row, slot_ok, row_slot, row_ok):
+    """Token t <- sum over its k slots of w[t, j] * y[row of slot (t, j)],
+    the slots that did not land here left out."""
+    return _combine_fwd(y, w, slot_row, slot_ok, row_slot, row_ok)[0]
+
+
+def _slot_rows(y, slot_row, slot_ok, tokens):
+    ys = jnp.where(slot_ok[:, None], jnp.take(y, slot_row, axis=0), 0)
+    return ys.astype(jnp.float32).reshape(tokens, -1, y.shape[-1])
+
+
+def _combine_fwd(y, w, slot_row, slot_ok, row_slot, row_ok):
+    out = jnp.sum(_slot_rows(y, slot_row, slot_ok, w.shape[0])
+                  * w[:, :, None], axis=1).astype(y.dtype)
+    return out, (y, w, slot_row, slot_ok, row_slot, row_ok)
+
+
+def _combine_bwd(res, g):
+    y, w, slot_row, slot_ok, row_slot, row_ok = res
+    k = w.shape[1]
+    w_row = jnp.where(row_ok, jnp.take(w.reshape(-1), row_slot), 0.0)
+    dy = (jnp.take(g, row_slot // k, axis=0).astype(jnp.float32)
+          * w_row[:, None]).astype(y.dtype)
+    dw = jnp.sum(_slot_rows(y, slot_row, slot_ok, w.shape[0])
+                 * g.astype(jnp.float32)[:, None, :], axis=-1)
+    return dy, dw, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe(p: MoEParam, params: Params, x, ctx):
+    """(result [rows, positions, d], counters [len(MOE_COUNTERS)] f32,
+    the experts every position chose [rows, positions, k] int32)."""
+    r, n, d = x.shape
+    tokens, k = r * n, p.num_experts_per_tok
+    first, held = p.experts_held
+    xf = x.reshape(tokens, d)
+    with jax.named_scope("router"):
+        idx, w = route(p, params, xf)
+    with jax.named_scope("dispatch"):
+        rows = moe_capacity(p, tokens)
+        local = idx.reshape(-1) - first
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> slot
+        slot_row = jnp.argsort(order).astype(jnp.int32)          # slot -> row
+        sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
+                        axis=0, dtype=jnp.int32)
+        landed = jnp.sum(sizes)
+        # what does not fit is dropped from the END of the sorted slots
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        kept_sizes = jnp.diff(ends, prepend=0)
+        kept = ends[-1]
+        slot_ok = (key < held) & (slot_row < kept)
+        slot_row = jnp.minimum(slot_row, rows - 1)
+        row_slot = order[:rows] if rows <= order.shape[0] else jnp.pad(
+            order, (0, rows - order.shape[0]))
+        row_ok = jnp.arange(rows, dtype=jnp.int32) < kept
+        xs = _gather_rows(xf, row_slot // k, slot_row, slot_ok)
+    with jax.named_scope("experts"):
+        g = _grouped_dot(xs, params["experts_gate"], kept_sizes, ctx)
+        u = _grouped_dot(xs, params["experts_up"], kept_sizes, ctx)
+        h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+        y = _grouped_dot(h.astype(g.dtype), params["experts_down"],
+                         kept_sizes, ctx)
+    with jax.named_scope("combine"):
+        out = _combine(y, w, slot_row, slot_ok, row_slot, row_ok)
+    if p.n_shared_experts:
+        with jax.named_scope("shared"):
+            out = out + _swiglu(xf, params["shared_gate"],
+                                params["shared_up"], params["shared_down"])
+    counters = jnp.stack([landed, landed - kept, jnp.max(sizes),
+                          jnp.min(sizes)]).astype(jnp.float32)
+    return (out.reshape(r, n, d), lax.stop_gradient(counters),
+            idx.reshape(r, n, k))
+
+
+def _moe_shapes(p: MoEParam, in_shape):
+    return (in_shape, (len(MOE_COUNTERS),),
+            tuple(in_shape[:-1]) + (p.num_experts_per_tok,))
+
+
+def infer_moe(layer: LayerSpec, in_shapes):
+    return _moe_shapes(layer.moe, in_shapes[0])
+
+
+def apply_moe(layer: LayerSpec, params: Params, inputs, ctx):
+    return moe(layer.moe, params, inputs[0], ctx)
+
+
+# -- MTP ---------------------------------------------------------------------
+
+def init_mtp(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.mtp, in_shapes[0][-1]
+    k_eh, k_attn, k_moe = jax.random.split(key, 3)
+    ones = lambda: jnp.ones((d,), jnp.float32)
+    return {"enorm": ones(), "hnorm": ones(),
+            "eh_proj": _normal(k_eh, (2 * d, d), p.std),
+            "attn_norm": ones(), **init_mla(k_attn, p.attention, d),
+            "mlp_norm": ones(), **init_moe_params(k_moe, p.moe, d),
+            "norm": ones()}
+
+
+def infer_mtp(layer: LayerSpec, in_shapes):
+    return _moe_shapes(layer.mtp.moe, in_shapes[0])
+
+
+def apply_mtp(layer: LayerSpec, params: Params, inputs, ctx):
+    """(h [rows, positions, d]: the last layer's output before the final
+    norm; e: the embedding of the NEXT token at every position) -> the
+    module's normed output, for the shared head, and its expert layer's
+    counters and choices."""
+    p = layer.mtp
+    h, e = inputs
+    x = _dot(jnp.concatenate([_rms(h, params["hnorm"], p.eps),
+                              _rms(e, params["enorm"], p.eps)], axis=-1),
+             params["eh_proj"])
+    with jax.named_scope("attention"):
+        x = x + mla(p.attention, params,
+                    _rms(x, params["attn_norm"], p.eps), ctx)
+    with jax.named_scope("moe"):
+        y, counters, chosen = moe(p.moe, params,
+                                  _rms(x, params["mlp_norm"], p.eps), ctx)
+    return _rms(x + y, params["norm"], p.eps), counters, chosen
+
+
+#: layer type -> (which of its tops is its counters, their names)
+COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
+
+SEQ_LAYER_IMPLS = {
+    "Embed": (init_embed, apply_embed, infer_embed),
+    "RMSNorm": (init_rmsnorm, apply_rmsnorm, infer_same),
+    "Eltwise": (None, apply_eltwise, infer_same),
+    "GatedMLP": (init_gatedmlp, apply_gatedmlp, infer_same),
+    "MLAttention": (init_mlattention, apply_mlattention, infer_same),
+    "MoE": (init_moe, apply_moe, infer_moe),
+    "MTP": (init_mtp, apply_mtp, infer_mtp),
+}

@@ -5,9 +5,13 @@ The reference framework consumed Caffe prototxt (parsed natively via
 GraphDefs. Here the IR is a plain-Python dataclass graph that a compiler
 (`sparknet_tpu.model.net`) lowers to a pure JAX `apply(params, batch)` function.
 
-Layer set = exactly what the reference model zoo uses
-(reference `models/*.prototxt`): Convolution, Pooling, LRN, ReLU, InnerProduct,
-Softmax, SoftmaxWithLoss, Accuracy, Dropout — plus Input declarations.
+Layer set = what the reference model zoo uses (reference
+`models/*.prototxt`): Convolution, Pooling, LRN, ReLU, InnerProduct, Softmax,
+SoftmaxWithLoss, Accuracy, Dropout — plus Input declarations — and the
+sequence-model set a sparse-expert decoder is built from: Embed, RMSNorm,
+MLAttention (latent attention), GatedMLP, MoE (an expert layer that holds a
+share of its experts), MTP (a multi-token-prediction module) and Eltwise (the
+residual sum, as Caffe has it).
 """
 from __future__ import annotations
 
@@ -74,6 +78,10 @@ class LRNParam:
 class InnerProductParam:
     num_output: int = 0
     bias_term: bool = True
+    #: Caffe's `axis`: the first axis lumped into the inner product. 1 (the
+    #: default) flattens everything after the batch; -1 applies the product to
+    #: the last axis alone ([rows, positions, d] -> [rows, positions, out])
+    axis: int = 1
     weight_filler: Filler = field(default_factory=Filler)
     bias_filler: Filler = field(default_factory=Filler)
 
@@ -86,6 +94,101 @@ class DropoutParam:
 @dataclass(frozen=True)
 class AccuracyParam:
     top_k: int = 1
+
+
+@dataclass(frozen=True)
+class LossParam:
+    """Caffe's LossParameter + the layer's loss_weight, for SoftmaxWithLoss.
+    `ignore_label`: positions whose label equals it leave the mean (None:
+    none do). `label_shift` k: the logits at position i are held against the
+    label at position i + k of the same row, and the last k positions have
+    no target (a next-token loss reads the ids it was given as labels)."""
+
+    ignore_label: Optional[int] = None
+    loss_weight: float = 1.0
+    label_shift: int = 0
+
+
+@dataclass(frozen=True)
+class EltwiseParam:
+    operation: str = "SUM"
+    coeff: Tuple[float, ...] = ()  # SUM only; () = all ones
+
+
+@dataclass(frozen=True)
+class EmbedParam:
+    """Rows of a table by integer id (Caffe's Embed, no bias). `shift` k
+    looks up the id at position i + k of the same row (0 past the end)."""
+
+    num_embeddings: int = 0
+    dim: int = 0
+    shift: int = 0
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class RMSNormParam:
+    eps: float = 1e-5
+
+
+@dataclass(frozen=True)
+class MLAttentionParam:
+    """Multi-head latent attention (DeepSeek-V2's MLA): queries and
+    keys/values go through low-rank latents, a rotary part of width
+    `qk_rope_head_dim` rides beside `qk_nope_head_dim` in every head's
+    query and ONE rotary key is shared by all heads. Causal."""
+
+    num_heads: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class GatedMLPParam:
+    """SwiGLU: (silu(x W_g) * x W_u) W_d."""
+
+    intermediate_size: int = 0
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class MoEParam:
+    """A routed-expert layer that holds a SHARE of its experts (expert
+    parallelism's view from one chip): the router scores all
+    `n_routed_experts`, the layer holds the weights of experts
+    `experts_held[0]` .. `experts_held[0] + experts_held[1] - 1` only and
+    sums over the chosen experts it holds; what the absent ones would add is
+    left out. `capacity_factor`: room for the slots that land here, as a
+    multiple of the even share (tokens x top-k x held / routed); None = room
+    for every slot that can land here, so none is ever dropped."""
+
+    n_routed_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)  # (first, count)
+    num_experts_per_tok: int = 1
+    intermediate_size: int = 0
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    capacity_factor: Optional[float] = None
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class MTPParam:
+    """One multi-token-prediction module (DeepSeek-V3's form): the last
+    layer's output and the next token's embedding, each normed, projected
+    from 2 x d to d, then one expert block and a norm of its own."""
+
+    attention: MLAttentionParam = field(default_factory=MLAttentionParam)
+    moe: MoEParam = field(default_factory=MoEParam)
+    eps: float = 1e-5
+    std: float = 0.02
 
 
 @dataclass(frozen=True)
@@ -102,6 +205,22 @@ class LayerSpec:
     inner_product: Optional[InnerProductParam] = None
     dropout: Optional[DropoutParam] = None
     accuracy: Optional[AccuracyParam] = None
+    loss: Optional[LossParam] = None
+    eltwise: Optional[EltwiseParam] = None
+    embed: Optional[EmbedParam] = None
+    rmsnorm: Optional[RMSNormParam] = None
+    mla: Optional[MLAttentionParam] = None
+    gated_mlp: Optional[GatedMLPParam] = None
+    moe: Optional[MoEParam] = None
+    mtp: Optional[MTPParam] = None
+    #: this layer has no parameters of its own and runs on those of the
+    #: layer named here (Caffe shares blobs by `param { name }`): a second
+    #: head on the one output matrix, a second lookup in the one table
+    param_from: Optional[str] = None
+    #: consecutive layers that carry the same tag are one recomputation
+    #: block: in training only the block's inputs are kept for the backward
+    #: pass and its insides are computed again there (`jax.checkpoint`)
+    block: Optional[str] = None
 
 
 @dataclass(frozen=True)
@@ -144,13 +263,20 @@ class NetSpec:
 
 
 # Layer types that carry trainable parameters.
-PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct")
+PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct", "Embed", "RMSNorm",
+                          "MLAttention", "GatedMLP", "MoE", "MTP")
 
 
 def validate(spec: NetSpec) -> None:
     """Structural validation: every bottom must be produced before use."""
     available = set(spec.input_names())
+    seen = set()
     for l in spec.layers:
+        if l.param_from is not None and l.param_from not in seen:
+            raise ValueError(
+                f"layer {l.name!r}: param_from {l.param_from!r} names no "
+                f"earlier layer")
+        seen.add(l.name)
         for b in l.bottoms:
             if b not in available:
                 raise ValueError(

@@ -314,33 +314,43 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
         computation that made one of its operands, else of the nearest that
         uses it, else `outside_step` with no layer.
     """
-    comps: Dict[str, List[Dict[str, Any]]] = {}
+    # an instruction may run over several lines (a Pallas kernel's metadata
+    # holds line breaks, and a line of it starts with "}"): a computation
+    # ends at a line that is "}" alone, and a line inside one that starts no
+    # instruction continues the instruction before it
+    raw: Dict[str, List[List[Any]]] = {}  # computation -> [root, name, text]
     current = None
     for line in text.splitlines():
         if current is None:
             m = _COMPUTATION.match(line)
             if m and "=" not in line.split("(", 1)[0]:
-                current = comps.setdefault(m.group(1).lstrip("%"), [])
+                current = raw.setdefault(m.group(1).lstrip("%"), [])
             continue
-        if line.startswith("}"):
+        if line.rstrip() == "}":
             current = None
             continue
         m = _INSTRUCTION.match(line)
-        if not m:
-            continue
-        rest = m.group(3)
-        body = rest.split(", metadata=", 1)[0]
-        op = _OPCODE.search(" " + body)
-        name = _OP_NAME.search(rest)
-        paren = body.find("(", op.start()) if op else -1
-        current.append({
-            "name": "%" + m.group(2).lstrip("%"), "root": bool(m.group(1)),
-            "opcode": op.group(1) if op else "",
-            "op_name": name.group(1) if name else None,
-            "called": {k: [c.strip().lstrip("%") for c in v.split(",")]
-                       for k, v in _CALLED.findall(body)},
-            "operands": re.findall(r"%[\w.\-]+", body[paren:].split(")")[0])
-            if paren >= 0 else []})
+        if m:
+            current.append([bool(m.group(1)), m.group(2), m.group(3)])
+        elif current:
+            current[-1][2] += " " + line.strip()
+    comps: Dict[str, List[Dict[str, Any]]] = {}
+    for cname, lines in raw.items():
+        current = comps.setdefault(cname, [])
+        for root, iname, rest in lines:
+            body = rest.split(", metadata=", 1)[0]
+            op = _OPCODE.search(" " + body)
+            name = _OP_NAME.search(rest)
+            paren = body.find("(", op.start()) if op else -1
+            current.append({
+                "name": "%" + iname.lstrip("%"), "root": root,
+                "opcode": op.group(1) if op else "",
+                "op_name": name.group(1) if name else None,
+                "called": {k: [c.strip().lstrip("%") for c in v.split(",")]
+                           for k, v in _CALLED.findall(body)},
+                "operands": re.findall(r"%[\w.\-]+",
+                                       body[paren:].split(")")[0])
+                if paren >= 0 else []})
     inlined = {c for ins in comps.values() for i in ins
                for k, cs in i["called"].items() if k in _INLINED
                and i["opcode"] not in CONTAINERS for c in cs}
@@ -424,6 +434,24 @@ def attach_program_gauges(registry: MetricsRegistry,
             f"the compiled {name} program's {key} bytes per device (XLA "
             f"memory analysis, read by program_report)"
         ).set_fn(lambda key=key: _program_memory[name][key])
+
+
+def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
+    """Show the counters the trainer's net returns with its round's scalars
+    (an expert layer's `slots_landed`, `slots_dropped`, `expert_tokens_max`,
+    `expert_tokens_min`: sums over a round's steps and workers) as gauges
+    `sparknet_moe_<counter>{layer=...}`: live-read from
+    `trainer.counter_values()`, which waits for nothing. No gauge for a
+    net whose layers count nothing."""
+    for blob, names in getattr(trainer, "counter_blobs", {}).items():
+        layer = blob[:-len("_counters")] if blob.endswith("_counters") else blob
+        for name in names:
+            registry.gauge(
+                f"sparknet_moe_{name}",
+                f"an expert layer's {name}, summed over the last finished "
+                f"round's steps and workers", labels=("layer",)
+            ).set_fn(lambda blob=blob, name=name:
+                     trainer.counter_values()[blob][name], layer=layer)
 
 
 def program_memory() -> Dict[str, Dict[str, int]]:

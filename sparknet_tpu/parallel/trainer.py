@@ -216,6 +216,18 @@ class ParallelTrainer:
         #: reads them at its log_every flush — no extra per-round host
         #: sync.
         self.last_health: Optional[Dict[str, jax.Array]] = None
+        #: the counters the net's layers return beside their results (an
+        #: expert layer's routed slots landed / dropped, its fullest and
+        #: emptiest expert: `CompiledNet.counter_blobs`), summed on the
+        #: device over the round's steps and over the workers and returned
+        #: with the round's other scalars: {blob: [n] f32} DEVICE arrays of
+        #: the newest round (`last_counters`) and of the one before it
+        #: (`counter_values()` reads that one: done, or all but, whenever it
+        #: is asked). None for a net without such layers, whose compiled
+        #: round is the one it always was.
+        self.counter_blobs = net.counter_blobs()
+        self.last_counters: Optional[Dict[str, jax.Array]] = None
+        self._settled_counters: Optional[Dict[str, jax.Array]] = None
         self._lr_scale_dev: Optional[Tuple[float, jax.Array]] = None
         #: optional PhaseTimers (utils/metrics.py): when the train loop
         #: installs one, train_round's phases — "round_keys", "h2d" (the
@@ -243,9 +255,14 @@ class ParallelTrainer:
     state_layout = "replica"
 
     def _health_specs(self):
-        return ({"grad_norm": P(), "nonfinite": P(),
-                 "nonfinite_by_worker": P()}
-                if self.compute_health else {})
+        """out_specs of the round's scalars besides the loss: the health
+        scalars and, for a net that has them, the layers' counters."""
+        specs = ({"grad_norm": P(), "nonfinite": P(),
+                  "nonfinite_by_worker": P()}
+                 if self.compute_health else {})
+        if self.counter_blobs:
+            specs["counters"] = P()
+        return specs
 
     def _compile(self) -> None:
         """Build the jitted round + eval executables. The state lives on
@@ -552,9 +569,10 @@ class ParallelTrainer:
                 batch, step_rng = inputs
             else:
                 batch, step_rng, step_idx = inputs
-            (loss, _), grads = jax.value_and_grad(
+            (loss, blobs), grads = jax.value_and_grad(
                 lambda p: loss_fn(p, batch, step_rng),
                 has_aux=True)(params)
+            counters = {b: blobs[b] for b in self.counter_blobs}
             grads = fix_tp_grads(grads)
             # health signal: this step's LOCAL squared gradient norm (a
             # per-leaf reduction fused into the compiled step, no host
@@ -590,7 +608,7 @@ class ParallelTrainer:
                     it=new_sstate.it)
                 loss = jnp.where(active, loss, 0.0)
                 grad_sq = jnp.where(active, grad_sq, 0.0)
-            return (new_params, new_sstate), (loss, grad_sq)
+            return (new_params, new_sstate), (loss, grad_sq, counters)
 
         def rows_at(i):
             # the ONLY place the round reads its rows: step i's
@@ -633,22 +651,25 @@ class ParallelTrainer:
             last_rows = rows_at(self.tau - 1)
             step_idxs, last_rows = lax.optimization_barrier(
                 (step_idxs, last_rows))
-        outs = []  # (losses, grad_sqs) of each stretch of steps
+        outs = []  # (losses, grad_sqs, counters) of each stretch of steps
         if n_scanned:  # τ=1 fused: the whole round is scan-free
             carry, scanned = lax.scan(
                 scanned_step, carry, (step_idxs, step_rngs[:n_scanned]),
                 unroll=scan_unroll(n_scanned))
             outs.append(scanned)
         if self.fused_boundary:
-            carry, (loss_t, gs_t) = step_at(
+            carry, last = step_at(
                 carry, self.tau - 1, step_rngs[-1], last_rows)
-            outs.append((loss_t[None], gs_t[None]))
+            outs.append(jax.tree.map(lambda x: x[None], last))
         params, sstate = carry
-        losses, grad_sqs = map(jnp.concatenate, zip(*outs))
-        return self._tau_boundary(params, sstate, losses, grad_sqs, my_tau)
+        losses, grad_sqs, counters = jax.tree.map(
+            lambda *xs: jnp.concatenate(xs), *outs)
+        return self._tau_boundary(params, sstate, losses, grad_sqs, my_tau,
+                                  counters)
 
     @jax.named_scope("tau_boundary")
-    def _tau_boundary(self, params, sstate, losses, grad_sqs, my_tau):
+    def _tau_boundary(self, params, sstate, losses, grad_sqs, my_tau,
+                      counters=None):
         """What the round does once the τ steps are done: the weight
         average over the data axis, the round's mean loss and the health
         reductions — one scope (`tau_boundary`) in the compiled program."""
@@ -727,6 +748,14 @@ class ParallelTrainer:
                 by_worker = lax.pmean(by_worker, self._tp_axis)
             health = {"grad_norm": grad_norm, "nonfinite": nonfinite,
                       "nonfinite_by_worker": by_worker}
+        if counters:
+            # the layers' counters: each step's [n] vector summed over the
+            # round's steps and over the workers
+            total = lax.psum(jax.tree.map(lambda c: jnp.sum(c, axis=0),
+                                          counters), DATA_AXIS)
+            if self._tp_axis is not None:
+                total = lax.pmean(total, self._tp_axis)
+            health = dict(health, counters=total)
         if self._tp_axis is not None:
             # numerically a no-op (TP replicas compute identical losses);
             # clears the model-axis vma so the P() out_spec typechecks
@@ -825,8 +854,25 @@ class ParallelTrainer:
                     args)
             with self._phase("dispatch", step):
                 new_state, loss, health = self._round(*args)
+        if self.counter_blobs:
+            health = dict(health)
+            self._settled_counters = self.last_counters
+            self.last_counters = health.pop("counters")
         self.last_health = health or None  # {} when compute_health=False
         return new_state, loss
+
+    def counter_values(self) -> Dict[str, Dict[str, float]]:
+        """{layer blob: {counter: value}} of the last round but one (the
+        newest whose numbers are on the device for certain, so reading them
+        waits for nothing: a scrape must be cheap), else of the only round
+        there has been; {} for a net without counters or before a round.
+        Sums over the round's steps and workers."""
+        dev = self._settled_counters or self.last_counters
+        if not dev:
+            return {}
+        return {blob: dict(zip(self.counter_blobs[blob],
+                               map(float, np.asarray(vec))))
+                for blob, vec in dev.items()}
 
     def _phase(self, name: str, step: int):
         """One phase of `train_round`: the installed PhaseTimers' phase

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from .model.net import CompiledNet, PyTree
-from .model.spec import ParamSpec
 
 
 @dataclass(frozen=True)
@@ -228,20 +227,30 @@ class SgdSolver:
 
 
 def _param_multipliers(net: CompiledNet):
-    """Per-blob lr_mult/decay_mult from LayerSpec.params.
+    """Per-blob lr_mult/decay_mult, by layer and by the layer's own
+    parameter names.
 
     Caffe convention (reference prototxts, e.g.
     `models/cifar10/cifar10_quick_train_test.prototxt` `param { lr_mult: 1 }
-    param { lr_mult: 2 }`): first ParamSpec is the weight, second the bias.
-    Missing specs default to 1.0.
+    param { lr_mult: 2 }`): a layer's first ParamSpec is for its weight
+    "w", the second for its bias "b". Any other parameter, and one the spec
+    says nothing about, takes its name's default (`layers.param_defaults`:
+    1.0 / 1.0, except that a norm's scale is not decayed and a router's
+    selection bias is neither trained nor decayed).
     """
+    from .model.layers import param_defaults
+    names = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
+    positional = {"w": 0, "b": 1}
     lr: Dict[str, Dict[str, float]] = {}
     decay: Dict[str, Dict[str, float]] = {}
     for layer in net.spec.layers:
-        from .model.layers import LAYER_IMPLS
-        if LAYER_IMPLS[layer.type][0] is None:
+        if layer.name not in names:
             continue
-        specs = list(layer.params) + [ParamSpec()] * (2 - len(layer.params))
-        lr[layer.name] = {"w": specs[0].lr_mult, "b": specs[1].lr_mult}
-        decay[layer.name] = {"w": specs[0].decay_mult, "b": specs[1].decay_mult}
+        lr[layer.name], decay[layer.name] = {}, {}
+        for pn in names[layer.name]:
+            i = positional.get(pn, len(layer.params))
+            spec = (layer.params[i] if i < len(layer.params)
+                    else param_defaults(pn))
+            lr[layer.name][pn] = spec.lr_mult
+            decay[layer.name][pn] = spec.decay_mult
     return lr, decay

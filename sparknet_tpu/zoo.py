@@ -7,6 +7,11 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
+and one family of sequence models, built from a file of its published config:
+  - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
+                      (latent attention, routed experts of which this chip
+                      holds a share, one multi-token-prediction module)
+
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
 """
@@ -15,8 +20,10 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 from .model.spec import (AccuracyParam, ConvolutionParam, DropoutParam,
-                         Filler, InnerProductParam, InputSpec, LayerSpec,
-                         LRNParam, NetSpec, ParamSpec, PoolingParam)
+                         EltwiseParam, EmbedParam, Filler, GatedMLPParam,
+                         InnerProductParam, InputSpec, LayerSpec, LossParam,
+                         LRNParam, MLAttentionParam, MoEParam, MTPParam,
+                         NetSpec, ParamSpec, PoolingParam, RMSNormParam)
 
 _GAUSS = lambda std: Filler(type="gaussian", std=std)
 _CONST = lambda v=0.0: Filler(type="constant", value=v)
@@ -171,3 +178,122 @@ def adult_mlp(batch: int = 64, n_features: int = 1) -> NetSpec:
                       tops=("prob",)),
         ),
     )
+
+
+def glm4_moe_lite(config: dict, rows: int, positions: int) -> NetSpec:
+    """A `glm4_moe_lite` decoder (GLM-4.7-Flash) as ONE CHIP'S SHARE of an
+    expert-parallel deployment, for training on `[rows, positions]` int32
+    token ids (input `tokens`; the targets are the ids themselves, read one
+    and two positions on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- `num_hidden_layers` layers of which the first
+    `first_k_dense_replace` are dense, `n_routed_experts` experts HELD in
+    each expert layer, `vocab_size` rows of the vocabulary HELD -- and a
+    `share` block that says what they are a share of: `n_routed_experts` (the
+    published count: the router's width), `experts_held` [first, count],
+    `vocab_rows` [first, count] and `chips_sharing_a_layer`. Optional there:
+    `capacity_factor` (MoEParam) and `mtp_loss_weight` (default 0.3).
+
+    Pre-norm residual blocks: x += MLA(RMSNorm(x)); x += MLP(RMSNorm(x)),
+    the MLP dense in the leading layers and routed experts + one shared
+    expert after. An untied head over the held vocabulary rows. The
+    multi-token-prediction module (DeepSeek-V3's form) reads the last
+    layer's output before the final norm and the next token's embedding,
+    and shares the embedding and the head. Loss = CE(next token) +
+    mtp_loss_weight x CE(second-next token), each a mean over the positions
+    that have a target. Every block is a recomputation block."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["rms_norm_eps"], 0.02
+    first, held = share["experts_held"]
+    vocab = share["vocab_rows"][1]
+    assert held == c["n_routed_experts"] and vocab == c["vocab_size"], (
+        "the share block and the held counts disagree")
+    assert c.get("n_group", 1) == 1 and c.get("topk_group", 1) == 1, (
+        "group-limited routing is not built")
+    attention = MLAttentionParam(
+        num_heads=c["num_attention_heads"], q_lora_rank=c["q_lora_rank"],
+        kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_theta=float(c["rope_theta"]), eps=eps, std=std)
+    experts = MoEParam(
+        n_routed_experts=share["n_routed_experts"],
+        experts_held=(first, held),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=c["norm_topk_prob"],
+        capacity_factor=share.get("capacity_factor"), std=std)
+    norm = lambda name, bottom, block: LayerSpec(
+        name=name, type="RMSNorm", bottoms=(bottom,), tops=(name,),
+        rmsnorm=RMSNormParam(eps=eps), block=block)
+    add = lambda name, a, b, top, block: LayerSpec(
+        name=name, type="Eltwise", bottoms=(a, b), tops=(top,), block=block)
+    head = lambda name, bottom, block, param_from=None: LayerSpec(
+        name=name, type="InnerProduct", bottoms=(bottom,), tops=(name,),
+        inner_product=InnerProductParam(num_output=vocab, bias_term=False,
+                                        axis=-1, weight_filler=_GAUSS(std)),
+        param_from=param_from, block=block)
+    loss = lambda name, logits, shift, weight, block: LayerSpec(
+        name=name, type="SoftmaxWithLoss", bottoms=(logits, "tokens"),
+        tops=(name,), block=block,
+        loss=LossParam(label_shift=shift, loss_weight=weight))
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",),
+                        embed=EmbedParam(num_embeddings=vocab, dim=d, std=std))]
+    for i in range(c["num_hidden_layers"]):
+        l, x = f"l{i}", f"x{i}"
+        layers += [
+            norm(f"{l}_attn_norm", x, l),
+            LayerSpec(name=f"{l}_attn", type="MLAttention",
+                      bottoms=(f"{l}_attn_norm",), tops=(f"{l}_attn",),
+                      mla=attention, block=l),
+            add(f"{l}_attn_res", x, f"{l}_attn", f"{l}_h", l),
+            norm(f"{l}_mlp_norm", f"{l}_h", l)]
+        if i < c["first_k_dense_replace"]:
+            mlp = f"{l}_mlp"
+            layers.append(LayerSpec(
+                name=mlp, type="GatedMLP", bottoms=(f"{l}_mlp_norm",),
+                tops=(mlp,), block=l,
+                gated_mlp=GatedMLPParam(
+                    intermediate_size=c["intermediate_size"], std=std)))
+        else:
+            mlp = f"{l}_moe"
+            layers.append(LayerSpec(
+                name=mlp, type="MoE", bottoms=(f"{l}_mlp_norm",),
+                tops=(mlp, f"{mlp}_counters", f"{mlp}_chosen"), moe=experts,
+                block=l))
+        layers.append(add(f"{l}_mlp_res", f"{l}_h", mlp, f"x{i + 1}", l))
+    last = f"x{c['num_hidden_layers']}"
+    layers += [norm("final_norm", last, "head"),
+               head("lm_head", "final_norm", "head"),
+               loss("loss_next", "lm_head", 1, 1.0, "head")]
+    losses = ["loss_next"]
+    if c.get("num_nextn_predict_layers", 0):
+        assert c["num_nextn_predict_layers"] == 1, "one MTP module is built"
+        layers += [
+            LayerSpec(name="mtp_embed", type="Embed", bottoms=("tokens",),
+                      tops=("mtp_embed",), param_from="embed", block="mtp",
+                      embed=EmbedParam(num_embeddings=vocab, dim=d, shift=1)),
+            LayerSpec(name="mtp", type="MTP", bottoms=(last, "mtp_embed"),
+                      tops=("mtp", "mtp_counters", "mtp_chosen"), block="mtp",
+                      mtp=MTPParam(attention=attention, moe=experts, eps=eps,
+                                   std=std)),
+            head("mtp_head", "mtp", "mtp_head", param_from="lm_head"),
+            loss("loss_mtp", "mtp_head", 2,
+                 float(share.get("mtp_loss_weight", 0.3)), "mtp_head")]
+        losses.append("loss_mtp")
+    layers.append(LayerSpec(name="loss", type="Eltwise",
+                            bottoms=tuple(losses), tops=("loss",),
+                            eltwise=EltwiseParam(operation="SUM")))
+    return NetSpec(name="glm4_moe_lite",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
+#: `model_type` of a published config.json -> its builder (config, rows,
+#: positions) -> NetSpec
+SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite}

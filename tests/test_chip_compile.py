@@ -310,3 +310,87 @@ def test_caffenet_serve_forward_compiles_for_v5e(v5e, as_tpu, bucket):
                                            sharding=one)}
     text = net._fwd_test.lower(params, batch, None).compile().as_text()
     assert text.count("tpu_custom_call") == 2  # the rows kernel, twice
+
+
+# -- the sequence layers' kernels, and the sequence model's round ------------
+
+def _seq_ctx():
+    from sparknet_tpu.model.layers import ApplyCtx
+    return ApplyCtx(train=True)
+
+
+def test_attention_core_backward_keeps_nothing_of_size_positions_squared(v5e, as_tpu):
+    """The latent-attention core at the benchmark's shape (2 rows, 20 heads,
+    8,192 positions, 256 + 256 a head), forward and backward, for a v5e: jax's
+    splash-attention kernels, and no [.., 8192, 8192] tensor anywhere (one
+    layer's float32 scores would be 10.7 GB)."""
+    from sparknet_tpu.model import seq_layers as sl
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((2, 8192, 20, 256), jnp.bfloat16, sharding=one)
+    text = _compiled_text(jax.grad(
+        lambda q, k, v: sl.attention_core(q, k, v, _seq_ctx()).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
+    assert text.count("tpu_custom_call") >= 2 and "splash_mha" in text
+    assert "8192,8192" not in text
+
+
+def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu):
+    """The experts' grouped matmul at the benchmark's shape (a 16,384-row
+    buffer, 8 held experts of 2048 x 1536), forward and both gradients:
+    megablox's kernels, so only the rows routed to an expert meet it."""
+    from sparknet_tpu.model import seq_layers as sl
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((8, 2048, 1536), jnp.float32, sharding=one)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+    precision.set_policy("bfloat16")
+    try:
+        text = _compiled_text(jax.grad(
+            lambda x, w, s: sl._grouped_dot(x, w, s, _seq_ctx()).astype(
+                jnp.float32).sum(), argnums=(0, 1)), x, w, sizes)
+    finally:
+        precision.set_policy("float32")
+    # the gradient of a sum needs the two backward products alone
+    assert text.count("tpu_custom_call") >= 2
+    assert "gmm" in text and "tgmm" in text
+
+
+@pytest.mark.slow
+def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`: the
+    published widths, 2 x 8,192 tokens a step, tau=4, bf16, donated, fused
+    boundary, health off) for one described chip: ~2 min. 5.65 GB of state
+    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them)."""
+    import json
+    from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
+    from sparknet_tpu.utils.config import RunConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, "benchmark", "configs", "glm47-flash-ep8-tau4.json")
+    with open(path) as f:
+        c = json.load(f)
+    cfg = RunConfig.from_dict({
+        "model": path, "tau": c["tau"], "local_batch": c["local_batch"],
+        "precision": c["precision"], "solver": c["solver"], "n_devices": 1,
+        **c["run_config"]})
+    mesh = Mesh(np.array(v5e[:1]), (DATA_AXIS,))
+    trainer = build_trainer(cfg, resolve_spec(cfg), mesh)
+    try:
+        batch = NamedSharding(mesh, P(None, DATA_AXIS))
+        key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 1))
+        compiled = trainer._round.lower(
+            _state_avals(trainer),
+            {"tokens": jax.ShapeDtypeStruct(
+                (c["tau"], c["local_batch"], c["seq_len"]), jnp.int32,
+                sharding=batch)},
+            jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                 sharding=NamedSharding(mesh, P(DATA_AXIS))),
+            jax.ShapeDtypeStruct((), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))).compile()
+    finally:
+        precision.set_policy("float32")
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 13e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text

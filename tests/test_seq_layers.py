@@ -1,0 +1,490 @@
+"""The sequence-model layer set (model/seq_layers.py), the builder
+`zoo.glm4_moe_lite` and what they needed of the net, the solver and the
+trainer -- against the benchmark's plain reference
+(`benchmark/configs/glm47-flash-ep8-tau4.reference.py`, which imports nothing
+of the program) at small widths on the CPU: layer by layer, the two-headed
+loss and its gradients, one tau-round through `ParallelTrainer.train_round`,
+and the share arithmetic (the parts all the shares give add up to the uncut
+layer).
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparknet_tpu import precision, zoo
+from sparknet_tpu.model import seq_layers as sl
+from sparknet_tpu.model.layers import LAYER_IMPLS, ApplyCtx
+from sparknet_tpu.model.net import CompiledNet
+from sparknet_tpu.model.spec import (EltwiseParam, EmbedParam,
+                                     InputSpec, LayerSpec, LossParam,
+                                     MLAttentionParam, MoEParam,
+                                     NetSpec, ParamSpec, RMSNormParam)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spec = importlib.util.spec_from_file_location(
+    "glm_reference", os.path.join(ROOT, "benchmark", "configs",
+                                  "glm47-flash-ep8-tau4.reference.py"))
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+#: hidden 64, 2 heads of 16+8 / 16, ranks 24 / 16, 8 experts top-2 of which
+#: 2 are held (experts 2 and 3), vocabulary 256, 32 positions
+TINY = {
+    "model_type": "glm4_moe_lite", "hidden_size": 64, "intermediate_size": 160,
+    "moe_intermediate_size": 48, "num_attention_heads": 2, "q_lora_rank": 24,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "n_routed_experts": 2, "n_shared_experts": 1,
+    "num_experts_per_tok": 2, "routed_scaling_factor": 1.8,
+    "norm_topk_prob": True, "first_k_dense_replace": 1, "num_hidden_layers": 3,
+    "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-5, "rope_theta": 1000000,
+    "vocab_size": 256, "seq_len": 32, "n_group": 1, "topk_group": 1,
+    "share": {"chips_sharing_a_layer": 4, "n_routed_experts": 8,
+              "experts_held": [2, 2], "vocab_rows": [0, 256],
+              "mtp_loss_weight": 0.3}}
+ROWS, POS, D = 2, 32, 64
+LAYERS = ref.layer_table(TINY)
+TABLE = {name: (kind, a) for name, kind, a in LAYERS}
+ATTN, MOE = TABLE["l0_attn"][1], TABLE["l1_moe"][1]
+MLA_P = MLAttentionParam(num_heads=2, q_lora_rank=24, kv_lora_rank=16,
+                         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                         rope_theta=1e6, eps=1e-5)
+MOE_P = MoEParam(n_routed_experts=8, experts_held=(2, 2), num_experts_per_tok=2,
+                 intermediate_size=48, n_shared_experts=1,
+                 routed_scaling_factor=1.8, norm_topk_prob=True)
+#: bf16 against the float32 reference: relative to the result's own scale
+BF16_TOL = 0.03
+CTX = ApplyCtx(train=True)
+
+
+def _params(seed, layer="l1_moe", bias_scale=1.0):
+    p = ref.init_params(seed, LAYERS)[layer]
+    if "router_bias" in p:  # a bias large enough to change who is chosen
+        p = dict(p, router_bias=p["router_bias"] * bias_scale)
+    return p
+
+
+def _x(seed, shape=(ROWS, POS, D)):
+    return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+
+
+def _close(got, want, policy):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = float(np.max(np.abs(want))) + 1e-30
+    tol = 2e-5 if policy == "float32" else BF16_TOL
+    assert float(np.max(np.abs(got - want))) / scale < tol
+
+
+def _per_row(fn, x):
+    with jax.default_matmul_precision("highest"):
+        return jnp.stack([fn(x[r]) for r in range(x.shape[0])])
+
+
+# -- layer by layer against the reference ------------------------------------
+
+def _layer_case(kind, seed):
+    """(program's result, reference's result) of one layer on one input."""
+    x = _x(seed)
+    if kind == "rmsnorm":
+        scale = 1.0 + 0.1 * _x(seed + 1, (D,))
+        return (sl._rms(x, scale, 1e-5), ref.rmsnorm(x, scale, 1e-5))
+    if kind == "mla":
+        p = _params(seed, "l0_attn")
+        return (sl.mla(MLA_P, p, x, CTX),
+                _per_row(lambda r: ref.mla(ATTN, p, r, "float32"), x))
+    if kind == "mlp":
+        p = _params(seed, "l0_mlp")
+        return (sl._swiglu(x, p["gate"], p["up"], p["down"]),
+                _per_row(lambda r: ref.swiglu(r, p["gate"], p["up"], p["down"],
+                                              "float32"), x))
+    if kind == "moe":
+        p = _params(seed, "l1_moe", bias_scale=20.0)
+        return (sl.moe(MOE_P, p, x, CTX)[0],
+                _per_row(lambda r: ref.moe(MOE, p, r, "float32")[0], x))
+    raise AssertionError(kind)
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["rmsnorm", "mla", "mlp", "moe"])
+def test_layer_matches_the_reference(kind, policy):
+    for seed in (1, 2):  # two weight draws
+        with precision.policy(policy):
+            got, want = _layer_case(kind, seed)
+        _close(got, want, policy)
+
+
+def test_embed_shift_and_eltwise():
+    table = _x(3, (256, D))
+    ids = jax.random.randint(jax.random.PRNGKey(4), (ROWS, POS), 0, 256, jnp.int32)
+    embed = lambda shift: LAYER_IMPLS["Embed"][1](
+        LayerSpec(name="e", type="Embed",
+                  embed=EmbedParam(num_embeddings=256, dim=D, shift=shift)),
+        {"w": table}, (ids,), CTX)[0]
+    assert np.array_equal(embed(0), np.asarray(table)[np.asarray(ids)])
+    nxt = np.asarray(embed(1))
+    assert np.array_equal(nxt[:, :-1], np.asarray(table)[np.asarray(ids)[:, 1:]])
+    assert np.array_equal(nxt[:, -1], np.broadcast_to(table[0], (ROWS, D)))
+    a, b = _x(5), _x(6)
+    elt = lambda p, *xs: LAYER_IMPLS["Eltwise"][1](
+        LayerSpec(name="s", type="Eltwise", eltwise=p), None, xs, CTX)[0]
+    assert np.array_equal(elt(None, a, b), a + b)
+    assert np.allclose(elt(EltwiseParam(coeff=(1.0, 0.3)), a, b), a + 0.3 * b)
+    with pytest.raises(ValueError, match="is not built"):
+        elt(EltwiseParam(operation="MAX"), a, b)
+
+
+@pytest.mark.parametrize("shift,weight", [(1, 1.0), (2, 0.3)])
+def test_masked_softmax_loss_by_hand(shift, weight):
+    """[rows, positions, V] logits against the ids `shift` positions on: the
+    mean over the positions that have a target, times the loss weight."""
+    logits = _x(7, (ROWS, POS, 50))
+    ids = jax.random.randint(jax.random.PRNGKey(8), (ROWS, POS), 0, 50, jnp.int32)
+    layer = LayerSpec(name="l", type="SoftmaxWithLoss",
+                      loss=LossParam(label_shift=shift, loss_weight=weight))
+    got = LAYER_IMPLS["SoftmaxWithLoss"][1](layer, None, (logits, ids), CTX)[0]
+    logp = np.asarray(jax.nn.log_softmax(logits, axis=-1))
+    want = -np.mean([logp[r, i, int(ids[r, i + shift])]
+                     for r in range(ROWS) for i in range(POS - shift)])
+    assert float(got) == pytest.approx(weight * want, rel=1e-5)
+    # an ignore label with no shift: those positions leave the mean
+    masked = np.asarray(ids).copy()
+    masked[:, ::3] = -1
+    layer = LayerSpec(name="l", type="SoftmaxWithLoss", loss=LossParam(ignore_label=-1))
+    got = LAYER_IMPLS["SoftmaxWithLoss"][1](layer, None, (logits, jnp.asarray(masked)), CTX)[0]
+    keep = masked >= 0
+    want = -np.mean(np.take_along_axis(logp, np.maximum(masked, 0)[..., None], -1)[..., 0][keep])
+    assert float(got) == pytest.approx(want, rel=1e-5)
+
+
+# -- the causal mask and the rotary embedding against a direct formula -------
+
+def test_rotary_against_the_direct_formula():
+    x = np.asarray(_x(9, (1, 5, 3, 8)))
+    got = np.asarray(sl.rotary(jnp.asarray(x), 1e6))
+    for pos in range(5):
+        for i in range(4):  # pair (x[2i], x[2i+1]) turned by pos * theta^(-2i/d)
+            ang = pos * 1e6 ** (-2 * i / 8)
+            a, b = x[0, pos, :, 2 * i], x[0, pos, :, 2 * i + 1]
+            assert np.allclose(got[0, pos, :, i], a * np.cos(ang) - b * np.sin(ang), atol=1e-5)
+            assert np.allclose(got[0, pos, :, 4 + i], b * np.cos(ang) + a * np.sin(ang), atol=1e-5)
+    # what attention sees depends on the distance alone
+    q, k = _x(10, (1, 9, 8)), _x(11, (1, 9, 8))
+    same = lambda s: float(jnp.dot(sl.rotary(jnp.roll(q, s, 1), 1e4)[0, 4 + s],
+                                   sl.rotary(jnp.roll(k, s, 1), 1e4)[0, 2 + s]))
+    assert same(0) == pytest.approx(same(3), rel=1e-4)
+    assert np.allclose(ref.rotary(q[0], 1e4), sl.rotary(q, 1e4)[0], atol=1e-6)
+
+
+def test_attention_core_is_causal_and_exact():
+    q, k, v = _x(12, (1, 6, 2, 8)), _x(13, (1, 6, 2, 8)), _x(14, (1, 6, 2, 4))
+    got = np.asarray(sl.attention_core(q, k, v, CTX))
+    for h in range(2):
+        for i in range(6):
+            s = np.asarray(q)[0, i, h] @ np.asarray(k)[0, :i + 1, h].T / np.sqrt(8)
+            w = np.exp(s - s.max())
+            want = (w / w.sum()) @ np.asarray(v)[0, :i + 1, h]
+            assert np.allclose(got[0, i, h], want, atol=1e-5)
+    # a later key changes no earlier position
+    k2 = k.at[0, 5].add(3.0)
+    again = np.asarray(sl.attention_core(q, k2, v, CTX))
+    assert np.array_equal(again[0, :5], got[0, :5]) and not np.allclose(again[0, 5], got[0, 5])
+    # the reference's blocked core is the same function
+    with jax.default_matmul_precision("highest"):
+        for block, groups in ((2, 1), (2, 3), (1, 2), (6, 4)):
+            blocked = ref.causal_attention(q[0], k[0], v[0], "float32",
+                                           block=block, groups=groups)
+            assert np.allclose(blocked, got[0], atol=1e-5), (block, groups)
+
+
+# -- the expert layer: shares, drops, counters -------------------------------
+
+def _uncut(seed, bias_scale=20.0):
+    """An expert layer with all 8 experts' weights, and the reference's
+    result for the whole (uncut) layer."""
+    table = ref.layer_table(dict(TINY, n_routed_experts=8, share=dict(
+        TINY["share"], experts_held=[0, 8])))
+    a = {n: x for n, k, x in table}["l1_moe"]
+    p = ref.init_params(seed, table)["l1_moe"]
+    return a, dict(p, router_bias=p["router_bias"] * bias_scale)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_shares_add_up_to_the_uncut_layer(seed):
+    """The parts of the result that the four shares give, the shared expert
+    counted once, equal the uncut reference."""
+    a, p = _uncut(seed)
+    x = _x(seed + 40)
+    whole = _per_row(lambda r: ref.moe(a, p, r, "float32")[0], x)
+    shared = _per_row(lambda r: ref.swiglu(r, p["shared_gate"], p["shared_up"],
+                                           p["shared_down"], "float32"), x)
+    total, landed = shared, 0.0
+    for first in range(0, 8, 2):
+        mine = dict(p, **{k: p[k][first:first + 2] for k in
+                          ("experts_gate", "experts_up", "experts_down")})
+        part, counters, _ = sl.moe(MOE_P.__class__(**{
+            **MOE_P.__dict__, "experts_held": (first, 2)}), mine, x, CTX)
+        total = total + (part - shared)
+        landed += float(counters[0])
+        assert float(counters[1]) == 0
+    assert landed == ROWS * POS * 2, "every routed slot lands on exactly one share"
+    assert float(jnp.max(jnp.abs(total - whole))) < 2e-5 * float(jnp.max(jnp.abs(whole)))
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_no_slot_is_dropped_over_weight_draws(seed):
+    p = _params(seed, bias_scale=20.0)
+    out, counters, chosen = sl.moe(MOE_P, p, _x(seed + 50), CTX)
+    landed, dropped, fullest, emptiest = map(float, counters)
+    assert dropped == 0 and 0 <= landed <= ROWS * POS * 2
+    assert landed == float(np.sum((np.asarray(chosen) >= 2) & (np.asarray(chosen) < 4)))
+    assert emptiest <= landed / 2 <= fullest and fullest + emptiest == landed
+    assert chosen.shape == (ROWS, POS, 2) and bool(jnp.all(jnp.isfinite(out)))
+
+
+def test_every_token_to_one_held_expert_drops_nothing_and_tight_room_counts():
+    """A bias that sends every token to held expert 3 (and, top 2, to absent
+    expert 6): that expert takes every token, nothing is dropped, and the
+    result is the reference's. With room for half the even share the rest is
+    counted as dropped, not lost silently."""
+    p = _params(5)
+    bias = jnp.zeros((8,)).at[3].set(50.0).at[6].set(40.0)
+    p = dict(p, router_bias=bias)
+    x = _x(60)
+    out, counters, chosen = sl.moe(MOE_P, p, x, CTX)
+    assert np.array_equal(np.sort(np.asarray(chosen), -1),
+                          np.broadcast_to([3, 6], (ROWS, POS, 2)))
+    assert list(map(float, counters)) == [ROWS * POS, 0.0, ROWS * POS, 0.0]
+    want = _per_row(lambda r: ref.moe(MOE, p, r, "float32")[0], x)
+    assert float(jnp.max(jnp.abs(out - want))) < 2e-5 * float(jnp.max(jnp.abs(want)))
+    tight = MoEParam(**{**MOE_P.__dict__, "capacity_factor": 0.5})
+    assert sl.moe_capacity(tight, ROWS * POS, tile=8) == 16
+    assert sl.moe_capacity(MOE_P, ROWS * POS, tile=8) == ROWS * POS * 2
+    room = sl.moe_capacity(tight, ROWS * POS)  # a tile of the grouped product
+    _, counters, _ = sl.moe(tight, p, x, CTX)
+    assert float(counters[1]) == max(0, ROWS * POS - room)
+
+
+def test_moe_gradients_match_autodiff_of_the_reference():
+    """Dispatch and combine carry hand-written transposes (gathers both
+    ways): the gradients are the reference's."""
+    p, x = _params(6, bias_scale=20.0), _x(61)
+    mine = jax.grad(lambda p, x: jnp.sum(sl.moe(MOE_P, p, x, CTX)[0] ** 2),
+                    argnums=(0, 1))(p, x)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda p, x: sum(
+            jnp.sum(ref.moe(MOE, p, x[r], "float32")[0] ** 2)
+            for r in range(ROWS)), argnums=(0, 1))(p, x)
+    for name in want[0]:
+        if name != "router_bias":
+            _close(mine[0][name], want[0][name], "float32")
+    _close(mine[1], want[1], "float32")
+    assert float(jnp.max(jnp.abs(mine[0]["router_bias"]))) == 0
+
+
+# -- the whole model ---------------------------------------------------------
+
+def _net():
+    return CompiledNet.compile(zoo.glm4_moe_lite(TINY, rows=ROWS, positions=POS))
+
+
+def _ids(seed, shape=(ROWS, POS)):
+    return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 256, jnp.int32)
+
+
+def _reference_loss_and_grads(params, ids):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(lambda p: sum(
+            ref.row_loss(p, ids[r], layers=LAYERS)[0] for r in range(ROWS)) / ROWS)(params)
+
+
+@pytest.mark.parametrize("policy,seed", [("float32", 1), ("float32", 2),
+                                         ("float32", 3), ("bfloat16", 1)])
+def test_two_headed_loss_and_gradients_match_the_reference(policy, seed):
+    net, params, ids = _net(), ref.init_params(seed, LAYERS), _ids(seed + 70)
+    with precision.policy(policy):
+        (loss, blobs), grads = jax.jit(jax.value_and_grad(
+            lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None),
+            has_aux=True))(params)
+    want, want_grads = _reference_loss_and_grads(params, ids)
+    assert float(loss) == pytest.approx(float(want), abs=2e-5 if policy == "float32" else 2e-3)
+    assert float(blobs["loss_next"] + blobs["loss_mtp"]) == pytest.approx(float(loss), rel=1e-6)
+    assert set(grads) == set(want_grads)
+    for layer, lp in want_grads.items():
+        for name, g in lp.items():
+            err = float(jnp.linalg.norm(grads[layer][name] - g)) / (
+                float(jnp.linalg.norm(g)) + 1e-30)
+            if name == "router_bias":
+                assert float(jnp.max(jnp.abs(grads[layer][name]))) == 0
+            else:
+                # bf16: an expert here sees some tens of tokens, and one
+                # slot that flips its expert on a rounding moves its gradient
+                assert err < (2e-5 if policy == "float32" else 0.3), (layer, name, err)
+
+
+def test_recomputation_blocks_change_no_number_and_sharing_sums_gradients():
+    spec = zoo.glm4_moe_lite(TINY, rows=ROWS, positions=POS)
+    assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "head", "mtp",
+                                              "mtp_head"}
+    flat = CompiledNet.compile(spec.replace(layers=tuple(
+        LayerSpec(**{**l.__dict__, "block": None}) for l in spec.layers)))
+    params, ids = ref.init_params(7, LAYERS), _ids(77)
+    f = lambda net: jax.jit(jax.value_and_grad(
+        lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None)[0]))(params)
+    (l1, g1), (l2, g2) = f(_net()), f(flat)
+    assert float(l1) == pytest.approx(float(l2), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        assert np.allclose(a, b, rtol=1e-4, atol=1e-7)
+    # the MTP module's lookup and head run on the embedding's and the head's
+    # own matrices: neither appears twice, and both gradients hold both uses
+    assert "mtp_embed" not in params and "mtp_head" not in params
+    assert _net().param_layers() == [n for n, _, _ in LAYERS]
+    no_mtp = CompiledNet.compile(zoo.glm4_moe_lite(
+        dict(TINY, num_nextn_predict_layers=0), rows=ROWS, positions=POS))
+    g0 = jax.grad(lambda p: no_mtp.loss_fn("loss")(p, {"tokens": ids}, None)[0])(
+        {k: v for k, v in params.items() if k != "mtp"})
+    assert not np.allclose(g0["lm_head"]["w"], g1["lm_head"]["w"], rtol=1e-3)
+    with pytest.raises(ValueError, match="param_from"):
+        CompiledNet.compile(spec.replace(layers=tuple(
+            LayerSpec(**{**l.__dict__, "param_from": "nowhere"})
+            if l.name == "mtp_head" else l for l in spec.layers)))
+
+
+def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path):
+    from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
+    from sparknet_tpu.parallel import make_mesh
+    from sparknet_tpu.utils.config import RunConfig
+
+    path = tmp_path / "tiny.json"
+    path.write_text(json.dumps(TINY))
+    solver = {"base_lr": 0.02, "lr_policy": "fixed", "momentum": 0.9,
+              "weight_decay": 1e-4}
+    cfg = RunConfig.from_dict({
+        "model": str(path), "tau": 3, "local_batch": ROWS, "precision": "float32",
+        "solver": solver, "n_devices": 1, "health": {"enabled": False}})
+    spec = resolve_spec(cfg)
+    assert spec.inputs == (InputSpec("tokens", (ROWS, POS), "int32"),)
+    assert resolve_spec(cfg, tokens=(ROWS, 16)).inputs[0].shape == (ROWS, 16)
+    trainer = build_trainer(cfg, spec, make_mesh(1))
+    params, ids = ref.init_params(8, LAYERS), np.asarray(_ids(78, (3, ROWS, POS)))
+    # ids stay int32 on their way to the device
+    placed = trainer.place_batches({"tokens": ids})
+    assert placed["tokens"].dtype == jnp.int32
+    assert np.array_equal(np.asarray(placed["tokens"]), ids)
+    state, loss = trainer.train_round(trainer.state_from_params(params), placed,
+                                      jax.random.PRNGKey(0))
+    want = ref.round_reference(params, lambda t, w: ids[t], tau=3, solver=solver,
+                               layers=LAYERS, mtp_weight=0.3)
+    assert float(loss) == pytest.approx(want["loss"], abs=2e-5)
+    for layer, lp in params.items():
+        for name, p0 in lp.items():
+            key = f"{layer}/{name}"
+            upd = float(jnp.linalg.norm(state.params[layer][name][0] - p0))
+            mom = float(jnp.linalg.norm(state.momentum[layer][name][0]))
+            assert upd == pytest.approx(want["update_norms"][key], rel=2e-4, abs=1e-9), key
+            assert mom == pytest.approx(want["momentum_norms"][0][key], rel=2e-4, abs=1e-9), key
+    assert float(jnp.linalg.norm(state.params["l1_moe"]["router_bias"][0]
+                                 - params["l1_moe"]["router_bias"])) == 0
+    # the round's counters: sums over its three steps, on the device until read
+    assert trainer.last_health is None
+    values = trainer.counter_values()
+    assert set(values) == {"l1_moe_counters", "l2_moe_counters", "mtp_counters"}
+    for v in values.values():
+        assert list(v) == list(sl.MOE_COUNTERS) and v["slots_dropped"] == 0
+        assert 0 < v["slots_landed"] <= 3 * ROWS * POS * 2
+        assert v["expert_tokens_max"] + v["expert_tokens_min"] == v["slots_landed"]
+    # ... and scrapeable: sparknet_moe_<counter>{layer=...}
+    from sparknet_tpu.obs import MetricsRegistry
+    from sparknet_tpu.obs import device as obs_device
+    registry = MetricsRegistry()
+    obs_device.attach_round_counter_gauges(registry, trainer)
+    text = registry.render_prometheus()
+    assert 'sparknet_moe_slots_dropped{layer="l1_moe"} 0' in text
+    assert registry.gauge("sparknet_moe_slots_landed", labels=("layer",)).value(
+        layer="mtp") == values["mtp_counters"]["slots_landed"]
+    with pytest.raises(ValueError, match="model_type"):
+        path.write_text(json.dumps(dict(TINY, model_type="other")))
+        resolve_spec(cfg)
+
+
+def test_a_net_without_counters_has_none_and_its_round_is_what_it_was():
+    from sparknet_tpu.parallel import ParallelTrainer, make_mesh
+    from sparknet_tpu.solver import SolverConfig
+
+    net = CompiledNet.compile(zoo.lenet(batch=4))
+    assert net.counter_blobs() == {}
+    trainer = ParallelTrainer(net, SolverConfig(), make_mesh(1), tau=2,
+                              compute_health=False)
+    assert trainer._health_specs() == {} and trainer.counter_values() == {}
+    assert _net().counter_blobs() == {
+        b: sl.MOE_COUNTERS for b in ("l1_moe_counters", "l2_moe_counters", "mtp_counters")}
+
+
+# -- the solver's multipliers ------------------------------------------------
+
+def test_param_multipliers_by_the_layers_own_parameter_names():
+    from sparknet_tpu.solver import _param_multipliers
+    lr, decay = _param_multipliers(_net())
+    assert lr["l1_moe"]["router_bias"] == 0 and decay["l1_moe"]["router_bias"] == 0
+    assert lr["mtp"]["router_bias"] == 0 and lr["mtp"]["router"] == 1
+    for layer, names in (("l0_attn", ("q_a_norm", "kv_a_norm")),
+                         ("final_norm", ("scale",)), ("l1_attn_norm", ("scale",)),
+                         ("mtp", ("enorm", "hnorm", "attn_norm", "mlp_norm", "norm",
+                                  "q_a_norm", "kv_a_norm"))):
+        for name in names:
+            assert (lr[layer][name], decay[layer][name]) == (1.0, 0.0), (layer, name)
+    for layer, name in (("l0_attn", "q_b"), ("l0_mlp", "down"), ("embed", "w"),
+                        ("lm_head", "w"), ("l2_moe", "experts_up"), ("mtp", "eh_proj")):
+        assert (lr[layer][name], decay[layer][name]) == (1.0, 1.0)
+    assert set(lr["l1_moe"]) == set(ref.param_shapes(LAYERS)["l1_moe"])
+    assert {n: ref.multipliers(n) for n in lr["mtp"]} == {
+        n: (lr["mtp"][n], decay["mtp"][n]) for n in lr["mtp"]}
+    # a spec's own ParamSpecs still go to "w" and "b", in that order
+    spec = NetSpec(name="n", inputs=(InputSpec("x", (2, 4)),), layers=(
+        LayerSpec(name="e", type="RMSNorm", bottoms=("x",), tops=("e",),
+                  rmsnorm=RMSNormParam()),))
+    assert _param_multipliers(CompiledNet.compile(spec))[1] == {"e": {"scale": 0.0}}
+
+
+def test_caffenets_multipliers_are_unchanged():
+    from sparknet_tpu.solver import _param_multipliers
+    lr, decay = _param_multipliers(CompiledNet.compile(zoo.caffenet(batch=2)))
+    layers = ["conv1", "conv2", "conv3", "conv4", "conv5", "fc6", "fc7", "fc8"]
+    assert lr == {l: {"w": 1.0, "b": 2.0} for l in layers}
+    assert decay == {l: {"w": 1.0, "b": 0.0} for l in layers}
+    lr, decay = _param_multipliers(CompiledNet.compile(zoo.lenet(batch=2)))
+    assert all(v == {"w": 1.0, "b": 2.0} for v in lr.values())
+    assert all(v == {"w": 1.0, "b": 1.0} for v in decay.values())
+    bare = zoo.lenet(batch=2)
+    bare = bare.replace(layers=tuple(LayerSpec(**{**l.__dict__, "params": (
+        ParamSpec(lr_mult=3.0),)}) if l.name == "fc2" else l for l in bare.layers))
+    lr, _ = _param_multipliers(CompiledNet.compile(bare))
+    assert lr["fc2"] == {"w": 3.0, "b": 1.0}
+
+
+# -- the compiled text's multi-line instructions -----------------------------
+
+def test_parse_hlo_ops_reads_an_instruction_that_runs_over_lines():
+    """A Pallas kernel's metadata holds line breaks, one line of it starting
+    with a brace: the computation goes on after it."""
+    from sparknet_tpu.obs.device import parse_hlo_ops
+    text = '''HloModule jit_train_round
+
+ENTRY %main.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %splash.1 = f32[4]{0} custom-call(%p), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{}"
+}}, metadata={op_name="jit(train_round)/tau_step/jvp(MLAttention/l0_attn)/core/pallas_call"}
+  ROOT %add.2 = f32[4]{0} add(%splash.1, %p), metadata={op_name="jit(train_round)/tau_step/transpose(jvp(MoE/l1_moe))/experts/add"}
+}
+'''
+    ops = parse_hlo_ops(text)
+    assert ops["%splash.1"]["layer_type"] == "MLAttention"
+    assert ops["%splash.1"]["scope"].endswith("l0_attn)/core")
+    assert ops["%splash.1"]["phase"] == "forward"
+    assert ops["%add.2"]["phase"] == "backward" and ops["%add.2"]["layer"] == "l1_moe"
