@@ -43,6 +43,15 @@ def _recompute_blocks(layers) -> List[list]:
     return runs
 
 
+def _kept_names(layers) -> Tuple[str, ...]:
+    """The names the types of these layers (one recomputation block's) put
+    on values the block is to keep for the backward pass
+    (`seq_layers.KEPT_NAMES`)."""
+    from .seq_layers import KEPT_NAMES
+    return tuple(dict.fromkeys(
+        n for l in layers for n in KEPT_NAMES.get(l.type, ())))
+
+
 def _to_nhwc_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
     if len(shape) == 4:
         n, c, h, w = shape
@@ -156,6 +165,16 @@ class CompiledNet:
                 for l in self.spec.layers_for_phase("TRAIN")
                 if l.type in COUNTER_TOPS}
 
+    def kept_kernels(self) -> Dict[str, str]:
+        """{name: the kernel that makes its values} for every name a
+        recomputation block of this net keeps for the backward pass
+        (`seq_layers.KEPT_NAMES` of the block's layer types). {} for a net
+        without blocks, or whose blocks' layers name nothing."""
+        from .seq_layers import KEPT_KERNELS
+        return {n: KEPT_KERNELS[n] for n in _kept_names(
+            l for l in self.spec.layers_for_phase("TRAIN")
+            if l.block is not None)}
+
     # -- execution ----------------------------------------------------------
 
     def apply(self, params: PyTree, batch: Dict[str, jnp.ndarray], *,
@@ -213,12 +232,19 @@ class CompiledNet:
 
         for layers in _recompute_blocks(self.spec.layers_for_phase(phase)):
             if train and layers[0].block is not None:
-                # one recomputation block: its inputs and parameters are
-                # all the backward pass keeps of it
+                # one recomputation block: the backward pass keeps its
+                # inputs, its parameters and the values its layers name
+                # (an attention core's output and statistics), and computes
+                # the rest of it again; a block that names nothing gets the
+                # bare `jax.checkpoint`
                 needs = {b: blobs[b] for l in layers for b in l.bottoms
                          if b in blobs}
                 owners = {l.param_from or l.name for l in layers}
-                tops = jax.checkpoint(functools.partial(run, layers))(
+                names = _kept_names(layers)
+                policy = (jax.checkpoint_policies.save_only_these_names(*names)
+                          if names else None)
+                tops = jax.checkpoint(functools.partial(run, layers),
+                                      policy=policy)(
                     {k: v for k, v in params.items() if k in owners}, needs)
             else:
                 tops = run(layers, params, blobs)

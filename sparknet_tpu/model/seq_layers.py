@@ -18,6 +18,14 @@ experts' products through megablox's grouped matmul (only the rows routed to
 an expert meet its weights). Off the chip, at test sizes and under
 `ops_interpret`, both take the exact path: `ops.attention.attention` and
 `lax.ragged_dot`.
+
+A layer may name a value that is dear to compute again and cheap to keep
+(`KEPT_NAMES`, by layer type): the recomputation block such a layer stands in
+(`LayerSpec.block`, `CompiledNet.apply`) keeps the named values for the
+backward pass beside the block's inputs and computes the rest again. The
+attention core names its output and, on the kernel path, its softmax
+statistics (the row-wise log-sum-exp): all the kernel's backward needs
+besides q, k and v, so its forward kernel runs once a step.
 """
 from __future__ import annotations
 
@@ -28,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
@@ -44,6 +53,9 @@ GMM_TILING = (512, 512, 512)
 #: the counters an expert layer returns beside its result, in this order
 MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
                 "expert_tokens_min")
+#: the name (`jax.ad_checkpoint.checkpoint_name`) on the attention core's
+#: output and softmax statistics
+ATTN_CORE = "attn_core"
 
 
 def param_defaults(pname: str) -> ParamSpec:
@@ -200,8 +212,10 @@ def _splash(heads: int, positions: int):
                           use_fused_bwd_kernel=True)  # dq with dk, dv: one pass
     mask = sm.MultiHeadMask([sm.CausalMask((positions, positions))] * heads)
     with jax.ensure_compile_time_eval():  # the mask tables are constants
+        # the kernel names its output and log-sum-exp itself
         return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
-                                  q_seq_shards=1)
+                                  q_seq_shards=1,
+                                  residual_checkpoint_name=ATTN_CORE)
 
 
 def attention_core(q, k, v, ctx):
@@ -217,7 +231,8 @@ def attention_core(q, k, v, ctx):
         o = jax.vmap(_splash(q.shape[2], n))(
             heads_first(q * scale), heads_first(k), heads_first(v))
         return heads_first(o)
-    return attention_ops.attention(q, k, v, causal=True)
+    return checkpoint_name(attention_ops.attention(q, k, v, causal=True),
+                           ATTN_CORE)
 
 
 def mla(p: MLAttentionParam, params: Params, x, ctx):
@@ -458,6 +473,13 @@ def apply_mtp(layer: LayerSpec, params: Params, inputs, ctx):
 
 #: layer type -> (which of its tops is its counters, their names)
 COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
+#: layer type -> the names its implementation puts on values a recomputation
+#: block keeps for the backward pass (`mla` serves both)
+KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,)}
+#: kept name -> the name of the Pallas kernel that computes its values, as a
+#: compiled program's text has it: run again in the backward pass only if
+#: the name did not reach a recomputation block's policy
+KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
 
 SEQ_LAYER_IMPLS = {
     "Embed": (init_embed, apply_embed, infer_embed),

@@ -218,8 +218,11 @@ class LayerSpec:
     #: head on the one output matrix, a second lookup in the one table
     param_from: Optional[str] = None
     #: consecutive layers that carry the same tag are one recomputation
-    #: block: in training only the block's inputs are kept for the backward
-    #: pass and its insides are computed again there (`jax.checkpoint`)
+    #: block: in training the backward pass keeps the block's inputs and the
+    #: values its layers' implementations name as dear to compute again
+    #: (`seq_layers.KEPT_NAMES`: an attention core's output and softmax
+    #: statistics), and computes the rest of its insides again
+    #: (`jax.checkpoint`, with a policy only where something is named)
     block: Optional[str] = None
 
 

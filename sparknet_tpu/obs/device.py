@@ -251,20 +251,28 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     """The compiled program's account of itself, by program name
     (`"train_round"`): `{"memory": {"argument", "output", "alias", "temp"}
     (bytes per device, XLA's memory analysis), "ops": {"%fusion.769":
-    {"scope", "phase", "layer_type", "layer", "opcode"}, ...}}` — see
-    `report_of_compiled` for the attribution rule. None when no such
-    program is registered or it has not been dispatched yet.
+    {"scope", "phase", "layer_type", "layer", "opcode", "computation"},
+    ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
+    "backward", "kept_bytes"}, ...}}` — see `parse_hlo_ops` for the
+    attribution rule and `recompute_report` for what the recomputation
+    blocks keep ({} for a net whose blocks name nothing, or without
+    blocks). None when no such program is registered or it has not been
+    dispatched yet.
 
     NEVER on the round path: the first call lowers and compiles the program
     again (a persistent-compile-cache hit where the cache is on, a second
     compile where it is not) and parses its text; nothing calls it in an
     untraced run. The memory numbers then show as the gauges
-    `sparknet_<name>_{temp,argument,output}_bytes` on the registries
-    `attach_program_gauges` was given, and in `program_memory()`."""
+    `sparknet_<name>_{temp,argument,output}_bytes`, and the kept values'
+    kernels that still run in the backward pass as
+    `sparknet_<name>_recompute_core_forward_in_backward`, on the registries
+    `attach_program_gauges` was given; both in `program_memory()` and
+    `program_recompute()`."""
     provider = _programs.get(name)
     report = provider() if provider is not None else None
     if report is not None:
         _program_memory[name] = report["memory"]
+        _program_recompute[name] = report["recompute"]
     return report
 
 
@@ -387,7 +395,7 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                        or _inherit(i, by_name, own,
                                    lambda x: users.get(x["name"], [])))
             ops[i["name"]] = {**scope_of(op_name or ""),
-                              "opcode": i["opcode"],
+                              "opcode": i["opcode"], "computation": cname,
                               **extras.get(i["name"], {})}
     return ops
 
@@ -410,30 +418,94 @@ def _inherit(instruction, by_name, own, neighbours) -> Optional[str]:
     return None
 
 
-def report_of_compiled(compiled) -> Dict[str, Any]:
+def _named_bytes(jaxpr, name: str) -> int:
+    """Bytes of the values `checkpoint_name` marks `name` in one pass over
+    `jaxpr`: what it names itself and in what it calls, or in one turn of
+    the loop body that names most, whichever is more (a round's scanned
+    steps and its peeled one each name a step's values)."""
+    from jax.core import jaxprs_in_params
+    here = in_a_loop = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name" and eqn.params["name"] == name:
+            here += sum(v.aval.size * v.aval.dtype.itemsize
+                        for v in eqn.outvars)
+        inner = max((_named_bytes(sub, name)
+                     for sub in jaxprs_in_params(eqn.params)), default=0)
+        if eqn.primitive.name in ("scan", "while"):
+            in_a_loop = max(in_a_loop, inner)
+        else:
+            here += inner
+    return max(here, in_a_loop)
+
+
+def recompute_report(ops: Dict[str, Dict[str, Any]],
+                     kept_kernels: Dict[str, str],
+                     jaxpr=None) -> Dict[str, Dict[str, Any]]:
+    """What the program's recomputation blocks keep, by kept name
+    (`kept_kernels`: name -> the Pallas kernel that makes its values,
+    `CompiledNet.kept_kernels()`): `{"kernel", "step_bodies": the
+    computations that hold an instance of it (a loop's body, the peeled
+    step), "forward" / "backward": its instances on a forward path and on a
+    backward one (`phase` of `scope_of`: a forward run again for the
+    backward carries `transpose(`) in the step body that has most,
+    "kept_bytes": of the named values in one step (`_named_bytes` of the
+    program's `jaxpr`; None without one)}`. The mechanism is engaged where
+    "backward" is 0. Off the chip no kernel runs and both counts are 0."""
+    out = {}
+    for name, kernel in kept_kernels.items():
+        count: Dict[str, Dict[str, int]] = {}   # computation -> phase -> n
+        for op in ops.values():
+            if op["opcode"] == "custom-call" and any(
+                    part.startswith(kernel) for part in op["scope"].split("/")):
+                body = count.setdefault(op["computation"], {})
+                body[op["phase"]] = body.get(op["phase"], 0) + 1
+        out[name] = {
+            "kernel": kernel, "step_bodies": len(count),
+            **{phase: max((b.get(phase, 0) for b in count.values()),
+                          default=0) for phase in ("forward", "backward")},
+            "kept_bytes": None if jaxpr is None
+            else _named_bytes(jaxpr, name)}
+    return out
+
+
+def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
+                       jaxpr=None) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
-    returns): its memory analysis and `parse_hlo_ops` of its text."""
+    returns): its memory analysis, `parse_hlo_ops` of its text and, for the
+    names its net's recomputation blocks keep, `recompute_report`."""
     mem = compiled.memory_analysis()
+    ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
                        for k in ("argument", "output", "alias", "temp")},
-            "ops": parse_hlo_ops(compiled.as_text())}
+            "ops": ops,
+            "recompute": recompute_report(ops, kept_kernels or {}, jaxpr)}
 
 
-#: program -> its report's memory part, once `program_report` has run
+#: program -> its report's memory and recompute parts, once `program_report`
+#: has run
 _program_memory: Dict[str, Dict[str, int]] = {}
+_program_recompute: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
 def attach_program_gauges(registry: MetricsRegistry,
                           name: str = "train_round") -> None:
-    """Show `sparknet_<name>_{temp,argument,output}_bytes` on this
-    registry's /metrics: live-read gauges with no sample until
-    `program_report(name)` has run (they never ask for it themselves)."""
+    """Show `sparknet_<name>_{temp,argument,output}_bytes` and
+    `sparknet_<name>_recompute_core_forward_in_backward` on this registry's
+    /metrics: live-read gauges with no sample until `program_report(name)`
+    has run (they never ask for it themselves)."""
     for key in ("temp", "argument", "output"):
         registry.gauge(
             f"sparknet_{name}_{key}_bytes",
             f"the compiled {name} program's {key} bytes per device (XLA "
             f"memory analysis, read by program_report)"
         ).set_fn(lambda key=key: _program_memory[name][key])
+    registry.gauge(
+        f"sparknet_{name}_recompute_core_forward_in_backward",
+        f"kernels of values the {name} program's recomputation blocks are "
+        f"to keep that run again in a step's backward pass (0: every named "
+        f"value is kept; read by program_report)"
+    ).set_fn(lambda: sum(r["backward"]
+                         for r in _program_recompute[name].values()))
 
 
 def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
@@ -459,3 +531,8 @@ def program_memory() -> Dict[str, Dict[str, int]]:
     has been asked for so far — a read of what is cached, never a compile
     (the /status route)."""
     return dict(_program_memory)
+
+
+def program_recompute() -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """{program: its report's recompute part}, as `program_memory`."""
+    return dict(_program_recompute)

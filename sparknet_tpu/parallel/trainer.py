@@ -889,8 +889,10 @@ class ParallelTrainer:
         cache hit where the cache is on), its text parsed once. None before
         the first dispatch. Never on the round path."""
         if self._report is None and self._round_avals is not None:
+            traced = self._round.trace(*self._round_avals)
             self._report = obs_device.report_of_compiled(
-                self._round.lower(*self._round_avals).compile())
+                traced.lower().compile(), self.net.kept_kernels(),
+                traced.jaxpr.jaxpr)
         return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":

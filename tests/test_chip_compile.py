@@ -360,7 +360,10 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`: the
     published widths, 2 x 8,192 tokens a step, tau=4, bf16, donated, fused
     boundary, health off) for one described chip: ~2 min. 5.65 GB of state
-    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them)."""
+    + ~6.9 GB of temporaries (the gradient is 2.83 GB of them; what the six
+    attention cores keep for the backward 1.01 GB, and their statistics
+    as the kernel writes them, padded to 128 lanes, 1.0 GB more). Each
+    step body runs the cores' forward kernel on its forward path alone."""
     import json
     from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.utils.config import RunConfig
@@ -394,3 +397,7 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     assert total < 13e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
+    from sparknet_tpu.obs.device import parse_hlo_ops, recompute_report
+    kept = recompute_report(parse_hlo_ops(text), trainer.net.kept_kernels())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (6, 0)

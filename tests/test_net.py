@@ -134,6 +134,56 @@ def test_gradients_flow(tiny_net):
     assert all(np.isfinite(norms)) and sum(norms) > 0
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of the jaxprs it calls with them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _traced(net):
+    """The primitives and the recomputation policies in the gradient of a
+    net's training loss: traced, never run."""
+    params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: net.apply(
+        p, b, train=True, rng=jax.random.PRNGKey(1))["loss"]))(
+            params, net.example_batch())
+    eqns = list(_eqns(jaxpr.jaxpr))
+    return ({e.primitive.name for e in eqns},
+            [e.params["policy"] for e in eqns if e.primitive.name == "remat2"])
+
+
+def test_a_block_whose_layers_name_nothing_is_the_bare_checkpoint(tiny_net):
+    """Recomputation keeps named values only where a layer of the block
+    names some (`seq_layers.KEPT_NAMES`): conv1 and pool1 as one block get
+    `jax.checkpoint` with no policy, and no value is named."""
+    from sparknet_tpu.model.spec import LayerSpec
+    spec = tiny_net.spec
+    blocked = CompiledNet.compile(spec.replace(layers=tuple(
+        LayerSpec(**{**l.__dict__, "block": "stem"})
+        if l.name in ("conv1", "pool1") else l for l in spec.layers)))
+    primitives, policies = _traced(blocked)
+    assert policies and all(p is None for p in policies)
+    assert "name" not in primitives
+    assert blocked.kept_kernels() == {}
+
+
+@pytest.mark.parametrize("build", ["tiny", "caffenet"])
+def test_a_net_without_blocks_is_not_checkpointed_at_all(tiny_net, build):
+    """CaffeNet's spec (and every prototxt's) has no `block`: its training
+    loss traces with no `jax.checkpoint`, no policy and no named value, as it
+    did before a block kept anything."""
+    from sparknet_tpu.zoo import caffenet
+    net = tiny_net if build == "tiny" else CompiledNet.compile(
+        caffenet(batch=2, crop=67, n_classes=16))
+    assert {l.block for l in net.spec.layers} == {None}
+    primitives, policies = _traced(net)
+    assert policies == [] and not {"remat2", "name"} & primitives
+    assert "conv_general_dilated" in primitives
+    assert net.kept_kernels() == {}
+
+
 def test_hidden_blob_extraction(tiny_net):
     """FeaturizerApp parity: request a hidden blob by name
     (apps/FeaturizerApp.scala:91-94)."""

@@ -197,6 +197,7 @@ def test_program_report_maps_the_whole_round(lenet_report):
     outside = [op for op in ops.values() if op["phase"] == "outside_step"]
     assert any("tau_boundary" in op["scope"] for op in outside)
     assert not any("tau_step" in op["scope"].split("/") for op in outside)
+    assert report["recompute"] == {}, "no block, nothing kept"
     assert set(report["memory"]) == {"argument", "output", "alias", "temp"}
     assert all(type(v) is int and v >= 0 for v in report["memory"].values())
     assert report["memory"]["temp"] > 0

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from sparknet_tpu import precision, zoo
+from sparknet_tpu.model import net as net_mod
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.layers import LAYER_IMPLS, ApplyCtx
 from sparknet_tpu.model.net import CompiledNet
@@ -327,16 +328,25 @@ def test_two_headed_loss_and_gradients_match_the_reference(policy, seed):
                 assert err < (2e-5 if policy == "float32" else 0.3), (layer, name, err)
 
 
-def test_recomputation_blocks_change_no_number_and_sharing_sums_gradients():
+@pytest.mark.parametrize("other", ["no_blocks", "blocks_that_keep_inputs_only"])
+def test_recomputation_blocks_change_no_number_and_sharing_sums_gradients(
+        other, monkeypatch):
+    """The net as built (blocks that keep what their layers name) against
+    the same net with no recomputation at all, and against blocks under the
+    bare `jax.checkpoint`: the same loss and gradients to the bit."""
     spec = zoo.glm4_moe_lite(TINY, rows=ROWS, positions=POS)
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "head", "mtp",
                                               "mtp_head"}
-    flat = CompiledNet.compile(spec.replace(layers=tuple(
-        LayerSpec(**{**l.__dict__, "block": None}) for l in spec.layers)))
     params, ids = ref.init_params(7, LAYERS), _ids(77)
     f = lambda net: jax.jit(jax.value_and_grad(
         lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None)[0]))(params)
-    (l1, g1), (l2, g2) = f(_net()), f(flat)
+    l1, g1 = f(_net())
+    if other == "no_blocks":
+        l2, g2 = f(CompiledNet.compile(spec.replace(layers=tuple(
+            LayerSpec(**{**l.__dict__, "block": None}) for l in spec.layers))))
+    else:
+        monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
+        l2, g2 = f(_net())
     assert float(l1) == pytest.approx(float(l2), rel=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         assert np.allclose(a, b, rtol=1e-4, atol=1e-7)
@@ -407,6 +417,14 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     assert 'sparknet_moe_slots_dropped{layer="l1_moe"} 0' in text
     assert registry.gauge("sparknet_moe_slots_landed", labels=("layer",)).value(
         layer="mtp") == values["mtp_counters"]["slots_landed"]
+    # the round program's account of what its blocks keep: off the chip no
+    # kernel runs (both counts 0), and a step keeps the four cores' outputs
+    report = obs_device.program_report("train_round")
+    assert report is trainer.program_report()
+    assert report["recompute"] == {sl.ATTN_CORE: {
+        "kernel": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
+        "kept_bytes": 4 * ROWS * POS * MLA_P.num_heads * MLA_P.v_head_dim * 4}}
+    assert obs_device.program_recompute()["train_round"] == report["recompute"]
     with pytest.raises(ValueError, match="model_type"):
         path.write_text(json.dumps(dict(TINY, model_type="other")))
         resolve_spec(cfg)
@@ -417,7 +435,7 @@ def test_a_net_without_counters_has_none_and_its_round_is_what_it_was():
     from sparknet_tpu.solver import SolverConfig
 
     net = CompiledNet.compile(zoo.lenet(batch=4))
-    assert net.counter_blobs() == {}
+    assert net.counter_blobs() == {} and net.kept_kernels() == {}
     trainer = ParallelTrainer(net, SolverConfig(), make_mesh(1), tau=2,
                               compute_health=False)
     assert trainer._health_specs() == {} and trainer.counter_values() == {}
@@ -465,6 +483,133 @@ def test_caffenets_multipliers_are_unchanged():
         ParamSpec(lr_mult=3.0),)}) if l.name == "fc2" else l for l in bare.layers))
     lr, _ = _param_multipliers(CompiledNet.compile(bare))
     assert lr["fc2"] == {"w": 3.0, "b": 1.0}
+
+
+# -- what a recomputation block keeps ----------------------------------------
+
+#: head sizes of whole lanes, positions a multiple of the kernel's tiles: the
+#: smallest attention the kernel path takes
+KERNEL_MLA_P = MLAttentionParam(num_heads=2, q_lora_rank=24, kv_lora_rank=16,
+                                qk_nope_head_dim=96, qk_rope_head_dim=32,
+                                v_head_dim=128, rope_theta=1e6, eps=1e-5)
+KERNEL_POS = max(sl.ATTN_BLOCKS)
+
+
+def _attention_block(mla_p=MLA_P, positions=POS):
+    """(net, params, x, loss) of one recomputation block as a decoder's
+    attention half is: norm, latent attention, residual sum."""
+    tag = dict(block="b")
+    net = CompiledNet.compile(NetSpec(
+        name="blk", inputs=(InputSpec("x", (ROWS, positions, D)),), layers=(
+            LayerSpec(name="n", type="RMSNorm", bottoms=("x",), tops=("xn",),
+                      rmsnorm=RMSNormParam(), **tag),
+            LayerSpec(name="a", type="MLAttention", bottoms=("xn",), tops=("y",),
+                      mla=mla_p, **tag),
+            LayerSpec(name="r", type="Eltwise", bottoms=("x", "y"), tops=("z",),
+                      **tag))))
+    params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
+    loss = lambda p, x: jnp.sum(
+        net.apply(p, {"x": x}, train=True)["z"].astype(jnp.float32))
+    return net, params, jax.ShapeDtypeStruct((ROWS, positions, D), jnp.float32), loss
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """The layers take their kernel path (as on the chip) under bf16: for
+    tracing alone, nothing here can run a TPU kernel."""
+    monkeypatch.setattr(sl, "use_kernels", lambda ctx: True)
+    with precision.policy("bfloat16"):
+        yield
+
+
+def test_the_core_forward_kernel_is_traced_once_where_the_block_keeps_its_names(
+        kernel_path, monkeypatch):
+    """`jax.make_jaxpr` of an attention block's gradient, the kernel path
+    forced: one forward splash kernel and one backward; under the bare
+    `jax.checkpoint` (what a block was before it kept names) the forward
+    kernel is there twice."""
+    import re
+    _, params, x, loss = _attention_block(KERNEL_MLA_P, KERNEL_POS)
+    kernels = lambda: re.findall(r"name=(splash_mha_\w+)",
+                                 str(jax.make_jaxpr(jax.grad(loss))(params, x)))
+    assert kernels() == ["splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"]
+    monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
+    assert kernels() == ["splash_mha_fwd_residuals", "splash_mha_fwd_residuals",
+                         "splash_mha_dkv_no_residuals"]
+
+
+def _kept(loss, params, x):
+    """What the backward pass keeps that is neither an argument nor a
+    constant: [(shape, dtype)]."""
+    from jax._src.ad_checkpoint import saved_residuals  # public: its printer
+    return sorted((a.shape, str(a.dtype)) for a, why in
+                  saved_residuals(loss, params, x)
+                  if "from the argument" not in why and "constant" not in why)
+
+
+def test_a_block_keeps_the_cores_output_and_nothing_else_on_the_exact_path():
+    _, params, x, loss = _attention_block()
+    assert _kept(loss, params, x) == [
+        ((ROWS, POS, MLA_P.num_heads, MLA_P.v_head_dim), "float32")]
+
+
+def test_a_block_keeps_the_cores_output_and_statistics_on_the_kernel_path(
+        kernel_path, monkeypatch):
+    net, params, x, loss = _attention_block(KERNEL_MLA_P, KERNEL_POS)
+    heads = (ROWS, KERNEL_MLA_P.num_heads, KERNEL_POS)
+    assert _kept(loss, params, x) == [
+        (heads, "float32"), (heads + (KERNEL_MLA_P.v_head_dim,), "bfloat16")]
+    assert net.kept_kernels() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    # under the bare jax.checkpoint: the block's inputs alone
+    monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
+    assert _kept(loss, params, x) == []
+
+
+RECOMPUTE_HLO = '''HloModule jit_train_round
+
+%body.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %splash_mha_fwd_residuals.1 = f32[4]{0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MLAttention/l0_attn)/core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/pallas_call"}
+  %splash_mha_fwd_residuals.2 = f32[4]{0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/attention/core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/pallas_call"}
+  %splash_mha_fwd_residuals.3 = f32[4]{0} custom-call(%splash_mha_fwd_residuals.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(jvp()))/checkpoint/rematted_computation/MTP/mtp/attention/core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/pallas_call"}
+  %splash_mha_dkv_no_residuals.1 = f32[4]{0} custom-call(%splash_mha_fwd_residuals.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(jvp()))/checkpoint/MTP/mtp/attention/core/vmap(jit(_splash_attention))/splash_mha_dkv_no_residuals/splash_mha_dkv_no_residuals/pallas_call"}
+  ROOT %add.2 = f32[4]{0} add(%splash_mha_fwd_residuals.1, %splash_mha_dkv_no_residuals.1), metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(MoE/l1_moe))/experts/add"}
+}
+
+ENTRY %main.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %splash_mha_fwd_residuals.4 = f32[4]{0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/tau_step/jvp(MLAttention/l0_attn)/core/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/splash_mha_fwd_residuals/pallas_call"}
+  ROOT %call.1 = f32[4]{0} call(%splash_mha_fwd_residuals.4), to_apply=%body.1
+}
+'''
+
+
+def test_the_report_counts_a_kept_values_kernel_by_the_pass_it_runs_in():
+    """Two cores in the loop's body, one of them run again for the backward
+    pass (its name did not reach the block's policy), one in the peeled
+    step: the step body that has most is the one reported."""
+    from sparknet_tpu.obs import MetricsRegistry
+    from sparknet_tpu.obs import device as obs_device
+    ops = obs_device.parse_hlo_ops(RECOMPUTE_HLO)
+    assert ops["%splash_mha_fwd_residuals.3"]["phase"] == "backward"
+    assert ops["%splash_mha_fwd_residuals.3"]["computation"] == "body.1"
+    assert ops["%splash_mha_fwd_residuals.4"]["computation"] == "main.1"
+    got = obs_device.recompute_report(ops, {sl.ATTN_CORE: "splash_mha_fwd"})
+    assert got == {sl.ATTN_CORE: {"kernel": "splash_mha_fwd", "step_bodies": 2,
+                                  "forward": 2, "backward": 1, "kept_bytes": None}}
+    assert obs_device.recompute_report(ops, {}) == {}
+    # ... and the gauge beside the program's memory gauges reads it
+    obs_device.register_program("a_round", lambda: {
+        "memory": {"temp": 1, "argument": 2, "output": 3}, "ops": ops,
+        "recompute": got})
+    registry = MetricsRegistry()
+    obs_device.attach_program_gauges(registry, "a_round")
+    assert "\nsparknet_a_round_recompute_core_forward_in_backward " not in \
+        registry.render_prometheus(), "no sample until the report has run"
+    obs_device.program_report("a_round")
+    assert registry.gauge(
+        "sparknet_a_round_recompute_core_forward_in_backward").value() == 1.0
+    assert obs_device.program_recompute()["a_round"] == got
 
 
 # -- the compiled text's multi-line instructions -----------------------------
