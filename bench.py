@@ -3224,193 +3224,6 @@ def elastic_bench(out_path: str | None = "BENCH_ELASTIC.json",
     return out
 
 
-def mfu_bench(out_path: str | None = "BENCH_r06.json", batch: int = BATCH,
-              tau: int = TAU, crop: int = 227, n_classes: int = 1000,
-              trials: int = 12, small: bool = False) -> dict:
-    """The r6 overlap-and-fuse audit trail (BENCH_r06): the CaffeNet round
-    through the REAL host-fed path (`ParallelTrainer.train_round` on host
-    batches — H2D included, unlike the device-batch headline), with the
-    three r6 levers toggled one at a time:
-
-      r5_baseline   dispatch-time H2D placement, no donation, XLA
-                    LRN(pallas as r5 shipped)/pool — the PR-5 round
-      +prefetch     `place_batches` on a one-deep prefetch thread while
-                    the previous round computes (t_h2d -> ~0)
-      +donate       batch buffers donated to the compiled round
-                    (two-slot rotation; peak-HBM relief)
-      +pallas       LRN/pool through the Pallas kernels in the layer
-                    path (`OpsImpl` auto on TPU; off-TPU the kernels run
-                    under the Pallas interpreter) — the XLA-vs-Pallas
-                    A/B is this row against the previous one. The
-                    HEADLINE stamps the prefetch_donate arm (the
-                    shipping RunConfig defaults); this arm is the A/B.
-
-    Every row carries the per-round step-time breakdown (t_data/h2d/
-    dispatch/collect ms — the same phases the train loop logs), the jit
-    cache size after the window (must stay at the baseline arm's steady
-    count — one executable plus its fast-path key, reported as 2:
-    pre-placement and donation may not ADD entries), and, where the
-    backend reports
-    allocator stats, HBM bytes-in-use/peak after the arm (the donation
-    before/after). `small=True` is the CPU smoke configuration
-    (tests/test_bench.py) — structure over speed."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    import jax
-    import numpy as np
-
-    from sparknet_tpu import CompiledNet, precision
-    from sparknet_tpu.model.layers import OpsImpl
-    from sparknet_tpu.obs import run_metadata
-    from sparknet_tpu.parallel import ParallelTrainer, make_mesh
-    from sparknet_tpu.solver import SolverConfig
-    from sparknet_tpu.utils import flops
-    from sparknet_tpu.utils.metrics import PhaseTimers
-    from sparknet_tpu.zoo import caffenet
-
-    if small:
-        batch, tau, crop, n_classes, trials = 4, 2, 35, 8, 3
-    precision.set_policy("bfloat16")
-    compute_dt = precision.compute_dtype()
-    net = CompiledNet.compile(
-        caffenet(batch=batch, crop=crop, n_classes=n_classes))
-    solver_cfg = SolverConfig(base_lr=0.01, momentum=0.9, weight_decay=5e-4,
-                              lr_policy="step", gamma=0.1, stepsize=100000)
-    # MFU is a chip metric: the CPU smoke (`small`) reports img/s only, and
-    # off the smoke an unknown device is an error (utils/flops.py)
-    peak = (None if small else
-            flops.peak_bf16_flops(jax.devices()[0].device_kind))
-    fpi = flops.train_flops_per_image(net)
-    r = np.random.default_rng(7)
-    # ONE host batch dict, reused every round: placement copies it into
-    # fresh device buffers (so reuse is donation-safe) and keeps host-side
-    # generation out of the timed loop — the levers under test are H2D
-    # placement, donation, and the kernels, not numpy RNG speed
-    host = {
-        "data": r.standard_normal(
-            (tau, batch, crop, crop, 3)).astype(np.float32),
-        "label": r.integers(0, n_classes,
-                            (tau, batch, 1)).astype(np.int32)}
-
-    def mem_row() -> dict:
-        stats = jax.local_devices()[0].memory_stats() or {}
-        out = {}
-        if "bytes_in_use" in stats:
-            out["hbm_bytes_in_use"] = int(stats["bytes_in_use"])
-        if "peak_bytes_in_use" in stats:
-            out["hbm_peak_bytes"] = int(stats["peak_bytes_in_use"])
-        return out
-
-    def run_arm(name: str, prefetch_h2d: bool, donate: bool,
-                pool_impl: str, lrn_impl: str,
-                interpret: bool = False) -> dict:
-        trainer = ParallelTrainer(
-            net, solver_cfg, make_mesh(1), tau=tau, compute_health=False,
-            donate_batches=donate,
-            ops=OpsImpl(lrn=lrn_impl, pool=pool_impl, interpret=interpret))
-        state = trainer.init_state(jax.random.PRNGKey(0))
-        timers = PhaseTimers()
-        trainer.phase_timers = timers
-        key = jax.random.PRNGKey(1)
-
-        def prep():
-            # the prefetch stage: cast + place (double-buffered H2D) or
-            # hand the host arrays through for dispatch-time placement
-            return (trainer.place_batches(host, compute_dt)
-                    if prefetch_h2d else dict(host))
-
-        # compile + pipeline-prime outside the window
-        state, loss = trainer.train_round(state, prep(),
-                                          jax.random.fold_in(key, 999))
-        assert np.isfinite(float(loss))
-        timers.reset()
-        exe = ThreadPoolExecutor(1, thread_name_prefix="mfu-prep")
-        try:
-            pending = exe.submit(prep)
-            prev = None
-            wait_s = 0.0
-            t0 = time.perf_counter()
-            for i in range(trials):
-                tw = time.perf_counter()
-                batches = pending.result()
-                wait_s += time.perf_counter() - tw
-                if i + 1 < trials:
-                    # no prefetch past the window: an orphaned placement
-                    # would skew the HBM reading mem_row() takes right after
-                    pending = exe.submit(prep)
-                state, loss = trainer.train_round(
-                    state, batches, jax.random.fold_in(key, i))
-                if prev is not None:
-                    float(prev)  # deferred fetch: sync one round behind
-                prev = loss
-            dt = time.perf_counter() - t0
-            float(prev)
-        finally:
-            exe.shutdown(wait=False, cancel_futures=True)
-        per_round = dt / trials
-        img_per_sec = batch * tau / per_round
-        row = {
-            "arm": name,
-            "prefetch_h2d": prefetch_h2d, "donate_batches": donate,
-            "pool_impl": pool_impl, "lrn_impl": lrn_impl,
-            "ops_interpret": interpret,
-            "images_per_sec_per_chip": round(img_per_sec, 2),
-            "round_ms": round(per_round * 1e3, 3),
-            "breakdown_ms": {
-                "data": round(wait_s / trials * 1e3, 3),
-                "h2d": round(timers.total.get("h2d", 0.0)
-                             / trials * 1e3, 3),
-                "dispatch": round(timers.total.get("dispatch", 0.0)
-                                  / trials * 1e3, 3),
-            },
-            "compiled_variants": trainer.compiled_variants(),
-            **mem_row(),
-        }
-        if peak:
-            row["mfu"] = round(img_per_sec * fpi / peak, 4)
-        print(f"  {name}: {img_per_sec:.1f} img/s "
-              f"(h2d {row['breakdown_ms']['h2d']:.2f} ms, "
-              f"variants {row['compiled_variants']})", file=sys.stderr)
-        return row
-
-    # off-TPU the Pallas arm must run the kernels under the interpreter
-    # with lrn='pallas' forced: 'auto' resolves to the same XLA program as
-    # the previous arm there, and the A/B row pair would compare nothing
-    interpret = jax.default_backend() != "tpu"
-    rows = [
-        run_arm("r5_baseline", False, False, "xla", "auto"),
-        run_arm("prefetch", True, False, "xla", "auto"),
-        run_arm("prefetch_donate", True, True, "xla", "auto"),
-        run_arm("prefetch_donate_pallas", True, True, "auto",
-                "pallas" if interpret else "auto", interpret=interpret),
-    ]
-    # the headline is the SHIPPING default configuration (RunConfig
-    # defaults: prefetch + donation on, pool_impl='xla' — r3 measured the
-    # pool kernel losing end to end on TPU); the Pallas arm stays the
-    # standing A/B row, not the stamped claim
-    best = next(r_ for r_ in rows if r_["arm"] == "prefetch_donate")
-    out = {
-        "metric": "caffenet_train_mfu_host_fed_round",
-        "value": best.get("mfu", best["images_per_sec_per_chip"]),
-        "unit": ("achieved/peak dense bf16 FLOP/s through the host-fed "
-                 "train_round (target >= 0.55)" if peak
-                 else "images/sec/chip (no MFU peak for this device kind)"),
-        "vs_baseline": round(
-            best["images_per_sec_per_chip"]
-            / max(rows[0]["images_per_sec_per_chip"], 1e-9), 3),
-        "batch": batch, "tau": tau,
-        "levers": {r_["arm"]: r_.get("mfu", r_["images_per_sec_per_chip"])
-                   for r_ in rows},
-        "t_h2d_ms_prefetched": best["breakdown_ms"]["h2d"],
-    }
-    if out_path:
-        with open(out_path, "w") as f:
-            json.dump({"headline": out, "rows": rows,
-                       "meta": run_metadata()}, f, indent=1)
-    print(json.dumps(out))
-    return {"headline": out, "rows": rows}
-
-
 def sharding_bench(out_path: str | None = "BENCH_r07.json",
                    trials: int = 8, n_devices: int = 8,
                    small: bool | None = None) -> dict:
@@ -4583,10 +4396,6 @@ def main() -> None:
                    help="telemetry overhead: per-round time with the obs "
                    "layer fully on (registry + breakdown + trace + "
                    "scraped /metrics) vs disabled; writes BENCH_OBS")
-    p.add_argument("--mfu", action="store_true",
-                   help="r6 overlap-and-fuse audit: host-fed rounds with "
-                   "the prefetch/donation/Pallas levers toggled one at a "
-                   "time + per-round breakdown; writes BENCH_r06")
     p.add_argument("--ckpt-shard", action="store_true",
                    help="sharded vs monolithic checkpoint save/restore "
                    "wall time vs worker count + bitwise/byte-ledger "
@@ -4665,10 +4474,6 @@ def main() -> None:
         slo_bench(duration_s=args.serve_secs, keep=args.keep)
     elif args.obs:
         obs_bench()
-    elif args.mfu:
-        import jax as _jax
-        mfu_bench(batch=args.batch_size or BATCH, tau=args.tau,
-                  small=_jax.default_backend() != "tpu")
     elif args.ckpt_shard:
         ckpt_shard_bench()
     elif args.sharding:

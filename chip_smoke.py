@@ -7,8 +7,8 @@ recipe), with random weights made from --seed:
 
   device    what jax found, versions, where the compile cache is
   kernels   the Pallas LRN (forward + gradient, norm1/norm2, bf16 and f32),
-            the rows kernel at a serve bucket and the opt-in pool backward,
-            compiled and executed, each checked against its XLA oracle
+            and the rows kernel at a serve bucket, compiled and executed,
+            each checked against its XLA oracle
   train     `apps.train_loop.train()` fed by the real ingest path (synthetic
             JPEG tar shards -> ShardedTarLoader -> StreamingRoundSource ->
             ImagePreprocessor 256->227): rounds, evals, a checkpoint save, a
@@ -55,13 +55,11 @@ import sparknet_tpu  # noqa: F401 — without the program beside it, this
 FULL = SimpleNamespace(
     tiny=False, crop=227, size=256, n_classes=1000, batch=256, tau=5,
     shards=4, per_shard=384, rounds=5, f32_rounds=3,
-    norm1=(256, 27, 27, 96), norm2=(256, 13, 13, 256),
-    pool1=(256, 55, 55, 96), rows_batch=8)
+    norm1=(256, 27, 27, 96), norm2=(256, 13, 13, 256), rows_batch=8)
 TINY = SimpleNamespace(
     tiny=True, crop=67, size=72, n_classes=16, batch=16, tau=2,
     shards=2, per_shard=48, rounds=4, f32_rounds=3,
-    norm1=(128, 7, 7, 32), norm2=(128, 3, 3, 64),
-    pool1=(128, 13, 13, 16), rows_batch=8)
+    norm1=(128, 7, 7, 32), norm2=(128, 3, 3, 64), rows_batch=8)
 
 #: normalised max error |got - want|_max / |want|_max a kernel may show
 #: against its oracle. bf16: a few ulps of the dtype the result is rounded
@@ -165,11 +163,9 @@ def phase_device(ctx) -> dict:
 def phase_kernels(ctx) -> dict:
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     from sparknet_tpu.ops.lrn import _lrn_xla
     from sparknet_tpu.ops.pallas_lrn import lrn_pallas
-    from sparknet_tpu.ops.pallas_pool import maxpool_pallas
 
     sz, interp = ctx.sz, ctx.sz.tiny
     keys = iter(jax.random.split(jax.random.PRNGKey(ctx.seed), 32))
@@ -213,18 +209,6 @@ def phase_kernels(ctx) -> dict:
             dy = jax.random.normal(next(keys), shape).astype(dtype)
             check(name, dtype, lrn_kernel, lrn_oracle, x, dy)
 
-    # the opt-in (pool_impl="auto") max-pool backward against XLA's
-    # select-and-scatter; ReLU'd input so that ties at zero are common
-    x = jax.nn.relu(jax.random.normal(next(keys), sz.pool1)
-                    ).astype(jnp.bfloat16)
-    oh = (sz.pool1[1] - 3) // 2 + 1
-    dy = jax.random.normal(next(keys), (sz.pool1[0], oh, oh, sz.pool1[3])
-                           ).astype(jnp.bfloat16)
-    check("pool-bwd-pool1", "bfloat16",
-          lambda a: maxpool_pallas(a, 3, 2, interp),
-          lambda a: lax.reduce_window(a, -jnp.inf, lax.max, (1, 3, 3, 1),
-                                      (1, 2, 2, 1), ((0, 0),) * 4),
-          x, dy)
     return {"interpret": interp, "cases": cases}
 
 

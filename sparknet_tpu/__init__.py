@@ -10,7 +10,6 @@ an on-device `pmean` over the ICI mesh rather than a driver round trip.
 __version__ = "0.1.0"
 
 from .model.spec import NetSpec, LayerSpec, InputSpec  # noqa: F401
-from .model.layers import OpsImpl  # noqa: F401
 from .model.net import CompiledNet  # noqa: F401
 from .model.prototxt import (  # noqa: F401
     net_from_prototxt,

@@ -34,7 +34,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import numpy as np
 
-from ..model.layers import OpsImpl
 from ..model.net import CompiledNet
 from ..model.spec import NetSpec
 from ..obs import (MetricsRegistry, StatusServer, register_build_info,
@@ -111,7 +110,7 @@ def resolve_trainer_impl(cfg: RunConfig) -> str:
     """cfg.trainer_impl -> the concrete layer-IR trainer implementation.
     "auto" defers to $SPARKNET_TRAINER_IMPL (the CI matrix leg runs the
     whole suite with it set to "named") and falls back to "shard_map",
-    today's default. Validated here — trainer BUILD time, the OpsImpl /
+    today's default. Validated here — trainer BUILD time, the
     ElasticConfig rule — so a typo'd knob cannot silently train on the
     wrong implementation."""
     import os
@@ -160,8 +159,8 @@ def probe_value(state: TrainState, net: CompiledNet):
 
 def build_trainer(cfg: RunConfig, spec: NetSpec, mesh=None):
     """cfg + spec -> the layer-IR trainer `train()` runs: the trainer
-    implementation, round-pipeline levers and kernel selection all come
-    from `cfg`, over `mesh` (default: the data mesh of cfg.n_devices).
+    implementation and the round-pipeline levers come from `cfg`, over
+    `mesh` (default: the data mesh of cfg.n_devices).
     Sets the precision policy and resolves the solver first — both shape
     the compiled round. Building is cheap: nothing compiles until the
     first round. Separate from `train()` so a caller can look at the
@@ -184,9 +183,7 @@ def build_trainer(cfg: RunConfig, spec: NetSpec, mesh=None):
                        elastic_tau=elastic_tau,
                        donate_batches=cfg.donate_batches,
                        fused_boundary=cfg.fused_boundary,
-                       ops=OpsImpl(lrn=cfg.lrn_impl,
-                                   pool=cfg.pool_impl,
-                                   interpret=cfg.ops_interpret),
+                       interpret=cfg.ops_interpret,
                        **trainer_kw)
 
 
@@ -325,7 +322,7 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
     n_dev = trainer.n_devices
     n_local = getattr(trainer, "n_local_devices", n_dev)
     # validated at LOOP ENTRY, not at the first save 25 rounds in — the
-    # OpsImpl/ElasticConfig fail-at-build rule: a typo'd knob must not
+    # ElasticConfig fail-at-build rule: a typo'd knob must not
     # cost a run its work (or, with checkpointing off, go unreported)
     if str(getattr(cfg, "checkpoint_sharded", "auto")) not in (
             "auto", "on", "off"):

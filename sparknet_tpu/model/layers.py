@@ -45,45 +45,6 @@ def tp_shards_layer(layer: "LayerSpec", tp_size: int) -> bool:
             and layer.inner_product.num_output % tp_size == 0)
 
 
-@dataclasses.dataclass(frozen=True)
-class OpsImpl:
-    """Kernel-implementation selection for the ops the layer IR routes
-    through hand-written Pallas TPU kernels (RunConfig.lrn_impl /
-    pool_impl surface these as config knobs; ApplyCtx threads them to the
-    layer applications).
-
-    lrn:  "auto" (Pallas on TPU, fused-elementwise elsewhere), "pallas",
-          "fused", or "window" (the XLA reduce_window fallback).
-    pool: "auto" (Pallas MAX-pool backward on TPU when the shape gate
-          passes, XLA select-and-scatter elsewhere), "pallas", or "xla".
-          Default "xla": the last measured TPU A/B (r3) had the kernel
-          LOSING 10% end to end; "auto" is the opt-in re-tested by the
-          bench.py --mfu row pair — flip the default once BENCH_r06's
-          TPU rows justify it (PERF.md §r6 Status).
-    interpret: run the Pallas kernels under the Pallas INTERPRETER — the
-          CPU parity-test mode ("auto" then resolves to the kernels on
-          CPU too, so tier-1 pins the exact layer-path wiring TPU runs).
-          The sequence layers (model/seq_layers.py) take their exact
-          paths under it instead of jax's attention and grouped-matmul
-          kernels.
-    """
-
-    lrn: str = "auto"
-    pool: str = "xla"
-    interpret: bool = False
-
-    def __post_init__(self) -> None:
-        # fail at construction (config parse / trainer build), not at the
-        # first train_round's trace deep inside jit — same rule PR 6
-        # applied to ElasticConfig
-        if self.lrn not in ("auto", "pallas", "fused", "window"):
-            raise ValueError(f"unknown lrn impl {self.lrn!r}: expected "
-                             f"'auto', 'pallas', 'fused', or 'window'")
-        if self.pool not in ("auto", "pallas", "xla"):
-            raise ValueError(f"unknown pool impl {self.pool!r}: expected "
-                             f"'auto', 'pallas', or 'xla'")
-
-
 @dataclasses.dataclass
 class ApplyCtx:
     """Per-call context threaded through layer application.
@@ -94,8 +55,12 @@ class ApplyCtx:
     all_gather the output features; other layers are replicated
     (`tp_shards_layer` is the single source of truth for the convention).
 
-    ops: kernel-implementation selection (OpsImpl) for LRN / pooling —
-    the Pallas-vs-XLA lever of the r6 MFU push.
+    interpret: run Pallas kernels under the Pallas INTERPRETER — the CPU
+    parity-test mode of the layer path the TPU runs. Which implementation
+    an op runs is the op's own decision (`ops/lrn.py`, `ops/pooling.py`),
+    from the backend, this boolean and the shapes; the sequence layers
+    (model/seq_layers.py) take their exact paths under it instead of jax's
+    attention and grouped-matmul kernels.
 
     quant: serve-side weight-only quantization config (model/quant.py) —
     sets the activation dtype quantized layers dequantize into. Only
@@ -107,7 +72,7 @@ class ApplyCtx:
     rng: Optional[jax.Array] = None
     tp_axis: Optional[str] = None
     tp_size: int = 1
-    ops: OpsImpl = dataclasses.field(default_factory=OpsImpl)
+    interpret: bool = False
     quant: Optional[QuantConfig] = None
 
     def tp_shards(self, layer: "LayerSpec") -> bool:
@@ -197,12 +162,6 @@ def init_convolution(key, layer: LayerSpec, in_shapes) -> Params:
     return params
 
 
-#: grouped-conv lowering: "native" (feature_group_count — the measured
-#: default) or "split" (explicit per-group convs + concat) — an A/B lever
-#: for the 64%-of-MXU-peak grouped convs (PERF.md r4 experiment)
-CONV_GROUP_IMPL = "native"
-
-
 def _s2d_eligible(p, cin: int) -> bool:
     """Space-to-depth rewrite gate: strided, ungrouped, unpadded convs with
     few input channels — i.e. an image-stem conv like CaffeNet's conv1
@@ -257,21 +216,10 @@ def apply_convolution(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
             precision=mm_precision,
             preferred_element_type=mm_out,
         )[:, :oh, :ow]
-    elif p.group > 1 and CONV_GROUP_IMPL == "split":
-        # A/B lever (PERF.md r4): grouped convs as EXPLICIT per-group convs
-        # + concat, versus XLA's native feature_group_count lowering. Same
-        # math (disjoint channel blocks), different schedule.
-        xs = jnp.split(x, p.group, axis=-1)
-        ws = jnp.split(w, p.group, axis=-1)
-        y = jnp.concatenate([
-            lax.conv_general_dilated(
-                xg, wg, window_strides=(p.stride, p.stride),
-                padding=((p.pad, p.pad), (p.pad, p.pad)),
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                precision=mm_precision,
-                preferred_element_type=mm_out)
-            for xg, wg in zip(xs, ws)], axis=-1)
     else:
+        # grouped convs take XLA's native feature_group_count lowering:
+        # explicit per-group convs + concat measured -5.3 % end to end on
+        # the chip (PERF.md section 6, r1-r5)
         y = lax.conv_general_dilated(
             x, w,
             window_strides=(p.stride, p.stride),
@@ -306,8 +254,7 @@ def apply_pooling(layer: LayerSpec, params, inputs, ctx: ApplyCtx):
     (x,) = inputs
     if p.global_pooling:
         return (global_pool2d(x, p.pool),)
-    return (pool2d(x, p.pool, p.kernel_size, p.stride, p.pad,
-                   impl=ctx.ops.pool, interpret=ctx.ops.interpret),)
+    return (pool2d(x, p.pool, p.kernel_size, p.stride, p.pad),)
 
 
 # ---------------------------------------------------------------------------
@@ -323,7 +270,7 @@ def apply_lrn(layer: LayerSpec, params, inputs, ctx: ApplyCtx):
     p = layer.lrn
     (x,) = inputs
     return (lrn_op(x, p.local_size, alpha=p.alpha, beta=p.beta, k=p.k,
-                   impl=ctx.ops.lrn, interpret=ctx.ops.interpret),)
+                   interpret=ctx.interpret),)
 
 
 # ---------------------------------------------------------------------------

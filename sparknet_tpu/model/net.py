@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .layers import LAYER_IMPLS, ApplyCtx, OpsImpl, Params
+from .layers import LAYER_IMPLS, ApplyCtx, Params
 from .quant import QuantConfig
 from .spec import InputSpec, NetSpec, validate
 
@@ -180,7 +180,7 @@ class CompiledNet:
     def apply(self, params: PyTree, batch: Dict[str, jnp.ndarray], *,
               train: bool = False, rng: Optional[jax.Array] = None,
               phase: Optional[str] = None, tp_axis: Optional[str] = None,
-              tp_size: int = 1, ops: Optional[OpsImpl] = None,
+              tp_size: int = 1, interpret: bool = False,
               quant: Optional[QuantConfig] = None
               ) -> Dict[str, jnp.ndarray]:
         """Run the net. `batch` maps input blob names to NHWC arrays.
@@ -193,9 +193,8 @@ class CompiledNet:
         tp_axis/tp_size: run tensor-parallel (inside shard_map over that
         mesh axis) with column-sharded InnerProduct weights — see ApplyCtx.
 
-        ops: kernel-implementation selection for LRN/pooling (OpsImpl;
-        None = "auto" dispatch — Pallas kernels on TPU, portable paths
-        elsewhere).
+        interpret: run Pallas kernels under the Pallas interpreter (the
+        CPU parity-test mode of the layer path the TPU runs; ApplyCtx).
 
         quant: serving-side weight-only quantization config (model/
         quant.py). `params` may then hold int8 `w_q` + per-channel
@@ -206,7 +205,7 @@ class CompiledNet:
         """
         phase = phase or ("TRAIN" if train else "TEST")
         ctx = ApplyCtx(train=train, rng=rng, tp_axis=tp_axis,
-                       tp_size=tp_size, ops=ops or OpsImpl(),
+                       tp_size=tp_size, interpret=interpret,
                        quant=quant)
         blobs: Dict[str, jnp.ndarray] = dict(batch)
         all_tops = set()
@@ -257,12 +256,13 @@ class CompiledNet:
 
     def loss_fn(self, loss_blob: str = "loss",
                 tp_axis: Optional[str] = None, tp_size: int = 1,
-                ops: Optional[OpsImpl] = None):
+                interpret: bool = False):
         """Returns `f(params, batch, rng) -> (loss, aux_blobs)` for jax.grad."""
 
         def f(params, batch, rng=None):
             blobs = self.apply(params, batch, train=True, rng=rng,
-                               tp_axis=tp_axis, tp_size=tp_size, ops=ops)
+                               tp_axis=tp_axis, tp_size=tp_size,
+                               interpret=interpret)
             return blobs[loss_blob], blobs
 
         return f

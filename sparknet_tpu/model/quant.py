@@ -64,7 +64,7 @@ class QuantConfig:
     atol: float = 0.08
 
     def __post_init__(self) -> None:
-        # the OpsImpl/ElasticConfig rule: a typo'd knob fails at config
+        # the ElasticConfig rule: a typo'd knob fails at config
         # construction, not at the first forward's trace
         if self.mode != "int8":
             raise ValueError(f"unknown quant mode {self.mode!r}: "

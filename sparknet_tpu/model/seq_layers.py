@@ -70,7 +70,7 @@ def param_defaults(pname: str) -> ParamSpec:
 
 
 def use_kernels(ctx) -> bool:
-    return not ctx.ops.interpret and jax.default_backend() == "tpu"
+    return not ctx.interpret and jax.default_backend() == "tpu"
 
 
 def _normal(key, shape, std):

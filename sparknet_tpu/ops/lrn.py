@@ -7,10 +7,14 @@ Caffe formula (LRNLayer, used by the reference AlexNet at
 
 window(c, n) = channels [c - (n-1)/2, c + (n-1)/2] clipped to [0, C).
 
-On NHWC the channel window is the minor (lane) dimension. The default path
-lets XLA fuse a channel-padded reduce_window; `sparknet_tpu.ops.pallas_lrn`
-provides a hand-fused Pallas TPU kernel selected automatically on TPU for
-supported shapes.
+On NHWC the channel window is the minor (lane) dimension. WHICH of the three
+implementations runs is decided here and nowhere else, from what this module
+can observe (`lrn`): the hand-fused Pallas TPU kernel
+(`sparknet_tpu.ops.pallas_lrn.lrn_pallas`) on the TPU and under the Pallas
+interpreter, the fused elementwise form (`_lrn_fused`) on any other backend.
+On the chip the kernel takes 10 ms where pure XLA takes 31 (PERF.md section
+6, r1-r5), so no option selects between them. `_lrn_xla` (a channel-padded
+reduce_window) is the oracle that tests and `chip_smoke.py` compare with.
 """
 from __future__ import annotations
 
@@ -21,53 +25,41 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def pallas_backend(interpret: bool = False) -> bool:
+    """May this backend route a layer to a Pallas call: the TPU, or any
+    backend under the Pallas interpreter (CPU parity tests of the path the
+    chip runs). The one place that knows; the trainer asks it to decide
+    whether `shard_map` may check replication (a `pallas_call` has no
+    replication rule)."""
+    return interpret or jax.default_backend() == "tpu"
+
+
 # NOTE: deliberately not jit-decorated — always called inside an outer jit,
 # and grad-through-jit with static_argnames mis-linearizes in jax 0.9.
 def lrn(x: jnp.ndarray, local_size: int = 5, *, alpha: float = 1e-4,
-        beta: float = 0.75, k: float = 1.0, impl: str = "auto",
+        beta: float = 0.75, k: float = 1.0,
         interpret: bool = False) -> jnp.ndarray:
-    """LRN across the channel (last) axis of an NHWC (or N...C) tensor.
+    """LRN across the channel (last) axis of an NHWC (or N...C) tensor:
+    the Pallas kernel where `_can_pallas` holds, the fused form elsewhere.
 
-    impl:
-      "auto"   — Pallas TPU kernel on TPU, fused-elementwise elsewhere.
-      "pallas" — the hand-fused Pallas TPU kernel (ops/pallas_lrn.py).
-      "fused"  — elementwise + channel-shift chain with a custom VJP that
-               recomputes the normalizer in backward. Measured on the r3
-               TPU profile this LOSES to the Pallas kernel end to end
-               (XLA materializes each shifted add: 31ms vs 17ms per
-               CaffeNet round, PERF.md §LRN) — kept as the portable
-               no-Pallas path and as the oracle for the kernel's VJP.
-      "window" — reduce_window reference implementation (oracle tests).
-
-    interpret: run the Pallas kernel under the Pallas INTERPRETER — lets
-      "auto"/"pallas" resolve to the kernel on the CPU backend, so the
-      net-level parity tests pin the exact wiring TPU runs (see OpsImpl).
+    interpret: run the Pallas kernel under the Pallas INTERPRETER, so the
+      kernel path resolves on the CPU backend too and the net-level parity
+      tests pin the exact wiring the TPU runs.
     """
-    if impl not in ("auto", "pallas", "fused", "window"):
-        raise ValueError(f"unknown LRN impl {impl!r}: expected "
-                         f"'auto', 'pallas', 'fused', or 'window'")
-    if impl == "pallas" and not _can_pallas(x, interpret):
-        raise ValueError(
-            f"impl='pallas' requires a TPU backend (or interpret=True) and "
-            f"ndim >= 2 input (backend={jax.default_backend()!r}, "
-            f"ndim={x.ndim}; use 'auto' for backend-dependent dispatch)")
-    if impl == "pallas" or (impl == "auto" and _can_pallas(x, interpret)):
+    if _can_pallas(x, interpret):
         from .pallas_lrn import lrn_pallas
         return lrn_pallas(x, local_size, alpha, beta, k,
                           interpret=interpret)
-    if impl == "window":
-        return _lrn_xla(x, local_size, alpha=alpha, beta=beta, k=k)
     return _lrn_fused(x, local_size, alpha, beta, k)
 
 
 def _can_pallas(x, interpret: bool = False) -> bool:
     """Affirmative TPU check — any other backend gets the portable path,
-    not the TPU Pallas kernel. interpret=True substitutes the Pallas
-    interpreter for the backend requirement (CPU parity tests)."""
-    return (interpret or jax.default_backend() == "tpu") and x.ndim >= 2
+    not the TPU Pallas kernel."""
+    return pallas_backend(interpret) and x.ndim >= 2
 
 
-# -- fused implementation (default) ------------------------------------------
+# -- fused implementation (off the TPU) --------------------------------------
 
 def window_sum(v: jnp.ndarray, half: int, axis: int = -1) -> jnp.ndarray:
     """Windowed sum over `axis` as 2*half shifted adds with zero edge
@@ -134,7 +126,9 @@ _lrn_fused.defvjp(_lrn_fused_fwd, _lrn_fused_bwd)
 
 def _lrn_xla(x: jnp.ndarray, local_size: int = 5, *, alpha: float = 1e-4,
              beta: float = 0.75, k: float = 1.0) -> jnp.ndarray:
-    """XLA fallback: channel-padded reduce_window normalizer."""
+    """The oracle: channel-padded reduce_window normalizer under plain
+    autodiff. Tests and `chip_smoke.py` hold the other two forms to it;
+    `lrn` never dispatches here."""
     half = (local_size - 1) // 2
     # Window sums accumulate in f32: better numerics, and reduce_window-add
     # on bf16 fails to linearize under jit (jax 0.9).

@@ -16,10 +16,6 @@ time, so XLA sees one reduce_window plus one broadcast multiply — both fuse.
 """
 from __future__ import annotations
 
-import functools
-from typing import Tuple
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -40,29 +36,17 @@ def _ave_divisor_1d(size: int, kernel: int, stride: int, pad: int,
 
 
 def pool2d(x: jnp.ndarray, mode: str, kernel: int, stride: int,
-           pad: int, impl: str = "auto",
-           interpret: bool = False) -> jnp.ndarray:
+           pad: int) -> jnp.ndarray:
     """Pool an NHWC tensor with Caffe semantics. mode: 'MAX' | 'AVE'.
 
-    impl: 'xla' — reduce_window + its select-and-scatter VJP; 'pallas' —
-    the ops/pallas_pool.py backward kernel (MAX only, raises when the
-    shape gate fails); 'auto' — the kernel when MAX and the static gate
-    passes on TPU, XLA otherwise. Since r6 'auto' DOES pick the kernel:
-    the r3 standalone A/B lost 10% end to end (the custom-call boundary
-    broke XLA's fusion of pool-backward with its elementwise neighbors),
-    but in the r6 donated/overlapped round the kernel sits between the
-    Pallas LRN custom calls whose fusion boundaries already exist, and the
-    layer-path A/B (`bench.py --mfu`, BENCH_r06) re-measures both arms —
-    `pool_impl="xla"` (RunConfig) restores the old lowering wholesale.
-
-    interpret: run the Pallas kernel under the Pallas INTERPRETER — CPU
-    parity-test mode; 'auto' then applies the same shape gate on CPU."""
-    if impl not in ("auto", "xla", "pallas"):
-        raise ValueError(f"unknown pool impl {impl!r}: expected "
-                         f"'auto', 'xla', or 'pallas'")
-    if impl == "pallas" and mode != "MAX":
-        raise ValueError(f"impl='pallas' supports MAX pooling only "
-                         f"(got mode={mode!r})")
+    MAX is `reduce_window` with its select-and-scatter VJP on every
+    backend, and there is no other form to choose: a Pallas kernel for the
+    backward (one fused pass over x, dy, y in the conv's N-minor layout)
+    was A/B'd on the chip twice and lost 10 % end to end both times (r3;
+    PR 29: `train_round_rate` 18,679 against 20,942 in
+    `caffenet-train-round`, `pool_device_ms` 115 against 61) -- the
+    custom-call boundary broke XLA's fusion of pool-backward with its
+    elementwise neighbours and cost layout passes on either side."""
     n, h, w, c = x.shape
     oh = caffe_pool_output_size(h, kernel, stride, pad)
     ow = caffe_pool_output_size(w, kernel, stride, pad)
@@ -74,18 +58,6 @@ def pool2d(x: jnp.ndarray, mode: str, kernel: int, stride: int,
     strides = (1, stride, stride, 1)
 
     if mode == "MAX":
-        # impl='xla' (the documented wholesale opt-out) must never touch
-        # the Pallas toolchain — only 'auto'/'pallas' consult the gate
-        if impl != "xla":
-            can = _can_pallas_pool(x, kernel, stride, pad, interpret)
-            if impl == "pallas" and not can:
-                raise ValueError(
-                    f"impl='pallas' unsupported for shape {x.shape} "
-                    f"k={kernel} s={stride} pad={pad} on "
-                    f"{jax.default_backend()!r} (see pallas_pool docstring)")
-            if can:
-                from .pallas_pool import maxpool_pallas
-                return maxpool_pallas(x, kernel, stride, interpret)
         return lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, padding)
     if mode == "AVE":
         # f32 accumulation (and: bf16 reduce_window-add mis-linearizes
@@ -97,18 +69,6 @@ def pool2d(x: jnp.ndarray, mode: str, kernel: int, stride: int,
         div = jnp.asarray(np.outer(div_h, div_w))
         return (s / div[None, :, :, None]).astype(x.dtype)
     raise ValueError(f"unknown pool mode {mode!r}")
-
-
-def _can_pallas_pool(x, kernel: int, stride: int, pad: int,
-                     interpret: bool = False) -> bool:
-    """Shape/backend gate for the kernel path. No blanket except: a
-    broken pallas_pool import must surface as itself, not masquerade as
-    an 'unsupported shape' error (r3 review). interpret=True waives the
-    backend requirement (CPU parity tests), never the shape gate."""
-    if not (interpret or jax.default_backend() == "tpu"):
-        return False
-    from .pallas_pool import pallas_maxpool_supported
-    return pallas_maxpool_supported(x.shape, x.dtype, kernel, stride, pad)
 
 
 def global_pool2d(x: jnp.ndarray, mode: str) -> jnp.ndarray:

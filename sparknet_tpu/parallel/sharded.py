@@ -65,7 +65,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..model.layers import OpsImpl, tp_shards_layer
+from ..model.layers import tp_shards_layer
 from ..model.net import CompiledNet, PyTree
 from ..solver import SolverConfig
 from .mesh import DATA_AXIS, MODEL_AXIS, shard_map_unchecked
@@ -100,7 +100,7 @@ class ShardedTrainer(ParallelTrainer):
                  loss_blob: str = "loss", acc_blob: Optional[str] = None,
                  compute_health: bool = True, elastic_tau: bool = False,
                  donate_batches: bool = False,
-                 ops: Optional[OpsImpl] = None,
+                 interpret: bool = False,
                  fused_boundary: bool = False,
                  state_sharding: str = "replicated"):
         if state_sharding not in STATE_SHARDINGS:
@@ -118,7 +118,8 @@ class ShardedTrainer(ParallelTrainer):
                          loss_blob=loss_blob, acc_blob=acc_blob,
                          compute_health=compute_health,
                          elastic_tau=elastic_tau,
-                         donate_batches=donate_batches, ops=ops,
+                         donate_batches=donate_batches,
+                         interpret=interpret,
                          fused_boundary=fused_boundary)
 
     def _ctor_extra(self) -> Dict[str, Any]:
@@ -270,7 +271,7 @@ class ShardedTrainer(ParallelTrainer):
     def _eval_impl(self, params, batch):
         blobs = self.net.apply(params, batch, train=False,
                                tp_axis=self._tp_axis, tp_size=self.tp,
-                               ops=self.ops)
+                               interpret=self.interpret)
         acc_blob = self.acc_blob or _find_accuracy_blob(self.net)
         n = next(iter(batch.values())).shape[0]
         correct = blobs[acc_blob] * n

@@ -40,7 +40,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..model.layers import OpsImpl, tp_shards_layer
+from ..model.layers import tp_shards_layer
+from ..ops.lrn import pallas_backend
 from ..model.net import CompiledNet, PyTree
 from ..obs import device as obs_device
 from ..obs import trace as obs_trace
@@ -83,7 +84,7 @@ class ParallelTrainer:
                  loss_blob: str = "loss", acc_blob: Optional[str] = None,
                  compute_health: bool = True, elastic_tau: bool = False,
                  donate_batches: bool = False,
-                 ops: Optional[OpsImpl] = None,
+                 interpret: bool = False,
                  fused_boundary: bool = False):
         assert mode in ("local_sgd", "sync_sgd")
         if mode == "sync_sgd":
@@ -141,9 +142,10 @@ class ParallelTrainer:
         # the flag compile the byte-identical legacy round.
         self.elastic_tau = bool(elastic_tau)
         self._tau_vec_dev: Optional[Tuple[Tuple[int, ...], jax.Array]] = None
-        #: kernel-implementation selection for LRN/pooling, threaded into
-        #: every loss/eval apply (the Pallas-vs-XLA config lever)
-        self.ops = ops or OpsImpl()
+        #: run Pallas kernels under the Pallas interpreter (CPU parity
+        #: tests of the layer path the TPU runs), threaded into every
+        #: loss/eval apply
+        self.interpret = bool(interpret)
         # donate_batches additionally donates the [tau, global_batch, ...]
         # input buffers to the compiled round: XLA reuses their HBM for
         # round intermediates instead of holding batch + intermediates
@@ -182,16 +184,10 @@ class ParallelTrainer:
         # flips it for the train loop.
         self.fused_boundary = bool(fused_boundary)
         # a pallas_call traced inside shard_map has no replication rule,
-        # so replication checking goes off exactly when the ops config can
-        # route LRN/pool to a Pallas kernel on this backend (explicit
-        # "pallas", or "auto" where it resolves to the kernel: TPU, or any
-        # backend under the interpreter)
-        may_pallas = any(
-            impl == "pallas"
-            or (impl == "auto" and (self.ops.interpret
-                                    or jax.default_backend() == "tpu"))
-            for impl in (self.ops.lrn, self.ops.pool))
-        self._smap = shard_map_unchecked if may_pallas else shard_map
+        # so replication checking goes off exactly where ops/ may route a
+        # layer to a Pallas kernel
+        self._smap = (shard_map_unchecked if pallas_backend(self.interpret)
+                      else shard_map)
         #: first-call-validated batch signatures: `_check_batch` asserts
         #: the tau/divisibility invariants once per (input, shape, dtype,
         #: placement) and steady-state rounds skip straight past them
@@ -542,7 +538,8 @@ class ParallelTrainer:
         state) so the parity suite can pin them bitwise. Returns (params,
         SolverState, mean_loss, health)."""
         loss_fn = self.net.loss_fn(self.loss_blob, tp_axis=self._tp_axis,
-                                   tp_size=self.tp, ops=self.ops)
+                                   tp_size=self.tp,
+                                   interpret=self.interpret)
         tp_layers = self._tp_sharded_layers()
 
         def fix_tp_grads(grads):
@@ -768,7 +765,7 @@ class ParallelTrainer:
         params = jax.tree.map(lambda x: x[0], params)
         blobs = self.net.apply(params, batch, train=False,
                                tp_axis=self._tp_axis, tp_size=self.tp,
-                               ops=self.ops)
+                               interpret=self.interpret)
         acc_blob = self.acc_blob or _find_accuracy_blob(self.net)
         n = next(iter(batch.values())).shape[0]
         correct = blobs[acc_blob] * n
@@ -913,7 +910,8 @@ class ParallelTrainer:
             self.net, self.solver.cfg, make_mesh(n_devices), tau=self.tau,
             mode=self.mode, loss_blob=self.loss_blob, acc_blob=self.acc_blob,
             compute_health=self.compute_health, elastic_tau=self.elastic_tau,
-            donate_batches=self.donate_batches, ops=self.ops,
+            donate_batches=self.donate_batches,
+            interpret=self.interpret,
             fused_boundary=self.fused_boundary,
             **self._ctor_extra())
 

@@ -243,7 +243,7 @@ class ServeConfig:
 
     def __post_init__(self) -> None:
         # fail at construction, not at the first _pick_bucket next() —
-        # the ElasticConfig/OpsImpl rule
+        # the ElasticConfig rule
         if self.max_batch < 1:
             raise ValueError(f"max_batch must be >= 1 "
                              f"(got {self.max_batch})")

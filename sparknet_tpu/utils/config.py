@@ -149,19 +149,16 @@ class RunConfig:
     # drops to ~0. donate_batches donates the [tau, global_batch, ...]
     # buffers to the compiled round (two-slot rotation: R donated while
     # R+1 places into fresh buffers), cutting peak HBM + allocator churn.
-    # lrn_impl / pool_impl pick the kernel implementation in the layer
-    # path: "auto" = the Pallas TPU kernels on TPU (XLA/fused elsewhere),
-    # lrn "window" / pool "xla" = the XLA reduce_window lowerings as the
-    # explicit fallback, "pallas" = force (raises where unsupported);
-    # validated at OpsImpl construction, i.e. trainer build. ops_interpret
-    # runs the Pallas kernels under the interpreter — CPU parity tests.
-    # pool_impl defaults to "xla" (the r3 TPU A/B measured the kernel
-    # losing 10% end to end); "auto" is the opt-in, re-measured by the
-    # bench.py --mfu row pair — flip here once BENCH_r06's TPU rows say so.
+    # ops_interpret runs the layer path's Pallas kernels under the
+    # interpreter — CPU parity tests of what the TPU runs. WHICH kernel a
+    # layer runs is no option: ops/lrn.py and ops/pooling.py decide from
+    # the backend, this boolean and the shapes (the Pallas LRN on the TPU;
+    # MAX-pool's backward stays XLA's select-and-scatter, because a
+    # custom-call boundary there broke XLA's fusion of pool-backward with
+    # its elementwise neighbours and lost end to end on the chip: PERF.md
+    # section 6, PR 29).
     h2d_prefetch: bool = True
     donate_batches: bool = True
-    lrn_impl: str = "auto"
-    pool_impl: str = "xla"
     ops_interpret: bool = False
     # the r8 gather-free boundary levers (each pinned bit-exact by
     # tests/test_round_pipeline.py). fused_boundary peels the final τ

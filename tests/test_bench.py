@@ -221,44 +221,6 @@ def test_obs_bench_smoke(tmp_path, monkeypatch):
     assert art["meta"]["jax_version"]  # run_metadata stamp
 
 
-def test_mfu_bench_smoke(tmp_path):
-    """bench.mfu_bench runs the REAL host-fed round through all four
-    lever arms (dispatch-H2D baseline, prefetch placement, +donation,
-    +Pallas layer path) and writes a complete BENCH_r06-style artifact.
-    The acceptance number (MFU >= 0.55) is stamped by running this on the
-    TPU pod; this smoke asserts the harness — arms present and ordered,
-    the breakdown recorded, prefetch arms placing off the dispatch path,
-    jit cache steady across arms, run_metadata stamped — on the CPU
-    config."""
-    import bench
-    out_path = str(tmp_path / "BENCH_r06.json")
-    out = bench.mfu_bench(out_path=out_path, small=True)
-    rows = out["rows"]
-    assert [r["arm"] for r in rows] == [
-        "r5_baseline", "prefetch", "prefetch_donate",
-        "prefetch_donate_pallas"]
-    for r in rows:
-        assert r["images_per_sec_per_chip"] > 0
-        assert set(r["breakdown_ms"]) == {"data", "h2d", "dispatch"}
-        # steady cache: pre-placement/donation caused no churn past the
-        # two fast-path keys of the one executable (see
-        # test_round_pipeline.test_overlapped_round_holds_steady_jit_cache)
-        assert r["compiled_variants"] <= rows[0]["compiled_variants"]
-    # prefetch arms place on the prep thread: the dispatch-side h2d phase
-    # sees the passthrough, the baseline pays the real copy there
-    assert rows[1]["breakdown_ms"]["h2d"] <= \
-        rows[0]["breakdown_ms"]["h2d"] + 1.0
-    # off-TPU the Pallas arm must actually run the kernels (interpreter,
-    # lrn forced) — 'auto' would silently rerun the XLA arm
-    import jax
-    if jax.default_backend() != "tpu":
-        assert rows[3]["ops_interpret"] and rows[3]["lrn_impl"] == "pallas"
-    art = json.load(open(out_path))
-    assert art["headline"]["metric"] == "caffenet_train_mfu_host_fed_round"
-    assert set(art["headline"]["levers"]) == {r["arm"] for r in rows}
-    assert art["meta"]["jax_version"]  # run_metadata stamp
-
-
 def test_sharding_bench_smoke(tmp_path):
     """bench.sharding_bench runs the three r7 trainer arms and writes a
     complete BENCH_r07-style artifact. The deterministic claims are

@@ -33,7 +33,6 @@ from jax.sharding import SingleDeviceSharding
 from sparknet_tpu import precision
 from sparknet_tpu.model.net import CompiledNet
 from sparknet_tpu.ops.pallas_lrn import lrn_pallas
-from sparknet_tpu.ops.pallas_pool import maxpool_pallas
 from sparknet_tpu.parallel import ParallelTrainer, ShardedTrainer, make_mesh
 from sparknet_tpu.parallel.mesh import DATA_AXIS, place_global_state
 from sparknet_tpu.parallel.trainer import TrainState
@@ -43,7 +42,6 @@ from sparknet_tpu.zoo import caffenet
 BATCH, CROP, CLASSES, TAU = 256, 227, 1000, 5
 NORM1 = (BATCH, 27, 27, 96)     # pool1 -> norm1
 NORM2 = (BATCH, 13, 13, 256)    # pool2 -> norm2
-POOL1 = (BATCH, 55, 55, 96)     # conv1 -> pool1
 
 
 @pytest.fixture(scope="module")
@@ -75,10 +73,6 @@ def _lrn_sum(x):
     return lrn_pallas(x).astype(jnp.float32).sum()
 
 
-def _pool_sum(x):
-    return maxpool_pallas(x, 3, 2).astype(jnp.float32).sum()
-
-
 KERNEL_CASES = [
     # the N-minor kernel (batch a multiple of 128 lanes): the training path
     ("lrn-fwd-norm1-bf16", lrn_pallas, NORM1, jnp.bfloat16),
@@ -93,8 +87,6 @@ KERNEL_CASES = [
     # the rows kernel: what a serve bucket of 8 runs at norm1 and norm2
     ("lrn-rows-norm1-b8", lrn_pallas, (8,) + NORM1[1:], jnp.float32),
     ("lrn-rows-norm2-b8", lrn_pallas, (8,) + NORM2[1:], jnp.float32),
-    # the opt-in (pool_impl="auto") max-pool backward
-    ("pool-grad-pool1-bf16", jax.grad(_pool_sum), POOL1, jnp.bfloat16),
 ]
 
 
@@ -125,8 +117,9 @@ def test_bf16_row_block_is_the_profiled_one():
 
 @pytest.fixture
 def as_tpu(monkeypatch):
-    """Steer the `jax.default_backend()` askers (ops/lrn, ops/pooling,
-    ParallelTrainer's may_pallas, mesh.scan_unroll) down their TPU branch."""
+    """Steer the `jax.default_backend()` askers (ops/lrn's `pallas_backend`,
+    which the trainer's may_pallas asks too; seq_layers; mesh.scan_unroll)
+    down their TPU branch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 

@@ -12,7 +12,7 @@ import torch.nn.functional as F
 import jax
 import jax.numpy as jnp
 
-from sparknet_tpu.ops.lrn import lrn
+from sparknet_tpu.ops.lrn import _lrn_fused, _lrn_xla
 from sparknet_tpu.ops.pooling import caffe_pool_output_size, pool2d
 
 
@@ -52,11 +52,20 @@ def test_pool2d_matches_torch(rng, mode, h, k, s, p):
     np.testing.assert_allclose(got, nhwc(want.numpy()), rtol=1e-5, atol=1e-5)
 
 
+#: the two portable LRN forms by name (`ops.lrn.lrn` picks between the
+#: Pallas kernel and the fused form by backend; the kernel's own parity
+#: tests are tests/test_pallas_lrn.py)
+LRN_IMPLS = {
+    "fused": _lrn_fused,
+    "window": lambda x, n, alpha, beta, k: _lrn_xla(x, n, alpha=alpha,
+                                                    beta=beta, k=k),
+}
+
+
 @pytest.mark.parametrize("impl", ["fused", "window"])
 def test_lrn_matches_torch(rng, impl):
     x = rng.standard_normal((2, 7, 7, 16), dtype=np.float32)
-    got = np.asarray(lrn(jnp.asarray(x), 5, alpha=1e-4, beta=0.75, k=1.0,
-                         impl=impl))
+    got = np.asarray(LRN_IMPLS[impl](jnp.asarray(x), 5, 1e-4, 0.75, 1.0))
     want = F.local_response_norm(torch.from_numpy(nchw(x)), size=5,
                                  alpha=1e-4, beta=0.75, k=1.0)
     np.testing.assert_allclose(got, nhwc(want.numpy()), rtol=1e-5, atol=1e-6)
@@ -70,8 +79,7 @@ def test_lrn_fused_gradient_matches_autodiff_of_window(rng):
 
     def f(impl):
         return lambda x_: jnp.vdot(
-            lrn(x_, 5, alpha=2e-4, beta=0.75, k=1.0, impl=impl),
-            jnp.asarray(dy))
+            LRN_IMPLS[impl](x_, 5, 2e-4, 0.75, 1.0), jnp.asarray(dy))
 
     g_want = np.asarray(jax.grad(f("window"))(jnp.asarray(x)))
     g_got = np.asarray(jax.grad(f("fused"))(jnp.asarray(x)))
@@ -113,44 +121,3 @@ def test_maxpool_tie_gradient_goes_to_first_max():
     g = jax.grad(lambda v: pool2d(v, "MAX", 2, 2, 0).sum())(jnp.asarray(x))
     np.testing.assert_array_equal(
         np.asarray(g)[0, :, :, 0], [[1.0, 0.0], [0.0, 0.0]])
-
-
-def test_grouped_conv_split_impl_matches_native(rng):
-    """The CONV_GROUP_IMPL='split' A/B lever (PERF.md r4) is the same math
-    as XLA's native feature_group_count: outputs and gradients must agree."""
-    import jax
-    import sparknet_tpu.model.layers as L
-    from sparknet_tpu.model.spec import (ConvolutionParam, Filler,
-                                         InputSpec, LayerSpec, NetSpec)
-    from sparknet_tpu import CompiledNet
-
-    spec = NetSpec(
-        name="g", inputs=(InputSpec("data", (2, 6, 8, 8)),),
-        layers=(LayerSpec(
-            name="conv", type="Convolution", bottoms=("data",),
-            tops=("conv",),
-            conv=ConvolutionParam(
-                num_output=8, kernel_size=3, pad=1, group=2,
-                weight_filler=Filler(type="gaussian", std=0.1))),))
-    net = CompiledNet.compile(spec)
-    params = net.init_params(jax.random.PRNGKey(0))
-    batch = {"data": rng.standard_normal((2, 8, 8, 6)).astype(np.float32)}
-
-    def out_sum(p):
-        return jnp.sum(net.apply(p, batch, train=False)["conv"] ** 2)
-
-    try:
-        y_nat = net.apply(params, batch, train=False)["conv"]
-        g_nat = jax.grad(out_sum)(params)
-        L.CONV_GROUP_IMPL = "split"
-        y_spl = net.apply(params, batch, train=False)["conv"]
-        g_spl = jax.grad(out_sum)(params)
-    finally:
-        L.CONV_GROUP_IMPL = "native"
-    np.testing.assert_allclose(np.asarray(y_spl), np.asarray(y_nat),
-                               rtol=1e-5, atol=1e-6)
-    for pname in g_nat["conv"]:
-        np.testing.assert_allclose(
-            np.asarray(g_spl["conv"][pname]),
-            np.asarray(g_nat["conv"][pname]), rtol=1e-5, atol=1e-6,
-            err_msg=pname)

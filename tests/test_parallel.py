@@ -59,42 +59,23 @@ def test_mesh_has_8_devices():
     assert len(jax.devices()) == N_DEV
 
 
-def test_tau_averaging_matches_sequential_oracle(net, cfg):
-    """One full round on the mesh == per-worker sequential simulation."""
-    mesh = make_mesh()
-    trainer = ParallelTrainer(net, cfg, mesh, tau=TAU)
+@pytest.mark.parametrize("tau", [1, 2, 3])
+def test_tau_averaging_matches_sequential_oracle(net, cfg, trainer_cls, tau):
+    """One full round on the mesh == the serial per-worker reference
+    (tests/round_oracle.py: tau steps a worker on its own rows and keys,
+    then the mean of the weights, momentum worker-local), under both
+    trainer implementations and at the round's three shapes: scan-free,
+    a scan of one step, a scan of several."""
+    import round_oracle
+
+    trainer = trainer_cls(net, cfg, make_mesh(), tau=tau)
     state = trainer.init_state(jax.random.PRNGKey(0))
-    init_params = trainer.averaged_params(state)
-    batches = make_round_batches(1)
+    batches = {k: v[:tau] for k, v in make_round_batches(1).items()}
     rng = jax.random.PRNGKey(42)
+    start = round_oracle.split_state(trainer, state)
     new_state, loss = trainer.train_round(state, batches, rng)
-
-    # oracle: run each worker's τ steps sequentially with the single-device
-    # solver, then average weights (momentum NOT averaged).
-    solver = SgdSolver(net, cfg)
-    rngs = jax.random.split(rng, N_DEV)
-    worker_params = []
-    for w in range(N_DEV):
-        p = init_params
-        s = solver.init_state(p)
-        step_rngs = jax.random.split(rngs[w], TAU)
-        for t in range(TAU):
-            batch = {
-                k: jnp.asarray(v[t, w * LOCAL_B:(w + 1) * LOCAL_B])
-                for k, v in batches.items()}
-            (l, _), grads = jax.value_and_grad(
-                lambda p_: net.loss_fn()(p_, batch, step_rngs[t]),
-                has_aux=True)(p)
-            p, s = solver.update(p, s, grads)
-        worker_params.append(p)
-    avg = jax.tree.map(lambda *xs: sum(xs) / N_DEV, *worker_params)
-
-    got = trainer.averaged_params(new_state)
-    for lname in avg:
-        for pname in avg[lname]:
-            np.testing.assert_allclose(
-                np.asarray(got[lname][pname]), np.asarray(avg[lname][pname]),
-                rtol=2e-5, atol=1e-6, err_msg=f"{lname}/{pname}")
+    round_oracle.assert_round_matches(trainer, start, new_state, loss,
+                                      batches, rng)
 
 
 def test_round_synchronizes_replicas(net, cfg):

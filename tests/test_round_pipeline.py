@@ -1,10 +1,12 @@
 """Round-pipeline overlap & fuse (r6): bit-exactness pins for the three
 MFU levers — double-buffered H2D pre-placement, batch-buffer donation, and
-the Pallas LRN/pool wiring in the layer path — plus the jit-cache-churn
+the Pallas LRN wiring in the layer path — plus the jit-cache-churn
 gauge check. The levers may only move WHERE work happens (prefetch thread
 vs dispatch, donated vs fresh buffers, kernel vs XLA lowering), never WHAT
-is computed: pre-placement and donation pin bitwise, the kernels pin to
-parity tolerances under the bf16 policy.
+is computed: pre-placement and donation pin bitwise, the kernel pins to
+parity tolerances under the bf16 policy. Each arm of a switch (donation,
+the fused boundary) is ALSO held to the serial reference of a round
+(tests/round_oracle.py), so an arm can leave the tree with its own cases.
 """
 import json
 import os
@@ -15,8 +17,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import round_oracle
 from sparknet_tpu import CompiledNet, net_from_prototxt, precision
-from sparknet_tpu.model.layers import OpsImpl
 from sparknet_tpu.parallel import ParallelTrainer, make_mesh
 from sparknet_tpu.solver import SolverConfig
 
@@ -42,9 +44,8 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip2" bottom: "label"
 """
 
 # conv -> LRN -> MAX pool -> ip -> loss at Pallas-gate-friendly shapes:
-# batch 128 (the pool kernel's N-lane and the LRN N-minor kernel's lane
-# alignment), pool 3x3/2 pad 0 (the CaffeNet pool geometry), C=16 (the
-# bf16 sublane tile)
+# batch 128 (the LRN N-minor kernel's lane alignment), pool 3x3/2 pad 0
+# (the CaffeNet pool geometry), C=16 (the bf16 sublane tile)
 CONV_LRN_POOL = """
 name: "conv_lrn_pool"
 input: "data"
@@ -146,23 +147,28 @@ def test_preplaced_batches_thread_cast_matches_main_thread(net, solver_cfg,
 # -- pin (b): donated-batch rotation never aliases a live buffer -------------
 
 
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["fresh", "donated"])
 def test_donating_trainer_bitwise_equals_non_donating(net, solver_cfg,
-                                                      trainer_cls):
+                                                      trainer_cls, donate):
     """Hammer τ rounds through a donate_batches trainer fed freshly placed
     batches each round (the train loop's two-slot rotation) and through
-    the legacy non-donating trainer: every round's loss and the final
-    params must match BITWISE — donation may recycle buffers, never
-    values."""
+    the legacy non-donating trainer. The arm named by `donate` is held,
+    round by round, to the serial reference of a round; and while both
+    arms exist every round's loss and the final params must match
+    BITWISE — donation may recycle buffers, never values."""
     mesh = make_mesh(N_DEV)
     t_ref = trainer_cls(net, solver_cfg, mesh, tau=TAU)
     t_don = trainer_cls(net, solver_cfg, mesh, tau=TAU,
                         donate_batches=True)
     assert t_don.donate_batches and not t_ref.donate_batches
+    arm = t_don if donate else t_ref
     s_ref = t_ref.init_state(jax.random.PRNGKey(9))
     s_don = t_don.init_state(jax.random.PRNGKey(9))
     placed_prev = None
     for rnd in range(8):
         rng = jax.random.PRNGKey(70 + rnd)
+        start = round_oracle.split_state(arm, s_don if donate else s_ref)
         s_ref, l_ref = t_ref.train_round(s_ref, make_round_batches(rnd), rng)
         # two-slot rotation: place round R+1's buffers while round R's
         # (donated) are still owned by the executable, as the loop does
@@ -174,6 +180,9 @@ def test_donating_trainer_bitwise_equals_non_donating(net, solver_cfg,
                 assert placed[k] is not placed_prev[k]
         s_don, l_don = t_don.train_round(s_don, placed, rng)
         placed_prev = placed
+        s_arm, l_arm = (s_don, l_don) if donate else (s_ref, l_ref)
+        round_oracle.assert_round_matches(arm, start, s_arm, l_arm,
+                                          make_round_batches(rnd), rng)
         assert float(l_ref) == float(l_don), rnd
     assert_trees_bitwise(params_np(s_ref), params_np(s_don), "donate")
 
@@ -277,20 +286,18 @@ def test_batch_invariants_still_enforced_on_first_call(net, solver_cfg,
 def test_pallas_lrn_inside_sharded_round(solver_cfg):
     """The kernel must trace inside the shard_map'd ROUND, not just in a
     bare loss_fn: pallas_call has no shard_map replication rule, so the
-    trainer switches replication checking off when the ops config routes
+    trainer switches replication checking off where ops/ may route a layer
     to a kernel (the net-level parity tests below bypass shard_map and
-    cannot catch a trace-time crash here)."""
+    cannot catch a trace-time crash here). Compared with the same round
+    on the fused form a CPU runs."""
     net = CompiledNet.compile(net_from_prototxt(CONV_LRN_POOL))
     r = np.random.default_rng(11)
     batches = {
         "data": r.standard_normal((2, 32, 9, 9, 3)).astype(np.float32),
         "label": r.integers(0, 4, (2, 32, 1)).astype(np.int32)}
-    t_pal = ParallelTrainer(
-        net, solver_cfg, make_mesh(N_DEV), tau=2,
-        ops=OpsImpl(lrn="pallas", pool="xla", interpret=True))
-    t_xla = ParallelTrainer(
-        net, solver_cfg, make_mesh(N_DEV), tau=2,
-        ops=OpsImpl(lrn="window", pool="xla"))
+    t_pal = ParallelTrainer(net, solver_cfg, make_mesh(N_DEV), tau=2,
+                            interpret=True)
+    t_xla = ParallelTrainer(net, solver_cfg, make_mesh(N_DEV), tau=2)
     rng = jax.random.PRNGKey(1)
     _, l_pal = t_pal.train_round(
         t_pal.init_state(jax.random.PRNGKey(0)), dict(batches), rng)
@@ -303,8 +310,8 @@ def test_pallas_lrn_inside_sharded_round(solver_cfg):
 # -- pin (c): net-level Pallas-vs-XLA parity under the bf16 policy -----------
 
 
-def _loss_and_grads(net, ops, batch, params):
-    loss_fn = net.loss_fn("loss", ops=ops)
+def _loss_and_grads(net, interpret, batch, params):
+    loss_fn = net.loss_fn("loss", interpret=interpret)
     (loss, _), grads = jax.value_and_grad(
         lambda p: loss_fn(p, batch, jax.random.PRNGKey(0)),
         has_aux=True)(params)
@@ -324,16 +331,15 @@ def _parity_net_and_batch():
 
 def test_net_level_pallas_lrn_parity_bf16():
     """The LAYER-PATH wiring pin (kernel-level parity lives in
-    tests/test_pallas_lrn.py): the same net through ops=(lrn=pallas,
-    interpret) vs the explicit XLA fallback, loss + all grads, under the
-    bf16 precision policy the TPU headline runs."""
+    tests/test_pallas_lrn.py): the same net through interpret=True (the
+    kernel path, as on the chip) vs interpret=False (the fused form a CPU
+    runs, itself held to the reduce_window oracle at op level by
+    tests/test_ops.py), loss + all grads, under the bf16 precision policy
+    the TPU headline runs."""
     net, batch, params = _parity_net_and_batch()
     with precision.policy("bfloat16"):
-        l_pal, g_pal = _loss_and_grads(
-            net, OpsImpl(lrn="pallas", pool="xla", interpret=True),
-            batch, params)
-        l_xla, g_xla = _loss_and_grads(
-            net, OpsImpl(lrn="window", pool="xla"), batch, params)
+        l_pal, g_pal = _loss_and_grads(net, True, batch, params)
+        l_xla, g_xla = _loss_and_grads(net, False, batch, params)
     # both paths quantize the LRN output to bf16 once; differences are
     # accumulation-order ulps inside the f32 normalizer
     assert l_pal == pytest.approx(l_xla, rel=2e-2)
@@ -345,61 +351,28 @@ def test_net_level_pallas_lrn_parity_bf16():
             rtol=5e-2, atol=5e-3, err_msg=str(kp))
 
 
-def test_net_level_pallas_pool_parity_bf16():
-    """Same wiring pin for the MAX-pool backward kernel."""
-    net, batch, params = _parity_net_and_batch()
-    with precision.policy("bfloat16"):
-        l_pal, g_pal = _loss_and_grads(
-            net, OpsImpl(lrn="window", pool="pallas", interpret=True),
-            batch, params)
-        l_xla, g_xla = _loss_and_grads(
-            net, OpsImpl(lrn="window", pool="xla"), batch, params)
-    # pool forward is reduce_window in BOTH arms; the backward routes every
-    # window's dy to the same first-max element — grads match to bf16 ulps
-    assert l_pal == pytest.approx(l_xla, rel=1e-2)
-    for (kp, gp), (_, gx) in zip(
-            jax.tree_util.tree_leaves_with_path(g_pal),
-            jax.tree_util.tree_leaves_with_path(g_xla)):
-        np.testing.assert_allclose(
-            np.asarray(gp, np.float32), np.asarray(gx, np.float32),
-            rtol=5e-2, atol=5e-3, err_msg=str(kp))
-
-
-def test_pool_auto_gate_degrades_to_xla_not_crash():
-    """'auto' must NEVER die where the shape gate fails — it silently takes
-    the XLA lowering (the explicit fallback); only impl='pallas' is allowed
-    to raise."""
-    from sparknet_tpu.ops.pooling import pool2d
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(
-        (2, 7, 7, 16)).astype(np.float32))  # N=2: fails the 128-lane gate
-    y_auto = pool2d(x, "MAX", 3, 2, 0, impl="auto", interpret=True)
-    y_xla = pool2d(x, "MAX", 3, 2, 0, impl="xla")
-    assert np.array_equal(np.asarray(y_auto), np.asarray(y_xla))
-    with pytest.raises(ValueError, match="unsupported"):
-        pool2d(x, "MAX", 3, 2, 0, impl="pallas", interpret=True)
-
-
-def test_ops_impl_validates_at_construction():
-    """A typo'd knob fails at config/trainer BUILD, not at the first
-    round's trace deep inside jit (the ElasticConfig rule from PR 6)."""
-    with pytest.raises(ValueError, match="unknown lrn impl"):
-        OpsImpl(lrn="palas")
-    with pytest.raises(ValueError, match="unknown pool impl"):
-        OpsImpl(pool="window")
-
-
 def test_ops_knobs_thread_through_trainer(net, solver_cfg):
-    """RunConfig-style OpsImpl reaches the compiled round AND survives an
-    elastic resize (resized() carries donate_batches + ops)."""
+    """`interpret` (RunConfig.ops_interpret) reaches the compiled round AND
+    survives an elastic resize (resized() carries donate_batches +
+    interpret)."""
     t = ParallelTrainer(net, solver_cfg, make_mesh(N_DEV), tau=TAU,
-                        donate_batches=True,
-                        ops=OpsImpl(lrn="window", pool="xla"))
-    assert t.ops.lrn == "window"
+                        donate_batches=True, interpret=True)
+    assert t.interpret is True
     s = t.init_state(jax.random.PRNGKey(0))
     s, loss = t.train_round(s, make_round_batches(0), jax.random.PRNGKey(1))
     assert np.isfinite(float(loss))
     t2 = t.resized(2)
-    assert t2.ops == t.ops and t2.donate_batches
+    assert t2.interpret is True and t2.donate_batches
+
+
+@pytest.mark.parametrize("op", ["pool", "lrn"])
+def test_run_config_rejects_the_removed_kernel_knobs(op):
+    """A stale config file is input from outside: which kernel an op runs
+    is no option any more (ops/ decides), and a file that still sets
+    `<op>_impl` fails at parse with the unknown-key error, not silently."""
+    from sparknet_tpu.utils.config import RunConfig
+    with pytest.raises(ValueError, match="unknown config keys"):
+        RunConfig.from_dict({f"{op}_impl": "xla"})
 
 
 # -- loop-level wiring: the knobs through train() ----------------------------
@@ -451,56 +424,73 @@ def test_train_loop_levers_do_not_change_the_trajectory(tmp_path):
 # -- r8: fused τ-boundary + async collect ------------------------------------
 
 
+def _assert_arms_bitwise(ref, s_ref, l_ref, fused, s_fus, l_fus, what):
+    """The arm-against-arm pin, while both arms exist: losses, params,
+    momentum AND the health scalars of one round, bit for bit."""
+    assert float(l_ref) == float(l_fus), what
+    assert_trees_bitwise(s_ref, s_fus, what)
+    for k in ("grad_norm", "nonfinite", "nonfinite_by_worker"):
+        assert np.array_equal(np.asarray(ref.last_health[k]),
+                              np.asarray(fused.last_health[k])), (what, k)
+
+
 # τ=2 is the scan of ONE step before the peeled one: the smallest round in
 # which reading rows by index and slicing the stack could differ
+@pytest.mark.parametrize("fused_arm", [False, True],
+                         ids=["unfused", "fused"])
 @pytest.mark.parametrize("tau", [TAU, 2])
 def test_fused_boundary_bitwise_multi_round(net, solver_cfg, trainer_cls,
-                                            tau):
+                                            tau, fused_arm):
     """The r8 fused τ-boundary (final scan step peeled so the boundary
     pmean — and the ZeRO re-shard under the named trainer — traces in the
     same region as the last optimizer update) must be a pure
     RESTRUCTURING: the same ops on the same values in the same order.
-    Pinned bitwise against the unfused two-step round over a multi-round
-    trajectory — losses, params, momentum, AND the health scalars —
-    under BOTH trainer impls (the conftest trainer_cls matrix)."""
+    The arm named by `fused_arm` is held, round by round over a
+    multi-round trajectory, to the serial reference of a round (weights,
+    momentum, clock, loss, health scalars); and while both arms exist they
+    are pinned bitwise against each other — under BOTH trainer impls (the
+    conftest trainer_cls matrix)."""
     mesh = make_mesh(N_DEV)
     ref = trainer_cls(net, solver_cfg, mesh, tau=tau)
     fused = trainer_cls(net, solver_cfg, mesh, tau=tau,
                         fused_boundary=True)
     assert ref.fused_boundary is False and fused.fused_boundary is True
+    arm = fused if fused_arm else ref
     s_ref = ref.init_state(jax.random.PRNGKey(0))
     s_fus = fused.init_state(jax.random.PRNGKey(0))
     for rnd in range(4):
         batches = make_round_batches(rnd, tau)
         key = jax.random.PRNGKey(rnd)
+        start = round_oracle.split_state(arm, s_fus if fused_arm else s_ref)
         s_ref, l_ref = ref.train_round(s_ref, batches, key)
         s_fus, l_fus = fused.train_round(s_fus, batches, key)
-        assert float(l_ref) == float(l_fus), rnd
-        for (ka, a), (_, b) in zip(
-                jax.tree_util.tree_leaves_with_path(s_ref),
-                jax.tree_util.tree_leaves_with_path(s_fus)):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), (rnd, ka)
-        for k in ("grad_norm", "nonfinite", "nonfinite_by_worker"):
-            assert np.array_equal(np.asarray(ref.last_health[k]),
-                                  np.asarray(fused.last_health[k])), \
-                (rnd, k)
+        s_arm, l_arm = (s_fus, l_fus) if fused_arm else (s_ref, l_ref)
+        round_oracle.assert_round_matches(arm, start, s_arm, l_arm, batches,
+                                          key)
+        _assert_arms_bitwise(ref, s_ref, l_ref, fused, s_fus, l_fus, rnd)
 
 
+@pytest.mark.parametrize("fused_arm", [False, True],
+                         ids=["unfused", "fused"])
 @pytest.mark.parametrize("kw,tau,tbw", [
     ({}, 1, None),
     ({"elastic_tau": True}, TAU, [1, TAU, 2, TAU]),
     ({"elastic_tau": True}, 2, [1, 2, 2, 1]),
 ], ids=["tau1", "elastic-tau3", "elastic-tau2"])
 def test_fused_boundary_tau1_and_elastic_masked(net, solver_cfg,
-                                                trainer_cls, kw, tau, tbw):
+                                                trainer_cls, kw, tau, tbw,
+                                                fused_arm):
     """Edge geometry: τ=1 compiles the fused round scan-free, and an
     elastic_tau-masked round (per-worker budgets, the peeled final step
-    masked off for short-budget workers) still pins bitwise against the
-    unfused trainer fed the same tau vector."""
+    masked off for short-budget workers). The arm named by `fused_arm` is
+    held to the serial reference in which a worker with a budget simply
+    stops there; the two arms, fed the same tau vector, still pin
+    bitwise."""
     mesh = make_mesh(N_DEV)
     ref = trainer_cls(net, solver_cfg, mesh, tau=tau, **kw)
     fused = trainer_cls(net, solver_cfg, mesh, tau=tau,
                         fused_boundary=True, **kw)
+    arm = fused if fused_arm else ref
     s_ref = ref.init_state(jax.random.PRNGKey(1))
     s_fus = fused.init_state(jax.random.PRNGKey(1))
     r = np.random.default_rng(5)
@@ -510,16 +500,16 @@ def test_fused_boundary_tau1_and_elastic_masked(net, solver_cfg,
     batches["label"] = (batches["data"].sum(-1, keepdims=True)
                         > 0).astype(np.int32)
     extra = {"tau_by_worker": tbw} if tbw is not None else {}
+    start = round_oracle.split_state(arm, s_fus if fused_arm else s_ref)
     s_ref, l_ref = ref.train_round(s_ref, batches,
                                    jax.random.PRNGKey(2), **extra)
     s_fus, l_fus = fused.train_round(s_fus, batches,
                                      jax.random.PRNGKey(2), **extra)
-    assert float(l_ref) == float(l_fus), (tau, tbw)
-    for (ka, a), (_, b) in zip(
-            jax.tree_util.tree_leaves_with_path(s_ref),
-            jax.tree_util.tree_leaves_with_path(s_fus)):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), \
-            (tau, tbw, ka)
+    s_arm, l_arm = (s_fus, l_fus) if fused_arm else (s_ref, l_ref)
+    round_oracle.assert_round_matches(arm, start, s_arm, l_arm, batches,
+                                      jax.random.PRNGKey(2),
+                                      tau_by_worker=tbw)
+    _assert_arms_bitwise(ref, s_ref, l_ref, fused, s_fus, l_fus, (tau, tbw))
 
 
 def test_fused_boundary_resize_carries_knob(net, solver_cfg, trainer_cls):
