@@ -398,8 +398,9 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
         # jitted round's cache size (churn = recompiles) live-read
         dev_tel = obs_device.DeviceTelemetry(registry)
         obs_device.attach_compile_metrics(registry)
-        # sparknet_train_round_{temp,argument,output}_bytes and
-        # ..._recompute_core_forward_in_backward, once a profile_dir run
+        # sparknet_train_round_{temp,argument,output}_bytes,
+        # ..._recompute_core_forward_in_backward and
+        # ..._attention_moves_*, once a profile_dir run
         # has asked the round program for its report
         obs_device.attach_program_gauges(registry)
         # sparknet_moe_*{layer}: the expert layers' counters of the last
@@ -512,10 +513,9 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                                               if monitor else 0),
                                 "phase_means": timers.summary(),
                                 # {} until a profile_dir run has asked
-                                "program_memory":
-                                    obs_device.program_memory(),
-                                "program_recompute":
-                                    obs_device.program_recompute(),
+                                **{f"program_{part}":
+                                   obs_device.program_part(part)
+                                   for part in obs_device.REPORT_PARTS},
                                 # {} for a net whose layers count nothing
                                 "round_counters": (
                                     trainer.counter_values()

@@ -175,6 +175,18 @@ class CompiledNet:
             l for l in self.spec.layers_for_phase("TRAIN")
             if l.block is not None)}
 
+    def attention_scopes(self) -> Tuple[Dict[str, str], int]:
+        """({layer type: the scope under such a layer's own that holds its
+        latent attention}, the positions those layers attend over) for the
+        types of this net's layers in `seq_layers.ATTENTION_SCOPES`; ({}, 0)
+        for a net without any."""
+        from .seq_layers import ATTENTION_SCOPES
+        layers = [l for l in self.spec.layers_for_phase("TRAIN")
+                  if l.type in ATTENTION_SCOPES]
+        return ({l.type: ATTENTION_SCOPES[l.type] for l in layers},
+                max((self.blob_shapes[l.bottoms[0]][1] for l in layers),
+                    default=0))
+
     # -- execution ----------------------------------------------------------
 
     def apply(self, params: PyTree, batch: Dict[str, jnp.ndarray], *,

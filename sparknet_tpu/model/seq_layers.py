@@ -19,6 +19,11 @@ an expert meet its weights). Off the chip, at test sizes and under
 `ops_interpret`, both take the exact path: `ops.attention.attention` and
 `lax.ragged_dot`.
 
+What the attention core reads is laid out where that is free, on the weights
+(`mla`): views of the stored matrices whose products come out heads first,
+rotary columns half-split, q scaled, so no activation is sliced at a stride,
+transposed or scaled between a projection and the core.
+
 A layer may name a value that is dear to compute again and cheap to keep
 (`KEPT_NAMES`, by layer type): the recomputation block such a layer stands in
 (`LayerSpec.block`, `CompiledNet.apply`) keeps the named values for the
@@ -184,21 +189,36 @@ def init_mlattention(key, layer: LayerSpec, in_shapes) -> Params:
     return init_mla(key, layer.mla, in_shapes[0][-1])
 
 
-def rotary(x, theta: float):
-    """Rotary position embedding over the whole last axis of x
-    [rows, positions, ..., d], position = index along axis 1. Pairs are
-    (x[2i], x[2i+1]) (interleaved, as the public DeepSeek-V3-family code
-    reads its weights); the result is laid out half-split, which no dot
-    product of two vectors rotated alike can tell."""
-    d, n = x.shape[-1], x.shape[1]
-    inv = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+def half_split(w):
+    """The last axis's interleaved pairs (w[2i], w[2i+1]) laid out evens
+    first, then odds: a static permutation, applied to a weight's columns
+    so that no activation is ever sliced at a stride."""
+    d = w.shape[-1]
+    return jnp.swapaxes(w.reshape(w.shape[:-1] + (d // 2, 2)),
+                        -1, -2).reshape(w.shape)
+
+
+def rotary(x, theta: float, rope: int):
+    """Rotary position embedding over the last `rope` lanes of x
+    [..., positions, d], laid out half-split: pair i is (x[d - rope + i],
+    x[d - rope/2 + i]), turned by position * theta^(-2i/rope), and stays
+    where it was; the lanes before pass. The public DeepSeek-V3-family code
+    pairs (x[2i], x[2i+1]): `half_split` of the columns of the weight that
+    makes x turns the one into the other, and no dot product of two vectors
+    permuted alike can tell. Written over the whole width, x * c + (x with
+    the halves exchanged) * s, contiguous slices alone: no gather forward,
+    no scatter backward, and on a v5e 0.8 ms a block-step faster than
+    slice, turn, concatenate (PERF.md section 6, PR 30)."""
+    d, n, half = x.shape[-1], x.shape[-2], rope // 2
+    inv = 1.0 / (theta ** (np.arange(0, rope, 2, dtype=np.float64) / rope))
     ang = jnp.asarray(np.arange(n)[:, None] * inv[None, :], jnp.float32)
-    ang = ang.reshape((1, n) + (1,) * (x.ndim - 3) + (d // 2,))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., 0::2], x32[..., 1::2]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    cos, sin, one = jnp.cos(ang), jnp.sin(ang), jnp.ones((n, d - rope))
+    c = jnp.concatenate([one, cos, cos], axis=-1)
+    s = jnp.concatenate([jnp.zeros_like(one), -sin, sin], axis=-1)
+    exchanged = jnp.concatenate([x[..., :d - rope], x[..., d - half:],
+                                 x[..., d - rope:d - half]], axis=-1)
+    return (x.astype(jnp.float32) * c
+            + exchanged.astype(jnp.float32) * s).astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=8)
@@ -219,39 +239,60 @@ def _splash(heads: int, positions: int):
 
 
 def attention_core(q, k, v, ctx):
-    """Causal softmax(q k^T / sqrt(d)) v over [rows, positions, heads, d].
-    The kernel where it applies (positions a multiple of its tile, head
-    sizes of whole lanes); else the exact path, which materialises the
-    scores."""
-    n, d = q.shape[1], q.shape[-1]
+    """Causal softmax(q k^T) v over [rows, heads, positions, d], heads
+    first as the kernel reads and writes them; q comes scaled (`mla` folds
+    1/sqrt(d) into its weight). The kernel where it applies (positions a
+    multiple of its tile, head sizes of whole lanes); else the exact path,
+    which materialises the scores."""
+    n, d = q.shape[2], q.shape[-1]
     if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0 and d % 128 == 0
             and v.shape[-1] % 128 == 0 and q.dtype == jnp.bfloat16):
-        heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-        scale = jnp.asarray(1.0 / np.sqrt(d), q.dtype)
-        o = jax.vmap(_splash(q.shape[2], n))(
-            heads_first(q * scale), heads_first(k), heads_first(v))
-        return heads_first(o)
-    return checkpoint_name(attention_ops.attention(q, k, v, causal=True),
-                           ATTN_CORE)
+        return jax.vmap(_splash(q.shape[1], n))(q, k, v)
+    swap = lambda x: jnp.swapaxes(x, 1, 2)  # the exact path's positions-first
+    return checkpoint_name(swap(attention_ops.attention(
+        swap(q), swap(k), swap(v), causal=True, scale=1.0)), ATTN_CORE)
+
+
+def _project(spec: str, x, w):
+    """einsum of an activation with a view of a weight, under the
+    precision policy."""
+    return jnp.einsum(spec, precision.cast_in(x), precision.cast_in(w),
+                      precision=precision.matmul_precision(),
+                      preferred_element_type=precision.preferred_out())
 
 
 def mla(p: MLAttentionParam, params: Params, x, ctx):
-    r, n, _ = x.shape
+    """Multi-head latent attention. The layout of q, k and v is decided on
+    the weights, where it is free: views of the stored matrices (published
+    shapes and column order), made each step, whose products come out heads
+    first, [rows, heads, positions, d], the rotary columns half-split and q
+    scaled -- what the core reads. v goes from its product to the core
+    untouched, q through the rotary turn alone, k through the concatenation
+    that sets the one shared rotary key beside every head's keys, and the
+    output projection contracts (heads, d) of the core's result as it
+    lies."""
     h, nope, rope = p.num_heads, p.qk_nope_head_dim, p.qk_rope_head_dim
+    rank = p.kv_lora_rank
+    w_q = params["q_b"].reshape(-1, h, nope + rope)
+    w_q = jnp.concatenate([w_q[..., :nope], half_split(w_q[..., nope:])],
+                          axis=-1) / np.sqrt(nope + rope)
+    w_kv = params["kv_b"].reshape(rank, h, nope + p.v_head_dim)
+    w_k_rope = half_split(params["kv_a"][:, rank:])[:, None, :]  # one "head"
+    w_o = params["o"].reshape(h, p.v_head_dim, -1)
+
     c_q = _rms(_dot(x, params["q_a"]), params["q_a_norm"], p.eps)
-    q = _dot(c_q, params["q_b"]).reshape(r, n, h, nope + rope)
-    kv_a = _dot(x, params["kv_a"])
-    c_kv = _rms(kv_a[..., :p.kv_lora_rank], params["kv_a_norm"], p.eps)
-    kv = _dot(c_kv, params["kv_b"]).reshape(r, n, h, nope + p.v_head_dim)
-    q_rope = rotary(q[..., nope:], p.rope_theta)
-    k_rope = rotary(kv_a[..., p.kv_lora_rank:], p.rope_theta)  # one, shared
-    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    c_kv = _rms(_dot(x, params["kv_a"][:, :rank]), params["kv_a_norm"], p.eps)
+    heads_first = "rnc,chd->rhnd"
+    q = rotary(_project(heads_first, c_q, w_q), p.rope_theta, rope)
+    k_rope = rotary(_project(heads_first, x, w_k_rope), p.rope_theta, rope)
+    k_nope = _project(heads_first, c_kv, w_kv[..., :nope])
     k = jnp.concatenate(
-        [kv[..., :nope],
-         jnp.broadcast_to(k_rope[:, :, None, :], (r, n, h, rope))], axis=-1)
+        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + (rope,))],
+        axis=-1)
+    v = _project(heads_first, c_kv, w_kv[..., nope:])
     with jax.named_scope("core"):
-        o = attention_core(q, k, kv[..., nope:], ctx)
-    return _dot(o.reshape(r, n, h * p.v_head_dim), params["o"])
+        o = attention_core(q, k, v, ctx)
+    return _project("rhnd,hdm->rnm", o, w_o)
 
 
 def apply_mlattention(layer: LayerSpec, params: Params, inputs, ctx):
@@ -480,6 +521,10 @@ KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,)}
 #: compiled program's text has it: run again in the backward pass only if
 #: the name did not reach a recomputation block's policy
 KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
+#: layer type -> the named scope, under the layer's own, that holds its
+#: latent attention ("": the whole layer): whose device ops
+#: `obs.device.attention_moves` counts
+ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention"}
 
 SEQ_LAYER_IMPLS = {
     "Embed": (init_embed, apply_embed, infer_embed),

@@ -42,6 +42,7 @@ registries die normally.
 """
 from __future__ import annotations
 
+import math
 import re
 import threading
 import time
@@ -231,6 +232,11 @@ _CALLED = re.compile(
     r"\b(calls|to_apply|select|scatter|body|condition|branch_computations|"
     r"true_computation|false_computation)=\{?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
 _LAYER = re.compile(r"(?:^|[/(])([A-Z][A-Za-z0-9]*)/([A-Za-z0-9_.\-]+)")
+_SHAPE = re.compile(r"\b(pred|bf16|[a-z]\d+\w*)\[([\d,]*)\]")
+#: what is no device op of its own, or moves nothing: an `-done` is counted
+#: at its `-start`
+_NO_BYTES = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+             "iota", "after-all", "partition-id", "replica-id")
 #: instructions that run a computation of their own: their time is their
 #: body's, listed instruction by instruction
 CONTAINERS = ("while", "conditional", "call")
@@ -239,6 +245,21 @@ CONTAINERS = ("while", "conditional", "call")
 _INLINED = ("calls", "to_apply", "select", "scatter")
 STEP_SCOPE, OPTIMIZER_SCOPE = "tau_step", "solver_update"
 PHASES = ("forward", "backward", "optimizer", "outside_step")
+
+
+def _shapes(types: str) -> List[Tuple[int, Tuple[int, ...]]]:
+    """[(bytes an element, dimensions)] of every array in a result's type as
+    the compiled text prints it (one array, or a tuple of them)."""
+    out = []
+    for dtype, dims in _SHAPE.findall(types):
+        bits = re.search(r"\d+", dtype)
+        out.append((max(1, int(bits.group()) // 8) if bits else 1,
+                    tuple(int(d) for d in dims.split(",") if d)))
+    return out
+
+
+def _nbytes(shapes) -> int:
+    return sum(itemsize * math.prod(dims) for itemsize, dims in shapes)
 
 
 def register_program(name: str, provider) -> None:
@@ -253,11 +274,13 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     (bytes per device, XLA's memory analysis), "ops": {"%fusion.769":
     {"scope", "phase", "layer_type", "layer", "opcode", "computation"},
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
-    "backward", "kept_bytes"}, ...}}` — see `parse_hlo_ops` for the
-    attribution rule and `recompute_report` for what the recomputation
-    blocks keep ({} for a net whose blocks name nothing, or without
-    blocks). None when no such program is registered or it has not been
-    dispatched yet.
+    "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
+    "bytes", "gathers_scatters"}}` — see `parse_hlo_ops` for the
+    attribution rule, `recompute_report` for what the recomputation blocks
+    keep ({} for a net whose blocks name nothing, or without blocks) and
+    `attention_moves` for what a step's latent attention moves without
+    computing ({} for a net without such layers). None when no such
+    program is registered or it has not been dispatched yet.
 
     NEVER on the round path: the first call lowers and compiles the program
     again (a persistent-compile-cache hit where the cache is on, a second
@@ -265,14 +288,13 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     untraced run. The memory numbers then show as the gauges
     `sparknet_<name>_{temp,argument,output}_bytes`, and the kept values'
     kernels that still run in the backward pass as
-    `sparknet_<name>_recompute_core_forward_in_backward`, on the registries
-    `attach_program_gauges` was given; both in `program_memory()` and
-    `program_recompute()`."""
+    `sparknet_<name>_recompute_core_forward_in_backward`, and what its
+    attention moves as `sparknet_<name>_attention_moves_*`, on the registries
+    `attach_program_gauges` was given; all three in `program_part(key)`."""
     provider = _programs.get(name)
     report = provider() if provider is not None else None
     if report is not None:
-        _program_memory[name] = report["memory"]
-        _program_recompute[name] = report["recompute"]
+        _program_parts[name] = {k: report.get(k, {}) for k in REPORT_PARTS}
     return report
 
 
@@ -353,6 +375,7 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
             current.append({
                 "name": "%" + iname.lstrip("%"), "root": root,
                 "opcode": op.group(1) if op else "",
+                "shapes": _shapes(body[:op.start()]) if op else [],
                 "op_name": name.group(1) if name else None,
                 "called": {k: [c.strip().lstrip("%") for c in v.split(",")]
                            for k, v in _CALLED.findall(body)},
@@ -374,6 +397,7 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
             for o in i["operands"]:
                 users.setdefault(o, []).append(i["name"])
             op_name = i["op_name"]
+            fused = [i]
             if i["opcode"] == "fusion":
                 fused = comps.get((i["called"].get("calls") or [""])[0], [])
                 mm = [f for f in fused
@@ -387,6 +411,7 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                 elif op_name is None and root:
                     op_name = root[0]["op_name"]
             own[i["name"]] = op_name
+            extras.setdefault(i["name"], {}).update(_moves(i, by_name, fused))
         for i in instructions:
             if i["opcode"] == "parameter":
                 continue
@@ -398,6 +423,36 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                               "opcode": i["opcode"], "computation": cname,
                               **extras.get(i["name"], {})}
     return ops
+
+
+def _moves(instruction, by_name, fused) -> Dict[str, Any]:
+    """What `attention_moves` reads of one device op: `"bytes"` (its
+    operands' and results', as the text gives their shapes; 0 for what is no
+    op or moves nothing), `"matmul"` (it is, or its fusion `fused` holds, a
+    `dot` or a `convolution`) and, where it is or holds any, `"indexed"`: the
+    dimensions of every `gather`'s and `scatter`'s operands and result."""
+    opcode = instruction["opcode"]
+    result = instruction["shapes"]
+    if opcode in _NO_BYTES or opcode in CONTAINERS or opcode.endswith("-done"):
+        nbytes = 0
+    else:
+        if opcode.endswith("-start"):  # (the result, the operand again, ...)
+            result = result[:1]
+        nbytes = _nbytes(result) + sum(
+            _nbytes(by_name[o]["shapes"]) for o in instruction["operands"]
+            if o in by_name)
+    out = {"bytes": nbytes, "matmul": any(
+        f["opcode"] in ("convolution", "dot") for f in fused)}
+    inside = {f["name"]: f["shapes"] for f in fused}
+    indexed = []
+    for f in fused:
+        if f["opcode"] in ("gather", "scatter"):
+            arrays = f["shapes"] + [s for o in f["operands"]
+                                    for s in inside.get(o, [])]
+            indexed.append(tuple(d for _, dims in arrays for d in dims))
+    if indexed:
+        out["indexed"] = indexed
+    return out
 
 
 def _inherit(instruction, by_name, own, neighbours) -> Optional[str]:
@@ -468,44 +523,89 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
     return out
 
 
+def attention_moves(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
+                    positions: int) -> Dict[str, int]:
+    """What a step's latent attention moves without computing: of the device
+    ops under its scopes (`scopes`: layer type -> the scope under the layer's
+    own that holds its attention, "" for the whole layer;
+    `CompiledNet.attention_scopes()`) that hold neither a matmul nor a kernel
+    -- slices, copies, transposes, concatenations, elementwise passes --
+    `{"instructions", "bytes": their operands' and results' together,
+    "gathers_scatters": the `gather` and `scatter` instructions in them that
+    index an activation (one with the step's `positions`, or the half the
+    TPU compiler splits them into, among its operands' or result's
+    dimensions)}`, in the step body that moves most. {} for a net without
+    such layers. The layout is decided on the weights where
+    "gathers_scatters" is 0."""
+    if not scopes:
+        return {}
+    along = (positions, positions // 2)
+    zero = {"instructions": 0, "bytes": 0, "gathers_scatters": 0}
+    bodies: Dict[str, Dict[str, int]] = {}
+    for op in ops.values():
+        sub = scopes.get(op["layer_type"])
+        if (sub is None or (sub and sub not in op["scope"].split("/"))
+                or op["matmul"] or op["opcode"] == "custom-call"
+                or not op["bytes"]):
+            continue
+        body = bodies.setdefault(op["computation"], dict(zero))
+        body["instructions"] += 1
+        body["bytes"] += op["bytes"]
+        body["gathers_scatters"] += sum(
+            any(d in along for d in dims) for dims in op.get("indexed", ()))
+    return max(bodies.values(), key=lambda b: b["bytes"], default=zero)
+
+
 def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
-                       jaxpr=None) -> Dict[str, Any]:
+                       jaxpr=None, attention=({}, 0)) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
-    returns): its memory analysis, `parse_hlo_ops` of its text and, for the
-    names its net's recomputation blocks keep, `recompute_report`."""
+    returns): its memory analysis, `parse_hlo_ops` of its text, for the
+    names its net's recomputation blocks keep `recompute_report`, and for
+    its latent-attention layers (`attention`: their scopes and positions)
+    `attention_moves`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
                        for k in ("argument", "output", "alias", "temp")},
             "ops": ops,
-            "recompute": recompute_report(ops, kept_kernels or {}, jaxpr)}
+            "recompute": recompute_report(ops, kept_kernels or {}, jaxpr),
+            "attention_moves": attention_moves(ops, *attention)}
 
 
-#: program -> its report's memory and recompute parts, once `program_report`
-#: has run
-_program_memory: Dict[str, Dict[str, int]] = {}
-_program_recompute: Dict[str, Dict[str, Dict[str, Any]]] = {}
+#: program -> these parts of its report, once `program_report` has run
+REPORT_PARTS = ("memory", "recompute", "attention_moves")
+_program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
 def attach_program_gauges(registry: MetricsRegistry,
                           name: str = "train_round") -> None:
-    """Show `sparknet_<name>_{temp,argument,output}_bytes` and
-    `sparknet_<name>_recompute_core_forward_in_backward` on this registry's
-    /metrics: live-read gauges with no sample until `program_report(name)`
-    has run (they never ask for it themselves)."""
+    """Show `sparknet_<name>_{temp,argument,output}_bytes`,
+    `sparknet_<name>_recompute_core_forward_in_backward` and
+    `sparknet_<name>_attention_moves_{bytes,gathers_scatters}` on this
+    registry's /metrics: live-read gauges with no sample until
+    `program_report(name)` has run (they never ask for it themselves)."""
+    part = lambda key: _program_parts[name][key]
     for key in ("temp", "argument", "output"):
         registry.gauge(
             f"sparknet_{name}_{key}_bytes",
             f"the compiled {name} program's {key} bytes per device (XLA "
             f"memory analysis, read by program_report)"
-        ).set_fn(lambda key=key: _program_memory[name][key])
+        ).set_fn(lambda key=key: part("memory")[key])
     registry.gauge(
         f"sparknet_{name}_recompute_core_forward_in_backward",
         f"kernels of values the {name} program's recomputation blocks are "
         f"to keep that run again in a step's backward pass (0: every named "
         f"value is kept; read by program_report)"
-    ).set_fn(lambda: sum(r["backward"]
-                         for r in _program_recompute[name].values()))
+    ).set_fn(lambda: sum(r["backward"] for r in part("recompute").values()))
+    for key, what in (("bytes", "operand and result bytes"),
+                      ("gathers_scatters",
+                       "gathers and scatters that index an activation")):
+        registry.gauge(
+            f"sparknet_{name}_attention_moves_{key}",
+            f"{what} of the device ops under the {name} program's latent "
+            f"attention, a step, that hold neither a matmul nor a kernel "
+            f"(read by program_report)"
+        ).set_fn(lambda key=key: part("attention_moves")[key])
 
 
 def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
@@ -526,13 +626,9 @@ def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
                      trainer.counter_values()[blob][name], layer=layer)
 
 
-def program_memory() -> Dict[str, Dict[str, int]]:
-    """{program: its report's memory part} for every program whose report
-    has been asked for so far — a read of what is cached, never a compile
-    (the /status route)."""
-    return dict(_program_memory)
-
-
-def program_recompute() -> Dict[str, Dict[str, Dict[str, Any]]]:
-    """{program: its report's recompute part}, as `program_memory`."""
-    return dict(_program_recompute)
+def program_part(key: str) -> Dict[str, Dict[str, Any]]:
+    """{program: the `key` part of its report ("memory", "recompute",
+    "attention_moves")} for every program whose report has been asked for
+    so far — a read of what is cached, never a compile (the /status
+    route)."""
+    return {name: parts[key] for name, parts in _program_parts.items()}

@@ -20,12 +20,15 @@ from .. import precision
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = False,
-              bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Exact multi-head attention. Shapes [B, L, H, D] (length-major)."""
-    d = q.shape[-1]
+              bias: Optional[jnp.ndarray] = None,
+              scale: Optional[float] = None) -> jnp.ndarray:
+    """Exact multi-head attention. Shapes [B, L, H, D] (length-major).
+    The scores are divided by sqrt(D), or multiplied by `scale` where one
+    is given (1.0: q comes scaled)."""
     s = jnp.einsum("blhd,bmhd->bhlm", precision.cast_in(q),
                    precision.cast_in(k),
-                   precision=precision.matmul_precision()) / np.sqrt(d)
+                   precision=precision.matmul_precision())
+    s = s / np.sqrt(q.shape[-1]) if scale is None else s * scale
     s = s.astype(jnp.float32)
     if bias is not None:
         s = s + bias

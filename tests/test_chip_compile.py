@@ -314,17 +314,60 @@ def _seq_ctx():
 
 def test_attention_core_backward_keeps_nothing_of_size_positions_squared(v5e, as_tpu):
     """The latent-attention core at the benchmark's shape (2 rows, 20 heads,
-    8,192 positions, 256 + 256 a head), forward and backward, for a v5e: jax's
-    splash-attention kernels, and no [.., 8192, 8192] tensor anywhere (one
-    layer's float32 scores would be 10.7 GB)."""
+    8,192 positions, 256 + 256 a head, heads first as `mla` hands them over),
+    forward and backward, for a v5e: jax's splash-attention kernels, and no
+    [.., 8192, 8192] tensor anywhere (one layer's float32 scores would be
+    10.7 GB)."""
     from sparknet_tpu.model import seq_layers as sl
     one = SingleDeviceSharding(v5e[0])
-    x = jax.ShapeDtypeStruct((2, 8192, 20, 256), jnp.bfloat16, sharding=one)
+    x = jax.ShapeDtypeStruct((2, 20, 8192, 256), jnp.bfloat16, sharding=one)
     text = _compiled_text(jax.grad(
         lambda q, k, v: sl.attention_core(q, k, v, _seq_ctx()).astype(
             jnp.float32).sum(), argnums=(0, 1, 2)), x, x, x)
     assert text.count("tpu_custom_call") >= 2 and "splash_mha" in text
     assert "8192,8192" not in text
+    # the operands are the kernel's as they come: none is laid out again
+    assert not re.search(r"\[2,20,8192,256\]\S* (transpose|copy)\(", text)
+
+
+def test_attention_block_lays_out_nothing_between_projection_and_core(v5e, as_tpu):
+    """One attention block at GLM-4.7-Flash's widths and the benchmark's
+    shape (norm, latent attention, residual sum: a recomputation block that
+    keeps the core's names), forward + backward, for a v5e, ~15 s: q, k and v
+    leave their projections heads first, so no gather or scatter touches an
+    activation (twelve and four did, from the rotary turn's strided slices),
+    v goes from its matmul into the forward kernel with nothing between, and
+    the block accesses under 19 GB (26.3 before the layout moved into the
+    weights, 15.7 after)."""
+    from test_seq_layers import _attention_block
+    from sparknet_tpu.model.spec import MLAttentionParam
+    from sparknet_tpu.obs.device import attention_moves, parse_hlo_ops
+    net, params, x, loss = _attention_block(MLAttentionParam(
+        num_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6, eps=1e-5),
+        positions=8192, d=2048)
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda l, dtype=None: jax.ShapeDtypeStruct(
+        l.shape, dtype or l.dtype, sharding=one)
+    precision.set_policy("bfloat16")
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree.map(on_chip, params), on_chip(x, jnp.bfloat16)).compile()
+    finally:
+        precision.set_policy("float32")
+    text = compiled.as_text()
+    ops = parse_hlo_ops(text)
+    moves = attention_moves(ops, *net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves
+    assert 0 < moves["bytes"] < 10e9, moves  # 17.5 GB with the strided slices
+    fwd = re.search(r"(%splash_mha_fwd_residuals[\w.]*) = .*? custom-call\(([^)]*)\)",
+                    text)
+    assert "transpose(" not in ops[fwd.group(1)]["scope"]  # the forward's own
+    straight = [o for o in re.findall(r"%[\w.\-]+", fwd.group(2))
+                if ops[o]["matmul"]]
+    assert len(straight) == 1, f"v passes through something: {fwd.group(2)}"
+    accessed = compiled.cost_analysis()["bytes accessed"]
+    assert accessed < 19e9, f"the block accesses {accessed / 1e9:.2f} GB"
 
 
 def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu):
@@ -353,10 +396,12 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`: the
     published widths, 2 x 8,192 tokens a step, tau=4, bf16, donated, fused
     boundary, health off) for one described chip: ~2 min. 5.65 GB of state
-    + ~6.9 GB of temporaries (the gradient is 2.83 GB of them; what the six
+    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
     attention cores keep for the backward 1.01 GB, and their statistics
-    as the kernel writes them, padded to 128 lanes, 1.0 GB more). Each
-    step body runs the cores' forward kernel on its forward path alone."""
+    as the kernel writes them, padded to 128 lanes, 1.0 GB more; 6.9 GB
+    while q, k and v were laid out again between projection and core). Each
+    step body runs the cores' forward kernel on its forward path alone, and
+    no gather or scatter in its attention touches an activation."""
     import json
     from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.utils.config import RunConfig
@@ -387,10 +432,15 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert total < 13e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    assert total < 11.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
-    from sparknet_tpu.obs.device import parse_hlo_ops, recompute_report
-    kept = recompute_report(parse_hlo_ops(text), trainer.net.kept_kernels())
+    from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
+                                         recompute_report)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_kernels())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (6, 0)
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves  # 96 before PR 30
+    assert moves["bytes"] < 57e9, moves  # 94.9 GB a step body before, 42.8 now

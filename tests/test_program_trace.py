@@ -212,7 +212,7 @@ def test_program_report_publishes_memory_gauges_when_it_has_run(lenet_report):
         assert registry.gauge(f"sparknet_train_round_{key}_bytes").value() \
             == float(report["memory"][key])
     assert "sparknet_train_round_temp_bytes" in registry.render_prometheus()
-    assert obs_device.program_memory()["train_round"] == report["memory"]
+    assert obs_device.program_part("memory")["train_round"] == report["memory"]
     assert obs_device.program_report("no_such_program") is None
 
 

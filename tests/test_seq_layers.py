@@ -166,25 +166,37 @@ def test_masked_softmax_loss_by_hand(shift, weight):
 # -- the causal mask and the rotary embedding against a direct formula -------
 
 def test_rotary_against_the_direct_formula():
-    x = np.asarray(_x(9, (1, 5, 3, 8)))
-    got = np.asarray(sl.rotary(jnp.asarray(x), 1e6))
+    """The half-split turn of columns `half_split` has de-interleaved is the
+    direct formula on interleaved pairs, under the same permutation; lanes
+    before the last `rope` pass untouched."""
+    x = np.asarray(_x(9, (1, 3, 5, 12)))  # [rows, heads, positions, 4 + 8]
+    split = jnp.concatenate([x[..., :4], sl.half_split(jnp.asarray(x[..., 4:]))], -1)
+    assert np.array_equal(split[..., 4:8], x[..., 4::2])
+    assert np.array_equal(split[..., 8:], x[..., 5::2])
+    got = np.asarray(sl.rotary(split, 1e6, 8))
+    assert np.array_equal(got[..., :4], x[..., :4])
     for pos in range(5):
         for i in range(4):  # pair (x[2i], x[2i+1]) turned by pos * theta^(-2i/d)
             ang = pos * 1e6 ** (-2 * i / 8)
-            a, b = x[0, pos, :, 2 * i], x[0, pos, :, 2 * i + 1]
-            assert np.allclose(got[0, pos, :, i], a * np.cos(ang) - b * np.sin(ang), atol=1e-5)
-            assert np.allclose(got[0, pos, :, 4 + i], b * np.cos(ang) + a * np.sin(ang), atol=1e-5)
+            a, b = x[0, :, pos, 4 + 2 * i], x[0, :, pos, 4 + 2 * i + 1]
+            assert np.allclose(got[0, :, pos, 4 + i], a * np.cos(ang) - b * np.sin(ang), atol=1e-5)
+            assert np.allclose(got[0, :, pos, 8 + i], b * np.cos(ang) + a * np.sin(ang), atol=1e-5)
     # what attention sees depends on the distance alone
-    q, k = _x(10, (1, 9, 8)), _x(11, (1, 9, 8))
-    same = lambda s: float(jnp.dot(sl.rotary(jnp.roll(q, s, 1), 1e4)[0, 4 + s],
-                                   sl.rotary(jnp.roll(k, s, 1), 1e4)[0, 2 + s]))
+    q, k = sl.half_split(_x(10, (1, 9, 8))), sl.half_split(_x(11, (1, 9, 8)))
+    same = lambda s: float(jnp.dot(sl.rotary(jnp.roll(q, s, 1), 1e4, 8)[0, 4 + s],
+                                   sl.rotary(jnp.roll(k, s, 1), 1e4, 8)[0, 2 + s]))
     assert same(0) == pytest.approx(same(3), rel=1e-4)
-    assert np.allclose(ref.rotary(q[0], 1e4), sl.rotary(q, 1e4)[0], atol=1e-6)
+    # the reference turns interleaved pairs and writes them out half-split
+    assert np.allclose(ref.rotary(_x(10, (9, 8)), 1e4),
+                       sl.rotary(sl.half_split(_x(10, (1, 9, 8))), 1e4, 8)[0], atol=1e-6)
 
 
 def test_attention_core_is_causal_and_exact():
     q, k, v = _x(12, (1, 6, 2, 8)), _x(13, (1, 6, 2, 8)), _x(14, (1, 6, 2, 4))
-    got = np.asarray(sl.attention_core(q, k, v, CTX))
+    # the core reads heads first, and q scaled
+    core = lambda q, k, v: jnp.swapaxes(sl.attention_core(
+        *(jnp.swapaxes(t, 1, 2) for t in (q / np.sqrt(8), k, v)), CTX), 1, 2)
+    got = np.asarray(core(q, k, v))
     for h in range(2):
         for i in range(6):
             s = np.asarray(q)[0, i, h] @ np.asarray(k)[0, :i + 1, h].T / np.sqrt(8)
@@ -193,7 +205,7 @@ def test_attention_core_is_causal_and_exact():
             assert np.allclose(got[0, i, h], want, atol=1e-5)
     # a later key changes no earlier position
     k2 = k.at[0, 5].add(3.0)
-    again = np.asarray(sl.attention_core(q, k2, v, CTX))
+    again = np.asarray(core(q, k2, v))
     assert np.array_equal(again[0, :5], got[0, :5]) and not np.allclose(again[0, 5], got[0, 5])
     # the reference's blocked core is the same function
     with jax.default_matmul_precision("highest"):
@@ -201,6 +213,81 @@ def test_attention_core_is_causal_and_exact():
             blocked = ref.causal_attention(q[0], k[0], v[0], "float32",
                                            block=block, groups=groups)
             assert np.allclose(blocked, got[0], atol=1e-5), (block, groups)
+
+
+# -- the layout lives in the weights: the stored parameters see nothing ------
+
+def _plain_mla(p, params, x):
+    """Latent attention as the published code writes it: positions first,
+    heads split and sliced on the activations, interleaved rotary pairs
+    (x[2i], x[2i+1]), the scores scaled, an exact causal softmax. float32."""
+    hi = dict(precision=jax.lax.Precision.HIGHEST)
+    r, n, _ = x.shape
+    h, nope, rope, dv = (p.num_heads, p.qk_nope_head_dim, p.qk_rope_head_dim,
+                         p.v_head_dim)
+    rms = lambda t, g: t * jax.lax.rsqrt(
+        jnp.mean(jnp.square(t), axis=-1, keepdims=True) + p.eps) * g
+
+    def turn(t):  # [rows, positions, ..., rope], position along axis 1
+        inv = 1.0 / (p.rope_theta ** (np.arange(0, rope, 2, dtype=np.float64) / rope))
+        ang = jnp.asarray(np.arange(n)[:, None] * inv[None, :], jnp.float32)
+        ang = ang.reshape((1, n) + (1,) * (t.ndim - 3) + (rope // 2,))
+        a, b = t[..., 0::2], t[..., 1::2]
+        return jnp.concatenate([a * jnp.cos(ang) - b * jnp.sin(ang),
+                                b * jnp.cos(ang) + a * jnp.sin(ang)], axis=-1)
+
+    q = jnp.dot(rms(jnp.dot(x, params["q_a"], **hi), params["q_a_norm"]),
+                params["q_b"], **hi).reshape(r, n, h, nope + rope)
+    kv_a = jnp.dot(x, params["kv_a"], **hi)
+    kv = jnp.dot(rms(kv_a[..., :p.kv_lora_rank], params["kv_a_norm"]),
+                 params["kv_b"], **hi).reshape(r, n, h, nope + dv)
+    q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+    k_rope = turn(kv_a[..., p.kv_lora_rank:])
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_rope[:, :, None, :], (r, n, h, rope))], axis=-1)
+    s = jnp.einsum("rnhd,rmhd->rhnm", q, k, **hi) / np.sqrt(nope + rope)
+    s = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :], s, -jnp.inf)
+    o = jnp.einsum("rhnm,rmhd->rnhd", jax.nn.softmax(s, axis=-1),
+                   kv[..., nope:], **hi)
+    return jnp.dot(o.reshape(r, n, h * dv), params["o"], **hi)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_mla_and_every_stored_gradient_equal_the_positions_first_formula(seed):
+    """`mla` permutes, splits and scales views of its weights, never the
+    stored matrices: the result and every stored parameter's gradient, in
+    its published shape and column order, are the plain formula's -- a
+    checkpoint written before the layout moved trains on identically."""
+    p = {k: jnp.asarray(v) for k, v in _params(seed, "l0_attn").items()}
+    p = dict(p, q_a_norm=1.0 + 0.1 * _x(seed + 20, p["q_a_norm"].shape),
+             kv_a_norm=1.0 + 0.1 * _x(seed + 21, p["kv_a_norm"].shape))
+    x, weigh = _x(seed + 30), _x(seed + 31)
+    assert {k: v.shape for k, v in p.items()} == {
+        k: v.shape for k, v in sl.init_mla(jax.random.PRNGKey(0), MLA_P, D).items()}
+    loss = lambda fn: lambda p, x: jnp.sum(fn(p, x) * weigh)
+    got, want = (jax.value_and_grad(loss(fn), argnums=(0, 1))(p, x) for fn in (
+        lambda p, x: sl.mla(MLA_P, p, x, CTX),
+        lambda p, x: _plain_mla(MLA_P, p, x)))
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    assert rel(sl.mla(MLA_P, p, x, CTX), _plain_mla(MLA_P, p, x)) < 1e-5
+    assert rel(got[1][1], want[1][1]) < 1e-5
+    for name in p:  # q_a, q_a_norm, q_b, kv_a, kv_a_norm, kv_b, o
+        assert got[1][0][name].shape == p[name].shape
+        assert rel(got[1][0][name], want[1][0][name]) < 1e-5, name
+
+
+def test_the_scale_folded_into_the_weight_gives_bit_equal_bf16_q():
+    """GLM's heads are 256 wide: 1/sqrt(256) = 2^-4 shifts an exponent, so
+    q from the scaled weight is q scaled, to the last bit of every bf16."""
+    c_q = _x(40, (2, 64, 24)).astype(jnp.bfloat16)
+    w = 0.02 * _x(41, (24, 3, 256))
+    with precision.policy("bfloat16"):
+        folded = sl._project("rnc,chd->rhnd", c_q, w / np.sqrt(256))
+        scaled = sl._project("rnc,chd->rhnd", c_q, w) * jnp.bfloat16(1 / 16)
+    assert folded.dtype == jnp.bfloat16 and float(jnp.max(jnp.abs(folded))) > 0
+    assert np.array_equal(np.asarray(folded, np.float32),
+                          np.asarray(scaled, np.float32))
 
 
 # -- the expert layer: shares, drops, counters -------------------------------
@@ -424,7 +511,7 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     assert report["recompute"] == {sl.ATTN_CORE: {
         "kernel": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": 4 * ROWS * POS * MLA_P.num_heads * MLA_P.v_head_dim * 4}}
-    assert obs_device.program_recompute()["train_round"] == report["recompute"]
+    assert obs_device.program_part("recompute")["train_round"] == report["recompute"]
     with pytest.raises(ValueError, match="model_type"):
         path.write_text(json.dumps(dict(TINY, model_type="other")))
         resolve_spec(cfg)
@@ -495,12 +582,12 @@ KERNEL_MLA_P = MLAttentionParam(num_heads=2, q_lora_rank=24, kv_lora_rank=16,
 KERNEL_POS = max(sl.ATTN_BLOCKS)
 
 
-def _attention_block(mla_p=MLA_P, positions=POS):
+def _attention_block(mla_p=MLA_P, positions=POS, d=D):
     """(net, params, x, loss) of one recomputation block as a decoder's
     attention half is: norm, latent attention, residual sum."""
     tag = dict(block="b")
     net = CompiledNet.compile(NetSpec(
-        name="blk", inputs=(InputSpec("x", (ROWS, positions, D)),), layers=(
+        name="blk", inputs=(InputSpec("x", (ROWS, positions, d)),), layers=(
             LayerSpec(name="n", type="RMSNorm", bottoms=("x",), tops=("xn",),
                       rmsnorm=RMSNormParam(), **tag),
             LayerSpec(name="a", type="MLAttention", bottoms=("xn",), tops=("y",),
@@ -510,7 +597,7 @@ def _attention_block(mla_p=MLA_P, positions=POS):
     params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
     loss = lambda p, x: jnp.sum(
         net.apply(p, {"x": x}, train=True)["z"].astype(jnp.float32))
-    return net, params, jax.ShapeDtypeStruct((ROWS, positions, D), jnp.float32), loss
+    return net, params, jax.ShapeDtypeStruct((ROWS, positions, d), jnp.float32), loss
 
 
 @pytest.fixture
@@ -550,7 +637,7 @@ def _kept(loss, params, x):
 def test_a_block_keeps_the_cores_output_and_nothing_else_on_the_exact_path():
     _, params, x, loss = _attention_block()
     assert _kept(loss, params, x) == [
-        ((ROWS, POS, MLA_P.num_heads, MLA_P.v_head_dim), "float32")]
+        ((ROWS, MLA_P.num_heads, POS, MLA_P.v_head_dim), "float32")]
 
 
 def test_a_block_keeps_the_cores_output_and_statistics_on_the_kernel_path(
@@ -609,7 +696,92 @@ def test_the_report_counts_a_kept_values_kernel_by_the_pass_it_runs_in():
     obs_device.program_report("a_round")
     assert registry.gauge(
         "sparknet_a_round_recompute_core_forward_in_backward").value() == 1.0
-    assert obs_device.program_recompute()["a_round"] == got
+    assert obs_device.program_part("recompute")["a_round"] == got
+
+
+MOVES_HLO = '''HloModule jit_train_round
+
+%fused_turn (a: f32[2,16,8], i: s32[4]) -> f32[2,16,4] {
+  %a = f32[2,16,8]{2,1,0} parameter(0)
+  %i = s32[4]{0} parameter(1)
+  ROOT %gather.1 = f32[2,16,4]{2,1,0} gather(%a, %i), offset_dims={0,1}, collapsed_slice_dims={2}, start_index_map={2}, index_vector_dim=1, slice_sizes={2,16,1}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MLAttention/l0_attn)/gather"}
+}
+
+%fused_weight (w: f32[12,6], i: s32[4]) -> f32[12,4] {
+  %w = f32[12,6]{1,0} parameter(0)
+  %i = s32[4]{0} parameter(1)
+  ROOT %gather.2 = f32[12,4]{1,0} gather(%w, %i), offset_dims={0}, collapsed_slice_dims={1}, start_index_map={1}, index_vector_dim=1, slice_sizes={12,1}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MLAttention/l0_attn)/gather"}
+}
+
+%fused_dot (x: bf16[2,16,8], w: bf16[8,6]) -> bf16[2,16,6] {
+  %x = bf16[2,16,8]{2,1,0} parameter(0)
+  %w = bf16[8,6]{1,0} parameter(1)
+  ROOT %dot.1 = bf16[2,16,6]{2,1,0} dot(%x, %w), lhs_contracting_dims={2}, rhs_contracting_dims={0}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MLAttention/l0_attn)/dot_general"}
+}
+
+%body.1 (x: bf16[2,16,8], a: f32[2,16,8], w: bf16[8,6], i: s32[4], m: f32[12,6]) -> bf16[2,16,6] {
+  %x = bf16[2,16,8]{2,1,0} parameter(0)
+  %a = f32[2,16,8]{2,1,0} parameter(1)
+  %w = bf16[8,6]{1,0} parameter(2)
+  %i = s32[4]{0} parameter(3)
+  %m = f32[12,6]{1,0} parameter(4)
+  %turn.1 = f32[2,16,4]{2,1,0} fusion(%a, %i), kind=kLoop, calls=%fused_turn
+  %weight.1 = f32[12,4]{1,0} fusion(%m, %i), kind=kLoop, calls=%fused_weight
+  %copy.1 = bf16[2,16,8]{1,2,0} copy(%x), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/attention/transpose"}
+  %copy.2 = bf16[2,16,8]{1,2,0} copy(%x), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/moe/experts/transpose"}
+  %kernel.1 = bf16[2,16,8]{2,1,0} custom-call(%copy.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/attention/core/pallas_call"}
+  %bitcast.1 = bf16[2,16,8]{2,1,0} bitcast(%kernel.1), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/attention/reshape"}
+  ROOT %project.1 = bf16[2,16,6]{2,1,0} fusion(%bitcast.1, %w), kind=kOutput, calls=%fused_dot
+}
+
+ENTRY %main.1 (x: bf16[2,16,8], a: f32[2,16,8], w: bf16[8,6], i: s32[4], m: f32[12,6]) -> bf16[2,16,6] {
+  %x = bf16[2,16,8]{2,1,0} parameter(0)
+  %a = f32[2,16,8]{2,1,0} parameter(1)
+  %w = bf16[8,6]{1,0} parameter(2)
+  %i = s32[4]{0} parameter(3)
+  %m = f32[12,6]{1,0} parameter(4)
+  %copy.3 = bf16[2,16,8]{1,2,0} copy(%x), metadata={op_name="jit(train_round)/tau_step/jvp(MLAttention/l0_attn)/transpose"}
+  ROOT %call.1 = bf16[2,16,6]{2,1,0} call(%x, %a, %w, %i, %m), to_apply=%body.1
+}
+'''
+
+
+def test_attention_moves_counts_what_attention_moves_without_computing():
+    """In the loop's body: a fusion that gathers along an activation's lanes
+    ([rows 2, positions 16, 8] -> 4), one that gathers a weight's columns,
+    and a copy, under the two kinds of attention scope; a copy under the MTP
+    module's experts, a kernel, a bitcast and a matmul fusion, none of which
+    count. The peeled step holds one copy: the body that moves most is the
+    one reported."""
+    from sparknet_tpu.obs import MetricsRegistry
+    from sparknet_tpu.obs import device as obs_device
+    ops = obs_device.parse_hlo_ops(MOVES_HLO)
+    assert ops["%project.1"]["matmul"] and not ops["%copy.1"]["matmul"]
+    assert ops["%copy.1"]["bytes"] == 2 * 2 * 16 * 8 * 2
+    assert ops["%turn.1"]["bytes"] == 4 * (2 * 16 * 8 + 4 + 2 * 16 * 4)
+    assert ops["%bitcast.1"]["bytes"] == 0
+    got = obs_device.attention_moves(ops, sl.ATTENTION_SCOPES, positions=16)
+    assert got == {"instructions": 3, "gathers_scatters": 1,
+                   "bytes": ops["%turn.1"]["bytes"] + ops["%weight.1"]["bytes"]
+                   + ops["%copy.1"]["bytes"]}
+    # the compiler may split the positions in two; a weight's axis is no position
+    assert obs_device.attention_moves(ops, sl.ATTENTION_SCOPES, 32)["gathers_scatters"] == 1
+    assert obs_device.attention_moves(ops, sl.ATTENTION_SCOPES, 8)["gathers_scatters"] == 2
+    assert obs_device.attention_moves(ops, {}, 0) == {}
+    # the net says which scopes and how many positions
+    assert _net().attention_scopes() == (sl.ATTENTION_SCOPES, POS)
+    # ... and the gauges beside the program's memory gauges read it
+    obs_device.register_program("b_round", lambda: {
+        "memory": {"temp": 1, "argument": 2, "output": 3}, "ops": ops,
+        "recompute": {}, "attention_moves": got})
+    registry = MetricsRegistry()
+    obs_device.attach_program_gauges(registry, "b_round")
+    assert "\nsparknet_b_round_attention_moves_bytes " not in \
+        registry.render_prometheus(), "no sample until the report has run"
+    obs_device.program_report("b_round")
+    assert registry.gauge("sparknet_b_round_attention_moves_bytes").value() == got["bytes"]
+    assert registry.gauge("sparknet_b_round_attention_moves_gathers_scatters").value() == 1.0
+    assert obs_device.program_part("attention_moves")["b_round"] == got
 
 
 # -- the compiled text's multi-line instructions -----------------------------
