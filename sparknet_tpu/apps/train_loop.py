@@ -72,8 +72,10 @@ def resolve_spec(cfg: RunConfig, **input_shapes) -> NetSpec:
     (capability parity: the reference's apps loaded prototxt data files,
     `apps/CifarApp.scala:83-88`), or a .json path: a sequence model's
     published config.json keys as run here plus the `share` block that says
-    which part of an expert-parallel deployment this worker holds
-    (`zoo.glm4_moe_lite`). Rows a step are cfg.local_batch; positions the
+    which part of an expert-parallel deployment this worker holds (the
+    file's `model_type` picks the builder: `zoo.SEQUENCE_MODELS`, whose
+    decoders' layer kinds follow the file's own keys). Rows a step are
+    cfg.local_batch; positions the
     `tokens` shape given here, else the file's `seq_len`."""
     from .. import zoo
     from ..model.prototxt import net_from_prototxt_file

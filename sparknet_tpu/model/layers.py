@@ -39,9 +39,11 @@ def tp_shards_layer(layer: "LayerSpec", tp_size: int) -> bool:
     """THE tensor-parallel sharding convention, shared by the forward pass
     (ApplyCtx.tp_shards) and the trainer's state construction
     (ParallelTrainer._tp_sharded_layers): an InnerProduct layer is
-    column-sharded iff tp_size divides its num_output; everything else is
+    column-sharded iff tp_size divides its num_output (a tied head's matrix,
+    stored transposed, is its embedding's and is not); everything else is
     replicated across the model axis."""
     return (tp_size > 1 and layer.type == "InnerProduct"
+            and not layer.inner_product.transposed
             and layer.inner_product.num_output % tp_size == 0)
 
 
@@ -310,8 +312,10 @@ def init_innerproduct(key, layer: LayerSpec, in_shapes) -> Params:
     p = layer.inner_product
     fan_in = in_shapes[0][-1] if p.axis == -1 else _flat_dim(in_shapes[0])
     wkey, bkey = jax.random.split(key)
-    # Stored (in, out): feeds the MXU directly as x @ w.
-    params = {"w": fill(wkey, p.weight_filler, (fan_in, p.num_output), fan_in)}
+    # Stored (in, out): feeds the MXU directly as x @ w. `transposed`: (out,
+    # in), an embedding's table as it lies.
+    shape = (p.num_output, fan_in) if p.transposed else (fan_in, p.num_output)
+    params = {"w": fill(wkey, p.weight_filler, shape, fan_in)}
     if p.bias_term:
         params["b"] = fill(bkey, p.bias_filler, (p.num_output,), fan_in)
     return params
@@ -326,8 +330,12 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
             x = jnp.transpose(x, (0, 3, 1, 2))
         x = x.reshape(x.shape[0], -1)
     x, w, mm_precision, mm_out = resolve_weight(params, x, ctx)
-    y = jnp.dot(x, w, precision=mm_precision,
-                preferred_element_type=mm_out)
+    if layer.inner_product.transposed:  # a tied head: x @ w^T, w (out, in)
+        y = jnp.einsum("...k,nk->...n", x, w, precision=mm_precision,
+                       preferred_element_type=mm_out)
+    else:
+        y = jnp.dot(x, w, precision=mm_precision,
+                    preferred_element_type=mm_out)
     if "b" in params:
         y = y + params["b"].astype(y.dtype)
     if ctx.tp_shards(layer):

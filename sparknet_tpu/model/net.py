@@ -177,7 +177,7 @@ class CompiledNet:
 
     def attention_scopes(self) -> Tuple[Dict[str, str], int]:
         """({layer type: the scope under such a layer's own that holds its
-        latent attention}, the positions those layers attend over) for the
+        attention}, the positions those layers attend over) for the
         types of this net's layers in `seq_layers.ATTENTION_SCOPES`; ({}, 0)
         for a net without any."""
         from .seq_layers import ATTENTION_SCOPES

@@ -1,15 +1,20 @@
 """The sequence-model layer set: what a sparse-expert decoder is built from.
 
-Embed, RMSNorm, MLAttention (multi-head latent attention), GatedMLP (SwiGLU),
-MoE (routed experts of which this chip holds a share), MTP (a multi-token-
-prediction module) and Eltwise (the residual sum). Same three functions a
-layer type as `layers.py` (`init_`, `apply_`, `infer_`); registered there in
-`LAYER_IMPLS`. Activations are `[rows, positions, d]`; matrices are stored
-(in, out) in float32 and cast by the precision policy at use.
+Embed, RMSNorm, two kinds of attention -- MLAttention (multi-head latent
+attention) and GQAttention (grouped-query attention with per-head q/k norms)
+-- ShortConv (a gated short convolution: the operator a hybrid decoder sets
+between its attention layers), GatedMLP (SwiGLU), MoE (routed experts of
+which this chip holds a share, with or without a shared one), MTP (a
+multi-token-prediction module) and Eltwise (the residual sum). Same three
+functions a layer type as `layers.py` (`init_`, `apply_`, `infer_`);
+registered there in `LAYER_IMPLS`. Activations are `[rows, positions, d]`;
+matrices are stored (in, out) in float32 and cast by the precision policy at
+use. (A tied head is `layers.py`'s InnerProduct, `transposed`, on the
+embedding's own table.)
 
 Every layer's parameters carry names of their own (`q_a`, `kv_a_norm`,
-`router_bias`, ...): `param_defaults` gives the per-name lr/decay multipliers
-the solver applies where the spec gives none.
+`q_norm`, `conv`, `router_bias`, ...): `param_defaults` gives the per-name
+lr/decay multipliers the solver applies where the spec gives none.
 
 The two places where a plain formulation would not fit a chip at 8k positions
 go through kernels jax ships: the attention core through
@@ -20,9 +25,12 @@ an expert meet its weights). Off the chip, at test sizes and under
 `lax.ragged_dot`.
 
 What the attention core reads is laid out where that is free, on the weights
-(`mla`): views of the stored matrices whose products come out heads first,
-rotary columns half-split, q scaled, so no activation is sliced at a stride,
-transposed or scaled between a projection and the core.
+(`mla`, `gqa`): views of the stored matrices whose products come out heads
+first, rotary columns half-split, q scaled, so no activation is sliced at a
+stride, transposed or scaled between a projection and the core. Both kinds
+of attention hand the one `attention_core` the kernel's own operands; where
+the key/value heads are fewer than the query heads the kernel does the
+grouping.
 
 A layer may name a value that is dear to compute again and cheap to keep
 (`KEPT_NAMES`, by layer type): the recomputation block such a layer stands in
@@ -45,14 +53,23 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
-from .spec import LayerSpec, MLAttentionParam, MoEParam, ParamSpec
+from .spec import (GQAttentionParam, LayerSpec, MLAttentionParam, MoEParam,
+                   ParamSpec)
 
 Params = Dict[str, jnp.ndarray]
 
 #: splash attention's tiles (queries, keys a block, keys a product; positions
 #: must divide by the largest) and the grouped matmul's (rows, contraction,
-#: columns): the fastest of those tried on a v5e at [2, 20, 8192, 256] and
-#: [65536 x 2048] x [8, 2048, 1536] (PERF.md section 6, PR 27)
+#: columns), one set for every layer that runs them. Chosen on a v5e as the
+#: fastest of those tried at latent attention's [2, 20, 8192, 256] and the
+#: experts' [65536 x 2048] x [8, 2048, 1536] (PERF.md section 6, PR 27), and
+#: read again at grouped-query attention's [2, 32 q / 8 kv, 8192, 64] and
+#: [32768 x 2048] x [8, 2048, 1792] (PR 31, forward + backward): the core
+#: 34.6 ms at these blocks with the 64-wide head as it is and 35.5 zero-padded
+#: to a lane row (32.3 at (1024, 1024, 512), which latent attention has not
+#: been read at); the three grouped products 14.1 ms at these tiles, which
+#: overhang 1,792 (megablox masks the overhang), against 19.7 at tiles of 256
+#: that divide it, 40.9 at 128 and 12.2 at (512, 1024, 1024)
 ATTN_BLOCKS = (512, 1024, 512)
 GMM_TILING = (512, 512, 512)
 #: the counters an expert layer returns beside its result, in this order
@@ -239,18 +256,24 @@ def _splash(heads: int, positions: int):
 
 
 def attention_core(q, k, v, ctx):
-    """Causal softmax(q k^T) v over [rows, heads, positions, d], heads
-    first as the kernel reads and writes them; q comes scaled (`mla` folds
-    1/sqrt(d) into its weight). The kernel where it applies (positions a
-    multiple of its tile, head sizes of whole lanes); else the exact path,
-    which materialises the scores."""
-    n, d = q.shape[2], q.shape[-1]
-    if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0 and d % 128 == 0
-            and v.shape[-1] % 128 == 0 and q.dtype == jnp.bfloat16):
+    """Causal softmax(q k^T) v over q [rows, heads, positions, d] and k, v
+    [rows, key/value heads, positions, d], heads first as the kernel reads
+    and writes them; q comes scaled (its layer folds 1/sqrt(d) into a
+    weight). Where the key/value heads are fewer, query heads g*n ..
+    g*n + n - 1 read key/value head g (the kernel's own grouping). The
+    kernel where it applies (positions a multiple of its tile, head sizes a
+    half or whole lane rows); else the exact path, which materialises the
+    scores."""
+    n, group = q.shape[2], q.shape[1] // k.shape[1]
+    if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0
+            and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0
+            and q.dtype == jnp.bfloat16):
         return jax.vmap(_splash(q.shape[1], n))(q, k, v)
-    swap = lambda x: jnp.swapaxes(x, 1, 2)  # the exact path's positions-first
+    # the exact path's positions-first, every query head with its own copy
+    swap = lambda x: jnp.swapaxes(x, 1, 2)
+    spread = lambda x: swap(x if group == 1 else jnp.repeat(x, group, axis=1))
     return checkpoint_name(swap(attention_ops.attention(
-        swap(q), swap(k), swap(v), causal=True, scale=1.0)), ATTN_CORE)
+        swap(q), spread(k), spread(v), causal=True, scale=1.0)), ATTN_CORE)
 
 
 def _project(spec: str, x, w):
@@ -299,6 +322,78 @@ def apply_mlattention(layer: LayerSpec, params: Params, inputs, ctx):
     return (mla(layer.mla, params, inputs[0], ctx),)
 
 
+# -- GQAttention -------------------------------------------------------------
+
+def init_gqattention(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.gqa, in_shapes[0][-1]
+    ks = jax.random.split(key, 4)
+    kv = p.num_kv_heads * p.head_dim
+    return {"q": _normal(ks[0], (d, p.num_heads * p.head_dim), p.std),
+            "k": _normal(ks[1], (d, kv), p.std),
+            "v": _normal(ks[2], (d, kv), p.std),
+            "q_norm": jnp.ones((p.head_dim,), jnp.float32),
+            "k_norm": jnp.ones((p.head_dim,), jnp.float32),
+            "o": _normal(ks[3], (p.num_heads * p.head_dim, d), p.std)}
+
+
+def gqa(p: GQAttentionParam, params: Params, x, ctx):
+    """Grouped-query attention, laid out as `mla` lays its own out: the
+    products of x with views of the stored matrices come out heads first,
+    q and k go through their per-head norm (1/sqrt(d) folded into q's scale
+    vector) and the rotary turn over the whole head, v from its product into
+    the core, and the output projection contracts (heads, d) of the core's
+    result as it lies."""
+    h, kv, hd, d = p.num_heads, p.num_kv_heads, p.head_dim, x.shape[-1]
+    heads_first = "rnc,chd->rhnd"
+    q = _project(heads_first, x, params["q"].reshape(d, h, hd))
+    k = _project(heads_first, x, params["k"].reshape(d, kv, hd))
+    v = _project(heads_first, x, params["v"].reshape(d, kv, hd))
+    q = rotary(_rms(q, params["q_norm"] / np.sqrt(hd), p.eps), p.rope_theta, hd)
+    k = rotary(_rms(k, params["k_norm"], p.eps), p.rope_theta, hd)
+    with jax.named_scope("core"):
+        o = attention_core(q, k, v, ctx)
+    return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
+
+
+def apply_gqattention(layer: LayerSpec, params: Params, inputs, ctx):
+    return (gqa(layer.gqa, params, inputs[0], ctx),)
+
+
+# -- ShortConv ---------------------------------------------------------------
+
+def init_shortconv(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.shortconv, in_shapes[0][-1]
+    k_in, k_conv, k_out = jax.random.split(key, 3)
+    return {"in_proj": _normal(k_in, (d, 3 * d), p.std),
+            "conv": _normal(k_conv, (d, p.taps), p.std),
+            "out_proj": _normal(k_out, (d, d), p.std)}
+
+
+def causal_taps(s, w):
+    """c[:, t] = sum_j w[:, j] * s[:, t - (taps - 1) + j] over s [rows,
+    positions, d] with w [d, taps]: a depthwise causal convolution, zeros
+    before position 0, as shifted sums (a pad and a contiguous slice each)."""
+    taps, n = w.shape[-1], s.shape[1]
+    out = s * w[:, taps - 1]
+    for j in range(taps - 1):
+        back = taps - 1 - j
+        out = out + jnp.pad(s, ((0, 0), (back, 0), (0, 0)))[:, :n] * w[:, j]
+    return out
+
+
+def apply_shortconv(layer: LayerSpec, params: Params, inputs, ctx):
+    """[B | C | z] = x W_in; (C * taps(B * z)) W_out. The gates and the
+    taps (`mix`) run in float32 between the two products."""
+    (x,) = inputs
+    with jax.named_scope("in_proj"):
+        bcz = _dot(x, params["in_proj"])
+    with jax.named_scope("mix"):
+        b, c, z = (t.astype(jnp.float32) for t in jnp.split(bcz, 3, axis=-1))
+        y = (c * causal_taps(b * z, params["conv"])).astype(bcz.dtype)
+    with jax.named_scope("out_proj"):
+        return (_dot(y, params["out_proj"]),)
+
+
 # -- MoE ---------------------------------------------------------------------
 
 def moe_capacity(p: MoEParam, tokens: int, tile: int = GMM_TILING[0]) -> int:
@@ -343,7 +438,7 @@ def route(p: MoEParam, params: Params, xf):
                        p.num_experts_per_tok)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if p.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_topk_eps)
     return idx.astype(jnp.int32), w * p.routed_scaling_factor
 
 
@@ -516,15 +611,16 @@ def apply_mtp(layer: LayerSpec, params: Params, inputs, ctx):
 COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
 #: layer type -> the names its implementation puts on values a recomputation
 #: block keeps for the backward pass (`mla` serves both)
-KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,)}
+KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
+              "GQAttention": (ATTN_CORE,)}
 #: kept name -> the name of the Pallas kernel that computes its values, as a
 #: compiled program's text has it: run again in the backward pass only if
 #: the name did not reach a recomputation block's policy
 KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
 #: layer type -> the named scope, under the layer's own, that holds its
-#: latent attention ("": the whole layer): whose device ops
+#: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts
-ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention"}
+ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": ""}
 
 SEQ_LAYER_IMPLS = {
     "Embed": (init_embed, apply_embed, infer_embed),
@@ -532,6 +628,8 @@ SEQ_LAYER_IMPLS = {
     "Eltwise": (None, apply_eltwise, infer_same),
     "GatedMLP": (init_gatedmlp, apply_gatedmlp, infer_same),
     "MLAttention": (init_mlattention, apply_mlattention, infer_same),
+    "GQAttention": (init_gqattention, apply_gqattention, infer_same),
+    "ShortConv": (init_shortconv, apply_shortconv, infer_same),
     "MoE": (init_moe, apply_moe, infer_moe),
     "MTP": (init_mtp, apply_mtp, infer_mtp),
 }

@@ -9,9 +9,10 @@ Layer set = what the reference model zoo uses (reference
 `models/*.prototxt`): Convolution, Pooling, LRN, ReLU, InnerProduct, Softmax,
 SoftmaxWithLoss, Accuracy, Dropout — plus Input declarations — and the
 sequence-model set a sparse-expert decoder is built from: Embed, RMSNorm,
-MLAttention (latent attention), GatedMLP, MoE (an expert layer that holds a
-share of its experts), MTP (a multi-token-prediction module) and Eltwise (the
-residual sum, as Caffe has it).
+MLAttention (latent attention), GQAttention (grouped-query attention),
+ShortConv (a gated short convolution), GatedMLP, MoE (an expert layer that
+holds a share of its experts), MTP (a multi-token-prediction module) and
+Eltwise (the residual sum, as Caffe has it).
 """
 from __future__ import annotations
 
@@ -82,6 +83,10 @@ class InnerProductParam:
     #: default) flattens everything after the batch; -1 applies the product to
     #: the last axis alone ([rows, positions, d] -> [rows, positions, out])
     axis: int = 1
+    #: the matrix is stored (out, in) and the product runs over its
+    #: transpose: a head tied to an embedding's table (`param_from` the Embed
+    #: layer, whose gradient is then the sum of both uses)
+    transposed: bool = False
     weight_filler: Filler = field(default_factory=Filler)
     bias_filler: Filler = field(default_factory=Filler)
 
@@ -150,6 +155,33 @@ class MLAttentionParam:
 
 
 @dataclass(frozen=True)
+class GQAttentionParam:
+    """Grouped-query attention: `num_heads` query heads of `head_dim` over
+    `num_kv_heads` key/value heads (query heads g*n .. g*n + n - 1 read
+    key/value head g, n = num_heads / num_kv_heads), an RMS norm over each
+    head's `head_dim` on q and on k (one scale vector each, shared by the
+    heads), rotary over the whole head on contiguous halves. Causal."""
+
+    num_heads: int = 0
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class ShortConvParam:
+    """A gated short convolution (LFM2's operator): [B | C | z] = x W_in
+    (d -> 3d), a depthwise causal convolution of `taps` taps a channel over
+    B * z (zeros before position 0, no bias), (C * that) W_out (d -> d). No
+    nonlinearity."""
+
+    taps: int = 3
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
 class GatedMLPParam:
     """SwiGLU: (silu(x W_g) * x W_u) W_d."""
 
@@ -175,6 +207,9 @@ class MoEParam:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    #: added to the sum of the chosen scores before the division
+    #: (`norm_topk_prob`): the DeepSeek-V3 family's code adds 1e-20, LFM2's 1e-6
+    norm_topk_eps: float = 1e-20
     capacity_factor: Optional[float] = None
     std: float = 0.02
 
@@ -210,6 +245,8 @@ class LayerSpec:
     embed: Optional[EmbedParam] = None
     rmsnorm: Optional[RMSNormParam] = None
     mla: Optional[MLAttentionParam] = None
+    gqa: Optional[GQAttentionParam] = None
+    shortconv: Optional[ShortConvParam] = None
     gated_mlp: Optional[GatedMLPParam] = None
     moe: Optional[MoEParam] = None
     mtp: Optional[MTPParam] = None
@@ -267,7 +304,8 @@ class NetSpec:
 
 # Layer types that carry trainable parameters.
 PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct", "Embed", "RMSNorm",
-                          "MLAttention", "GatedMLP", "MoE", "MTP")
+                          "MLAttention", "GQAttention", "ShortConv",
+                          "GatedMLP", "MoE", "MTP")
 
 
 def validate(spec: NetSpec) -> None:

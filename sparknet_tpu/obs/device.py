@@ -278,7 +278,7 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "bytes", "gathers_scatters"}}` — see `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks) and
-    `attention_moves` for what a step's latent attention moves without
+    `attention_moves` for what a step's attention moves without
     computing ({} for a net without such layers). None when no such
     program is registered or it has not been dispatched yet.
 
@@ -525,7 +525,7 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
 
 def attention_moves(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
                     positions: int) -> Dict[str, int]:
-    """What a step's latent attention moves without computing: of the device
+    """What a step's attention moves without computing: of the device
     ops under its scopes (`scopes`: layer type -> the scope under the layer's
     own that holds its attention, "" for the whole layer;
     `CompiledNet.attention_scopes()`) that hold neither a matmul nor a kernel

@@ -7,10 +7,15 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and one family of sequence models, built from a file of its published config:
+and two families of sequence models, each built from a file of its published
+config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
                       holds a share, one multi-token-prediction module)
+  - lfm2_moe       <- huggingface.co/LiquidAI/LFM2-8B-A1B config.json
+                      (gated short convolutions among grouped-query
+                      attention by the config's `layer_types`, routed
+                      experts without a shared one, a tied head)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -21,9 +26,10 @@ from typing import Optional, Tuple
 
 from .model.spec import (AccuracyParam, ConvolutionParam, DropoutParam,
                          EltwiseParam, EmbedParam, Filler, GatedMLPParam,
-                         InnerProductParam, InputSpec, LayerSpec, LossParam,
-                         LRNParam, MLAttentionParam, MoEParam, MTPParam,
-                         NetSpec, ParamSpec, PoolingParam, RMSNormParam)
+                         GQAttentionParam, InnerProductParam, InputSpec,
+                         LayerSpec, LossParam, LRNParam, MLAttentionParam,
+                         MoEParam, MTPParam, NetSpec, ParamSpec, PoolingParam,
+                         RMSNormParam, ShortConvParam)
 
 _GAUSS = lambda std: Filler(type="gaussian", std=std)
 _CONST = lambda v=0.0: Filler(type="constant", value=v)
@@ -180,6 +186,35 @@ def adult_mlp(batch: int = 64, n_features: int = 1) -> NetSpec:
     )
 
 
+# -- what the sequence models' decoder blocks share ---------------------------
+
+def _rms_layer(name, bottom, block, eps) -> LayerSpec:
+    return LayerSpec(name=name, type="RMSNorm", bottoms=(bottom,),
+                     tops=(name,), rmsnorm=RMSNormParam(eps=eps), block=block)
+
+
+def _sum_layer(name, a, b, top, block) -> LayerSpec:
+    """The residual sum."""
+    return LayerSpec(name=name, type="Eltwise", bottoms=(a, b), tops=(top,),
+                     block=block)
+
+
+def _ff_layer(l: str, dense: bool, dense_width: int, experts: MoEParam,
+              std: float) -> LayerSpec:
+    """The feed-forward layer of decoder block `l`, reading `<l>_mlp_norm`:
+    a dense SwiGLU `<l>_mlp` in a leading layer, else the routed experts
+    `<l>_moe` with their counters and choices."""
+    if dense:
+        return LayerSpec(
+            name=f"{l}_mlp", type="GatedMLP", bottoms=(f"{l}_mlp_norm",),
+            tops=(f"{l}_mlp",), block=l,
+            gated_mlp=GatedMLPParam(intermediate_size=dense_width, std=std))
+    mlp = f"{l}_moe"
+    return LayerSpec(name=mlp, type="MoE", bottoms=(f"{l}_mlp_norm",),
+                     tops=(mlp, f"{mlp}_counters", f"{mlp}_chosen"),
+                     moe=experts, block=l)
+
+
 def glm4_moe_lite(config: dict, rows: int, positions: int) -> NetSpec:
     """A `glm4_moe_lite` decoder (GLM-4.7-Flash) as ONE CHIP'S SHARE of an
     expert-parallel deployment, for training on `[rows, positions]` int32
@@ -226,11 +261,7 @@ def glm4_moe_lite(config: dict, rows: int, positions: int) -> NetSpec:
         routed_scaling_factor=c["routed_scaling_factor"],
         norm_topk_prob=c["norm_topk_prob"],
         capacity_factor=share.get("capacity_factor"), std=std)
-    norm = lambda name, bottom, block: LayerSpec(
-        name=name, type="RMSNorm", bottoms=(bottom,), tops=(name,),
-        rmsnorm=RMSNormParam(eps=eps), block=block)
-    add = lambda name, a, b, top, block: LayerSpec(
-        name=name, type="Eltwise", bottoms=(a, b), tops=(top,), block=block)
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
     head = lambda name, bottom, block, param_from=None: LayerSpec(
         name=name, type="InnerProduct", bottoms=(bottom,), tops=(name,),
         inner_product=InnerProductParam(num_output=vocab, bias_term=False,
@@ -251,22 +282,12 @@ def glm4_moe_lite(config: dict, rows: int, positions: int) -> NetSpec:
             LayerSpec(name=f"{l}_attn", type="MLAttention",
                       bottoms=(f"{l}_attn_norm",), tops=(f"{l}_attn",),
                       mla=attention, block=l),
-            add(f"{l}_attn_res", x, f"{l}_attn", f"{l}_h", l),
+            _sum_layer(f"{l}_attn_res", x, f"{l}_attn", f"{l}_h", l),
             norm(f"{l}_mlp_norm", f"{l}_h", l)]
-        if i < c["first_k_dense_replace"]:
-            mlp = f"{l}_mlp"
-            layers.append(LayerSpec(
-                name=mlp, type="GatedMLP", bottoms=(f"{l}_mlp_norm",),
-                tops=(mlp,), block=l,
-                gated_mlp=GatedMLPParam(
-                    intermediate_size=c["intermediate_size"], std=std)))
-        else:
-            mlp = f"{l}_moe"
-            layers.append(LayerSpec(
-                name=mlp, type="MoE", bottoms=(f"{l}_mlp_norm",),
-                tops=(mlp, f"{mlp}_counters", f"{mlp}_chosen"), moe=experts,
-                block=l))
-        layers.append(add(f"{l}_mlp_res", f"{l}_h", mlp, f"x{i + 1}", l))
+        ff = _ff_layer(l, i < c["first_k_dense_replace"],
+                       c["intermediate_size"], experts, std)
+        layers += [ff, _sum_layer(f"{l}_mlp_res", f"{l}_h", ff.name,
+                                  f"x{i + 1}", l)]
     last = f"x{c['num_hidden_layers']}"
     layers += [norm("final_norm", last, "head"),
                head("lm_head", "final_norm", "head"),
@@ -294,6 +315,95 @@ def glm4_moe_lite(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def lfm2_moe(config: dict, rows: int, positions: int) -> NetSpec:
+    """An `lfm2_moe` decoder (LFM2-8B-A1B) as ONE CHIP'S SHARE of an
+    expert-parallel deployment, for training on `[rows, positions]` int32
+    token ids (input `tokens`; the targets are the ids themselves, read one
+    position on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- `num_hidden_layers` layers whose operator `layer_types` names one
+    by one ("conv": a gated short convolution; "full_attention":
+    grouped-query attention), the first `num_dense_layers` with a dense MLP,
+    `num_experts` experts HELD in each expert layer, `vocab_size` rows of the
+    vocabulary HELD -- and a `share` block as `glm4_moe_lite`'s:
+    `num_experts` (the published count: the router's width), `experts_held`
+    [first, count], `vocab_rows` [first, count], `chips_sharing_a_layer`,
+    optionally `capacity_factor`. `head_dim` where the file gives one, else
+    hidden_size / num_attention_heads.
+
+    Pre-norm residual blocks: x += Op(RMSNorm(x)); x += FF(RMSNorm(x)), FF a
+    dense SwiGLU in the leading layers and routed experts (no shared one)
+    after. The head is the embedding's table transposed (tied), over the
+    held vocabulary rows. Loss = CE(next token), a mean over the positions
+    that have a target. Every block is a recomputation block."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["norm_eps"], 0.02
+    kinds, depth = c["layer_types"], c["num_hidden_layers"]
+    first, held = share["experts_held"]
+    vocab = share["vocab_rows"][1]
+    if held != c["num_experts"] or vocab != c["vocab_size"]:
+        raise ValueError("the share block and the held counts disagree: "
+                         f"experts_held {share['experts_held']} against "
+                         f"num_experts {c['num_experts']}, vocab_rows "
+                         f"{share['vocab_rows']} against vocab_size "
+                         f"{c['vocab_size']}")
+    if len(kinds) != depth or set(kinds) - {"conv", "full_attention"}:
+        raise ValueError(f"layer_types {kinds} does not name the operator "
+                         f"(conv | full_attention) of each of the "
+                         f"{depth} layers")
+    if c.get("conv_bias") or not c.get("use_expert_bias", True):
+        raise ValueError("a convolution bias, or a router without its "
+                         "selection bias, is not built")
+    attention = GQAttentionParam(
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        head_dim=c.get("head_dim") or d // c["num_attention_heads"],
+        rope_theta=float(c["rope_theta"]), eps=eps, std=std)
+    conv = ShortConvParam(taps=c["conv_L_cache"], std=std)
+    experts = MoEParam(
+        n_routed_experts=share["num_experts"], experts_held=(first, held),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        intermediate_size=c["moe_intermediate_size"], n_shared_experts=0,
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=c["norm_topk_prob"], norm_topk_eps=1e-6,
+        capacity_factor=share.get("capacity_factor"), std=std)
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",),
+                        embed=EmbedParam(num_embeddings=vocab, dim=d, std=std))]
+    for i, kind in enumerate(kinds):
+        l, x = f"l{i}", f"x{i}"
+        op = f"{l}_conv" if kind == "conv" else f"{l}_attn"
+        layers += [
+            norm(f"{l}_op_norm", x, l),
+            LayerSpec(name=op, type="ShortConv", bottoms=(f"{l}_op_norm",),
+                      tops=(op,), shortconv=conv, block=l)
+            if kind == "conv" else
+            LayerSpec(name=op, type="GQAttention", bottoms=(f"{l}_op_norm",),
+                      tops=(op,), gqa=attention, block=l),
+            _sum_layer(f"{l}_op_res", x, op, f"{l}_h", l),
+            norm(f"{l}_mlp_norm", f"{l}_h", l)]
+        ff = _ff_layer(l, i < c["num_dense_layers"], c["intermediate_size"],
+                       experts, std)
+        layers += [ff, _sum_layer(f"{l}_mlp_res", f"{l}_h", ff.name,
+                                  f"x{i + 1}", l)]
+    layers += [
+        norm("final_norm", f"x{depth}", "head"),
+        LayerSpec(name="lm_head", type="InnerProduct", bottoms=("final_norm",),
+                  tops=("lm_head",), param_from="embed", block="head",
+                  inner_product=InnerProductParam(
+                      num_output=vocab, bias_term=False, axis=-1,
+                      transposed=True)),
+        LayerSpec(name="loss", type="SoftmaxWithLoss",
+                  bottoms=("lm_head", "tokens"), tops=("loss",), block="head",
+                  loss=LossParam(label_shift=1))]
+    return NetSpec(name="lfm2_moe",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
-SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite}
+SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe}

@@ -330,6 +330,26 @@ def test_attention_core_backward_keeps_nothing_of_size_positions_squared(v5e, as
     assert not re.search(r"\[2,20,8192,256\]\S* (transpose|copy)\(", text)
 
 
+def test_grouped_core_at_head_width_64_runs_as_a_kernel(v5e, as_tpu):
+    """The grouped-query core at the hybrid cell's shape (2 rows, 32 query
+    heads over 8 key/value heads, 8,192 positions, 64 a head), forward and
+    backward, for a v5e: the same kernels, the grouping done inside them (no
+    key or value is written out once a query head), and no [.., 8192, 8192]
+    tensor (one layer's float32 scores would be 17 GB)."""
+    from sparknet_tpu.model import seq_layers as sl
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16, sharding=one)
+    text = _compiled_text(jax.grad(
+        lambda q, k, v: sl.attention_core(q, k, v, _seq_ctx()).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 2 and "splash_mha" in text
+    assert "8192,8192" not in text
+    # the kernels take the 8 key/value heads as they are
+    assert re.search(r"custom-call\([^)]*\), custom_call_target=\"tpu_custom_call\"",
+                     text) and "bf16[2,8,8192,64]" in text
+
+
 def test_attention_block_lays_out_nothing_between_projection_and_core(v5e, as_tpu):
     """One attention block at GLM-4.7-Flash's widths and the benchmark's
     shape (norm, latent attention, residual sum: a recomputation block that
@@ -370,14 +390,16 @@ def test_attention_block_lays_out_nothing_between_projection_and_core(v5e, as_tp
     assert accessed < 19e9, f"the block accesses {accessed / 1e9:.2f} GB"
 
 
-def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu):
-    """The experts' grouped matmul at the benchmark's shape (a 16,384-row
-    buffer, 8 held experts of 2048 x 1536), forward and both gradients:
-    megablox's kernels, so only the rows routed to an expert meet it."""
+@pytest.mark.parametrize("rows,width", [(16384, 1536), (32768, 1792)])
+def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu, rows, width):
+    """The experts' grouped matmul at the benchmark's shapes (a 16,384-row
+    buffer, 8 held experts of 2048 x 1536; a 32,768-row one, 8 of 2048 x
+    1,792, which the 512-wide tiles overhang), forward and both gradients: megablox's
+    kernels, so only the rows routed to an expert meet it."""
     from sparknet_tpu.model import seq_layers as sl
     one = SingleDeviceSharding(v5e[0])
-    x = jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16, sharding=one)
-    w = jax.ShapeDtypeStruct((8, 2048, 1536), jnp.float32, sharding=one)
+    x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((8, 2048, width), jnp.float32, sharding=one)
     sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
     precision.set_policy("bfloat16")
     try:
@@ -391,22 +413,16 @@ def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu):
     assert "gmm" in text and "tgmm" in text
 
 
-@pytest.mark.slow
-def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
-    """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`: the
-    published widths, 2 x 8,192 tokens a step, tau=4, bf16, donated, fused
-    boundary, health off) for one described chip: ~2 min. 5.65 GB of state
-    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
-    attention cores keep for the backward 1.01 GB, and their statistics
-    as the kernel writes them, padded to 128 lanes, 1.0 GB more; 6.9 GB
-    while q, k and v were laid out again between projection and core). Each
-    step body runs the cores' forward kernel on its forward path alone, and
-    no gather or scatter in its attention touches an activation."""
+def _sequence_round(v5e, config: str):
+    """(compiled, trainer) of a sequence configuration's benchmark round
+    (`benchmark/configs/<config>.json`: the published widths, 2 x 8,192
+    tokens a step, tau=4, bf16, donated, fused boundary, health off) for one
+    described chip."""
     import json
     from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.utils.config import RunConfig
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "benchmark", "configs", "glm47-flash-ep8-tau4.json")
+    path = os.path.join(root, "benchmark", "configs", config + ".json")
     with open(path) as f:
         c = json.load(f)
     cfg = RunConfig.from_dict({
@@ -429,9 +445,27 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
                                  sharding=NamedSharding(mesh, P()))).compile()
     finally:
         precision.set_policy("float32")
+    return compiled, trainer
+
+
+def _round_bytes(compiled) -> int:
     mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+@pytest.mark.slow
+def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`) for one
+    described chip: ~2 min. 5.65 GB of state
+    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
+    attention cores keep for the backward 1.01 GB, and their statistics
+    as the kernel writes them, padded to 128 lanes, 1.0 GB more; 6.9 GB
+    while q, k and v were laid out again between projection and core). Each
+    step body runs the cores' forward kernel on its forward path alone, and
+    no gather or scatter in its attention touches an activation."""
+    compiled, trainer = _sequence_round(v5e, "glm47-flash-ep8-tau4")
+    total = _round_bytes(compiled)
     assert total < 11.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
@@ -444,3 +478,27 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves  # 96 before PR 30
     assert moves["bytes"] < 57e9, moves  # 94.9 GB a step body before, 42.8 now
+
+
+@pytest.mark.slow
+def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The hybrid sequence model's round (`lfm2-8b-a1b-ep4-tau4`: seven gated
+    short convolutions, two grouped-query attentions at head width 64, eight
+    expert layers of width 1,792, a tied head) for one described chip: 7.37
+    GB of state (921,256,448 parameters and their momentum) and the round's
+    temporaries under the chip's 16 GB beside the benchmark's stacks. The two
+    attention cores run as kernels with grouped heads (no [.., 8192, 8192]
+    scores), once a step body on its forward path alone."""
+    compiled, trainer = _sequence_round(v5e, "lfm2-8b-a1b-ep4-tau4")
+    total = _round_bytes(compiled)
+    assert total < 14.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
+    from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
+                                         recompute_report)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_kernels())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (2, 0)
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves

@@ -769,7 +769,8 @@ def test_attention_moves_counts_what_attention_moves_without_computing():
     assert obs_device.attention_moves(ops, sl.ATTENTION_SCOPES, 8)["gathers_scatters"] == 2
     assert obs_device.attention_moves(ops, {}, 0) == {}
     # the net says which scopes and how many positions
-    assert _net().attention_scopes() == (sl.ATTENTION_SCOPES, POS)
+    assert _net().attention_scopes() == (
+        {"MLAttention": "", "MTP": "attention"}, POS)  # of ITS layers' types
     # ... and the gauges beside the program's memory gauges read it
     obs_device.register_program("b_round", lambda: {
         "memory": {"temp": 1, "argument": 2, "output": 3}, "ops": ops,
