@@ -187,6 +187,17 @@ class CompiledNet:
                 max((self.blob_shapes[l.bottoms[0]][1] for l in layers),
                     default=0))
 
+    def routing_scopes(self) -> Tuple[Tuple[str, ...], int]:
+        """(the scopes under which this net's expert layers choose experts
+        and move rows to and from them, the width of the rows they move):
+        `seq_layers.ROUTING_SCOPES` for a net with a layer of one of
+        `seq_layers.COUNTER_TOPS`' types; ((), 0) for a net without any."""
+        from .seq_layers import COUNTER_TOPS, ROUTING_SCOPES
+        widths = [self.blob_shapes[l.bottoms[0]][-1]
+                  for l in self.spec.layers_for_phase("TRAIN")
+                  if l.type in COUNTER_TOPS]
+        return (ROUTING_SCOPES, widths[0]) if widths else ((), 0)
+
     # -- execution ----------------------------------------------------------
 
     def apply(self, params: PyTree, batch: Dict[str, jnp.ndarray], *,

@@ -455,61 +455,94 @@ def _grouped_dot(x, w, group_sizes, ctx):
                           preferred_element_type=precision.preferred_out())
 
 
-# Dispatch and combine are each other's transpose. Written as gathers both
-# ways: left to autodiff, the backward of a row gather is a scatter-add of
-# thousands of rows, which a TPU runs one row at a time.
+# Dispatch and combine are each other's transpose, written as one pair over
+# the buffer's rows. Left to autodiff, the backward of a row gather is a
+# scatter-add of thousands of rows, which a TPU runs one row at a time; and a
+# pass over the step's tokens x k slots is mostly masked (one slot in four,
+# or in eight, lands here). A `plan` is the routing's index arrays: `tok`
+# [rows] the token of every buffer row, `row_slot` [rows] its slot, `row_ok`
+# [rows] whether a slot landed in it, and the other way round `slot_row`
+# [tokens, k] (clamped into the buffer) and `slot_ok` [tokens, k].
+
+def _plan(idx, experts_held, rows: int):
+    """(the plan, the slots that landed by held expert [held], those of them
+    that found room [held]) from the experts every token chose (`idx`
+    [tokens, k]), for a buffer of `rows` rows: the slots sorted by held
+    expert (stable: by token within an expert), those that land nowhere
+    here last; what does not fit is dropped from the END of the sorted
+    slots."""
+    (tokens, k), (first, held) = idx.shape, experts_held
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> slot
+    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    kept = ends[-1]
+    n = min(rows, tokens * k)
+    row_slot = jnp.pad(order[:n], (0, rows - n))
+    slot_row = jnp.argsort(order).astype(jnp.int32).reshape(tokens, k)
+    plan = {"tok": row_slot // k, "row_slot": row_slot,
+            "row_ok": jnp.arange(rows, dtype=jnp.int32) < kept,
+            "slot_row": jnp.minimum(slot_row, rows - 1),
+            "slot_ok": slot_row < kept}
+    return plan, sizes, jnp.diff(ends, prepend=0)
+
+
+def _take_rows(x, index):
+    """x[index] along rows; every index is in bounds by construction, so no
+    fill pass follows the gather."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+def _weighted_sum_by_token(rows, w, plan):
+    """Token t <- sum over its k slots of w[t, j] * rows[row of slot (t, j)]
+    in float32, the slots that did not land left out (their rows may hold
+    anything): k gathers of [tokens] rows and one fused weighted add."""
+    return sum(
+        jnp.where(plan["slot_ok"][:, j, None],
+                  _take_rows(rows, plan["slot_row"][:, j]).astype(jnp.float32),
+                  0) * w[:, j, None]
+        for j in range(w.shape[1])).astype(rows.dtype)
+
 
 @jax.custom_vjp
-def _gather_rows(xf, row_tok, slot_row, slot_ok):
-    """Buffer row r <- token row_tok[r]."""
-    return jnp.take(xf, row_tok, axis=0)
+def rows_of_tokens(xf, plan):
+    """Buffer row r <- token plan["tok"][r]; rows no slot landed in hold
+    some token's row."""
+    return _take_rows(xf, plan["tok"])
 
 
-def _gather_rows_fwd(xf, row_tok, slot_row, slot_ok):
-    return jnp.take(xf, row_tok, axis=0), (slot_row, slot_ok, xf.shape[0])
+def _rows_of_tokens_bwd(plan, g):
+    return sum_by_token(g, plan["slot_ok"].astype(jnp.float32), plan), None
 
 
-def _gather_rows_bwd(res, g):
-    slot_row, slot_ok, tokens = res
-    gs = jnp.where(slot_ok[:, None], jnp.take(g, slot_row, axis=0), 0)
-    dxf = jnp.sum(gs.astype(jnp.float32).reshape(tokens, -1, g.shape[-1]),
-                  axis=1)
-    return dxf.astype(g.dtype), None, None, None
-
-
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+rows_of_tokens.defvjp(lambda xf, plan: (_take_rows(xf, plan["tok"]), plan),
+                      _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, w, slot_row, slot_ok, row_slot, row_ok):
-    """Token t <- sum over its k slots of w[t, j] * y[row of slot (t, j)],
-    the slots that did not land here left out."""
-    return _combine_fwd(y, w, slot_row, slot_ok, row_slot, row_ok)[0]
+def sum_by_token(rows, w, plan):
+    """Token t <- sum over its landed slots of w[t, j] * rows[row of slot
+    (t, j)], accumulated in float32: `rows_of_tokens`' transpose, weighted."""
+    return _weighted_sum_by_token(rows, w, plan)
 
 
-def _slot_rows(y, slot_row, slot_ok, tokens):
-    ys = jnp.where(slot_ok[:, None], jnp.take(y, slot_row, axis=0), 0)
-    return ys.astype(jnp.float32).reshape(tokens, -1, y.shape[-1])
+def _sum_by_token_bwd(res, g):
+    rows, w, plan = res
+    g_tok = rows_of_tokens(g, plan).astype(jnp.float32)
+    w_row = jnp.where(plan["row_ok"],
+                      _take_rows(w.reshape(-1), plan["row_slot"]), 0.0)
+    drows = (w_row[:, None] * g_tok).astype(rows.dtype)
+    # <rows[r], g[its token]>: a scalar a row, fetched by the row's slot
+    dw_row = jnp.sum(rows.astype(jnp.float32) * g_tok, axis=-1)
+    dw = jnp.where(plan["slot_ok"], _take_rows(dw_row, plan["slot_row"]), 0.0)
+    return drows, dw, None
 
 
-def _combine_fwd(y, w, slot_row, slot_ok, row_slot, row_ok):
-    out = jnp.sum(_slot_rows(y, slot_row, slot_ok, w.shape[0])
-                  * w[:, :, None], axis=1).astype(y.dtype)
-    return out, (y, w, slot_row, slot_ok, row_slot, row_ok)
-
-
-def _combine_bwd(res, g):
-    y, w, slot_row, slot_ok, row_slot, row_ok = res
-    k = w.shape[1]
-    w_row = jnp.where(row_ok, jnp.take(w.reshape(-1), row_slot), 0.0)
-    dy = (jnp.take(g, row_slot // k, axis=0).astype(jnp.float32)
-          * w_row[:, None]).astype(y.dtype)
-    dw = jnp.sum(_slot_rows(y, slot_row, slot_ok, w.shape[0])
-                 * g.astype(jnp.float32)[:, None, :], axis=-1)
-    return dy, dw, None, None, None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
+sum_by_token.defvjp(
+    lambda rows, w, plan: (_weighted_sum_by_token(rows, w, plan),
+                           (rows, w, plan)), _sum_by_token_bwd)
 
 
 def moe(p: MoEParam, params: Params, x, ctx):
@@ -517,29 +550,13 @@ def moe(p: MoEParam, params: Params, x, ctx):
     the experts every position chose [rows, positions, k] int32)."""
     r, n, d = x.shape
     tokens, k = r * n, p.num_experts_per_tok
-    first, held = p.experts_held
     xf = x.reshape(tokens, d)
     with jax.named_scope("router"):
         idx, w = route(p, params, xf)
     with jax.named_scope("dispatch"):
-        rows = moe_capacity(p, tokens)
-        local = idx.reshape(-1) - first
-        key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row -> slot
-        slot_row = jnp.argsort(order).astype(jnp.int32)          # slot -> row
-        sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
-                        axis=0, dtype=jnp.int32)
-        landed = jnp.sum(sizes)
-        # what does not fit is dropped from the END of the sorted slots
-        ends = jnp.minimum(jnp.cumsum(sizes), rows)
-        kept_sizes = jnp.diff(ends, prepend=0)
-        kept = ends[-1]
-        slot_ok = (key < held) & (slot_row < kept)
-        slot_row = jnp.minimum(slot_row, rows - 1)
-        row_slot = order[:rows] if rows <= order.shape[0] else jnp.pad(
-            order, (0, rows - order.shape[0]))
-        row_ok = jnp.arange(rows, dtype=jnp.int32) < kept
-        xs = _gather_rows(xf, row_slot // k, slot_row, slot_ok)
+        plan, sizes, kept_sizes = _plan(idx, p.experts_held,
+                                        moe_capacity(p, tokens))
+        xs = rows_of_tokens(xf, plan)
     with jax.named_scope("experts"):
         g = _grouped_dot(xs, params["experts_gate"], kept_sizes, ctx)
         u = _grouped_dot(xs, params["experts_up"], kept_sizes, ctx)
@@ -547,12 +564,13 @@ def moe(p: MoEParam, params: Params, x, ctx):
         y = _grouped_dot(h.astype(g.dtype), params["experts_down"],
                          kept_sizes, ctx)
     with jax.named_scope("combine"):
-        out = _combine(y, w, slot_row, slot_ok, row_slot, row_ok)
+        out = sum_by_token(y, w, plan)
     if p.n_shared_experts:
         with jax.named_scope("shared"):
             out = out + _swiglu(xf, params["shared_gate"],
                                 params["shared_up"], params["shared_down"])
-    counters = jnp.stack([landed, landed - kept, jnp.max(sizes),
+    landed = jnp.sum(sizes)
+    counters = jnp.stack([landed, landed - jnp.sum(kept_sizes), jnp.max(sizes),
                           jnp.min(sizes)]).astype(jnp.float32)
     return (out.reshape(r, n, d), lax.stop_gradient(counters),
             idx.reshape(r, n, k))
@@ -621,6 +639,9 @@ KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts
 ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": ""}
+#: the named scopes, under an expert layer's own (`moe`), whose device ops
+#: `obs.device.routing_moves` counts
+ROUTING_SCOPES = ("router", "dispatch", "combine")
 
 SEQ_LAYER_IMPLS = {
     "Embed": (init_embed, apply_embed, infer_embed),

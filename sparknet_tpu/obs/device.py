@@ -275,11 +275,14 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     {"scope", "phase", "layer_type", "layer", "opcode", "computation"},
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
-    "bytes", "gathers_scatters"}}` — see `parse_hlo_ops` for the
+    "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
+    "row_gathers", "rows_gathered"}}` — see `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
-    keep ({} for a net whose blocks name nothing, or without blocks) and
+    keep ({} for a net whose blocks name nothing, or without blocks),
     `attention_moves` for what a step's attention moves without
-    computing ({} for a net without such layers). None when no such
+    computing and `routing_moves` for what its expert layers move around
+    their products (each {} for a net without such layers; both are calls
+    of `moves_under`). None when no such
     program is registered or it has not been dispatched yet.
 
     NEVER on the round path: the first call lowers and compiles the program
@@ -430,7 +433,8 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
     operands' and results', as the text gives their shapes; 0 for what is no
     op or moves nothing), `"matmul"` (it is, or its fusion `fused` holds, a
     `dot` or a `convolution`) and, where it is or holds any, `"indexed"`: the
-    dimensions of every `gather`'s and `scatter`'s operands and result."""
+    dimensions of every `gather`'s and `scatter`'s operands and result, and
+    `"gathered"`: the dimensions of every `gather`'s result alone."""
     opcode = instruction["opcode"]
     result = instruction["shapes"]
     if opcode in _NO_BYTES or opcode in CONTAINERS or opcode.endswith("-done"):
@@ -452,6 +456,8 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
             indexed.append(tuple(d for _, dims in arrays for d in dims))
     if indexed:
         out["indexed"] = indexed
+        out["gathered"] = [dims for f in fused if f["opcode"] == "gather"
+                           for _, dims in f["shapes"][:1]]
     return out
 
 
@@ -523,6 +529,26 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
     return out
 
 
+def moves_under(ops: Dict[str, Dict[str, Any]], belongs,
+                sums: Dict[str, Any]) -> Dict[str, int]:
+    """The one query the per-mechanism counters are calls of: of the device
+    ops that move bytes and for which `belongs(op, its scope's parts)` holds,
+    by the computation that runs them (a loop's body, the peeled step),
+    `{"instructions", "bytes": their operands' and results' together, **{key:
+    the sum of sums[key](op)}}` -- in the body that moves most bytes."""
+    zero = {"instructions": 0, "bytes": 0, **{key: 0 for key in sums}}
+    bodies: Dict[str, Dict[str, int]] = {}
+    for op in ops.values():
+        if not op["bytes"] or not belongs(op, op["scope"].split("/")):
+            continue
+        body = bodies.setdefault(op["computation"], dict(zero))
+        body["instructions"] += 1
+        body["bytes"] += op["bytes"]
+        for key, of in sums.items():
+            body[key] += of(op)
+    return max(bodies.values(), key=lambda b: b["bytes"], default=zero)
+
+
 def attention_moves(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
                     positions: int) -> Dict[str, int]:
     """What a step's attention moves without computing: of the device
@@ -540,40 +566,57 @@ def attention_moves(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     if not scopes:
         return {}
     along = (positions, positions // 2)
-    zero = {"instructions": 0, "bytes": 0, "gathers_scatters": 0}
-    bodies: Dict[str, Dict[str, int]] = {}
-    for op in ops.values():
+
+    def belongs(op, parts):
         sub = scopes.get(op["layer_type"])
-        if (sub is None or (sub and sub not in op["scope"].split("/"))
-                or op["matmul"] or op["opcode"] == "custom-call"
-                or not op["bytes"]):
-            continue
-        body = bodies.setdefault(op["computation"], dict(zero))
-        body["instructions"] += 1
-        body["bytes"] += op["bytes"]
-        body["gathers_scatters"] += sum(
-            any(d in along for d in dims) for dims in op.get("indexed", ()))
-    return max(bodies.values(), key=lambda b: b["bytes"], default=zero)
+        return (sub is not None and (not sub or sub in parts)
+                and not op["matmul"] and op["opcode"] != "custom-call")
+
+    return moves_under(ops, belongs, {"gathers_scatters": lambda op: sum(
+        any(d in along for d in dims) for dims in op.get("indexed", ()))})
+
+
+def routing_moves(ops: Dict[str, Dict[str, Any]], scopes: Tuple[str, ...],
+                  width: int) -> Dict[str, int]:
+    """What a step's expert layers move to choose experts and to carry rows
+    to and from them: of the device ops under their routing scopes (`scopes`,
+    `width`: `CompiledNet.routing_scopes()`), `{"instructions", "bytes",
+    "row_gathers": the `gather` instructions among them whose result's minor
+    dimension is the model's `width` (rows of activations, not scalars),
+    "rows_gathered": the rows those fetch}`, in the step body that moves
+    most. {} for a net without such layers. Every data pass walks the
+    buffer's rows where "rows_gathered" counts no tokens x top-k."""
+    if not scopes:
+        return {}
+    rows = lambda op: [math.prod(dims[:-1]) for dims in op.get("gathered", ())
+                       if dims and dims[-1] == width]
+    return moves_under(
+        ops, lambda op, parts: any(s in parts for s in scopes),
+        {"row_gathers": lambda op: len(rows(op)),
+         "rows_gathered": lambda op: sum(rows(op))})
 
 
 def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
-                       jaxpr=None, attention=({}, 0)) -> Dict[str, Any]:
+                       jaxpr=None, attention=({}, 0),
+                       routing=((), 0)) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
-    names its net's recomputation blocks keep `recompute_report`, and for
-    its latent-attention layers (`attention`: their scopes and positions)
-    `attention_moves`."""
+    names its net's recomputation blocks keep `recompute_report`, for
+    its attention layers (`attention`: their scopes and positions)
+    `attention_moves`, and for its expert layers (`routing`: their routing
+    scopes and the model's width) `routing_moves`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
                        for k in ("argument", "output", "alias", "temp")},
             "ops": ops,
             "recompute": recompute_report(ops, kept_kernels or {}, jaxpr),
-            "attention_moves": attention_moves(ops, *attention)}
+            "attention_moves": attention_moves(ops, *attention),
+            "routing_moves": routing_moves(ops, *routing)}
 
 
 #: program -> these parts of its report, once `program_report` has run
-REPORT_PARTS = ("memory", "recompute", "attention_moves")
+REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
@@ -627,8 +670,8 @@ def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
 
 
 def program_part(key: str) -> Dict[str, Dict[str, Any]]:
-    """{program: the `key` part of its report ("memory", "recompute",
-    "attention_moves")} for every program whose report has been asked for
+    """{program: the `key` part of its report (one of `REPORT_PARTS`)}
+    for every program whose report has been asked for
     so far — a read of what is cached, never a compile (the /status
     route)."""
     return {name: parts[key] for name, parts in _program_parts.items()}

@@ -889,7 +889,8 @@ class ParallelTrainer:
             traced = self._round.trace(*self._round_avals)
             self._report = obs_device.report_of_compiled(
                 traced.lower().compile(), self.net.kept_kernels(),
-                traced.jaxpr.jaxpr, self.net.attention_scopes())
+                traced.jaxpr.jaxpr, self.net.attention_scopes(),
+                self.net.routing_scopes())
         return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":

@@ -413,6 +413,145 @@ def test_grouped_expert_products_compile_for_v5e(v5e, as_tpu, rows, width):
     assert "gmm" in text and "tgmm" in text
 
 
+#: the two cells' expert layers (`zoo.lfm2_moe`, `zoo.glm4_moe_lite` of the
+#: benchmark's configurations): 16,384 tokens a step, top 4, width 2,048
+_EXPERT_LAYERS = {
+    "lfm2": dict(n_routed_experts=32, intermediate_size=1792,
+                 n_shared_experts=0, norm_topk_eps=1e-6),
+    "glm": dict(n_routed_experts=64, intermediate_size=1536,
+                n_shared_experts=1, routed_scaling_factor=1.8)}
+
+
+def _made_under(text, ops, scopes) -> set:
+    """The dimensions of every array a device op under one of `scopes`
+    makes (its result, or the first of a tuple's)."""
+    made = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+)\[([\d,]*)\]", line)
+        if m and m.group(1) in ops and any(
+                s in ops[m.group(1)]["scope"].split("/") for s in scopes):
+            made.add(tuple(int(n) for n in m.group(3).split(",") if n))
+    return made
+
+
+def _routing_walks_rows(text, ops, trainer, slot_side_gathers: int,
+                        buffer_rows: int):
+    """A compiled round's routing: `routing_moves` of a step body gathers
+    3 x the buffer's rows an expert layer and `slot_side_gathers` x tokens x
+    k in all, and no op under the routing scopes makes an array of tokens x
+    k rows of the model's width."""
+    from sparknet_tpu.obs.device import routing_moves
+    scopes, d = trainer.net.routing_scopes()
+    tokens, k = 2 * 8192, 4
+    moves = routing_moves(ops, scopes, d)
+    layers = len(trainer.net.counter_blobs())
+    assert moves["rows_gathered"] <= (slot_side_gathers * tokens * k
+                                      + 3 * layers * buffer_rows), moves
+    made = _made_under(text, ops, scopes)
+    assert not made & {(tokens * k, d), (tokens, k, d)}, made
+
+
+def _slot_side_pair(sl):
+    """The form routing had before its data passes walked the buffer's rows,
+    as (rows_of_tokens, sum_by_token) over the same plan: gathers of every
+    slot's row (`jnp.take`, which masks out-of-bounds afterwards), reshaped
+    [tokens, k, d] and summed over k, and `dw` from a second slot-side gather
+    of `y`. Kept here so that `routing_moves` is shown to tell the two apart."""
+    def slot_rows(y, plan):
+        ys = jnp.where(plan["slot_ok"].reshape(-1, 1),
+                       jnp.take(y, plan["slot_row"].reshape(-1), axis=0), 0)
+        return ys.astype(jnp.float32).reshape(*plan["slot_ok"].shape, -1)
+
+    @jax.custom_vjp
+    def gather_rows(xf, plan):
+        return jnp.take(xf, plan["tok"], axis=0)
+
+    def gather_rows_bwd(plan, g):
+        return jnp.sum(slot_rows(g, plan), axis=1).astype(g.dtype), None
+
+    gather_rows.defvjp(lambda xf, plan: (gather_rows(xf, plan), plan),
+                       gather_rows_bwd)
+
+    @jax.custom_vjp
+    def combine(y, w, plan):
+        return jnp.sum(slot_rows(y, plan) * w[:, :, None], axis=1).astype(y.dtype)
+
+    def combine_bwd(res, g):
+        y, w, plan = res
+        w_row = jnp.where(plan["row_ok"],
+                          jnp.take(w.reshape(-1), plan["row_slot"]), 0.0)
+        dy = (jnp.take(g, plan["tok"], axis=0).astype(jnp.float32)
+              * w_row[:, None]).astype(y.dtype)
+        dw = jnp.sum(slot_rows(y, plan) * g.astype(jnp.float32)[:, None, :],
+                     axis=-1)
+        return dy, dw, None
+
+    combine.defvjp(lambda y, w, plan: (combine(y, w, plan), (y, w, plan)),
+                   combine_bwd)
+    return gather_rows, combine
+
+
+@pytest.mark.parametrize("cell,form", [("lfm2", "rows"), ("glm", "rows"),
+                                       ("lfm2", "slots")])
+def test_routing_walks_the_buffers_rows_not_the_steps_slots(
+        v5e, as_tpu, monkeypatch, cell, form):
+    """The lone expert layer at both sequence cells' shapes (T = 16,384
+    tokens, k = 4, d = 2,048; a buffer of R = 32,768 rows for LFM2-8B-A1B's,
+    16,384 for GLM-4.7-Flash's), forward + backward in a recomputation block
+    as the net builds it, for a v5e, ~20 s each: it compiles (the grouped
+    products as kernels), and under `router` / `dispatch` / `combine` no op
+    makes an array of T x k rows of width d, as one [T k, d] or as [T, k, d]
+    -- the combine and the dispatch's backward fetch T rows k times and add
+    them in one pass, `dw` comes from the rows the backward fetches anyway --
+    so `routing_moves` counts 2 T k + 3 R rows gathered. The same query on
+    the form the layer had (`_slot_side_pair`) reads 3 T k + 3 R and finds
+    those arrays: the counter tells the two apart."""
+    from sparknet_tpu.model import seq_layers as sl
+    from sparknet_tpu.model.spec import MoEParam
+    from sparknet_tpu.obs.device import parse_hlo_ops, routing_moves
+    tokens, k, d = 16384, 4, 2048
+    p = MoEParam(experts_held=(0, 8), num_experts_per_tok=k,
+                 capacity_factor=2.0, **_EXPERT_LAYERS[cell])
+    rows, slots = sl.moe_capacity(p, tokens), tokens * k
+    assert rows == {"lfm2": 32768, "glm": 16384}[cell]
+    if form == "slots":
+        gather_rows, combine = _slot_side_pair(sl)
+        monkeypatch.setattr(sl, "rows_of_tokens", gather_rows)
+        monkeypatch.setattr(sl, "sum_by_token", combine)
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda l, dtype=None: jax.ShapeDtypeStruct(
+        l.shape, dtype or l.dtype, sharding=one)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: sl.init_moe_params(jax.random.PRNGKey(0), p, d)))
+    x = on_chip(jax.ShapeDtypeStruct((2, tokens // 2, d), jnp.bfloat16))
+
+    def loss(params, x):
+        with jax.named_scope("MoE/lone"):  # as `CompiledNet.apply` opens it
+            out = jax.checkpoint(
+                lambda pp, xx: sl.moe(p, pp, xx, _seq_ctx())[0])(params, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    precision.set_policy("bfloat16")
+    try:
+        text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), params, x)
+    finally:
+        precision.set_policy("float32")
+    assert "gmm" in text and "tgmm" in text
+    ops = parse_hlo_ops(text)
+    moves = routing_moves(ops, sl.ROUTING_SCOPES, d)
+    made = _made_under(text, ops, sl.ROUTING_SCOPES)
+    by_slot = {(slots, d), (tokens, k, d)}
+    if form == "rows":
+        assert not made & by_slot, made & by_slot
+        assert f"[{tokens},{k},{d}]" not in text
+        assert moves["row_gathers"] == 3 + 2 * k, moves
+        assert moves["rows_gathered"] == 2 * slots + 3 * rows, moves
+    else:
+        assert made & by_slot
+        assert moves["rows_gathered"] == 3 * slots + 3 * rows, moves
+    assert moves["instructions"] > 0 and moves["bytes"] > 0
+
+
 def _sequence_round(v5e, config: str):
     """(compiled, trainer) of a sequence configuration's benchmark round
     (`benchmark/configs/<config>.json`: the published widths, 2 x 8,192
@@ -478,6 +617,10 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves  # 96 before PR 30
     assert moves["bytes"] < 57e9, moves  # 94.9 GB a step body before, 42.8 now
+    # four expert layers fetch tokens x k rows twice a step (the combine, the
+    # dispatch's backward), the MTP module's a third time (its combine is
+    # made again for the norm that follows it)
+    _routing_walks_rows(text, ops, trainer, 4 * 2 + 3, 16384)
 
 
 @pytest.mark.slow
@@ -502,3 +645,4 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (2, 0)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
+    _routing_walks_rows(text, ops, trainer, 8 * 2, 32768)

@@ -320,6 +320,11 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
         "kernel": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": ROWS * POS * GQA_P.num_heads * GQA_P.head_dim * 4}}
     assert report["attention_moves"]["instructions"] > 0
+    # ... and what its three expert layers move around their products (what
+    # the counts come to is the chip compiler's: tests/test_chip_compile.py)
+    moves = report["routing_moves"]
+    assert set(moves) == {"instructions", "bytes", "row_gathers", "rows_gathered"}
+    assert moves["row_gathers"] > 0 and moves["rows_gathered"] % ROWS == 0
     scopes = {op["scope"] for op in report["ops"].values()}
     for part in ("ShortConv/l0_conv)/in_proj", "ShortConv/l0_conv)/mix",
                  "ShortConv/l0_conv)/out_proj", "GQAttention/l1_attn)/core"):
@@ -345,6 +350,7 @@ def test_zoo_follows_layer_types_and_names_what_a_block_keeps():
     net = _net()
     assert net.kept_kernels() == {sl.ATTN_CORE: "splash_mha_fwd"}
     assert net.attention_scopes() == ({"GQAttention": ""}, POS)
+    assert net.routing_scopes() == (sl.ROUTING_SCOPES, TINY["hidden_size"])
     assert sum(int(np.prod(s)) for lp in ref.param_shapes(LAYERS).values()
                for s in lp.values()) == sum(
         int(np.prod(v.shape)) for lp in jax.eval_shape(

@@ -375,6 +375,131 @@ def test_moe_gradients_match_autodiff_of_the_reference():
     assert float(jnp.max(jnp.abs(mine[0]["router_bias"]))) == 0
 
 
+# -- the pair dispatch and combine are written as ---------------------------
+
+def _tight_keep(chosen, experts_held, rows):
+    """Which slots [tokens, k] land AND find room, as the layer's rule has
+    it: sorted by held expert (stable), dropped from the END."""
+    first, held = experts_held
+    flat = np.asarray(chosen).reshape(-1)
+    key = np.where((flat >= first) & (flat < first + held), flat - first, held)
+    order = np.argsort(key, kind="stable")
+    keep = np.zeros(flat.shape, bool)
+    keep[order[:min(rows, int(np.sum(key < held)))]] = True
+    return keep.reshape(np.shape(chosen))
+
+
+#: landing share -> the held experts of 8 (every token chooses k distinct of
+#: 8; for "none", of the seven that are not held)
+_SHARES = {"none": (7, 1), "eighth": (3, 1), "quarter": (2, 2), "every": (0, 8)}
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("share,room", [("none", None), ("eighth", None),
+                                        ("quarter", None), ("every", None),
+                                        ("quarter", 16), ("every", 40)])
+def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
+        share, room, k, policy):
+    """`rows_of_tokens` and `sum_by_token` against the dense one-hot matrix
+    D[r, t] = (row r holds a slot of token t that landed and found room):
+    rows = D xf on those rows, sum = D^T (w_row * rows) in float32, and each
+    one's `jax.vjp` is the other -- whatever share of the slots lands (none,
+    1 in 8, 1 in 4, every one), for 2 and 4 choices a token, with room for
+    all and with a tight buffer that drops, in both policies. Rows no slot
+    landed in hold NaN: nothing may read them."""
+    tokens, d = 48, 16
+    rng = np.random.default_rng(7 + k)
+    held = _SHARES[share]
+    idx = jnp.asarray(np.stack([rng.permutation(7 if share == "none" else 8)[:k]
+                                for _ in range(tokens)]), jnp.int32)
+    rows = room or max(8, tokens * min(k, held[1]))
+    plan, sizes, kept_sizes = sl._plan(idx, held, rows)
+    keep = _tight_keep(idx, held, rows)
+    assert int(jnp.sum(kept_sizes)) == keep.sum() <= int(jnp.sum(sizes))
+    assert room is None or keep.sum() == room < int(jnp.sum(sizes)), "it drops"
+    assert np.array_equal(np.asarray(plan["slot_ok"]), keep)
+    n = int(keep.sum())
+    assert np.asarray(plan["row_ok"]).tolist() == [True] * n + [False] * (rows - n)
+    # the dense matrix, from the plan's row side alone
+    ok, tok = np.asarray(plan["row_ok"]), np.asarray(plan["tok"])
+    dense = jnp.asarray(ok[:, None] & (tok[:, None] == np.arange(tokens)),
+                        jnp.float32)
+    assert np.array_equal(np.asarray(dense.sum(0)), keep.sum(1))  # <= k a token
+    dtype = jnp.float32 if policy == "float32" else jnp.bfloat16
+    xf = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
+    w = jnp.asarray(rng.random((tokens, k)) + 0.1, jnp.float32)
+    y = jnp.where(ok[:, None], jnp.asarray(rng.standard_normal((rows, d)), dtype),
+                  jnp.nan)
+    g = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
+    w_row = jnp.where(ok, w.reshape(-1)[np.asarray(plan["row_slot"])], 0.0)
+    clean = lambda a: jnp.where(ok[:, None], a.astype(jnp.float32), 0.0)
+
+    def dense_sum(y, w_row):
+        return jnp.einsum("rt,r,rd->td", dense, w_row, clean(y),
+                          precision="highest")
+
+    got, vjp_rows = jax.vjp(lambda x: sl.rows_of_tokens(x, plan), xf)
+    assert np.array_equal(np.asarray(clean(got)), np.asarray(dense @ xf.astype(
+        jnp.float32))), "a gather: exact"
+    out, vjp_sum = jax.vjp(lambda y, w: sl.sum_by_token(y, w, plan), y, w)
+    assert out.dtype == dtype and bool(jnp.all(jnp.isfinite(out)))
+    _close(out, dense_sum(y, w_row), policy)
+    # each is the other's transpose: rows' cotangent (NaN where nothing
+    # landed) summed by token, the sum's cotangent fetched by row
+    (dxf,) = vjp_rows(y)
+    _close(dxf, dense_sum(y, ok.astype(np.float32)), policy)
+    dy, dw = vjp_sum(g)
+    want_dy, want_dw_row = jax.vjp(dense_sum, clean(y), w_row)[1](
+        g.astype(jnp.float32))
+    assert not np.any(np.asarray(dy, np.float32)[~ok]), "zero where nothing landed"
+    _close(dy, want_dy, policy)
+    want_dw = np.zeros((tokens * k,), np.float32)
+    want_dw[np.asarray(plan["row_slot"])[ok]] = np.asarray(want_dw_row)[ok]
+    assert not np.any(np.asarray(dw)[~keep])
+    _close(dw, want_dw.reshape(tokens, k), policy)
+
+
+@pytest.mark.parametrize("factor", [0.5, 1.0])
+def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(factor):
+    """The gradients of the whole layer -- the router's (through `dw`), the
+    experts' (through `dy`) and the input's (through `dxf` and the router) --
+    when the buffer is too small and slots are dropped: the reference's, with
+    the dropped slots' weights zeroed in it. A bias sends most tokens to the
+    two held experts, so that much more lands than finds room (2,048 tokens:
+    the buffer is whole tiles of the grouped product, 512 or 1,024 rows)."""
+    tight = MoEParam(**{**MOE_P.__dict__, "capacity_factor": factor})
+    p = _params(7)
+    p = dict(p, router_bias=jnp.zeros((8,)).at[2].set(0.4).at[3].set(0.3))
+    x = _x(62, (ROWS, 1024, D))
+    room = sl.moe_capacity(tight, ROWS * 1024)
+    _, counters, chosen = sl.moe(tight, p, x, CTX)
+    assert float(counters[1]) == float(counters[0]) - room > 0, "it drops"
+    keep = jnp.asarray(_tight_keep(chosen.reshape(-1, 2), (2, 2), room))
+
+    def reference(p, x):
+        xf = x.reshape(-1, D)
+        idx, w = ref.route(MOE, p, xf)
+        w = jnp.where(keep, w, 0.0)
+        y = ref.swiglu(xf, p["shared_gate"], p["shared_up"], p["shared_down"],
+                       "float32")
+        for e in range(2):
+            w_e = jnp.sum(jnp.where(idx == 2 + e, w, 0.0), axis=-1)
+            y = y + w_e[:, None] * ref.swiglu(
+                xf, p["experts_gate"][e], p["experts_up"][e],
+                p["experts_down"][e], "float32")
+        return jnp.sum(y ** 2)
+
+    mine = jax.grad(lambda p, x: jnp.sum(sl.moe(tight, p, x, CTX)[0] ** 2),
+                    argnums=(0, 1))(p, x)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(reference, argnums=(0, 1))(p, x)
+    for name in want[0]:
+        if name != "router_bias":
+            _close(mine[0][name], want[0][name], "float32")
+    _close(mine[1], want[1], "float32")
+
+
 # -- the whole model ---------------------------------------------------------
 
 def _net():
@@ -783,6 +908,83 @@ def test_attention_moves_counts_what_attention_moves_without_computing():
     assert registry.gauge("sparknet_b_round_attention_moves_bytes").value() == got["bytes"]
     assert registry.gauge("sparknet_b_round_attention_moves_gathers_scatters").value() == 1.0
     assert obs_device.program_part("attention_moves")["b_round"] == got
+
+
+ROUTES_HLO = '''HloModule jit_train_round
+
+%fused_rows (x: bf16[4,8], i: s32[6]) -> bf16[6,8] {
+  %x = bf16[4,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  ROOT %gather.1 = bf16[6,8]{1,0} gather(%x, %i), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,8}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/dispatch/gather"}
+}
+
+%fused_sum (y: bf16[6,8], i: s32[4], j: s32[4]) -> bf16[4,8] {
+  %y = bf16[6,8]{1,0} parameter(0)
+  %i = s32[4]{0} parameter(1)
+  %j = s32[4]{0} parameter(2)
+  %gather.2 = bf16[4,8]{1,0} gather(%y, %i), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,8}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/moe/combine/gather"}
+  %gather.3 = bf16[4,8]{1,0} gather(%y, %j), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,8}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/moe/combine/gather"}
+  ROOT %add.1 = bf16[4,8]{1,0} add(%gather.2, %gather.3), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MTP/mtp)/moe/combine/add"}
+}
+
+%fused_weights (w: f32[8], i: s32[6]) -> f32[6] {
+  %w = f32[8]{0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  ROOT %gather.4 = f32[6]{0} gather(%w, %i), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(MoE/l1_moe))/combine/gather"}
+}
+
+%body.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8]) -> bf16[4,8] {
+  %x = bf16[4,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %j = s32[4]{0} parameter(2)
+  %w = f32[8]{0} parameter(3)
+  %rows.1 = bf16[6,8]{1,0} fusion(%x, %i), kind=kLoop, calls=%fused_rows
+  %weights.1 = f32[6]{0} fusion(%w, %i), kind=kLoop, calls=%fused_weights
+  %copy.1 = bf16[6,8]{0,1} copy(%rows.1), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/experts/transpose"}
+  %sort.1 = s32[4]{0} sort(%j), dimensions={0}, to_apply=%lt, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/router/sort"}
+  ROOT %sum.1 = bf16[4,8]{1,0} fusion(%rows.1, %j, %sort.1), kind=kLoop, calls=%fused_sum
+}
+
+ENTRY %main.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8]) -> bf16[4,8] {
+  %x = bf16[4,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %j = s32[4]{0} parameter(2)
+  %w = f32[8]{0} parameter(3)
+  %peeled.1 = bf16[6,8]{1,0} fusion(%x, %i), kind=kLoop, calls=%fused_rows
+  ROOT %call.1 = bf16[4,8]{1,0} call(%x, %i, %j, %w), to_apply=%body.1
+}
+'''
+
+
+def test_routing_moves_counts_the_rows_routing_gathers():
+    """In the loop's body, under an expert layer's and the MTP module's
+    routing scopes: a fusion that gathers 6 rows of width 8, one that holds
+    two gathers of 4 rows, one that gathers 6 SCALARS (no row), a sort; a
+    copy under `experts`, which is no routing. The peeled step holds one
+    gather: the body that moves most is the one reported. `attention_moves`
+    and `routing_moves` are two calls of one query."""
+    from sparknet_tpu.obs import device as obs_device
+    ops = obs_device.parse_hlo_ops(ROUTES_HLO)
+    assert ops["%sum.1"]["gathered"] == [(4, 8), (4, 8)]
+    assert ops["%weights.1"]["gathered"] == [(6,)]
+    got = obs_device.routing_moves(ops, sl.ROUTING_SCOPES, width=8)
+    counted = ("%rows.1", "%weights.1", "%sort.1", "%sum.1")
+    assert got == {"instructions": 4, "row_gathers": 3, "rows_gathered": 14,
+                   "bytes": sum(ops[n]["bytes"] for n in counted)}
+    # another width: the same ops, no rows of it
+    assert obs_device.routing_moves(ops, sl.ROUTING_SCOPES, 16) == {
+        **got, "row_gathers": 0, "rows_gathered": 0}
+    assert obs_device.routing_moves(ops, (), 0) == {}
+    # the query both counters are calls of
+    under_experts = obs_device.moves_under(
+        ops, lambda op, parts: "experts" in parts, {"copies": lambda op: 1})
+    assert under_experts == {"instructions": 1, "copies": 1,
+                             "bytes": ops["%copy.1"]["bytes"]}
+    assert obs_device.moves_under(ops, lambda op, parts: False, {"n": len}) == {
+        "instructions": 0, "bytes": 0, "n": 0}
+    # the net says which scopes and which width; a net without expert layers none
+    assert _net().routing_scopes() == (sl.ROUTING_SCOPES, D)
+    assert "routing_moves" in obs_device.REPORT_PARTS
 
 
 # -- the compiled text's multi-line instructions -----------------------------
