@@ -173,7 +173,7 @@ class CompiledNet:
         from .seq_layers import KEPT_KERNELS
         return {n: KEPT_KERNELS[n] for n in _kept_names(
             l for l in self.spec.layers_for_phase("TRAIN")
-            if l.block is not None)}
+            if l.block is not None) if n in KEPT_KERNELS}
 
     def attention_scopes(self) -> Tuple[Dict[str, str], int]:
         """({layer type: the scope under such a layer's own that holds its
@@ -186,6 +186,17 @@ class CompiledNet:
         return ({l.type: ATTENTION_SCOPES[l.type] for l in layers},
                 max((self.blob_shapes[l.bottoms[0]][1] for l in layers),
                     default=0))
+
+    def delta_scopes(self) -> Tuple[Dict[str, str], Tuple[str, ...]]:
+        """({layer type: the scope under such a layer's own that holds its
+        delta rule}, the names those layers' blocks keep for the backward
+        pass) for the types of this net's layers in
+        `seq_layers.DELTA_SCOPES`; ({}, ()) for a net without any."""
+        from .seq_layers import DELTA_SCOPES
+        layers = [l for l in self.spec.layers_for_phase("TRAIN")
+                  if l.type in DELTA_SCOPES]
+        return ({l.type: DELTA_SCOPES[l.type] for l in layers},
+                _kept_names(l for l in layers if l.block is not None))
 
     def routing_scopes(self) -> Tuple[Tuple[str, ...], int]:
         """(the scopes under which this net's expert layers choose experts

@@ -1,10 +1,13 @@
 """The sequence-model layer set: what a sparse-expert decoder is built from.
 
 Embed, RMSNorm, two kinds of attention -- MLAttention (multi-head latent
-attention) and GQAttention (grouped-query attention with per-head q/k norms)
--- ShortConv (a gated short convolution: the operator a hybrid decoder sets
-between its attention layers), GatedMLP (SwiGLU), MoE (routed experts of
-which this chip holds a share, with or without a shared one), MTP (a
+attention, its queries through a latent or projected directly) and
+GQAttention (grouped-query attention with per-head q/k norms) -- KDAttention
+(a linear attention: one matrix state a head, updated by the gated delta
+rule, `ops.delta_rule`), ShortConv (a gated short convolution: the operator a
+hybrid decoder sets between its attention layers), GatedMLP (SwiGLU), MoE
+(routed experts of which this chip holds a share, with or without a shared
+one, chosen among all or among the best groups), MTP (a
 multi-token-prediction module) and Eltwise (the residual sum). Same three
 functions a layer type as `layers.py` (`init_`, `apply_`, `infer_`);
 registered there in `LAYER_IMPLS`. Activations are `[rows, positions, d]`;
@@ -53,8 +56,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
-from .spec import (GQAttentionParam, LayerSpec, MLAttentionParam, MoEParam,
-                   ParamSpec)
+from ..ops import delta_rule
+from .spec import (GQAttentionParam, KDAttentionParam, LayerSpec,
+                   MLAttentionParam, MoEParam, ParamSpec)
 
 Params = Dict[str, jnp.ndarray]
 
@@ -76,8 +80,10 @@ GMM_TILING = (512, 512, 512)
 MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
                 "expert_tokens_min")
 #: the name (`jax.ad_checkpoint.checkpoint_name`) on the attention core's
-#: output and softmax statistics
+#: output and softmax statistics, and that on a Kimi Delta Attention layer's
+#: result
 ATTN_CORE = "attn_core"
+KDA_OUT = "kda_out"
 
 
 def param_defaults(pname: str) -> ParamSpec:
@@ -189,16 +195,23 @@ def apply_gatedmlp(layer: LayerSpec, params: Params, inputs, ctx):
 # -- MLAttention -------------------------------------------------------------
 
 def init_mla(key, p: MLAttentionParam, d: int) -> Params:
-    ks = jax.random.split(key, 5)
+    # (a layer without the gate draws the five keys it always drew)
+    ks = jax.random.split(key, 6 if p.output_gate else 5)
     qk = p.qk_nope_head_dim + p.qk_rope_head_dim
-    return {
+    queries = {
         "q_a": _normal(ks[0], (d, p.q_lora_rank), p.std),
         "q_a_norm": jnp.ones((p.q_lora_rank,), jnp.float32),
         "q_b": _normal(ks[1], (p.q_lora_rank, p.num_heads * qk), p.std),
+    } if p.q_lora_rank else {"q": _normal(ks[0], (d, p.num_heads * qk), p.std)}
+    gate = {"out_gate": _normal(ks[5], (d, p.num_heads), p.std)} \
+        if p.output_gate else {}
+    return {
+        **queries,
         "kv_a": _normal(ks[2], (d, p.kv_lora_rank + p.qk_rope_head_dim), p.std),
         "kv_a_norm": jnp.ones((p.kv_lora_rank,), jnp.float32),
         "kv_b": _normal(ks[3], (p.kv_lora_rank, p.num_heads * (
             p.qk_nope_head_dim + p.v_head_dim)), p.std),
+        **gate,
         "o": _normal(ks[4], (p.num_heads * p.v_head_dim, d), p.std)}
 
 
@@ -293,17 +306,19 @@ def mla(p: MLAttentionParam, params: Params, x, ctx):
     untouched, q through the rotary turn alone, k through the concatenation
     that sets the one shared rotary key beside every head's keys, and the
     output projection contracts (heads, d) of the core's result as it
-    lies."""
+    lies. Without a query latent (`q_lora_rank` 0 or None) the one matrix `q`
+    takes `q_b`'s place and reads x itself, with no norm before it."""
     h, nope, rope = p.num_heads, p.qk_nope_head_dim, p.qk_rope_head_dim
     rank = p.kv_lora_rank
-    w_q = params["q_b"].reshape(-1, h, nope + rope)
+    w_q = params["q_b" if p.q_lora_rank else "q"].reshape(-1, h, nope + rope)
     w_q = jnp.concatenate([w_q[..., :nope], half_split(w_q[..., nope:])],
                           axis=-1) / np.sqrt(nope + rope)
     w_kv = params["kv_b"].reshape(rank, h, nope + p.v_head_dim)
     w_k_rope = half_split(params["kv_a"][:, rank:])[:, None, :]  # one "head"
     w_o = params["o"].reshape(h, p.v_head_dim, -1)
 
-    c_q = _rms(_dot(x, params["q_a"]), params["q_a_norm"], p.eps)
+    c_q = _rms(_dot(x, params["q_a"]), params["q_a_norm"], p.eps) \
+        if p.q_lora_rank else x
     c_kv = _rms(_dot(x, params["kv_a"][:, :rank]), params["kv_a_norm"], p.eps)
     heads_first = "rnc,chd->rhnd"
     q = rotary(_project(heads_first, c_q, w_q), p.rope_theta, rope)
@@ -315,7 +330,17 @@ def mla(p: MLAttentionParam, params: Params, x, ctx):
     v = _project(heads_first, c_kv, w_kv[..., nope:])
     with jax.named_scope("core"):
         o = attention_core(q, k, v, ctx)
+    if p.output_gate:
+        o = _head_gate(o, x, params["out_gate"])
     return _project("rhnd,hdm->rnm", o, w_o)
+
+
+def _head_gate(o, x, w_gate):
+    """o [rows, heads, positions, d] scaled by sigmoid(x w_h): one scalar a
+    head and position (float32), the result in the compute dtype."""
+    gate = jax.nn.sigmoid(_project("rnc,ch->rhn", x, w_gate).astype(jnp.float32))
+    return (o.astype(jnp.float32) * gate[..., None]).astype(
+        precision.compute_dtype())
 
 
 def apply_mlattention(layer: LayerSpec, params: Params, inputs, ctx):
@@ -370,14 +395,18 @@ def init_shortconv(key, layer: LayerSpec, in_shapes) -> Params:
 
 
 def causal_taps(s, w):
-    """c[:, t] = sum_j w[:, j] * s[:, t - (taps - 1) + j] over s [rows,
-    positions, d] with w [d, taps]: a depthwise causal convolution, zeros
-    before position 0, as shifted sums (a pad and a contiguous slice each)."""
-    taps, n = w.shape[-1], s.shape[1]
-    out = s * w[:, taps - 1]
+    """c[.., t, :] = sum_j w[.., j] * s[.., t - (taps - 1) + j, :] over s
+    [rows, positions, d] with w [d, taps], or s [rows, heads, positions, d]
+    with w [heads, d, taps]: a depthwise causal convolution, zeros before
+    position 0, as shifted sums (a pad and a contiguous slice each)."""
+    taps, n = w.shape[-1], s.shape[-2]
+    # a head's taps stand over the positions' axis
+    tap = (lambda j: w[:, j]) if w.ndim == 2 else (lambda j: w[:, None, :, j])
+    ahead = ((0, 0),) * (s.ndim - 2)
+    out = s * tap(taps - 1)
     for j in range(taps - 1):
         back = taps - 1 - j
-        out = out + jnp.pad(s, ((0, 0), (back, 0), (0, 0)))[:, :n] * w[:, j]
+        out = out + jnp.pad(s, ahead + ((back, 0), (0, 0)))[..., :n, :] * tap(j)
     return out
 
 
@@ -392,6 +421,90 @@ def apply_shortconv(layer: LayerSpec, params: Params, inputs, ctx):
         y = (c * causal_taps(b * z, params["conv"])).astype(bcz.dtype)
     with jax.named_scope("out_proj"):
         return (_dot(y, params["out_proj"]),)
+
+
+# -- KDAttention -------------------------------------------------------------
+
+def init_kdattention(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.kda, in_shapes[0][-1]
+    ks = jax.random.split(key, 11)
+    hd = p.num_heads * p.head_dim
+    return {"q": _normal(ks[0], (d, hd), p.std),
+            "k": _normal(ks[1], (d, hd), p.std),
+            "v": _normal(ks[2], (d, hd), p.std),
+            "q_conv": _normal(ks[3], (hd, p.taps), p.std),
+            "k_conv": _normal(ks[4], (hd, p.taps), p.std),
+            "v_conv": _normal(ks[5], (hd, p.taps), p.std),
+            "a": _normal(ks[6], (d, hd), p.std),
+            "dt_bias": _normal(ks[7], (hd,), p.std),
+            "A_log": jnp.zeros((p.num_heads,), jnp.float32),
+            "beta": _normal(ks[8], (d, p.num_heads), p.std),
+            "out_gate": _normal(ks[9], (d, p.num_heads), p.std),
+            "o_norm": jnp.ones((p.head_dim,), jnp.float32),
+            "o": _normal(ks[10], (hd, d), p.std)}
+
+
+def kda(p: KDAttentionParam, params: Params, x, ctx):
+    """Kimi Delta Attention, laid out as `mla` and `gqa` lay theirs out: the
+    products of x with views of the stored matrices come out heads first,
+    [rows, heads, positions, d]; the three convolutions run over the
+    positions of that layout (`causal_taps`, float32, SiLU after); the L2
+    norms, the decay and the writing strength are float32; the rule itself
+    is `ops.delta_rule.gated_delta_rule`; its result is normed a head, scaled
+    by the head-wise gate and contracted with `o` as it lies.
+
+    ONE ROW AT A TIME, in a checkpointed `lax.map`: between its products the
+    layer is some thirty float32 passes over [heads, positions, d] (0.13 GB
+    each at 32 x 8,192 x 128) that the backward pass holds together, 4.4 GB
+    for two rows at once against 2.4 (compiled for a v5e, PERF.md section 6,
+    PR 33); a row's are made again when its turn comes. The two elementwise
+    stages (what shapes q, k, v and the decay from the projections; the norm
+    and gate of the result) are checkpoints of their own for the same
+    reason."""
+    h, hd, d = p.num_heads, p.head_dim, x.shape[-1]
+    heads_first, f32 = "rnc,chd->rhnd", jnp.float32
+    view = lambda name: params[name].reshape(d, h, hd)
+
+    def shaped(q, k, v, a, b):
+        with jax.named_scope("conv"):
+            q, k, v = (jax.nn.silu(causal_taps(
+                t.astype(f32), params[n + "_conv"].reshape(h, hd, p.taps)))
+                for t, n in ((q, "q"), (k, "k"), (v, "v")))
+        with jax.named_scope("gates"):
+            unit = lambda t: t * lax.rsqrt(
+                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+            g = p.lower_bound * jax.nn.sigmoid(
+                jnp.exp(params["A_log"])[:, None, None]
+                * (a.astype(f32) + params["dt_bias"].reshape(h, 1, hd)))
+            cast = lambda t: t.astype(precision.compute_dtype())
+            return (cast(unit(q) * hd ** -0.5), cast(unit(k)), cast(v), g,
+                    jax.nn.sigmoid(b.astype(f32)))
+
+    def rows(x):
+        with jax.named_scope("in_proj"):
+            q, k, v = (_project(heads_first, x, view(n)) for n in "qkv")
+        with jax.named_scope("gates"):
+            a = _project(heads_first, x, view("a"))
+            b = _project("rnc,ch->rhn", x, params["beta"])
+        q, k, v, g, beta = jax.checkpoint(shaped)(q, k, v, a, b)
+        with jax.named_scope("delta"):
+            o = delta_rule.gated_delta_rule(q, k, v, g, beta)
+        with jax.named_scope("out_gate"):
+            o = jax.checkpoint(lambda o, x: _head_gate(
+                _rms(o, params["o_norm"], p.eps), x, params["out_gate"]))(o, x)
+        with jax.named_scope("out_proj"):
+            return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
+
+    # the block keeps the layer's result (KEPT_NAMES): the rows' checkpoints
+    # need nothing of the forward pass but its inputs, so with the result at
+    # hand the block's backward pass never runs the layer forward a second
+    # time before the rows' own recomputation does
+    return checkpoint_name(lax.map(
+        jax.checkpoint(lambda row: rows(row[None])[0]), x), KDA_OUT)
+
+
+def apply_kdattention(layer: LayerSpec, params: Params, inputs, ctx):
+    return (kda(layer.kda, params, inputs[0], ctx),)
 
 
 # -- MoE ---------------------------------------------------------------------
@@ -429,13 +542,22 @@ def init_moe(key, layer: LayerSpec, in_shapes) -> Params:
 
 def route(p: MoEParam, params: Params, xf):
     """(chosen experts [tokens, k] int32, their weights [tokens, k] f32):
-    sigmoid scores in float32, the top k of score + bias, weights the
-    chosen scores normalised and scaled (`noaux_tc`, one group)."""
+    sigmoid scores in float32, the top k of score + bias -- among all the
+    routed experts (`n_group` 1) or among those of the `topk_group` groups
+    whose two best entries of score + bias sum highest -- weights the chosen
+    scores normalised and scaled (`noaux_tc`)."""
     s = jax.nn.sigmoid(jnp.dot(
         xf.astype(jnp.float32), params["router"].astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(s + lax.stop_gradient(params["router_bias"]),
-                       p.num_experts_per_tok)
+    choice = s + lax.stop_gradient(params["router_bias"])
+    if p.n_group > 1:
+        grouped = choice.reshape(choice.shape[0], p.n_group, -1)
+        _, best = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1),
+                            p.topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(p.n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
+            choice.shape)
+    _, idx = lax.top_k(choice, p.num_experts_per_tok)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if p.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_topk_eps)
@@ -629,16 +751,27 @@ def apply_mtp(layer: LayerSpec, params: Params, inputs, ctx):
 COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
 #: layer type -> the names its implementation puts on values a recomputation
 #: block keeps for the backward pass (`mla` serves both)
+#: KDAttention names its RESULT (84 MB a layer at [2, 8192, 2560] bf16) and
+#: nothing of the rule: what the rule's backward needs (every chunk's state
+#: and pseudo-values, 0.8 GB a layer) the layer's own checkpoints make again
+#: a row and a segment at a time, from the layer's inputs alone -- so a
+#: block that holds the result has no reason to run the layer again itself
+#: (PERF.md section 6, PR 33)
 KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
-              "GQAttention": (ATTN_CORE,)}
+              "GQAttention": (ATTN_CORE,), "KDAttention": (KDA_OUT,)}
 #: kept name -> the name of the Pallas kernel that computes its values, as a
 #: compiled program's text has it: run again in the backward pass only if
-#: the name did not reach a recomputation block's policy
+#: the name did not reach a recomputation block's policy (a kept value no
+#: kernel makes has no entry)
 KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts
-ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": ""}
+ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": "",
+                    "KDAttention": ""}
+#: layer type -> the named scope, under the layer's own, that holds its
+#: delta rule: whose loops and device ops `obs.device.delta_rule` counts
+DELTA_SCOPES = {"KDAttention": "delta"}
 #: the named scopes, under an expert layer's own (`moe`), whose device ops
 #: `obs.device.routing_moves` counts
 ROUTING_SCOPES = ("router", "dispatch", "combine")
@@ -650,6 +783,7 @@ SEQ_LAYER_IMPLS = {
     "GatedMLP": (init_gatedmlp, apply_gatedmlp, infer_same),
     "MLAttention": (init_mlattention, apply_mlattention, infer_same),
     "GQAttention": (init_gqattention, apply_gqattention, infer_same),
+    "KDAttention": (init_kdattention, apply_kdattention, infer_same),
     "ShortConv": (init_shortconv, apply_shortconv, infer_same),
     "MoE": (init_moe, apply_moe, infer_moe),
     "MTP": (init_mtp, apply_mtp, infer_mtp),

@@ -141,16 +141,44 @@ class MLAttentionParam:
     """Multi-head latent attention (DeepSeek-V2's MLA): queries and
     keys/values go through low-rank latents, a rotary part of width
     `qk_rope_head_dim` rides beside `qk_nope_head_dim` in every head's
-    query and ONE rotary key is shared by all heads. Causal."""
+    query and ONE rotary key is shared by all heads. Causal.
+    `output_gate`: every head's result is scaled by sigmoid(x w_h), one
+    learned scalar a head and position, before the output projection."""
 
     num_heads: int = 0
-    q_lora_rank: int = 0
+    #: the rank of the queries' latent (`q_a`, `q_a_norm`, `q_b`); 0 or None:
+    #: no latent and no query norm, one direct projection `q` [d, heads x
+    #: (nope + rope)]
+    q_lora_rank: Optional[int] = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_theta: float = 10000.0
     eps: float = 1e-5
+    std: float = 0.02
+    output_gate: bool = False
+
+
+@dataclass(frozen=True)
+class KDAttentionParam:
+    """Kimi Delta Attention (arXiv:2510.26692), a linear attention with one
+    matrix state [head_dim, head_dim] a head, updated by the gated delta rule
+    with a per-channel decay (`ops.delta_rule`): q, k, v = SiLU(conv(x W))
+    (a depthwise causal convolution of `taps` taps each), q and k L2-normed a
+    head (q also x head_dim^-1/2); log-decay `lower_bound` x sigmoid(exp(A_h)
+    (x W_a + b)), a vector a head; writing strength sigmoid(x w_b), a scalar
+    a head; the result normed a head (one shared scale) and scaled by
+    sigmoid(x w_r), a scalar a head, before the output projection. No rotary
+    turn."""
+
+    num_heads: int = 0
+    head_dim: int = 0
+    taps: int = 4
+    #: the log-decay's lower bound (`kda_lower_bound`); the chunked rule's
+    #: float32 range rests on it (`ops.delta_rule.MIN_LOG_DECAY`)
+    lower_bound: float = -5.0
+    eps: float = 1e-6
     std: float = 0.02
 
 
@@ -210,6 +238,12 @@ class MoEParam:
     #: added to the sum of the chosen scores before the division
     #: (`norm_topk_prob`): the DeepSeek-V3 family's code adds 1e-20, LFM2's 1e-6
     norm_topk_eps: float = 1e-20
+    #: group-limited choice (DeepSeek-V3's `noaux_tc`): the routed experts
+    #: in `n_group` equal groups, a group's score the sum of its two largest
+    #: entries of score + bias, the `topk_group` best groups kept and the top
+    #: k taken among their experts; 1 / 1: one group, the plain top k
+    n_group: int = 1
+    topk_group: int = 1
     capacity_factor: Optional[float] = None
     std: float = 0.02
 
@@ -246,6 +280,7 @@ class LayerSpec:
     rmsnorm: Optional[RMSNormParam] = None
     mla: Optional[MLAttentionParam] = None
     gqa: Optional[GQAttentionParam] = None
+    kda: Optional[KDAttentionParam] = None
     shortconv: Optional[ShortConvParam] = None
     gated_mlp: Optional[GatedMLPParam] = None
     moe: Optional[MoEParam] = None
@@ -304,7 +339,8 @@ class NetSpec:
 
 # Layer types that carry trainable parameters.
 PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct", "Embed", "RMSNorm",
-                          "MLAttention", "GQAttention", "ShortConv",
+                          "MLAttention", "GQAttention", "KDAttention",
+                          "ShortConv",
                           "GatedMLP", "MoE", "MTP")
 
 

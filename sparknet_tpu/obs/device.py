@@ -228,6 +228,8 @@ _INSTRUCTION = re.compile(
     r"^\s+(ROOT\s+)?(%?[\w.\-]+)\s*=\s*(.*)$")
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_INT_CONSTANT = re.compile(r"\bs32\[\]\S* constant\((\d+)\)")
 _CALLED = re.compile(
     r"\b(calls|to_apply|select|scatter|body|condition|branch_computations|"
     r"true_computation|false_computation)=\{?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
@@ -276,13 +278,16 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
-    "row_gathers", "rows_gathered"}}` — see `parse_hlo_ops` for the
+    "row_gathers", "rows_gathered"}, "delta_rule": {"loops", "trips",
+    "carried_bytes", "instructions", "bytes", "kept_bytes"}}` — see
+    `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
     `attention_moves` for what a step's attention moves without
-    computing and `routing_moves` for what its expert layers move around
-    their products (each {} for a net without such layers; both are calls
-    of `moves_under`). None when no such
+    computing, `routing_moves` for what its expert layers move around
+    their products and `delta_rule` for how its delta rules were compiled
+    (each {} for a net without such layers; all three call `moves_under`).
+    None when no such
     program is registered or it has not been dispatched yet.
 
     NEVER on the round path: the first call lowers and compiles the program
@@ -375,7 +380,10 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
             op = _OPCODE.search(" " + body)
             name = _OP_NAME.search(rest)
             paren = body.find("(", op.start()) if op else -1
+            trips, const = _TRIPS.search(rest), _INT_CONSTANT.search(body)
             current.append({
+                "trips": int(trips.group(1)) if trips else None,
+                "const": int(const.group(1)) if const else None,
                 "name": "%" + iname.lstrip("%"), "root": root,
                 "opcode": op.group(1) if op else "",
                 "shapes": _shapes(body[:op.start()]) if op else [],
@@ -385,6 +393,15 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                 "operands": re.findall(r"%[\w.\-]+",
                                        body[paren:].split(")")[0])
                 if paren >= 0 else []})
+    for instructions in comps.values():
+        for i in instructions:
+            # a loop whose trip count the text does not state: the one
+            # integer its condition compares the counter with
+            if i["opcode"] == "while" and i["trips"] is None:
+                bounds = [c["const"] for c in comps.get(
+                    (i["called"].get("condition") or [""])[0], [])
+                    if c["const"] is not None]
+                i["trips"] = bounds[0] if len(bounds) == 1 else None
     inlined = {c for ins in comps.values() for i in ins
                for k, cs in i["called"].items() if k in _INLINED
                and i["opcode"] not in CONTAINERS for c in cs}
@@ -447,6 +464,9 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
             if o in by_name)
     out = {"bytes": nbytes, "matmul": any(
         f["opcode"] in ("convolution", "dot") for f in fused)}
+    if opcode == "while":  # what `delta_rule` reads of a loop
+        out["loop"] = {"trips": instruction["trips"],
+                       "carried_bytes": _nbytes(result)}
     inside = {f["name"]: f["shapes"] for f in fused}
     indexed = []
     for f in fused:
@@ -596,15 +616,46 @@ def routing_moves(ops: Dict[str, Dict[str, Any]], scopes: Tuple[str, ...],
          "rows_gathered": lambda op: sum(rows(op))})
 
 
+def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
+               kept_bytes: int = 0) -> Dict[str, int]:
+    """How a program's delta rules were compiled: of the device ops under
+    their scopes (`scopes`: layer type -> the scope under the layer's own
+    that holds its rule; `CompiledNet.delta_scopes()`), `{"loops": the
+    `while` instructions among them in the WHOLE program (a layer's rows go
+    through loops of their own, so a step's are spread over several
+    computations; a round holds a step twice, as the scanned body and as the
+    peeled last step), "trips": their trip counts together (a loop whose
+    count the text does not give counts 1), "carried_bytes": the most one of
+    them carries a trip (its state and what it walks), "instructions",
+    "bytes": of the ops that hold neither a matmul nor a kernel, in the
+    computation that moves most (a call of `moves_under`: one row's segment
+    of chunks), "kept_bytes": what the recomputation blocks keep of such
+    layers for the backward pass (`kept_bytes`: the caller's count of the
+    values they name)}`. {} for a net without such layers."""
+    if not scopes:
+        return {}
+    under = lambda op, parts: scopes.get(op["layer_type"]) in parts
+    loops = [op["loop"] for op in ops.values()
+             if "loop" in op and under(op, op["scope"].split("/"))]
+    moves = moves_under(ops, lambda op, parts: under(op, parts)
+                        and not op["matmul"] and op["opcode"] != "custom-call",
+                        {})
+    return {"loops": len(loops), "trips": sum(l["trips"] or 1 for l in loops),
+            "carried_bytes": max((l["carried_bytes"] for l in loops), default=0),
+            **moves, "kept_bytes": kept_bytes}
+
+
 def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
-                       routing=((), 0)) -> Dict[str, Any]:
+                       routing=((), 0), delta=({}, ())) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
     names its net's recomputation blocks keep `recompute_report`, for
     its attention layers (`attention`: their scopes and positions)
-    `attention_moves`, and for its expert layers (`routing`: their routing
-    scopes and the model's width) `routing_moves`."""
+    `attention_moves`, for its expert layers (`routing`: their routing
+    scopes and the model's width) `routing_moves`, and for its delta-rule
+    layers (`delta`: their scopes and the names their blocks keep)
+    `delta_rule`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
@@ -612,11 +663,15 @@ def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
             "ops": ops,
             "recompute": recompute_report(ops, kept_kernels or {}, jaxpr),
             "attention_moves": attention_moves(ops, *attention),
-            "routing_moves": routing_moves(ops, *routing)}
+            "routing_moves": routing_moves(ops, *routing),
+            "delta_rule": delta_rule(ops, delta[0], sum(
+                _named_bytes(jaxpr, name) for name in delta[1]
+                if jaxpr is not None))}
 
 
 #: program -> these parts of its report, once `program_report` has run
-REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves")
+REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves",
+                "delta_rule")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 

@@ -7,8 +7,8 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and two families of sequence models, each built from a file of its published
-config:
+and three families of sequence models, each built from a file of its
+published config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
                       holds a share, one multi-token-prediction module)
@@ -16,6 +16,11 @@ config:
                       (gated short convolutions among grouped-query
                       attention by the config's `layer_types`, routed
                       experts without a shared one, a tied head)
+  - ling3_flash    <- huggingface.co/inclusionAI/Ling-3.0-flash-VL config.json
+                      (the language model: Kimi Delta Attention -- a gated
+                      delta rule -- in five layers of six and latent
+                      attention with direct queries in the sixth, head-wise
+                      output gates, experts chosen among the best groups)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -27,7 +32,7 @@ from typing import Optional, Tuple
 from .model.spec import (AccuracyParam, ConvolutionParam, DropoutParam,
                          EltwiseParam, EmbedParam, Filler, GatedMLPParam,
                          GQAttentionParam, InnerProductParam, InputSpec,
-                         LayerSpec, LossParam, LRNParam, MLAttentionParam,
+                         KDAttentionParam, LayerSpec, LossParam, LRNParam, MLAttentionParam,
                          MoEParam, MTPParam, NetSpec, ParamSpec, PoolingParam,
                          RMSNormParam, ShortConvParam)
 
@@ -404,6 +409,118 @@ def lfm2_moe(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def ling3_flash(config: dict, rows: int, positions: int) -> NetSpec:
+    """A `ling3_flash` decoder (Ling-3.0-flash's language model: the file's
+    own `model_type`, the published config gives none) as ONE CHIP'S SHARE
+    of an expert-parallel deployment, for training on `[rows, positions]`
+    int32 token ids (input `tokens`; the targets are the ids themselves, read
+    one position on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- `num_hidden_layers` layers of which the first
+    `first_k_dense_replace` have a dense MLP, `num_experts` experts HELD in
+    each expert layer, `vocab_size` rows of the vocabulary HELD -- and a
+    `share` block as the other decoders': `num_experts` (the published
+    count: the router's width), `experts_held` [first, count], `vocab_rows`
+    [first, count], `chips_sharing_a_layer`, optionally `capacity_factor`,
+    and `first_layer`, the published index of the first layer held (0 where
+    absent): layer i here is published layer `first_layer` + i, and a
+    published layer j is latent attention where (j + 1) % `layer_group_size`
+    is 0 and Kimi Delta Attention elsewhere.
+
+    Pre-norm residual blocks: x += Op(RMSNorm(x)); x += FF(RMSNorm(x)), FF a
+    dense SwiGLU in the leading layers and routed experts + one shared
+    expert after, the experts chosen among the `topk_group` best of
+    `n_group` groups. Latent attention projects its queries directly
+    (`q_lora_rank` null); both operators gate their result a head. An untied
+    head over the held vocabulary rows. Loss = CE(next token), a mean over
+    the positions that have a target. Every block is a recomputation block.
+    A non-zero entry of the two swiglu-limit lists at a layer held is
+    refused: no clamp is built."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["rms_norm_eps"], 0.02
+    depth, first_layer = c["num_hidden_layers"], share.get("first_layer", 0)
+    first, held = share["experts_held"]
+    vocab = share["vocab_rows"][1]
+    if held != c["num_experts"] or vocab != c["vocab_size"]:
+        raise ValueError("the share block and the held counts disagree: "
+                         f"experts_held {share['experts_held']} against "
+                         f"num_experts {c['num_experts']}, vocab_rows "
+                         f"{share['vocab_rows']} against vocab_size "
+                         f"{c['vocab_size']}")
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        limits = c.get(key) or []
+        if any(limits[first_layer:first_layer + depth]):
+            raise ValueError(f"{key} is not zero at a layer held (published "
+                             f"layers {first_layer} to "
+                             f"{first_layer + depth - 1}): the swiglu clamp "
+                             f"is not built")
+    if (c.get("gated_attention_proj_granularity_type") != "head_wise"
+            or not c.get("kda_safe_gate") or not c.get("no_kda_lora")
+            or not c.get("linear_silu") or c.get("group_norm_size", 1) != 1
+            or c.get("q_lora_rank") or c.get("score_function") != "sigmoid"):
+        raise ValueError("built: head-wise output gates, the safe KDA gate at "
+                         "full rank, SiLU after the convolutions, one norm "
+                         "group a head, direct queries, sigmoid scores; the "
+                         "file asks for something else")
+    latent = MLAttentionParam(
+        num_heads=c["num_attention_heads"], q_lora_rank=None,
+        kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_theta=float(c["rope_theta"]), eps=eps, std=std, output_gate=True)
+    linear = KDAttentionParam(
+        num_heads=c["num_attention_heads"], head_dim=c["head_dim"],
+        taps=c["short_conv_kernel_size"],
+        lower_bound=float(c["kda_lower_bound"]), eps=eps, std=std)
+    experts = MoEParam(
+        n_routed_experts=share["num_experts"], experts_held=(first, held),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["moe_shared_expert_intermediate_size"]
+        // c["moe_intermediate_size"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=c["norm_topk_prob"], n_group=c["n_group"],
+        topk_group=c["topk_group"],
+        capacity_factor=share.get("capacity_factor"), std=std)
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",),
+                        embed=EmbedParam(num_embeddings=vocab, dim=d, std=std))]
+    for i in range(depth):
+        l, x = f"l{i}", f"x{i}"
+        is_latent = (first_layer + i + 1) % c["layer_group_size"] == 0
+        op = f"{l}_attn" if is_latent else f"{l}_kda"
+        layers += [
+            norm(f"{l}_op_norm", x, l),
+            LayerSpec(name=op, type="MLAttention", bottoms=(f"{l}_op_norm",),
+                      tops=(op,), mla=latent, block=l)
+            if is_latent else
+            LayerSpec(name=op, type="KDAttention", bottoms=(f"{l}_op_norm",),
+                      tops=(op,), kda=linear, block=l),
+            _sum_layer(f"{l}_op_res", x, op, f"{l}_h", l),
+            norm(f"{l}_mlp_norm", f"{l}_h", l)]
+        ff = _ff_layer(l, i < c["first_k_dense_replace"],
+                       c["intermediate_size"], experts, std)
+        layers += [ff, _sum_layer(f"{l}_mlp_res", f"{l}_h", ff.name,
+                                  f"x{i + 1}", l)]
+    layers += [
+        norm("final_norm", f"x{depth}", "head"),
+        LayerSpec(name="lm_head", type="InnerProduct", bottoms=("final_norm",),
+                  tops=("lm_head",), block="head",
+                  inner_product=InnerProductParam(
+                      num_output=vocab, bias_term=False, axis=-1,
+                      weight_filler=_GAUSS(std))),
+        LayerSpec(name="loss", type="SoftmaxWithLoss",
+                  bottoms=("lm_head", "tokens"), tops=("loss",), block="head",
+                  loss=LossParam(label_shift=1))]
+    return NetSpec(name="ling3_flash",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
-SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe}
+SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe,
+                   "ling3_flash": ling3_flash}

@@ -350,6 +350,50 @@ def test_grouped_core_at_head_width_64_runs_as_a_kernel(v5e, as_tpu):
                      text) and "bf16[2,8,8192,64]" in text
 
 
+def test_delta_rule_block_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """One Kimi Delta Attention layer at the linear-attention cell's shape (2
+    rows, 8,192 positions, hidden 2,560, 32 heads of 128) in a recomputation
+    block, forward and backward under the bfloat16 policy, for a v5e (~20 s):
+    its temporaries stay under 3 GB (4.4 with both rows at once, 10.2 before
+    the rule's operands were made a segment at a time: PERF.md section 6, PR
+    33), the rule runs as loops over segments and chunks whose trip counts
+    the report reads from the text (the chip's compiler prints none beside
+    the loop), and nothing under `delta` is a gather or a scatter (an index
+    with two integers a slice apart is one, and a TPU runs it as a loop)."""
+    from sparknet_tpu.model import seq_layers as sl
+    from sparknet_tpu.model.spec import KDAttentionParam, LayerSpec
+    from sparknet_tpu.obs import device as obs_device
+    one = SingleDeviceSharding(v5e[0])
+    p = KDAttentionParam(num_heads=32, head_dim=128, taps=4, lower_bound=-5.0, eps=1e-6)
+    layer = LayerSpec(name="k", type="KDAttention", kda=p)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda k: sl.init_kdattention(k, layer, ((2, 8192, 2560),)),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((2, 8192, 2560), jnp.bfloat16, sharding=one)
+
+    def loss(params, x):
+        with jax.named_scope("tau_step"), jax.named_scope("KDAttention/l0_kda"):
+            y = jax.checkpoint(lambda params, x: sl.kda(p, params, x, _seq_ctx()))(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    try:
+        precision.set_policy("bfloat16")
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    finally:
+        precision.set_policy("float32")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3.0e9, f"the block's temporaries are {temp / 1e9:.2f} GB"
+    ops = obs_device.parse_hlo_ops(compiled.as_text())
+    got = obs_device.delta_rule(ops, sl.DELTA_SCOPES)
+    # a row: 16 segments of 8 chunks, forward, made again, backward
+    assert got["loops"] >= 6 and got["trips"] >= 3 * (16 + 8), got
+    assert got["carried_bytes"] >= 32 * 128 * 128 * 4  # a row's float32 states
+    under_delta = [op for op in ops.values() if op["layer_type"] == "KDAttention"
+                   and "delta" in op["scope"].split("/")]
+    assert under_delta and not any(op.get("indexed") for op in under_delta)
+
+
 def test_attention_block_lays_out_nothing_between_projection_and_core(v5e, as_tpu):
     """One attention block at GLM-4.7-Flash's widths and the benchmark's
     shape (norm, latent attention, residual sum: a recomputation block that
@@ -646,3 +690,35 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     _routing_walks_rows(text, ops, trainer, 8 * 2, 32768)
+
+
+@pytest.mark.slow
+def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The linear-attention model's round (`ling3-flash-ep64-tau4`: six Kimi
+    Delta Attention layers and one latent attention with direct queries,
+    six expert layers behind a 512-wide group-limited router, an untied
+    head) for one described chip (~3 min): 6.58 GB of state (822,036,416
+    parameters and their momentum) + 5.68 GB of temporaries (the gradient is
+    3.29 of them). The one attention core runs as a kernel once a step body
+    on its forward path alone; every delta rule is loops over segments and
+    chunks, and no gather or scatter in any operator touches an activation."""
+    compiled, trainer = _sequence_round(v5e, "ling3-flash-ep64-tau4")
+    total = _round_bytes(compiled)
+    assert total < 13.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
+    from sparknet_tpu.obs.device import (attention_moves, delta_rule,
+                                         parse_hlo_ops, recompute_report)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_kernels())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (1, 0)
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves
+    scopes, kept_names = trainer.net.delta_scopes()
+    rule = delta_rule(ops, scopes)
+    # two step bodies x six layers x (forward, made again, backward) x 2 loops
+    assert rule["loops"] >= 2 * 6 * 3 * 2 and kept_names == (), rule
+    # six expert layers fetch tokens x k rows twice a step at k = 8 (twice
+    # the helper's k of 4), and three times the buffer's 4,096 rows
+    _routing_walks_rows(text, ops, trainer, 6 * 2 * 2, 4096)

@@ -1,0 +1,149 @@
+"""The gated delta rule with a per-channel decay (`ops.delta_rule`): the
+chunked form that runs against the recurrence a position at a time, forward
+and every gradient, at chunks of 16 / 32 / 64, lengths that are and are not a
+multiple of the chunk, decays all at the bound (-5), spread over it, and all
+near 0; the unit-lower-triangular inverse by hand; and what the sub-chunks
+are for: a chunk-wide factoring of the decay overflows where this one does
+not.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparknet_tpu import precision
+from sparknet_tpu.ops import delta_rule as dr
+
+
+def _inputs(seed, n, gates, lead=(2, 3), dk=16, dv=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], lead + (n, dk))) / np.sqrt(dk)
+    k = unit(jax.random.normal(ks[1], lead + (n, dk)))
+    v = jax.random.normal(ks[2], lead + (n, dv))
+    u = jax.random.uniform(ks[3], lead + (n, dk))
+    g = {"spread": dr.MIN_LOG_DECAY * u,
+         "at_the_bound": jnp.full(lead + (n, dk), dr.MIN_LOG_DECAY),
+         "near_zero": -1e-3 * u}[gates]
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], lead + (n,)))
+    return q, k, v, g, beta
+
+
+def _recurrence_by_hand(q, k, v, g, beta):
+    """The definition in float64 numpy, a position at a time."""
+    q, k, v, g, beta = (np.asarray(x, np.float64) for x in (q, k, v, g, beta))
+    s = np.zeros(q.shape[:-2] + (q.shape[-1], v.shape[-1]))
+    o = np.zeros(v.shape)
+    for t in range(q.shape[-2]):
+        s = np.exp(g[..., t, :])[..., None] * s
+        u = beta[..., t, None] * (v[..., t, :] - np.einsum(
+            "...kv,...k->...v", s, k[..., t, :]))
+        s = s + k[..., t, :, None] * u[..., None, :]
+        o[..., t, :] = np.einsum("...kv,...k->...v", s, q[..., t, :])
+    return o, s
+
+
+_LOSS = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
+_ALL = (0, 1, 2, 3, 4)
+# jitted once a shape: the three kinds of gates share every compile
+_REC = jax.jit(lambda *a: dr.delta_rule_recurrent(*a)[0])
+_REC_GRAD = jax.jit(jax.grad(_LOSS(lambda *a: dr.delta_rule_recurrent(*a)[0]), argnums=_ALL))
+_CHUNKED = jax.jit(dr.gated_delta_rule, static_argnames=("chunk",))
+_CHUNKED_GRAD = jax.jit(
+    lambda *a, chunk: jax.grad(_LOSS(lambda *b: dr.gated_delta_rule(*b, chunk=chunk)),
+                               argnums=_ALL)(*a), static_argnames=("chunk",))
+
+
+@pytest.mark.parametrize("gates", ["spread", "at_the_bound", "near_zero"])
+@pytest.mark.parametrize("n", [128, 50, 7])
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+def test_chunked_form_equals_the_recurrence_forward_and_gradient(chunk, n, gates):
+    x = _inputs(n + chunk, n, gates)
+    with precision.policy("float32"):
+        want, got = _REC(*x), _CHUNKED(*x, chunk=chunk)
+        g_want, g_got = _REC_GRAD(*x), _CHUNKED_GRAD(*x, chunk=chunk)
+    assert got.shape == want.shape == x[2].shape and got.dtype == jnp.float32
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * scale
+    for name, a, b in zip("q k v g beta".split(), g_got, g_want):
+        err = float(jnp.linalg.norm(a - b)) / (float(jnp.linalg.norm(b)) + 1e-30)
+        # at the bound the decay's own gradient is a difference of near-equal
+        # terms a thousandth of the others' size
+        assert err < (2e-3 if (name, gates) == ("g", "at_the_bound") else 5e-5), (name, err)
+
+
+@pytest.mark.parametrize("gates", ["spread", "at_the_bound", "near_zero"])
+def test_both_forms_equal_the_definition_in_float64(gates):
+    x = _inputs(3, 96, gates, lead=(2,))
+    want, last = _recurrence_by_hand(*x)
+    with precision.policy("float32"):
+        rec, s = dr.delta_rule_recurrent(*x)
+        got = dr.gated_delta_rule(*x)
+    scale = np.max(np.abs(want))
+    assert np.max(np.abs(np.asarray(rec) - want)) < 1e-5 * scale
+    assert np.max(np.abs(np.asarray(s) - last)) < 1e-5 * np.max(np.abs(last))
+    assert np.max(np.abs(np.asarray(got) - want)) < 1e-5 * scale
+
+
+def test_the_rule_is_causal_and_padding_neither_writes_nor_decays():
+    x = _inputs(5, 80, "spread")
+    later = tuple(t.at[..., 50:, :].add(0.3) if t.ndim == 4 else t for t in x)
+    later = later[:3] + (x[3], x[4])
+    with precision.policy("float32"):
+        a, b = dr.gated_delta_rule(*x), dr.gated_delta_rule(*later)
+        # 80 positions = a chunk of 64 and 16 of a second, padded to 64
+        short = dr.gated_delta_rule(*(t[..., :80, :] if t.ndim == 4 else t[..., :80]
+                                      for t in _inputs(5, 128, "spread")))
+        whole = dr.gated_delta_rule(*_inputs(5, 128, "spread"))
+    assert np.array_equal(a[..., :50, :], b[..., :50, :])
+    assert not np.allclose(a[..., 50, :], b[..., 50, :])
+    assert np.allclose(short, whole[..., :80, :], atol=1e-6)
+
+
+@pytest.mark.parametrize("c", [16, 32, 64])
+def test_unit_lower_inverse_by_hand(c):
+    """Alike keys make every entry under the diagonal close to 1: the powers
+    of N then grow past float32 (binom(63, 31) ~ 1e18) and the blockwise
+    substitution does not care."""
+    rng = np.random.default_rng(c)
+    for entries in (rng.uniform(-1, 1, (3, c, c)), np.full((3, c, c), 0.97)):
+        n = np.tril(entries, -1).astype(np.float32)
+        got = np.asarray(dr._unit_lower_inverse(jnp.asarray(n)))
+        want = np.linalg.inv(np.eye(c) + n.astype(np.float64))
+        assert np.max(np.abs(got - want)) < 1e-4 * max(1.0, np.max(np.abs(want)))
+        assert np.allclose(np.triu(got, 1), 0) and np.allclose(np.diagonal(got, axis1=-2, axis2=-1), 1)
+
+
+def test_sub_chunks_are_what_keeps_the_factored_decay_finite():
+    """exp(G_t - G_j) factored around one reference a chunk of 64 overflows
+    float32 with every gate at the bound (exp(5 * 32) = inf); factored a
+    sub-chunk of 16 at a time it stays within exp(+-40)."""
+    assert dr.SUB * -dr.MIN_LOG_DECAY / 2 < 88 < dr.CHUNK * -dr.MIN_LOG_DECAY / 2
+    q, k, v, g, beta = _inputs(9, 64, "at_the_bound", lead=(1,))
+    g_sum = jnp.cumsum(g, axis=-2)
+    whole = jnp.exp(g_sum[..., 31:32, :] - g_sum)  # one reference, mid-chunk
+    assert not bool(jnp.all(jnp.isfinite(whole)))
+    with precision.policy("float32"):
+        a, b = dr._pair_terms(q, k, g_sum)
+    assert bool(jnp.all(jnp.isfinite(a))) and bool(jnp.all(jnp.isfinite(b)))
+    # by hand, across two sub-chunks and inside one
+    for t, j in ((40, 37), (40, 20), (63, 48), (17, 2)):
+        want = float(jnp.sum(k[0, t] * k[0, j] * jnp.exp(g_sum[0, t] - g_sum[0, j])))
+        assert float(a[0, t, j]) == pytest.approx(want, rel=1e-4, abs=1e-12)
+
+
+def test_bfloat16_policy_runs_the_large_products_in_bfloat16():
+    x = _inputs(11, 128, "spread")
+    with precision.policy("float32"):
+        want = dr.gated_delta_rule(*x)
+    with precision.policy("bfloat16"):
+        got = dr.gated_delta_rule(*x)
+        grads = jax.grad(lambda *a: jnp.sum(jnp.sin(dr.gated_delta_rule(*a))),
+                         argnums=(0, 1, 2, 3, 4))(*x)
+        text = jax.jit(dr.gated_delta_rule).lower(*x).as_text()
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 0.03 * float(jnp.max(jnp.abs(want)))
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in grads)
+    assert "bf16" in text and "while" in text
