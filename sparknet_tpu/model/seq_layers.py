@@ -488,7 +488,8 @@ def kda(p: KDAttentionParam, params: Params, x, ctx):
             b = _project("rnc,ch->rhn", x, params["beta"])
         q, k, v, g, beta = jax.checkpoint(shaped)(q, k, v, a, b)
         with jax.named_scope("delta"):
-            o = delta_rule.gated_delta_rule(q, k, v, g, beta)
+            o = delta_rule.gated_delta_rule(q, k, v, g, beta,
+                                            interpret=ctx.interpret)
         with jax.named_scope("out_gate"):
             o = jax.checkpoint(lambda o, x: _head_gate(
                 _rms(o, params["o_norm"], p.eps), x, params["out_gate"]))(o, x)

@@ -229,6 +229,10 @@ _INSTRUCTION = re.compile(
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+#: the target of a Pallas kernel's `custom-call` (the compiler's own,
+#: `AllocateBuffer` and the like, carry others)
+PALLAS_TARGET = "tpu_custom_call"
 _INT_CONSTANT = re.compile(r"\bs32\[\]\S* constant\((\d+)\)")
 _CALLED = re.compile(
     r"\b(calls|to_apply|select|scatter|body|condition|branch_computations|"
@@ -279,7 +283,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
     "row_gathers", "rows_gathered"}, "delta_rule": {"loops", "trips",
-    "carried_bytes", "instructions", "bytes", "kept_bytes"}}` — see
+    "kernel_calls", "carried_bytes", "instructions", "bytes",
+    "kept_bytes"}}` — see
     `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
@@ -381,7 +386,9 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
             name = _OP_NAME.search(rest)
             paren = body.find("(", op.start()) if op else -1
             trips, const = _TRIPS.search(rest), _INT_CONSTANT.search(body)
+            target = _TARGET.search(body)
             current.append({
+                "target": target.group(1) if target else None,
                 "trips": int(trips.group(1)) if trips else None,
                 "const": int(const.group(1)) if const else None,
                 "name": "%" + iname.lstrip("%"), "root": root,
@@ -467,6 +474,8 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
     if opcode == "while":  # what `delta_rule` reads of a loop
         out["loop"] = {"trips": instruction["trips"],
                        "carried_bytes": _nbytes(result)}
+    if instruction["target"] == PALLAS_TARGET:  # and of a kernel
+        out["pallas"] = True
     inside = {f["name"]: f["shapes"] for f in fused}
     indexed = []
     for f in fused:
@@ -625,7 +634,10 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     through loops of their own, so a step's are spread over several
     computations; a round holds a step twice, as the scanned body and as the
     peeled last step), "trips": their trip counts together (a loop whose
-    count the text does not give counts 1), "carried_bytes": the most one of
+    count the text does not give counts 1), "kernel_calls": the Pallas
+    kernels' `custom-call` instructions among them in the whole program
+    (the chunk stage's, `ops.pallas_delta_rule`: 0 where the `jnp` form
+    was taken), "carried_bytes": the most one of
     them carries a trip (its state and what it walks), "instructions",
     "bytes": of the ops that hold neither a matmul nor a kernel, in the
     computation that moves most (a call of `moves_under`: one row's segment
@@ -635,12 +647,13 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     if not scopes:
         return {}
     under = lambda op, parts: scopes.get(op["layer_type"]) in parts
-    loops = [op["loop"] for op in ops.values()
-             if "loop" in op and under(op, op["scope"].split("/"))]
+    here = [op for op in ops.values() if under(op, op["scope"].split("/"))]
+    loops = [op["loop"] for op in here if "loop" in op]
     moves = moves_under(ops, lambda op, parts: under(op, parts)
                         and not op["matmul"] and op["opcode"] != "custom-call",
                         {})
     return {"loops": len(loops), "trips": sum(l["trips"] or 1 for l in loops),
+            "kernel_calls": sum(op.get("pallas", False) for op in here),
             "carried_bytes": max((l["carried_bytes"] for l in loops), default=0),
             **moves, "kept_bytes": kept_bytes}
 

@@ -9,7 +9,15 @@ the writing strength b [..., n] in (0, 1). `delta_rule_recurrent` is the
 recurrence a position at a time (the oracle of the tests); `gated_delta_rule`
 is the form that runs: CHUNKED, so that the work is matrix products and the
 sequential part is one step a chunk. WHICH form a layer runs is decided here
-and nowhere else (there is one; no option, no enum).
+and nowhere else, from what this module can observe (no option, no enum):
+the chunk stage -- what a chunk's step of the scan reads -- is the Pallas
+kernel pair of `pallas_delta_rule` where a Pallas call may run
+(`ops.lrn.pallas_backend`: the TPU, or any backend under the interpreter)
+and the shape is the kernels' (`_can_pallas`: chunks of 64, heads whose
+widths are multiples of the 128 lanes), and `_chunk_operands` in plain `jnp`
+everywhere else: narrow heads, short rows, any other backend. The `jnp` form
+is the kernels' oracle (`tests/test_delta_rule.py`), the scan over chunks is
+one `lax.scan` for both.
 
 The chunked (WY) form. With a_t = exp g_t and u_t = b_t (v_t - S_{t-1}^T (a_t
 * k_t)) the update is S_t = Diag(a_t) S_{t-1} + k_t u_t^T, so inside a chunk
@@ -44,9 +52,14 @@ rests on; the layer's gate keeps g inside it (the published
 
 Float32 for the decays, their running sums, the solve and the state; the
 precision policy's dtype for the operands of the large products (float32
-accumulation). The backward pass is autodiff, with two `jax.checkpoint`s
-that say what it keeps: of the batched part its inputs (made again a slice
-of heads at a time), of the scan one state a segment of chunks.
+accumulation). The backward pass of the scan is autodiff under a
+`jax.checkpoint` a segment of chunks: it keeps one state a segment. The
+backward pass of the chunk stage is the kernel's OWN on the kernel path (a
+`jax.custom_vjp` whose residuals are the five inputs: the backward kernel
+makes the forward's intermediates again in VMEM and, with (I + N)^-1 at hand,
+needs no substitution); in the `jnp` form it is autodiff under a second
+`jax.checkpoint` that keeps the inputs and makes the operands again a
+segment at a time.
 """
 from __future__ import annotations
 
@@ -56,6 +69,7 @@ import numpy as np
 from jax import lax
 
 from .. import precision
+from .lrn import pallas_backend
 
 #: positions a chunk (one step of the sequential scan) and a sub-chunk (one
 #: reference point of the factored decay); SUB * -MIN_LOG_DECAY must stay
@@ -191,48 +205,69 @@ def _chunk_operands(q, k, v, g, beta):
             cast(jnp.where(lower, b, 0.0)))
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
+def _can_pallas(c: int, dk: int, dv: int, interpret: bool) -> bool:
+    """The kernel's shape: whole chunks of `CHUNK` positions, heads whose
+    widths fill the 128 lanes; and a backend a Pallas call may run on."""
+    return pallas_backend(interpret) and c == CHUNK \
+        and dk % 128 == 0 and dv % 128 == 0
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
+                     interpret: bool = False):
     """o [..., n, dv] (float32) of the gated delta rule, chunked. `g` must
     lie in [MIN_LOG_DECAY, 0]. A length that is no multiple of the chunk is
     padded at its end with positions that neither write nor decay.
 
-    Two stages, both over SEGMENTS of `SEGMENT` chunks. What a chunk's step
-    reads (`_chunk_operands`: the decays' running sums, the factored pair
-    terms, the solve) is made a segment at a time in a checkpointed
-    `lax.map`, for every leading entry (row, head) together and laid out
-    chunks first, as the scan walks them: its temporaries are a dozen
-    tensors of the inputs' size, which the backward pass makes again a
-    segment at a time and never holds whole. The scan over chunks then
-    carries the state and does four products a step; each segment is a
-    checkpoint, so the backward pass keeps one state a segment."""
+    Two stages. What a chunk's step reads (the decays' running sums, the
+    factored pair terms, the solve) is made for every leading entry (row,
+    head) together and laid out chunks first, as the scan walks them: by the
+    kernel pair of `pallas_delta_rule` where `_can_pallas` holds (its custom
+    VJP keeps the inputs alone), else by `_chunk_operands` a segment of
+    `SEGMENT` chunks at a time in a checkpointed `lax.map`, whose
+    temporaries -- a dozen tensors of the inputs' size -- the backward pass
+    makes again a segment at a time and never holds whole. The scan over
+    chunks then carries the state and does four products a step; each
+    segment is a checkpoint, so the backward pass keeps one state a segment.
+
+    interpret: run the kernels under the Pallas INTERPRETER (the CPU parity
+      tests of the path the chip runs), as `ops.lrn.lrn` does."""
     assert chunk % SUB == 0 and (chunk // SUB) & (chunk // SUB - 1) == 0, chunk
     n, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
     lead = q.shape[:-2]
     c = chunk
     while c // 2 >= max(n, SUB):  # a short row: the smallest chunk that holds it
         c //= 2
-    pad = -n % c
-    nc, many = (n + pad) // c, int(np.prod(lead, dtype=np.int64))
-
-    def cut(x, trailing):  # [..., n(, d)] -> [many, nc, C(, d)], padded
-        x = x.reshape((many,) + x.shape[len(lead):])
-        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * trailing)
-        return x.reshape((many, nc, c) + x.shape[2:])
-
-    xs = (cut(q, 1), cut(k, 1), cut(v, 1), cut(g.astype(jnp.float32), 1),
-          cut(beta.astype(jnp.float32), 0))
+    many = int(np.prod(lead, dtype=np.int64))
+    kernel = _can_pallas(c, dk, dv, interpret)
+    if kernel:
+        from . import pallas_delta_rule as pk  # (it imports this module)
+    pad = pk.padding(n) if kernel else -n % c
+    nc = (n + pad) // c
     seg = max(d for d in range(1, SEGMENT + 1) if nc % d == 0)
 
-    def operands(i):
-        """Segment i's chunks of every leading entry, chunks leading (as the
-        scan walks them): [seg, many, C, ..]. Sliced out of the closed-over
-        inputs: handing the map the segments as inputs of its own
-        (transposed to lead) was 13 ms a layer-step SLOWER on the chip
-        (PERF.md section 6, PR 33)."""
-        part = (lax.dynamic_slice_in_dim(x, i * seg, seg, axis=1) for x in xs)
-        return tuple(jnp.moveaxis(x, 1, 0) for x in _chunk_operands(*part))
+    def flat(x, trailing):  # [..., n(, d)] -> [many, n + pad(, d)]
+        x = x.reshape((many,) + x.shape[len(lead):])
+        return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * trailing)
 
-    ops = lax.map(jax.checkpoint(operands), jnp.arange(nc // seg))
+    xs = (flat(q, 1), flat(k, 1), flat(v, 1), flat(g.astype(jnp.float32), 1),
+          flat(beta.astype(jnp.float32), 0))
+    if kernel:
+        ops = tuple(x.reshape((nc // seg, seg) + x.shape[1:])
+                    for x in pk.chunk_operands(*xs, precision.compute_dtype(),
+                                               interpret))
+    else:
+        xs = tuple(x.reshape((many, nc, c) + x.shape[2:]) for x in xs)
+
+        def operands(i):
+            """Segment i's chunks of every leading entry, chunks leading (as
+            the scan walks them): [seg, many, C, ..]. Sliced out of the
+            closed-over inputs: handing the map the segments as inputs of
+            its own (transposed to lead) was 13 ms a layer-step SLOWER on
+            the chip (PERF.md section 6, PR 33)."""
+            part = (lax.dynamic_slice_in_dim(x, i * seg, seg, axis=1) for x in xs)
+            return tuple(jnp.moveaxis(x, 1, 0) for x in _chunk_operands(*part))
+
+        ops = lax.map(jax.checkpoint(operands), jnp.arange(nc // seg))
 
     def step(s, x):
         w_k, w_v, k_end, d_end, q_dec, b_low = x

@@ -100,6 +100,33 @@ def test_kernel_compiles_for_v5e(v5e, fn, shape, dtype):
     assert "tpu_custom_call" in _compiled_text(fn, x)
 
 
+def _delta_operands(dtype, grad: bool):
+    """The chunk stage's forward kernel, or (the cotangents ones) its
+    backward kernel alone."""
+    from sparknet_tpu.ops.pallas_delta_rule import chunk_operands
+    total = lambda *a: sum(jnp.sum(o.astype(jnp.float32))
+                           for o in chunk_operands(*a, jnp.dtype(dtype), False))
+    return jax.grad(total, argnums=(0, 1, 2, 3, 4)) if grad else total
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_delta_rule_kernels_compile_for_v5e(v5e, grad, dtype):
+    """`ops.pallas_delta_rule`'s pair at the linear-attention cell's shape (a
+    row's 32 heads, 8,192 positions, 128 a head: 4,096 chunks, a program
+    eight tiles of two): Mosaic takes every op of both bodies, and their
+    blocks and temporaries fit the scoped VMEM the call states (float32
+    blocks are twice the size: the backward asked 16.16 MB of the default
+    16 MiB before `_specs` stated the need)."""
+    one = SingleDeviceSharding(v5e[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    x = s((32, 8192, 128), dtype)
+    text = _compiled_text(_delta_operands(dtype, grad), x, x, x,
+                          s((32, 8192, 128), jnp.float32), s((32, 8192), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+    assert ("delta_chunk_bwd" if grad else "delta_chunk_fwd") in text
+
+
 def test_bf16_row_block_is_the_profiled_one():
     """PERF.md's LRN profile is of the bf16 kernel at these blocks; the f32
     repair states a VMEM need and must never move them."""
@@ -354,12 +381,16 @@ def test_delta_rule_block_compiles_for_v5e_and_fits(v5e, as_tpu):
     """One Kimi Delta Attention layer at the linear-attention cell's shape (2
     rows, 8,192 positions, hidden 2,560, 32 heads of 128) in a recomputation
     block, forward and backward under the bfloat16 policy, for a v5e (~20 s):
-    its temporaries stay under 3 GB (4.4 with both rows at once, 10.2 before
-    the rule's operands were made a segment at a time: PERF.md section 6, PR
-    33), the rule runs as loops over segments and chunks whose trip counts
-    the report reads from the text (the chip's compiler prints none beside
-    the loop), and nothing under `delta` is a gather or a scatter (an index
-    with two integers a slice apart is one, and a TPU runs it as a loop)."""
+    the chunk stage runs as `ops.pallas_delta_rule`'s kernels (forward in the
+    block, forward again under the row's checkpoint, backward: three calls
+    under `delta`, `kernel_calls` of the report), the scan over segments and
+    chunks as the loops it was (forward, made again by the row, and backward
+    with the segment's chunks made again: seven, whose trip counts the
+    report reads from the text), its temporaries stay under 3 GB (2.14; 2.43
+    with the chunk stage in `jnp`, 4.4 with both rows at once, 10.2 before
+    the operands were made a segment at a time: PERF.md section 6, PR 33 and
+    37), and nothing under `delta` is a gather or a scatter (an index with
+    two integers a slice apart is one, and a TPU runs it as a loop)."""
     from sparknet_tpu.model import seq_layers as sl
     from sparknet_tpu.model.spec import KDAttentionParam, LayerSpec
     from sparknet_tpu.obs import device as obs_device
@@ -384,13 +415,20 @@ def test_delta_rule_block_compiles_for_v5e_and_fits(v5e, as_tpu):
         precision.set_policy("float32")
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 3.0e9, f"the block's temporaries are {temp / 1e9:.2f} GB"
-    ops = obs_device.parse_hlo_ops(compiled.as_text())
+    text = compiled.as_text()
+    ops = obs_device.parse_hlo_ops(text)
     got = obs_device.delta_rule(ops, sl.DELTA_SCOPES)
-    # a row: 16 segments of 8 chunks, forward, made again, backward
-    assert got["loops"] >= 6 and got["trips"] >= 3 * (16 + 8), got
-    assert got["carried_bytes"] >= 32 * 128 * 128 * 4  # a row's float32 states
     under_delta = [op for op in ops.values() if op["layer_type"] == "KDAttention"
                    and "delta" in op["scope"].split("/")]
+    # forward in the block, forward again by the row, backward: a row loop each
+    assert got["kernel_calls"] == sum(op.get("pallas", False) for op in under_delta) == 3, got
+    assert "delta_chunk_fwd" in text and "delta_chunk_bwd" in text
+    # a row's scan: 16 segments of 8 chunks, forward, made again, backward
+    assert got["loops"] >= 6 and got["trips"] >= 3 * (16 + 8), got
+    assert got["carried_bytes"] >= 32 * 128 * 128 * 4  # a row's float32 states
+    # the chunk stage's elementwise passes are gone from the program: what
+    # is left under `delta` beside kernels and products moves a few arrays
+    assert got["instructions"] < 60, got
     assert under_delta and not any(op.get("indexed") for op in under_delta)
 
 
@@ -700,8 +738,9 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     head) for one described chip (~3 min): 6.58 GB of state (822,036,416
     parameters and their momentum) + 5.68 GB of temporaries (the gradient is
     3.29 of them). The one attention core runs as a kernel once a step body
-    on its forward path alone; every delta rule is loops over segments and
-    chunks, and no gather or scatter in any operator touches an activation."""
+    on its forward path alone; every delta rule is the chunk stage's kernels
+    and loops over segments and chunks, and no gather or scatter in any
+    operator touches an activation."""
     compiled, trainer = _sequence_round(v5e, "ling3-flash-ep64-tau4")
     total = _round_bytes(compiled)
     assert total < 13.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
@@ -718,7 +757,10 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     scopes, kept_names = trainer.net.delta_scopes()
     rule = delta_rule(ops, scopes)
     # two step bodies x six layers x (forward, made again, backward) x 2 loops
-    assert rule["loops"] >= 2 * 6 * 3 * 2 and kept_names == (), rule
+    # (+ a segment's chunks made again in the backward), and the chunk
+    # stage's kernels: forward, forward again by the row, backward
+    assert rule["loops"] >= 2 * 6 * 3 * 2 and kept_names == ("kda_out",), rule
+    assert rule["kernel_calls"] == 2 * 6 * 3, rule
     # six expert layers fetch tokens x k rows twice a step at k = 8 (twice
     # the helper's k of 4), and three times the buffer's 4,096 rows
     _routing_walks_rows(text, ops, trainer, 6 * 2 * 2, 4096)

@@ -4,7 +4,10 @@ and every gradient, at chunks of 16 / 32 / 64, lengths that are and are not a
 multiple of the chunk, decays all at the bound (-5), spread over it, and all
 near 0; the unit-lower-triangular inverse by hand; and what the sub-chunks
 are for: a chunk-wide factoring of the decay overflows where this one does
-not.
+not. And the kernel pair of `ops.pallas_delta_rule` under the Pallas
+interpreter: its six operands and five gradients against `_chunk_operands`
+and `jax.vjp` of it, and `gated_delta_rule` on the kernel path against the
+recurrence; every case above keeps running on the `jnp` form (heads of 16).
 """
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import pytest
 
 from sparknet_tpu import precision
 from sparknet_tpu.ops import delta_rule as dr
+from sparknet_tpu.ops import pallas_delta_rule as pk
 
 
 def _inputs(seed, n, gates, lead=(2, 3), dk=16, dv=8):
@@ -147,3 +151,101 @@ def test_bfloat16_policy_runs_the_large_products_in_bfloat16():
     assert float(jnp.max(jnp.abs(got - want))) < 0.03 * float(jnp.max(jnp.abs(want)))
     assert all(bool(jnp.all(jnp.isfinite(t))) for t in grads)
     assert "bf16" in text and "while" in text
+
+
+# -- the kernel pair (`ops.pallas_delta_rule`), under the Pallas interpreter --
+
+def _kernel_inputs(case, many=1, n=256, d=128):
+    """Rows of whole chunks at the kernel's widths: [many, n, d]."""
+    q, k, v, g, beta = _inputs(17, n, {"at_the_bound": "at_the_bound"}.get(case, "spread"),
+                               lead=(many,), dk=d, dv=d)
+    if case == "zero_gates":
+        g = jnp.zeros_like(g)
+    if case == "beta_near_0":
+        beta = jnp.full_like(beta, 1e-4)
+    if case == "beta_near_1":
+        beta = jnp.full_like(beta, 1.0 - 1e-4)
+    if case == "alike_keys":  # what the no-powers solve exists for
+        k = k[:, :1, :] + 1e-3 * k
+        k, g = k / jnp.linalg.norm(k, axis=-1, keepdims=True), -1e-3 * jnp.ones_like(g)
+        beta = jnp.full_like(beta, 0.97)
+    return q, k, v, g, beta
+
+
+def _oracle(q, k, v, g, beta):
+    """`_chunk_operands` on the rows cut into chunks, chunks first."""
+    cut = lambda x: x.reshape((x.shape[0], x.shape[1] // dr.CHUNK, dr.CHUNK) + x.shape[2:])
+    return tuple(jnp.moveaxis(x, 1, 0) for x in dr._chunk_operands(*map(cut, (q, k, v, g, beta))))
+
+
+_KERNEL_CASES = ["spread", "at_the_bound", "zero_gates", "beta_near_0",
+                 "beta_near_1", "alike_keys"]
+_NAMES = "w_k w_v k_end d_end q_dec b_low".split()
+
+
+@pytest.mark.parametrize("case", _KERNEL_CASES)
+@pytest.mark.parametrize("mode", ["float32", "bfloat16"])
+def test_kernel_pair_equals_chunk_operands_and_their_autodiff(mode, case):
+    dt = jnp.dtype(mode)
+    q, k, v, g, beta = _kernel_inputs(case)
+    x = (q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
+    with precision.policy(mode):
+        want, pull_want = jax.vjp(_oracle, *x)
+        got, pull_got = jax.vjp(lambda *a: pk.chunk_operands(*a, dt, True), *x)
+        cot = tuple(jax.random.normal(jax.random.PRNGKey(i), w.shape).astype(w.dtype)
+                    for i, w in enumerate(want))
+        g_want, g_got = pull_want(cot), pull_got(cot)
+    f32 = lambda t: np.asarray(t, np.float32)
+    for name, a, b in zip(_NAMES, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        # the running sums (to 320) add in another order: exp carries 3e-5;
+        # bfloat16: one rounding apart at the most
+        tol = 5e-5 if mode == "float32" else 1e-2
+        assert np.max(np.abs(f32(a) - f32(b))) <= tol * max(np.max(np.abs(f32(b))), 1e-30), name
+    for name, a, b in zip("q k v g beta".split(), g_got, g_want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.all(np.isfinite(f32(a))), name
+        err = np.linalg.norm(f32(a) - f32(b)) / (np.linalg.norm(f32(b)) + 1e-30)
+        # at the bound the decay's gradient is a difference of near-equal terms
+        tight = 2e-3 if (name, case) == ("g", "at_the_bound") else 5e-5
+        assert err < (tight if mode == "float32" else 25 * 2e-3), (name, err)
+
+
+_KERNEL_RULE = jax.jit(lambda *a: dr.gated_delta_rule(*a, interpret=True))
+_KERNEL_RULE_GRAD = jax.jit(jax.grad(
+    _LOSS(lambda *a: dr.gated_delta_rule(*a, interpret=True)), argnums=_ALL))
+
+
+@pytest.mark.parametrize("gates", ["spread", "at_the_bound", "near_zero"])
+@pytest.mark.parametrize("n", [256, 200, 50])
+def test_kernel_path_equals_the_recurrence_forward_and_gradient(n, gates):
+    """Heads of 128 under the interpreter take the kernels (a length that is
+    no multiple of their tile of 128 is padded as the `jnp` form pads)."""
+    x = _inputs(n, n, gates, lead=(1, 2), dk=128, dv=128)
+    with precision.policy("float32"):
+        want, got = _REC(*x), _KERNEL_RULE(*x)
+        g_want, g_got = _REC_GRAD(*x), _KERNEL_RULE_GRAD(*x)
+    assert got.shape == want.shape == x[2].shape and got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(jnp.max(jnp.abs(want)))
+    for name, a, b in zip("q k v g beta".split(), g_got, g_want):
+        err = float(jnp.linalg.norm(a - b)) / (float(jnp.linalg.norm(b)) + 1e-30)
+        assert err < (2e-3 if (name, gates) == ("g", "at_the_bound") else 5e-5), (name, err)
+
+
+def test_which_form_runs_is_decided_by_backend_and_shape_alone():
+    """The kernels where a Pallas call may run (here: the interpreter) and
+    the heads fill the lanes; the `jnp` form for narrow heads, for a short
+    row's smaller chunk, and on this backend without the interpreter."""
+    calls = lambda interpret, **kw: str(jax.make_jaxpr(
+        lambda *a: dr.gated_delta_rule(*a, interpret=interpret))(
+            *_inputs(1, kw.pop("n", 128), "spread", lead=(1,), **kw))).count("pallas_call")
+    assert calls(True, dk=128, dv=128) == 1
+    assert calls(False, dk=128, dv=128) == 0       # the CPU: no Pallas call may run
+    assert calls(True, dk=16, dv=8) == 0           # narrow heads
+    assert calls(True, dk=128, dv=64) == 0
+    assert calls(True, n=20, dk=128, dv=128) == 0  # a chunk of 32 holds the row
+    grad = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(dr.gated_delta_rule(*a, interpret=True)),
+                                   argnums=_ALL))(*_inputs(1, 128, "spread", lead=(1,), dk=128, dv=128))
+    text = str(grad)
+    # the backward of the chunk stage is the kernel's own, not autodiff
+    assert "delta_chunk_fwd" in text and "delta_chunk_bwd" in text

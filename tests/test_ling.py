@@ -341,9 +341,10 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     report = obs_device.program_report("train_round")
     assert report["recompute"][sl.ATTN_CORE]["kept_bytes"] == ROWS * POS * 4 * 16 * 4
     delta = report["delta_rule"]
-    assert set(delta) == {"loops", "trips", "carried_bytes", "instructions",
-                          "bytes", "kept_bytes"}
+    assert set(delta) == {"loops", "trips", "kernel_calls", "carried_bytes",
+                          "instructions", "bytes", "kept_bytes"}
     assert delta["loops"] == 0 and delta["instructions"] > 0 and delta["bytes"] > 0
+    assert delta["kernel_calls"] == 0  # the `jnp` form: no TPU, narrow heads
     # the three delta-rule blocks keep their layers' results, float32 here
     assert delta["kept_bytes"] == 3 * ROWS * POS * D * 4
     assert obs_device.program_part("delta_rule")["train_round"] == delta
@@ -375,6 +376,7 @@ def test_the_delta_rule_compiles_to_one_loop_over_chunks_a_pass():
     assert got["loops"] >= 6 and got["trips"] >= 2 * 3 + 8 * 3, got
     assert got["carried_bytes"] >= ROWS * 4 * 16 * 16 * 4
     assert got["instructions"] > 0 and got["bytes"] > 0
+    assert got["kernel_calls"] == 0  # heads of 16: the `jnp` form, here and on a TPU
     assert obs_device.delta_rule(ops, {}) == {}
     # a net of other layers: nothing under such a scope
     assert obs_device.delta_rule(ops, {"GQAttention": "delta"})["loops"] == 0
