@@ -9,6 +9,13 @@ an on-device `pmean` over the ICI mesh rather than a driver round trip.
 
 __version__ = "0.1.0"
 
+import time as _time
+
+#: `time.perf_counter()` at this process's first import of the package,
+#: before any of its modules (or jax) is loaded: where a start-up account
+#: begins (`obs.trace.import_stamp()`)
+IMPORT_T0 = _time.perf_counter()
+
 from .model.spec import NetSpec, LayerSpec, InputSpec  # noqa: F401
 from .model.net import CompiledNet  # noqa: F401
 from .model.prototxt import (  # noqa: F401

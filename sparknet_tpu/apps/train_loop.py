@@ -29,7 +29,7 @@ import time
 import warnings
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
@@ -67,6 +67,7 @@ def _hb_float(v: float):
 _RETRY_DATA_OFFSET = 1 << 20
 
 
+@obs_trace.startup_span("resolve_spec")
 def resolve_spec(cfg: RunConfig, **input_shapes) -> NetSpec:
     """cfg.model -> NetSpec: a zoo builder name, a .prototxt path
     (capability parity: the reference's apps loaded prototxt data files,
@@ -159,15 +160,19 @@ def probe_value(state: TrainState, net: CompiledNet):
     return float(np.asarray(leaf).reshape(-1)[0])
 
 
+@obs_trace.startup_span("build_trainer")
 def build_trainer(cfg: RunConfig, spec: NetSpec, mesh=None):
     """cfg + spec -> the layer-IR trainer `train()` runs: the trainer
     implementation and the round-pipeline levers come from `cfg`, over
     `mesh` (default: the data mesh of cfg.n_devices).
     Sets the precision policy and resolves the solver first — both shape
     the compiled round. Building is cheap: nothing compiles until the
-    first round. Separate from `train()` so a caller can look at the
+    first round (whose compile is the `train_round` entry of the compile
+    log). Separate from `train()` so a caller can look at the
     program the loop will run (`chip_smoke.py` lowers it to check the
-    Pallas kernels are in it)."""
+    Pallas kernels are in it). A kept start-up span, with `compile_net`
+    and `trainer_init` (the mesh and the trainer's construction) inside
+    it."""
     precision.set_policy(cfg.precision)
     resolve_solver(cfg)
     compute_health = cfg.health is not None and cfg.health.enabled
@@ -178,15 +183,18 @@ def build_trainer(cfg: RunConfig, spec: NetSpec, mesh=None):
     if resolve_trainer_impl(cfg) == "named":
         trainer_cls = ShardedTrainer
         trainer_kw["state_sharding"] = cfg.state_sharding
-    return trainer_cls(CompiledNet.compile(spec), cfg.solver,
-                       mesh if mesh is not None
-                       else make_mesh(cfg.n_devices), tau=cfg.tau,
-                       mode=cfg.mode, compute_health=compute_health,
-                       elastic_tau=elastic_tau,
-                       donate_batches=cfg.donate_batches,
-                       fused_boundary=cfg.fused_boundary,
-                       interpret=cfg.ops_interpret,
-                       **trainer_kw)
+    with obs_trace.startup_span("compile_net"):
+        net = CompiledNet.compile(spec)
+    with obs_trace.startup_span("trainer_init"):
+        return trainer_cls(net, cfg.solver,
+                           mesh if mesh is not None
+                           else make_mesh(cfg.n_devices), tau=cfg.tau,
+                           mode=cfg.mode, compute_health=compute_health,
+                           elastic_tau=elastic_tau,
+                           donate_batches=cfg.donate_batches,
+                           fused_boundary=cfg.fused_boundary,
+                           interpret=cfg.ops_interpret,
+                           **trainer_kw)
 
 
 def train(cfg: RunConfig, spec: NetSpec, train_ds: ArrayDataset,
@@ -354,8 +362,11 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
     if cfg.checkpoint_dir and cfg.resume:
         last = ckpt.latest_step(cfg.checkpoint_dir)
         if last is not None:
-            flat, start_round, extra = ckpt.restore_flat(cfg.checkpoint_dir)
-            state, same_topo = _restore_state(trainer, state, flat, extra)
+            with obs_trace.startup_span("restore"):
+                flat, start_round, extra = ckpt.restore_flat(
+                    cfg.checkpoint_dir)
+                state, same_topo = _restore_state(trainer, state, flat,
+                                                  extra)
             if same_topo:
                 log.log(f"resumed from checkpoint round {start_round}")
             else:
@@ -495,6 +506,10 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
     # beat_ts is the LOOP's own freshness stamp (updated at each flush):
     # a hung round loop whose HTTP daemon thread still answers must read
     # as stale to the pod aggregator, not as alive-and-fresh
+    # `time.perf_counter()` when this loop's first round completed (its
+    # loss was fetched): what came before is start-up (`/status` `startup`,
+    # the `start-up:` log line), what compiles after is a recompile
+    first_round_t: List[Optional[float]] = [None]
     vitals: Dict[str, Any] = {"role": "train", "round": start_round,
                               "status": "ok", "loss": None,
                               "worker": jax.process_index(),
@@ -514,6 +529,11 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                                 "rollbacks": (monitor.rollbacks
                                               if monitor else 0),
                                 "phase_means": timers.summary(),
+                                # the kept start-up spans and the compile
+                                # log up to the first completed round, the
+                                # count and the newest of later compiles
+                                "startup": obs_device.startup_report(
+                                    first_round_t[0]),
                                 # {} until a profile_dir run has asked
                                 **{f"program_{part}":
                                    obs_device.program_part(part)
@@ -723,6 +743,10 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
         t_c0 = time.perf_counter()
         loss_ = float(loss_)
         t_collect = time.perf_counter() - t_c0
+        if first_round_t[0] is None:
+            first_round_t[0] = time.perf_counter()
+            log.log(obs_device.startup_line(
+                obs_device.startup_report(first_round_t[0])), rnd_)
         kv: Dict[str, Any] = {}
         if breakdown_ is not None:
             if collect_async:
@@ -1100,8 +1124,11 @@ def run_loop(cfg: RunConfig, trainer, train_ds: ArrayDataset,
                 log.log(f"test accuracy: {acc:.4f}", rnd)
                 log.metrics(rnd, test_accuracy=acc)
 
-            # trace ONE steady-state round (the first would trace compile):
-            # the wait for its rows, the prefetch thread preparing the next
+            # trace ONE steady-state round (the first holds the round's
+            # compile, which needs no profiler: its stages, the cache's
+            # verdict and this step are the round's entry of the compile
+            # log, `utils/compile_cache.py`): the wait for its rows, the
+            # prefetch thread preparing the next
             # (`round_prep` and its phases), and the dispatch
             profile_this = cfg.profile_dir and rnd == start_round + 1
             with profiling.maybe_trace(cfg.profile_dir if profile_this

@@ -11,30 +11,43 @@ Two signals the host-side registry could not see before this module:
     gauge always does.
 
   - **Compiles.** XLA compilation is the serving tail-latency cliff and
-    the training warm-up tax, yet it was invisible: nothing counted how
-    often it happened or how long it took. `note_compile(what, seconds,
-    cache_hit=...)` is the process-wide record — `CompiledNet.compile`
-    stamps spec compiles, the serve worker stamps the first forward of
-    each batch bucket (the jit-cache entry being built), and
-    `attach_compile_metrics` replays the history into a registry as
+    the training warm-up tax. `note_compile(what, seconds, cache_hit=...,
+    stages=...)` is the process-wide record: a row of sums a `what`, and
+    no list of events. Who writes it: the sink this module registers with
+    `utils/compile_cache.py`'s compile log, once for EVERY executable jax
+    builds or fetches, with the seconds of its three stages (tracing,
+    lowering, and the backend's compile or cache fetch) and the
+    persistent cache's verdict, under the jitted function's own name
+    where the program named it (`what` = `train_round`, `eval_round`, a
+    name given to `register_program`) and under `other` for the rest
+    (jax's own small programs, a serve net's forward: executables, where
+    `serve_bucket` below counts the regions that needed them);
+    `CompiledNet.compile`, for spec compiles (`"net"`); the serve worker,
+    for the first forward of each batch bucket (`"serve_bucket"`: the
+    region's wall time and verdict).
+    `attach_compile_metrics` gives a registry what was recorded so far, as
     `sparknet_compile_events_total{what,cache_hit}` +
     `sparknet_compile_seconds{what}` so a registry created AFTER the
     model was compiled (the train loop's per-run registry) still shows
     the compile that preceded it. Jit-cache CHURN — recompiles past the
-    expected steady state — is then a first-class scrapeable number
-    instead of a log-grep.
+    expected steady state — is then a scrapeable number with a time, and
+    (`register_program(..., stamp=...)`) with the round that paid it.
 
-    `cache_hit` (r9, the persistent-compile-cache PR) says whether the
-    event required FRESH XLA compilation: "true" = the region built no
-    executable from scratch (served from the persistent cache via
-    `utils/compile_cache.py`, or a memoized spec compile), "false" = at
-    least one executable compiled fresh with the cache absent or
-    missing, "unknown" = the verdict doesn't apply (a memo-MISS spec
+    `cache_hit` says whether the event required FRESH XLA compilation:
+    "true" = nothing was built from scratch (served from the persistent
+    cache via `utils/compile_cache.py`, or a memoized spec compile),
+    "false" = at least one executable compiled fresh with the cache absent
+    or missing, "unknown" = the verdict doesn't apply (a memo-MISS spec
     compile is pure Python — no XLA to cache — and out-of-tree
     note_compile callers don't sample). A warm replica's cold start
-    showing ZERO cache_hit="false" events is the BENCH_ECON acceptance
-    row; the seconds histogram records non-"true" events only, so memo
-    hits never dilute real compile-cost percentiles.
+    showing ZERO cache_hit="false" net and bucket events is the BENCH_ECON
+    acceptance row; the seconds histogram records non-"true" events only,
+    so memo hits never dilute real compile-cost percentiles.
+
+  - **Start-up.** `startup_report()` puts the kept start-up spans
+    (`obs.trace.startup_spans()`) and the compile log side by side: what a
+    process paid before its first round, step by step. The train loop logs
+    it as one line and serves it as `/status` `startup`.
 
 The accumulator is process-global by design (compiles happen before any
 registry exists); attached registries are held weakly so per-run/test
@@ -49,6 +62,9 @@ import time
 import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..utils.compile_cache import (compile_log, compile_log_dropped, on_entry,
+                                   track_compiles)
+from . import trace
 from .registry import Metric, MetricsRegistry
 
 #: compile durations span four orders of magnitude: a sub-ms cached spec
@@ -56,93 +72,134 @@ from .registry import Metric, MetricsRegistry
 COMPILE_BUCKETS = (0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                    10.0, 30.0, 60.0, 120.0, 300.0)
 
+#: the jitted functions the trainers name (`parallel.trainer.named`): a
+#: compile of one is counted under its own name, with those a program
+#: registered (`register_program`); every other executable jax builds (its
+#: own small programs, a serve net's forward, a caller's lambdas) is counted
+#: under `OTHER`, so the `what` label stays a handful of values. The compile
+#: log keeps each under its own name.
+NAMED_PROGRAMS = ("train_round", "eval_round")
+OTHER = "other"
+
 _lock = threading.Lock()
-#: (what, seconds, cache_hit), process lifetime. cache_hit: True/False/None
-_events: List[Tuple[str, float, Optional[bool]]] = []
+#: what -> {"events", "seconds", "cache_hits", "cache_misses", and where
+#: events came with stages "trace_s", "lower_s", "backend_s"}: sums, so a
+#: process that compiles for days holds a row a `what` and no list
+_stats: Dict[str, Dict[str, float]] = {}
 #: weakly-held (counter, histogram) pairs of attached registries
 _attached: List[Tuple["weakref.ref[Metric]", "weakref.ref[Metric]"]] = []
 
 
+def _compile_metrics(registry: MetricsRegistry) -> Tuple[Metric, Metric]:
+    return (registry.counter("sparknet_compile_events_total",
+                             "XLA/spec compile events by site and "
+                             "persistent-cache outcome",
+                             labels=("what", "cache_hit")),
+            registry.histogram("sparknet_compile_seconds",
+                               "seconds per FRESH compile event (cache/memo "
+                               "hits excluded — real compile cost only)",
+                               labels=("what",), buckets=COMPILE_BUCKETS))
+
+
+#: the process's own copy of the two families, written under `_lock`: what
+#: a registry attached later takes over (`Metric.absorb`)
+_record = _compile_metrics(MetricsRegistry())
+
+
 def _hit_label(cache_hit: Optional[bool]) -> str:
-    return "unknown" if cache_hit is None else \
-        ("true" if cache_hit else "false")
+    return "unknown" if cache_hit is None else (
+        "true" if cache_hit else "false")
+
+
+def _count(c: Metric, h: Metric, what: str, seconds: float,
+           cache_hit: Optional[bool]) -> None:
+    c.inc(what=what, cache_hit=_hit_label(cache_hit))
+    # the seconds histogram records REAL compile cost only: ~0-second
+    # memo/cache-hit events would collapse its percentiles toward zero and
+    # blind slow-compile attribution
+    if cache_hit is not True:
+        h.observe(seconds, what=what)
 
 
 def note_compile(what: str, seconds: float,
-                 cache_hit: Optional[bool] = None) -> None:
+                 cache_hit: Optional[bool] = None,
+                 stages: Optional[Dict[str, float]] = None) -> None:
     """Record one compile event (`what` is the site: "net" for
     CompiledNet.compile, "serve_bucket" for a serve bucket's first
-    forward). `cache_hit` is the persistent-cache verdict for the region
-    (see module doc; None = not sampled). Fans out to every attached
-    registry; never raises."""
+    forward, a jitted function's name or `OTHER` for an entry of the
+    compile log). `cache_hit` is the persistent-cache verdict for the
+    region (see module doc; None = not sampled); `stages` the seconds the
+    event spent in each stage, summed a `what` into `compile_stats()`.
+    Fans out to every attached registry; never raises."""
+    what, seconds = str(what), float(seconds)
     cache_hit = None if cache_hit is None else bool(cache_hit)
     with _lock:
-        _events.append((str(what), float(seconds), cache_hit))
+        d = _stats.setdefault(what, {"events": 0, "seconds": 0.0,
+                                     "cache_hits": 0, "cache_misses": 0})
+        d["events"] += 1
+        d["seconds"] += seconds
+        if cache_hit is not None:
+            d["cache_hits" if cache_hit else "cache_misses"] += 1
+        for k, v in (stages or {}).items():
+            d[k] = d.get(k, 0.0) + float(v)
+        _count(*_record, what, seconds, cache_hit)
         pairs = list(_attached)
     for c_ref, h_ref in pairs:
         c, h = c_ref(), h_ref()
         if c is None or h is None:
             continue
         try:
-            c.inc(what=what, cache_hit=_hit_label(cache_hit))
-            # the seconds histogram records REAL compile cost only:
-            # ~0-second memo/cache-hit events would collapse its
-            # percentiles toward zero and blind slow-compile attribution
-            if cache_hit is not True:
-                h.observe(seconds, what=what)
+            _count(c, h, what, seconds, cache_hit)
         except Exception:
             pass  # a dying registry must not break the compile path
 
 
 def attach_compile_metrics(registry: MetricsRegistry) -> None:
-    """Register the compile counter + histogram into `registry`, replay
-    every event recorded so far (compiles routinely PRECEDE registry
-    creation), and keep feeding it (weakly held) as new ones land."""
-    c = registry.counter("sparknet_compile_events_total",
-                         "XLA/spec compile events by site and persistent-"
-                         "cache outcome", labels=("what", "cache_hit"))
-    h = registry.histogram("sparknet_compile_seconds",
-                           "seconds per FRESH compile event (cache/memo "
-                           "hits excluded — real compile cost only)",
-                           labels=("what",), buckets=COMPILE_BUCKETS)
+    """Register the compile counter + histogram into `registry`, give them
+    what was recorded so far (compiles routinely PRECEDE registry
+    creation), and keep feeding it (weakly held) as new events land."""
+    c, h = _compile_metrics(registry)
     with _lock:
-        history = list(_events)
+        c.absorb(_record[0])
+        h.absorb(_record[1])
         _attached[:] = [(cr, hr) for cr, hr in _attached
                         if cr() is not None and hr() is not None]
         _attached.append((weakref.ref(c), weakref.ref(h)))
-    for what, seconds, cache_hit in history:
-        c.inc(what=what, cache_hit=_hit_label(cache_hit))
-        if cache_hit is not True:  # replay keeps the histogram's
-            h.observe(seconds, what=what)  # real-compile-cost contract
 
 
 def compile_stats() -> Dict[str, Dict[str, float]]:
     """{what: {"events": n, "seconds": total, "cache_hits": n,
-    "cache_misses": n}} — the accumulated record (tests, status JSON,
-    the BENCH_ECON cold-start child). Events with an unknown verdict
-    count in "events" only."""
-    out: Dict[str, Dict[str, float]] = {}
+    "cache_misses": n, and for the compile log's entries "trace_s",
+    "lower_s", "backend_s": their stages' sums}} — the accumulated record
+    (tests, status JSON, the BENCH_ECON cold-start child). Events with an
+    unknown verdict count in "events" only."""
     with _lock:
-        for what, seconds, cache_hit in _events:
-            d = out.setdefault(what, {"events": 0, "seconds": 0.0,
-                                      "cache_hits": 0, "cache_misses": 0})
-            d["events"] += 1
-            d["seconds"] += seconds
-            if cache_hit is not None:
-                d["cache_hits" if cache_hit else "cache_misses"] += 1
-    return out
+        return {what: dict(d) for what, d in _stats.items()}
+
+
+def _on_compile_entry(entry: Dict[str, Any]) -> None:
+    """The compile log's sink: an entry that closes is stamped with what
+    the program registered for its name and counted, under its own name if
+    it is one of the program's, else under `OTHER`."""
+    what = entry["what"]
+    for k, v in compile_stamp(what).items():
+        entry.setdefault(k, v)
+    stages = {k: entry[k] for k in STAGES}
+    note_compile(what if what in NAMED_PROGRAMS or what in _programs
+                 else OTHER, sum(stages.values()),
+                 cache_hit=entry["cache"] == "hit", stages=stages)
 
 
 class timed_compile:
     """Context manager stamping its wall time as one compile event, with
-    the persistent-cache verdict sampled over the region (thread-local —
-    concurrent lanes' compiles don't cross-attribute)."""
+    the persistent-cache verdict sampled over the region (this thread's
+    entries of the compile log — concurrent lanes' compiles don't
+    cross-attribute)."""
 
     def __init__(self, what: str):
         self.what = what
 
     def __enter__(self):
-        from ..utils.compile_cache import track_compiles
         self._track = track_compiles()
         self._track.__enter__()
         self._t0 = time.perf_counter()
@@ -154,6 +211,91 @@ class timed_compile:
             note_compile(self.what, time.perf_counter() - self._t0,
                          cache_hit=self._track.cache_hit)
         return False
+
+
+# -- start-up's account -------------------------------------------------------
+
+#: the three stages of an entry of the compile log
+STAGES = ("trace_s", "lower_s", "backend_s")
+#: the kept start-up spans summed under each heading of a start-up's sums
+STARTUP_PARTS = {"build": ("resolve_spec", "build_trainer"),
+                 "restore": ("restore",), "state": ("state_from_params",)}
+
+
+def startup_report(until: Optional[float] = None) -> Dict[str, Any]:
+    """What this process paid before its first round, and what it compiled
+    since: `{"import_t0": the package's first import, "until", "spans": the
+    kept start-up spans begun before `until`, "compiles": the compile log's
+    entries closed before it, "later_compiles": how many closed since (the
+    `"dropped_compiles"` the full log no longer holds among them),
+    "newest_compile": the last of those (`what`, `step`, `seconds`,
+    `cache`) or None}`; times on `time.perf_counter()`. `until` None (no
+    round has completed yet): everything so far is start-up."""
+    cut = math.inf if until is None else until
+    log, dropped = compile_log(), compile_log_dropped()
+    later = [e for e in log if e["t1"] >= cut]
+    newest = later[-1] if later else None
+    return {
+        "import_t0": trace.import_stamp(), "until": until,
+        "spans": [s for s in trace.startup_spans() if s["t0"] < cut],
+        "compiles": [e for e in log if e["t1"] < cut],
+        "later_compiles": 0 if until is None else len(later) + dropped,
+        "dropped_compiles": dropped,
+        "newest_compile": None if newest is None else {
+            "what": newest["what"], "step": newest.get("step"),
+            "seconds": sum(newest[k] for k in STAGES),
+            "cache": newest["cache"]}}
+
+
+def startup_sums(spans: List[Dict[str, Any]], compiles: List[Dict[str, Any]],
+                 since: float, program: str = "train_round"
+                 ) -> Dict[str, Any]:
+    """The one arithmetic of a start-up, over kept spans and entries of the
+    compile log the caller has cut already (`startup_report()`'s, or the
+    benchmark's "before the window"): `import_s` from `since` to the first
+    span (None with no span); `build_s`, `restore_s`, `state_s` the spans of
+    each heading of `STARTUP_PARTS` that no kept span encloses
+    (`trainer_init` is `build_trainer`'s time already, and the state a
+    resume builds is `restore`'s); `round`: `program`'s entries, each stage
+    summed, with their `entries` and the set of their `cache` verdicts;
+    `other`: the `entries` and `seconds` (all three stages) of every other
+    one; `cache_misses`: entries whose verdict is not `hit`."""
+    first = min((s["t0"] for s in spans), default=None)
+    own = [e for e in compiles if e["what"] == program]
+    other = [e for e in compiles if e["what"] != program]
+    out: Dict[str, Any] = {
+        "import_s": None if first is None else first - since}
+    for part, names in STARTUP_PARTS.items():
+        out[part + "_s"] = sum(s["t1"] - s["t0"] for s in spans
+                               if s["parent"] is None and s["name"] in names)
+    out["round"] = {**{k: sum(e[k] for e in own) for k in STAGES},
+                    "entries": len(own),
+                    "cache": sorted({e["cache"] for e in own})}
+    out["other"] = {"entries": len(other),
+                    "seconds": sum(e[k] for e in other for k in STAGES)}
+    out["cache_misses"] = sum(e["cache"] != "hit" for e in compiles)
+    return out
+
+
+def startup_line(report: Dict[str, Any], program: str = "train_round") -> str:
+    """`startup_report()` as the one line the train loop logs when its
+    first round completes: `start-up: import 14.2 s, build 3.1, restore 0,
+    state 1.9, train_round compile 41.0 (trace 9.1, lower 6.3, backend
+    25.6, cache miss), 2 other programs 3.3`."""
+    sums = startup_sums(report["spans"], report["compiles"],
+                        report["import_t0"], program)
+    parts = ["import " + ("?" if sums["import_s"] is None
+                          else f"{sums['import_s']:.1f} s")]
+    parts += [f"{part} {sums[part + '_s']:.1f}" for part in STARTUP_PARTS]
+    own = sums["round"]
+    if own["entries"]:
+        parts.append(
+            f"{program} compile {sum(own[k] for k in STAGES):.1f} (trace "
+            f"{own['trace_s']:.1f}, lower {own['lower_s']:.1f}, backend "
+            f"{own['backend_s']:.1f}, cache {'/'.join(own['cache'])})")
+    parts.append(f"{sums['other']['entries']} other programs "
+                 f"{sums['other']['seconds']:.1f}")
+    return "start-up: " + ", ".join(parts)
 
 
 #: memory_stats() keys -> gauge name suffix (jaxlib's PJRT spelling; a
@@ -222,6 +364,9 @@ class DeviceTelemetry:
 #: name -> zero-argument provider of the program's report (the newest
 #: registration: one trainer at most is kept alive by it)
 _programs: Dict[str, Any] = {}
+#: name -> zero-argument provider of what the compile log stamps on an
+#: entry of that name (`{"step": n}`), read when the entry closes
+_stamps: Dict[str, Any] = {}
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s*\(.*\{\s*$")
 _INSTRUCTION = re.compile(
@@ -268,10 +413,34 @@ def _nbytes(shapes) -> int:
     return sum(itemsize * math.prod(dims) for itemsize, dims in shapes)
 
 
-def register_program(name: str, provider) -> None:
+def register_program(name: str, provider, stamp=None) -> None:
     """Make `program_report(name)` answer from `provider()` — called by a
-    trainer when it compiles (the latest registration wins)."""
+    trainer when it compiles (the latest registration wins). `stamp()`, if
+    given, returns what every entry of the compile log under this name
+    carries besides its stages (`{"step": the round being dispatched}`):
+    read by the listener when such an entry closes, so on the compiling
+    thread, inside the call that compiled."""
     _programs[name] = provider
+    if stamp is not None:
+        _stamps[name] = stamp
+    else:
+        _stamps.pop(name, None)
+
+
+def compile_stamp(name: str) -> Dict[str, Any]:
+    """What the program registered for entries called `name`, now; {} for
+    a name nobody registered. Never raises: a compile must not fail for
+    its record."""
+    stamp = _stamps.get(name)
+    if stamp is None:
+        return {}
+    try:
+        return dict(stamp())
+    except Exception:
+        return {}
+
+
+on_entry(_on_compile_entry)
 
 
 def program_report(name: str) -> Optional[Dict[str, Any]]:

@@ -122,6 +122,28 @@ class Metric:
             h.sum += v
             h.n += 1
 
+    def absorb(self, other: "Metric") -> None:
+        """Add every child of `other` (a family of the same kind, labels
+        and buckets, of another registry) to this one: how a registry made
+        late takes over a record the process kept before it existed."""
+        assert (self.kind, self.label_names, self.buckets) == (
+            other.kind, other.label_names, other.buckets)
+        with other.registry._lock:
+            theirs = {k: ((list(v.counts), v.sum, v.n)
+                          if isinstance(v, _Hist) else v)
+                      for k, v in other._values.items()}
+        with self.registry._lock:
+            for key, v in theirs.items():
+                if self.kind != "histogram":
+                    self._values[key] = self._values.get(key, 0.0) + v
+                    continue
+                h = self._values.get(key)
+                if h is None:
+                    h = self._values[key] = _Hist(len(self.buckets))
+                h.counts = [a + b for a, b in zip(h.counts, v[0])]
+                h.sum += v[1]
+                h.n += v[2]
+
     # -- readers -------------------------------------------------------------
 
     def value(self, **labels: Any) -> Optional[float]:

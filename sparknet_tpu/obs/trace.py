@@ -32,6 +32,15 @@ stays readable after the session ends, until the next one begins. So a
 `named_scope`s on the device ops), and `trace_out` is the host-only,
 profiler-free view of the same spans.
 
+A third record is always on: the steps that run ONCE A BUILD (`startup_span`:
+`resolve_spec`, `build_trainer` with `compile_net` and `trainer_init` inside
+it, `state_from_params`, `restore`) are kept whether or not a tracer or a
+profiler is live, in one small bounded record (`MAX_STARTUP_SPANS`) that
+`startup_spans()` reads in the shape `session_spans()` returns, beside
+`import_stamp()`, the process's first import of the package on the same clock.
+The round's compile is not a span: it is the `train_round` entry of the compile
+log (`utils/compile_cache.compile_log()`), `t0` to `t1` on this clock too.
+
 Tracing is off by default. `span()` is on when a tracer was started
 (`start_tracing`) OR a profiler session is live
 (`TraceAnnotation.is_enabled()`): no configuration field, flag or environment
@@ -63,10 +72,16 @@ ANNOTATION_PREFIX = "sparknet:"
 MAX_EVENTS = 500_000
 
 
+#: start-up spans kept a process (a handful a trainer built; a process that
+#: rebuilds trainers for days stops adding, and counts, at this many)
+MAX_STARTUP_SPANS = 512
+
+
 class Tracer:
     """Collects span events; thread-safe; one instance per capture."""
 
-    def __init__(self):
+    def __init__(self, max_events: int = MAX_EVENTS):
+        self.max_events = max_events
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._thread_names: Dict[int, str] = {}
@@ -95,7 +110,7 @@ class Tracer:
         if args:
             ev["args"] = args
         with self._lock:
-            if len(self._events) >= MAX_EVENTS:
+            if len(self._events) >= self.max_events:
                 self.dropped += 1
                 return
             self._thread_names.setdefault(th.ident, th.name)
@@ -111,7 +126,7 @@ class Tracer:
         if args:
             ev["args"] = args
         with self._lock:
-            if len(self._events) >= MAX_EVENTS:
+            if len(self._events) >= self.max_events:
                 self.dropped += 1
                 return
             self._thread_names.setdefault(th.ident, th.name)
@@ -188,19 +203,83 @@ def _session_record(live: bool) -> Optional[Tracer]:
     return _session
 
 
-def session_spans() -> List[Dict[str, Any]]:
-    """The host spans of the live profiler session, or of the last one:
-    `{"name", "t0", "t1", "thread", "id", "parent", "args"}` each, times on
-    `time.perf_counter()`, `parent` the id of the span that enclosed it on
-    the same thread (None at the top), in order of completion. Empty when no
-    span ever ran inside a profiler session."""
-    rec = _session
+def _kept_spans(rec: Optional[Tracer]) -> List[Dict[str, Any]]:
     if rec is None:
         return []
     return [{"name": e["name"], "t0": e["t0"], "t1": e["t1"],
              "thread": e["thread"], "id": e["id"], "parent": e["parent"],
              "args": e.get("args", {})}
             for e in rec.events() if e["ph"] == "X"]
+
+
+def session_spans() -> List[Dict[str, Any]]:
+    """The host spans of the live profiler session, or of the last one:
+    `{"name", "t0", "t1", "thread", "id", "parent", "args"}` each, times on
+    `time.perf_counter()`, `parent` the id of the span that enclosed it on
+    the same thread (None at the top), in order of completion. Empty when no
+    span ever ran inside a profiler session."""
+    return _kept_spans(_session)
+
+
+#: the start-up spans of this process (a Tracer: bounded, thread-safe)
+_startup = Tracer(MAX_STARTUP_SPANS)
+
+
+def startup_spans() -> List[Dict[str, Any]]:
+    """The spans `startup_span` kept, in the shape of `session_spans()`, in
+    order of completion (an enclosing span after those inside it)."""
+    return _kept_spans(_startup)
+
+
+def import_stamp() -> float:
+    """`time.perf_counter()` at the process's first import of the package:
+    from here to the first start-up span is imports and backend start-up."""
+    from .. import IMPORT_T0
+    return IMPORT_T0
+
+
+class _recorded:
+    """The with-block as one span of `rec`: an id, the id of the span of
+    this record that encloses it on this thread (`_tls.<stack_name>`), its
+    two stamps on `time.perf_counter()` (`.t0`, and `.t1` once the block
+    has ended). A class, not a generator: it is `span()`'s cost a round
+    while a profiler session is live."""
+    __slots__ = ("rec", "stack", "name", "args", "sid", "parent", "t0", "t1")
+
+    def __init__(self, rec: Tracer, stack_name: str, name: str,
+                 args: Dict[str, Any]):
+        stack = getattr(_tls, stack_name, None)
+        if stack is None:
+            stack = []
+            setattr(_tls, stack_name, stack)
+        self.rec, self.stack, self.name, self.args = rec, stack, name, args
+
+    def __enter__(self) -> "_recorded":
+        self.sid = next(_ids)
+        self.parent = self.stack[-1] if self.stack else None
+        self.stack.append(self.sid)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t0, t1 = self.t0, time.perf_counter()
+        self.t1 = t1
+        self.stack.pop()
+        self.rec.add_complete(
+            self.name, self.rec.us(t0), (t1 - t0) * 1e6, self.args or None,
+            t0=t0, t1=t1, id=self.sid, parent=self.parent,
+            thread=threading.current_thread().name)
+        return False
+
+
+@contextmanager
+def startup_span(name: str, **args: Any) -> Iterator[None]:
+    """A step that runs once a build: kept in the start-up record whether or
+    not anything is tracing, and an ordinary `span()` besides. `args` are
+    plain numbers and strings (the record outlives the call: no array).
+    Usable as a decorator. Never on the per-round path."""
+    with _recorded(_startup, "startup_stack", name, args), span(name, **args):
+        yield
 
 
 @contextmanager
@@ -226,24 +305,14 @@ def span(name: str, **args: Any) -> Iterator[None]:
             if _active is tr:
                 tr.add_complete(name, t0, tr.now_us() - t0, args or None)
         return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    sid = next(_ids)
-    parent = stack[-1] if stack else None
-    stack.append(sid)
-    t0 = time.perf_counter()
+    kept = _recorded(rec, "stack", name, args)
     try:
-        with _Annotation(ANNOTATION_PREFIX + name, **args):
+        with kept, _Annotation(ANNOTATION_PREFIX + name, **args):
             yield
     finally:
-        t1 = time.perf_counter()
-        stack.pop()
-        rec.add_complete(name, rec.us(t0), (t1 - t0) * 1e6, args or None,
-                         t0=t0, t1=t1, id=sid, parent=parent,
-                         thread=threading.current_thread().name)
         if tr is not None and _active is tr:
-            tr.add_complete(name, tr.us(t0), (t1 - t0) * 1e6, args or None)
+            tr.add_complete(name, tr.us(kept.t0), (kept.t1 - kept.t0) * 1e6,
+                            args or None)
 
 
 @contextmanager

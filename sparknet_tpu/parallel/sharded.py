@@ -67,6 +67,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..model.layers import tp_shards_layer
 from ..model.net import CompiledNet, PyTree
+from ..obs import trace as obs_trace
 from ..solver import SolverConfig
 from .mesh import DATA_AXIS, MODEL_AXIS, shard_map_unchecked
 from .trainer import (ParallelTrainer, TrainState, _find_accuracy_blob,
@@ -310,6 +311,7 @@ class ShardedTrainer(ParallelTrainer):
         return {l: {p: adapt(l, p, m) for p, m in lp.items()}
                 for l, lp in mom.items()}
 
+    @obs_trace.startup_span("state_from_params")
     def state_from_params(self, params: PyTree,
                           momentum: Optional[PyTree] = None,
                           it: int = 0) -> TrainState:

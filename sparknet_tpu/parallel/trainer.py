@@ -241,8 +241,11 @@ class ParallelTrainer:
         self._compile()
         # the newest trainer answers `program_report("train_round")`: a
         # reader of a trace holds no trainer (strong: the reader may run
-        # when its caller has let the trainer go)
-        obs_device.register_program("train_round", self.program_report)
+        # when its caller has let the trainer go), and stamps the round's
+        # entries of the compile log with the step that compiled
+        obs_device.register_program(
+            "train_round", self.program_report,
+            stamp=lambda: {"step": self._dispatched - 1})
 
     #: checkpoint/state-layout tag ("replica": every leaf carries the
     #: leading [n_devices] axis; the NamedSharding trainer overrides with
@@ -306,6 +309,7 @@ class ParallelTrainer:
         workers from worker-0's weights, `apps/CifarApp.scala:98`)."""
         return self.state_from_params(self.net.init_params(key))
 
+    @obs_trace.startup_span("state_from_params")
     def state_from_params(self, params: PyTree,
                           momentum: Optional[PyTree] = None,
                           it: int = 0) -> TrainState:
