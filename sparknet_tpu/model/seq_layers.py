@@ -56,7 +56,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
-from ..ops import delta_rule
+from ..ops import delta_rule, kda_shape
 from .spec import (GQAttentionParam, KDAttentionParam, LayerSpec,
                    MLAttentionParam, MoEParam, ParamSpec)
 
@@ -447,38 +447,27 @@ def init_kdattention(key, layer: LayerSpec, in_shapes) -> Params:
 def kda(p: KDAttentionParam, params: Params, x, ctx):
     """Kimi Delta Attention, laid out as `mla` and `gqa` lay theirs out: the
     products of x with views of the stored matrices come out heads first,
-    [rows, heads, positions, d]; the three convolutions run over the
-    positions of that layout (`causal_taps`, float32, SiLU after); the L2
-    norms, the decay and the writing strength are float32; the rule itself
-    is `ops.delta_rule.gated_delta_rule`; its result is normed a head, scaled
-    by the head-wise gate and contracted with `o` as it lies.
+    [rows, heads, positions, d]; `ops.kda_shape.shape` takes the projections
+    to what the rule reads (three convolutions over the positions of that
+    layout, SiLU after, the L2 norms, the decay and the writing strength,
+    all float32: one Pallas kernel forward and one backward where the shape
+    is theirs, `causal_taps` and plain `jnp` under a checkpoint elsewhere);
+    the rule itself is `ops.delta_rule.gated_delta_rule`; its result is
+    normed a head, scaled by the head-wise gate and contracted with `o` as
+    it lies.
 
-    ONE ROW AT A TIME, in a checkpointed `lax.map`: between its products the
-    layer is some thirty float32 passes over [heads, positions, d] (0.13 GB
-    each at 32 x 8,192 x 128) that the backward pass holds together, 4.4 GB
-    for two rows at once against 2.4 (compiled for a v5e, PERF.md section 6,
-    PR 33); a row's are made again when its turn comes. The two elementwise
-    stages (what shapes q, k, v and the decay from the projections; the norm
-    and gate of the result) are checkpoints of their own for the same
-    reason."""
+    ONE ROW AT A TIME, in a checkpointed `lax.map`: what the backward pass of
+    a row holds together (the five projections, the rule's inputs and
+    segment states, the gate's) is 2.4 GB for one row where two rows at once
+    took 4.4 when the layer was some thirty float32 passes between its
+    products (compiled for a v5e, PERF.md section 6, PR 33); a row's values
+    are made again when its turn comes. Of the two elementwise stages the
+    first keeps its inputs alone (the kernels' `custom_vjp`; the `jnp` form's
+    checkpoint), the second (the norm and gate of the result) is a
+    checkpoint of its own."""
     h, hd, d = p.num_heads, p.head_dim, x.shape[-1]
-    heads_first, f32 = "rnc,chd->rhnd", jnp.float32
+    heads_first = "rnc,chd->rhnd"
     view = lambda name: params[name].reshape(d, h, hd)
-
-    def shaped(q, k, v, a, b):
-        with jax.named_scope("conv"):
-            q, k, v = (jax.nn.silu(causal_taps(
-                t.astype(f32), params[n + "_conv"].reshape(h, hd, p.taps)))
-                for t, n in ((q, "q"), (k, "k"), (v, "v")))
-        with jax.named_scope("gates"):
-            unit = lambda t: t * lax.rsqrt(
-                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-            g = p.lower_bound * jax.nn.sigmoid(
-                jnp.exp(params["A_log"])[:, None, None]
-                * (a.astype(f32) + params["dt_bias"].reshape(h, 1, hd)))
-            cast = lambda t: t.astype(precision.compute_dtype())
-            return (cast(unit(q) * hd ** -0.5), cast(unit(k)), cast(v), g,
-                    jax.nn.sigmoid(b.astype(f32)))
 
     def rows(x):
         with jax.named_scope("in_proj"):
@@ -486,7 +475,11 @@ def kda(p: KDAttentionParam, params: Params, x, ctx):
         with jax.named_scope("gates"):
             a = _project(heads_first, x, view("a"))
             b = _project("rnc,ch->rhn", x, params["beta"])
-        q, k, v, g, beta = jax.checkpoint(shaped)(q, k, v, a, b)
+        q, k, v, g, beta = kda_shape.shape(
+            q, k, v, a, b,
+            [params[n + "_conv"].reshape(h, hd, p.taps) for n in "qkv"],
+            params["dt_bias"].reshape(h, hd), params["A_log"], p.lower_bound,
+            conv=causal_taps, interpret=ctx.interpret)
         with jax.named_scope("delta"):
             o = delta_rule.gated_delta_rule(q, k, v, g, beta,
                                             interpret=ctx.interpret)

@@ -452,8 +452,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
     "row_gathers", "rows_gathered"}, "delta_rule": {"loops", "trips",
-    "kernel_calls", "carried_bytes", "instructions", "bytes",
-    "kept_bytes"}}` — see
+    "kernel_calls", "shape_kernel_calls", "carried_bytes", "instructions",
+    "bytes", "kept_bytes"}}` — see
     `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
@@ -806,7 +806,11 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     count the text does not give counts 1), "kernel_calls": the Pallas
     kernels' `custom-call` instructions among them in the whole program
     (the chunk stage's, `ops.pallas_delta_rule`: 0 where the `jnp` form
-    was taken), "carried_bytes": the most one of
+    was taken), "shape_kernel_calls": those of such layers in the whole
+    program under the scope of the stage before the rule, which shapes q,
+    k, v and the decay (`ops.kda_shape.SCOPE`, the scope its kernel pair
+    runs under: `ops.pallas_kda_shape`; 0 where its `jnp` form was taken; a
+    kernel elsewhere in such a layer counts in neither), "carried_bytes": the most one of
     them carries a trip (its state and what it walks), "instructions",
     "bytes": of the ops that hold neither a matmul nor a kernel, in the
     computation that moves most (a call of `moves_under`: one row's segment
@@ -817,12 +821,16 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
         return {}
     under = lambda op, parts: scopes.get(op["layer_type"]) in parts
     here = [op for op in ops.values() if under(op, op["scope"].split("/"))]
+    from ..ops.kda_shape import SCOPE
+    before = [op for op in ops.values() if op["layer_type"] in scopes
+              and SCOPE in op["scope"].split("/")]
     loops = [op["loop"] for op in here if "loop" in op]
     moves = moves_under(ops, lambda op, parts: under(op, parts)
                         and not op["matmul"] and op["opcode"] != "custom-call",
                         {})
     return {"loops": len(loops), "trips": sum(l["trips"] or 1 for l in loops),
             "kernel_calls": sum(op.get("pallas", False) for op in here),
+            "shape_kernel_calls": sum(op.get("pallas", False) for op in before),
             "carried_bytes": max((l["carried_bytes"] for l in loops), default=0),
             **moves, "kept_bytes": kept_bytes}
 

@@ -17,7 +17,12 @@ and the shape is the kernels' (`_can_pallas`: chunks of 64, heads whose
 widths are multiples of the 128 lanes), and `_chunk_operands` in plain `jnp`
 everywhere else: narrow heads, short rows, any other backend. The `jnp` form
 is the kernels' oracle (`tests/test_delta_rule.py`), the scan over chunks is
-one `lax.scan` for both.
+one `lax.scan` for both. What stands BEFORE the chunk stage is the layer's
+own (`ops.kda_shape`: the convolutions, norms and gates that make q, k, v, g
+and b from the projections); where that stage runs as its kernel pair it
+writes q, k, v in the policy's dtype and g in float32, whole programs of
+positions a row, so `gated_delta_rule`'s own casts and pads are no-ops and
+nothing stands between that kernel's results and this one's operands.
 
 The chunked (WY) form. With a_t = exp g_t and u_t = b_t (v_t - S_{t-1}^T (a_t
 * k_t)) the update is S_t = Diag(a_t) S_{t-1} + k_t u_t^T, so inside a chunk

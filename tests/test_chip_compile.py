@@ -127,6 +127,26 @@ def test_delta_rule_kernels_compile_for_v5e(v5e, grad, dtype):
     assert ("delta_chunk_bwd" if grad else "delta_chunk_fwd") in text
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_kda_shape_kernels_compile_for_v5e(v5e, grad, dtype):
+    """`ops.pallas_kda_shape`'s pair at the linear-attention cell's shape (a
+    row's 32 heads, 8,192 positions, 128 a head: a grid of 32 x 16 tiles of
+    512 positions with their halo blocks): Mosaic takes every op of both
+    bodies (the sublane rotations of 136 and 144 rows, the selects on the
+    program's index) and their blocks fit the scoped VMEM the calls state."""
+    from sparknet_tpu.ops.pallas_kda_shape import param_rows, shape_kernels
+    one = SingleDeviceSharding(v5e[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    x = s((1, 32, 8192, 128), dtype)
+    total = lambda *a: sum(jnp.sum(o.astype(jnp.float32))
+                           for o in shape_kernels(*a, 4, -5.0, False))
+    fn = jax.grad(total, argnums=(0, 1, 2, 3, 4)) if grad else total
+    text = _compiled_text(fn, x, x, x, x, s((32, param_rows(4), 128), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+    assert ("kda_shape_bwd" if grad else "kda_shape_fwd") in text
+
+
 def test_bf16_row_block_is_the_profiled_one():
     """PERF.md's LRN profile is of the bf16 kernel at these blocks; the f32
     repair states a VMEM need and must never move them."""
@@ -423,6 +443,10 @@ def test_delta_rule_block_compiles_for_v5e_and_fits(v5e, as_tpu):
     # forward in the block, forward again by the row, backward: a row loop each
     assert got["kernel_calls"] == sum(op.get("pallas", False) for op in under_delta) == 3, got
     assert "delta_chunk_fwd" in text and "delta_chunk_bwd" in text
+    # the stage before the rule likewise, under a scope of its own: what
+    # shapes q, k, v and the decay is `ops.pallas_kda_shape`'s pair
+    assert got["shape_kernel_calls"] == 3, got
+    assert "kda_shape_fwd" in text and "kda_shape_bwd" in text
     # a row's scan: 16 segments of 8 chunks, forward, made again, backward
     assert got["loops"] >= 6 and got["trips"] >= 3 * (16 + 8), got
     assert got["carried_bytes"] >= 32 * 128 * 128 * 4  # a row's float32 states
@@ -735,9 +759,13 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The linear-attention model's round (`ling3-flash-ep64-tau4`: six Kimi
     Delta Attention layers and one latent attention with direct queries,
     six expert layers behind a 512-wide group-limited router, an untied
-    head) for one described chip (~3 min): 6.58 GB of state (822,036,416
-    parameters and their momentum) + 5.68 GB of temporaries (the gradient is
-    3.29 of them). The one attention core runs as a kernel once a step body
+    head) for one described chip (~4 min): 6.58 GB of state (822,036,416
+    parameters and their momentum) + 6.22 GB of temporaries (the gradient is
+    3.29 of them; 6.23 before PR 39; 7.43 with every forward of the stage
+    before the rule a kernel call, which is why the shaping kernels' forward
+    rule makes v in plain `jnp`: `ops/pallas_kda_shape.py`, PERF.md section
+    6).
+    The one attention core runs as a kernel once a step body
     on its forward path alone; every delta rule is the chunk stage's kernels
     and loops over segments and chunks, and no gather or scatter in any
     operator touches an activation."""
@@ -761,6 +789,8 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # stage's kernels: forward, forward again by the row, backward
     assert rule["loops"] >= 2 * 6 * 3 * 2 and kept_names == ("kda_out",), rule
     assert rule["kernel_calls"] == 2 * 6 * 3, rule
+    # and as many of the stage before the rule (`ops.pallas_kda_shape`)
+    assert rule["shape_kernel_calls"] == 2 * 6 * 3, rule
     # six expert layers fetch tokens x k rows twice a step at k = 8 (twice
     # the helper's k of 4), and three times the buffer's 4,096 rows
     _routing_walks_rows(text, ops, trainer, 6 * 2 * 2, 4096)

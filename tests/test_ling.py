@@ -122,6 +122,36 @@ def test_layer_matches_the_reference(kind, policy):
         _close(got, want, policy)
 
 
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+def test_kda_on_the_kernels_equals_kda_in_jnp_loss_and_every_gradient(policy):
+    """A layer whose shape is the kernels' (heads of 128, 512 positions: a
+    tile of `ops.pallas_kda_shape`, four of `ops.pallas_delta_rule`) with
+    every kernel under the Pallas interpreter, against the same layer in the
+    `jnp` forms: the loss and every parameter's and the input's gradient."""
+    from sparknet_tpu.model.layers import ApplyCtx
+    wide = KDAttentionParam(num_heads=2, head_dim=128, taps=4, lower_bound=-5.0,
+                            eps=1e-6)
+    layer = sl.LayerSpec(name="k", type="KDAttention", kda=wide)
+    p = sl.init_kdattention(jax.random.PRNGKey(5), layer, ((ROWS, 512, D),))
+    p = dict(p, **{n: p[n] * 50.0 for n in ("q_conv", "k_conv", "v_conv")},
+             a=p["a"] * 20.0, dt_bias=p["dt_bias"] * 20.0,
+             A_log=0.3 * _x(7, (2,)), beta=p["beta"] * 20.0)
+    x = _x(41, (ROWS, 512, D))
+    loss = lambda ctx: jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(jnp.sin(sl.kda(wide, p, x, ctx))), argnums=(0, 1)))
+    with precision.policy(policy):
+        assert "kda_shape_fwd" in str(jax.make_jaxpr(
+            lambda p, x: sl.kda(wide, p, x, ApplyCtx(train=True, interpret=True)))(p, x))
+        want, (gp_want, gx_want) = loss(CTX)(p, x)
+        got, (gp_got, gx_got) = loss(ApplyCtx(train=True, interpret=True))(p, x)
+    tol = 1e-4 if policy == "float32" else 0.05
+    assert float(got) == pytest.approx(float(want), rel=tol, abs=tol)
+    for name in ["x"] + sorted(p):
+        a, b = (gx_got, gx_want) if name == "x" else (gp_got[name], gp_want[name])
+        err = float(jnp.linalg.norm(a - b)) / (float(jnp.linalg.norm(b)) + 1e-30)
+        assert err < tol, (name, err)
+
+
 def test_kda_is_causal_and_its_parts_are_what_the_formula_says():
     """Nothing at position t moves when what follows it changes; the decay
     lies in (-5, 0) and differs by head and channel; with the writing
@@ -341,10 +371,11 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     report = obs_device.program_report("train_round")
     assert report["recompute"][sl.ATTN_CORE]["kept_bytes"] == ROWS * POS * 4 * 16 * 4
     delta = report["delta_rule"]
-    assert set(delta) == {"loops", "trips", "kernel_calls", "carried_bytes",
-                          "instructions", "bytes", "kept_bytes"}
+    assert set(delta) == {"loops", "trips", "kernel_calls", "shape_kernel_calls",
+                          "carried_bytes", "instructions", "bytes", "kept_bytes"}
     assert delta["loops"] == 0 and delta["instructions"] > 0 and delta["bytes"] > 0
-    assert delta["kernel_calls"] == 0  # the `jnp` form: no TPU, narrow heads
+    # the `jnp` forms, of the rule and of the stage before it: no TPU, narrow heads
+    assert delta["kernel_calls"] == 0 and delta["shape_kernel_calls"] == 0
     # the three delta-rule blocks keep their layers' results, float32 here
     assert delta["kept_bytes"] == 3 * ROWS * POS * D * 4
     assert obs_device.program_part("delta_rule")["train_round"] == delta
@@ -376,7 +407,8 @@ def test_the_delta_rule_compiles_to_one_loop_over_chunks_a_pass():
     assert got["loops"] >= 6 and got["trips"] >= 2 * 3 + 8 * 3, got
     assert got["carried_bytes"] >= ROWS * 4 * 16 * 16 * 4
     assert got["instructions"] > 0 and got["bytes"] > 0
-    assert got["kernel_calls"] == 0  # heads of 16: the `jnp` form, here and on a TPU
+    # heads of 16: the `jnp` forms, here and on a TPU
+    assert got["kernel_calls"] == 0 and got["shape_kernel_calls"] == 0
     assert obs_device.delta_rule(ops, {}) == {}
     # a net of other layers: nothing under such a scope
     assert obs_device.delta_rule(ops, {"GQAttention": "delta"})["loops"] == 0
