@@ -330,6 +330,8 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
             x = jnp.transpose(x, (0, 3, 1, 2))
         x = x.reshape(x.shape[0], -1)
     x, w, mm_precision, mm_out = resolve_weight(params, x, ctx)
+    if layer.inner_product.float32_out:
+        mm_out = jnp.float32
     if layer.inner_product.transposed:  # a tied head: x @ w^T, w (out, in)
         y = jnp.einsum("...k,nk->...n", x, w, precision=mm_precision,
                        preferred_element_type=mm_out)
@@ -390,8 +392,14 @@ def _masked_softmax_loss(p, logits, label):
     if label.ndim == logits.ndim:  # Caffe's [N, 1] labels
         label = label[..., 0]
     ignore = p.ignore_label
-    if p.label_shift:
+    if p.label_shift or p.heads > 1:
         ignore = -1 if ignore is None else ignore
+    if p.heads > 1:
+        # [..., heads x V] -> [..., heads, V]; head m's labels beside it
+        logits = logits.reshape(logits.shape[:-1] + (p.heads, -1))
+        label = jnp.stack([shifted(label, p.label_shift + m, fill=ignore)
+                           for m in range(p.heads)], axis=-1)
+    elif p.label_shift:
         label = shifted(label, p.label_shift, fill=ignore)
     has_target = (jnp.ones(label.shape, bool) if ignore is None
                   else label != ignore)
@@ -399,6 +407,12 @@ def _masked_softmax_loss(p, logits, label):
     picked = jnp.take_along_axis(
         logits, jnp.where(has_target, label, 0)[..., None], axis=-1)[..., 0]
     nll = jax.nn.logsumexp(logits, axis=-1) - picked
+    if p.heads > 1:  # the mean over the heads of each head's own mean
+        over = tuple(range(label.ndim - 1))
+        count = jnp.maximum(jnp.sum(has_target, axis=over), 1)
+        return p.loss_weight * jnp.mean(
+            jnp.sum(jnp.where(has_target, nll, 0.0), axis=over)
+            / count.astype(jnp.float32))
     count = jnp.maximum(jnp.sum(has_target), 1).astype(jnp.float32)
     return p.loss_weight * jnp.sum(jnp.where(has_target, nll, 0.0)) / count
 

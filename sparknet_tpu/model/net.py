@@ -198,6 +198,20 @@ class CompiledNet:
         return ({l.type: DELTA_SCOPES[l.type] for l in layers},
                 _kept_names(l for l in layers if l.block is not None))
 
+    def eva_scopes(self) -> Tuple[Dict[str, Tuple[str, str]], Optional[dict]]:
+        """({layer type: the scopes under such a layer's own that hold its
+        chunk summaries and its core}, what one such layer's core is given:
+        `seq_layers.eva_core_blocks`) for the types of this net's layers in
+        `seq_layers.EVA_SCOPES`; ({}, None) for a net without any."""
+        from .seq_layers import EVA_SCOPES, eva_core_blocks
+        layers = [l for l in self.spec.layers_for_phase("TRAIN")
+                  if l.type in EVA_SCOPES]
+        if not layers:
+            return {}, None
+        return ({l.type: EVA_SCOPES[l.type] for l in layers},
+                eva_core_blocks(layers[0].eva,
+                                self.blob_shapes[layers[0].bottoms[0]][1]))
+
     def routing_scopes(self) -> Tuple[Tuple[str, ...], int]:
         """(the scopes under which this net's expert layers choose experts
         and move rows to and from them, the width of the rows they move):

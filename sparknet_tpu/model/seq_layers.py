@@ -1,14 +1,18 @@
-"""The sequence-model layer set: what a sparse-expert decoder is built from.
+"""The sequence-model layer set: what a decoder, sparse-expert or dense, is
+built from.
 
-Embed, RMSNorm, two kinds of attention -- MLAttention (multi-head latent
-attention, its queries through a latent or projected directly) and
-GQAttention (grouped-query attention with per-head q/k norms) -- KDAttention
+Embed, RMSNorm (its scale w, or 1 + w), three kinds of softmax attention --
+MLAttention (multi-head latent attention, its queries through a latent or
+projected directly), GQAttention (grouped-query attention with per-head q/k
+norms) and EVAttention (EVA: an exact causal window beside learned summaries
+of the chunks before it, under one softmax; `ops.eva`) -- KDAttention
 (a linear attention: one matrix state a head, updated by the gated delta
 rule, `ops.delta_rule`), ShortConv (a gated short convolution: the operator a
 hybrid decoder sets between its attention layers), GatedMLP (SwiGLU), MoE
 (routed experts of which this chip holds a share, with or without a shared
 one, chosen among all or among the best groups), MTP (a
-multi-token-prediction module) and Eltwise (the residual sum). Same three
+multi-token-prediction module) and Eltwise (the residual sum, in float32
+where the model carries its stream so). Same three
 functions a layer type as `layers.py` (`init_`, `apply_`, `infer_`);
 registered there in `LAYER_IMPLS`. Activations are `[rows, positions, d]`;
 matrices are stored (in, out) in float32 and cast by the precision policy at
@@ -33,7 +37,12 @@ first, rotary columns half-split, q scaled, so no activation is sliced at a
 stride, transposed or scaled between a projection and the core. Both kinds
 of attention hand the one `attention_core` the kernel's own operands; where
 the key/value heads are fewer than the query heads the kernel does the
-grouping.
+grouping. The core is causal over as many keys as queries unless its layer
+hands it a mask: a value the layer derives from its own parameters (never a
+run's option), a function of (query positions, key columns) the kernel
+works out tile by tile, skipping the tiles it empties; the key columns may
+then be more than the queries (`eva`: a row's keys and one summary a chunk
+behind them).
 
 A layer may name a value that is dear to compute again and cheap to keep
 (`KEPT_NAMES`, by layer type): the recomputation block such a layer stands in
@@ -56,9 +65,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
-from ..ops import delta_rule, kda_shape
-from .spec import (GQAttentionParam, KDAttentionParam, LayerSpec,
-                   MLAttentionParam, MoEParam, ParamSpec)
+from ..ops import delta_rule, eva as eva_ops, kda_shape
+from .spec import (EVAttentionParam, GQAttentionParam, KDAttentionParam,
+                   LayerSpec, MLAttentionParam, MoEParam, ParamSpec)
 
 Params = Dict[str, jnp.ndarray]
 
@@ -73,7 +82,10 @@ Params = Dict[str, jnp.ndarray]
 #: to a lane row (32.3 at (1024, 1024, 512), which latent attention has not
 #: been read at); the three grouped products 14.1 ms at these tiles, which
 #: overhang 1,792 (megablox masks the overhang), against 19.7 at tiles of 256
-#: that divide it, 40.9 at 128 and 12.2 at (512, 1024, 1024)
+#: that divide it, 40.9 at 128 and 12.2 at (512, 1024, 1024). Under a mask the
+#: key columns need not be as many as the queries, but both must be whole
+#: tiles of the largest (EVA's 16,384 + 1,024 columns are 17 tiles of keys);
+#: a key length that is no multiple of it takes the exact path
 ATTN_BLOCKS = (512, 1024, 512)
 GMM_TILING = (512, 512, 512)
 #: the counters an expert layer returns beside its result, in this order
@@ -157,22 +169,27 @@ def infer_same(layer: LayerSpec, in_shapes):
 
 
 def init_rmsnorm(key, layer: LayerSpec, in_shapes) -> Params:
-    return {"scale": jnp.ones((in_shapes[0][-1],), jnp.float32)}
+    fill = jnp.zeros if layer.rmsnorm.unit_offset else jnp.ones
+    return {"scale": fill((in_shapes[0][-1],), jnp.float32)}
 
 
 def apply_rmsnorm(layer: LayerSpec, params: Params, inputs, ctx):
-    return (_rms(inputs[0], params["scale"], layer.rmsnorm.eps),)
+    p, scale = layer.rmsnorm, params["scale"]
+    return (_rms(inputs[0], 1.0 + scale if p.unit_offset else scale, p.eps),)
 
 
 # -- Eltwise -----------------------------------------------------------------
 
 def apply_eltwise(layer: LayerSpec, params, inputs, ctx):
-    """Caffe's Eltwise SUM, with its `coeff`s: the residual add, and the
-    sum of the weighted losses."""
+    """Caffe's Eltwise SUM, with its `coeff`s: the residual add (in
+    float32, and left so, where the layer says `float32`), and the sum of
+    the weighted losses."""
     p = layer.eltwise
     if p is not None and p.operation != "SUM":
         raise ValueError(f"layer {layer.name!r}: Eltwise operation "
                          f"{p.operation!r} is not built (SUM is)")
+    if p is not None and p.float32:
+        inputs = tuple(x.astype(jnp.float32) for x in inputs)
     coeff = p.coeff if p and p.coeff else (1.0,) * len(inputs)
     assert len(coeff) == len(inputs), (layer.name, coeff)
     return (sum(x if c == 1.0 else c * x for c, x in zip(coeff, inputs)),)
@@ -252,7 +269,13 @@ def rotary(x, theta: float, rope: int):
 
 
 @functools.lru_cache(maxsize=8)
-def _splash(heads: int, positions: int):
+def _splash(heads: int, positions: int, mask=None, interpret: bool = False):
+    """The kernel for `heads` heads of `positions` queries: causal over as
+    many keys (`mask` None), or under a mask of its own -- a hashable
+    function of (query positions, key columns) with a `shape` (queries, key
+    columns), `ops.eva.WindowSummaryMask` -- which the kernel works out
+    tile by tile from the positions, reading no table, and whose empty tiles
+    it never visits."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     bq, bkv, bkv_compute = ATTN_BLOCKS
@@ -260,33 +283,48 @@ def _splash(heads: int, positions: int):
                           block_q_dkv=bq, block_kv_dkv=bkv,
                           block_kv_dkv_compute=bkv_compute,
                           use_fused_bwd_kernel=True)  # dq with dk, dv: one pass
-    mask = sm.MultiHeadMask([sm.CausalMask((positions, positions))] * heads)
+    if mask is None:
+        one = sm.CausalMask((positions, positions))
+    else:
+        class Computed(sm._ComputableMask):
+            __eq__ = lambda a, b: (isinstance(b, Computed)
+                                   and a.mask_function == b.mask_function)
+            __hash__ = lambda a: hash(a.mask_function)
+        one = Computed(mask.shape, mask)
     with jax.ensure_compile_time_eval():  # the mask tables are constants
         # the kernel names its output and log-sum-exp itself
-        return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+        return sk.make_splash_mha(sm.MultiHeadMask([one] * heads),
+                                  block_sizes=sizes, head_shards=1,
                                   q_seq_shards=1,
-                                  residual_checkpoint_name=ATTN_CORE)
+                                  residual_checkpoint_name=ATTN_CORE,
+                                  interpret=interpret)
 
 
-def attention_core(q, k, v, ctx):
+def attention_core(q, k, v, ctx, mask=None):
     """Causal softmax(q k^T) v over q [rows, heads, positions, d] and k, v
     [rows, key/value heads, positions, d], heads first as the kernel reads
     and writes them; q comes scaled (its layer folds 1/sqrt(d) into a
     weight). Where the key/value heads are fewer, query heads g*n ..
-    g*n + n - 1 read key/value head g (the kernel's own grouping). The
-    kernel where it applies (positions a multiple of its tile, head sizes a
-    half or whole lane rows); else the exact path, which materialises the
+    g*n + n - 1 read key/value head g (the kernel's own grouping). With a
+    `mask` (`_splash`: a value the layer derives from its own parameters)
+    k and v hold `mask.shape[1]` key columns, more or fewer than the
+    queries, and a query reads those the mask grants it. The kernel where
+    it applies (queries and key columns whole tiles, head sizes a half or
+    whole lane rows); else the exact path, which materialises the
     scores."""
     n, group = q.shape[2], q.shape[1] // k.shape[1]
     if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0
+            and k.shape[2] % max(ATTN_BLOCKS) == 0
             and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0
             and q.dtype == jnp.bfloat16):
-        return jax.vmap(_splash(q.shape[1], n))(q, k, v)
+        return jax.vmap(_splash(q.shape[1], n, mask))(q, k, v)
     # the exact path's positions-first, every query head with its own copy
     swap = lambda x: jnp.swapaxes(x, 1, 2)
     spread = lambda x: swap(x if group == 1 else jnp.repeat(x, group, axis=1))
+    bias = None if mask is None else jnp.where(mask.dense(), 0.0, -jnp.inf)
     return checkpoint_name(swap(attention_ops.attention(
-        swap(q), spread(k), spread(v), causal=True, scale=1.0)), ATTN_CORE)
+        swap(q), spread(k), spread(v), causal=mask is None, bias=bias,
+        scale=1.0)), ATTN_CORE)
 
 
 def _project(spec: str, x, w):
@@ -382,6 +420,74 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
 
 def apply_gqattention(layer: LayerSpec, params: Params, inputs, ctx):
     return (gqa(layer.gqa, params, inputs[0], ctx),)
+
+
+# -- EVAttention -------------------------------------------------------------
+
+def init_evattention(key, layer: LayerSpec, in_shapes) -> Params:
+    p, d = layer.eva, in_shapes[0][-1]
+    ks = jax.random.split(key, 6)
+    hd = p.num_heads * p.head_dim
+    return {"q": _normal(ks[0], (d, hd), p.std),
+            "k": _normal(ks[1], (d, hd), p.std),
+            "v": _normal(ks[2], (d, hd), p.std),
+            "mu": _normal(ks[3], (p.num_heads, p.head_dim), p.std),
+            "phi": _normal(ks[4], (p.num_heads, p.head_dim), p.std),
+            "o": _normal(ks[5], (hd, d), p.std)}
+
+
+def eva(p: EVAttentionParam, params: Params, x, ctx):
+    """EVA attention, laid out as `gqa` lays its own out (as many key/value
+    heads as query heads, no q/k norm: 1/sqrt(d) rides on the view of q's
+    weight): q and k through the rotary turn over the whole head, v from its
+    product. A row longer than a window gets, behind its keys and values,
+    one summary of every chunk (`summaries`: `ops.eva.chunk_summaries`, of
+    the turned keys), and the one core call (`core`) reads keys and
+    summaries under `ops.eva.WindowSummaryMask`: one softmax, one
+    normaliser, the gradients reaching mu and phi through the
+    concatenation. A row no longer than a window has no chunk behind it
+    and is plain causal attention."""
+    h, hd, d, n = p.num_heads, p.head_dim, x.shape[-1], x.shape[1]
+    heads_first = "rnc,chd->rhnd"
+    view = lambda name: params[name].reshape(d, h, hd)
+    q = rotary(_project(heads_first, x, view("q") / np.sqrt(hd)),
+               p.rope_theta, hd)
+    k = rotary(_project(heads_first, x, view("k")), p.rope_theta, hd)
+    v = _project(heads_first, x, view("v"))
+    mask = None
+    if n > p.window_size:
+        mask = eva_ops.WindowSummaryMask(n, p.window_size, p.chunk_size)
+        with jax.named_scope("summaries"):
+            k_s, v_s = eva_ops.chunk_summaries(k, v, params["mu"],
+                                               params["phi"], p.chunk_size)
+            k = jnp.concatenate([k, k_s], axis=2)
+            v = jnp.concatenate([v, v_s], axis=2)
+    with jax.named_scope("core"):
+        o = attention_core(q, k, v, ctx, mask)
+    return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
+
+
+def eva_core_blocks(p: EVAttentionParam, positions: int) -> Dict[str, int]:
+    """What one EVA layer's core is given at `positions` a row:
+    {"keys_per_query": the key columns behind a query row (the positions and
+    one summary a chunk; the positions alone within one window),
+    "blocks_visited": the key blocks the forward kernel's tables send it to,
+    all query blocks together, "blocks": the key blocks there are} -- 0 and
+    0 where the shape is not the kernel's."""
+    if positions <= p.window_size:
+        return {"keys_per_query": positions, "blocks_visited": 0, "blocks": 0}
+    mask = eva_ops.WindowSummaryMask(positions, p.window_size, p.chunk_size)
+    n, n_kv = mask.shape
+    out = {"keys_per_query": n_kv, "blocks_visited": 0, "blocks": 0}
+    if n % max(ATTN_BLOCKS) == 0 and n_kv % max(ATTN_BLOCKS) == 0:
+        table = _splash(p.num_heads, n, mask).fwd_mask_info.block_mask
+        out.update(blocks_visited=int(np.count_nonzero(np.asarray(table))),
+                   blocks=(n // ATTN_BLOCKS[0]) * (n_kv // ATTN_BLOCKS[1]))
+    return out
+
+
+def apply_evattention(layer: LayerSpec, params: Params, inputs, ctx):
+    return (eva(layer.eva, params, inputs[0], ctx),)
 
 
 # -- ShortConv ---------------------------------------------------------------
@@ -752,7 +858,8 @@ COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
 #: block that holds the result has no reason to run the layer again itself
 #: (PERF.md section 6, PR 33)
 KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
-              "GQAttention": (ATTN_CORE,), "KDAttention": (KDA_OUT,)}
+              "GQAttention": (ATTN_CORE,), "EVAttention": (ATTN_CORE,),
+              "KDAttention": (KDA_OUT,)}
 #: kept name -> the name of the Pallas kernel that computes its values, as a
 #: compiled program's text has it: run again in the backward pass only if
 #: the name did not reach a recomputation block's policy (a kept value no
@@ -762,10 +869,13 @@ KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts
 ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": "",
-                    "KDAttention": ""}
+                    "EVAttention": "", "KDAttention": ""}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: delta rule: whose loops and device ops `obs.device.delta_rule` counts
 DELTA_SCOPES = {"KDAttention": "delta"}
+#: layer type -> the named scopes, under the layer's own, of its chunk
+#: summaries and of its core: whose kernels and bytes `obs.device.eva` counts
+EVA_SCOPES = {"EVAttention": ("summaries", "core")}
 #: the named scopes, under an expert layer's own (`moe`), whose device ops
 #: `obs.device.routing_moves` counts
 ROUTING_SCOPES = ("router", "dispatch", "combine")
@@ -777,6 +887,7 @@ SEQ_LAYER_IMPLS = {
     "GatedMLP": (init_gatedmlp, apply_gatedmlp, infer_same),
     "MLAttention": (init_mlattention, apply_mlattention, infer_same),
     "GQAttention": (init_gqattention, apply_gqattention, infer_same),
+    "EVAttention": (init_evattention, apply_evattention, infer_same),
     "KDAttention": (init_kdattention, apply_kdattention, infer_same),
     "ShortConv": (init_shortconv, apply_shortconv, infer_same),
     "MoE": (init_moe, apply_moe, infer_moe),

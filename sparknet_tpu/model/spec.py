@@ -10,9 +10,11 @@ Layer set = what the reference model zoo uses (reference
 SoftmaxWithLoss, Accuracy, Dropout — plus Input declarations — and the
 sequence-model set a sparse-expert decoder is built from: Embed, RMSNorm,
 MLAttention (latent attention), GQAttention (grouped-query attention),
-ShortConv (a gated short convolution), GatedMLP, MoE (an expert layer that
-holds a share of its experts), MTP (a multi-token-prediction module) and
-Eltwise (the residual sum, as Caffe has it).
+EVAttention (an exact causal window beside chunk summaries, one softmax),
+KDAttention (a delta-rule linear attention), ShortConv (a gated short
+convolution), GatedMLP, MoE (an expert layer that holds a share of its
+experts), MTP (a multi-token-prediction module) and Eltwise (the residual
+sum, as Caffe has it).
 """
 from __future__ import annotations
 
@@ -87,6 +89,10 @@ class InnerProductParam:
     #: transpose: a head tied to an embedding's table (`param_from` the Embed
     #: layer, whose gradient is then the sum of both uses)
     transposed: bool = False
+    #: the product comes out float32 whatever the precision policy (under
+    #: bfloat16: its operands rounded, its accumulators as they are): a
+    #: head whose logits are not rounded on their way to the loss
+    float32_out: bool = False
     weight_filler: Filler = field(default_factory=Filler)
     bias_filler: Filler = field(default_factory=Filler)
 
@@ -107,17 +113,25 @@ class LossParam:
     `ignore_label`: positions whose label equals it leave the mean (None:
     none do). `label_shift` k: the logits at position i are held against the
     label at position i + k of the same row, and the last k positions have
-    no target (a next-token loss reads the ids it was given as labels)."""
+    no target (a next-token loss reads the ids it was given as labels).
+    `heads` h > 1: the logits' last axis is h heads of V side by side, head
+    m held against the label at position i + `label_shift` + m; the loss is
+    the mean over the heads of each head's mean over the positions it
+    scores (several next tokens predicted from one position)."""
 
     ignore_label: Optional[int] = None
     loss_weight: float = 1.0
     label_shift: int = 0
+    heads: int = 1
 
 
 @dataclass(frozen=True)
 class EltwiseParam:
     operation: str = "SUM"
     coeff: Tuple[float, ...] = ()  # SUM only; () = all ones
+    #: the sum is taken in float32 and stays float32 whatever the precision
+    #: policy: a residual stream carried unrounded from block to block
+    float32: bool = False
 
 
 @dataclass(frozen=True)
@@ -134,6 +148,8 @@ class EmbedParam:
 @dataclass(frozen=True)
 class RMSNormParam:
     eps: float = 1e-5
+    #: the scale is 1 + w, the stored w starting at zero
+    unit_offset: bool = False
 
 
 @dataclass(frozen=True)
@@ -195,6 +211,26 @@ class GQAttentionParam:
     head_dim: int = 0
     rope_theta: float = 10000.0
     eps: float = 1e-5
+    std: float = 0.02
+
+
+@dataclass(frozen=True)
+class EVAttentionParam:
+    """EVA as EvaByte runs it (arXiv:2302.04542, simplified for causal
+    byte-level modelling): `num_heads` heads of `head_dim` with as many
+    key/value heads, rotary over the whole head on contiguous halves, no
+    q/k norm. Position i reads, under ONE softmax, the keys of its own
+    aligned window of `window_size` positions up to itself, and one learned
+    summary of every `chunk_size` positions of the windows before: key
+    mean(k) + mu, value sum_j softmax_j(phi . k_j / sqrt(head_dim)) v_j, mu
+    and phi a vector of `head_dim` a head. Rows no longer than a window are
+    plain causal attention; longer ones are whole windows."""
+
+    num_heads: int = 0
+    head_dim: int = 0
+    window_size: int = 0
+    chunk_size: int = 0
+    rope_theta: float = 10000.0
     std: float = 0.02
 
 
@@ -281,6 +317,7 @@ class LayerSpec:
     mla: Optional[MLAttentionParam] = None
     gqa: Optional[GQAttentionParam] = None
     kda: Optional[KDAttentionParam] = None
+    eva: Optional[EVAttentionParam] = None
     shortconv: Optional[ShortConvParam] = None
     gated_mlp: Optional[GatedMLPParam] = None
     moe: Optional[MoEParam] = None
@@ -340,7 +377,7 @@ class NetSpec:
 # Layer types that carry trainable parameters.
 PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct", "Embed", "RMSNorm",
                           "MLAttention", "GQAttention", "KDAttention",
-                          "ShortConv",
+                          "EVAttention", "ShortConv",
                           "GatedMLP", "MoE", "MTP")
 
 

@@ -453,14 +453,17 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
     "row_gathers", "rows_gathered"}, "delta_rule": {"loops", "trips",
     "kernel_calls", "shape_kernel_calls", "carried_bytes", "instructions",
-    "bytes", "kept_bytes"}}` — see
+    "bytes", "kept_bytes"}, "eva": {"layers", "core_forward_calls",
+    "core_backward_calls", "keys_per_query", "blocks_visited", "blocks",
+    "summary_instructions", "summary_bytes"}}` — see
     `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
     `attention_moves` for what a step's attention moves without
     computing, `routing_moves` for what its expert layers move around
-    their products and `delta_rule` for how its delta rules were compiled
-    (each {} for a net without such layers; all three call `moves_under`).
+    their products, `delta_rule` for how its delta rules were compiled and
+    `eva` for how its EVA attention layers were
+    (each {} for a net without such layers; all four call `moves_under`).
     None when no such
     program is registered or it has not been dispatched yet.
 
@@ -835,17 +838,60 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
             **moves, "kept_bytes": kept_bytes}
 
 
+def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
+        core: Optional[Dict[str, int]] = None) -> Dict[str, int]:
+    """How a program's EVA attention layers were compiled: of the device ops
+    of such layers (`scopes`: layer type -> the scopes under the layer's own
+    that hold its chunk summaries and its core; `core`: what a layer's core
+    is given -- both `CompiledNet.eva_scopes()`), `{"layers": the layers of
+    these types, "core_forward_calls" / "core_backward_calls": the Pallas
+    kernels' `custom-call` instructions under the core scope on a forward
+    path and on a backward one (`phase` of `scope_of`: a forward kernel run
+    again for the backward counts with the backward's), in the step body
+    that has most (one forward and one backward a layer where the kernel
+    ran and the block kept its output; 0 and 0 off the chip),
+    "keys_per_query": the key columns a query row is given (the row's
+    positions and one summary a chunk), "blocks_visited" / "blocks": the
+    key blocks a layer's forward kernel visits over the key blocks there
+    are, all query blocks together (the mask's density as the kernel's
+    tables have it), "summary_instructions" / "summary_bytes": the device
+    ops under the summaries scope and their operands' and results' bytes,
+    in the step body that moves most (a call of `moves_under`)}`. {} for a
+    net without such layers."""
+    if not scopes:
+        return {}
+    of = lambda op, which: scopes.get(op["layer_type"], (None, None))[which]
+    calls: Dict[str, Dict[str, int]] = {}   # computation -> phase -> n
+    for op in ops.values():
+        if op.get("pallas") and of(op, 1) in op["scope"].split("/"):
+            body = calls.setdefault(op["computation"], {})
+            body[op["phase"]] = body.get(op["phase"], 0) + 1
+    most = max(calls.values(), key=lambda b: sum(b.values()), default={})
+    moves = moves_under(
+        ops, lambda op, parts: of(op, 0) is not None and of(op, 0) in parts, {})
+    return {"layers": len({op["layer"] for op in ops.values()
+                           if op["layer_type"] in scopes}),
+            "core_forward_calls": most.get("forward", 0),
+            "core_backward_calls": most.get("backward", 0),
+            **{key: (core or {}).get(key, 0)
+               for key in ("keys_per_query", "blocks_visited", "blocks")},
+            "summary_instructions": moves["instructions"],
+            "summary_bytes": moves["bytes"]}
+
+
 def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
-                       routing=((), 0), delta=({}, ())) -> Dict[str, Any]:
+                       routing=((), 0), delta=({}, ()),
+                       eva_layers=({}, None)) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
     names its net's recomputation blocks keep `recompute_report`, for
     its attention layers (`attention`: their scopes and positions)
     `attention_moves`, for its expert layers (`routing`: their routing
-    scopes and the model's width) `routing_moves`, and for its delta-rule
+    scopes and the model's width) `routing_moves`, for its delta-rule
     layers (`delta`: their scopes and the names their blocks keep)
-    `delta_rule`."""
+    `delta_rule`, and for its EVA attention layers (`eva_layers`: their
+    scopes and what a core is given) `eva`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
@@ -856,12 +902,13 @@ def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
             "routing_moves": routing_moves(ops, *routing),
             "delta_rule": delta_rule(ops, delta[0], sum(
                 _named_bytes(jaxpr, name) for name in delta[1]
-                if jaxpr is not None))}
+                if jaxpr is not None)),
+            "eva": eva(ops, *eva_layers)}
 
 
 #: program -> these parts of its report, once `program_report` has run
 REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves",
-                "delta_rule")
+                "delta_rule", "eva")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
@@ -894,6 +941,20 @@ def attach_program_gauges(registry: MetricsRegistry,
             f"attention, a step, that hold neither a matmul nor a kernel "
             f"(read by program_report)"
         ).set_fn(lambda key=key: part("attention_moves")[key])
+    for key, what in (("core_forward_calls", "forward kernel calls of the "
+                       "cores, a step"),
+                      ("core_backward_calls", "backward kernel calls of the "
+                       "cores, a step"),
+                      ("keys_per_query", "key columns a query row is given"),
+                      ("blocks_visited", "key blocks a core's forward visits"),
+                      ("blocks", "key blocks a core's forward could visit"),
+                      ("summary_bytes", "operand and result bytes of the "
+                       "chunk summaries' device ops, a step")):
+        registry.gauge(
+            f"sparknet_{name}_eva_{key}",
+            f"{what}, of the {name} program's EVA attention layers (read "
+            f"by program_report)"
+        ).set_fn(lambda key=key: part("eva")[key])
 
 
 def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:

@@ -894,7 +894,8 @@ class ParallelTrainer:
             self._report = obs_device.report_of_compiled(
                 traced.lower().compile(), self.net.kept_kernels(),
                 traced.jaxpr.jaxpr, self.net.attention_scopes(),
-                self.net.routing_scopes(), self.net.delta_scopes())
+                self.net.routing_scopes(), self.net.delta_scopes(),
+                self.net.eva_scopes())
         return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":

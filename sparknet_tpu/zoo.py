@@ -7,7 +7,7 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and three families of sequence models, each built from a file of its
+and four families of sequence models, each built from a file of its
 published config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
@@ -21,6 +21,11 @@ published config:
                       delta rule -- in five layers of six and latent
                       attention with direct queries in the sixth, head-wise
                       output gates, experts chosen among the best groups)
+  - evabyte        <- huggingface.co/EvaByte/EvaByte config.json
+                      (a dense byte-level decoder: EVA attention -- an exact
+                      causal window beside chunk summaries under one softmax
+                      -- norms scaled by 1 + w, a float32 residual stream,
+                      eight next-byte heads with float32 logits)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -30,11 +35,12 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 from .model.spec import (AccuracyParam, ConvolutionParam, DropoutParam,
-                         EltwiseParam, EmbedParam, Filler, GatedMLPParam,
-                         GQAttentionParam, InnerProductParam, InputSpec,
-                         KDAttentionParam, LayerSpec, LossParam, LRNParam, MLAttentionParam,
-                         MoEParam, MTPParam, NetSpec, ParamSpec, PoolingParam,
-                         RMSNormParam, ShortConvParam)
+                         EltwiseParam, EmbedParam, EVAttentionParam, Filler,
+                         GatedMLPParam, GQAttentionParam, InnerProductParam,
+                         InputSpec, KDAttentionParam, LayerSpec, LossParam,
+                         LRNParam, MLAttentionParam, MoEParam, MTPParam,
+                         NetSpec, ParamSpec, PoolingParam, RMSNormParam,
+                         ShortConvParam)
 
 _GAUSS = lambda std: Filler(type="gaussian", std=std)
 _CONST = lambda v=0.0: Filler(type="constant", value=v)
@@ -193,15 +199,18 @@ def adult_mlp(batch: int = 64, n_features: int = 1) -> NetSpec:
 
 # -- what the sequence models' decoder blocks share ---------------------------
 
-def _rms_layer(name, bottom, block, eps) -> LayerSpec:
+def _rms_layer(name, bottom, block, eps, unit_offset=False) -> LayerSpec:
     return LayerSpec(name=name, type="RMSNorm", bottoms=(bottom,),
-                     tops=(name,), rmsnorm=RMSNormParam(eps=eps), block=block)
+                     tops=(name,), block=block,
+                     rmsnorm=RMSNormParam(eps=eps, unit_offset=unit_offset))
 
 
-def _sum_layer(name, a, b, top, block) -> LayerSpec:
-    """The residual sum."""
+def _sum_layer(name, a, b, top, block, float32=False) -> LayerSpec:
+    """The residual sum (taken and carried in float32 where the model's
+    stream is)."""
     return LayerSpec(name=name, type="Eltwise", bottoms=(a, b), tops=(top,),
-                     block=block)
+                     block=block,
+                     eltwise=EltwiseParam(float32=True) if float32 else None)
 
 
 def _ff_layer(l: str, dense: bool, dense_width: int, experts: MoEParam,
@@ -520,7 +529,77 @@ def ling3_flash(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def evabyte(config: dict, rows: int, positions: int) -> NetSpec:
+    """An `evabyte` decoder (EvaByte: a dense byte-level model), each layer
+    whole on this chip, for training on `[rows, positions]` int32 byte ids
+    (input `tokens`; the targets are the ids themselves, read one to
+    `num_pred_heads` positions on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here: `num_hidden_layers` layers, each x += EVA(RMSNorm(x)); x +=
+    SwiGLU(RMSNorm(x)) -- EVA attention (`attention_class` "eva") over
+    aligned windows of `window_size` positions and summaries of
+    `chunk_size`; every norm's scale 1 + w (`norm_add_unit_offset`); both
+    sums taken and carried in float32 (`fp32_skip_add`); an untied head of
+    `num_pred_heads` x `vocab_size` columns, its logits float32
+    (`fp32_logits`): head m of position i is scored against byte i + 1 + m,
+    and the loss is the mean over the heads of each head's mean cross-entropy
+    over the positions whose target lies in the row. Every matrix, and the
+    summaries' mu and phi, start normal(0, `init_std`). Every block is a
+    recomputation block. Keys the builder cannot honour (another attention
+    class, fewer key/value heads, biases, a tied head, rope scaling) are
+    refused."""
+    c = config
+    d, eps, std = c["hidden_size"], c["rms_norm_eps"], c["init_std"]
+    heads, depth = c["num_attention_heads"], c["num_hidden_layers"]
+    if (c.get("attention_class") != "eva" or c["num_key_value_heads"] != heads
+            or c.get("attention_bias") or c.get("tie_word_embeddings")
+            or c.get("rope_scaling") or c.get("hidden_act", "silu") != "silu"
+            or d % heads):
+        raise ValueError("built: EVA attention with as many key/value heads "
+                         "as query heads, no biases, an untied head, plain "
+                         "rotary, SiLU; the file asks for something else")
+    attention = EVAttentionParam(
+        num_heads=heads, head_dim=d // heads, window_size=c["window_size"],
+        chunk_size=c["chunk_size"], rope_theta=float(c["rope_theta"]), std=std)
+    offset, f32 = bool(c.get("norm_add_unit_offset")), bool(c.get("fp32_skip_add"))
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps,
+                                                  offset)
+    res = lambda name, a, b, top, block: _sum_layer(name, a, b, top, block,
+                                                    f32)
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",), embed=EmbedParam(
+                            num_embeddings=c["vocab_size"], dim=d, std=std))]
+    for i in range(depth):
+        l, x = f"l{i}", f"x{i}"
+        layers += [
+            norm(f"{l}_attn_norm", x, l),
+            LayerSpec(name=f"{l}_attn", type="EVAttention",
+                      bottoms=(f"{l}_attn_norm",), tops=(f"{l}_attn",),
+                      eva=attention, block=l),
+            res(f"{l}_attn_res", x, f"{l}_attn", f"{l}_h", l),
+            norm(f"{l}_mlp_norm", f"{l}_h", l),
+            _ff_layer(l, True, c["intermediate_size"], None, std),
+            res(f"{l}_mlp_res", f"{l}_h", f"{l}_mlp", f"x{i + 1}", l)]
+    layers += [
+        norm("final_norm", f"x{depth}", "head"),
+        LayerSpec(name="lm_head", type="InnerProduct", bottoms=("final_norm",),
+                  tops=("lm_head",), block="head",
+                  inner_product=InnerProductParam(
+                      num_output=c["num_pred_heads"] * c["vocab_size"],
+                      bias_term=False, axis=-1,
+                      float32_out=bool(c.get("fp32_logits")),
+                      weight_filler=_GAUSS(std))),
+        LayerSpec(name="loss", type="SoftmaxWithLoss",
+                  bottoms=("lm_head", "tokens"), tops=("loss",), block="head",
+                  loss=LossParam(label_shift=1, heads=c["num_pred_heads"]))]
+    return NetSpec(name="evabyte",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
 SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe,
-                   "ling3_flash": ling3_flash}
+                   "ling3_flash": ling3_flash, "evabyte": evabyte}

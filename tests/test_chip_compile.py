@@ -794,3 +794,27 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # six expert layers fetch tokens x k rows twice a step at k = 8 (twice
     # the helper's k of 4), and three times the buffer's 4,096 rows
     _routing_walks_rows(text, ops, trainer, 6 * 2 * 2, 4096)
+
+
+@pytest.mark.slow
+def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The dense byte model's round (`evabyte-l4-tau4`: four layers of EVA
+    attention and SwiGLU at width 4,096, one row of 16,384 bytes a step, a
+    float32 residual stream, eight heads) for one described chip: 6.57 GB
+    of state (821,366,784 parameters and their momentum) and the round's
+    temporaries under 15 GB together. Every core is ONE kernel call forward
+    a layer-step over 17,408 key columns (the row's keys and 1,024 chunk
+    summaries) and one backward, on its forward path alone."""
+    compiled, trainer = _sequence_round(v5e, "evabyte-l4-tau4")
+    total = _round_bytes(compiled)
+    assert total < 15e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "16384,17408" not in text
+    from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
+                                         recompute_report)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_kernels())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (4, 0)
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves
