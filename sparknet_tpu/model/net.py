@@ -165,15 +165,16 @@ class CompiledNet:
                 for l in self.spec.layers_for_phase("TRAIN")
                 if l.type in COUNTER_TOPS}
 
-    def kept_kernels(self) -> Dict[str, str]:
-        """{name: the kernel that makes its values} for every name a
-        recomputation block of this net keeps for the backward pass
+    def kept_makers(self) -> Dict[str, str]:
+        """{name: what marks the device ops that make its values (a Pallas
+        kernel's name, a named scope: `seq_layers.KEPT_MAKERS`)} for every
+        name a recomputation block of this net keeps for the backward pass
         (`seq_layers.KEPT_NAMES` of the block's layer types). {} for a net
         without blocks, or whose blocks' layers name nothing."""
-        from .seq_layers import KEPT_KERNELS
-        return {n: KEPT_KERNELS[n] for n in _kept_names(
+        from .seq_layers import KEPT_MAKERS
+        return {n: KEPT_MAKERS[n] for n in _kept_names(
             l for l in self.spec.layers_for_phase("TRAIN")
-            if l.block is not None) if n in KEPT_KERNELS}
+            if l.block is not None) if n in KEPT_MAKERS}
 
     def attention_scopes(self) -> Tuple[Dict[str, str], int]:
         """({layer type: the scope under such a layer's own that holds its

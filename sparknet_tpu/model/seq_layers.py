@@ -50,7 +50,17 @@ A layer may name a value that is dear to compute again and cheap to keep
 backward pass beside the block's inputs and computes the rest again. The
 attention core names its output and, on the kernel path, its softmax
 statistics (the row-wise log-sum-exp): all the kernel's backward needs
-besides q, k and v, so its forward kernel runs once a step.
+besides q, k and v, so its forward kernel runs once a step. The dense
+SwiGLU (`GatedMLP`) names its two input products `x W_gate` and `x W_up`
+(bf16 under the bf16 policy: 360.7 MB each a layer-step at EvaByte's
+16,384 x 11,008), so its block's backward makes no product twice; the
+gated `h` between them and the down projection is one elementwise pass
+from the two and is made again, and the expert layer's shared expert, which
+runs the same arithmetic at a fraction of the width inside a block whose
+memory the routed experts decide, names nothing. In training the layer also
+sets its input behind an `optimization_barrier`, so that the norm before it
+is a value the backward pass reads and not one its products make again
+(`apply_gatedmlp`).
 """
 from __future__ import annotations
 
@@ -92,10 +102,13 @@ GMM_TILING = (512, 512, 512)
 MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
                 "expert_tokens_min")
 #: the name (`jax.ad_checkpoint.checkpoint_name`) on the attention core's
-#: output and softmax statistics, and that on a Kimi Delta Attention layer's
-#: result
+#: output and softmax statistics, that on a Kimi Delta Attention layer's
+#: result, and that on the dense SwiGLU's two input products `x W_gate` and
+#: `x W_up` (also the named scope the two run under: how a compiled
+#: program's text tells them from the layer's third product)
 ATTN_CORE = "attn_core"
 KDA_OUT = "kda_out"
+MLP_PRE = "mlp_pre"
 
 
 def param_defaults(pname: str) -> ParamSpec:
@@ -131,10 +144,14 @@ def _rms(x, scale, eps):
     return (y * scale).astype(precision.compute_dtype())
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    g, u = _dot(x, w_gate), _dot(x, w_up)
+def _gated(g, u, w_down):
+    """silu(g) u W_down: the product in float32, `h` in g's dtype."""
     h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
     return _dot(h.astype(g.dtype), w_down)
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return _gated(_dot(x, w_gate), _dot(x, w_up), w_down)
 
 
 # -- Embed -------------------------------------------------------------------
@@ -206,7 +223,22 @@ def init_gatedmlp(key, layer: LayerSpec, in_shapes) -> Params:
 
 
 def apply_gatedmlp(layer: LayerSpec, params: Params, inputs, ctx):
-    return (_swiglu(inputs[0], params["gate"], params["up"], params["down"]),)
+    # `_swiglu` with its two input products named (KEPT_NAMES): the block
+    # keeps g and u, and makes h = silu(g) u again from them, elementwise.
+    # In training the layer's input is pinned as a value of its own: a block
+    # that makes neither product again has two readers of the norm before
+    # it left in its backward pass, the weight gradients' products, and the
+    # TPU compiler then makes the norm again INSIDE each of them, from the
+    # float32 stream, tile by tile (+10.6 ms a layer-step at EvaByte's
+    # shape, over half of what the kept products save), and fuses the
+    # norm's own backward into the product that makes its cotangent (+4.7).
+    # Behind the barrier the norm's result is written once, 134 MB, and
+    # read (PERF.md section 6, PR 41: round 3,677 -> 3,434 ms)
+    x = lax.optimization_barrier(inputs[0]) if ctx.train else inputs[0]
+    with jax.named_scope(MLP_PRE):
+        g, u = (checkpoint_name(_dot(x, params[w]), MLP_PRE)
+                for w in ("gate", "up"))
+    return (_gated(g, u, params["down"]),)
 
 
 # -- MLAttention -------------------------------------------------------------
@@ -856,15 +888,28 @@ COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
 #: and pseudo-values, 0.8 GB a layer) the layer's own checkpoints make again
 #: a row and a segment at a time, from the layer's inputs alone -- so a
 #: block that holds the result has no reason to run the layer again itself
-#: (PERF.md section 6, PR 33)
+#: (PERF.md section 6, PR 33).
+#: GatedMLP names its two input products g = x W_gate and u = x W_up, in the
+#: compute dtype (2 x 360.7 MB a layer-step at EvaByte's [1, 16384, 11008]
+#: bf16; 2 x 335.5 MB at GLM's [2, 8192, 10240]): the two of the layer's
+#: eleven products a step that a bare block made twice, 1.478 TFLOP each at
+#: EvaByte's widths. h = silu(g) u is NOT named: from g and u it is one
+#: elementwise float32 pass, which costs a few per cent of the product it
+#: feeds, where keeping it would hold a third array of that size. The expert
+#: layer's shared expert (`moe`, through `_swiglu`) names nothing: it is a
+#: fraction of this layer's width, and its block's memory and XLA's schedule
+#: of the routed products around it stay what they were (PERF.md section 6,
+#: PR 41)
 KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
               "GQAttention": (ATTN_CORE,), "EVAttention": (ATTN_CORE,),
-              "KDAttention": (KDA_OUT,)}
-#: kept name -> the name of the Pallas kernel that computes its values, as a
-#: compiled program's text has it: run again in the backward pass only if
-#: the name did not reach a recomputation block's policy (a kept value no
-#: kernel makes has no entry)
-KEPT_KERNELS = {ATTN_CORE: "splash_mha_fwd"}
+              "KDAttention": (KDA_OUT,), "GatedMLP": (MLP_PRE,)}
+#: kept name -> what marks the device ops that make its values in a compiled
+#: program's text, a part of their scope: the name of the Pallas kernel
+#: (matched as a prefix: `splash_mha_fwd_residuals`), or the named scope the
+#: layer runs its plain products under. Such an op on a recomputed path
+#: (`rematted_computation`) means the name did not reach its block's policy
+#: (a kept value whose making leaves no such mark has no entry)
+KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts

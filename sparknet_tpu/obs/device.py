@@ -700,29 +700,41 @@ def _named_bytes(jaxpr, name: str) -> int:
     return max(here, in_a_loop)
 
 
+#: what jax puts in the path of an op of a recomputation block's forward
+#: pass made again for its backward
+REMATTED = "rematted_computation"
+
+
 def recompute_report(ops: Dict[str, Dict[str, Any]],
-                     kept_kernels: Dict[str, str],
+                     kept_makers: Dict[str, str],
                      jaxpr=None) -> Dict[str, Dict[str, Any]]:
     """What the program's recomputation blocks keep, by kept name
-    (`kept_kernels`: name -> the Pallas kernel that makes its values,
-    `CompiledNet.kept_kernels()`): `{"kernel", "step_bodies": the
-    computations that hold an instance of it (a loop's body, the peeled
-    step), "forward" / "backward": its instances on a forward path and on a
-    backward one (`phase` of `scope_of`: a forward run again for the
-    backward carries `transpose(`) in the step body that has most,
-    "kept_bytes": of the named values in one step (`_named_bytes` of the
-    program's `jaxpr`; None without one)}`. The mechanism is engaged where
-    "backward" is 0. Off the chip no kernel runs and both counts are 0."""
+    (`kept_makers`: name -> what marks the ops that make its values, a part
+    of their scope matched as a prefix -- a Pallas kernel's name, or the
+    named scope a layer runs its plain products under;
+    `CompiledNet.kept_makers()`): `{"maker", "step_bodies": the
+    computations that hold such an op (a loop's body, the peeled step),
+    "forward" / "backward": the kernel calls and matrix products so marked
+    on a forward path and on a recomputed one (`phase` of `scope_of`
+    backward, under `rematted_computation`: a block's forward made again;
+    the products of the backward pass proper run under the same scope and
+    do not count) in the step body that has most, "kept_bytes": of the
+    named values in one step (`_named_bytes` of the program's `jaxpr`; None
+    without one)}`. The mechanism is engaged where "backward" is 0. Off the
+    chip no kernel runs and a kernel's counts are both 0."""
     out = {}
-    for name, kernel in kept_kernels.items():
+    for name, maker in kept_makers.items():
         count: Dict[str, Dict[str, int]] = {}   # computation -> phase -> n
         for op in ops.values():
-            if op["opcode"] == "custom-call" and any(
-                    part.startswith(kernel) for part in op["scope"].split("/")):
+            made = (op["opcode"] == "custom-call" or op["matmul"]) and any(
+                part.startswith(maker) for part in op["scope"].split("/"))
+            again = op["phase"] == "backward" and REMATTED in op["scope"]
+            if made and (again or op["phase"] == "forward"):
                 body = count.setdefault(op["computation"], {})
-                body[op["phase"]] = body.get(op["phase"], 0) + 1
+                body[op["phase"]] = (body.get(op["phase"], 0)
+                                     + op.get("matmuls", 1))
         out[name] = {
-            "kernel": kernel, "step_bodies": len(count),
+            "maker": maker, "step_bodies": len(count),
             **{phase: max((b.get(phase, 0) for b in count.values()),
                           default=0) for phase in ("forward", "backward")},
             "kept_bytes": None if jaxpr is None
@@ -879,7 +891,7 @@ def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
             "summary_bytes": moves["bytes"]}
 
 
-def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
+def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
                        routing=((), 0), delta=({}, ()),
                        eva_layers=({}, None)) -> Dict[str, Any]:
@@ -897,7 +909,7 @@ def report_of_compiled(compiled, kept_kernels: Optional[Dict[str, str]] = None,
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
                        for k in ("argument", "output", "alias", "temp")},
             "ops": ops,
-            "recompute": recompute_report(ops, kept_kernels or {}, jaxpr),
+            "recompute": recompute_report(ops, kept_makers or {}, jaxpr),
             "attention_moves": attention_moves(ops, *attention),
             "routing_moves": routing_moves(ops, *routing),
             "delta_rule": delta_rule(ops, delta[0], sum(
@@ -928,9 +940,9 @@ def attach_program_gauges(registry: MetricsRegistry,
         ).set_fn(lambda key=key: part("memory")[key])
     registry.gauge(
         f"sparknet_{name}_recompute_core_forward_in_backward",
-        f"kernels of values the {name} program's recomputation blocks are "
-        f"to keep that run again in a step's backward pass (0: every named "
-        f"value is kept; read by program_report)"
+        f"kernels and products of values the {name} program's recomputation "
+        f"blocks are to keep that run again in a step's backward pass (0: "
+        f"every named value is kept; read by program_report)"
     ).set_fn(lambda: sum(r["backward"] for r in part("recompute").values()))
     for key, what in (("bytes", "operand and result bytes"),
                       ("gathers_scatters",

@@ -892,7 +892,7 @@ class ParallelTrainer:
         if self._report is None and self._round_avals is not None:
             traced = self._round.trace(*self._round_avals)
             self._report = obs_device.report_of_compiled(
-                traced.lower().compile(), self.net.kept_kernels(),
+                traced.lower().compile(), self.net.kept_makers(),
                 traced.jaxpr.jaxpr, self.net.attention_scopes(),
                 self.net.routing_scopes(), self.net.delta_scopes(),
                 self.net.eva_scopes())

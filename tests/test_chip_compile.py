@@ -699,27 +699,48 @@ def _round_bytes(compiled) -> int:
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
 
 
+def _dense_products_made_once(text: str, kept, layers: int) -> None:
+    """The round's `layers` dense SwiGLUs (`GatedMLP`) keep their two input
+    products: two a layer on a step body's forward path, none on a
+    recomputed one, and no instruction of the compiled text, inside a fusion
+    or out, comes from a `dot_general` under a recomputed `GatedMLP` scope
+    (the down projection was never made twice; 2 a layer and step body
+    before PR 41)."""
+    import re
+    pre = kept["mlp_pre"]
+    assert pre["step_bodies"] == 2, pre  # the loop's, the peeled
+    assert (pre["forward"], pre["backward"]) == (2 * layers, 0), pre
+    assert re.search(r'op_name="[^"]*/GatedMLP/[^"]*dot_general"', text)
+    again = re.findall(
+        r'op_name="[^"]*rematted_computation/GatedMLP/[^"]*dot_general"', text)
+    assert not again, again[:2]
+
+
 @pytest.mark.slow
 def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`) for one
     described chip: ~2 min. 5.65 GB of state
-    + ~5.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
+    + ~6.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
     attention cores keep for the backward 1.01 GB, and their statistics
-    as the kernel writes them, padded to 128 lanes, 1.0 GB more; 6.9 GB
+    as the kernel writes them, padded to 128 lanes, 1.0 GB more; since PR 41
+    the dense block's two SwiGLU input products, 0.67 GB kept, which put
+    0.96 GB on the 5.1 GB the round took before; 6.9 GB
     while q, k and v were laid out again between projection and core). Each
-    step body runs the cores' forward kernel on its forward path alone, and
-    no gather or scatter in its attention touches an activation."""
+    step body runs the cores' forward kernel on its forward path alone, makes
+    no product of the dense SwiGLU twice, and no gather or scatter in its
+    attention touches an activation."""
     compiled, trainer = _sequence_round(v5e, "glm47-flash-ep8-tau4")
     total = _round_bytes(compiled)
-    assert total < 11.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    assert total < 12.0e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "8192,8192" not in text
     from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
                                          recompute_report)
     ops = parse_hlo_ops(text)
-    kept = recompute_report(ops, trainer.net.kept_kernels())
+    kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (6, 0)
+    _dense_products_made_once(text, kept, 1)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves  # 96 before PR 30
     assert moves["bytes"] < 57e9, moves  # 94.9 GB a step body before, 42.8 now
@@ -746,9 +767,10 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
                                          recompute_report)
     ops = parse_hlo_ops(text)
-    kept = recompute_report(ops, trainer.net.kept_kernels())
+    kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (2, 0)
+    _dense_products_made_once(text, kept, 1)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     _routing_walks_rows(text, ops, trainer, 8 * 2, 32768)
@@ -760,8 +782,9 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     Delta Attention layers and one latent attention with direct queries,
     six expert layers behind a 512-wide group-limited router, an untied
     head) for one described chip (~4 min): 6.58 GB of state (822,036,416
-    parameters and their momentum) + 6.22 GB of temporaries (the gradient is
-    3.29 of them; 6.23 before PR 39; 7.43 with every forward of the stage
+    parameters and their momentum) + 6.49 GB of temporaries (the gradient is
+    3.29 of them; 6.22 before PR 41, whose dense block keeps its SwiGLU's
+    two input products; 6.23 before PR 39; 7.43 with every forward of the stage
     before the rule a kernel call, which is why the shaping kernels' forward
     rule makes v in plain `jnp`: `ops/pallas_kda_shape.py`, PERF.md section
     6).
@@ -777,9 +800,10 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     from sparknet_tpu.obs.device import (attention_moves, delta_rule,
                                          parse_hlo_ops, recompute_report)
     ops = parse_hlo_ops(text)
-    kept = recompute_report(ops, trainer.net.kept_kernels())
+    kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (1, 0)
+    _dense_products_made_once(text, kept, 1)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     scopes, kept_names = trainer.net.delta_scopes()
@@ -802,9 +826,12 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     attention and SwiGLU at width 4,096, one row of 16,384 bytes a step, a
     float32 residual stream, eight heads) for one described chip: 6.57 GB
     of state (821,366,784 parameters and their momentum) and the round's
-    temporaries under 15 GB together. Every core is ONE kernel call forward
-    a layer-step over 17,408 key columns (the row's keys and 1,024 chunk
-    summaries) and one backward, on its forward path alone."""
+    temporaries under 15 GB together (14.44 since PR 41: the four blocks keep
+    their SwiGLUs' two input products, 2.89 GB a step, which put 1.29 GB on
+    the 6.58 GB of temporaries the round took before). Every core is ONE
+    kernel call forward a layer-step over 17,408 key columns (the row's keys
+    and 1,024 chunk summaries) and one backward, on its forward path alone,
+    and no SwiGLU makes a product twice."""
     compiled, trainer = _sequence_round(v5e, "evabyte-l4-tau4")
     total = _round_bytes(compiled)
     assert total < 15e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
@@ -813,8 +840,9 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
                                          recompute_report)
     ops = parse_hlo_ops(text)
-    kept = recompute_report(ops, trainer.net.kept_kernels())
+    kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (4, 0)
+    _dense_products_made_once(text, kept, 4)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves

@@ -343,7 +343,7 @@ def test_eva_core_blocks_and_scopes():
         "keys_per_query": 48, "blocks_visited": 0, "blocks": 0})
     assert sl.eva_core_blocks(EVA_P, 8) == {"keys_per_query": 8, "blocks_visited": 0,
                                             "blocks": 0}
-    assert net.kept_kernels() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE}
     assert net.attention_scopes() == ({"EVAttention": ""}, POS)
     assert net.delta_scopes() == ({}, ()) and net.routing_scopes() == ((), 0)
     assert sl.KEPT_NAMES["EVAttention"] == (sl.ATTN_CORE,)

@@ -316,9 +316,15 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # what the round's one attention block keeps: the core's output, and off
     # the chip no kernel
     report = obs_device.program_report("train_round")
-    assert report["recompute"] == {sl.ATTN_CORE: {
-        "kernel": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
+    kept = dict(report["recompute"])
+    pre = kept.pop(sl.MLP_PRE)
+    assert kept == {sl.ATTN_CORE: {
+        "maker": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": ROWS * POS * GQA_P.num_heads * GQA_P.head_dim * 4}}
+    # ... and the dense block its SwiGLU's two input products: none of them
+    # is made again for the backward pass
+    assert (pre["maker"], pre["backward"]) == (sl.MLP_PRE, 0) and pre["forward"] >= 2
+    assert pre["kept_bytes"] == 2 * ROWS * POS * TINY["intermediate_size"] * 4
     assert report["attention_moves"]["instructions"] > 0
     # ... and what its three expert layers move around their products (what
     # the counts come to is the chip compiler's: tests/test_chip_compile.py)
@@ -348,7 +354,7 @@ def test_zoo_follows_layer_types_and_names_what_a_block_keeps():
     assert not any(l.type == "MTP" for l in spec.layers)
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "l3", "head"}
     net = _net()
-    assert net.kept_kernels() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE}
     assert net.attention_scopes() == ({"GQAttention": ""}, POS)
     assert net.routing_scopes() == (sl.ROUTING_SCOPES, TINY["hidden_size"])
     assert sum(int(np.prod(s)) for lp in ref.param_shapes(LAYERS).values()

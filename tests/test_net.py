@@ -166,7 +166,7 @@ def test_a_block_whose_layers_name_nothing_is_the_bare_checkpoint(tiny_net):
     primitives, policies = _traced(blocked)
     assert policies and all(p is None for p in policies)
     assert "name" not in primitives
-    assert blocked.kept_kernels() == {}
+    assert blocked.kept_makers() == {}
 
 
 @pytest.mark.parametrize("build", ["tiny", "caffenet"])
@@ -181,7 +181,7 @@ def test_a_net_without_blocks_is_not_checkpointed_at_all(tiny_net, build):
     primitives, policies = _traced(net)
     assert policies == [] and not {"remat2", "name"} & primitives
     assert "conv_general_dilated" in primitives
-    assert net.kept_kernels() == {}
+    assert net.kept_makers() == {}
 
 
 def test_hidden_blob_extraction(tiny_net):

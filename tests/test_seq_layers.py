@@ -633,9 +633,15 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # kernel runs (both counts 0), and a step keeps the four cores' outputs
     report = obs_device.program_report("train_round")
     assert report is trainer.program_report()
-    assert report["recompute"] == {sl.ATTN_CORE: {
-        "kernel": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
+    kept = dict(report["recompute"])
+    pre = kept.pop(sl.MLP_PRE)
+    assert kept == {sl.ATTN_CORE: {
+        "maker": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": 4 * ROWS * POS * MLA_P.num_heads * MLA_P.v_head_dim * 4}}
+    # ... and the one dense block its SwiGLU's two input products, neither
+    # made again (the shared experts of the other blocks name nothing)
+    assert (pre["maker"], pre["backward"]) == (sl.MLP_PRE, 0) and pre["forward"] >= 2
+    assert pre["kept_bytes"] == 2 * ROWS * POS * TINY["intermediate_size"] * 4
     assert obs_device.program_part("recompute")["train_round"] == report["recompute"]
     with pytest.raises(ValueError, match="model_type"):
         path.write_text(json.dumps(dict(TINY, model_type="other")))
@@ -647,7 +653,7 @@ def test_a_net_without_counters_has_none_and_its_round_is_what_it_was():
     from sparknet_tpu.solver import SolverConfig
 
     net = CompiledNet.compile(zoo.lenet(batch=4))
-    assert net.counter_blobs() == {} and net.kept_kernels() == {}
+    assert net.counter_blobs() == {} and net.kept_makers() == {}
     trainer = ParallelTrainer(net, SolverConfig(), make_mesh(1), tau=2,
                               compute_health=False)
     assert trainer._health_specs() == {} and trainer.counter_values() == {}
@@ -771,10 +777,143 @@ def test_a_block_keeps_the_cores_output_and_statistics_on_the_kernel_path(
     heads = (ROWS, KERNEL_MLA_P.num_heads, KERNEL_POS)
     assert _kept(loss, params, x) == [
         (heads, "float32"), (heads + (KERNEL_MLA_P.v_head_dim,), "bfloat16")]
-    assert net.kept_kernels() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd"}
     # under the bare jax.checkpoint: the block's inputs alone
     monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
     assert _kept(loss, params, x) == []
+
+
+#: the dense feed-forward's width in the blocks below
+MLP_WIDTH = 160
+
+
+def _mlp_block():
+    """(net, its loss and gradients) of one recomputation block as a
+    decoder's dense feed-forward half is: norm, SwiGLU, residual sum. The
+    gradient is taken under the round's step scope, which
+    `obs.device.scope_of` reads a pass from."""
+    from sparknet_tpu.model.spec import GatedMLPParam
+    from sparknet_tpu.obs.device import STEP_SCOPE
+    tag = dict(block="b")
+    net = CompiledNet.compile(NetSpec(
+        name="blk", inputs=(InputSpec("x", (ROWS, POS, D)),), layers=(
+            LayerSpec(name="n", type="RMSNorm", bottoms=("x",), tops=("xn",),
+                      rmsnorm=RMSNormParam(), **tag),
+            LayerSpec(name="m", type="GatedMLP", bottoms=("xn",), tops=("y",),
+                      gated_mlp=GatedMLPParam(intermediate_size=MLP_WIDTH), **tag),
+            LayerSpec(name="r", type="Eltwise", bottoms=("x", "y"), tops=("z",),
+                      **tag))))
+
+    def loss(p, x):
+        z = net.apply(p, {"x": x}, train=True)["z"].astype(jnp.float32)
+        return jnp.sum(z * z)
+
+    def grad(p, x):
+        with jax.named_scope(STEP_SCOPE):
+            return jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    return net, loss, grad
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+def test_a_dense_block_makes_no_product_twice_and_computes_what_it_did(
+        policy, monkeypatch):
+    """The gradient of norm -> SwiGLU -> sum in one block holds the layer's
+    three products forward and their six backward; under the bare
+    `jax.checkpoint` (what the block was before `GatedMLP` named anything)
+    `x W_gate` and `x W_up` are there a second time. Loss and gradients are
+    the same bits either way."""
+    net, loss, grad = _mlp_block()
+    params = net.init_params(jax.random.PRNGKey(5))
+    x = _x(11)
+    # (a fresh function a trace: the policy is no part of jax's cache key)
+    products = lambda: str(jax.make_jaxpr(lambda p, x: grad(p, x))(
+        params, x)).count("dot_general")
+    wide = lambda: [k for k in _kept(loss, params, x) if k[0][-1] == MLP_WIDTH]
+    with precision.policy(policy):
+        assert wide() == [((ROWS, POS, MLP_WIDTH), policy)] * 2
+        assert products() == 9
+        kept = jax.jit(lambda p, x: grad(p, x))(params, x)
+        monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
+        assert wide() == []
+        assert products() == 11
+        bare = jax.jit(lambda p, x: grad(p, x))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(bare)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert float(kept[0]) > 0 and all(
+        np.any(np.asarray(g)) for g in jax.tree_util.tree_leaves(kept[1]))
+
+
+@pytest.mark.parametrize("train", [True, False])
+def test_a_dense_layer_pins_its_input_in_training_alone(train):
+    """`apply_gatedmlp` sets its input behind an `optimization_barrier` where
+    a backward pass will follow (so the norm before it is written once and
+    read, not made again inside each weight-gradient product: PERF.md
+    section 6, PR 41), and nowhere else; the result is `_swiglu`'s bits."""
+    from sparknet_tpu.model.spec import GatedMLPParam
+    layer = LayerSpec(name="m", type="GatedMLP", bottoms=("x",), tops=("y",),
+                      gated_mlp=GatedMLPParam(intermediate_size=MLP_WIDTH))
+    p, x = _params(3, "l0_mlp"), _x(4)
+    apply = lambda p, x: sl.apply_gatedmlp(layer, p, (x,), ApplyCtx(train=train))[0]
+    text = str(jax.make_jaxpr(apply)(p, x))
+    assert ("optimization_barrier" in text) == train
+    assert text.count("name=" + sl.MLP_PRE) == 2
+    assert np.array_equal(np.asarray(jax.jit(apply)(p, x)), np.asarray(
+        jax.jit(lambda p, x: sl._swiglu(x, p["gate"], p["up"], p["down"]))(p, x)))
+
+
+def test_the_report_counts_a_dense_blocks_products_made_again(monkeypatch):
+    """`recompute_report` of the compiled block: the two named products on
+    the forward path and none on a recomputed one, with the bytes a step
+    keeps; with the name struck from the block's policy, both made again
+    (the backward pass proper runs four products under the same scope, which
+    are no recomputation and do not count)."""
+    from sparknet_tpu.obs import device as obs_device
+    net, _, grad = _mlp_block()
+    makers = net.kept_makers()
+    assert makers == {sl.MLP_PRE: sl.MLP_PRE}
+    params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((ROWS, POS, D), jnp.float32)
+
+    def report():
+        # (a fresh function a trace: the policy is no part of the cache key)
+        traced = jax.jit(lambda p, x: grad(p, x)).trace(params, x)
+        ops = obs_device.parse_hlo_ops(traced.lower().compile().as_text())
+        return obs_device.recompute_report(ops, makers,
+                                           traced.jaxpr.jaxpr)[sl.MLP_PRE]
+
+    assert report() == {"maker": sl.MLP_PRE, "step_bodies": 1, "forward": 2,
+                        "backward": 0,
+                        "kept_bytes": 2 * ROWS * POS * MLP_WIDTH * 4}
+    with precision.policy("bfloat16"):
+        assert report()["kept_bytes"] == 2 * ROWS * POS * MLP_WIDTH * 2
+    monkeypatch.setattr(net_mod, "_kept_names", lambda layers: ())
+    got = report()
+    assert (got["forward"], got["backward"]) == (2, 2)
+
+
+def test_an_expert_layers_shared_expert_names_nothing():
+    """The shared expert runs `_swiglu` as the dense layer does but under no
+    name: an expert block's policy is what it was (nothing for `MoE`), and
+    a gradient through the layer names no value."""
+    assert "MoE" not in sl.KEPT_NAMES and sl.KEPT_NAMES["MTP"] == (sl.ATTN_CORE,)
+    net = _net()
+    by_block = {}
+    for l in net.spec.layers_for_phase("TRAIN"):
+        by_block.setdefault(l.block, []).append(l)
+    expert = [ls for b, ls in by_block.items() if b is not None
+              and any(l.type == "MoE" for l in ls)]
+    assert expert and all(
+        net_mod._kept_names(ls) == (sl.ATTN_CORE,) for ls in expert)
+    dense = [ls for b, ls in by_block.items() if b is not None
+             and any(l.type == "GatedMLP" for l in ls)]
+    assert [net_mod._kept_names(ls) for ls in dense] == [
+        (sl.ATTN_CORE, sl.MLP_PRE)]
+    assert MOE_P.n_shared_experts == 1
+    p, x = _params(1), _x(2)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, x: jnp.sum(sl.moe(MOE_P, p, x, CTX)[0])))(p, x)
+    assert "name=" + sl.MLP_PRE not in str(jaxpr) and " name[" not in str(jaxpr)
 
 
 RECOMPUTE_HLO = '''HloModule jit_train_round
@@ -807,7 +946,7 @@ def test_the_report_counts_a_kept_values_kernel_by_the_pass_it_runs_in():
     assert ops["%splash_mha_fwd_residuals.3"]["computation"] == "body.1"
     assert ops["%splash_mha_fwd_residuals.4"]["computation"] == "main.1"
     got = obs_device.recompute_report(ops, {sl.ATTN_CORE: "splash_mha_fwd"})
-    assert got == {sl.ATTN_CORE: {"kernel": "splash_mha_fwd", "step_bodies": 2,
+    assert got == {sl.ATTN_CORE: {"maker": "splash_mha_fwd", "step_bodies": 2,
                                   "forward": 2, "backward": 1, "kept_bytes": None}}
     assert obs_device.recompute_report(ops, {}) == {}
     # ... and the gauge beside the program's memory gauges reads it
