@@ -199,6 +199,15 @@ class CompiledNet:
         return ({l.type: DELTA_SCOPES[l.type] for l in layers},
                 _kept_names(l for l in layers if l.block is not None))
 
+    def ssd_scopes(self) -> Dict[str, str]:
+        """{layer type: the scope under such a layer's own that holds its
+        state-space scan} for the types of this net's layers in
+        `seq_layers.SSD_SCOPES`; {} for a net without any."""
+        from .seq_layers import SSD_SCOPES
+        return {l.type: SSD_SCOPES[l.type]
+                for l in self.spec.layers_for_phase("TRAIN")
+                if l.type in SSD_SCOPES}
+
     def eva_scopes(self) -> Tuple[Dict[str, Tuple[str, str]], Optional[dict]]:
         """({layer type: the scopes under such a layer's own that hold its
         chunk summaries and its core}, what one such layer's core is given:
@@ -215,11 +224,13 @@ class CompiledNet:
 
     def routing_scopes(self) -> Tuple[Tuple[str, ...], int]:
         """(the scopes under which this net's expert layers choose experts
-        and move rows to and from them, the width of the rows they move):
+        and move rows to and from them, the width of the rows they move:
+        the stream's, or the latent's where the experts work in one):
         `seq_layers.ROUTING_SCOPES` for a net with a layer of one of
         `seq_layers.COUNTER_TOPS`' types; ((), 0) for a net without any."""
         from .seq_layers import COUNTER_TOPS, ROUTING_SCOPES
-        widths = [self.blob_shapes[l.bottoms[0]][-1]
+        widths = [(l.moe and l.moe.latent_size)
+                  or self.blob_shapes[l.bottoms[0]][-1]
                   for l in self.spec.layers_for_phase("TRAIN")
                   if l.type in COUNTER_TOPS]
         return (ROUTING_SCOPES, widths[0]) if widths else ((), 0)

@@ -7,10 +7,13 @@ projected directly), GQAttention (grouped-query attention with per-head q/k
 norms) and EVAttention (EVA: an exact causal window beside learned summaries
 of the chunks before it, under one softmax; `ops.eva`) -- KDAttention
 (a linear attention: one matrix state a head, updated by the gated delta
-rule, `ops.delta_rule`), ShortConv (a gated short convolution: the operator a
-hybrid decoder sets between its attention layers), GatedMLP (SwiGLU), MoE
-(routed experts of which this chip holds a share, with or without a shared
-one, chosen among all or among the best groups), MTP (a
+rule, `ops.delta_rule`), Mamba2 (a state-space mixer: one matrix state a
+head under a scalar decay, `ops.ssd`, of whose heads this chip may hold a
+share, as GQAttention may of its own), ShortConv (a gated short convolution:
+the operator a hybrid decoder sets between its attention layers), GatedMLP
+(SwiGLU), MoE (routed experts of which this chip holds a share, SwiGLU or
+relu^2, in the stream's width or in a latent narrower than it, with or
+without a shared one, chosen among all or among the best groups), MTP (a
 multi-token-prediction module) and Eltwise (the residual sum, in float32
 where the model carries its stream so). Same three
 functions a layer type as `layers.py` (`init_`, `apply_`, `infer_`);
@@ -75,9 +78,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import precision
 from ..ops import attention as attention_ops
-from ..ops import delta_rule, eva as eva_ops, kda_shape
+from ..ops import delta_rule, eva as eva_ops, kda_shape, ssd as ssd_ops
 from .spec import (EVAttentionParam, GQAttentionParam, KDAttentionParam,
-                   LayerSpec, MLAttentionParam, MoEParam, ParamSpec)
+                   LayerSpec, MLAttentionParam, Mamba2Param, MoEParam,
+                   ParamSpec)
 
 Params = Dict[str, jnp.ndarray]
 
@@ -152,6 +156,15 @@ def _gated(g, u, w_down):
 
 def _swiglu(x, w_gate, w_up, w_down):
     return _gated(_dot(x, w_gate), _dot(x, w_up), w_down)
+
+
+def _relu2(u):
+    """relu(u)^2: the square in float32, the result in u's dtype."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)
+
+
+def _relu2_mlp(x, w_up, w_down):
+    return _dot(_relu2(_dot(x, w_up)), w_down)
 
 
 # -- Embed -------------------------------------------------------------------
@@ -422,13 +435,13 @@ def apply_mlattention(layer: LayerSpec, params: Params, inputs, ctx):
 def init_gqattention(key, layer: LayerSpec, in_shapes) -> Params:
     p, d = layer.gqa, in_shapes[0][-1]
     ks = jax.random.split(key, 4)
-    kv = p.num_kv_heads * p.head_dim
-    return {"q": _normal(ks[0], (d, p.num_heads * p.head_dim), p.std),
+    h, kv = (n * p.head_dim for n in p.held())
+    norms = {"q_norm": jnp.ones((p.head_dim,), jnp.float32),
+             "k_norm": jnp.ones((p.head_dim,), jnp.float32)} if p.qk_norm else {}
+    return {"q": _normal(ks[0], (d, h), p.std),
             "k": _normal(ks[1], (d, kv), p.std),
-            "v": _normal(ks[2], (d, kv), p.std),
-            "q_norm": jnp.ones((p.head_dim,), jnp.float32),
-            "k_norm": jnp.ones((p.head_dim,), jnp.float32),
-            "o": _normal(ks[3], (p.num_heads * p.head_dim, d), p.std)}
+            "v": _normal(ks[2], (d, kv), p.std), **norms,
+            "o": _normal(ks[3], (h, d), p.std)}
 
 
 def gqa(p: GQAttentionParam, params: Params, x, ctx):
@@ -437,14 +450,24 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
     q and k go through their per-head norm (1/sqrt(d) folded into q's scale
     vector) and the rotary turn over the whole head, v from its product into
     the core, and the output projection contracts (heads, d) of the core's
-    result as it lies."""
-    h, kv, hd, d = p.num_heads, p.num_kv_heads, p.head_dim, x.shape[-1]
+    result as it lies. Without the norms 1/sqrt(d) rides on the view of q's
+    weight; without the rotary turn q and k go from product (or norm) to
+    core. The heads are those the layer holds (`GQAttentionParam.held`): a
+    share's result is its part of the sum over all heads."""
+    (h, kv), hd, d = p.held(), p.head_dim, x.shape[-1]
     heads_first = "rnc,chd->rhnd"
-    q = _project(heads_first, x, params["q"].reshape(d, h, hd))
+    w_q = params["q"].reshape(d, h, hd)
+    q = _project(heads_first, x, w_q if p.qk_norm else w_q / np.sqrt(hd))
     k = _project(heads_first, x, params["k"].reshape(d, kv, hd))
     v = _project(heads_first, x, params["v"].reshape(d, kv, hd))
-    q = rotary(_rms(q, params["q_norm"] / np.sqrt(hd), p.eps), p.rope_theta, hd)
-    k = rotary(_rms(k, params["k_norm"], p.eps), p.rope_theta, hd)
+
+    def shaped(t, scale):
+        if p.qk_norm:
+            t = _rms(t, scale(), p.eps)
+        return rotary(t, p.rope_theta, hd) if p.rotary else t
+
+    q = shaped(q, lambda: params["q_norm"] / np.sqrt(hd))
+    k = shaped(k, lambda: params["k_norm"])
     with jax.named_scope("core"):
         o = attention_core(q, k, v, ctx)
     return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
@@ -520,6 +543,73 @@ def eva_core_blocks(p: EVAttentionParam, positions: int) -> Dict[str, int]:
 
 def apply_evattention(layer: LayerSpec, params: Params, inputs, ctx):
     return (eva(layer.eva, params, inputs[0], ctx),)
+
+
+# -- Mamba2 ------------------------------------------------------------------
+
+def init_mamba2(key, layer: LayerSpec, in_shapes) -> Params:
+    """Matrices normal(0, std); the taps and their bias uniform in
+    +-1/sqrt(taps) (a depthwise Conv1d's default), the time steps' bias,
+    `A_log` and `D` as Mamba-2 publishes them (`Mamba2Param`): with small
+    taps x, B and C all but vanish, and at zeros every head would forget
+    within two positions and the state between chunks would carry nothing."""
+    p, d = layer.mamba2, in_shapes[0][-1]
+    (h, g), n_state = p.held(), p.state_size
+    inner, conv = h * p.head_dim, h * p.head_dim + 2 * g * n_state
+    ks, bound = jax.random.split(key, 6), 1.0 / np.sqrt(p.taps)
+    step = jnp.maximum(jnp.exp(
+        jax.random.uniform(ks[3], (h,)) * (np.log(p.dt_max) - np.log(p.dt_min))
+        + np.log(p.dt_min)), p.dt_floor)
+    return {"in_proj": _normal(ks[0], (d, inner + conv + h), p.std),
+            "conv": jax.random.uniform(ks[1], (conv, p.taps), minval=-bound,
+                                       maxval=bound),
+            "conv_bias": jax.random.uniform(ks[2], (conv,), minval=-bound,
+                                            maxval=bound),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),  # softplus^-1
+            "A_log": jnp.log(jax.random.uniform(ks[4], (h,), minval=1.0,
+                                                maxval=16.0)),
+            "D": jnp.ones((h,), jnp.float32),
+            "norm": jnp.ones((inner,), jnp.float32),
+            "out_proj": _normal(ks[5], (inner, d), p.std)}
+
+
+def mamba2(p: Mamba2Param, params: Params, u, ctx):
+    """A Mamba-2 mixer over the heads and groups the layer holds
+    (`Mamba2Param.held`): one product to gate, x, B, C and time steps; the
+    taps, their bias and SiLU over x, B and C in float32 (`conv`); the scan
+    with its skip (`ssd`: `ops.ssd`, the time steps' softplus and the decay
+    float32); the gate and the norm a group in float32 (`gate_norm`: the
+    gate first); the product back. Both rows at once, nothing named for the
+    block to keep: the backward pass of a block makes the layer again once
+    and holds a chunk's squares for one layer at a time (PERF.md section 6,
+    PR 42)."""
+    (h, g), hd, n_state = p.held(), p.head_dim, p.state_size
+    (r, n, _), inner = u.shape, h * hd
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.named_scope("in_proj"):
+        zxbcdt = _dot(u, params["in_proj"])
+    z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:-h], zxbcdt[..., -h:])
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(causal_taps(f32(xbc), params["conv"])
+                          + params["conv_bias"]).astype(zxbcdt.dtype)
+    x = xbc[..., :inner].reshape(r, n, h, hd)
+    b, c = (t.reshape(r, n, g, n_state)
+            for t in jnp.split(xbc[..., inner:], 2, axis=-1))
+    with jax.named_scope("ssd"):
+        y = ssd_ops.ssd(x, jax.nn.softplus(f32(dt) + params["dt_bias"]),
+                        -jnp.exp(params["A_log"]), b, c, p.chunk_size)
+        y = y + params["D"][:, None] * f32(x)
+    with jax.named_scope("gate_norm"):
+        y = (y.reshape(r, n, inner) * jax.nn.silu(f32(z))).reshape(r, n, g, -1)
+        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + p.eps)
+        y = (y.reshape(r, n, inner) * params["norm"]).astype(zxbcdt.dtype)
+    with jax.named_scope("out_proj"):
+        return _dot(y, params["out_proj"])
+
+
+def apply_mamba2(layer: LayerSpec, params: Params, inputs, ctx):
+    return (mamba2(layer.mamba2, params, inputs[0], ctx),)
 
 
 # -- ShortConv ---------------------------------------------------------------
@@ -654,18 +744,31 @@ def moe_capacity(p: MoEParam, tokens: int, tile: int = GMM_TILING[0]) -> int:
 
 def init_moe_params(key, p: MoEParam, d: int) -> Params:
     ks = jax.random.split(key, 8)
-    held, w = p.experts_held[1], p.intermediate_size
+    held, w, ws = p.experts_held[1], p.intermediate_size, p.shared_width()
+    gated, dl = _expert_form(p) == "swiglu", p.latent_size or d
     out = {"router": _normal(ks[0], (d, p.n_routed_experts), p.std),
-           "router_bias": _normal(ks[1], (p.n_routed_experts,), p.std),
-           "experts_gate": _normal(ks[2], (held, d, w), p.std),
-           "experts_up": _normal(ks[3], (held, d, w), p.std),
-           "experts_down": _normal(ks[4], (held, w, d), p.std)}
-    if p.n_shared_experts:
-        ws = w * p.n_shared_experts
-        out.update(shared_gate=_normal(ks[5], (d, ws), p.std),
-                   shared_up=_normal(ks[6], (d, ws), p.std),
+           "router_bias": _normal(ks[1], (p.n_routed_experts,), p.std)}
+    if gated:
+        out["experts_gate"] = _normal(ks[2], (held, dl, w), p.std)
+    out.update(experts_up=_normal(ks[3], (held, dl, w), p.std),
+               experts_down=_normal(ks[4], (held, w, dl), p.std))
+    if p.latent_size:
+        k_down, k_up = jax.random.split(jax.random.fold_in(key, 8))
+        out.update(latent_down=_normal(k_down, (d, dl), p.std),
+                   latent_up=_normal(k_up, (dl, d), p.std))
+    if ws and gated:
+        out["shared_gate"] = _normal(ks[5], (d, ws), p.std)
+    if ws:
+        out.update(shared_up=_normal(ks[6], (d, ws), p.std),
                    shared_down=_normal(ks[7], (ws, d), p.std))
     return out
+
+
+def _expert_form(p: MoEParam) -> str:
+    if p.expert_form not in ("swiglu", "relu2"):
+        raise ValueError(f"expert_form {p.expert_form!r} is not built "
+                         f"(swiglu and relu2 are)")
+    return p.expert_form
 
 
 def init_moe(key, layer: LayerSpec, in_shapes) -> Params:
@@ -801,28 +904,44 @@ sum_by_token.defvjp(
 
 def moe(p: MoEParam, params: Params, x, ctx):
     """(result [rows, positions, d], counters [len(MOE_COUNTERS)] f32,
-    the experts every position chose [rows, positions, k] int32)."""
+    the experts every position chose [rows, positions, k] int32). With a
+    latent the rows that travel are the latent's: `latent_down` before the
+    dispatch, `latent_up` after the combine (the router's weights applied in
+    the latent); the router and the shared expert read x itself."""
     r, n, d = x.shape
     tokens, k = r * n, p.num_experts_per_tok
+    gated = _expert_form(p) == "swiglu"
     xf = x.reshape(tokens, d)
     with jax.named_scope("router"):
         idx, w = route(p, params, xf)
+    lf = xf
+    if p.latent_size:
+        with jax.named_scope("latent_down"):
+            lf = _dot(xf, params["latent_down"])
     with jax.named_scope("dispatch"):
         plan, sizes, kept_sizes = _plan(idx, p.experts_held,
                                         moe_capacity(p, tokens))
-        xs = rows_of_tokens(xf, plan)
+        xs = rows_of_tokens(lf, plan)
     with jax.named_scope("experts"):
-        g = _grouped_dot(xs, params["experts_gate"], kept_sizes, ctx)
-        u = _grouped_dot(xs, params["experts_up"], kept_sizes, ctx)
-        h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-        y = _grouped_dot(h.astype(g.dtype), params["experts_down"],
-                         kept_sizes, ctx)
+        if gated:
+            g = _grouped_dot(xs, params["experts_gate"], kept_sizes, ctx)
+            u = _grouped_dot(xs, params["experts_up"], kept_sizes, ctx)
+            h = (jax.nn.silu(g.astype(jnp.float32))
+                 * u.astype(jnp.float32)).astype(g.dtype)
+        else:
+            h = _relu2(_grouped_dot(xs, params["experts_up"], kept_sizes, ctx))
+        y = _grouped_dot(h, params["experts_down"], kept_sizes, ctx)
     with jax.named_scope("combine"):
         out = sum_by_token(y, w, plan)
-    if p.n_shared_experts:
+    if p.latent_size:
+        with jax.named_scope("latent_up"):
+            out = _dot(out, params["latent_up"])
+    if p.shared_width():
         with jax.named_scope("shared"):
-            out = out + _swiglu(xf, params["shared_gate"],
-                                params["shared_up"], params["shared_down"])
+            out = out + (_swiglu(xf, params["shared_gate"], params["shared_up"],
+                                 params["shared_down"]) if gated else
+                         _relu2_mlp(xf, params["shared_up"],
+                                    params["shared_down"]))
     landed = jnp.sum(sizes)
     counters = jnp.stack([landed, landed - jnp.sum(kept_sizes), jnp.max(sizes),
                           jnp.min(sizes)]).astype(jnp.float32)
@@ -914,10 +1033,13 @@ KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE}
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts
 ATTENTION_SCOPES = {"MLAttention": "", "MTP": "attention", "GQAttention": "",
-                    "EVAttention": "", "KDAttention": ""}
+                    "EVAttention": "", "KDAttention": "", "Mamba2": ""}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: delta rule: whose loops and device ops `obs.device.delta_rule` counts
 DELTA_SCOPES = {"KDAttention": "delta"}
+#: layer type -> the named scope, under the layer's own, that holds its
+#: state-space scan: whose loops and device ops `obs.device.ssm` counts
+SSD_SCOPES = {"Mamba2": "ssd"}
 #: layer type -> the named scopes, under the layer's own, of its chunk
 #: summaries and of its core: whose kernels and bytes `obs.device.eva` counts
 EVA_SCOPES = {"EVAttention": ("summaries", "core")}
@@ -934,6 +1056,7 @@ SEQ_LAYER_IMPLS = {
     "GQAttention": (init_gqattention, apply_gqattention, infer_same),
     "EVAttention": (init_evattention, apply_evattention, infer_same),
     "KDAttention": (init_kdattention, apply_kdattention, infer_same),
+    "Mamba2": (init_mamba2, apply_mamba2, infer_same),
     "ShortConv": (init_shortconv, apply_shortconv, infer_same),
     "MoE": (init_moe, apply_moe, infer_moe),
     "MTP": (init_mtp, apply_mtp, infer_mtp),

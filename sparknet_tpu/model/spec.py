@@ -11,9 +11,10 @@ SoftmaxWithLoss, Accuracy, Dropout — plus Input declarations — and the
 sequence-model set a sparse-expert decoder is built from: Embed, RMSNorm,
 MLAttention (latent attention), GQAttention (grouped-query attention),
 EVAttention (an exact causal window beside chunk summaries, one softmax),
-KDAttention (a delta-rule linear attention), ShortConv (a gated short
-convolution), GatedMLP, MoE (an expert layer that holds a share of its
-experts), MTP (a multi-token-prediction module) and Eltwise (the residual
+KDAttention (a delta-rule linear attention), Mamba2 (a state-space mixer
+that holds a share of its heads), ShortConv (a gated short convolution),
+GatedMLP, MoE (an expert layer that holds a share of its experts), MTP (a
+multi-token-prediction module) and Eltwise (the residual
 sum, as Caffe has it).
 """
 from __future__ import annotations
@@ -198,13 +199,27 @@ class KDAttentionParam:
     std: float = 0.02
 
 
+def _held(share: Optional[Tuple[int, int]], whole: int) -> Tuple[int, int]:
+    """(first, count) of a layer's share of `whole` heads or groups; all of
+    them where the layer names none."""
+    return share if share else (0, whole)
+
+
 @dataclass(frozen=True)
 class GQAttentionParam:
     """Grouped-query attention: `num_heads` query heads of `head_dim` over
     `num_kv_heads` key/value heads (query heads g*n .. g*n + n - 1 read
     key/value head g, n = num_heads / num_kv_heads), an RMS norm over each
     head's `head_dim` on q and on k (one scale vector each, shared by the
-    heads), rotary over the whole head on contiguous halves. Causal."""
+    heads; none where `qk_norm` is off), rotary over the whole head on
+    contiguous halves (no turn where `rotary` is off: a model whose other
+    layers carry position). Causal.
+
+    A layer may hold a SHARE of its heads (tensor parallelism's view from one
+    chip): `heads_held` / `kv_heads_held` (first, count) of the published
+    `num_heads` / `num_kv_heads`; it builds the held heads' columns of q, k
+    and v and their rows of o alone, and its result is its own part of the
+    sum over all heads. None: all of them."""
 
     num_heads: int = 0
     num_kv_heads: int = 0
@@ -212,6 +227,71 @@ class GQAttentionParam:
     rope_theta: float = 10000.0
     eps: float = 1e-5
     std: float = 0.02
+    rotary: bool = True
+    qk_norm: bool = True
+    heads_held: Optional[Tuple[int, int]] = None
+    kv_heads_held: Optional[Tuple[int, int]] = None
+
+    def held(self) -> Tuple[int, int]:
+        """(query heads, key/value heads) this layer builds. Every held
+        query head must read a held key/value head, as many of them each (a
+        key/value head that fewer chips than query heads' shares can divide
+        is held by several, each with a part of its query heads)."""
+        first, h = _held(self.heads_held, self.num_heads)
+        kv_first, kv = _held(self.kv_heads_held, self.num_kv_heads)
+        per = self.num_heads // self.num_kv_heads
+        if h % kv or any((first + j) // per - kv_first != j // (h // kv)
+                         for j in range(h)):
+            raise ValueError(
+                f"heads_held {self.heads_held} are not the query heads of "
+                f"kv_heads_held {self.kv_heads_held} ({per} a key/value head)")
+        return h, kv
+
+
+@dataclass(frozen=True)
+class Mamba2Param:
+    """A Mamba-2 mixer (arXiv:2405.21060; the `nemotron_h` family's form):
+    [z | xBC | dt] = u W_in; xBC through a depthwise causal convolution of
+    `taps` taps with a bias, then SiLU; x [heads, head_dim], B and C
+    [n_groups, state_size] (head h reads group h // (num_heads / n_groups));
+    time steps softplus(dt + dt_bias); the scan `ops.ssd` with A =
+    -exp(A_log) a scalar a head, plus the skip D x; the result times SiLU(z),
+    RMS-normed over each group's channels (the gate first); W_out.
+
+    The layer holds a SHARE of its heads and groups (tensor parallelism's
+    view from one chip): `heads_held` / `groups_held` (first, count) of the
+    published counts -- whole groups with all their heads, so the group norm
+    needs nothing from another chip -- builds the held columns of W_in, the
+    held taps and the held rows of W_out alone, and its result is its own
+    part of the sum over all heads. None: all of them."""
+
+    num_heads: int = 0
+    head_dim: int = 0
+    n_groups: int = 1
+    state_size: int = 0
+    taps: int = 4
+    chunk_size: int = 128
+    eps: float = 1e-5
+    std: float = 0.02
+    #: softplus(dt_bias) starts log-uniform in [dt_min, dt_max], floored at
+    #: dt_floor; A_log = log U[1, 16]; D = 1 (Mamba-2's published
+    #: initialisation)
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    heads_held: Optional[Tuple[int, int]] = None
+    groups_held: Optional[Tuple[int, int]] = None
+
+    def held(self) -> Tuple[int, int]:
+        """(heads, groups) this layer builds."""
+        first, h = _held(self.heads_held, self.num_heads)
+        g_first, g = _held(self.groups_held, self.n_groups)
+        per = self.num_heads // self.n_groups
+        if h != g * per or first != g_first * per:
+            raise ValueError(
+                f"heads_held {self.heads_held} are not the heads of "
+                f"groups_held {self.groups_held} ({per} a group)")
+        return h, g
 
 
 @dataclass(frozen=True)
@@ -262,7 +342,17 @@ class MoEParam:
     sums over the chosen experts it holds; what the absent ones would add is
     left out. `capacity_factor`: room for the slots that land here, as a
     multiple of the even share (tokens x top-k x held / routed); None = room
-    for every slot that can land here, so none is ever dropped."""
+    for every slot that can land here, so none is ever dropped.
+
+    `latent_size` (LatentMoE): the routed experts work in a latent narrower
+    than the stream -- x W_down (d -> latent) before the dispatch, W_up
+    (latent -> d) after the combine; the router and the shared expert read
+    the stream itself. `expert_form`: "swiglu", down(silu(gate x) up x), or
+    "relu2", down(relu(up x)^2): two products a slot, no gate. The shared
+    expert's width is `shared_intermediate_size` where given (else
+    `n_shared_experts` x `intermediate_size`), of which the layer builds
+    the columns `shared_columns` (first, count) where given: a share whose
+    result is its own part of the sum over all columns."""
 
     n_routed_experts: int = 0
     experts_held: Tuple[int, int] = (0, 0)  # (first, count)
@@ -282,6 +372,19 @@ class MoEParam:
     topk_group: int = 1
     capacity_factor: Optional[float] = None
     std: float = 0.02
+    latent_size: Optional[int] = None
+    expert_form: str = "swiglu"  # swiglu | relu2
+    shared_intermediate_size: Optional[int] = None
+    shared_columns: Optional[Tuple[int, int]] = None
+
+    def shared_width(self) -> int:
+        """Columns of the shared expert this layer builds (0: none)."""
+        if not self.n_shared_experts:
+            return 0
+        if self.shared_columns is not None:
+            return self.shared_columns[1]
+        return (self.shared_intermediate_size
+                or self.n_shared_experts * self.intermediate_size)
 
 
 @dataclass(frozen=True)
@@ -318,6 +421,7 @@ class LayerSpec:
     gqa: Optional[GQAttentionParam] = None
     kda: Optional[KDAttentionParam] = None
     eva: Optional[EVAttentionParam] = None
+    mamba2: Optional[Mamba2Param] = None
     shortconv: Optional[ShortConvParam] = None
     gated_mlp: Optional[GatedMLPParam] = None
     moe: Optional[MoEParam] = None
@@ -378,7 +482,7 @@ class NetSpec:
 # Layer types that carry trainable parameters.
 PARAMETRIC_LAYER_TYPES = ("Convolution", "InnerProduct", "Embed", "RMSNorm",
                           "MLAttention", "GQAttention", "KDAttention",
-                          "EVAttention", "ShortConv",
+                          "EVAttention", "Mamba2", "ShortConv",
                           "GatedMLP", "MoE", "MTP")
 
 

@@ -455,15 +455,17 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "kernel_calls", "shape_kernel_calls", "carried_bytes", "instructions",
     "bytes", "kept_bytes"}, "eva": {"layers", "core_forward_calls",
     "core_backward_calls", "keys_per_query", "blocks_visited", "blocks",
-    "summary_instructions", "summary_bytes"}}` — see
+    "summary_instructions", "summary_bytes"}, "ssm": {"layers", "loops",
+    "trips", "kernel_calls", "carried_bytes", "instructions", "bytes"}}` — see
     `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
     `attention_moves` for what a step's attention moves without
     computing, `routing_moves` for what its expert layers move around
     their products, `delta_rule` for how its delta rules were compiled and
-    `eva` for how its EVA attention layers were
-    (each {} for a net without such layers; all four call `moves_under`).
+    `eva` for how its EVA attention layers were and `ssm` for how its
+    state-space scans were
+    (each {} for a net without such layers; all five call `moves_under`).
     None when no such
     program is registered or it has not been dispatched yet.
 
@@ -834,20 +836,54 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     values they name)}`. {} for a net without such layers."""
     if not scopes:
         return {}
-    under = lambda op, parts: scopes.get(op["layer_type"]) in parts
-    here = [op for op in ops.values() if under(op, op["scope"].split("/"))]
     from ..ops.kda_shape import SCOPE
     before = [op for op in ops.values() if op["layer_type"] in scopes
               and SCOPE in op["scope"].split("/")]
+    return {**_scan_account(ops, scopes),
+            "shape_kernel_calls": sum(op.get("pallas", False) for op in before),
+            "kept_bytes": kept_bytes}
+
+
+def _scan_account(ops: Dict[str, Dict[str, Any]],
+                  scopes: Dict[str, str]) -> Dict[str, int]:
+    """What `delta_rule` and `ssm` both read of the device ops under a
+    sequential scan's scope (`scopes`: layer type -> the scope under the
+    layer's own): the `while` instructions in the whole program, their
+    trips, the Pallas calls, the most one loop carries a trip, and
+    `moves_under` of the ops that hold neither a matmul nor a kernel."""
+    under = lambda op, parts: scopes.get(op["layer_type"]) in parts
+    here = [op for op in ops.values() if under(op, op["scope"].split("/"))]
     loops = [op["loop"] for op in here if "loop" in op]
     moves = moves_under(ops, lambda op, parts: under(op, parts)
                         and not op["matmul"] and op["opcode"] != "custom-call",
                         {})
     return {"loops": len(loops), "trips": sum(l["trips"] or 1 for l in loops),
             "kernel_calls": sum(op.get("pallas", False) for op in here),
-            "shape_kernel_calls": sum(op.get("pallas", False) for op in before),
             "carried_bytes": max((l["carried_bytes"] for l in loops), default=0),
-            **moves, "kept_bytes": kept_bytes}
+            **moves}
+
+
+def ssm(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str]
+        ) -> Dict[str, int]:
+    """How a program's state-space scans were compiled: of the device ops
+    under their scopes (`scopes`: layer type -> the scope under the layer's
+    own that holds its scan; `CompiledNet.ssd_scopes()`), `{"layers": the
+    layers that hold one, "loops": the `while` instructions among them in
+    the WHOLE program (the scan over chunks forward, made again, and its
+    transpose; a round holds a step twice, as the scanned body and as the
+    peeled last step), "trips": their trip counts together (a loop whose
+    count the text does not give counts 1), "kernel_calls": the Pallas
+    kernels' `custom-call` instructions among them (0: the scan is plain
+    `jnp`, `ops.ssd`), "carried_bytes": the most one of them carries a trip
+    (the float32 state and what it walks), "instructions", "bytes": of the
+    ops that hold neither a matmul nor a kernel, in the computation that
+    moves most (a call of `moves_under`)}`. {} for a net without such
+    layers."""
+    if not scopes:
+        return {}
+    layers = {op["layer"] for op in ops.values()
+              if scopes.get(op["layer_type"]) in op["scope"].split("/")}
+    return {"layers": len(layers), **_scan_account(ops, scopes)}
 
 
 def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
@@ -894,7 +930,7 @@ def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
 def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
                        routing=((), 0), delta=({}, ()),
-                       eva_layers=({}, None)) -> Dict[str, Any]:
+                       eva_layers=({}, None), ssd=None) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
     names its net's recomputation blocks keep `recompute_report`, for
@@ -902,8 +938,9 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
     `attention_moves`, for its expert layers (`routing`: their routing
     scopes and the model's width) `routing_moves`, for its delta-rule
     layers (`delta`: their scopes and the names their blocks keep)
-    `delta_rule`, and for its EVA attention layers (`eva_layers`: their
-    scopes and what a core is given) `eva`."""
+    `delta_rule`, for its EVA attention layers (`eva_layers`: their
+    scopes and what a core is given) `eva`, and for its state-space mixers
+    (`ssd`: their scans' scopes) `ssm`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
@@ -915,12 +952,12 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
             "delta_rule": delta_rule(ops, delta[0], sum(
                 _named_bytes(jaxpr, name) for name in delta[1]
                 if jaxpr is not None)),
-            "eva": eva(ops, *eva_layers)}
+            "eva": eva(ops, *eva_layers), "ssm": ssm(ops, ssd or {})}
 
 
 #: program -> these parts of its report, once `program_report` has run
 REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves",
-                "delta_rule", "eva")
+                "delta_rule", "eva", "ssm")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
@@ -967,6 +1004,16 @@ def attach_program_gauges(registry: MetricsRegistry,
             f"{what}, of the {name} program's EVA attention layers (read "
             f"by program_report)"
         ).set_fn(lambda key=key: part("eva")[key])
+    for key, what in (("layers", "layers that hold a scan"),
+                      ("loops", "device loops of the scans, the whole program"),
+                      ("trips", "trips of those loops together"),
+                      ("bytes", "operand and result bytes of the scans' device "
+                       "ops that hold no product, a step")):
+        registry.gauge(
+            f"sparknet_{name}_ssm_{key}",
+            f"{what}, of the {name} program's state-space mixers (read by "
+            f"program_report)"
+        ).set_fn(lambda key=key: part("ssm")[key])
 
 
 def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:

@@ -1,0 +1,132 @@
+"""The Mamba-2 state-space scan (SSD, arXiv:2405.21060): a scalar decay a
+head, one matrix state a head, causal:
+
+    S_t = a_t S_{t-1} + dt_t x_t (x) B_t       a_t = exp(dt_t A), A < 0
+    y_t = S_t C_t                              S_0 = 0, S [P, N]
+
+over x [rows, n, heads, P], the time steps dt [rows, n, heads] > 0, A [heads]
+and B, C [rows, n, groups, N]; head h reads group h // (heads / groups). (The
+layer adds the skip D x_t and everything after; `model.seq_layers`.)
+`ssd_recurrent` is the recurrence a position at a time, in float32 (the
+oracle of the tests); `ssd` is the form that runs: CHUNKED, so that the work
+is matrix products and the sequential part is one step a chunk. There is one
+form, plain `jnp`, on every backend: no kernel, no option.
+
+The chunked form. Inside a chunk of Q positions that starts from the state
+S, with L_t the running sum of dt A inside the chunk (float32, <= 0 and
+falling),
+
+    y_t = sum_{s <= t} exp(L_t - L_s) (C_t . B_s) dt_s x_s  +  exp(L_t) S C_t
+    S'  = exp(L_Q) S + sum_s exp(L_Q - L_s) dt_s x_s (x) B_s
+
+Every exponent is a DIFFERENCE of running sums taken before the exp, and
+<= 0 where it is used (what lies above the diagonal is masked before the
+exp): nothing is a ratio of two exps, so no decay, however strong, leaves
+float32's range. The first sum is two batched products a chunk (the
+[Q, Q] scores C B^T a group, and their masked, decayed copy a head with x);
+the second term and the chunk's own contribution to the state are one
+product each; `lax.scan` over the chunks carries the float32 state and does
+no product at all: S' = exp(L_Q) S + (the chunk's own), emitting the state
+every chunk STARTS from, which the second term reads for all chunks at once.
+
+Float32 for the time steps, the running sums, the decays and the state; the
+precision policy's dtype for the operands of the four products (float32
+accumulation). The backward pass is autodiff: the scan keeps the one state a
+chunk it emits anyway, and the layer's recomputation block decides what of
+the rest is held (nothing: PERF.md section 6, PR 42).
+"""
+from __future__ import annotations
+
+import jax.numpy as jnp
+from jax import lax
+
+from .. import precision
+
+#: positions a chunk (one step of the sequential scan): the published
+#: `chunk_size`
+CHUNK = 128
+
+
+def _by_head(t, heads: int):
+    """B or C [..., groups, N] as every head reads it, [..., heads, N]."""
+    return jnp.repeat(t, heads // t.shape[-2], axis=-2)
+
+
+def ssd_recurrent(x, dt, a, b, c):
+    """The recurrence, a position at a time, in float32: the definition the
+    chunked form is held to. Returns (y [rows, n, heads, P], the last state
+    [rows, heads, P, N])."""
+    f32 = lambda t: jnp.moveaxis(t.astype(jnp.float32), 1, 0)
+    heads = x.shape[2]
+    x, dt, b, c = f32(x), f32(dt), f32(_by_head(b, heads)), f32(_by_head(c, heads))
+
+    def step(s, at):
+        x_t, dt_t, b_t, c_t = at
+        s = jnp.exp(dt_t * a)[..., None, None] * s \
+            + (dt_t[..., None] * x_t)[..., :, None] * b_t[..., None, :]
+        return s, jnp.einsum("rhpn,rhn->rhp", s, c_t,
+                             precision=lax.Precision.HIGHEST)
+
+    s0 = jnp.zeros(x.shape[1:] + (b.shape[-1],), jnp.float32)
+    s, y = lax.scan(step, s0, (x, dt, b, c))
+    return jnp.moveaxis(y, 0, 1), s
+
+
+def _mm(spec: str, a, b):
+    """A large product: operands in the precision policy's dtype, float32
+    out."""
+    return jnp.einsum(spec, precision.cast_in(a), precision.cast_in(b),
+                      precision=precision.matmul_precision(),
+                      preferred_element_type=jnp.float32)
+
+
+def ssd(x, dt, a, b, c, chunk: int = CHUNK):
+    """y [rows, n, heads, P] (float32) of the scan, chunked. A length that is
+    no multiple of the chunk is padded at its end with positions whose time
+    step is 0: they neither write nor decay, and their results are cut off."""
+    rows, n, heads, p = x.shape
+    groups, per = b.shape[2], heads // b.shape[2]
+    q = min(chunk, n)
+    pad = -n % q
+    nc = (n + pad) // q
+
+    def chunks(t):  # [rows, n, ..] -> [rows, nc, q, ..]
+        t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+        return t.reshape((rows, nc, q) + t.shape[2:])
+
+    x, b, c = chunks(x), chunks(b), chunks(c)
+    dt = jnp.swapaxes(chunks(dt.astype(jnp.float32)), 2, 3)   # [r, nc, h, q]
+    run = jnp.cumsum(dt * a.astype(jnp.float32)[:, None], axis=-1)  # L
+    end = run[..., -1]                                        # L_Q [r, nc, h]
+
+    # inside a chunk: scores a group, masked and decayed a head. Heads
+    # before positions: the [q, q] squares lie in the two minor axes
+    t_idx = jnp.arange(q)
+    lower = t_idx[:, None] >= t_idx[None, :]                  # [t, s]
+    diff = run[..., :, None] - run[..., None, :]              # L_t - L_s
+    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))         # [r, nc, h, t, s]
+    scores = _mm("rctgn,rcsgn->rcgts", c, b)                  # C_t . B_s
+    mix = (scores[:, :, :, None] * (decay * dt[..., None, :]).reshape(
+        (rows, nc, groups, per, q, q))).reshape(decay.shape)
+    y = _mm("rchts,rcshp->rcthp", mix, x)
+
+    # the chunk's own contribution to the state it hands on
+    to_end = jnp.moveaxis(jnp.exp(end[..., None] - run) * dt, 2, 3)
+    x_end = x.astype(jnp.float32) * to_end[..., None]         # [r, nc, s, h, P]
+    own = _mm("rcsgjp,rcsgn->rcgjpn",
+              x_end.reshape(x.shape[:3] + (groups, per, p)), b)
+    own = own.reshape((rows, nc, heads) + own.shape[-2:])     # [r, nc, h, P, N]
+
+    if nc > 1:  # (one chunk starts from the zero state and hands nothing on)
+        def step(s, at):
+            a_end, own_c = at
+            return jnp.exp(a_end)[..., None, None] * s + own_c, s
+
+        _, before = lax.scan(step, jnp.zeros_like(own[:, 0]),
+                             (jnp.moveaxis(end, 1, 0), jnp.moveaxis(own, 1, 0)))
+        before = jnp.moveaxis(before, 0, 1)                   # [r, nc, h, P, N]
+        y = y + jnp.moveaxis(jnp.exp(run), 2, 3)[..., None] * _mm(
+            "rctgn,rcgjpn->rctgjp", c,
+            before.reshape((rows, nc, groups, per) + before.shape[-2:])
+        ).reshape(y.shape)
+    return y.reshape(rows, nc * q, heads, p)[:, :n]

@@ -7,7 +7,7 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and four families of sequence models, each built from a file of its
+and five families of sequence models, each built from a file of its
 published config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
@@ -26,6 +26,14 @@ published config:
                       causal window beside chunk summaries under one softmax
                       -- norms scaled by 1 + w, a float32 residual stream,
                       eight next-byte heads with float32 logits)
+  - nemotron_h     <- huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
+                      config.json (one mixer a layer by the config's
+                      `hybrid_override_pattern`: Mamba-2 state-space mixers
+                      and grouped-query attention without a rotary turn, of
+                      whose heads this chip holds a share, and LatentMoE --
+                      relu^2 experts in a latent narrower than the stream;
+                      a multi-token-prediction module built of the same
+                      layer types)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -38,8 +46,8 @@ from .model.spec import (AccuracyParam, ConvolutionParam, DropoutParam,
                          EltwiseParam, EmbedParam, EVAttentionParam, Filler,
                          GatedMLPParam, GQAttentionParam, InnerProductParam,
                          InputSpec, KDAttentionParam, LayerSpec, LossParam,
-                         LRNParam, MLAttentionParam, MoEParam, MTPParam,
-                         NetSpec, ParamSpec, PoolingParam, RMSNormParam,
+                         LRNParam, MLAttentionParam, Mamba2Param, MoEParam,
+                         MTPParam, NetSpec, ParamSpec, PoolingParam, RMSNormParam,
                          ShortConvParam)
 
 _GAUSS = lambda std: Filler(type="gaussian", std=std)
@@ -599,7 +607,179 @@ def evabyte(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def nemotron_h(config: dict, rows: int, positions: int) -> NetSpec:
+    """A `nemotron_h` decoder (Nemotron-3-Super) as ONE CHIP'S SHARE of a
+    deployment that is tensor-parallel inside a host and expert-parallel
+    across hosts, for training on `[rows, positions]` int32 token ids (input
+    `tokens`; the targets are the ids themselves, read one and two positions
+    on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- one layer a letter of `hybrid_override_pattern` ("M": a Mamba-2
+    mixer, "*": grouped-query attention, "E": LatentMoE; any other letter is
+    refused), `mamba_num_heads` heads and `n_groups` groups HELD in each
+    Mamba-2 mixer, `num_attention_heads` / `num_key_value_heads` heads HELD
+    in each attention, `n_routed_experts` experts HELD in each expert layer,
+    `vocab_size` rows of the vocabulary HELD -- and a `share` block that says
+    what they are a share of: the five published counts under their own
+    keys, and [first, count] of each as `mamba_heads_held`,
+    `mamba_groups_held`, `attention_heads_held`, `kv_heads_held`,
+    `experts_held`, `vocab_rows`; `shared_columns` [first, count], the
+    columns held of the shared expert's published width
+    `moe_shared_expert_intermediate_size` (a width: the file keeps it whole);
+    `chips_sharing_a_layer`; optionally `capacity_factor` (MoEParam) and
+    `mtp_loss_weight` (default 0.1).
+
+    Every layer is x += Mixer(RMSNorm(x)), one mixer and no second
+    sublayer; the stream is the policy's dtype (`residual_in_fp32` true is
+    refused). The attention turns nothing by position and norms no head (the
+    Mamba-2 layers carry position). An expert layer's router and shared
+    expert read the stream; its routed experts, relu(up x)^2 down, work in
+    the `moe_latent_size` latent. A mixer's result is this chip's part of
+    the sum over all heads, columns and experts: what the absent ones would
+    add is left out. An untied head over the held vocabulary rows. The
+    multi-token-prediction module (`num_nextn_predict_layers` 1) reads the
+    last layer's output before the final norm and the next token's
+    embedding, each normed, concatenated and projected 2d -> d, then one
+    layer a letter of `mtp_hybrid_override_pattern` with weights of its
+    own, a norm, and the shared head. Loss = CE(next token) +
+    mtp_loss_weight x CE(second-next token). Every layer is a recomputation
+    block."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["layer_norm_epsilon"], 0.02
+    pattern, vocab = c["hybrid_override_pattern"], share["vocab_rows"][1]
+    mtp_pattern = c.get("mtp_hybrid_override_pattern", "") \
+        if c.get("num_nextn_predict_layers", 0) else ""
+    held = {"n_routed_experts": "experts_held",
+            "mamba_num_heads": "mamba_heads_held",
+            "n_groups": "mamba_groups_held",
+            "num_attention_heads": "attention_heads_held",
+            "num_key_value_heads": "kv_heads_held",
+            "vocab_size": "vocab_rows"}
+    wrong = {k: (c[k], share[v]) for k, v in held.items()
+             if c[k] != share[v][1]}
+    if sum(share["shared_columns"]) > c["moe_shared_expert_intermediate_size"]:
+        wrong["moe_shared_expert_intermediate_size"] = (
+            c["moe_shared_expert_intermediate_size"], share["shared_columns"])
+    if wrong:
+        raise ValueError(f"the share block and the held counts disagree "
+                         f"(key: held, share's [first, count]): {wrong}")
+    if len(pattern) != c["num_hidden_layers"] or set(pattern + mtp_pattern) - set("ME*"):
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r} / {mtp_pattern!r} does not "
+            f"name the mixer (M | E | *) of each of the "
+            f"{c['num_hidden_layers']} layers; no other letter is built")
+    if (c.get("mamba_hidden_act", "silu") != "silu"
+            or c.get("mlp_hidden_act") != "relu2" or not c.get("use_conv_bias")
+            or any(c.get(k) for k in ("use_bias", "mlp_bias", "attention_bias",
+                                      "mamba_proj_bias", "tie_word_embeddings",
+                                      "residual_in_fp32", "sliding_window"))
+            or c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1
+            or c.get("num_nextn_predict_layers", 0) > 1
+            or share["mamba_num_heads"] * c["mamba_head_dim"] != c["expand"] * d):
+        raise ValueError("built: SiLU in the mixers, relu^2 experts, biased "
+                         "taps, no other bias, an untied head, a stream in "
+                         "the policy's dtype, full attention, one routing "
+                         "group, one MTP module, expand x hidden = heads x "
+                         "head; the file asks for something else")
+    mixer = Mamba2Param(
+        num_heads=share["mamba_num_heads"], head_dim=c["mamba_head_dim"],
+        n_groups=share["n_groups"], state_size=c["ssm_state_size"],
+        taps=c["conv_kernel"], chunk_size=c["chunk_size"], eps=eps, std=std,
+        dt_min=c["time_step_min"], dt_max=c["time_step_max"],
+        dt_floor=c["time_step_floor"],
+        heads_held=tuple(share["mamba_heads_held"]),
+        groups_held=tuple(share["mamba_groups_held"]))
+    attention = GQAttentionParam(
+        num_heads=share["num_attention_heads"],
+        num_kv_heads=share["num_key_value_heads"], head_dim=c["head_dim"],
+        eps=eps, std=std, rotary=False, qk_norm=False,
+        heads_held=tuple(share["attention_heads_held"]),
+        kv_heads_held=tuple(share["kv_heads_held"]))
+    experts = MoEParam(
+        n_routed_experts=share["n_routed_experts"],
+        experts_held=tuple(share["experts_held"]),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=c["norm_topk_prob"],
+        capacity_factor=share.get("capacity_factor"), std=std,
+        latent_size=c["moe_latent_size"], expert_form="relu2",
+        shared_intermediate_size=c["moe_shared_expert_intermediate_size"],
+        shared_columns=tuple(share["shared_columns"]))
+    mixer.held(), attention.held()  # whole groups, or the builder refuses
+    #: letter -> (the layer's suffix, its type, its parameter)
+    mixers = {"M": ("mamba", "Mamba2", dict(mamba2=mixer)),
+              "*": ("attn", "GQAttention", dict(gqa=attention)),
+              "E": ("moe", "MoE", dict(moe=experts))}
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
+    head = lambda name, bottom, block, param_from=None: LayerSpec(
+        name=name, type="InnerProduct", bottoms=(bottom,), tops=(name,),
+        inner_product=InnerProductParam(num_output=vocab, bias_term=False,
+                                        axis=-1, weight_filler=_GAUSS(std)),
+        param_from=param_from, block=block)
+    loss = lambda name, logits, shift, weight, block: LayerSpec(
+        name=name, type="SoftmaxWithLoss", bottoms=(logits, "tokens"),
+        tops=(name,), block=block,
+        loss=LossParam(label_shift=shift, loss_weight=weight))
+
+    def body(letters: str, l_of, x_of) -> list:
+        """x_of(i + 1) = x_of(i) + Mixer(RMSNorm(x_of(i))), a layer a letter,
+        each a recomputation block of its own."""
+        out = []
+        for i, letter in enumerate(letters):
+            l, (suffix, kind, param) = l_of(i), mixers[letter]
+            mix = f"{l}_{suffix}"
+            tops = (mix, f"{mix}_counters", f"{mix}_chosen") \
+                if kind == "MoE" else (mix,)
+            out += [norm(f"{l}_norm", x_of(i), l),
+                    LayerSpec(name=mix, type=kind, bottoms=(f"{l}_norm",),
+                              tops=tops, block=l, **param),
+                    _sum_layer(f"{l}_res", x_of(i), mix, x_of(i + 1), l)]
+        return out
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",),
+                        embed=EmbedParam(num_embeddings=vocab, dim=d, std=std))]
+    layers += body(pattern, "l{}".format, "x{}".format)
+    last = f"x{len(pattern)}"
+    layers += [norm("final_norm", last, "head"),
+               head("lm_head", "final_norm", "head"),
+               loss("loss_next", "lm_head", 1, 1.0, "head")]
+    losses = ["loss_next"]
+    if mtp_pattern:
+        layers += [
+            LayerSpec(name="mtp_embed", type="Embed", bottoms=("tokens",),
+                      tops=("mtp_embed",), param_from="embed", block="mtp",
+                      embed=EmbedParam(num_embeddings=vocab, dim=d, shift=1)),
+            norm("mtp_hnorm", last, "mtp"),
+            norm("mtp_enorm", "mtp_embed", "mtp"),
+            LayerSpec(name="mtp_cat", type="Concat",
+                      bottoms=("mtp_hnorm", "mtp_enorm"), tops=("mtp_cat",),
+                      block="mtp"),
+            LayerSpec(name="mtp_eh_proj", type="InnerProduct",
+                      bottoms=("mtp_cat",), tops=("mtp_x0",), block="mtp",
+                      inner_product=InnerProductParam(
+                          num_output=d, bias_term=False, axis=-1,
+                          weight_filler=_GAUSS(std)))]
+        layers += body(mtp_pattern, "mtp{}".format, "mtp_x{}".format)
+        layers += [
+            norm("mtp_norm", f"mtp_x{len(mtp_pattern)}", "mtp_head"),
+            head("mtp_head", "mtp_norm", "mtp_head", param_from="lm_head"),
+            loss("loss_mtp", "mtp_head", 2,
+                 float(share.get("mtp_loss_weight", 0.1)), "mtp_head")]
+        losses.append("loss_mtp")
+    layers.append(LayerSpec(name="loss", type="Eltwise",
+                            bottoms=tuple(losses), tops=("loss",),
+                            eltwise=EltwiseParam(operation="SUM")))
+    return NetSpec(name="nemotron_h",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
 SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe,
-                   "ling3_flash": ling3_flash, "evabyte": evabyte}
+                   "ling3_flash": ling3_flash, "evabyte": evabyte,
+                   "nemotron_h": nemotron_h}

@@ -846,3 +846,42 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     _dense_products_made_once(text, kept, 4)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
+
+
+@pytest.mark.slow
+def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The state-space hybrid's round (`nemotron3-super-tp4-ep64-tau4`: five
+    Mamba-2 mixers at 32 held heads, one grouped-query attention without a
+    rotary turn at 8 held heads, five LatentMoE layers behind a 512-wide
+    router that chooses 22, the MTP module's attention and expert layer, two
+    heads) for one described chip: 5.74 GB of state (716,980,192 parameters
+    and their momentum) + 6.31 GB of temporaries: 12.05 GB, under 13 together.
+    Both attention cores run as kernels once a step body on their forward
+    path alone; every scan is plain `jnp` (no kernel call) and a device loop
+    over its 64 chunks; no gather or scatter in any mixer touches an
+    activation; the expert layers move rows of the latent's width."""
+    compiled, trainer = _sequence_round(v5e, "nemotron3-super-tp4-ep64-tau4")
+    total = _round_bytes(compiled)
+    assert total < 13e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    # (the MTP module's concatenation is [2, 8192, 8192]; no score square is)
+    assert "splash_mha" in text and "gmm" in text and "8,8192,8192" not in text
+    from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
+                                         recompute_report, ssm)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_makers())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert kept["attn_core"]["forward"] >= 2 and kept["attn_core"]["backward"] == 0
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves
+    scans = ssm(ops, trainer.net.ssd_scopes())
+    assert scans["layers"] == 5 and scans["kernel_calls"] == 0, scans
+    # two step bodies x five layers x (forward, made again, backward)
+    assert scans["loops"] >= 2 * 5 * 3 and scans["trips"] >= 64 * scans["loops"], scans
+    # a trip carries the float32 state [2, 32, 64, 128]
+    assert scans["carried_bytes"] >= 2 * 32 * 64 * 128 * 4, scans
+    scopes, width = trainer.net.routing_scopes()
+    assert width == 1024
+    from sparknet_tpu.model.seq_layers import moe_capacity
+    rows = moe_capacity(trainer.net.spec.layer_by_name("l1_moe").moe, 2 * 8192)
+    assert rows % 512 == 0 and rows >= 2 * 5632
