@@ -102,6 +102,26 @@ Params = Dict[str, jnp.ndarray]
 #: a key length that is no multiple of it takes the exact path
 ATTN_BLOCKS = (512, 1024, 512)
 GMM_TILING = (512, 512, 512)
+#: what adding one buffer row into its token's float32 row costs the chip, in
+#: rows fetched by a gather: the expert layer's weighted sum by token walks
+#: the buffer (a scatter-add of its rows) where SCATTER_ROW_COST x rows <
+#: k x tokens, and fetches every token's row k times elsewhere
+#: (`sum_walks_buffer`). Read on a v5e with the lone layer at the four
+#: sequence cells' shapes (16,384 tokens, forward + backward in a
+#: recomputation block, bf16, ms a call on the device, gathers | buffer;
+#: PERF.md section 6, PR 43): k x tokens / rows = 16 (Nemotron-3-Super, k = 22
+#: in a latent of 1,024) 52.15 | 44.70; 4 (GLM-4.7-Flash, 2,048 wide) 25.26 |
+#: 25.03 and 2 (LFM2-8B-A1B) 31.16 | 31.27, both ties; 32 (Ling-3.0-flash, k =
+#: 8, 2,560 wide) 26.51 | 25.01. So 4 keeps the buffer form to where it wins.
+#: SCATTER_COLUMNS: the scatter-add runs a slab of that many columns at a
+#: time. The TPU compiler's scatter (it sorts the indices, fetches the float32
+#: updates in that order and adds them sorted) falls off a cliff at whole rows
+#: of 2,560 floats: 4,096 rows into [16384, 2560] alone take 7.09 ms whole,
+#: 1.28 in slabs of 1,280, 0.79 in slabs of 512 (2,048 wide: 1.44 whole), and
+#: Ling's layer 36.61 | 26.01 | 25.01 (24.72 at 128); Nemotron's 1,024-wide
+#: rows lose a little to it (44.70 whole, 45.28 at 512, 46.68 at 128)
+SCATTER_ROW_COST = 4
+SCATTER_COLUMNS = 512
 #: the counters an expert layer returns beside its result, in this order
 MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
                 "expert_tokens_min")
@@ -816,8 +836,14 @@ def _grouped_dot(x, w, group_sizes, ctx):
 # the buffer's rows. Left to autodiff, the backward of a row gather is a
 # scatter-add of thousands of rows, which a TPU runs one row at a time; and a
 # pass over the step's tokens x k slots is mostly masked (one slot in four,
-# or in eight, lands here). A `plan` is the routing's index arrays: `tok`
-# [rows] the token of every buffer row, `row_slot` [rows] its slot, `row_ok`
+# or in eight, lands here). Written by hand as ONE scatter-add of the buffer's
+# rows it is nevertheless the cheaper way to sum by token where the buffer is
+# a small share of the slots (k = 22 against room for 1.4 slots a token; one
+# expert in 64 held): the k gathers fetch every token's row whether its slot
+# landed or not, k x tokens rows, and the scatter-add walks the buffer's rows
+# alone (`sum_walks_buffer`; SCATTER_ROW_COST holds the chip's readings). A
+# `plan` is the routing's index arrays: `tok` [rows] the token of every
+# buffer row, `row_slot` [rows] its slot, `row_ok`
 # [rows] whether a slot landed in it, and the other way round `slot_row`
 # [tokens, k] (clamped into the buffer) and `slot_ok` [tokens, k].
 
@@ -852,10 +878,34 @@ def _take_rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
+def sum_walks_buffer(rows: int, tokens: int, k: int) -> bool:
+    """Whether the weighted sum by token walks the buffer's `rows` rows (one
+    scatter-add) or the `tokens` x `k` slots (k gathers): the cheaper of the
+    two on the chip, from the shapes alone."""
+    return SCATTER_ROW_COST * rows < k * tokens
+
+
 def _weighted_sum_by_token(rows, w, plan):
     """Token t <- sum over its k slots of w[t, j] * rows[row of slot (t, j)]
     in float32, the slots that did not land left out (their rows may hold
-    anything): k gathers of [tokens] rows and one fused weighted add."""
+    anything). Where the buffer is short beside the slots
+    (`sum_walks_buffer`): every landed row times its slot's weight, added
+    into its token's row of a float32 zero array by scatter-add, a slab of
+    SCATTER_COLUMNS columns at a time (a token's slots that landed in several
+    held experts are several rows with one index). Elsewhere: k gathers of
+    [tokens] rows and one fused weighted add."""
+    tokens, k = w.shape
+    if sum_walks_buffer(rows.shape[0], tokens, k):
+        ok = plan["row_ok"]
+        w_row = jnp.where(ok, _take_rows(w.reshape(-1), plan["row_slot"]), 0.0)
+        landed = jnp.where(ok[:, None], rows.astype(jnp.float32),
+                           0) * w_row[:, None]
+        d = rows.shape[1]
+        return jnp.concatenate([
+            jnp.zeros((tokens, min(SCATTER_COLUMNS, d - c)), jnp.float32).at[
+                plan["tok"]].add(landed[:, c:c + SCATTER_COLUMNS],
+                                 mode="promise_in_bounds").astype(rows.dtype)
+            for c in range(0, d, SCATTER_COLUMNS)], axis=1)
     return sum(
         jnp.where(plan["slot_ok"][:, j, None],
                   _take_rows(rows, plan["slot_row"][:, j]).astype(jnp.float32),

@@ -451,7 +451,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
-    "row_gathers", "rows_gathered"}, "delta_rule": {"loops", "trips",
+    "row_gathers", "rows_gathered", "row_scatters", "rows_scattered"},
+    "delta_rule": {"loops", "trips",
     "kernel_calls", "shape_kernel_calls", "carried_bytes", "instructions",
     "bytes", "kept_bytes"}, "eva": {"layers", "core_forward_calls",
     "core_backward_calls", "keys_per_query", "blocks_visited", "blocks",
@@ -612,7 +613,8 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                 elif op_name is None and root:
                     op_name = root[0]["op_name"]
             own[i["name"]] = op_name
-            extras.setdefault(i["name"], {}).update(_moves(i, by_name, fused))
+            extras.setdefault(i["name"], {}).update(
+                _moves(i, by_name, fused, _with_nested(fused, comps)))
         for i in instructions:
             if i["opcode"] == "parameter":
                 continue
@@ -626,13 +628,26 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
     return ops
 
 
-def _moves(instruction, by_name, fused) -> Dict[str, Any]:
+def _with_nested(fused, comps) -> List[Dict[str, Any]]:
+    """`fused` and, to any depth, the instructions of the fusions among
+    them."""
+    return list(fused) + [n for f in fused if f["opcode"] == "fusion"
+                          for n in _with_nested(comps.get(
+                              (f["called"].get("calls") or [""])[0], []), comps)]
+
+
+def _moves(instruction, by_name, fused, nested) -> Dict[str, Any]:
     """What `attention_moves` reads of one device op: `"bytes"` (its
     operands' and results', as the text gives their shapes; 0 for what is no
     op or moves nothing), `"matmul"` (it is, or its fusion `fused` holds, a
     `dot` or a `convolution`) and, where it is or holds any, `"indexed"`: the
-    dimensions of every `gather`'s and `scatter`'s operands and result, and
-    `"gathered"`: the dimensions of every `gather`'s result alone."""
+    dimensions of every `gather`'s and `scatter`'s operands and result,
+    `"gathered"`: the dimensions of every `gather`'s result alone, and
+    `"scattered"`: of every `scatter`, the dimensions of (the array it adds
+    into, the updates it adds). The TPU compiler wraps a scatter in a fusion
+    of its own inside the fusion that sorts its indices and fetches its
+    updates in that order: `nested` is `fused` with what such inner fusions
+    hold, and the scatters are looked for there."""
     opcode = instruction["opcode"]
     result = instruction["shapes"]
     if opcode in _NO_BYTES or opcode in CONTAINERS or opcode.endswith("-done"):
@@ -650,7 +665,9 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
                        "carried_bytes": _nbytes(result)}
     if instruction["target"] == PALLAS_TARGET:  # and of a kernel
         out["pallas"] = True
-    inside = {f["name"]: f["shapes"] for f in fused}
+    inside = {f["name"]: f["shapes"] for f in nested}
+    dims_of = lambda o: next((dims for _, dims in inside.get(o) or by_name.get(
+        o, {}).get("shapes", [])), ())
     indexed = []
     for f in fused:
         if f["opcode"] in ("gather", "scatter"):
@@ -661,6 +678,14 @@ def _moves(instruction, by_name, fused) -> Dict[str, Any]:
         out["indexed"] = indexed
         out["gathered"] = [dims for f in fused if f["opcode"] == "gather"
                            for _, dims in f["shapes"][:1]]
+    # scatter(arrays..., indices, updates...): the first array's result
+    # beside the first of as many updates
+    scattered = [(f["shapes"][0][1],
+                  dims_of(f["operands"][len(f["operands"]) // 2 + 1]))
+                 for f in nested if f["opcode"] == "scatter"
+                 and f["shapes"] and len(f["operands"]) >= 3]
+    if scattered:
+        out["scattered"] = scattered
     return out
 
 
@@ -798,17 +823,29 @@ def routing_moves(ops: Dict[str, Dict[str, Any]], scopes: Tuple[str, ...],
     `width`: `CompiledNet.routing_scopes()`), `{"instructions", "bytes",
     "row_gathers": the `gather` instructions among them whose result's minor
     dimension is the model's `width` (rows of activations, not scalars),
-    "rows_gathered": the rows those fetch}`, in the step body that moves
-    most. {} for a net without such layers. Every data pass walks the
-    buffer's rows where "rows_gathered" counts no tokens x top-k."""
+    "rows_gathered": the rows those fetch, "row_scatters": the `scatter`
+    instructions among them that add rows into an array as wide as its
+    updates, of that width or of a slab of its columns, "rows_scattered":
+    the rows of the width those add (a slab of c columns adds c / width of a
+    row)}`, in the step body that moves most. {} for a net without such
+    layers. Every data pass walks the buffer's rows where "rows_gathered"
+    counts no tokens x top-k; a weighted sum by token walked them as a
+    scatter-add, a slab of columns at a time, where "rows_scattered" counts
+    them (`seq_layers.sum_walks_buffer`)."""
     if not scopes:
         return {}
     rows = lambda op: [math.prod(dims[:-1]) for dims in op.get("gathered", ())
                        if dims and dims[-1] == width]
-    return moves_under(
+    added = lambda op: [math.prod(updates)  # the elements a row scatter adds
+                        for into, updates in op.get("scattered", ())
+                        if into and updates and into[-1] == updates[-1] <= width]
+    out = moves_under(
         ops, lambda op, parts: any(s in parts for s in scopes),
         {"row_gathers": lambda op: len(rows(op)),
-         "rows_gathered": lambda op: sum(rows(op))})
+         "rows_gathered": lambda op: sum(rows(op)),
+         "row_scatters": lambda op: len(added(op)),
+         "rows_scattered": lambda op: sum(added(op))})
+    return {**out, "rows_scattered": out["rows_scattered"] // width}
 
 
 def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],

@@ -541,11 +541,13 @@ def _made_under(text, ops, scopes) -> set:
 
 
 def _routing_walks_rows(text, ops, trainer, slot_side_gathers: int,
-                        buffer_rows: int):
+                        buffer_rows: int, buffer_sums: int = 0):
     """A compiled round's routing: `routing_moves` of a step body gathers
     3 x the buffer's rows an expert layer and `slot_side_gathers` x tokens x
-    k in all, and no op under the routing scopes makes an array of tokens x
-    k rows of the model's width."""
+    k in all, scatter-adds `buffer_sums` x the buffer's rows an expert layer
+    (the weighted sums by token that walk the buffer: none where the k
+    gathers run), and no op under the routing scopes makes an array of tokens
+    x k rows of the model's width."""
     from sparknet_tpu.obs.device import routing_moves
     scopes, d = trainer.net.routing_scopes()
     tokens, k = 2 * 8192, 4
@@ -553,6 +555,7 @@ def _routing_walks_rows(text, ops, trainer, slot_side_gathers: int,
     layers = len(trainer.net.counter_blobs())
     assert moves["rows_gathered"] <= (slot_side_gathers * tokens * k
                                       + 3 * layers * buffer_rows), moves
+    assert moves["rows_scattered"] == buffer_sums * layers * buffer_rows, moves
     made = _made_under(text, ops, scopes)
     assert not made & {(tokens * k, d), (tokens, k, d)}, made
 
@@ -597,29 +600,50 @@ def _slot_side_pair(sl):
     return gather_rows, combine
 
 
+#: cell -> (top k, the routed width, the buffer's rows) of its expert layer
+#: at a step's 16,384 tokens
+_ROUTED = {"lfm2": (4, 2048, 32768), "glm": (4, 2048, 16384),
+           "ling": (8, 2560, 4096), "nemotron": (22, 1024, 22528)}
+
+
 @pytest.mark.parametrize("cell,form", [("lfm2", "rows"), ("glm", "rows"),
-                                       ("lfm2", "slots")])
+                                       ("lfm2", "slots"), ("ling", "rows"),
+                                       ("nemotron", "rows")])
 def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         v5e, as_tpu, monkeypatch, cell, form):
-    """The lone expert layer at both sequence cells' shapes (T = 16,384
-    tokens, k = 4, d = 2,048; a buffer of R = 32,768 rows for LFM2-8B-A1B's,
-    16,384 for GLM-4.7-Flash's), forward + backward in a recomputation block
-    as the net builds it, for a v5e, ~20 s each: it compiles (the grouped
-    products as kernels), and under `router` / `dispatch` / `combine` no op
-    makes an array of T x k rows of width d, as one [T k, d] or as [T, k, d]
-    -- the combine and the dispatch's backward fetch T rows k times and add
-    them in one pass, `dw` comes from the rows the backward fetches anyway --
-    so `routing_moves` counts 2 T k + 3 R rows gathered. The same query on
-    the form the layer had (`_slot_side_pair`) reads 3 T k + 3 R and finds
-    those arrays: the counter tells the two apart."""
+    """The lone expert layer at the four sequence cells' shapes (T = 16,384
+    tokens; k = 4, d = 2,048 and a buffer of R = 32,768 rows for
+    LFM2-8B-A1B's, 16,384 for GLM-4.7-Flash's; k = 8, d = 2,560, R = 4,096
+    for Ling-3.0-flash's; k = 22 in a latent of 1,024, R = 22,528 for
+    Nemotron-3-Super's, from the benchmark's own files), forward + backward
+    in a recomputation block as the net builds it, for a v5e, ~20 s each: it
+    compiles (the grouped products as kernels), and under `router` /
+    `dispatch` / `combine` no op makes an array of T x k rows of width d, as
+    one [T k, d] or as [T, k, d]. Where the buffer is no short one beside the
+    slots (`sum_walks_buffer`: LFM2, GLM) the combine and the dispatch's
+    backward fetch T rows k times and add them in one pass, `dw` comes from
+    the rows the backward fetches anyway, so `routing_moves` counts 2 T k +
+    3 R rows gathered and none scattered. Where it is (Ling: 1 row for 32
+    slots; Nemotron: 1 for 16) each of those passes is one scatter-add of the
+    buffer's R rows: 3 R rows gathered, and 2 R scattered, 3 R where a
+    latent's `latent_up` has the combine made again in the backward. The same
+    query on the form the layer had (`_slot_side_pair`) reads 3 T k + 3 R and
+    finds those arrays: the counter tells the three apart."""
     from sparknet_tpu.model import seq_layers as sl
     from sparknet_tpu.model.spec import MoEParam
     from sparknet_tpu.obs.device import parse_hlo_ops, routing_moves
-    tokens, k, d = 16384, 4, 2048
-    p = MoEParam(experts_held=(0, 8), num_experts_per_tok=k,
-                 capacity_factor=2.0, **_EXPERT_LAYERS[cell])
+    tokens, (k, width, want_rows) = 16384, _ROUTED[cell]
+    if cell in _EXPERT_LAYERS:
+        d, p = width, MoEParam(experts_held=(0, 8), num_experts_per_tok=k,
+                               capacity_factor=2.0, **_EXPERT_LAYERS[cell])
+    else:
+        from test_seq_layers import benchmark_expert_layers
+        (p, *_), _, d = benchmark_expert_layers({
+            "ling": "ling3-flash-ep64-tau4",
+            "nemotron": "nemotron3-super-tp4-ep64-tau4"}[cell])
+    assert (p.num_experts_per_tok, p.latent_size or d) == (k, width)
     rows, slots = sl.moe_capacity(p, tokens), tokens * k
-    assert rows == {"lfm2": 32768, "glm": 16384}[cell]
+    assert rows == want_rows
     if form == "slots":
         gather_rows, combine = _slot_side_pair(sl)
         monkeypatch.setattr(sl, "rows_of_tokens", gather_rows)
@@ -644,17 +668,26 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         precision.set_policy("float32")
     assert "gmm" in text and "tgmm" in text
     ops = parse_hlo_ops(text)
-    moves = routing_moves(ops, sl.ROUTING_SCOPES, d)
+    moves = routing_moves(ops, sl.ROUTING_SCOPES, width)
     made = _made_under(text, ops, sl.ROUTING_SCOPES)
-    by_slot = {(slots, d), (tokens, k, d)}
-    if form == "rows":
-        assert not made & by_slot, made & by_slot
-        assert f"[{tokens},{k},{d}]" not in text
-        assert moves["row_gathers"] == 3 + 2 * k, moves
-        assert moves["rows_gathered"] == 2 * slots + 3 * rows, moves
-    else:
+    by_slot = {(slots, width), (tokens, k, width)}
+    if form == "slots":
         assert made & by_slot
         assert moves["rows_gathered"] == 3 * slots + 3 * rows, moves
+    else:
+        assert not made & by_slot, made & by_slot
+        assert f"[{tokens},{k},{width}]" not in text
+        # the combine, the dispatch's backward and, under a latent, the
+        # combine made again for `latent_up`'s weight gradient
+        sums = 3 if p.latent_size else 2
+        walks = sl.sum_walks_buffer(rows, tokens, k)
+        assert walks == (cell in ("ling", "nemotron"))
+        assert moves["row_gathers"] == 3 + (0 if walks else sums * k), moves
+        assert moves["rows_gathered"] == 3 * rows + (
+            0 if walks else sums * slots), moves
+        slabs = -(-width // sl.SCATTER_COLUMNS)  # a scatter-add a slab
+        assert moves["row_scatters"] == (sums * slabs if walks else 0), moves
+        assert moves["rows_scattered"] == (sums * rows if walks else 0), moves
     assert moves["instructions"] > 0 and moves["bytes"] > 0
 
 
@@ -782,9 +815,12 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     Delta Attention layers and one latent attention with direct queries,
     six expert layers behind a 512-wide group-limited router, an untied
     head) for one described chip (~4 min): 6.58 GB of state (822,036,416
-    parameters and their momentum) + 6.49 GB of temporaries (the gradient is
-    3.29 of them; 6.22 before PR 41, whose dense block keeps its SwiGLU's
-    two input products; 6.23 before PR 39; 7.43 with every forward of the stage
+    parameters and their momentum) + 6.57 GB of temporaries: 13.14 GB (the
+    gradient is 3.29 of the temporaries; 6.49 before PR 43, whose weighted
+    sums by token add the buffer's 4,096 rows into a float32 [16384, 2560]
+    array a slab of 512 columns at a time where eight gathers fetched 16,384
+    rows each -- 6.73 with the rows added whole; 6.22 before PR 41, whose
+    dense block keeps its SwiGLU's two input products; 6.23 before PR 39; 7.43 with every forward of the stage
     before the rule a kernel call, which is why the shaping kernels' forward
     rule makes v in plain `jnp`: `ops/pallas_kda_shape.py`, PERF.md section
     6).
@@ -815,9 +851,10 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     assert rule["kernel_calls"] == 2 * 6 * 3, rule
     # and as many of the stage before the rule (`ops.pallas_kda_shape`)
     assert rule["shape_kernel_calls"] == 2 * 6 * 3, rule
-    # six expert layers fetch tokens x k rows twice a step at k = 8 (twice
-    # the helper's k of 4), and three times the buffer's 4,096 rows
-    _routing_walks_rows(text, ops, trainer, 6 * 2 * 2, 4096)
+    # six expert layers fetch three times the buffer's 4,096 rows and add
+    # them by token twice (the combine, the dispatch's backward): no gather
+    # of tokens x k rows is left (2 x 6 x 8 of 16,384 rows before PR 43)
+    _routing_walks_rows(text, ops, trainer, 0, 4096, buffer_sums=2)
 
 
 @pytest.mark.slow
@@ -855,7 +892,9 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     rotary turn at 8 held heads, five LatentMoE layers behind a 512-wide
     router that chooses 22, the MTP module's attention and expert layer, two
     heads) for one described chip: 5.74 GB of state (716,980,192 parameters
-    and their momentum) + 6.31 GB of temporaries: 12.05 GB, under 13 together.
+    and their momentum) + 6.22 GB of temporaries: 11.95 GB, under 13 together
+    (6.31 and 12.05 before PR 43, when every weighted sum by token was 22
+    gathers of 16,384 latent rows).
     Both attention cores run as kernels once a step body on their forward
     path alone; every scan is plain `jnp` (no kernel call) and a device loop
     over its 64 chunks; no gather or scatter in any mixer touches an
@@ -885,3 +924,7 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     from sparknet_tpu.model.seq_layers import moe_capacity
     rows = moe_capacity(trainer.net.spec.layer_by_name("l1_moe").moe, 2 * 8192)
     assert rows % 512 == 0 and rows >= 2 * 5632
+    # five expert layers and the MTP module's fetch three times the buffer's
+    # rows and add them by token three times (the combine, the combine made
+    # again for `latent_up`'s weight gradient, the dispatch's backward)
+    _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3)

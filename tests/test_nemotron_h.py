@@ -508,7 +508,25 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
     assert any("GQAttention/mtp0_attn)/core" in s for s in scopes)
     # the rows the routing moves are the latent's
     assert trainer.net.routing_scopes() == (sl.ROUTING_SCOPES, 32)
-    assert report["routing_moves"]["rows_gathered"] > 0
+    moves = report["routing_moves"]
+    assert moves["rows_gathered"] > 0 and moves["rows_scattered"] == 0
+    # ... by gathers here, where 64 tokens x 6 slots are fewer than one tile
+    # of the buffer; at 512 tokens with room for the even share the layer's
+    # own rule sums by token in one scatter-add over the buffer's rows
+    tight = MoEParam(**{**MOE_P.__dict__, "capacity_factor": 1.0})
+    rows = sl.moe_capacity(tight, 512)
+    assert sl.sum_walks_buffer(rows, 512, tight.num_experts_per_tok)
+
+    def lone(p, x):
+        with jax.named_scope("MoE/lone"):
+            return jnp.sum(sl.moe(tight, p, x, CTX)[0] ** 2)
+
+    text = jax.jit(jax.grad(lone, argnums=(0, 1))).lower(
+        params["l1_moe"], _x(79, (ROWS, 256, D))).compile().as_text()
+    walked = obs_device.routing_moves(obs_device.parse_hlo_ops(text),
+                                      sl.ROUTING_SCOPES, 32)
+    assert walked["row_scatters"] == 2 and walked["rows_scattered"] == 2 * rows
+    assert walked["rows_gathered"] == 2 * rows  # no [tokens]-row gather left
 
 
 @pytest.mark.parametrize("control", ["fp8", "state_dropped"])

@@ -394,20 +394,36 @@ def _tight_keep(chosen, experts_held, rows):
 _SHARES = {"none": (7, 1), "eighth": (3, 1), "quarter": (2, 2), "every": (0, 8)}
 
 
+#: (landing share, the buffer's rows or None for every slot that can land, top
+#: k, whether slots are dropped, the form `sum_walks_buffer` picks for the
+#: weighted sum) at 48 tokens: room for all and a tight buffer that drops, on
+#: either side of SCATTER_ROW_COST x rows = k x tokens
+_PAIR_CASES = [
+    *[(share, None, k, False, "gathers") for k in (2, 4)
+      for share in ("none", "eighth", "quarter", "every")],
+    ("every", 40, 2, True, "gathers"), ("every", 64, 4, True, "gathers"),
+    ("none", 8, 2, False, "buffer"), ("none", 8, 4, False, "buffer"),
+    ("eighth", 20, 2, False, "buffer"), ("eighth", 40, 4, False, "buffer"),
+    ("eighth", 48, 6, False, "buffer"),
+    ("quarter", 16, 2, True, "buffer"), ("quarter", 16, 4, True, "buffer"),
+    ("quarter", 64, 6, True, "buffer"),  # tokens with both slots landed
+    ("every", 16, 2, True, "buffer"), ("every", 40, 4, True, "buffer")]
+
+
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
-@pytest.mark.parametrize("k", [2, 4])
-@pytest.mark.parametrize("share,room", [("none", None), ("eighth", None),
-                                        ("quarter", None), ("every", None),
-                                        ("quarter", 16), ("every", 40)])
+@pytest.mark.parametrize("share,room,k,drops,form", _PAIR_CASES)
 def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
-        share, room, k, policy):
+        share, room, k, drops, form, policy):
     """`rows_of_tokens` and `sum_by_token` against the dense one-hot matrix
     D[r, t] = (row r holds a slot of token t that landed and found room):
     rows = D xf on those rows, sum = D^T (w_row * rows) in float32, and each
     one's `jax.vjp` is the other -- whatever share of the slots lands (none,
-    1 in 8, 1 in 4, every one), for 2 and 4 choices a token, with room for
-    all and with a tight buffer that drops, in both policies. Rows no slot
-    landed in hold NaN: nothing may read them."""
+    1 in 8, 1 in 4, every one), for 2, 4 and 6 choices a token, with room for
+    all and with a tight buffer that drops, in both policies, and in both
+    forms of the weighted sum: the k gathers where the buffer is long beside
+    the slots, the one scatter-add over its rows where it is short (the
+    shapes are such that the layer's own rule picks the form named). Rows no
+    slot landed in hold NaN: nothing may read them."""
     tokens, d = 48, 16
     rng = np.random.default_rng(7 + k)
     held = _SHARES[share]
@@ -416,8 +432,10 @@ def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
     rows = room or max(8, tokens * min(k, held[1]))
     plan, sizes, kept_sizes = sl._plan(idx, held, rows)
     keep = _tight_keep(idx, held, rows)
+    assert sl.sum_walks_buffer(rows, tokens, k) == (form == "buffer")
     assert int(jnp.sum(kept_sizes)) == keep.sum() <= int(jnp.sum(sizes))
-    assert room is None or keep.sum() == room < int(jnp.sum(sizes)), "it drops"
+    assert (keep.sum() == rows < int(jnp.sum(sizes))) if drops else (
+        keep.sum() == int(jnp.sum(sizes))), "it drops, or all find room"
     assert np.array_equal(np.asarray(plan["slot_ok"]), keep)
     n = int(keep.sum())
     assert np.asarray(plan["row_ok"]).tolist() == [True] * n + [False] * (rows - n)
@@ -426,6 +444,8 @@ def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
     dense = jnp.asarray(ok[:, None] & (tok[:, None] == np.arange(tokens)),
                         jnp.float32)
     assert np.array_equal(np.asarray(dense.sum(0)), keep.sum(1))  # <= k a token
+    if (share, room) in (("quarter", 64), ("every", 40), ("every", None)):
+        assert float(dense.sum(0).max()) > 1, "tokens with several landed slots"
     dtype = jnp.float32 if policy == "float32" else jnp.bfloat16
     xf = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
     w = jnp.asarray(rng.random((tokens, k)) + 0.1, jnp.float32)
@@ -460,19 +480,51 @@ def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
     _close(dw, want_dw.reshape(tokens, k), policy)
 
 
-@pytest.mark.parametrize("factor", [0.5, 1.0])
-def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(factor):
+@pytest.mark.parametrize("d", [sl.SCATTER_COLUMNS, sl.SCATTER_COLUMNS + 128,
+                               2 * sl.SCATTER_COLUMNS])
+def test_the_buffer_form_adds_a_slab_of_columns_at_a_time(d, monkeypatch):
+    """Rows wider than SCATTER_COLUMNS are added in slabs of that many
+    columns, the last one as narrow as what is left: one scatter-add a slab,
+    and to the bit what one scatter-add of whole rows gives (a column's adds
+    are the same adds in the same order)."""
+    tokens, k, rows = 48, 4, 16
+    rng = np.random.default_rng(11)
+    idx = jnp.asarray(np.stack([rng.permutation(8)[:k] for _ in range(tokens)]),
+                      jnp.int32)
+    plan, _, _ = sl._plan(idx, _SHARES["quarter"], rows)
+    assert sl.sum_walks_buffer(rows, tokens, k)
+    y = jnp.asarray(rng.standard_normal((rows, d)), jnp.bfloat16)
+    w = jnp.asarray(rng.random((tokens, k)) + 0.1, jnp.float32)
+    slabs = -(-d // sl.SCATTER_COLUMNS)
+    by_slab = jax.jit(lambda y, w: sl.sum_by_token(y, w, plan))
+    assert str(jax.make_jaxpr(by_slab)(y, w)).count("scatter-add") == slabs
+    got = by_slab(y, w)
+    monkeypatch.setattr(sl, "SCATTER_COLUMNS", d)
+    whole = jax.jit(lambda y, w: sl.sum_by_token(y, w, plan))
+    assert str(jax.make_jaxpr(whole)(y, w)).count("scatter-add") == 1
+    assert got.shape == (tokens, d) and np.array_equal(
+        np.asarray(got, np.float32), np.asarray(whole(y, w), np.float32))
+
+
+@pytest.mark.parametrize("factor,positions,form", [
+    (0.5, 1024, "buffer"), (1.0, 1024, "gathers"),
+    (0.75, 2048, "buffer"), (1.0, 2048, "gathers")])
+def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(
+        factor, positions, form):
     """The gradients of the whole layer -- the router's (through `dw`), the
     experts' (through `dy`) and the input's (through `dxf` and the router) --
     when the buffer is too small and slots are dropped: the reference's, with
     the dropped slots' weights zeroed in it. A bias sends most tokens to the
-    two held experts, so that much more lands than finds room (2,048 tokens:
-    the buffer is whole tiles of the grouped product, 512 or 1,024 rows)."""
+    two held experts, so that much more lands than finds room (2,048 or 4,096
+    tokens: the buffer is whole tiles of the grouped product, 512 to 2,048
+    rows), and the layer's rule takes the weighted sums over the buffer's
+    rows at the shorter buffers and as k gathers at the longer."""
     tight = MoEParam(**{**MOE_P.__dict__, "capacity_factor": factor})
     p = _params(7)
     p = dict(p, router_bias=jnp.zeros((8,)).at[2].set(0.4).at[3].set(0.3))
-    x = _x(62, (ROWS, 1024, D))
-    room = sl.moe_capacity(tight, ROWS * 1024)
+    x = _x(62, (ROWS, positions, D))
+    room = sl.moe_capacity(tight, ROWS * positions)
+    assert sl.sum_walks_buffer(room, ROWS * positions, 2) == (form == "buffer")
     _, counters, chosen = sl.moe(tight, p, x, CTX)
     assert float(counters[1]) == float(counters[0]) - room > 0, "it drops"
     keep = jnp.asarray(_tight_keep(chosen.reshape(-1, 2), (2, 2), room))
@@ -498,6 +550,43 @@ def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(factor)
         if name != "router_bias":
             _close(mine[0][name], want[0][name], "float32")
     _close(mine[1], want[1], "float32")
+
+
+def benchmark_expert_layers(config: str):
+    """(every expert layer's MoEParam, a step's tokens, the model's width) of
+    the benchmark's configuration `config`, built from its own file."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "benchmark", "configs", config + ".json")
+    with open(path) as f:
+        c = json.load(f)
+    spec = zoo.SEQUENCE_MODELS[c["model_type"]](
+        c, rows=c["local_batch"], positions=c["seq_len"])
+    return ([l.moe for l in spec.layers if l.type == "MoE"],
+            c["local_batch"] * c["seq_len"], c["hidden_size"])
+
+
+@pytest.mark.parametrize("config,k,rows,form", [
+    ("nemotron3-super-tp4-ep64-tau4", 22, 22528, "buffer"),
+    ("ling3-flash-ep64-tau4", 8, 4096, "buffer"),
+    ("glm47-flash-ep8-tau4", 4, 16384, "gathers"),
+    ("lfm2-8b-a1b-ep4-tau4", 4, 32768, "gathers")])
+def test_the_weighted_sums_form_follows_the_cells_shapes(config, k, rows, form):
+    """Which form the weighted sum by token takes is a function of (the
+    buffer's rows, k, tokens) alone: at the benchmark's own configurations'
+    expert layers -- built from their files, a step's 2 x 8,192 tokens, the
+    buffer `moe_capacity` gives -- the buffer's rows are 1 in 16 of the slots
+    (Nemotron-3-Super, k = 22) and 1 in 32 (Ling-3.0-flash), where one
+    scatter-add over them is the cheaper, and 1 in 4 (GLM-4.7-Flash) and 1
+    in 2 (LFM2-8B-A1B), where the k gathers stay."""
+    layers, tokens, _ = benchmark_expert_layers(config)
+    assert layers and tokens == 16384
+    for p in layers:
+        assert (p.num_experts_per_tok, sl.moe_capacity(p, tokens)) == (k, rows)
+        assert sl.sum_walks_buffer(rows, tokens, k) == (form == "buffer")
+    # one comparison of row counts, at the constant's own edge
+    c_rows = sl.SCATTER_ROW_COST
+    assert not sl.sum_walks_buffer(k * tokens // c_rows, tokens, k)
+    assert sl.sum_walks_buffer(k * tokens // c_rows - 1, tokens, k)
 
 
 # -- the whole model ---------------------------------------------------------
@@ -1072,11 +1161,35 @@ ROUTES_HLO = '''HloModule jit_train_round
   ROOT %gather.4 = f32[6]{0} gather(%w, %i), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(MoE/l1_moe))/combine/gather"}
 }
 
-%body.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8]) -> bf16[4,8] {
+%fused_fetch (u: f32[6,8], i: s32[6]) -> f32[6,8] {
+  %u = f32[6,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  ROOT %gather.5 = f32[6,8]{1,0} gather(%u, %i), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,8}, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/combine/scatter-add"}
+}
+
+%fused_scatter (z: f32[4,8], i: s32[6], u: f32[6,8]) -> f32[4,8] {
+  %z = f32[4,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %u = f32[6,8]{1,0} parameter(2)
+  ROOT %scatter.1 = f32[4,8]{1,0} scatter(%z, %i, %u), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, indices_are_sorted=true, to_apply=%add_f32, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/combine/scatter-add"}
+}
+
+%fused_sorted_add (z: f32[4,8], i: s32[6], u: f32[6,8]) -> f32[4,8] {
+  %z = f32[4,8]{1,0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %u = f32[6,8]{1,0} parameter(2)
+  %fetch.1 = f32[6,8]{1,0} fusion(%u, %i), kind=kCustom, calls=%fused_fetch
+  ROOT %inner.1 = f32[4,8]{1,0} fusion(%z, %i, %fetch.1), kind=kCustom, calls=%fused_scatter
+}
+
+%body.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8], z: f32[4,8], u: f32[6,8]) -> bf16[4,8] {
   %x = bf16[4,8]{1,0} parameter(0)
   %i = s32[6]{0} parameter(1)
   %j = s32[4]{0} parameter(2)
   %w = f32[8]{0} parameter(3)
+  %z = f32[4,8]{1,0} parameter(4)
+  %u = f32[6,8]{1,0} parameter(5)
+  %added.1 = f32[4,8]{1,0} fusion(%z, %i, %u), kind=kCustom, calls=%fused_sorted_add, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/combine/scatter-add"}
   %rows.1 = bf16[6,8]{1,0} fusion(%x, %i), kind=kLoop, calls=%fused_rows
   %weights.1 = f32[6]{0} fusion(%w, %i), kind=kLoop, calls=%fused_weights
   %copy.1 = bf16[6,8]{0,1} copy(%rows.1), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/experts/transpose"}
@@ -1084,13 +1197,15 @@ ROUTES_HLO = '''HloModule jit_train_round
   ROOT %sum.1 = bf16[4,8]{1,0} fusion(%rows.1, %j, %sort.1), kind=kLoop, calls=%fused_sum
 }
 
-ENTRY %main.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8]) -> bf16[4,8] {
+ENTRY %main.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8], z: f32[4,8], u: f32[6,8]) -> bf16[4,8] {
   %x = bf16[4,8]{1,0} parameter(0)
   %i = s32[6]{0} parameter(1)
   %j = s32[4]{0} parameter(2)
   %w = f32[8]{0} parameter(3)
+  %z = f32[4,8]{1,0} parameter(4)
+  %u = f32[6,8]{1,0} parameter(5)
   %peeled.1 = bf16[6,8]{1,0} fusion(%x, %i), kind=kLoop, calls=%fused_rows
-  ROOT %call.1 = bf16[4,8]{1,0} call(%x, %i, %j, %w), to_apply=%body.1
+  ROOT %call.1 = bf16[4,8]{1,0} call(%x, %i, %j, %w, %z, %u), to_apply=%body.1
 }
 '''
 
@@ -1098,21 +1213,33 @@ ENTRY %main.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8]) -> bf16[4,8] {
 def test_routing_moves_counts_the_rows_routing_gathers():
     """In the loop's body, under an expert layer's and the MTP module's
     routing scopes: a fusion that gathers 6 rows of width 8, one that holds
-    two gathers of 4 rows, one that gathers 6 SCALARS (no row), a sort; a
-    copy under `experts`, which is no routing. The peeled step holds one
-    gather: the body that moves most is the one reported. `attention_moves`
-    and `routing_moves` are two calls of one query."""
+    two gathers of 4 rows, one that gathers 6 SCALARS (no row), a sort, and
+    a scatter-add of 6 rows into [4, 8] as the TPU compiler writes one -- a
+    fusion that holds a fusion with the fetch of the updates in sorted order
+    and a fusion with the scatter: one scatter of 6 rows, and the compiler's
+    own fetch no row gather of routing's; a copy under `experts`, which is
+    no routing. The peeled step holds one gather: the body that moves most
+    is the one reported. `attention_moves` and `routing_moves` are two calls
+    of one query."""
     from sparknet_tpu.obs import device as obs_device
     ops = obs_device.parse_hlo_ops(ROUTES_HLO)
     assert ops["%sum.1"]["gathered"] == [(4, 8), (4, 8)]
     assert ops["%weights.1"]["gathered"] == [(6,)]
+    assert ops["%added.1"]["scattered"] == [((4, 8), (6, 8))]
+    assert "gathered" not in ops["%added.1"] and "scattered" not in ops["%sum.1"]
     got = obs_device.routing_moves(ops, sl.ROUTING_SCOPES, width=8)
-    counted = ("%rows.1", "%weights.1", "%sort.1", "%sum.1")
-    assert got == {"instructions": 4, "row_gathers": 3, "rows_gathered": 14,
+    counted = ("%rows.1", "%weights.1", "%sort.1", "%sum.1", "%added.1")
+    assert got == {"instructions": 5, "row_gathers": 3, "rows_gathered": 14,
+                   "row_scatters": 1, "rows_scattered": 6,
                    "bytes": sum(ops[n]["bytes"] for n in counted)}
-    # another width: the same ops, no rows of it
+    # another width: the same ops, no rows of it fetched; the scatter's 6
+    # rows of 8 are a slab of half its columns (3 rows' worth), and no slab
+    # of a narrower width's
     assert obs_device.routing_moves(ops, sl.ROUTING_SCOPES, 16) == {
-        **got, "row_gathers": 0, "rows_gathered": 0}
+        **got, "row_gathers": 0, "rows_gathered": 0, "rows_scattered": 3}
+    assert obs_device.routing_moves(ops, sl.ROUTING_SCOPES, 4) == {
+        **got, "row_gathers": 0, "rows_gathered": 0, "row_scatters": 0,
+        "rows_scattered": 0}
     assert obs_device.routing_moves(ops, (), 0) == {}
     # the query both counters are calls of
     under_experts = obs_device.moves_under(
