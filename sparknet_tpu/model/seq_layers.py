@@ -795,12 +795,53 @@ def init_moe(key, layer: LayerSpec, in_shapes) -> Params:
     return init_moe_params(key, layer.moe, in_shapes[0][-1])
 
 
+# No per-slot scalar of the expert layer travels by an index over tokens x k:
+# a TPU fetches or places single elements one at a time, 7-10 ns each. The
+# chosen scores by `take_along_axis` -- forward, made again for the backward
+# pass, and the transpose's scatter -- were 3.68 + 3.68 + 3.12 of the
+# router's 19.4 ms a layer-step at k = 22 of 512 columns, to move 1.4 MB
+# (16,384 tokens on a v5e; PERF.md section 6, PR 44). They sit in an array
+# the router has just written, so they are SELECTED from it: compare every
+# slot's column with the experts' columns, keep the score where they meet,
+# sum over the columns -- one fused pass (0.57 ms there) in which [tokens, k,
+# experts] never exists as an array. The scores as a payload of `top_k`'s
+# sort were read too and lost: the sort with two payloads takes 0.8 ms more.
+
+def _chosen_scores_fwd(s, idx):
+    columns = lax.iota(idx.dtype, s.shape[-1])
+    w = jnp.sum(jnp.where(idx[:, :, None] == columns, s[:, None, :], 0.0),
+                axis=-1)
+    return w, (idx, columns)
+
+
+def _chosen_scores_bwd(res, dw):
+    idx, columns = res
+    return jnp.sum(jnp.where(idx[:, :, None] == columns, dw[:, :, None], 0.0),
+                   axis=1), None
+
+
+@jax.custom_vjp
+def chosen_scores(s, idx):
+    """w[t, j] = s[t, idx[t, j]] (`s` [tokens, experts] float32, `idx`
+    [tokens, k] integers): a select over the experts' columns and a sum of
+    them, never a fetch by index and never a product with a one-hot (a score
+    that is not finite may not meet a 0). A token's k columns are distinct,
+    so every sum has one term that is not 0 and the result and its gradient
+    `ds[t, e] = sum over j of (dw[t, j] where idx[t, j] == e)` are those of
+    `take_along_axis` and of its transpose's scatter-add bit for bit."""
+    return _chosen_scores_fwd(s, idx)[0]
+
+
+chosen_scores.defvjp(_chosen_scores_fwd, _chosen_scores_bwd)
+
+
 def route(p: MoEParam, params: Params, xf):
     """(chosen experts [tokens, k] int32, their weights [tokens, k] f32):
     sigmoid scores in float32, the top k of score + bias -- among all the
     routed experts (`n_group` 1) or among those of the `topk_group` groups
     whose two best entries of score + bias sum highest -- weights the chosen
-    scores normalised and scaled (`noaux_tc`)."""
+    scores (`chosen_scores`: selected from the scores' columns, not fetched
+    by index) normalised and scaled (`noaux_tc`)."""
     s = jax.nn.sigmoid(jnp.dot(
         xf.astype(jnp.float32), params["router"].astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
@@ -813,7 +854,7 @@ def route(p: MoEParam, params: Params, xf):
         choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
             choice.shape)
     _, idx = lax.top_k(choice, p.num_experts_per_tok)
-    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = chosen_scores(s, idx)
     if p.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_topk_eps)
     return idx.astype(jnp.int32), w * p.routed_scaling_factor
@@ -941,10 +982,13 @@ def _sum_by_token_bwd(res, g):
     w_row = jnp.where(plan["row_ok"],
                       _take_rows(w.reshape(-1), plan["row_slot"]), 0.0)
     drows = (w_row[:, None] * g_tok).astype(rows.dtype)
-    # <rows[r], g[its token]>: a scalar a row, fetched by the row's slot
+    # <rows[r], g[its token]>: a scalar a row, placed at the row's slot (a
+    # slot lands in at most one row; a row nothing landed in adds 0 wherever
+    # it points): the buffer's scalars scattered, not tokens x k fetched
     dw_row = jnp.sum(rows.astype(jnp.float32) * g_tok, axis=-1)
-    dw = jnp.where(plan["slot_ok"], _take_rows(dw_row, plan["slot_row"]), 0.0)
-    return drows, dw, None
+    dw = jnp.zeros(w.size, jnp.float32).at[plan["row_slot"]].add(
+        jnp.where(plan["row_ok"], dw_row, 0.0), mode="promise_in_bounds")
+    return drows, dw.reshape(w.shape), None
 
 
 sum_by_token.defvjp(
@@ -1094,7 +1138,12 @@ SSD_SCOPES = {"Mamba2": "ssd"}
 #: summaries and of its core: whose kernels and bytes `obs.device.eva` counts
 EVA_SCOPES = {"EVAttention": ("summaries", "core")}
 #: the named scopes, under an expert layer's own (`moe`), whose device ops
-#: `obs.device.routing_moves` counts
+#: `obs.device.routing_moves` counts: the rows they gather and scatter-add
+#: (the buffer's, never tokens x k rows) and the single scalars they fetch or
+#: place by an index (a buffer row's weight and its `dw`: a few times the
+#: buffer's rows; the chosen scores and their gradient are selects over the
+#: experts' columns, `chosen_scores`, because a TPU moves single elements by
+#: index one at a time)
 ROUTING_SCOPES = ("router", "dispatch", "combine")
 
 SEQ_LAYER_IMPLS = {

@@ -379,6 +379,9 @@ _TARGET = re.compile(r'custom_call_target="([^"]*)"')
 #: `AllocateBuffer` and the like, carry others)
 PALLAS_TARGET = "tpu_custom_call"
 _INT_CONSTANT = re.compile(r"\bs32\[\]\S* constant\((\d+)\)")
+#: a `gather` whose slices are one element each, or a `scatter` whose updates
+#: have no window: single elements fetched, or placed, one an index
+_SINGLE = re.compile(r"\bslice_sizes=\{1(?:,1)*\}|\bupdate_window_dims=\{\}")
 _CALLED = re.compile(
     r"\b(calls|to_apply|select|scatter|body|condition|branch_computations|"
     r"true_computation|false_computation)=\{?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
@@ -451,7 +454,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
-    "row_gathers", "rows_gathered", "row_scatters", "rows_scattered"},
+    "row_gathers", "rows_gathered", "row_scatters", "rows_scattered",
+    "slot_scalar_moves", "slot_scalars_moved"},
     "delta_rule": {"loops", "trips",
     "kernel_calls", "shape_kernel_calls", "carried_bytes", "instructions",
     "bytes", "kept_bytes"}, "eva": {"layers", "core_forward_calls",
@@ -566,6 +570,8 @@ def parse_hlo_ops(text: str) -> Dict[str, Dict[str, Any]]:
                 "target": target.group(1) if target else None,
                 "trips": int(trips.group(1)) if trips else None,
                 "const": int(const.group(1)) if const else None,
+                "single": bool(op and op.group(1) in ("gather", "scatter")
+                               and _SINGLE.search(body)),
                 "name": "%" + iname.lstrip("%"), "root": root,
                 "opcode": op.group(1) if op else "",
                 "shapes": _shapes(body[:op.start()]) if op else [],
@@ -642,9 +648,11 @@ def _moves(instruction, by_name, fused, nested) -> Dict[str, Any]:
     op or moves nothing), `"matmul"` (it is, or its fusion `fused` holds, a
     `dot` or a `convolution`) and, where it is or holds any, `"indexed"`: the
     dimensions of every `gather`'s and `scatter`'s operands and result,
-    `"gathered"`: the dimensions of every `gather`'s result alone, and
+    `"gathered"`: the dimensions of every `gather`'s result alone,
     `"scattered"`: of every `scatter`, the dimensions of (the array it adds
-    into, the updates it adds). The TPU compiler wraps a scatter in a fusion
+    into, the updates it adds), and `"scalars"`: how many elements each
+    `gather` or `scatter` among them that moves single elements (`_SINGLE`)
+    fetches or places. The TPU compiler wraps a scatter in a fusion
     of its own inside the fusion that sorts its indices and fetches its
     updates in that order: `nested` is `fused` with what such inner fusions
     hold, and the scatters are looked for there."""
@@ -680,12 +688,22 @@ def _moves(instruction, by_name, fused, nested) -> Dict[str, Any]:
                            for _, dims in f["shapes"][:1]]
     # scatter(arrays..., indices, updates...): the first array's result
     # beside the first of as many updates
+    scatters = [f for f in nested if f["opcode"] == "scatter"
+                and f["shapes"] and len(f["operands"]) >= 3]
     scattered = [(f["shapes"][0][1],
                   dims_of(f["operands"][len(f["operands"]) // 2 + 1]))
-                 for f in nested if f["opcode"] == "scatter"
-                 and f["shapes"] and len(f["operands"]) >= 3]
+                 for f in scatters]
     if scattered:
         out["scattered"] = scattered
+    # the elements fetched or placed one an index (the compiler's own fetch
+    # of a scatter's updates in sorted order, in a fusion inside, is none of
+    # the program's)
+    scalars = [math.prod(f["shapes"][0][1]) for f in fused
+               if f["opcode"] == "gather" and f["single"] and f["shapes"]] + [
+        math.prod(updates) for f, (_, updates) in zip(scatters, scattered)
+        if f["single"]]
+    if scalars:
+        out["scalars"] = scalars
     return out
 
 
@@ -827,11 +845,19 @@ def routing_moves(ops: Dict[str, Dict[str, Any]], scopes: Tuple[str, ...],
     instructions among them that add rows into an array as wide as its
     updates, of that width or of a slab of its columns, "rows_scattered":
     the rows of the width those add (a slab of c columns adds c / width of a
-    row)}`, in the step body that moves most. {} for a net without such
-    layers. Every data pass walks the buffer's rows where "rows_gathered"
-    counts no tokens x top-k; a weighted sum by token walked them as a
-    scatter-add, a slab of columns at a time, where "rows_scattered" counts
-    them (`seq_layers.sum_walks_buffer`)."""
+    row), "slot_scalar_moves": the `gather` and `scatter` instructions among
+    them that fetch or place single elements (a weight, a score or a
+    gradient of one: a TPU moves those one at a time, 7-10 ns each),
+    "slot_scalars_moved": the elements those move}`, in the step body that
+    moves most. {} for a net without such layers. Every data pass walks the
+    buffer's rows where "rows_gathered" counts no tokens x top-k; a weighted
+    sum by token walked them as a scatter-add, a slab of columns at a time,
+    where "rows_scattered" counts them (`seq_layers.sum_walks_buffer`); and
+    no per-slot scalar travels by an index over tokens x top-k where
+    "slot_scalars_moved" counts a few times the buffer's rows -- a row's
+    weight fetched, its `dw` placed -- and no tokens x top-k: the router
+    selects its chosen scores from the experts' columns
+    (`seq_layers.chosen_scores`)."""
     if not scopes:
         return {}
     rows = lambda op: [math.prod(dims[:-1]) for dims in op.get("gathered", ())
@@ -844,7 +870,9 @@ def routing_moves(ops: Dict[str, Dict[str, Any]], scopes: Tuple[str, ...],
         {"row_gathers": lambda op: len(rows(op)),
          "rows_gathered": lambda op: sum(rows(op)),
          "row_scatters": lambda op: len(added(op)),
-         "rows_scattered": lambda op: sum(added(op))})
+         "rows_scattered": lambda op: sum(added(op)),
+         "slot_scalar_moves": lambda op: len(op.get("scalars", ())),
+         "slot_scalars_moved": lambda op: sum(op.get("scalars", ()))})
     return {**out, "rows_scattered": out["rows_scattered"] // width}
 
 

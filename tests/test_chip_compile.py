@@ -18,6 +18,7 @@ CPU branch (portable LRN, checked shard_map, unrolled τ scan), so the
 whole-program cases steer it from the test; the kernel cases call the kernels
 directly.
 """
+import math
 import os
 import re
 
@@ -540,17 +541,28 @@ def _made_under(text, ops, scopes) -> set:
     return made
 
 
+def _no_scalar_by_slot(ops, scopes, slots: int) -> None:
+    """No device op under the routing `scopes` fetches or places `slots`
+    (tokens x k) single elements with one gather or scatter."""
+    by_slot = [(name, op["scalars"]) for name, op in ops.items()
+               if slots in op.get("scalars", ())
+               and any(s in op["scope"].split("/") for s in scopes)]
+    assert not by_slot, by_slot
+
+
 def _routing_walks_rows(text, ops, trainer, slot_side_gathers: int,
-                        buffer_rows: int, buffer_sums: int = 0):
-    """A compiled round's routing: `routing_moves` of a step body gathers
+                        buffer_rows: int, buffer_sums: int = 0, k: int = 4):
+    """A compiled round's routing (its layers choose `k` experts a token):
+    `routing_moves` of a step body gathers
     3 x the buffer's rows an expert layer and `slot_side_gathers` x tokens x
     k in all, scatter-adds `buffer_sums` x the buffer's rows an expert layer
     (the weighted sums by token that walk the buffer: none where the k
-    gathers run), and no op under the routing scopes makes an array of tokens
-    x k rows of the model's width."""
+    gathers run), no op under the routing scopes makes an array of tokens
+    x k rows of the model's width, and none fetches or places tokens x k
+    single scalars (at most 4 x the buffer's rows an expert layer)."""
     from sparknet_tpu.obs.device import routing_moves
     scopes, d = trainer.net.routing_scopes()
-    tokens, k = 2 * 8192, 4
+    tokens = 2 * 8192
     moves = routing_moves(ops, scopes, d)
     layers = len(trainer.net.counter_blobs())
     assert moves["rows_gathered"] <= (slot_side_gathers * tokens * k
@@ -558,6 +570,9 @@ def _routing_walks_rows(text, ops, trainer, slot_side_gathers: int,
     assert moves["rows_scattered"] == buffer_sums * layers * buffer_rows, moves
     made = _made_under(text, ops, scopes)
     assert not made & {(tokens * k, d), (tokens, k, d)}, made
+    assert moves["slot_scalar_moves"] > 0, moves
+    assert moves["slot_scalars_moved"] <= 4 * layers * buffer_rows, moves
+    _no_scalar_by_slot(ops, scopes, tokens * k)
 
 
 def _slot_side_pair(sl):
@@ -628,7 +643,14 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
     buffer's R rows: 3 R rows gathered, and 2 R scattered, 3 R where a
     latent's `latent_up` has the combine made again in the backward. The same
     query on the form the layer had (`_slot_side_pair`) reads 3 T k + 3 R and
-    finds those arrays: the counter tells the three apart."""
+    finds those arrays: the counter tells the three apart. And no per-slot
+    SCALAR travels by an index over the T k slots (`slot_scalars_moved`: 2 R
+    where the k gathers run -- a row's weight fetched in the backward pass,
+    its `dw` placed -- and 4 R where the sums walk the buffer, which fetch
+    the rows' weights forward and for the dispatch's backward too; 4 T k + R
+    and 4 T k + 3 R before the router selected its chosen scores from the
+    experts' columns, `seq_layers.chosen_scores`), and that select's [T, k,
+    experts] is no array any op under `router` writes."""
     from sparknet_tpu.model import seq_layers as sl
     from sparknet_tpu.model.spec import MoEParam
     from sparknet_tpu.obs.device import parse_hlo_ops, routing_moves
@@ -688,6 +710,18 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         slabs = -(-width // sl.SCATTER_COLUMNS)  # a scatter-add a slab
         assert moves["row_scatters"] == (sums * slabs if walks else 0), moves
         assert moves["rows_scattered"] == (sums * rows if walks else 0), moves
+        # no per-slot scalar travels by an index over the step's slots: a
+        # row's weight is fetched (in the backward pass; forward, backward
+        # and for the dispatch's backward where the sums walk the buffer)
+        # and its `dw` placed, R single elements a move, none of T x k
+        moved = 4 if walks else 2
+        assert moves["slot_scalar_moves"] == moved, moves
+        assert moves["slot_scalars_moved"] == moved * rows, moves
+        _no_scalar_by_slot(ops, sl.ROUTING_SCOPES, slots)
+        # ... and the router's select over the experts' columns stays
+        # inside its fusions
+        assert not any(math.prod(dims) == slots * p.n_routed_experts
+                       for dims in _made_under(text, ops, ("router",)))
     assert moves["instructions"] > 0 and moves["bytes"] > 0
 
 
@@ -815,8 +849,11 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     Delta Attention layers and one latent attention with direct queries,
     six expert layers behind a 512-wide group-limited router, an untied
     head) for one described chip (~4 min): 6.58 GB of state (822,036,416
-    parameters and their momentum) + 6.57 GB of temporaries: 13.14 GB (the
-    gradient is 3.29 of the temporaries; 6.49 before PR 43, whose weighted
+    parameters and their momentum) + 6.73 GB of temporaries: 13.30 GB (the
+    gradient is 3.29 of the temporaries; 6.57 before PR 44 -- one packing
+    of the compiler's that every form of that PR's expert layer left, with
+    the lone layer's own temporaries 50 MB lower: PERF.md section 6 --;
+    6.49 before PR 43, whose weighted
     sums by token add the buffer's 4,096 rows into a float32 [16384, 2560]
     array a slab of 512 columns at a time where eight gathers fetched 16,384
     rows each -- 6.73 with the rows added whole; 6.22 before PR 41, whose
@@ -854,7 +891,7 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # six expert layers fetch three times the buffer's 4,096 rows and add
     # them by token twice (the combine, the dispatch's backward): no gather
     # of tokens x k rows is left (2 x 6 x 8 of 16,384 rows before PR 43)
-    _routing_walks_rows(text, ops, trainer, 0, 4096, buffer_sums=2)
+    _routing_walks_rows(text, ops, trainer, 0, 4096, buffer_sums=2, k=8)
 
 
 @pytest.mark.slow
@@ -927,4 +964,4 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # five expert layers and the MTP module's fetch three times the buffer's
     # rows and add them by token three times (the combine, the combine made
     # again for `latent_up`'s weight gradient, the dispatch's backward)
-    _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3)
+    _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3, k=22)

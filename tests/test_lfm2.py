@@ -330,7 +330,8 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # the counts come to is the chip compiler's: tests/test_chip_compile.py)
     moves = report["routing_moves"]
     assert set(moves) == {"instructions", "bytes", "row_gathers", "rows_gathered",
-                          "row_scatters", "rows_scattered"}
+                          "row_scatters", "rows_scattered", "slot_scalar_moves",
+                          "slot_scalars_moved"}
     assert moves["row_gathers"] > 0 and moves["rows_gathered"] % ROWS == 0
     assert moves["rows_scattered"] == 0  # k = 2 x 64 tokens: the gathers' side
     scopes = {op["scope"] for op in report["ops"].values()}

@@ -510,6 +510,12 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
     assert trainer.net.routing_scopes() == (sl.ROUTING_SCOPES, 32)
     moves = report["routing_moves"]
     assert moves["rows_gathered"] > 0 and moves["rows_scattered"] == 0
+    # ... and the single scalars it fetches or places are a buffer's rows a
+    # move (a row's weight fetched, its `dw` placed), never tokens x k
+    slots, room = ROWS * POS * MOE_P.num_experts_per_tok, sl.moe_capacity(
+        MOE_P, ROWS * POS)
+    assert moves["slot_scalar_moves"] > 0 and slots != room
+    assert moves["slot_scalars_moved"] == moves["slot_scalar_moves"] * room
     # ... by gathers here, where 64 tokens x 6 slots are fewer than one tile
     # of the buffer; at 512 tokens with room for the even share the layer's
     # own rule sums by token in one scatter-add over the buffer's rows
@@ -527,6 +533,8 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
                                       sl.ROUTING_SCOPES, 32)
     assert walked["row_scatters"] == 2 and walked["rows_scattered"] == 2 * rows
     assert walked["rows_gathered"] == 2 * rows  # no [tokens]-row gather left
+    assert (walked["slot_scalar_moves"], walked["slot_scalars_moved"]) == (
+        2, 2 * rows)
 
 
 @pytest.mark.parametrize("control", ["fp8", "state_dropped"])

@@ -589,6 +589,121 @@ def test_the_weighted_sums_form_follows_the_cells_shapes(config, k, rows, form):
     assert sl.sum_walks_buffer(k * tokens // c_rows - 1, tokens, k)
 
 
+# -- no per-slot scalar travels by index -------------------------------------
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.int32)
+
+
+@pytest.mark.parametrize("config,k,experts,n_group,topk_group", [
+    ("nemotron3-super-tp4-ep64-tau4", 22, 512, 1, 1),
+    ("ling3-flash-ep64-tau4", 8, 512, 8, 4),
+    ("glm47-flash-ep8-tau4", 4, 64, 1, 1),
+    ("lfm2-8b-a1b-ep4-tau4", 4, 32, 1, 1)])
+def test_route_selects_the_scores_the_gather_fetched_to_the_bit(
+        config, k, experts, n_group, topk_group, monkeypatch):
+    """`route()` at the four configurations' own routers (their files' k,
+    columns, groups, normalisation and scaling; 300 tokens of width 48): the
+    chosen experts, their weights and the gradients of a weighted sum of the
+    weights with respect to the tokens and to the router equal, BIT FOR BIT,
+    those of the form the layer had -- `take_along_axis` over the scores,
+    whose transpose is a scatter-add -- with exact ties among the scores (two
+    pairs of columns with one weight vector and one bias: `top_k`'s order on
+    ties is the same order) and a column whose score overflows to exactly 1.
+    Op by op: a token's k columns are distinct, so every select-and-sum has
+    one term that is not 0. Compiled as one program XLA folds the
+    normaliser's sum over k into the select's sum over the columns, which
+    may add the k scores in another order: the same experts, the weights and
+    gradients to a few units in the last place."""
+    p = benchmark_expert_layers(config)[0][0]
+    assert (p.num_experts_per_tok, p.n_routed_experts, p.n_group,
+            p.topk_group) == (k, experts, n_group, topk_group)
+    tokens, d = 300, 48
+    rng = np.random.default_rng(experts + k)
+    router = rng.standard_normal((d, experts)).astype(np.float32)
+    bias = (0.1 * rng.standard_normal(experts)).astype(np.float32)
+    for a, b in ((1, 5), (experts - 2, 7)):  # exact ties, within and across groups
+        router[:, a], bias[a] = router[:, b], bias[b]
+    params = {"router": jnp.asarray(router), "router_bias": jnp.asarray(bias)}
+    xf = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    xf = xf.at[0].set(40.0 * jnp.sign(params["router"][:, 3]))  # sigmoid -> 1.0
+    c = jnp.asarray(rng.standard_normal((tokens, k)), jnp.float32)
+
+    def run():  # a function of its own a form: jax caches traces by function
+        def weighed(params, xf):
+            idx, w = sl.route(p, params, xf)
+            return jnp.sum(w * c), (idx, w)
+
+        both = jax.value_and_grad(weighed, argnums=(0, 1), has_aux=True)
+        return (both(params, xf), jax.jit(both)(params, xf),
+                str(jax.make_jaxpr(both)(params, xf)))
+
+    got, got_jit, text = run()
+    assert "gather" not in text and "scatter" not in text
+    monkeypatch.setattr(sl, "chosen_scores",
+                        lambda s, idx: jnp.take_along_axis(s, idx, axis=-1))
+    want, want_jit, text = run()
+    assert "gather" in text and "scatter" in text
+    (_, (idx, w)), (dparams, dxf) = got
+    assert idx.dtype == jnp.int32 and w.dtype == jnp.float32
+    s = np.asarray(jax.nn.sigmoid(jnp.dot(xf, params["router"],
+                                          precision="highest")))
+    assert s[0, 3] == 1.0 and np.any(np.sort(s + bias, axis=1)[:, 1:]
+                                     == np.sort(s + bias, axis=1)[:, :-1])
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(_bits(a), _bits(b))
+    assert not np.any(np.asarray(dparams["router_bias"]))
+    assert np.any(np.asarray(dparams["router"])) and np.any(np.asarray(dxf))
+    for a, b in zip(jax.tree.leaves(got_jit), jax.tree.leaves(want_jit)):
+        if a.dtype == jnp.int32:
+            assert np.array_equal(a, b) and np.array_equal(a, idx)
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * float(
+                jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("share,room,k,what", [
+    ("none", 8, 2, "nothing lands: every row is empty"),
+    ("eighth", 40, 4, "rows nothing landed in, none dropped"),
+    ("quarter", 16, 4, "slots dropped, the buffer full"),
+    ("every", 40, 2, "slots dropped, several landed slots a token")])
+def test_dw_is_scattered_from_the_rows_as_the_slots_fetched_it(
+        share, room, k, what):
+    """`sum_by_token`'s gradient with respect to the weights: one scalar a
+    buffer row, <rows[r], g[its token]>, placed at the row's slot by ONE
+    scatter-add of the buffer's scalars -- bit for bit what the slot side
+    fetched (`where(slot_ok, dw_row[slot_row], 0)`, tokens x k fetches): a
+    slot lands in at most one row, a dropped slot in none, and a row nothing
+    landed in (NaN in it) adds 0 wherever its `row_slot` points -- at a slot
+    that landed nowhere here, at a dropped one, or at slot 0 where the buffer
+    is longer than the slots."""
+    tokens, d = 48, 16
+    rng = np.random.default_rng(3 + k)
+    held = _SHARES[share]
+    idx = jnp.asarray(np.stack([rng.permutation(7 if share == "none" else 8)[:k]
+                                for _ in range(tokens)]), jnp.int32)
+    plan, sizes, kept_sizes = sl._plan(idx, held, room)
+    ok = np.asarray(plan["row_ok"])
+    landed, kept = int(jnp.sum(sizes)), int(jnp.sum(kept_sizes))
+    assert (kept < landed) == what.startswith("slots dropped")
+    assert kept == ok.sum()
+    y = jnp.where(ok[:, None], jnp.asarray(rng.standard_normal((room, d)),
+                                           jnp.bfloat16), jnp.nan)
+    w = jnp.asarray(rng.random((tokens, k)) + 0.1, jnp.float32)
+    g = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
+    grad = lambda y, w: jax.vjp(lambda y, w: sl.sum_by_token(y, w, plan),
+                                y, w)[1](g)
+    assert str(jax.make_jaxpr(grad)(y, w)).count("scatter-add") >= 1
+    for dy, dw in (grad(y, w), jax.jit(grad)(y, w)):
+        dw_row = jnp.sum(y.astype(jnp.float32) * sl.rows_of_tokens(g, plan).astype(
+            jnp.float32), axis=-1)
+        by_slot = jnp.where(plan["slot_ok"], jnp.take(dw_row, plan["slot_row"]), 0.0)
+        assert dw.shape == (tokens, k) and dw.dtype == jnp.float32
+        assert np.array_equal(_bits(dw), _bits(by_slot))
+        assert np.count_nonzero(np.asarray(dw)) == kept
+        assert not np.any(np.asarray(dy, np.float32)[~ok])
+
+
 # -- the whole model ---------------------------------------------------------
 
 def _net():
@@ -1182,6 +1297,27 @@ ROUTES_HLO = '''HloModule jit_train_round
   ROOT %inner.1 = f32[4,8]{1,0} fusion(%z, %i, %fetch.1), kind=kCustom, calls=%fused_scatter
 }
 
+%fused_fetch_scalars (v: f32[6], i: s32[6]) -> f32[6] {
+  %v = f32[6]{0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  ROOT %gather.6 = f32[6]{0} gather(%v, %i), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(MoE/l1_moe))/combine/scatter-add"}
+}
+
+%fused_place (q: f32[8], i: s32[6], v: f32[6]) -> f32[8] {
+  %q = f32[8]{0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %v = f32[6]{0} parameter(2)
+  ROOT %scatter.2 = f32[8]{0} scatter(%q, %i, %v), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, indices_are_sorted=true, to_apply=%add_f32
+}
+
+%fused_sorted_place (q: f32[8], i: s32[6], v: f32[6]) -> f32[8] {
+  %q = f32[8]{0} parameter(0)
+  %i = s32[6]{0} parameter(1)
+  %v = f32[6]{0} parameter(2)
+  %fetch.2 = f32[6]{0} fusion(%v, %i), kind=kCustom, calls=%fused_fetch_scalars
+  ROOT %inner.2 = f32[8]{0} fusion(%q, %i, %fetch.2), kind=kCustom, calls=%fused_place
+}
+
 %body.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8], z: f32[4,8], u: f32[6,8]) -> bf16[4,8] {
   %x = bf16[4,8]{1,0} parameter(0)
   %i = s32[6]{0} parameter(1)
@@ -1192,6 +1328,7 @@ ROUTES_HLO = '''HloModule jit_train_round
   %added.1 = f32[4,8]{1,0} fusion(%z, %i, %u), kind=kCustom, calls=%fused_sorted_add, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/combine/scatter-add"}
   %rows.1 = bf16[6,8]{1,0} fusion(%x, %i), kind=kLoop, calls=%fused_rows
   %weights.1 = f32[6]{0} fusion(%w, %i), kind=kLoop, calls=%fused_weights
+  %placed.1 = f32[8]{0} fusion(%w, %i, %weights.1), kind=kCustom, calls=%fused_sorted_place, metadata={op_name="jit(train_round)/while/body/tau_step/transpose(jvp(MoE/l1_moe))/combine/scatter-add"}
   %copy.1 = bf16[6,8]{0,1} copy(%rows.1), metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/experts/transpose"}
   %sort.1 = s32[4]{0} sort(%j), dimensions={0}, to_apply=%lt, metadata={op_name="jit(train_round)/while/body/tau_step/jvp(MoE/l1_moe)/router/sort"}
   ROOT %sum.1 = bf16[4,8]{1,0} fusion(%rows.1, %j, %sort.1), kind=kLoop, calls=%fused_sum
@@ -1213,24 +1350,31 @@ ENTRY %main.1 (x: bf16[4,8], i: s32[6], j: s32[4], w: f32[8], z: f32[4,8], u: f3
 def test_routing_moves_counts_the_rows_routing_gathers():
     """In the loop's body, under an expert layer's and the MTP module's
     routing scopes: a fusion that gathers 6 rows of width 8, one that holds
-    two gathers of 4 rows, one that gathers 6 SCALARS (no row), a sort, and
-    a scatter-add of 6 rows into [4, 8] as the TPU compiler writes one -- a
-    fusion that holds a fusion with the fetch of the updates in sorted order
-    and a fusion with the scatter: one scatter of 6 rows, and the compiler's
-    own fetch no row gather of routing's; a copy under `experts`, which is
-    no routing. The peeled step holds one gather: the body that moves most
-    is the one reported. `attention_moves` and `routing_moves` are two calls
-    of one query."""
+    two gathers of 4 rows, one that gathers 6 SCALARS (no row: one move of
+    single elements, 6 of them), a sort, a scatter-add of 6 rows into [4, 8]
+    as the TPU compiler writes one -- a fusion that holds a fusion with the
+    fetch of the updates in sorted order and a fusion with the scatter: one
+    scatter of 6 rows, and the compiler's own fetch no row gather of
+    routing's -- and a scatter-add of 6 scalars into [8] written the same
+    way: a second move of single elements, the compiler's fetch inside it
+    none; a copy under `experts`, which is no routing. The peeled step holds
+    one gather: the body that moves most is the one reported.
+    `attention_moves` and `routing_moves` are two calls of one query."""
     from sparknet_tpu.obs import device as obs_device
     ops = obs_device.parse_hlo_ops(ROUTES_HLO)
     assert ops["%sum.1"]["gathered"] == [(4, 8), (4, 8)]
     assert ops["%weights.1"]["gathered"] == [(6,)]
     assert ops["%added.1"]["scattered"] == [((4, 8), (6, 8))]
     assert "gathered" not in ops["%added.1"] and "scattered" not in ops["%sum.1"]
+    assert ops["%weights.1"]["scalars"] == [6] == ops["%placed.1"]["scalars"]
+    assert ops["%placed.1"]["scattered"] == [((8,), (6,))]
+    assert not any("scalars" in ops[n] for n in ("%rows.1", "%sum.1", "%added.1"))
     got = obs_device.routing_moves(ops, sl.ROUTING_SCOPES, width=8)
-    counted = ("%rows.1", "%weights.1", "%sort.1", "%sum.1", "%added.1")
-    assert got == {"instructions": 5, "row_gathers": 3, "rows_gathered": 14,
+    counted = ("%rows.1", "%weights.1", "%placed.1", "%sort.1", "%sum.1",
+               "%added.1")
+    assert got == {"instructions": 6, "row_gathers": 3, "rows_gathered": 14,
                    "row_scatters": 1, "rows_scattered": 6,
+                   "slot_scalar_moves": 2, "slot_scalars_moved": 12,
                    "bytes": sum(ops[n]["bytes"] for n in counted)}
     # another width: the same ops, no rows of it fetched; the scatter's 6
     # rows of 8 are a slab of half its columns (3 rows' worth), and no slab
