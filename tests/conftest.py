@@ -27,10 +27,18 @@ def _compile_cache_in_tmp(tmp_path_factory):
     assertion must not depend on what ran yesterday. Child processes
     inherit the variable, so they share the session's cache. The cache
     earns its keep here: tests compile the same rounds again and again,
-    and a cold session cache took the suite from ~815 s to ~650 s
-    (PR 21, 8 cores, limit 870 s). On the CPU a cache HIT makes XLA's
-    loader print kilobytes of machine-feature warnings per executable: a
-    test must never leave a child's output in a pipe it does not read."""
+    and a cold session cache took the serial suite from ~815 s to ~650 s
+    (PR 21, 8 cores). The driver runs the suite under `-p xdist -n 6
+    --dist loadfile` with a limit of 1,470 s (`/root/TESTS_LAST_RUN.json`,
+    ROADMAP.md D13); `tmp_path_factory` is a worker's own there, so each of
+    the six workers keeps its own cache. One cache for the whole session
+    was weighed and left (PR 45): no two workers compile the same program
+    -- 0 keys in common among the 4,323 entries the six caches of a whole
+    run held, a file's tests running in one worker -- and jax writes an
+    entry in place, not by rename, so a second writer would only add the
+    risk of a torn read. On the CPU a cache HIT makes XLA's loader print
+    kilobytes of machine-feature warnings per executable: a test must never
+    leave a child's output in a pipe it does not read."""
     from sparknet_tpu.utils.compile_cache import CACHE_DIR_ENV
     if CACHE_DIR_ENV in os.environ:
         yield

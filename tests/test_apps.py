@@ -417,7 +417,7 @@ def test_elastic_resume_momentum_trajectory_band(tmp_path):
     from sparknet_tpu.parallel import ParallelTrainer, make_mesh
     from sparknet_tpu.parallel.mesh import fetch_global
     from sparknet_tpu.utils import checkpoint as ck
-    from test_parallel import TINY_MLP
+    from tiny_nets import TINY_MLP
 
     net = CompiledNet.compile(net_from_prototxt(TINY_MLP))
     scfg = SolverConfig(base_lr=0.05, momentum=0.9, weight_decay=0.001,

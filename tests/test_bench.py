@@ -232,7 +232,7 @@ def test_sharding_bench_smoke(tmp_path):
     CPU mesh is noise), not a tier-1 assertion."""
     import bench
     out_path = str(tmp_path / "BENCH_r07.json")
-    out = bench.sharding_bench(out_path=out_path, trials=2, small=True)
+    out = bench.sharding_bench(out_path=out_path, trials=1, small=True)
     rows = out["rows"]
     assert [r["arm"] for r in rows] == [
         "r6_prefetch_donate", "named_replicated", "named_fused",

@@ -283,7 +283,7 @@ def test_round_never_slices_its_stack_along_tau(as_tpu, cls, fused):
     (TPU-branch) round on the CPU backend: the only reads of the [τ, ...]
     stack are one step's rows at a time, so no op anywhere produces a
     [τ-1, ...] array of the batch's trailing shape."""
-    from test_parallel import TINY_MLP
+    from tiny_nets import TINY_MLP
     from sparknet_tpu import net_from_prototxt
 
     tau, n, local_b = 4, 2, 8
@@ -466,10 +466,10 @@ def test_attention_block_lays_out_nothing_between_projection_and_core(v5e, as_tp
     v goes from its matmul into the forward kernel with nothing between, and
     the block accesses under 19 GB (26.3 before the layout moved into the
     weights, 15.7 after)."""
-    from test_seq_layers import _attention_block
+    from model_cases import attention_block
     from sparknet_tpu.model.spec import MLAttentionParam
     from sparknet_tpu.obs.device import attention_moves, parse_hlo_ops
-    net, params, x, loss = _attention_block(MLAttentionParam(
+    net, params, x, loss = attention_block(MLAttentionParam(
         num_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
         qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6, eps=1e-5),
         positions=8192, d=2048)
@@ -659,7 +659,7 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         d, p = width, MoEParam(experts_held=(0, 8), num_experts_per_tok=k,
                                capacity_factor=2.0, **_EXPERT_LAYERS[cell])
     else:
-        from test_seq_layers import benchmark_expert_layers
+        from model_cases import benchmark_expert_layers
         (p, *_), _, d = benchmark_expert_layers({
             "ling": "ling3-flash-ep64-tau4",
             "nemotron": "nemotron3-super-tp4-ep64-tau4"}[cell])

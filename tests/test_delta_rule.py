@@ -11,6 +11,8 @@ recurrence; every case above keeps running on the `jnp` form (heads of 16).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,7 +54,8 @@ def _recurrence_by_hand(q, k, v, g, beta):
 _LOSS = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
 _ALL = (0, 1, 2, 3, 4)
 # jitted once a shape: the three kinds of gates share every compile
-_REC = jax.jit(lambda *a: dr.delta_rule_recurrent(*a)[0])
+_REC_AND_STATE = jax.jit(dr.delta_rule_recurrent)
+_REC = lambda *a: _REC_AND_STATE(*a)[0]
 _REC_GRAD = jax.jit(jax.grad(_LOSS(lambda *a: dr.delta_rule_recurrent(*a)[0]), argnums=_ALL))
 _CHUNKED = jax.jit(dr.gated_delta_rule, static_argnames=("chunk",))
 _CHUNKED_GRAD = jax.jit(
@@ -83,8 +86,8 @@ def test_both_forms_equal_the_definition_in_float64(gates):
     x = _inputs(3, 96, gates, lead=(2,))
     want, last = _recurrence_by_hand(*x)
     with precision.policy("float32"):
-        rec, s = dr.delta_rule_recurrent(*x)
-        got = dr.gated_delta_rule(*x)
+        rec, s = _REC_AND_STATE(*x)
+        got = _CHUNKED(*x)
     scale = np.max(np.abs(want))
     assert np.max(np.abs(np.asarray(rec) - want)) < 1e-5 * scale
     assert np.max(np.abs(np.asarray(s) - last)) < 1e-5 * np.max(np.abs(last))
@@ -96,11 +99,11 @@ def test_the_rule_is_causal_and_padding_neither_writes_nor_decays():
     later = tuple(t.at[..., 50:, :].add(0.3) if t.ndim == 4 else t for t in x)
     later = later[:3] + (x[3], x[4])
     with precision.policy("float32"):
-        a, b = dr.gated_delta_rule(*x), dr.gated_delta_rule(*later)
+        a, b = _CHUNKED(*x), _CHUNKED(*later)
         # 80 positions = a chunk of 64 and 16 of a second, padded to 64
-        short = dr.gated_delta_rule(*(t[..., :80, :] if t.ndim == 4 else t[..., :80]
-                                      for t in _inputs(5, 128, "spread")))
-        whole = dr.gated_delta_rule(*_inputs(5, 128, "spread"))
+        short = _CHUNKED(*(t[..., :80, :] if t.ndim == 4 else t[..., :80]
+                           for t in _inputs(5, 128, "spread")))
+        whole = _CHUNKED(*_inputs(5, 128, "spread"))
     assert np.array_equal(a[..., :50, :], b[..., :50, :])
     assert not np.allclose(a[..., 50, :], b[..., 50, :])
     assert np.allclose(short, whole[..., :80, :], atol=1e-6)
@@ -112,9 +115,10 @@ def test_unit_lower_inverse_by_hand(c):
     of N then grow past float32 (binom(63, 31) ~ 1e18) and the blockwise
     substitution does not care."""
     rng = np.random.default_rng(c)
+    inverse = jax.jit(dr._unit_lower_inverse)
     for entries in (rng.uniform(-1, 1, (3, c, c)), np.full((3, c, c), 0.97)):
         n = np.tril(entries, -1).astype(np.float32)
-        got = np.asarray(dr._unit_lower_inverse(jnp.asarray(n)))
+        got = np.asarray(inverse(jnp.asarray(n)))
         want = np.linalg.inv(np.eye(c) + n.astype(np.float64))
         assert np.max(np.abs(got - want)) < 1e-4 * max(1.0, np.max(np.abs(want)))
         assert np.allclose(np.triu(got, 1), 0) and np.allclose(np.diagonal(got, axis1=-2, axis2=-1), 1)
@@ -141,12 +145,14 @@ def test_sub_chunks_are_what_keeps_the_factored_decay_finite():
 def test_bfloat16_policy_runs_the_large_products_in_bfloat16():
     x = _inputs(11, 128, "spread")
     with precision.policy("float32"):
-        want = dr.gated_delta_rule(*x)
+        want = _CHUNKED(*x)
     with precision.policy("bfloat16"):
-        got = dr.gated_delta_rule(*x)
-        grads = jax.grad(lambda *a: jnp.sum(jnp.sin(dr.gated_delta_rule(*a))),
-                         argnums=(0, 1, 2, 3, 4))(*x)
-        text = jax.jit(dr.gated_delta_rule).lower(*x).as_text()
+        # (a fresh function a trace: the policy is no part of jax's cache key)
+        rule = jax.jit(lambda *a: dr.gated_delta_rule(*a))
+        got = rule(*x)
+        grads = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(dr.gated_delta_rule(*a))),
+                                 argnums=(0, 1, 2, 3, 4)))(*x)
+        text = rule.lower(*x).as_text()
     assert got.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(got - want))) < 0.03 * float(jnp.max(jnp.abs(want)))
     assert all(bool(jnp.all(jnp.isfinite(t))) for t in grads)
@@ -183,6 +189,22 @@ _KERNEL_CASES = ["spread", "at_the_bound", "zero_gates", "beta_near_0",
 _NAMES = "w_k w_v k_end d_end q_dec b_low".split()
 
 
+@functools.cache
+def _pair_and_oracle(mode):
+    """(x, cotangents) -> the oracle's and the kernel pair's operands and
+    their pullbacks of the cotangents: jitted once a mode, so that the six
+    cases of a mode share one compile (traced under the mode's policy)."""
+    dt = jnp.dtype(mode)
+
+    @jax.jit
+    def both(x, cot):
+        want, pull_want = jax.vjp(_oracle, *x)
+        got, pull_got = jax.vjp(lambda *a: pk.chunk_operands(*a, dt, True), *x)
+        return want, got, pull_want(cot), pull_got(cot)
+
+    return both
+
+
 @pytest.mark.parametrize("case", _KERNEL_CASES)
 @pytest.mark.parametrize("mode", ["float32", "bfloat16"])
 def test_kernel_pair_equals_chunk_operands_and_their_autodiff(mode, case):
@@ -190,11 +212,9 @@ def test_kernel_pair_equals_chunk_operands_and_their_autodiff(mode, case):
     q, k, v, g, beta = _kernel_inputs(case)
     x = (q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
     with precision.policy(mode):
-        want, pull_want = jax.vjp(_oracle, *x)
-        got, pull_got = jax.vjp(lambda *a: pk.chunk_operands(*a, dt, True), *x)
         cot = tuple(jax.random.normal(jax.random.PRNGKey(i), w.shape).astype(w.dtype)
-                    for i, w in enumerate(want))
-        g_want, g_got = pull_want(cot), pull_got(cot)
+                    for i, w in enumerate(jax.eval_shape(_oracle, *x)))
+        want, got, g_want, g_got = _pair_and_oracle(mode)(x, cot)
     f32 = lambda t: np.asarray(t, np.float32)
     for name, a, b in zip(_NAMES, got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, name

@@ -25,7 +25,7 @@ from sparknet_tpu.utils.config import ElasticConfig, RunConfig
 from sparknet_tpu.utils.health import TrainingHealthError, liveness_classify
 from sparknet_tpu.utils.heartbeat import HeartbeatWriter, read_heartbeat
 from sparknet_tpu.utils.logger import Logger
-from test_parallel import TINY_MLP
+from tiny_nets import TINY_MLP
 
 
 # -- heartbeat age + the shared dead-vs-slow rule ----------------------------

@@ -10,7 +10,6 @@ the exact path, and what the builder refuses.
 """
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 
@@ -20,10 +19,13 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import checkpoint_name
 
+from model_cases import (CTX, D, POS, ROOT, ROWS, _close, _x, case, check_layer,
+                         check_loss_and_every_gradient, check_round, compiled,
+                         max_err, program_round, reference_loss_and_grads,
+                         tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import layers as base_layers
 from sparknet_tpu.model import seq_layers as sl
-from sparknet_tpu.model.layers import ApplyCtx
 from sparknet_tpu.model.net import CompiledNet
 from sparknet_tpu.model.spec import (EltwiseParam, EVAttentionParam,
                                      GQAttentionParam, LayerSpec, LossParam,
@@ -31,43 +33,18 @@ from sparknet_tpu.model.spec import (EltwiseParam, EVAttentionParam,
 from sparknet_tpu.ops import attention as attention_ops
 from sparknet_tpu.ops import eva as eva_ops
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "evabyte_reference", os.path.join(ROOT, "benchmark", "configs",
-                                      "evabyte-l4-tau4.reference.py"))
-ref = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(ref)
-
-#: hidden 64, 2 heads of 32, windows of 8 positions in chunks of 2, rows of
-#: 32 (four windows: the last reads twelve summaries), 2 layers, 3 heads
-TINY = {"model_type": "evabyte", "hidden_size": 64, "intermediate_size": 96,
-        "num_attention_heads": 2, "num_key_value_heads": 2, "window_size": 8,
-        "chunk_size": 2, "num_pred_heads": 3, "vocab_size": 32,
-        "num_hidden_layers": 2, "rope_theta": 100000, "rms_norm_eps": 1e-5,
-        "norm_add_unit_offset": True, "fp32_skip_add": True, "fp32_logits": True,
-        "init_std": 0.05, "attention_class": "eva", "hidden_act": "silu",
-        "attention_bias": False, "tie_word_embeddings": False, "rope_scaling": None}
-ROWS, POS, D, W, C = 2, 32, 64, 8, 2
-LAYERS = ref.layer_table(TINY)
-TABLE = {name: (kind, a) for name, kind, a in LAYERS}
+EVA = case("evabyte")
+ref, TINY, LAYERS, TABLE = EVA.ref, EVA.tiny, EVA.layers, EVA.table
+W, C = 8, 2
 EVA_P = EVAttentionParam(num_heads=2, head_dim=32, window_size=W, chunk_size=C,
                          rope_theta=1e5)
-CTX = ApplyCtx(train=True)
-BF16_TOL = 0.03
-
-
-def _x(seed, shape=(ROWS, POS, D)):
-    return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
-
-
-def _ids(seed, shape=(ROWS, POS)):
-    return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, 32, jnp.int32)
+_ids = EVA.ids  # bytes: a vocabulary of 32
 
 
 def _params(seed, scale=1.0):
     """The reference's draw; `scale` > 1 spreads the attention's weights so
     that scores, summaries' weights and norms' scales differ visibly."""
-    p = ref.init_params(seed, LAYERS, std=0.05)
+    p = EVA.params(seed)
     if scale != 1.0:
         for name, (kind, _) in TABLE.items():
             if kind == "eva":
@@ -77,33 +54,24 @@ def _params(seed, scale=1.0):
     return p
 
 
-def _close(got, want, policy="float32", tol=None):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    scale = float(np.max(np.abs(want))) + 1e-30
-    tol = tol or (2e-5 if policy == "float32" else BF16_TOL)
-    assert float(np.max(np.abs(got - want))) / scale < tol
-
-
-def _per_row(fn, x):
-    with jax.default_matmul_precision("highest"):
-        return jnp.stack([fn(x[r]) for r in range(x.shape[0])])
-
-
 def _net(rows=ROWS, positions=POS, **over):
-    return CompiledNet.compile(zoo.evabyte(dict(TINY, **over), rows=rows,
-                                           positions=positions))
+    if (rows, positions) == (ROWS, POS) and not over:
+        return compiled("evabyte")
+    return CompiledNet.compile(EVA.spec(rows=rows, positions=positions, **over))
 
 
 # -- the layer and the net against the reference -----------------------------
 
+#: kind -> (seed -> the layer's weights, the program's layer, the reference's
+#: on one row)
+LAYER_TABLE = {"eva": (lambda seed: _params(seed, scale=4.0)["l0_attn"],
+                       lambda p, x: sl.eva(EVA_P, p, x, CTX),
+                       lambda p, r: ref.eva(TABLE["l0_attn"][1], p, r, "float32"))}
+
+
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 def test_layer_matches_the_reference(policy):
-    for seed in (1, 2):
-        p, x = _params(seed, scale=4.0)["l0_attn"], _x(seed)
-        with precision.policy(policy):
-            got = sl.eva(EVA_P, p, x, CTX)
-        want = _per_row(lambda r: ref.eva(TABLE["l0_attn"][1], p, r, "float32"), x)
-        _close(got, want, policy)
+    check_layer(LAYER_TABLE, "eva", policy)
 
 
 def test_layer_gradients_match_the_reference():
@@ -120,28 +88,16 @@ def test_layer_gradients_match_the_reference():
     _close(got[1], want[1], tol=1e-4)
 
 
-def _reference_loss_and_grads(params, ids, **kw):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(lambda p: sum(
-            ref.row_loss(p, ids[r], layers=LAYERS, **kw) for r in range(ROWS)) / ROWS)(params)
-
-
 @pytest.mark.parametrize("policy,seed", [("float32", 1), ("float32", 2),
                                          ("bfloat16", 1)])
 def test_loss_and_gradients_match_the_reference(policy, seed):
-    net, params, ids = _net(), _params(seed, scale=4.0), _ids(seed)
-    with precision.policy(policy):
-        (loss, blobs), grads = jax.value_and_grad(net.loss_fn(), has_aux=True)(
-            params, {"tokens": ids}, None)
-    want_loss, want = _reference_loss_and_grads(params, ids)
+    f32 = policy == "float32"
+    blobs, _, want = check_loss_and_every_gradient(
+        "evabyte", policy, _params(seed, scale=4.0), _ids(seed),
+        loss_tol=2e-5 if f32 else 2e-2, grad_tol=2e-5 if f32 else 0.08,
+        err=max_err)
     assert blobs["x1"].dtype == jnp.float32 == blobs["lm_head"].dtype
     assert blobs["l0_attn"].dtype == jnp.dtype(policy)
-    assert abs(float(loss) - float(want_loss)) < (2e-5 if policy == "float32" else 2e-2)
-    assert set(grads) == set(want)
-    for layer in want:
-        for name in want[layer]:
-            _close(grads[layer][name], want[layer][name], policy,
-                   tol=None if policy == "float32" else 0.08)
     # the summaries' own parameters are reached, in every layer
     for layer in ("l0_attn", "l1_attn"):
         assert float(jnp.max(jnp.abs(want[layer]["phi"]))) > 0
@@ -152,8 +108,8 @@ def test_a_program_without_summaries_is_another_model():
     """The reference with its summary columns masked out: another loss, and
     nothing reaches mu and phi."""
     params, ids = _params(1, scale=4.0), _ids(1)
-    whole, _ = _reference_loss_and_grads(params, ids)
-    blind, grads = _reference_loss_and_grads(params, ids, summaries=False)
+    whole, _ = reference_loss_and_grads("evabyte")(params, ids)
+    blind, grads = reference_loss_and_grads("evabyte", summaries=False)(params, ids)
     assert abs(float(whole) - float(blind)) > 1e-4
     assert not np.any(np.asarray(grads["l0_attn"]["phi"]))
     assert not np.any(np.asarray(grads["l1_attn"]["mu"]))
@@ -348,8 +304,7 @@ def test_eva_core_blocks_and_scopes():
     assert net.delta_scopes() == ({}, ()) and net.routing_scopes() == ((), 0)
     assert sl.KEPT_NAMES["EVAttention"] == (sl.ATTN_CORE,)
     assert zoo.SEQUENCE_MODELS["evabyte"] is zoo.evabyte
-    from test_seq_layers import _net as glm_net
-    assert glm_net().eva_scopes() == ({}, None)
+    assert compiled("glm4_moe_lite").eva_scopes() == ({}, None)
     lowered = jax.jit(lambda p, b: net.apply(p, b, train=True)["loss"]).lower(
         net.init_params(jax.random.PRNGKey(0)), {"tokens": _ids(1)}).as_text(debug_info=True)
     assert "EVAttention/l0_attn/summaries" in lowered
@@ -467,24 +422,14 @@ def test_what_the_builder_refuses():
 
 # -- one round through the trainer -------------------------------------------
 
-def test_one_round_through_the_trainer_matches_the_reference():
-    from sparknet_tpu.parallel import ParallelTrainer, make_mesh
-    from sparknet_tpu.solver import SolverConfig
-    solver = {"base_lr": 0.02, "lr_policy": "fixed", "momentum": 0.9, "weight_decay": 1e-4}
-    net, tau = _net(rows=1), 2
-    trainer = ParallelTrainer(net, SolverConfig(**solver), make_mesh(1), tau=tau,
-                              compute_health=False)
-    params = _params(2, scale=4.0)
-    ids = _ids(3, (tau, 1, POS))
-    state, loss = trainer.train_round(trainer.state_from_params(params), {"tokens": ids},
-                                      jax.random.PRNGKey(0))
-    want = ref.round_reference(params, lambda t, w: ids[t], tau=tau, solver=solver,
-                               layers=LAYERS)
-    assert float(loss) == pytest.approx(want["loss"], abs=2e-5)
-    probe = np.asarray(state.momentum["l0_attn"]["phi"][0])
-    _close(probe, want["probe"][0], tol=1e-4)
-    for layer, lp in state.momentum.items():
-        for name, m in lp.items():
-            norm = float(jnp.sqrt(jnp.sum(jnp.square(m[0]))))
-            assert norm == pytest.approx(want["momentum_norms"][0][f"{layer}/{name}"],
-                                         rel=1e-3), (layer, name)
+def _spread_params(seed):
+    return _params(seed, scale=4.0)
+
+
+def test_one_round_through_the_trainer_matches_the_reference(tmp_path):
+    case_ = tiny_round("evabyte", tmp_path, tau=2, rows=1, draw=_spread_params)
+    state, got = program_round("evabyte", case_.make_trainer(), case_.params,
+                               case_.ids)
+    check_round(got, case_.want, rel=1e-3)
+    _close(got["probe"][0], case_.want["probe"][0], tol=1e-4)
+    assert ref.PROBE_LEAF == ("l0_attn", "phi")

@@ -123,14 +123,14 @@ def tiny_trainer(trainer_cls):
     from sparknet_tpu import CompiledNet, net_from_prototxt
     from sparknet_tpu.parallel import make_mesh
     from sparknet_tpu.solver import SolverConfig
-    from test_parallel import TINY_MLP
+    from tiny_nets import TINY_MLP
     net = CompiledNet.compile(net_from_prototxt(TINY_MLP))
     cfg = SolverConfig(base_lr=0.05, momentum=0.9, lr_policy="fixed")
     return trainer_cls(net, cfg, make_mesh(), tau=3)
 
 
 def _mlp_batches(seed):
-    from test_parallel import make_round_batches
+    from tiny_nets import make_round_batches
     return make_round_batches(seed)
 
 

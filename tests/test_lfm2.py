@@ -9,51 +9,23 @@ needed of the core, the head and the grouped products' tiles.
 """
 from __future__ import annotations
 
-import importlib.util
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparknet_tpu import precision, zoo
+from model_cases import (CTX, D, POS, ROWS, _ids, _per_row, _x, case,
+                         check_layer, check_loss_and_every_gradient,
+                         check_round, compiled, program_round, tiny_round)
+from sparknet_tpu import zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.layers import LAYER_IMPLS
 from sparknet_tpu.model.net import CompiledNet
 from sparknet_tpu.model.spec import (GQAttentionParam, InnerProductParam,
-                                     InputSpec, LayerSpec, MoEParam,
-                                     ShortConvParam)
+                                     LayerSpec, MoEParam, ShortConvParam)
 
-# the same sizes (2 rows, 32 positions, hidden 64) and the same helpers as the
-# other sequence model's tests
-from test_seq_layers import (CTX, D, POS, ROWS, _close, _ids, _per_row,  # noqa: E402
-                             _x)
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "lfm2_reference", os.path.join(ROOT, "benchmark", "configs",
-                                   "lfm2-8b-a1b-ep4-tau4.reference.py"))
-ref = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(ref)
-
-#: hidden 64, 4 query heads of 16 over 2 key/value heads, 3 taps, 8 experts
-#: top-2 of which 2 are held (experts 2 and 3), vocabulary 256, 32 positions;
-#: a leading dense layer, then conv, attention, conv: every kind of layer
-TINY = {
-    "model_type": "lfm2_moe", "hidden_size": 64, "intermediate_size": 160,
-    "moe_intermediate_size": 48, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "conv_L_cache": 3, "conv_bias": False,
-    "layer_types": ["conv", "full_attention", "conv", "conv"],
-    "num_hidden_layers": 4, "num_dense_layers": 1, "num_experts": 2,
-    "num_experts_per_tok": 2, "routed_scaling_factor": 1,
-    "norm_topk_prob": True, "use_expert_bias": True, "norm_eps": 1e-5,
-    "rope_theta": 1000000, "vocab_size": 256, "seq_len": 32,
-    "share": {"chips_sharing_a_layer": 4, "num_experts": 8,
-              "experts_held": [2, 2], "vocab_rows": [0, 256]}}
-LAYERS = ref.layer_table(TINY)
-TABLE = {name: (kind, a) for name, kind, a in LAYERS}
+LFM2 = case("lfm2_moe")
+ref, TINY, LAYERS, TABLE = LFM2.ref, LFM2.tiny, LFM2.layers, LFM2.table
 GQA_P = GQAttentionParam(num_heads=4, num_kv_heads=2, head_dim=16,
                          rope_theta=1e6, eps=1e-5)
 MOE_P = MoEParam(n_routed_experts=8, experts_held=(2, 2), num_experts_per_tok=2,
@@ -63,11 +35,11 @@ MOE_P = MoEParam(n_routed_experts=8, experts_held=(2, 2), num_experts_per_tok=2,
 
 
 def _net():
-    return CompiledNet.compile(zoo.lfm2_moe(TINY, rows=ROWS, positions=POS))
+    return compiled("lfm2_moe")
 
 
 def _params(seed, layer, bias_scale=1.0):
-    p = ref.init_params(seed, LAYERS)[layer]
+    p = LFM2.params(seed)[layer]
     if "router_bias" in p:  # a bias large enough to change who is chosen
         p = dict(p, router_bias=p["router_bias"] * bias_scale)
     if "conv" in p:  # taps of the size of a gate, so that each one shows
@@ -84,31 +56,27 @@ def _apply(kind, layer, params, x):
 
 # -- layer by layer against the reference ------------------------------------
 
-def _layer_case(kind, seed):
-    x = _x(seed)
-    if kind == "shortconv":
-        p = _params(seed, "l0_conv")
-        layer = LayerSpec(name="c", type="ShortConv", shortconv=ShortConvParam(taps=3))
-        return (_apply("ShortConv", layer, p, x),
-                _per_row(lambda r: ref.shortconv(TABLE["l0_conv"][1], p, r, "float32"), x))
-    if kind == "gqa":
-        p = _params(seed, "l1_attn")
-        return (sl.gqa(GQA_P, p, x, CTX),
-                _per_row(lambda r: ref.gqa(TABLE["l1_attn"][1], p, r, "float32"), x))
-    if kind == "moe":
-        p = _params(seed, "l1_moe", bias_scale=20.0)
-        return (sl.moe(MOE_P, p, x, CTX)[0],
-                _per_row(lambda r: ref.moe(TABLE["l1_moe"][1], p, r, "float32")[0], x))
-    raise AssertionError(kind)
+#: kind -> (seed -> the layer's weights, the program's layer, the reference's
+#: on one row)
+LAYER_TABLE = {
+    "shortconv": (lambda seed: _params(seed, "l0_conv"),
+                  lambda p, x: _apply("ShortConv", LayerSpec(
+                      name="c", type="ShortConv",
+                      shortconv=ShortConvParam(taps=3)), p, x),
+                  lambda p, r: ref.shortconv(TABLE["l0_conv"][1], p, r, "float32")),
+    "gqa": (lambda seed: _params(seed, "l1_attn"),
+            lambda p, x: sl.gqa(GQA_P, p, x, CTX),
+            lambda p, r: ref.gqa(TABLE["l1_attn"][1], p, r, "float32")),
+    "moe": (lambda seed: _params(seed, "l1_moe", bias_scale=20.0),
+            lambda p, x: sl.moe(MOE_P, p, x, CTX)[0],
+            lambda p, r: ref.moe(TABLE["l1_moe"][1], p, r, "float32")[0]),
+}
 
 
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", ["shortconv", "gqa", "moe"])
 def test_layer_matches_the_reference(kind, policy):
-    for seed in (1, 2):  # two weight draws
-        with precision.policy(policy):
-            got, want = _layer_case(kind, seed)
-        _close(got, want, policy)
+    check_layer(LAYER_TABLE, kind, policy)
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -178,34 +146,16 @@ def test_gqa_rotary_is_over_the_whole_head_on_contiguous_halves():
 
 # -- the whole model ---------------------------------------------------------
 
-def _reference_loss_and_grads(params, ids):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(lambda p: sum(
-            ref.row_loss(p, ids[r], layers=LAYERS)[0] for r in range(ROWS)) / ROWS)(params)
-
-
 @pytest.mark.parametrize("policy,seed", [("float32", 1), ("float32", 2),
                                          ("float32", 3), ("bfloat16", 1)])
 def test_loss_and_every_stored_gradient_match_the_reference(policy, seed):
-    net, params, ids = _net(), ref.init_params(seed, LAYERS), _ids(seed + 70)
-    assert net.param_layers() == list(ref.param_shapes(LAYERS))
-    with precision.policy(policy):
-        (loss, _), grads = jax.jit(jax.value_and_grad(
-            lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None),
-            has_aux=True))(params)
-    want, want_grads = _reference_loss_and_grads(params, ids)
-    assert float(loss) == pytest.approx(float(want), abs=2e-5 if policy == "float32" else 2e-3)
-    assert set(grads) == set(want_grads) and "lm_head" not in grads
-    seen = set()
-    for layer, lp in want_grads.items():
-        for name, g in lp.items():
-            seen.add(name)
-            err = float(jnp.linalg.norm(grads[layer][name] - g)) / (
-                float(jnp.linalg.norm(g)) + 1e-30)
-            if name == "router_bias":
-                assert float(jnp.max(jnp.abs(grads[layer][name]))) == 0
-            else:
-                assert err < (2e-5 if policy == "float32" else 0.3), (layer, name, err)
+    assert _net().param_layers() == list(ref.param_shapes(LAYERS))
+    f32 = policy == "float32"
+    _, grads, want_grads = check_loss_and_every_gradient(
+        "lfm2_moe", policy, LFM2.params(seed), _ids(seed + 70),
+        loss_tol=2e-5 if f32 else 2e-3, grad_tol=2e-5 if f32 else 0.3)
+    assert "lm_head" not in grads
+    seen = {name for lp in want_grads.values() for name in lp}
     assert {"conv", "q_norm", "k_norm", "w", "in_proj", "out_proj"} <= seen
 
 
@@ -213,7 +163,7 @@ def test_the_tied_matrix_gradient_is_the_embeddings_plus_the_heads():
     """The head runs on the embedding's table transposed, and the table's
     gradient holds both uses: it equals the gradient of an untied net's
     table plus that of its head, transposed."""
-    spec = zoo.lfm2_moe(TINY, rows=ROWS, positions=POS)
+    spec = LFM2.spec()
     head = spec.layer_by_name("lm_head")
     assert head.param_from == "embed" and head.inner_product.transposed
     untied = CompiledNet.compile(spec.replace(layers=tuple(
@@ -221,10 +171,10 @@ def test_the_tied_matrix_gradient_is_the_embeddings_plus_the_heads():
                      "inner_product": InnerProductParam(
                          num_output=256, bias_term=False, axis=-1)})
         if l.name == "lm_head" else l for l in spec.layers)))
-    params, ids = ref.init_params(4, LAYERS), _ids(74)
+    params, ids = LFM2.params(4), _ids(74)
     loss = lambda net: lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None)[0]
-    l1, g1 = jax.value_and_grad(loss(_net()))(params)
-    l2, g2 = jax.value_and_grad(loss(untied))(
+    l1, g1 = jax.jit(jax.value_and_grad(loss(_net())))(params)
+    l2, g2 = jax.jit(jax.value_and_grad(loss(untied)))(
         dict(params, lm_head={"w": params["embed"]["w"].T}))
     assert float(l1) == pytest.approx(float(l2), rel=1e-6)
     assert np.allclose(g1["embed"]["w"], g2["embed"]["w"] + g2["lm_head"]["w"].T,
@@ -280,37 +230,14 @@ def test_the_routers_epsilon_is_the_layers_own():
 
 
 def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path):
-    from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.obs import device as obs_device
-    from sparknet_tpu.parallel import make_mesh
-    from sparknet_tpu.utils.config import RunConfig
 
-    path = tmp_path / "tiny.json"
-    path.write_text(json.dumps(TINY))
-    solver = {"base_lr": 0.02, "lr_policy": "fixed", "momentum": 0.9,
-              "weight_decay": 1e-4}
-    cfg = RunConfig.from_dict({
-        "model": str(path), "tau": 2, "local_batch": ROWS, "precision": "float32",
-        "solver": solver, "n_devices": 1, "health": {"enabled": False}})
-    spec = resolve_spec(cfg)
-    assert spec.name == "lfm2_moe"
-    assert spec.inputs == (InputSpec("tokens", (ROWS, POS), "int32"),)
-    trainer = build_trainer(cfg, spec, make_mesh(1))
-    params, ids = ref.init_params(8, LAYERS), np.asarray(_ids(78, (2, ROWS, POS)))
-    state, loss = trainer.train_round(trainer.state_from_params(params),
-                                      trainer.place_batches({"tokens": ids}),
-                                      jax.random.PRNGKey(0))
-    want = ref.round_reference(params, lambda t, w: ids[t], tau=2, solver=solver,
-                               layers=LAYERS, mtp_weight=0.3)  # accepted, unread
-    assert float(loss) == pytest.approx(want["loss"], abs=2e-5)
-    for layer, lp in params.items():
-        for name, p0 in lp.items():
-            key = f"{layer}/{name}"
-            upd = float(jnp.linalg.norm(state.params[layer][name][0] - p0))
-            mom = float(jnp.linalg.norm(state.momentum[layer][name][0]))
-            assert upd == pytest.approx(want["update_norms"][key], rel=2e-4, abs=1e-9), key
-            assert mom == pytest.approx(want["momentum_norms"][0][key], rel=2e-4, abs=1e-9), key
-    assert set(want["chosen"]) == {"l1_moe", "l2_moe", "l3_moe"}
+    # (`mtp_weight`: accepted, unread)
+    case_ = tiny_round("lfm2_moe", tmp_path, tau=2, mtp_weight=0.3)
+    trainer = case_.make_trainer()
+    _, got = program_round("lfm2_moe", trainer, case_.params, case_.ids)
+    check_round(got, case_.want, rel=2e-4)
+    assert set(case_.want["chosen"]) == {"l1_moe", "l2_moe", "l3_moe"}
     assert set(trainer.counter_values()) == {
         "l1_moe_counters", "l2_moe_counters", "l3_moe_counters"}
     # what the round's one attention block keeps: the core's output, and off

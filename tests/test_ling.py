@@ -10,60 +10,25 @@ arithmetic, and what the builder refuses.
 """
 from __future__ import annotations
 
-import importlib.util
 import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from model_cases import MLA_P as LATENT_WITH_RANK
+from model_cases import _params as _glm_params
+from model_cases import (CTX, D, POS, ROWS, _ids, _per_row, _x, case,
+                         check_layer, check_loss_and_every_gradient,
+                         check_round, compiled, program_round, tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
-from sparknet_tpu.model.net import CompiledNet
-from sparknet_tpu.model.spec import (InputSpec, KDAttentionParam,
-                                     MLAttentionParam, MoEParam)
+from sparknet_tpu.model.spec import (KDAttentionParam, MLAttentionParam,
+                                     MoEParam)
 
-# the same sizes (2 rows, 32 positions, hidden 64) and the same helpers as the
-# other sequence models' tests
-from test_seq_layers import (CTX, D, POS, ROWS, _close, _ids, _per_row,  # noqa: E402
-                             _x)
-from test_seq_layers import MLA_P as LATENT_WITH_RANK  # noqa: E402
-from test_seq_layers import _params as _glm_params  # noqa: E402
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "ling_reference", os.path.join(ROOT, "benchmark", "configs",
-                                   "ling3-flash-ep64-tau4.reference.py"))
-ref = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(ref)
-
-#: hidden 64, 4 heads of 16, 4 taps, 16 experts in 4 groups of which the 2
-#: best are kept, top 2, 2 held (experts 4 and 5: half of group 1), one shared
-#: expert, vocabulary 256, 32 positions; published layers 3 to 6 of a period
-#: of 6: delta rule, delta rule, latent attention, delta rule, the first with
-#: a dense MLP
-TINY = {
-    "model_type": "ling3_flash", "hidden_size": 64, "intermediate_size": 160,
-    "moe_intermediate_size": 48, "moe_shared_expert_intermediate_size": 48,
-    "num_attention_heads": 4, "head_dim": 16, "q_lora_rank": None,
-    "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
-    "v_head_dim": 16, "rope_theta": 6000000, "rms_norm_eps": 1e-6,
-    "short_conv_kernel_size": 4, "kda_lower_bound": -5, "kda_safe_gate": True,
-    "no_kda_lora": True, "linear_silu": True, "group_norm_size": 1,
-    "gated_attention_proj_granularity_type": "head_wise",
-    "score_function": "sigmoid", "layer_group_size": 6,
-    "num_hidden_layers": 4, "first_k_dense_replace": 1, "num_experts": 2,
-    "num_experts_per_tok": 2, "n_group": 4, "topk_group": 2,
-    "routed_scaling_factor": 2.5, "norm_topk_prob": True,
-    "expert_swiglu_limit_list": [0, 0, 0, 0, 0, 0, 0, 4],
-    "share_expert_swiglu_limit_list": [0, 0, 0, 0, 0, 0, 0, 5],
-    "vocab_size": 256, "seq_len": 32,
-    "share": {"chips_sharing_a_layer": 8, "num_experts": 16,
-              "experts_held": [4, 2], "vocab_rows": [0, 256], "first_layer": 3}}
-LAYERS = ref.layer_table(TINY)
-TABLE = {name: (kind, a) for name, kind, a in LAYERS}
+LING = case("ling3_flash")
+ref, TINY, LAYERS, TABLE = LING.ref, LING.tiny, LING.layers, LING.table
 KDA_P = KDAttentionParam(num_heads=4, head_dim=16, taps=4, lower_bound=-5.0,
                          eps=1e-6)
 MLA_P = MLAttentionParam(num_heads=4, q_lora_rank=None, kv_lora_rank=32,
@@ -76,11 +41,11 @@ MOE_P = MoEParam(n_routed_experts=16, experts_held=(4, 2), num_experts_per_tok=2
 
 
 def _net():
-    return CompiledNet.compile(zoo.ling3_flash(TINY, rows=ROWS, positions=POS))
+    return compiled("ling3_flash")
 
 
 def _params(seed, layer, bias_scale=1.0):
-    p = ref.init_params(seed, LAYERS)[layer]
+    p = LING.params(seed)[layer]
     if "router_bias" in p:  # a bias large enough to change who is chosen
         p = dict(p, router_bias=p["router_bias"] * bias_scale)
     if "q_conv" in p:  # taps and gates of a size that shows: decays spread
@@ -96,30 +61,25 @@ def _params(seed, layer, bias_scale=1.0):
 
 # -- layer by layer against the reference ------------------------------------
 
-def _layer_case(kind, seed):
-    x = _x(seed)
-    if kind == "kda":
-        p = _params(seed, "l0_kda")
-        return (sl.kda(KDA_P, p, x, CTX),
-                _per_row(lambda r: ref.kda(TABLE["l0_kda"][1], p, r, "float32"), x))
-    if kind == "mla":
-        p = _params(seed, "l2_attn")
-        return (sl.mla(MLA_P, p, x, CTX),
-                _per_row(lambda r: ref.mla(TABLE["l2_attn"][1], p, r, "float32"), x))
-    if kind == "moe":
-        p = _params(seed, "l1_moe", bias_scale=20.0)
-        return (sl.moe(MOE_P, p, x, CTX)[0],
-                _per_row(lambda r: ref.moe(TABLE["l1_moe"][1], p, r, "float32")[0], x))
-    raise AssertionError(kind)
+#: kind -> (seed -> the layer's weights, the program's layer, the reference's
+#: on one row)
+LAYER_TABLE = {
+    "kda": (lambda seed: _params(seed, "l0_kda"),
+            lambda p, x: sl.kda(KDA_P, p, x, CTX),
+            lambda p, r: ref.kda(TABLE["l0_kda"][1], p, r, "float32")),
+    "mla": (lambda seed: _params(seed, "l2_attn"),
+            lambda p, x: sl.mla(MLA_P, p, x, CTX),
+            lambda p, r: ref.mla(TABLE["l2_attn"][1], p, r, "float32")),
+    "moe": (lambda seed: _params(seed, "l1_moe", bias_scale=20.0),
+            lambda p, x: sl.moe(MOE_P, p, x, CTX)[0],
+            lambda p, r: ref.moe(TABLE["l1_moe"][1], p, r, "float32")[0]),
+}
 
 
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", ["kda", "mla", "moe"])
 def test_layer_matches_the_reference(kind, policy):
-    for seed in (1, 2):  # two weight draws
-        with precision.policy(policy):
-            got, want = _layer_case(kind, seed)
-        _close(got, want, policy)
+    check_layer(LAYER_TABLE, kind, policy)
 
 
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
@@ -295,73 +255,31 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
 
 # -- the whole model ---------------------------------------------------------
 
-def _reference_loss_and_grads(params, ids):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(lambda p: sum(
-            ref.row_loss(p, ids[r], layers=LAYERS)[0] for r in range(ROWS)) / ROWS)(params)
-
-
 @pytest.mark.parametrize("policy,seed", [("float32", 1), ("float32", 2),
                                          ("bfloat16", 1)])
 def test_loss_and_every_stored_gradient_match_the_reference(policy, seed):
-    net, params, ids = _net(), ref.init_params(seed, LAYERS), _ids(seed + 70)
-    assert net.param_layers() == list(ref.param_shapes(LAYERS))
-    with precision.policy(policy):
-        (loss, _), grads = jax.jit(jax.value_and_grad(
-            lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None),
-            has_aux=True))(params)
-    want, want_grads = _reference_loss_and_grads(params, ids)
-    assert float(loss) == pytest.approx(float(want), abs=2e-5 if policy == "float32" else 2e-3)
-    assert set(grads) == set(want_grads)
-    seen = set()
-    for layer, lp in want_grads.items():
-        for name, g in lp.items():
-            seen.add(name)
-            err = float(jnp.linalg.norm(grads[layer][name] - g)) / (
-                float(jnp.linalg.norm(g)) + 1e-30)
-            if name == "router_bias":
-                assert float(jnp.max(jnp.abs(grads[layer][name]))) == 0
-            else:
-                # in bfloat16 a position near a tie chooses another expert:
-                # the held experts' own gradients differ by whole slots
-                loose = 0.6 if name.startswith("experts_") else 0.3
-                assert err < (5e-5 if policy == "float32" else loose), (layer, name, err)
+    assert _net().param_layers() == list(ref.param_shapes(LAYERS))
+    # in bfloat16 a position near a tie chooses another expert: the held
+    # experts' own gradients differ by whole slots
+    f32 = policy == "float32"
+    loose = lambda name: 0.6 if name.startswith("experts_") else 0.3
+    _, _, want_grads = check_loss_and_every_gradient(
+        "ling3_flash", policy, LING.params(seed), _ids(seed + 70),
+        loss_tol=2e-5 if f32 else 2e-3, grad_tol=5e-5 if f32 else loose)
+    seen = {name for lp in want_grads.values() for name in lp}
     assert {"q_conv", "k_conv", "v_conv", "a", "dt_bias", "A_log", "beta",
             "out_gate", "o_norm", "q", "kv_b", "shared_down"} <= seen
 
 
 def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path):
-    from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.obs import device as obs_device
-    from sparknet_tpu.parallel import make_mesh
-    from sparknet_tpu.utils.config import RunConfig
 
-    path = tmp_path / "tiny.json"
-    path.write_text(json.dumps(TINY))
-    solver = {"base_lr": 0.02, "lr_policy": "fixed", "momentum": 0.9,
-              "weight_decay": 1e-4}
-    cfg = RunConfig.from_dict({
-        "model": str(path), "tau": 2, "local_batch": ROWS, "precision": "float32",
-        "solver": solver, "n_devices": 1, "health": {"enabled": False}})
-    spec = resolve_spec(cfg)
-    assert spec.name == "ling3_flash"
-    assert spec.inputs == (InputSpec("tokens", (ROWS, POS), "int32"),)
-    trainer = build_trainer(cfg, spec, make_mesh(1))
-    params, ids = ref.init_params(8, LAYERS), np.asarray(_ids(78, (2, ROWS, POS)))
-    state, loss = trainer.train_round(trainer.state_from_params(params),
-                                      trainer.place_batches({"tokens": ids}),
-                                      jax.random.PRNGKey(0))
-    want = ref.round_reference(params, lambda t, w: ids[t], tau=2, solver=solver,
-                               layers=LAYERS, mtp_weight=0.3)  # accepted, unread
-    assert float(loss) == pytest.approx(want["loss"], abs=2e-5)
-    for layer, lp in params.items():
-        for name, p0 in lp.items():
-            key = f"{layer}/{name}"
-            upd = float(jnp.linalg.norm(state.params[layer][name][0] - p0))
-            mom = float(jnp.linalg.norm(state.momentum[layer][name][0]))
-            assert upd == pytest.approx(want["update_norms"][key], rel=3e-4, abs=1e-9), key
-            assert mom == pytest.approx(want["momentum_norms"][0][key], rel=3e-4, abs=1e-9), key
-    assert set(want["chosen"]) == {"l1_moe", "l2_moe", "l3_moe"}
+    # (`mtp_weight`: accepted, unread)
+    case_ = tiny_round("ling3_flash", tmp_path, tau=2, mtp_weight=0.3)
+    trainer = case_.make_trainer()
+    _, got = program_round("ling3_flash", trainer, case_.params, case_.ids)
+    check_round(got, case_.want, rel=3e-4)
+    assert set(case_.want["chosen"]) == {"l1_moe", "l2_moe", "l3_moe"}
     assert set(trainer.counter_values()) == {
         "l1_moe_counters", "l2_moe_counters", "l3_moe_counters"}
     # the round's account of itself: the one latent block keeps its core's
@@ -417,7 +335,7 @@ def test_the_delta_rule_compiles_to_one_loop_over_chunks_a_pass():
 # -- the builder -------------------------------------------------------------
 
 def test_zoo_follows_the_published_period_and_names_what_a_block_keeps():
-    spec = zoo.ling3_flash(TINY, rows=ROWS, positions=POS)
+    spec = LING.spec()
     ops = [(l.name, l.type) for l in spec.layers if l.type in ("KDAttention", "MLAttention")]
     # published layers 3, 4, 5, 6: (j + 1) % 6 == 0 at 5
     assert ops == [("l0_kda", "KDAttention"), ("l1_kda", "KDAttention"),
@@ -450,9 +368,7 @@ def test_zoo_follows_the_published_period_and_names_what_a_block_keeps():
             net.init_params, jax.random.PRNGKey(0)).values() for v in lp.values())
     assert zoo.SEQUENCE_MODELS["ling3_flash"] is zoo.ling3_flash
     # the other builders' nets have no delta rule to report
-    from test_lfm2 import TINY as LFM2_TINY
-    assert CompiledNet.compile(zoo.lfm2_moe(LFM2_TINY, rows=ROWS, positions=POS)
-                               ).delta_scopes() == ({}, ())
+    assert compiled("lfm2_moe").delta_scopes() == ({}, ())
 
 
 @pytest.mark.parametrize("change,match", [

@@ -11,75 +11,28 @@ must refuse, the share arithmetic, and what the builder refuses.
 """
 from __future__ import annotations
 
-import importlib.util
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from model_cases import MOE_P as GLM_MOE
+from model_cases import _params as glm_params
+from model_cases import (CTX, D, POS, ROWS, SOLVER, _ids, _per_row, _x, case,
+                         check_layer, check_loss_and_every_gradient,
+                         check_round, compiled, load, program_round,
+                         tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
-from sparknet_tpu.model.net import CompiledNet
-from sparknet_tpu.model.spec import (GQAttentionParam, InputSpec, Mamba2Param,
-                                     MoEParam)
+from sparknet_tpu.model.spec import GQAttentionParam, Mamba2Param, MoEParam
 from sparknet_tpu.ops import ssd as ssd_ops
 
-# the same sizes (2 rows, 32 positions, hidden 64) and the same helpers as the
-# other sequence models' tests
-from test_seq_layers import CTX, D, POS, ROWS, _close, _ids, _per_row, _x  # noqa: E402
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(name, rel):
-    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, rel))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-ref = _load("nemotron_reference",
-            "benchmark/configs/nemotron3-super-tp4-ep64-tau4.reference.py")
-compare = _load("nemotron_compare", "benchmark/compare.py")
-
-#: hidden 64; Mamba-2 with 8 heads of 16 in 4 groups of state 16 (expand 2),
-#: 4 heads and 2 groups held (heads 4-7, groups 2-3), chunks of 16: 32
-#: positions are two; attention with 4 query and 2 key/value heads of 16, 2
-#: and 1 held (the second pair); 16 experts of width 48 in a latent of 32,
-#: the 6 best a token, 2 held (experts 4 and 5: fewer held than chosen), a
-#: shared expert of 96 columns of which 24 are held; vocabulary 256;
-#: MEM*E and an MTP module *E
-TINY = {
-    "model_type": "nemotron_h", "hidden_size": 64, "expand": 2,
-    "mamba_num_heads": 4, "mamba_head_dim": 16, "n_groups": 2,
-    "ssm_state_size": 16, "conv_kernel": 4, "chunk_size": 16,
-    "use_conv_bias": True, "mamba_hidden_act": "silu", "mlp_hidden_act": "relu2",
-    "time_step_min": 0.001, "time_step_max": 0.1, "time_step_floor": 1e-4,
-    "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 16,
-    "n_routed_experts": 2, "num_experts_per_tok": 6, "moe_intermediate_size": 48,
-    "moe_latent_size": 32, "moe_shared_expert_intermediate_size": 96,
-    "n_shared_experts": 1, "routed_scaling_factor": 5, "norm_topk_prob": True,
-    "n_group": 1, "topk_group": 1, "layer_norm_epsilon": 1e-5,
-    "num_hidden_layers": 5, "hybrid_override_pattern": "MEM*E",
-    "num_nextn_predict_layers": 1, "mtp_hybrid_override_pattern": "*E",
-    "vocab_size": 256, "seq_len": 32,
-    "share": {"chips_sharing_a_layer": 8, "tensor_parallel": 2,
-              "n_routed_experts": 16, "mamba_num_heads": 8, "n_groups": 4,
-              "num_attention_heads": 4, "num_key_value_heads": 2,
-              "experts_held": [4, 2], "mamba_heads_held": [4, 4],
-              "mamba_groups_held": [2, 2], "attention_heads_held": [2, 2],
-              "kv_heads_held": [1, 1], "shared_columns": [24, 24],
-              "vocab_rows": [0, 256], "first_layer": 3, "mtp_loss_weight": 0.1}}
-LAYERS = ref.layer_table(TINY)
-TABLE = {name: (kind, a) for name, kind, a in LAYERS}
-#: the weights' spread: 0.16 at a hidden size of 64 gives the projections the
-#: size 0.02 gives them at 4,096, so the scan adds what the skip does
-STD = 0.16
-SOLVER = {"base_lr": 0.02, "lr_policy": "fixed", "momentum": 0.9,
-          "weight_decay": 1e-4}
+NEMOTRON = case("nemotron_h")
+ref, TINY, LAYERS, TABLE = (NEMOTRON.ref, NEMOTRON.tiny, NEMOTRON.layers,
+                            NEMOTRON.table)
+compare = load("benchmark/compare.py")
+#: the weights' spread (`model_cases._CONFIGS`)
+STD = NEMOTRON.init["std"]
 #: the tiny model's limits, from CPU readings of this file's own runs (float32
 #: program against the float32 reference: sound reads 1e-6 to 3e-4)
 TINY_LIMITS = {"loss_gap": 1e-4, "update_gap": 2e-3, "momentum_gap": 2e-3,
@@ -91,7 +44,7 @@ def _spec(config=TINY):
 
 
 def _net():
-    return CompiledNet.compile(_spec())
+    return compiled("nemotron_h")
 
 
 MAMBA_P = _spec().layer_by_name("l0_mamba").mamba2
@@ -110,7 +63,7 @@ MOE = jax.jit(lambda p, x, held=None: sl.moe(held or MOE_P, p, x, CTX),
 
 
 def _params(seed, layer, bias_scale=1.0):
-    p = ref.init_params(seed, LAYERS, std=STD)[layer]
+    p = NEMOTRON.params(seed)[layer]
     if "router_bias" in p:  # a bias large enough to change who is chosen
         p = dict(p, router_bias=p["router_bias"] * bias_scale)
     if "conv" in p:  # a skip and a norm that differ by head and channel
@@ -190,29 +143,24 @@ def test_a_strong_decay_leaves_nothing_outside_float32():
 
 # -- layer by layer against the reference ------------------------------------
 
-def _layer_case(kind, seed):
-    """(program's result, reference's result) of one layer on one input, each
-    side one jitted call."""
-    x = _x(seed)
-    layer, bias, program, reference = {
-        "mamba2": ("l0_mamba", 1.0, MAMBA, ref.mamba2),
-        "gqa": ("l3_attn", 1.0, GQA, ref.gqa),
-        "latent_moe": ("l1_moe", 20.0, lambda p, x: MOE(p, x)[0],
-                       lambda *a: ref.latent_moe(*a)[0])}[kind]
-    p = _params(seed, layer, bias_scale=bias)
-    want = jax.jit(lambda p, x: _per_row(
-        lambda r: reference(TABLE[layer][1], p, r, "float32"), x))
-    return program(p, x), want(p, x)
+#: kind -> (seed -> the layer's weights, the program's layer, the reference's
+#: on one row)
+LAYER_TABLE = {
+    "mamba2": (lambda seed: _params(seed, "l0_mamba"), MAMBA,
+               lambda p, r: ref.mamba2(TABLE["l0_mamba"][1], p, r, "float32")),
+    "gqa": (lambda seed: _params(seed, "l3_attn"), GQA,
+            lambda p, r: ref.gqa(TABLE["l3_attn"][1], p, r, "float32")),
+    "latent_moe": (lambda seed: _params(seed, "l1_moe", bias_scale=20.0),
+                   lambda p, x: MOE(p, x)[0],
+                   lambda p, r: ref.latent_moe(TABLE["l1_moe"][1], p, r,
+                                               "float32")[0]),
+}
 
 
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kind", ["mamba2", "gqa", "latent_moe"])
 def test_layer_matches_the_reference(kind, policy):
-    for seed in (1, 2):  # two weight draws
-        with precision.policy(policy):
-            got, want = _layer_case(kind, seed)
-        assert float(jnp.max(jnp.abs(want))) > 1e-4
-        _close(got, want, policy)
+    check_layer(LAYER_TABLE, kind, policy)
 
 
 def test_the_mixers_parts_are_what_the_formulas_say():
@@ -256,10 +204,7 @@ def test_other_models_layers_are_bit_equal_to_what_they_were():
     and GLM's expert layer (SwiGLU in the stream's width), on their own tiny
     files, against the two functions written here as they stood before the
     shares, the switches and the latent."""
-    from test_lfm2 import TINY as LFM2_TINY
-    from test_seq_layers import MOE_P as GLM_MOE
-    from test_seq_layers import _params as glm_params
-    spec = zoo.lfm2_moe(LFM2_TINY, rows=ROWS, positions=POS)
+    spec = case("lfm2_moe").spec()
     layer = next(l for l in spec.layers if l.type == "GQAttention")
     p = layer.gqa
     assert (p.rotary, p.qk_norm, p.heads_held, p.kv_heads_held) == (True, True, None, None)
@@ -393,77 +338,35 @@ def test_the_shares_add_up_to_the_uncut_layers(seed):
 
 # -- the whole model ---------------------------------------------------------
 
-def _reference_loss_and_grads(params, ids):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(lambda p: sum(
-            ref.row_loss(p, ids[r], layers=LAYERS)[0] for r in range(ROWS)) / ROWS)(params)
-
-
 @pytest.mark.parametrize("policy,seed", [("float32", 1), ("float32", 2),
                                          ("bfloat16", 1)])
 def test_loss_and_every_stored_gradient_match_the_reference(policy, seed):
-    net, params, ids = _net(), ref.init_params(seed, LAYERS, std=STD), _ids(seed + 70)
-    assert net.param_layers() == list(ref.param_shapes(LAYERS))
-    with precision.policy(policy):
-        (loss, _), grads = jax.jit(jax.value_and_grad(
-            lambda p: net.loss_fn("loss")(p, {"tokens": ids}, None),
-            has_aux=True))(params)
-    want, want_grads = _reference_loss_and_grads(params, ids)
-    assert float(loss) == pytest.approx(float(want), abs=2e-5 if policy == "float32" else 1e-2)
-    assert set(grads) == set(want_grads)
-    seen = set()
-    for layer, lp in want_grads.items():
-        for name, g in lp.items():
-            seen.add(name)
-            err = float(jnp.linalg.norm(grads[layer][name] - g)) / (
-                float(jnp.linalg.norm(g)) + 1e-30)
-            if name == "router_bias":
-                assert float(jnp.max(jnp.abs(grads[layer][name]))) == 0
-            else:
-                # in bfloat16 a position near a tie chooses another expert:
-                # the held experts' own gradients differ by whole slots
-                loose = 0.6 if name.startswith("experts_") else 0.3
-                assert err < (5e-5 if policy == "float32" else loose), (layer, name, err)
+    assert _net().param_layers() == list(ref.param_shapes(LAYERS))
+    # in bfloat16 a position near a tie chooses another expert: the held
+    # experts' own gradients differ by whole slots
+    f32 = policy == "float32"
+    loose = lambda name: 0.6 if name.startswith("experts_") else 0.3
+    _, _, want_grads = check_loss_and_every_gradient(
+        "nemotron_h", policy, NEMOTRON.params(seed), _ids(seed + 70),
+        loss_tol=2e-5 if f32 else 1e-2, grad_tol=5e-5 if f32 else loose)
+    seen = {name for lp in want_grads.values() for name in lp}
     assert {"in_proj", "conv", "conv_bias", "dt_bias", "A_log", "D", "norm",
             "out_proj", "q", "o", "latent_down", "latent_up", "experts_up",
             "shared_down", "w", "scale"} <= seen
 
 
-@pytest.fixture(scope="module")
-def tiny_round(tmp_path_factory):
-    """(trainer, weights, ids [tau, rows, positions], the reference's round)."""
-    from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
-    from sparknet_tpu.parallel import make_mesh
-    from sparknet_tpu.utils.config import RunConfig
-    path = tmp_path_factory.mktemp("nemotron") / "tiny.json"
-    path.write_text(json.dumps(TINY))
-    cfg = RunConfig.from_dict({
-        "model": str(path), "tau": 2, "local_batch": ROWS, "precision": "float32",
-        "solver": SOLVER, "n_devices": 1, "health": {"enabled": False}})
-    spec = resolve_spec(cfg)
-    assert spec.name == "nemotron_h"
-    assert spec.inputs == (InputSpec("tokens", (ROWS, POS), "int32"),)
-    params, ids = ref.init_params(8, LAYERS, std=STD), np.asarray(_ids(78, (2, ROWS, POS)))
-    want = ref.round_reference(params, lambda t, w: ids[t], tau=2, solver=SOLVER,
-                               layers=LAYERS, mtp_weight=0.1)
-    return (lambda: build_trainer(cfg, spec, make_mesh(1))), params, ids, want
+@pytest.fixture(scope="module", name="tiny_round")
+def _tiny_round(tmp_path_factory):
+    """(a trainer's maker, weights, ids [tau, rows, positions], the
+    reference's round)."""
+    case_ = tiny_round("nemotron_h", tmp_path_factory.mktemp("nemotron"), tau=2,
+                       mtp_weight=0.1)
+    return case_.make_trainer, case_.params, case_.ids, case_.want
 
 
 def _program_round(make_trainer, params, ids):
-    """What `correct` reads of a round of the program (the token driver's
-    `check_round`, in small)."""
     trainer = make_trainer()
-    state, loss = trainer.train_round(trainer.state_from_params(params),
-                                      trainer.place_batches({"tokens": ids}),
-                                      jax.random.PRNGKey(0))
-    norm = lambda x: float(jnp.linalg.norm(x))
-    flat = lambda fn: {f"{l}/{n}": fn(l, n) for l, lp in params.items() for n in lp}
-    layer, leaf = ref.PROBE_LEAF
-    return trainer, {
-        "loss": float(loss),
-        "update_norms": flat(lambda l, n: norm(state.params[l][n][0] - params[l][n])),
-        "momentum_norms": [flat(lambda l, n: norm(state.momentum[l][n][0]))],
-        "probe": [np.asarray(state.momentum[layer][leaf][0])]}
+    return trainer, program_round("nemotron_h", trainer, params, ids)[1]
 
 
 def _failed(got, want):
@@ -475,11 +378,7 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
     from sparknet_tpu.obs import device as obs_device
     make_trainer, params, ids, want = tiny_round
     trainer, got = _program_round(make_trainer, params, ids)
-    assert got["loss"] == pytest.approx(want["loss"], abs=2e-5)
-    for key, norm in want["update_norms"].items():
-        assert got["update_norms"][key] == pytest.approx(norm, rel=3e-4, abs=1e-9), key
-        assert got["momentum_norms"][0][key] == pytest.approx(
-            want["momentum_norms"][0][key], rel=3e-4, abs=1e-9), key
+    check_round(got, want, rel=3e-4)
     assert _failed(got, want) == []
     assert set(want["chosen"]) == {"l1_moe", "l4_moe", "mtp1_moe"}
     assert want["chosen"]["l1_moe"].shape == (ROWS, POS, 6)
@@ -670,9 +569,7 @@ def test_zoo_follows_the_pattern_and_builds_the_mtp_module_of_layer_types():
         == ref.param_shapes(LAYERS)
     assert zoo.SEQUENCE_MODELS["nemotron_h"] is zoo.nemotron_h
     # the other builders' nets have no scan to report
-    from test_lfm2 import TINY as LFM2_TINY
-    assert CompiledNet.compile(zoo.lfm2_moe(LFM2_TINY, rows=ROWS, positions=POS)
-                               ).ssd_scopes() == {}
+    assert compiled("lfm2_moe").ssd_scopes() == {}
 
 
 def test_the_programs_own_initial_values_keep_the_state_alive():

@@ -15,33 +15,7 @@ from sparknet_tpu.model.caffe_compat import (collection_to_params,
 from sparknet_tpu.model.weights import WeightCollection
 from sparknet_tpu.net_api import JaxNet
 from sparknet_tpu.solver import SolverConfig
-from tests.test_prototxt import ADULT
-
-CIFARISH = """
-name: "tiny_cifar"
-input: "data"
-input_shape { dim: 4 dim: 3 dim: 16 dim: 16 }
-input: "label"
-input_shape { dim: 4 dim: 1 }
-layer {
-  name: "conv1" type: "Convolution" bottom: "data" top: "conv1"
-  param { lr_mult: 1 } param { lr_mult: 2 }
-  convolution_param {
-    num_output: 8 pad: 2 kernel_size: 5 stride: 1
-    weight_filler { type: "gaussian" std: 0.01 }
-    bias_filler { type: "constant" }
-  }
-}
-layer { name: "pool1" type: "Pooling" bottom: "conv1" top: "pool1"
-        pooling_param { pool: MAX kernel_size: 3 stride: 2 } }
-layer { name: "relu1" type: "ReLU" bottom: "pool1" top: "pool1" }
-layer { name: "ip1" type: "InnerProduct" bottom: "pool1" top: "ip1"
-        inner_product_param { num_output: 10
-          weight_filler { type: "gaussian" std: 0.1 } } }
-layer { name: "prob" type: "Softmax" bottom: "ip1" top: "prob" }
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip1" bottom: "label" top: "loss" }
-layer { name: "acc" type: "Accuracy" bottom: "ip1" bottom: "label" top: "acc" }
-"""
+from tiny_nets import ADULT, CIFARISH
 
 
 @pytest.fixture(scope="module")

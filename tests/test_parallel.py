@@ -13,27 +13,7 @@ import jax.numpy as jnp
 from sparknet_tpu import CompiledNet, net_from_prototxt
 from sparknet_tpu.parallel import ParallelTrainer, make_mesh
 from sparknet_tpu.solver import SgdSolver, SolverConfig, SolverState
-
-TINY_MLP = """
-name: "tiny_mlp"
-input: "data"
-input_shape { dim: 8 dim: 6 }
-input: "label"
-input_shape { dim: 8 dim: 1 }
-layer { name: "ip1" type: "InnerProduct" bottom: "data" top: "ip1"
-        inner_product_param { num_output: 16
-          weight_filler { type: "gaussian" std: 0.3 } } }
-layer { name: "relu1" type: "ReLU" bottom: "ip1" top: "ip1" }
-layer { name: "ip2" type: "InnerProduct" bottom: "ip1" top: "ip2"
-        inner_product_param { num_output: 4
-          weight_filler { type: "gaussian" std: 0.3 } } }
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip2" bottom: "label" top: "loss" }
-layer { name: "acc" type: "Accuracy" bottom: "ip2" bottom: "label" top: "acc" }
-"""
-
-N_DEV = 8
-TAU = 3
-LOCAL_B = 8
+from tiny_nets import N_DEV, TAU, TINY_MLP, make_round_batches
 
 
 @pytest.fixture(scope="module")
@@ -45,14 +25,6 @@ def net():
 def cfg():
     return SolverConfig(base_lr=0.05, momentum=0.9, weight_decay=0.001,
                         lr_policy="fixed")
-
-
-def make_round_batches(seed):
-    r = np.random.default_rng(seed)
-    data = r.standard_normal((TAU, N_DEV * LOCAL_B, 6)).astype(np.float32)
-    label = (data.sum(-1, keepdims=True) > 0).astype(np.int32) + \
-        (data[..., :1] > 0.5).astype(np.int32)
-    return {"data": data, "label": label}
 
 
 def test_mesh_has_8_devices():

@@ -190,8 +190,8 @@ def test_imagenet_smoke(tmp_path):
 
 # -- Offline proxies (synthetic data; always run) ----------------------------
 
-def test_numpy_oracle_recipe_trajectory(tmp_path):
-    """VERDICT r3 item 4b: ~50 iterations of the cifar10_quick RECIPE
+def _recipe_trajectory(iters):
+    """VERDICT r3 item 4b: `iters` (10 in tier-1, 50 in the slow test) iterations of the cifar10_quick RECIPE
     (lr 0.001 fixed, momentum 0.9, wd 0.004, batch 100, lr_mult 1/2) through
     an INDEPENDENT numpy reimplementation of the net + Caffe SGD
     (tests/numpy_oracle.py: hand-written im2col/col2im, window-argmax max
@@ -239,7 +239,7 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
     from sparknet_tpu.solver import SgdSolver, SolverConfig
     from sparknet_tpu.zoo import cifar10_quick
 
-    B, ITERS = 100, 50
+    B = 100
     net = CompiledNet.compile(cifar10_quick(batch=B))
     cfg = SolverConfig(base_lr=0.001, momentum=0.9, weight_decay=0.004,
                        lr_policy="fixed")
@@ -248,7 +248,7 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
     np_params = {l: {p: np.asarray(v, np.float32) for p, v in lp.items()}
                  for l, lp in params.items()}
     mean = synth.mean_image(seed=0)
-    imgs, labels = synth.synthetic_cifar(B * ITERS, seed=0)
+    imgs, labels = synth.synthetic_cifar(B * iters, seed=0)
     nhwc = np.ascontiguousarray((imgs - mean).transpose(0, 2, 3, 1))
 
     # single-step gradient agreement (pins every layer's backward)
@@ -264,7 +264,7 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
             rel = np.abs(a - b).max() / max(np.abs(b).max(), 1e-12)
             assert rel < 1e-4, (l, p, rel)
 
-    # 50-iteration recipe trajectory (params checked at two horizons)
+    # the recipe trajectory (params checked at iter 10 and at the end)
     def param_dev():
         worst = 0.0
         for l in np_params:
@@ -296,7 +296,7 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
         if not ok:
             chaos_violations.append(detail)
 
-    for i in range(ITERS):
+    for i in range(iters):
         batch = {"data": nhwc[i * B:(i + 1) * B],
                  "label": labels[i * B:(i + 1) * B, None]}
         params, state, loss = solver.step(params, state, batch)
@@ -314,7 +314,8 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
             chaos_band(rel < 0.20, (i, fw_losses[-1], nl))
         if i + 1 == 10:
             chaos_band(param_dev() < 0.08, ("param_dev@10", param_dev()))
-    chaos_band(param_dev() < 0.25, ("param_dev@50", param_dev()))
+    if iters > 10:
+        chaos_band(param_dev() < 0.25, (f"param_dev@{iters}", param_dev()))
     # training happened (both sides — the oracle moved in lockstep above):
     # params displaced materially from init, not a frozen no-op. The
     # 50-iter loss LEVEL is a chaos-draw property (docstring) — the full
@@ -337,6 +338,26 @@ def test_numpy_oracle_recipe_trajectory(tmp_path):
             f"divergence below the trajectory's one-ulp "
             f"self-sensitivity, not an oracle failure (every hard pin "
             f"above passed)")
+
+
+
+def test_numpy_oracle_recipe_trajectory():
+    """The HARD pins of `_recipe_trajectory`, which need ten iterations and
+    no more: the single-step gradient of every layer at <= 1e-4, the first
+    ten iterations' losses at <= 1e-4, the parameter band at iter 10 and a
+    real displacement from init (5.04 of the most moved weight tensor's
+    own norm at iter 10, against a floor of 0.05). The forty further
+    iterations assert the chaotic-horizon envelope alone (the part that
+    xfails on an adverse conv-tiling draw) and are the slow test below."""
+    _recipe_trajectory(10)
+
+
+@pytest.mark.slow
+def test_numpy_oracle_recipe_trajectory_to_the_chaotic_horizon():
+    """All fifty iterations: the hard pins again, then the envelope over
+    iterations 10 to 49 and the parameter band at iter 50 (154 s of the
+    tier-1 run when it ran there: PR 45)."""
+    _recipe_trajectory(50)
 
 
 def test_parity_synth_round_matches_trainer():

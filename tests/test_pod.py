@@ -361,8 +361,16 @@ def pod_trained(tmp_path_factory):
             scraped["metrics"] = urllib.request.urlopen(
                 f"http://{host}:{port}/metrics", timeout=10).read().decode()
             host, port = cfg.pod_address
-            scraped["pod"] = json.loads(urllib.request.urlopen(
-                f"http://{host}:{port}/pod/status", timeout=10).read())
+            # the loop hands a round's record (and with it the beat) to its
+            # collector a round late and off this thread: ask until the
+            # first beat is on disk (an empty view is never cached)
+            deadline = time.monotonic() + 30.0
+            while True:
+                scraped["pod"] = json.loads(urllib.request.urlopen(
+                    f"http://{host}:{port}/pod/status", timeout=10).read())
+                if scraped["pod"]["n_workers"] or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
             scraped["pod_metrics"] = urllib.request.urlopen(
                 f"http://{host}:{port}/metrics", timeout=10).read().decode()
 

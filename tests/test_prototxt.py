@@ -10,26 +10,7 @@ from sparknet_tpu.model.prototxt import (
     parse_message,
     solver_from_prototxt,
 )
-
-ADULT = """
-name: "adult"
-input: "C0"
-input_shape { dim: 64 dim: 1 }
-layer {
-  name: "ip"
-  type: "InnerProduct"
-  bottom: "C0"
-  top: "ip"
-  param { lr_mult: 1 }
-  param { lr_mult: 2 }
-  inner_product_param {
-    num_output: 10
-    weight_filler { type: "xavier" }
-    bias_filler { type: "constant" }
-  }
-}
-layer { name: "prob" type: "Softmax" bottom: "ip" top: "prob" }
-"""
+from tiny_nets import ADULT
 
 SOLVER = """
 # a comment

@@ -33,7 +33,7 @@ from sparknet_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from sparknet_tpu.solver import SolverConfig
 from sparknet_tpu.utils import checkpoint as ckpt
 
-from test_parallel import TINY_MLP
+from tiny_nets import TINY_MLP
 
 N_DEV = 8
 TAU = 3

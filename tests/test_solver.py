@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from sparknet_tpu import CompiledNet, net_from_prototxt
 from sparknet_tpu.solver import SgdSolver, SolverConfig, learning_rate
-from tests.test_net import CIFARISH
+from tiny_nets import CIFARISH
 
 
 def lr_at(cfg, it):
