@@ -222,6 +222,23 @@ class CompiledNet:
                 eva_core_blocks(layers[0].eva,
                                 self.blob_shapes[layers[0].bottoms[0]][1]))
 
+    def window_scopes(self) -> Tuple[Dict[str, str], Dict[str, dict]]:
+        """({layer type: the scope under such a layer's own that holds its
+        core}, {layer: {"window": its window or None, "blocks_visited",
+        "blocks_causal": `seq_layers.gqa_core_blocks`}}) for this net's
+        layers of `seq_layers.WINDOW_SCOPES`' types, where at least one of
+        them attends under a window; ({}, {}) for a net in which none
+        does."""
+        from .seq_layers import WINDOW_SCOPES, gqa_core_blocks
+        layers = [l for l in self.spec.layers_for_phase("TRAIN")
+                  if l.type in WINDOW_SCOPES]
+        if all(l.gqa.window is None for l in layers):
+            return {}, {}
+        return ({l.type: WINDOW_SCOPES[l.type] for l in layers},
+                {l.name: {"window": l.gqa.window, **gqa_core_blocks(
+                    l.gqa, self.blob_shapes[l.bottoms[0]][1])}
+                 for l in layers})
+
     def routing_scopes(self) -> Tuple[Tuple[str, ...], int]:
         """(the scopes under which this net's expert layers choose experts
         and move rows to and from them, the width of the rows they move:

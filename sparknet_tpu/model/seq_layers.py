@@ -3,17 +3,20 @@ built from.
 
 Embed, RMSNorm (its scale w, or 1 + w), three kinds of softmax attention --
 MLAttention (multi-head latent attention, its queries through a latent or
-projected directly), GQAttention (grouped-query attention with per-head q/k
-norms) and EVAttention (EVA: an exact causal window beside learned summaries
+projected directly), GQAttention (grouped-query attention with or without
+per-head q/k norms and a rotary turn, over every key or over a sliding
+window of a query's last keys) and EVAttention (EVA: an exact causal window beside learned summaries
 of the chunks before it, under one softmax; `ops.eva`) -- KDAttention
 (a linear attention: one matrix state a head, updated by the gated delta
 rule, `ops.delta_rule`), Mamba2 (a state-space mixer: one matrix state a
 head under a scalar decay, `ops.ssd`, of whose heads this chip may hold a
 share, as GQAttention may of its own), ShortConv (a gated short convolution:
 the operator a hybrid decoder sets between its attention layers), GatedMLP
-(SwiGLU), MoE (routed experts of which this chip holds a share, SwiGLU or
-relu^2, in the stream's width or in a latent narrower than it, with or
-without a shared one, chosen among all or among the best groups), MTP (a
+(SwiGLU), MoE (routed experts of which this chip holds a share, SwiGLU,
+ReGLU or relu^2, in the stream's width or in a latent narrower than it, with
+or without a shared one, chosen among all or among the best groups by sigmoid
+scores or by a softmax over the chosen logits, the router fed the experts'
+input or a tensor of its own), MTP (a
 multi-token-prediction module) and Eltwise (the residual sum, in float32
 where the model carries its stream so). Same three
 functions a layer type as `layers.py` (`init_`, `apply_`, `infer_`);
@@ -45,7 +48,8 @@ hands it a mask: a value the layer derives from its own parameters (never a
 run's option), a function of (query positions, key columns) the kernel
 works out tile by tile, skipping the tiles it empties; the key columns may
 then be more than the queries (`eva`: a row's keys and one summary a chunk
-behind them).
+behind them; `gqa` under a `window`: as many keys as queries, a query's last
+`window` of them).
 
 A layer may name a value that is dear to compute again and cheap to keep
 (`KEPT_NAMES`, by layer type): the recomputation block such a layer stands in
@@ -365,6 +369,13 @@ def _splash(heads: int, positions: int, mask=None, interpret: bool = False):
                                   interpret=interpret)
 
 
+def _blocks_visited(heads: int, positions: int, mask=None) -> int:
+    """The key blocks the forward kernel's tables send it to, all query
+    blocks together (the kernel is built once a shape and mask: `_splash`)."""
+    return int(np.count_nonzero(np.asarray(
+        _splash(heads, positions, mask).fwd_mask_info.block_mask)))
+
+
 def attention_core(q, k, v, ctx, mask=None):
     """Causal softmax(q k^T) v over q [rows, heads, positions, d] and k, v
     [rows, key/value heads, positions, d], heads first as the kernel reads
@@ -473,7 +484,10 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
     result as it lies. Without the norms 1/sqrt(d) rides on the view of q's
     weight; without the rotary turn q and k go from product (or norm) to
     core. The heads are those the layer holds (`GQAttentionParam.held`): a
-    share's result is its part of the sum over all heads."""
+    share's result is its part of the sum over all heads. A layer with a
+    `window` shorter than the row hands the core a sliding mask
+    (`ops.attention.SlidingWindowMask`); one at least as long as the row is
+    plain causal attention, as a layer without one."""
     (h, kv), hd, d = p.held(), p.head_dim, x.shape[-1]
     heads_first = "rnc,chd->rhnd"
     w_q = params["q"].reshape(d, h, hd)
@@ -489,8 +503,31 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
     q = shaped(q, lambda: params["q_norm"] / np.sqrt(hd))
     k = shaped(k, lambda: params["k_norm"])
     with jax.named_scope("core"):
-        o = attention_core(q, k, v, ctx)
+        o = attention_core(q, k, v, ctx, gqa_mask(p, x.shape[1]))
     return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
+
+
+def gqa_mask(p: GQAttentionParam, positions: int):
+    """The mask a grouped-query layer hands its core at `positions` a row:
+    None (causal over every key) without a window, or under one that reaches
+    back over the whole row."""
+    if p.window is None or p.window >= positions:
+        return None
+    return attention_ops.SlidingWindowMask(positions, p.window)
+
+
+def gqa_core_blocks(p: GQAttentionParam, positions: int) -> Dict[str, int]:
+    """What one grouped-query layer's core visits at `positions` a row:
+    {"blocks_visited": the key blocks the forward kernel's tables send it to
+    under the layer's mask, all query blocks together, "blocks_causal": those
+    a causal mask over every key sends it to} -- 0 and 0 where the row is no
+    whole tiles (the exact path)."""
+    if positions % max(ATTN_BLOCKS):
+        return {"blocks_visited": 0, "blocks_causal": 0}
+    heads = p.held()[0]
+    return {"blocks_visited": _blocks_visited(heads, positions,
+                                              gqa_mask(p, positions)),
+            "blocks_causal": _blocks_visited(heads, positions, None)}
 
 
 def apply_gqattention(layer: LayerSpec, params: Params, inputs, ctx):
@@ -555,8 +592,7 @@ def eva_core_blocks(p: EVAttentionParam, positions: int) -> Dict[str, int]:
     n, n_kv = mask.shape
     out = {"keys_per_query": n_kv, "blocks_visited": 0, "blocks": 0}
     if n % max(ATTN_BLOCKS) == 0 and n_kv % max(ATTN_BLOCKS) == 0:
-        table = _splash(p.num_heads, n, mask).fwd_mask_info.block_mask
-        out.update(blocks_visited=int(np.count_nonzero(np.asarray(table))),
+        out.update(blocks_visited=_blocks_visited(p.num_heads, n, mask),
                    blocks=(n // ATTN_BLOCKS[0]) * (n_kv // ATTN_BLOCKS[1]))
     return out
 
@@ -762,12 +798,34 @@ def moe_capacity(p: MoEParam, tokens: int, tile: int = GMM_TILING[0]) -> int:
     return -(-rows // tile) * tile
 
 
+#: expert form -> the gate's activation, for the forms of three products a
+#: slot, down(act(gate x) up x)
+GATED_FORMS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
+def _expert_form(p: MoEParam) -> str:
+    if p.expert_form not in (*GATED_FORMS, "relu2"):
+        raise ValueError(f"expert_form {p.expert_form!r} is not built "
+                         f"(swiglu, reglu and relu2 are)")
+    if p.expert_form == "reglu" and p.shared_width():
+        raise ValueError("a shared expert beside reglu experts is not built")
+    return p.expert_form
+
+
+def _score_func(p: MoEParam) -> str:
+    if p.score_func not in ("sigmoid", "softmax_topk"):
+        raise ValueError(f"score_func {p.score_func!r} is not built "
+                         f"(sigmoid and softmax_topk are)")
+    return p.score_func
+
+
 def init_moe_params(key, p: MoEParam, d: int) -> Params:
     ks = jax.random.split(key, 8)
     held, w, ws = p.experts_held[1], p.intermediate_size, p.shared_width()
-    gated, dl = _expert_form(p) == "swiglu", p.latent_size or d
-    out = {"router": _normal(ks[0], (d, p.n_routed_experts), p.std),
-           "router_bias": _normal(ks[1], (p.n_routed_experts,), p.std)}
+    gated, dl = _expert_form(p) in GATED_FORMS, p.latent_size or d
+    out = {"router": _normal(ks[0], (d, p.n_routed_experts), p.std)}
+    if _score_func(p) == "sigmoid":
+        out["router_bias"] = _normal(ks[1], (p.n_routed_experts,), p.std)
     if gated:
         out["experts_gate"] = _normal(ks[2], (held, dl, w), p.std)
     out.update(experts_up=_normal(ks[3], (held, dl, w), p.std),
@@ -782,13 +840,6 @@ def init_moe_params(key, p: MoEParam, d: int) -> Params:
         out.update(shared_up=_normal(ks[6], (d, ws), p.std),
                    shared_down=_normal(ks[7], (ws, d), p.std))
     return out
-
-
-def _expert_form(p: MoEParam) -> str:
-    if p.expert_form not in ("swiglu", "relu2"):
-        raise ValueError(f"expert_form {p.expert_form!r} is not built "
-                         f"(swiglu and relu2 are)")
-    return p.expert_form
 
 
 def init_moe(key, layer: LayerSpec, in_shapes) -> Params:
@@ -841,10 +892,16 @@ def route(p: MoEParam, params: Params, xf):
     routed experts (`n_group` 1) or among those of the `topk_group` groups
     whose two best entries of score + bias sum highest -- weights the chosen
     scores (`chosen_scores`: selected from the scores' columns, not fetched
-    by index) normalised and scaled (`noaux_tc`)."""
-    s = jax.nn.sigmoid(jnp.dot(
-        xf.astype(jnp.float32), params["router"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    by index) normalised and scaled (`noaux_tc`). Under `softmax_topk`: the
+    top k of the float32 logits, weights the softmax over the chosen logits
+    alone (read the same way); no bias, nothing left to normalise or scale."""
+    z = jnp.dot(xf.astype(jnp.float32), params["router"].astype(jnp.float32),
+                precision=lax.Precision.HIGHEST)
+    if _score_func(p) == "softmax_topk":
+        _, idx = lax.top_k(z, p.num_experts_per_tok)
+        return (idx.astype(jnp.int32),
+                jax.nn.softmax(chosen_scores(z, idx), axis=-1))
+    s = jax.nn.sigmoid(z)
     choice = s + lax.stop_gradient(params["router_bias"])
     if p.n_group > 1:
         grouped = choice.reshape(choice.shape[0], p.n_group, -1)
@@ -996,18 +1053,22 @@ sum_by_token.defvjp(
                            (rows, w, plan)), _sum_by_token_bwd)
 
 
-def moe(p: MoEParam, params: Params, x, ctx):
+def moe(p: MoEParam, params: Params, x, ctx, router_x=None):
     """(result [rows, positions, d], counters [len(MOE_COUNTERS)] f32,
     the experts every position chose [rows, positions, k] int32). With a
     latent the rows that travel are the latent's: `latent_down` before the
     dispatch, `latent_up` after the combine (the router's weights applied in
-    the latent); the router and the shared expert read x itself."""
+    the latent); the router and the shared expert read x itself -- or the
+    router `router_x`, where the layer is given one (another tensor of x's
+    shape: the stream as it stood before the attention)."""
     r, n, d = x.shape
     tokens, k = r * n, p.num_experts_per_tok
-    gated = _expert_form(p) == "swiglu"
+    act = GATED_FORMS.get(_expert_form(p))
+    gated = act is not None
     xf = x.reshape(tokens, d)
     with jax.named_scope("router"):
-        idx, w = route(p, params, xf)
+        idx, w = route(p, params, xf if router_x is None
+                       else router_x.reshape(tokens, d))
     lf = xf
     if p.latent_size:
         with jax.named_scope("latent_down"):
@@ -1020,7 +1081,7 @@ def moe(p: MoEParam, params: Params, x, ctx):
         if gated:
             g = _grouped_dot(xs, params["experts_gate"], kept_sizes, ctx)
             u = _grouped_dot(xs, params["experts_up"], kept_sizes, ctx)
-            h = (jax.nn.silu(g.astype(jnp.float32))
+            h = (act(g.astype(jnp.float32))
                  * u.astype(jnp.float32)).astype(g.dtype)
         else:
             h = _relu2(_grouped_dot(xs, params["experts_up"], kept_sizes, ctx))
@@ -1053,7 +1114,9 @@ def infer_moe(layer: LayerSpec, in_shapes):
 
 
 def apply_moe(layer: LayerSpec, params: Params, inputs, ctx):
-    return moe(layer.moe, params, inputs[0], ctx)
+    """One bottom: the experts' input, which the router reads too. Two: the
+    experts' input, then the router's."""
+    return moe(layer.moe, params, inputs[0], ctx, *inputs[1:])
 
 
 # -- MTP ---------------------------------------------------------------------
@@ -1137,6 +1200,9 @@ SSD_SCOPES = {"Mamba2": "ssd"}
 #: layer type -> the named scopes, under the layer's own, of its chunk
 #: summaries and of its core: whose kernels and bytes `obs.device.eva` counts
 EVA_SCOPES = {"EVAttention": ("summaries", "core")}
+#: layer type -> the named scope, under the layer's own, of the core a
+#: sliding window may mask: whose kernel calls `obs.device.window` counts
+WINDOW_SCOPES = {"GQAttention": "core"}
 #: the named scopes, under an expert layer's own (`moe`), whose device ops
 #: `obs.device.routing_moves` counts: the rows they gather and scatter-add
 #: (the buffer's, never tokens x k rows) and the single scalars they fetch or

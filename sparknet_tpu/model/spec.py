@@ -213,7 +213,9 @@ class GQAttentionParam:
     head's `head_dim` on q and on k (one scale vector each, shared by the
     heads; none where `qk_norm` is off), rotary over the whole head on
     contiguous halves (no turn where `rotary` is off: a model whose other
-    layers carry position). Causal.
+    layers carry position). Causal over every key, or -- `window` -- over a
+    query's last `window` keys, itself among them (a sliding window: key j
+    where 0 <= p - j < window).
 
     A layer may hold a SHARE of its heads (tensor parallelism's view from one
     chip): `heads_held` / `kv_heads_held` (first, count) of the published
@@ -231,6 +233,7 @@ class GQAttentionParam:
     qk_norm: bool = True
     heads_held: Optional[Tuple[int, int]] = None
     kv_heads_held: Optional[Tuple[int, int]] = None
+    window: Optional[int] = None
 
     def held(self) -> Tuple[int, int]:
         """(query heads, key/value heads) this layer builds. Every held
@@ -347,8 +350,16 @@ class MoEParam:
     `latent_size` (LatentMoE): the routed experts work in a latent narrower
     than the stream -- x W_down (d -> latent) before the dispatch, W_up
     (latent -> d) after the combine; the router and the shared expert read
-    the stream itself. `expert_form`: "swiglu", down(silu(gate x) up x), or
-    "relu2", down(relu(up x)^2): two products a slot, no gate. The shared
+    the stream itself. `expert_form`: "swiglu", down(silu(gate x) up x),
+    "reglu", down(relu(gate x) up x), or "relu2", down(relu(up x)^2): two
+    products a slot, no gate. `score_func`: "sigmoid" -- sigmoid scores, the
+    top k of score + bias, the chosen scores normalised and scaled
+    (`noaux_tc`) -- or "softmax_topk": the top k of the router's logits
+    themselves and a softmax over the chosen k alone, with no bias (the layer
+    stores no `router_bias`), no normalisation left to do and no scaling. A
+    layer with a second bottom routes on it and feeds its experts the first
+    (a router that reads the stream before the attention its experts follow).
+    The shared
     expert's width is `shared_intermediate_size` where given (else
     `n_shared_experts` x `intermediate_size`), of which the layer builds
     the columns `shared_columns` (first, count) where given: a share whose
@@ -373,7 +384,8 @@ class MoEParam:
     capacity_factor: Optional[float] = None
     std: float = 0.02
     latent_size: Optional[int] = None
-    expert_form: str = "swiglu"  # swiglu | relu2
+    expert_form: str = "swiglu"  # swiglu | reglu | relu2
+    score_func: str = "sigmoid"  # sigmoid | softmax_topk
     shared_intermediate_size: Optional[int] = None
     shared_columns: Optional[Tuple[int, int]] = None
 

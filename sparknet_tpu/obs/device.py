@@ -461,16 +461,20 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "bytes", "kept_bytes"}, "eva": {"layers", "core_forward_calls",
     "core_backward_calls", "keys_per_query", "blocks_visited", "blocks",
     "summary_instructions", "summary_bytes"}, "ssm": {"layers", "loops",
-    "trips", "kernel_calls", "carried_bytes", "instructions", "bytes"}}` — see
+    "trips", "kernel_calls", "carried_bytes", "instructions", "bytes"},
+    "window": {"layers", "windowed_layers", "blocks_visited",
+    "blocks_causal"}}` — see
     `parse_hlo_ops` for the
     attribution rule, `recompute_report` for what the recomputation blocks
     keep ({} for a net whose blocks name nothing, or without blocks),
     `attention_moves` for what a step's attention moves without
     computing, `routing_moves` for what its expert layers move around
     their products, `delta_rule` for how its delta rules were compiled and
-    `eva` for how its EVA attention layers were and `ssm` for how its
-    state-space scans were
-    (each {} for a net without such layers; all five call `moves_under`).
+    `eva` for how its EVA attention layers were, `ssm` for how its
+    state-space scans were and `window` for what its attention cores under
+    sliding windows visit
+    (each {} for a net without such layers; the first five call
+    `moves_under`).
     None when no such
     program is registered or it has not been dispatched yet.
 
@@ -992,10 +996,48 @@ def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
             "summary_bytes": moves["bytes"]}
 
 
+def window(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
+           layers: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
+    """How a program's attention layers under sliding windows were compiled
+    (`scopes`: layer type -> the scope under the layer's own that holds its
+    core; `layers`: layer -> its window, or None, and the key blocks its
+    forward kernel visits under its mask beside those a causal mask over
+    every key would -- both `CompiledNet.window_scopes()`): `{"layers":
+    {layer: {"window", "blocks_visited", "blocks_causal",
+    "core_forward_calls" / "core_backward_calls": the Pallas kernels'
+    `custom-call` instructions under the layer's core scope on a forward
+    path and on a backward one, in the step body that has most (one and one
+    where the kernel ran and the block kept its output; 0 and 0 off the
+    chip)}}, "windowed_layers": those with a window, "blocks_visited" /
+    "blocks_causal": all the layers' together}`. {} for a net none of whose
+    layers attends under a window."""
+    if not scopes:
+        return {}
+    calls: Dict[str, Dict[str, Dict[str, int]]] = {}  # layer -> body -> phase
+    for op in ops.values():
+        if (op.get("pallas") and op["layer"] in layers
+                and scopes.get(op["layer_type"]) in op["scope"].split("/")):
+            body = calls.setdefault(op["layer"], {}).setdefault(
+                op["computation"], {})
+            body[op["phase"]] = body.get(op["phase"], 0) + 1
+    out = {}
+    for name, given in layers.items():
+        most = max(calls.get(name, {}).values(),
+                   key=lambda b: sum(b.values()), default={})
+        out[name] = {**given, "core_forward_calls": most.get("forward", 0),
+                     "core_backward_calls": most.get("backward", 0)}
+    return {"layers": out,
+            "windowed_layers": sum(l["window"] is not None
+                                   for l in layers.values()),
+            **{key: sum(l[key] for l in layers.values())
+               for key in ("blocks_visited", "blocks_causal")}}
+
+
 def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
                        routing=((), 0), delta=({}, ()),
-                       eva_layers=({}, None), ssd=None) -> Dict[str, Any]:
+                       eva_layers=({}, None), ssd=None,
+                       windows=({}, {})) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
     names its net's recomputation blocks keep `recompute_report`, for
@@ -1004,8 +1046,10 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
     scopes and the model's width) `routing_moves`, for its delta-rule
     layers (`delta`: their scopes and the names their blocks keep)
     `delta_rule`, for its EVA attention layers (`eva_layers`: their
-    scopes and what a core is given) `eva`, and for its state-space mixers
-    (`ssd`: their scans' scopes) `ssm`."""
+    scopes and what a core is given) `eva`, for its state-space mixers
+    (`ssd`: their scans' scopes) `ssm`, and for its attention layers under
+    sliding windows (`windows`: their cores' scopes and what each visits)
+    `window`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
@@ -1017,12 +1061,13 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
             "delta_rule": delta_rule(ops, delta[0], sum(
                 _named_bytes(jaxpr, name) for name in delta[1]
                 if jaxpr is not None)),
-            "eva": eva(ops, *eva_layers), "ssm": ssm(ops, ssd or {})}
+            "eva": eva(ops, *eva_layers), "ssm": ssm(ops, ssd or {}),
+            "window": window(ops, *windows)}
 
 
 #: program -> these parts of its report, once `program_report` has run
 REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves",
-                "delta_rule", "eva", "ssm")
+                "delta_rule", "eva", "ssm", "window")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
@@ -1069,6 +1114,17 @@ def attach_program_gauges(registry: MetricsRegistry,
             f"{what}, of the {name} program's EVA attention layers (read "
             f"by program_report)"
         ).set_fn(lambda key=key: part("eva")[key])
+    for key, what in (("windowed_layers", "layers that attend under a sliding "
+                       "window"),
+                      ("blocks_visited", "key blocks the cores' forward "
+                       "kernels visit under their masks, all layers"),
+                      ("blocks_causal", "key blocks a causal mask over every "
+                       "key would send them to")):
+        registry.gauge(
+            f"sparknet_{name}_window_{key}",
+            f"{what}, of the {name} program's grouped-query attention layers "
+            f"(read by program_report)"
+        ).set_fn(lambda key=key: part("window")[key])
     for key, what in (("layers", "layers that hold a scan"),
                       ("loops", "device loops of the scans, the whole program"),
                       ("trips", "trips of those loops together"),

@@ -9,6 +9,7 @@ stable online-softmax block accumulator shared by the ring pass.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -16,6 +17,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import precision
+
+
+@dataclasses.dataclass(frozen=True)
+class SlidingWindowMask:
+    """Which keys a query reads under a sliding window: its own position and
+    the `window` - 1 before it. A function of (query positions, key columns)
+    that broadcasts, over numpy arrays (a table made ahead) and over traced
+    ones (inside a kernel) alike; hashable, so a kernel built for it is
+    built once (as `ops.eva.WindowSummaryMask`, and used as it is)."""
+
+    positions: int
+    window: int
+
+    @property
+    def shape(self):
+        """(queries, key columns)."""
+        return (self.positions, self.positions)
+
+    def __call__(self, q_ids, kv_ids):
+        return (kv_ids <= q_ids) & (q_ids - kv_ids < self.window)
+
+    def dense(self):
+        """[queries, key columns] bool, for the exact path."""
+        n = np.arange(self.positions)
+        return self(n[:, None], n[None, :])
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,

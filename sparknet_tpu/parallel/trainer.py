@@ -895,7 +895,8 @@ class ParallelTrainer:
                 traced.lower().compile(), self.net.kept_makers(),
                 traced.jaxpr.jaxpr, self.net.attention_scopes(),
                 self.net.routing_scopes(), self.net.delta_scopes(),
-                self.net.eva_scopes(), self.net.ssd_scopes())
+                self.net.eva_scopes(), self.net.ssd_scopes(),
+                self.net.window_scopes())
         return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":

@@ -7,7 +7,7 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and five families of sequence models, each built from a file of its
+and six families of sequence models, each built from a file of its
 published config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
@@ -34,6 +34,13 @@ published config:
                       relu^2 experts in a latent narrower than the stream;
                       a multi-token-prediction module built of the same
                       layer types)
+  - smallthinker   <- huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct
+                      config.json (every layer an expert layer whose router
+                      reads the stream BEFORE the attention; grouped-query
+                      attention by the config's two layouts: global without
+                      a rotary turn, or over a sliding window with one;
+                      ReGLU experts weighted by a softmax over the chosen
+                      logits; no dense layer, no shared expert)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -778,8 +785,109 @@ def nemotron_h(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def smallthinker(config: dict, rows: int, positions: int) -> NetSpec:
+    """A `smallthinker` decoder (SmallThinker-21BA3B) as ONE CHIP'S SHARE of
+    an expert-parallel deployment, for training on `[rows, positions]` int32
+    token ids (input `tokens`; the targets are the ids themselves, read one
+    position on).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- `num_hidden_layers` layers, each grouped-query attention and then
+    routed experts; `sliding_window_layout[i]` 1: layer i attends over its
+    last `sliding_window_size` keys, 0: over every key; `rope_layout[i]` 1:
+    q and k take the rotary turn, 0: none; `moe_num_primary_experts` experts
+    HELD in each layer, `moe_num_active_primary_experts` chosen a token,
+    `vocab_size` rows of the vocabulary HELD -- and a `share` block as
+    `lfm2_moe`'s: `moe_num_primary_experts` (the published count: the
+    router's width), `experts_held` [first, count], `vocab_rows` [first,
+    count], `chips_sharing_a_layer`, optionally `capacity_factor`. Every matrix starts
+    normal(0, 0.02); `embed_init_std`, where the file gives one, is the
+    embedding table's spread instead.
+
+    A layer: n = RMSNorm(x); h = x + GQA(n); x' = h + Experts(RMSNorm(h)),
+    the experts chosen by a router that reads n -- the stream before the
+    attention -- weighted by a softmax over the chosen logits, each
+    (relu(m W_gate) * m W_up) W_down. No dense layer, no shared expert. An
+    untied head over the held vocabulary rows. Loss = CE(next token), a mean
+    over the positions that have a target. Every layer is a recomputation
+    block."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["rms_norm_eps"], 0.02
+    depth = c["num_hidden_layers"]
+    windows, turns = c["sliding_window_layout"], c["rope_layout"]
+    first, held = share["experts_held"]
+    vocab = share["vocab_rows"][1]
+    if held != c["moe_num_primary_experts"] or vocab != c["vocab_size"]:
+        raise ValueError("the share block and the held counts disagree: "
+                         f"experts_held {share['experts_held']} against "
+                         f"moe_num_primary_experts "
+                         f"{c['moe_num_primary_experts']}, vocab_rows "
+                         f"{share['vocab_rows']} against vocab_size "
+                         f"{c['vocab_size']}")
+    for key, layout in (("sliding_window_layout", windows),
+                        ("rope_layout", turns)):
+        if len(layout) != depth or set(layout) - {0, 1}:
+            raise ValueError(f"{key} {layout} does not say 0 or 1 for each "
+                             f"of the {depth} layers")
+    if not c.get("moe_primary_router_apply_softmax", False):
+        raise ValueError("a router without the softmax over its chosen "
+                         "logits (moe_primary_router_apply_softmax false: "
+                         "the sigmoid form) is not built")
+    if c.get("tie_word_embeddings") or c.get("rope_scaling"):
+        raise ValueError("a tied head, or scaled rotary frequencies, is not "
+                         "built")
+    attention = lambda i: GQAttentionParam(
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rope_theta=float(c["rope_theta"]), eps=eps, std=std, qk_norm=False,
+        rotary=bool(turns[i]),
+        window=c["sliding_window_size"] if windows[i] else None)
+    experts = MoEParam(
+        n_routed_experts=share["moe_num_primary_experts"],
+        experts_held=(first, held),
+        num_experts_per_tok=c["moe_num_active_primary_experts"],
+        intermediate_size=c["moe_ffn_hidden_size"], n_shared_experts=0,
+        score_func="softmax_topk", expert_form="reglu",
+        capacity_factor=share.get("capacity_factor"), std=std)
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",),
+                        embed=EmbedParam(num_embeddings=vocab, dim=d,
+                                         std=c.get("embed_init_std", std)))]
+    for i in range(depth):
+        l, x, moe = f"l{i}", f"x{i}", f"l{i}_moe"
+        layers += [
+            norm(f"{l}_op_norm", x, l),
+            LayerSpec(name=f"{l}_attn", type="GQAttention",
+                      bottoms=(f"{l}_op_norm",), tops=(f"{l}_attn",),
+                      gqa=attention(i), block=l),
+            _sum_layer(f"{l}_op_res", x, f"{l}_attn", f"{l}_h", l),
+            norm(f"{l}_mlp_norm", f"{l}_h", l),
+            # the experts read the norm after the attention, the router the
+            # one before it
+            LayerSpec(name=moe, type="MoE",
+                      bottoms=(f"{l}_mlp_norm", f"{l}_op_norm"),
+                      tops=(moe, f"{moe}_counters", f"{moe}_chosen"),
+                      moe=experts, block=l),
+            _sum_layer(f"{l}_mlp_res", f"{l}_h", moe, f"x{i + 1}", l)]
+    layers += [
+        norm("final_norm", f"x{depth}", "head"),
+        LayerSpec(name="lm_head", type="InnerProduct", bottoms=("final_norm",),
+                  tops=("lm_head",), block="head",
+                  inner_product=InnerProductParam(
+                      num_output=vocab, bias_term=False, axis=-1,
+                      weight_filler=_GAUSS(std))),
+        LayerSpec(name="loss", type="SoftmaxWithLoss",
+                  bottoms=("lm_head", "tokens"), tops=("loss",), block="head",
+                  loss=LossParam(label_shift=1))]
+    return NetSpec(name="smallthinker",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
 SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe,
                    "ling3_flash": ling3_flash, "evabyte": evabyte,
-                   "nemotron_h": nemotron_h}
+                   "nemotron_h": nemotron_h, "smallthinker": smallthinker}

@@ -6,7 +6,7 @@ nothing of the program) at one tiny size on the CPU: 2 rows of 32 positions,
 hidden 64. A model is a `Case`: its `model_type`, the configuration whose
 reference it reads, its tiny file and the keywords of the reference's weight
 draw. The suites (`test_seq_*.py` for GLM, `test_lfm2.py`, `test_ling.py`,
-`test_evabyte.py`, `test_nemotron_h.py`) stay apart -- `--dist loadfile`
+`test_evabyte.py`, `test_nemotron_h.py`, `test_smallthinker.py`) stay apart -- `--dist loadfile`
 balances by file -- and each calls the three checks every model repeats:
 
 * `check_layer`: one layer of the program against the reference's, a row at
@@ -145,6 +145,22 @@ TINY = {
                   "mamba_groups_held": [2, 2], "attention_heads_held": [2, 2],
                   "kv_heads_held": [1, 1], "shared_columns": [24, 24],
                   "vocab_rows": [0, 256], "first_layer": 3, "mtp_loss_weight": 0.1}},
+    #: hidden 64, 14 query heads of 16 over 2 key/value heads (7 a group), a
+    #: window of 8 of the 32 positions, 8 experts of width 48 of which the 2 best
+    #: a token and 2 are held (experts 2 and 3), vocabulary 256; a global layer
+    #: without a rotary turn, then a sliding layer with one
+    "smallthinker": {
+        "model_type": "smallthinker", "hidden_size": 64, "head_dim": 16,
+        "num_attention_heads": 14, "num_key_value_heads": 2,
+        "moe_ffn_hidden_size": 48, "moe_num_primary_experts": 2,
+        "moe_num_active_primary_experts": 2,
+        "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+        "num_hidden_layers": 2, "sliding_window_layout": [0, 1],
+        "rope_layout": [0, 1], "sliding_window_size": 8, "rope_theta": 1500000,
+        "rope_scaling": None, "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
+        "max_position_embeddings": 32, "vocab_size": 256, "seq_len": 32,
+        "share": {"chips_sharing_a_layer": 4, "moe_num_primary_experts": 8,
+                  "experts_held": [2, 2], "vocab_rows": [0, 256], "first_layer": 0}},
 }
 
 #: model -> (the configuration whose reference it reads, the keywords of that
@@ -157,6 +173,7 @@ _CONFIGS = {
     "ling3_flash": ("ling3-flash-ep64-tau4", {}),
     "evabyte": ("evabyte-l4-tau4", {"std": 0.05}),
     "nemotron_h": ("nemotron3-super-tp4-ep64-tau4", {"std": 0.16}),
+    "smallthinker": ("smallthinker-21b-ep4-tau4", {}),
 }
 
 

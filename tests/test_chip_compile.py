@@ -965,3 +965,42 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # rows and add them by token three times (the combine, the combine made
     # again for `latent_up`'s weight gradient, the dispatch's backward)
     _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3, k=22)
+
+
+@pytest.mark.slow
+def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The sliding-window model's round (`smallthinker-21b-ep4-tau4`: one
+    global grouped-query attention without a rotary turn and three over a
+    sliding window of 4,096, 28 query heads over 4, four expert layers of 16
+    held ReGLU experts behind a router that reads the stream before the
+    attention, an untied head over 37,984 rows; ONE row of 16,384 positions)
+    for one described chip: 5.25 GB of state (656,529,920 parameters and
+    their momentum) and the round's temporaries under the chip's 16 GB. The
+    four cores run as kernels at seven heads a group (no [.., 16384, 16384]
+    scores), once a step body on its forward path alone, and the sliding
+    cores' tables send them to 140 key blocks where the global core's sends
+    it to 272."""
+    compiled, trainer = _sequence_round(v5e, "smallthinker-21b-ep4-tau4")
+    total = _round_bytes(compiled)
+    assert total < 14.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "gmm" in text and "16384,16384" not in text
+    from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
+                                         recompute_report, window)
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_makers())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (4, 0)
+    moves = attention_moves(ops, *trainer.net.attention_scopes())
+    assert moves["gathers_scatters"] == 0, moves
+    part = window(ops, *trainer.net.window_scopes())
+    assert (part["windowed_layers"], part["blocks_visited"],
+            part["blocks_causal"]) == (3, 272 + 3 * 140, 4 * 272), part
+    for name, layer in part["layers"].items():
+        assert (layer["core_forward_calls"], layer["core_backward_calls"]) == (1, 1), name
+        assert layer["blocks_visited"] == (272 if layer["window"] is None else 140)
+    from sparknet_tpu.model.seq_layers import moe_capacity
+    rows = moe_capacity(trainer.net.spec.layer_by_name("l0_moe").moe, 16384)
+    assert rows == 61440
+    # four expert layers: the k = 6 gathers' side (4 x 61,440 > 6 x 16,384)
+    _routing_walks_rows(text, ops, trainer, 4 * 2, rows, k=6)

@@ -363,3 +363,49 @@ def test_profile_dir_capture_holds_the_loops_spans(tmp_path):
     assert {"round_prep", "sample", "cast", "train_round", "dispatch"} <= chrome
     # the profiled run is the one place the loop asks for the round's memory
     assert "train_round program, bytes per device: argument " in log_path.read_text()
+
+
+def test_the_window_part_of_a_compiled_text():
+    """`obs.device.window` on a made-up compiled text: every layer's kernel
+    calls by phase under its own core scope, in the step body that has most;
+    the blocks a layer's mask visits of those a causal one would, passed
+    through and summed; a layer with no window reports none; a net none of
+    whose layers has one reports nothing at all, and its gauges have no
+    sample."""
+    from sparknet_tpu.model import seq_layers as sl
+    from sparknet_tpu.obs import MetricsRegistry
+    call = ('custom-call(%p), custom_call_target="tpu_custom_call", '
+            'metadata={op_name="jit(train_round)/tau_step/')
+    text = "\n".join([
+        "ENTRY %main.1 (p: f32[4]) -> f32[4] {",
+        "  %p = f32[4]{0} parameter(0)",
+        f"  %fwd.1 = f32[4]{{0}} {call}jvp(GQAttention/l0_attn)/core/splash_mha_fwd/pallas_call\"}}",
+        f"  %fwd.2 = f32[4]{{0}} {call}jvp(GQAttention/l1_attn)/core/splash_mha_fwd/pallas_call\"}}",
+        f"  %bwd.2 = f32[4]{{0}} {call}transpose(jvp(GQAttention/l1_attn))/core/splash_mha_dkv/pallas_call\"}}",
+        f"  %gmm.1 = f32[4]{{0}} {call}jvp(MoE/l1_moe)/experts/gmm/pallas_call\"}}",
+        '  %sum.1 = f32[4]{0} add(%fwd.1, %fwd.2), metadata={op_name="jit(train_round)/tau_step/jvp(GQAttention/l0_attn)/core/add"}',
+        "  ROOT %out = f32[4]{0} add(%sum.1, %bwd.2)",
+        "}"])
+    ops = obs_device.parse_hlo_ops(text)
+    layers = {"l0_attn": {"window": None, "blocks_visited": 272, "blocks_causal": 272},
+              "l1_attn": {"window": 4096, "blocks_visited": 140, "blocks_causal": 272}}
+    got = obs_device.window(ops, sl.WINDOW_SCOPES, layers)
+    assert got == {
+        "layers": {"l0_attn": {**layers["l0_attn"], "core_forward_calls": 1,
+                               "core_backward_calls": 0},
+                   "l1_attn": {**layers["l1_attn"], "core_forward_calls": 1,
+                               "core_backward_calls": 1}},
+        "windowed_layers": 1, "blocks_visited": 412, "blocks_causal": 544}
+    assert obs_device.window(ops, {}, {}) == {}
+    assert "window" in obs_device.REPORT_PARTS
+    registry = MetricsRegistry()
+    obs_device.attach_program_gauges(registry, "w_round")
+    obs_device._program_parts["w_round"] = {"window": got}
+    try:
+        assert registry.gauge("sparknet_w_round_window_blocks_visited").value() == 412
+        assert registry.gauge("sparknet_w_round_window_blocks_causal").value() == 544
+        assert registry.gauge("sparknet_w_round_window_windowed_layers").value() == 1
+        obs_device._program_parts["w_round"] = {"window": {}}
+        assert "\nsparknet_w_round_window_blocks_visited " not in registry.render_prometheus()
+    finally:
+        del obs_device._program_parts["w_round"]
