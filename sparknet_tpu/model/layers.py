@@ -17,6 +17,7 @@ native Caffe (see reference `libs/CaffeNet.scala:91,118`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import zlib
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -25,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.pooling import caffe_pool_output_size, global_pool2d, pool2d
 from ..ops.lrn import lrn as lrn_op
@@ -332,12 +334,17 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
     x, w, mm_precision, mm_out = resolve_weight(params, x, ctx)
     if layer.inner_product.float32_out:
         mm_out = jnp.float32
-    if layer.inner_product.transposed:  # a tied head: x @ w^T, w (out, in)
-        y = jnp.einsum("...k,nk->...n", x, w, precision=mm_precision,
-                       preferred_element_type=mm_out)
-    else:
-        y = jnp.dot(x, w, precision=mm_precision,
-                    preferred_element_type=mm_out)
+    # in a recomputation block the product runs under a scope of its own
+    # (`seq_layers.KEPT_MAKERS`: how a compiled program's text tells it from
+    # one made again) and the layer's result is named below
+    kept = layer.block is not None
+    with jax.named_scope(IP_OUT) if kept else contextlib.nullcontext():
+        if layer.inner_product.transposed:  # a tied head: x @ w^T, w (out, in)
+            y = jnp.einsum("...k,nk->...n", x, w, precision=mm_precision,
+                           preferred_element_type=mm_out)
+        else:
+            y = jnp.dot(x, w, precision=mm_precision,
+                        preferred_element_type=mm_out)
     if "b" in params:
         y = y + params["b"].astype(y.dtype)
     if ctx.tp_shards(layer):
@@ -346,6 +353,18 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
         # downstream layers see the logical blob. autodiff turns the gather
         # into the matching reduce-scatter of the cotangent.
         y = jax.lax.all_gather(y, ctx.tp_axis, axis=y.ndim - 1, tiled=True)
+    if kept:
+        # the block keeps the layer's result as the product made it, in its
+        # own dtype (`seq_layers.KEPT_NAMES`): a head's logits are the
+        # largest product of their model, and the block that holds them
+        # reads them back where it would make them a second time. Under
+        # tensor parallelism the GATHERED side is named, the value the rest
+        # of the block reads: a name on this device's columns would keep
+        # 1/m of the bytes and leave the forward made again a second
+        # all_gather to run; named here, the backward pass holds neither
+        # the product nor the gather twice (the gather's own transpose, the
+        # reduce-scatter of the cotangent, is backward proper and stays)
+        y = checkpoint_name(y, IP_OUT)
     return (y,)
 
 
@@ -497,6 +516,7 @@ LAYER_IMPLS = {
     "Flatten": (None, apply_flatten, infer_flatten),
 }
 
-from .seq_layers import SEQ_LAYER_IMPLS, param_defaults, shifted  # noqa: E402
+from .seq_layers import (IP_OUT, SEQ_LAYER_IMPLS, param_defaults,  # noqa: E402
+                         shifted)
 
 LAYER_IMPLS.update(SEQ_LAYER_IMPLS)

@@ -310,9 +310,9 @@ class CompiledNet:
             if train and layers[0].block is not None:
                 # one recomputation block: the backward pass keeps its
                 # inputs, its parameters and the values its layers name
-                # (an attention core's output and statistics), and computes
-                # the rest of it again; a block that names nothing gets the
-                # bare `jax.checkpoint`
+                # (an attention core's output and statistics, a head's
+                # logits), and computes the rest of it again; a block that
+                # names nothing gets the bare `jax.checkpoint`
                 needs = {b: blobs[b] for l in layers for b in l.bottoms
                          if b in blobs}
                 owners = {l.param_from or l.name for l in layers}

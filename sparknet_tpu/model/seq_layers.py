@@ -67,7 +67,9 @@ runs the same arithmetic at a fraction of the width inside a block whose
 memory the routed experts decide, names nothing. In training the layer also
 sets its input behind an `optimization_barrier`, so that the norm before it
 is a value the backward pass reads and not one its products make again
-(`apply_gatedmlp`).
+(`apply_gatedmlp`). An `InnerProduct` that stands in a block (a head, with
+its norm and loss) names its result, the logits: its block makes the
+model's largest product once a step (`layers.apply_innerproduct`).
 """
 from __future__ import annotations
 
@@ -137,6 +139,10 @@ MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
 ATTN_CORE = "attn_core"
 KDA_OUT = "kda_out"
 MLP_PRE = "mlp_pre"
+#: that on the result of an `InnerProduct` that stands in a recomputation
+#: block (`layers.apply_innerproduct`: a head's logits), and the named scope
+#: its product runs under there
+IP_OUT = "ip_out"
 
 
 def param_defaults(pname: str) -> ParamSpec:
@@ -1175,17 +1181,38 @@ COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
 #: layer's shared expert (`moe`, through `_swiglu`) names nothing: it is a
 #: fraction of this layer's width, and its block's memory and XLA's schedule
 #: of the routed products around it stay what they were (PERF.md section 6,
-#: PR 41)
+#: PR 41).
+#: InnerProduct (`layers.apply_innerproduct`) names its RESULT wherever the
+#: layer stands in a recomputation block, and nowhere else (CaffeNet's fc6-fc8
+#: stand in none and trace to the program they were): in the sequence models
+#: that is every head -- the [tokens x vocabulary-share] product, the largest
+#: single matmul of each model, which the bare block its norm and loss shared
+#: with it made four times a head a step (forward, forward again, dX, dW) --
+#: and the 8,192 -> 4,096 projection into Nemotron's MTP module. Kept as
+#: made, in the product's own dtype (bf16 under the bf16 policy, float32
+#: where `float32_out` says so), the bits the second product would have made;
+#: the final norm and the loss's float32 passes are still made again, from
+#: the block's input and the kept logits. Bytes kept a step at 16,384 tokens:
+#: SmallThinker 1,245 MB (37,984 columns), Ling 644 (19,648), LFM2 537
+#: (16,384, on the table's matrix: `transposed`), EvaByte 168 (8 x 320,
+#: float32), GLM 2 x 634 (19,360) and Nemotron 2 x 537 + 134 (16,384; the
+#: second head of both runs on the first's matrix, `param_from`). The head's
+#: block is the LAST of the forward pass -- its backward starts the moment
+#: its forward ends -- so in the one-head models the kept logits occupy what
+#: the recomputed ones would at the same moment and the round's memory is
+#: what it was; in the two-head models the main head's logits live across the
+#: MTP module (PERF.md section 6, PR 47)
 KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
               "GQAttention": (ATTN_CORE,), "EVAttention": (ATTN_CORE,),
-              "KDAttention": (KDA_OUT,), "GatedMLP": (MLP_PRE,)}
+              "KDAttention": (KDA_OUT,), "GatedMLP": (MLP_PRE,),
+              "InnerProduct": (IP_OUT,)}
 #: kept name -> what marks the device ops that make its values in a compiled
 #: program's text, a part of their scope: the name of the Pallas kernel
 #: (matched as a prefix: `splash_mha_fwd_residuals`), or the named scope the
 #: layer runs its plain products under. Such an op on a recomputed path
 #: (`rematted_computation`) means the name did not reach its block's policy
 #: (a kept value whose making leaves no such mark has no entry)
-KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE}
+KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE, IP_OUT: IP_OUT}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts

@@ -446,8 +446,8 @@ class LayerSpec:
     #: block: in training the backward pass keeps the block's inputs and the
     #: values its layers' implementations name as dear to compute again
     #: (`seq_layers.KEPT_NAMES`: an attention core's output and softmax
-    #: statistics, a dense SwiGLU's two input products), and computes the
-    #: rest of its insides again
+    #: statistics, a dense SwiGLU's two input products, an InnerProduct's
+    #: result -- a head's logits), and computes the rest of its insides again
     #: (`jax.checkpoint`, with a policy only where something is named)
     block: Optional[str] = None
 

@@ -42,6 +42,7 @@ import pytest
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model.layers import ApplyCtx
 from sparknet_tpu.model.net import CompiledNet
+from sparknet_tpu.model.seq_layers import IP_OUT
 from sparknet_tpu.model.spec import (InputSpec, LayerSpec, MLAttentionParam,
                                      MoEParam, NetSpec, RMSNormParam)
 
@@ -401,6 +402,29 @@ def program_round(model, trainer, params, ids):
         "update_norms": flat(lambda l, n: norm(state.params[l][n][0] - params[l][n])),
         "momentum_norms": [flat(lambda l, n: norm(state.momentum[l][n][0]))],
         "probe": [np.asarray(state.momentum[layer][leaf][0])]}
+
+
+#: model -> the columns of what its blocks' `InnerProduct`s make a position
+#: (`seq_layers.IP_OUT`), a product each: a head's vocabulary (GLM's and
+#: Nemotron's second head runs on the first's matrix, `param_from`; LFM2's on
+#: the table's, `transposed`; EvaByte's one product makes its three heads'
+#: 32 ids each, in float32 whatever the policy), and the projection into
+#: Nemotron's MTP module, as wide as the stream
+BLOCK_PRODUCTS = {
+    "glm4_moe_lite": (256, 256), "lfm2_moe": (256,), "ling3_flash": (256,),
+    "evabyte": (3 * 32,), "nemotron_h": (256, 256, D), "smallthinker": (256,)}
+
+
+def check_products_kept(model, report, tau, rows=ROWS):
+    """A round's account of what its blocks' `InnerProduct`s keep
+    (`program_report("train_round")["recompute"]`): every product on the
+    forward path (off the chip the scan over `tau` steps is unrolled into
+    one step body), none made again, and a step's float32 results kept
+    whole."""
+    columns = BLOCK_PRODUCTS[model]
+    assert report["recompute"][IP_OUT] == {
+        "maker": IP_OUT, "step_bodies": 1, "forward": tau * len(columns),
+        "backward": 0, "kept_bytes": rows * POS * sum(columns) * 4}
 
 
 def check_round(got, want, rel):

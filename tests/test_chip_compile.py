@@ -761,25 +761,32 @@ def _sequence_round(v5e, config: str):
 
 
 def _round_bytes(compiled) -> int:
+    """State + temporaries of a compiled round, printed as read (`-rP`
+    shows a passing test's)."""
     mem = compiled.memory_analysis()
-    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"round: {total / 1e9:.2f} GB, of which temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f}")
+    return total
 
 
-def _dense_products_made_once(text: str, kept, layers: int) -> None:
-    """The round's `layers` dense SwiGLUs (`GatedMLP`) keep their two input
-    products: two a layer on a step body's forward path, none on a
+def _products_made_once(text: str, kept, name: str, kind: str, n: int) -> None:
+    """The round's `n` products under the kept name `name`, all in layers
+    of type `kind`, run once: `n` on a step body's forward path, none on a
     recomputed one, and no instruction of the compiled text, inside a fusion
-    or out, comes from a `dot_general` under a recomputed `GatedMLP` scope
-    (the down projection was never made twice; 2 a layer and step body
-    before PR 41)."""
-    import re
-    pre = kept["mlp_pre"]
-    assert pre["step_bodies"] == 2, pre  # the loop's, the peeled
-    assert (pre["forward"], pre["backward"]) == (2 * layers, 0), pre
-    assert re.search(r'op_name="[^"]*/GatedMLP/[^"]*dot_general"', text)
+    or out, comes from a `dot_general` under a recomputed `kind` scope.
+    `mlp_pre` / `GatedMLP`: a dense SwiGLU's two input products a layer (the
+    down projection was never made twice; 2 a layer and step body made again
+    before PR 41). `ip_out` / `InnerProduct`: the heads' logits, the largest
+    product of each model, and Nemotron's MTP projection (one a head and
+    step body made again before PR 47)."""
+    made = kept[name]
+    assert made["step_bodies"] == 2, made  # the loop's, the peeled
+    assert (made["forward"], made["backward"]) == (n, 0), made
+    assert re.search(rf'op_name="[^"]*/{kind}/[^"]*dot_general"', text)
     again = re.findall(
-        r'op_name="[^"]*rematted_computation/GatedMLP/[^"]*dot_general"', text)
+        rf'op_name="[^"]*rematted_computation/{kind}/[^"]*dot_general"', text)
     assert not again, again[:2]
 
 
@@ -787,7 +794,10 @@ def _dense_products_made_once(text: str, kept, layers: int) -> None:
 def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`) for one
     described chip: ~2 min. 5.65 GB of state
-    + ~6.1 GB of temporaries (the gradient is 2.83 GB of them; what the six
+    + 5.65 GB of temporaries = 11.30 GB since PR 47, whose head blocks keep
+    their logits (2 x 634 MB a step) and make no head's product twice: the
+    compiler packs the round 0.4 GB TIGHTER than the 6.05 GB it took with
+    both heads' products made again (the gradient is 2.83 GB of them; what the six
     attention cores keep for the backward 1.01 GB, and their statistics
     as the kernel writes them, padded to 128 lanes, 1.0 GB more; since PR 41
     the dense block's two SwiGLU input products, 0.67 GB kept, which put
@@ -795,7 +805,8 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     while q, k and v were laid out again between projection and core). Each
     step body runs the cores' forward kernel on its forward path alone, makes
     no product of the dense SwiGLU twice, and no gather or scatter in its
-    attention touches an activation."""
+    attention touches an activation; both heads' products run once a step
+    body (`ip_out` 2 forward, 0 on a recomputed path)."""
     compiled, trainer = _sequence_round(v5e, "glm47-flash-ep8-tau4")
     total = _round_bytes(compiled)
     assert total < 12.0e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
@@ -807,7 +818,8 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (6, 0)
-    _dense_products_made_once(text, kept, 1)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 2)
+    _products_made_once(text, kept, "mlp_pre", "GatedMLP", 2)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves  # 96 before PR 30
     assert moves["bytes"] < 57e9, moves  # 94.9 GB a step body before, 42.8 now
@@ -823,9 +835,12 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     short convolutions, two grouped-query attentions at head width 64, eight
     expert layers of width 1,792, a tied head) for one described chip: 7.37
     GB of state (921,256,448 parameters and their momentum) and the round's
-    temporaries under the chip's 16 GB beside the benchmark's stacks. The two
+    temporaries (4.52 GB: 11.89 together, PR 47's kept logits of the tied
+    head, 537 MB a step, moved nothing: the head's block is the last of the
+    forward pass) under the chip's 16 GB beside the benchmark's stacks. The two
     attention cores run as kernels with grouped heads (no [.., 8192, 8192]
-    scores), once a step body on its forward path alone."""
+    scores), once a step body on its forward path alone, and the head's
+    product once."""
     compiled, trainer = _sequence_round(v5e, "lfm2-8b-a1b-ep4-tau4")
     total = _round_bytes(compiled)
     assert total < 14.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
@@ -837,7 +852,8 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (2, 0)
-    _dense_products_made_once(text, kept, 1)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 1)
+    _products_made_once(text, kept, "mlp_pre", "GatedMLP", 2)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     _routing_walks_rows(text, ops, trainer, 8 * 2, 32768)
@@ -850,7 +866,8 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     six expert layers behind a 512-wide group-limited router, an untied
     head) for one described chip (~4 min): 6.58 GB of state (822,036,416
     parameters and their momentum) + 6.73 GB of temporaries: 13.30 GB (the
-    gradient is 3.29 of the temporaries; 6.57 before PR 44 -- one packing
+    same with PR 47's kept logits, 644 MB a step, whose product runs once;
+    the gradient is 3.29 of the temporaries; 6.57 before PR 44 -- one packing
     of the compiler's that every form of that PR's expert layer left, with
     the lone layer's own temporaries 50 MB lower: PERF.md section 6 --;
     6.49 before PR 43, whose weighted
@@ -876,7 +893,8 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (1, 0)
-    _dense_products_made_once(text, kept, 1)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 1)
+    _products_made_once(text, kept, "mlp_pre", "GatedMLP", 2)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     scopes, kept_names = trainer.net.delta_scopes()
@@ -902,7 +920,8 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     of state (821,366,784 parameters and their momentum) and the round's
     temporaries under 15 GB together (14.44 since PR 41: the four blocks keep
     their SwiGLUs' two input products, 2.89 GB a step, which put 1.29 GB on
-    the 6.58 GB of temporaries the round took before). Every core is ONE
+    the 6.58 GB of temporaries the round took before; the same with PR 47's
+    kept float32 logits of the eight heads, 168 MB a step). Every core is ONE
     kernel call forward a layer-step over 17,408 key columns (the row's keys
     and 1,024 chunk summaries) and one backward, on its forward path alone,
     and no SwiGLU makes a product twice."""
@@ -917,7 +936,8 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (4, 0)
-    _dense_products_made_once(text, kept, 4)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 1)
+    _products_made_once(text, kept, "mlp_pre", "GatedMLP", 8)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
 
@@ -929,9 +949,13 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     rotary turn at 8 held heads, five LatentMoE layers behind a 512-wide
     router that chooses 22, the MTP module's attention and expert layer, two
     heads) for one described chip: 5.74 GB of state (716,980,192 parameters
-    and their momentum) + 6.22 GB of temporaries: 11.95 GB, under 13 together
-    (6.31 and 12.05 before PR 43, when every weighted sum by token was 22
-    gathers of 16,384 latent rows).
+    and their momentum) + 6.26 GB of temporaries: 12.00 GB, under 13 together
+    (6.22 and 11.95 before PR 47, whose head blocks keep their logits, 2 x 537
+    MB a step, the main head's across the MTP module, and the block of the
+    projection into that module its result, 134 MB, the next block's input
+    either way: three products once a step body; 6.31 and 12.05 before PR
+    43, when every weighted sum by token was 22 gathers of 16,384 latent
+    rows).
     Both attention cores run as kernels once a step body on their forward
     path alone; every scan is plain `jnp` (no kernel call) and a device loop
     over its 64 chunks; no gather or scatter in any mixer touches an
@@ -948,6 +972,7 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert kept["attn_core"]["forward"] >= 2 and kept["attn_core"]["backward"] == 0
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 3)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     scans = ssm(ops, trainer.net.ssd_scopes())
@@ -975,14 +1000,27 @@ def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     held ReGLU experts behind a router that reads the stream before the
     attention, an untied head over 37,984 rows; ONE row of 16,384 positions)
     for one described chip: 5.25 GB of state (656,529,920 parameters and
-    their momentum) and the round's temporaries under the chip's 16 GB. The
+    their momentum) and the round's temporaries under the chip's 16 GB:
+    10.01 GB of them, 15.26 together, since PR 47 (7.48 and 12.73 before it;
+    bound 14.5 then). The head's block keeps its logits, 1.245 GB a step, and
+    makes their product once; the loss around them holds three float32
+    [16384, 37984] arrays of 2.49 GB, never more than two at once in either
+    form (its cast for the labels' gather, the softmax's gradient, that
+    gradient laid out again for the gather's scatter), and with the bf16
+    logits alive from the product to the gradient the compiler's packing
+    gives each a place of its own where it gave the three two: + 2.49 GB of
+    address space, not of live bytes -- the lone head block compiles to 7.47
+    GB of temporaries against the bare block's 4.98 at a peak of 4.98 live
+    in both, and on the chip the cell's `memory_peak_bytes` FELL, 8.52 ->
+    8.25 GB (PERF.md section 6, PR 47, which also says what removes the
+    three arrays: the labels' logits picked by a masked sum). The
     four cores run as kernels at seven heads a group (no [.., 16384, 16384]
     scores), once a step body on its forward path alone, and the sliding
     cores' tables send them to 140 key blocks where the global core's sends
     it to 272."""
     compiled, trainer = _sequence_round(v5e, "smallthinker-21b-ep4-tau4")
     total = _round_bytes(compiled)
-    assert total < 14.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    assert total < 15.6e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
     assert "splash_mha" in text and "gmm" in text and "16384,16384" not in text
     from sparknet_tpu.obs.device import (attention_moves, parse_hlo_ops,
@@ -991,6 +1029,7 @@ def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kept = recompute_report(ops, trainer.net.kept_makers())
     assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
     assert (kept["attn_core"]["forward"], kept["attn_core"]["backward"]) == (4, 0)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 1)
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     part = window(ops, *trainer.net.window_scopes())

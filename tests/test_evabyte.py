@@ -20,7 +20,8 @@ import pytest
 from jax.ad_checkpoint import checkpoint_name
 
 from model_cases import (CTX, D, POS, ROOT, ROWS, _close, _x, case, check_layer,
-                         check_loss_and_every_gradient, check_round, compiled,
+                         check_loss_and_every_gradient, check_products_kept,
+                         check_round, compiled,
                          max_err, program_round, reference_loss_and_grads,
                          tiny_round)
 from sparknet_tpu import precision, zoo
@@ -299,7 +300,7 @@ def test_eva_core_blocks_and_scopes():
         "keys_per_query": 48, "blocks_visited": 0, "blocks": 0})
     assert sl.eva_core_blocks(EVA_P, 8) == {"keys_per_query": 8, "blocks_visited": 0,
                                             "blocks": 0}
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE, sl.IP_OUT: sl.IP_OUT}
     assert net.attention_scopes() == ({"EVAttention": ""}, POS)
     assert net.delta_scopes() == ({}, ()) and net.routing_scopes() == ((), 0)
     assert sl.KEPT_NAMES["EVAttention"] == (sl.ATTN_CORE,)
@@ -433,3 +434,7 @@ def test_one_round_through_the_trainer_matches_the_reference(tmp_path):
     check_round(got, case_.want, rel=1e-3)
     _close(got["probe"][0], case_.want["probe"][0], tol=1e-4)
     assert ref.PROBE_LEAF == ("l0_attn", "phi")
+    # the heads' one product is made once a step and kept whole
+    from sparknet_tpu.obs import device as obs_device
+    check_products_kept("evabyte", obs_device.program_report("train_round"),
+                        tau=2, rows=1)

@@ -21,7 +21,8 @@ from model_cases import MLA_P as LATENT_WITH_RANK
 from model_cases import _params as _glm_params
 from model_cases import (CTX, D, POS, ROWS, _ids, _per_row, _x, case,
                          check_layer, check_loss_and_every_gradient,
-                         check_round, compiled, program_round, tiny_round)
+                         check_products_kept, check_round, compiled,
+                         program_round, tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.spec import (KDAttentionParam, MLAttentionParam,
@@ -288,6 +289,7 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # loops themselves: the lone layer's test below)
     report = obs_device.program_report("train_round")
     assert report["recompute"][sl.ATTN_CORE]["kept_bytes"] == ROWS * POS * 4 * 16 * 4
+    check_products_kept("ling3_flash", report, tau=2)
     delta = report["delta_rule"]
     assert set(delta) == {"loops", "trips", "kernel_calls", "shape_kernel_calls",
                           "carried_bytes", "instructions", "bytes", "kept_bytes"}
@@ -358,7 +360,7 @@ def test_zoo_follows_the_published_period_and_names_what_a_block_keeps():
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "l3", "head"}
     net = _net()
     assert net.kept_makers() == {  # nothing marks what makes kda_out
-        sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE}
+        sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE, sl.IP_OUT: sl.IP_OUT}
     assert net.attention_scopes() == ({"KDAttention": "", "MLAttention": ""}, POS)
     assert net.delta_scopes() == ({"KDAttention": "delta"}, (sl.KDA_OUT,))
     assert sl.KEPT_NAMES["KDAttention"] == (sl.KDA_OUT,)

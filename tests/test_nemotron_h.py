@@ -20,8 +20,8 @@ from model_cases import MOE_P as GLM_MOE
 from model_cases import _params as glm_params
 from model_cases import (CTX, D, POS, ROWS, SOLVER, _ids, _per_row, _x, case,
                          check_layer, check_loss_and_every_gradient,
-                         check_round, compiled, load, program_round,
-                         tiny_round)
+                         check_products_kept, check_round, compiled, load,
+                         program_round, tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.spec import GQAttentionParam, Mamba2Param, MoEParam
@@ -396,6 +396,8 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
     assert obs_device.program_part("ssm")["train_round"] == ssm
     assert report["delta_rule"] == {} and report["eva"] == {}
     assert report["recompute"][sl.ATTN_CORE]["kept_bytes"] == 2 * ROWS * POS * 2 * 16 * 4
+    # ... and both heads' logits and the projection into the MTP module
+    check_products_kept("nemotron_h", report, tau=2)
     scopes = {op["scope"] for op in report["ops"].values()}
     for part in ("in_proj", "conv", "ssd", "gate_norm", "out_proj"):
         assert any("Mamba2/l0_mamba" in s and part in s.split("/") for s in scopes), part
@@ -559,7 +561,7 @@ def test_zoo_follows_the_pattern_and_builds_the_mtp_module_of_layer_types():
     head = spec.layer_by_name("lm_head")
     assert head.param_from is None and not head.inner_product.transposed  # untied
     net = _net()
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT}
     assert "Mamba2" not in sl.KEPT_NAMES
     assert net.attention_scopes() == ({"Mamba2": "", "GQAttention": ""}, POS)
     assert net.ssd_scopes() == {"Mamba2": "ssd"} == sl.SSD_SCOPES

@@ -18,7 +18,7 @@ import pytest
 
 from model_cases import (CTX, D, MLA_P, MOE_P, POS, ROWS, _ids, _params, _x,
                          attention_block, case, check_loss_and_every_gradient,
-                         check_round, compiled, program_loss_and_grads,
+                         check_products_kept, check_round, compiled, program_loss_and_grads,
                          program_round, tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import net as net_mod
@@ -127,6 +127,9 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     assert report is trainer.program_report()
     kept = dict(report["recompute"])
     pre = kept.pop(sl.MLP_PRE)
+    # ... both heads their logits (the second runs on the first's matrix)
+    check_products_kept("glm4_moe_lite", report, tau=3)
+    del kept[sl.IP_OUT]
     assert kept == {sl.ATTN_CORE: {
         "maker": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": 4 * ROWS * POS * MLA_P.num_heads * MLA_P.v_head_dim * 4}}

@@ -16,7 +16,8 @@ import pytest
 
 from model_cases import (CTX, D, POS, ROWS, _close, _per_row, _x, case,
                          check_layer, check_loss_and_every_gradient,
-                         check_round, compiled, program_round, tiny_round)
+                         check_products_kept, check_round, compiled,
+                         program_round, tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.spec import GQAttentionParam, MoEParam
@@ -259,6 +260,7 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
         "windowed_layers": 1, "blocks_visited": 0, "blocks_causal": 0}
     assert obs_device.program_part("window")["train_round"] == report["window"]
     assert report["eva"] == {} and report["ssm"] == {}
+    check_products_kept("smallthinker", report, tau=2)
     scopes = {op["scope"] for op in report["ops"].values()}
     for part in ("GQAttention/l0_attn)/core", "GQAttention/l1_attn)/core",
                  "MoE/l0_moe)/router", "MoE/l1_moe)/experts"):
@@ -287,7 +289,7 @@ def test_zoo_follows_the_two_layouts_and_feeds_the_router_the_first_norm():
     assert own.layer_by_name("embed").embed.std == 1.0
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "head"}
     net = compiled("smallthinker")
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd"}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT}
     assert net.attention_scopes() == ({"GQAttention": ""}, POS)
     assert net.routing_scopes() == (sl.ROUTING_SCOPES, TINY["hidden_size"])
     assert net.window_scopes()[0] == {"GQAttention": "core"}
