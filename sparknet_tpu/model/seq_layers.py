@@ -640,11 +640,14 @@ def mamba2(p: Mamba2Param, params: Params, u, ctx):
     (`Mamba2Param.held`): one product to gate, x, B, C and time steps; the
     taps, their bias and SiLU over x, B and C in float32 (`conv`); the scan
     with its skip (`ssd`: `ops.ssd`, the time steps' softplus and the decay
-    float32); the gate and the norm a group in float32 (`gate_norm`: the
-    gate first); the product back. Both rows at once, nothing named for the
-    block to keep: the backward pass of a block makes the layer again once
-    and holds a chunk's squares for one layer at a time (PERF.md section 6,
-    PR 42)."""
+    float32; on the TPU at the published head and state widths the scan is
+    `ops.pallas_ssd`'s kernel pair, the skip beside it in `jnp`); the gate
+    and the norm a group in float32 (`gate_norm`: the gate first); the
+    product back. Both rows at once, nothing named for the block to keep: the
+    backward pass of a block makes the layer again once and holds, for one
+    layer at a time, the float32 state every chunk started from (134 MB at
+    the cell's shape; the `jnp` form's [128, 128] squares a chunk, 268 MB an
+    array, where that form runs: PERF.md section 6, PR 42 and 48)."""
     (h, g), hd, n_state = p.held(), p.head_dim, p.state_size
     (r, n, _), inner = u.shape, h * hd
     f32 = lambda t: t.astype(jnp.float32)
@@ -658,8 +661,11 @@ def mamba2(p: Mamba2Param, params: Params, u, ctx):
     b, c = (t.reshape(r, n, g, n_state)
             for t in jnp.split(xbc[..., inner:], 2, axis=-1))
     with jax.named_scope("ssd"):
+        # (the keyword only where it says something: an accepted benchmark
+        # test swaps `ssd` for a twin that takes the six operands alone)
         y = ssd_ops.ssd(x, jax.nn.softplus(f32(dt) + params["dt_bias"]),
-                        -jnp.exp(params["A_log"]), b, c, p.chunk_size)
+                        -jnp.exp(params["A_log"]), b, c, p.chunk_size,
+                        **({"interpret": True} if ctx.interpret else {}))
         y = y + params["D"][:, None] * f32(x)
     with jax.named_scope("gate_norm"):
         y = (y.reshape(r, n, inner) * jax.nn.silu(f32(z))).reshape(r, n, g, -1)

@@ -938,13 +938,16 @@ def ssm(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str]
     under their scopes (`scopes`: layer type -> the scope under the layer's
     own that holds its scan; `CompiledNet.ssd_scopes()`), `{"layers": the
     layers that hold one, "loops": the `while` instructions among them in
-    the WHOLE program (the scan over chunks forward, made again, and its
-    transpose; a round holds a step twice, as the scanned body and as the
-    peeled last step), "trips": their trip counts together (a loop whose
+    the WHOLE program (the `jnp` form's scan over chunks forward, made
+    again, and its transpose; a round holds a step twice, as the scanned
+    body and as the peeled last step; 0 where the kernels ran: their grid
+    walks the chunks), "trips": their trip counts together (a loop whose
     count the text does not give counts 1), "kernel_calls": the Pallas
-    kernels' `custom-call` instructions among them (0: the scan is plain
-    `jnp`, `ops.ssd`), "carried_bytes": the most one of them carries a trip
-    (the float32 state and what it walks), "instructions", "bytes": of the
+    kernels' `custom-call` instructions among them (`ops.pallas_ssd`: the
+    forward, the forward made again with its chunk states and the backward,
+    three a layer and step body; 0 where `ops.ssd` took its `jnp` form: any
+    backend but the TPU, narrow heads), "carried_bytes": the most one of the
+    loops carries a trip (the float32 state and what it walks), "instructions", "bytes": of the
     ops that hold neither a matmul nor a kernel, in the computation that
     moves most (a call of `moves_under`)}`. {} for a net without such
     layers."""

@@ -9,8 +9,15 @@ and B, C [rows, n, groups, N]; head h reads group h // (heads / groups). (The
 layer adds the skip D x_t and everything after; `model.seq_layers`.)
 `ssd_recurrent` is the recurrence a position at a time, in float32 (the
 oracle of the tests); `ssd` is the form that runs: CHUNKED, so that the work
-is matrix products and the sequential part is one step a chunk. There is one
-form, plain `jnp`, on every backend: no kernel, no option.
+is matrix products and the sequential part is one step a chunk. WHICH form
+of it a layer runs is decided here and nowhere else, from what this module
+can observe (no option, no enum): the Pallas kernel pair of `pallas_ssd`
+where a Pallas call may run (`ops.lrn.pallas_backend`: the TPU, or any
+backend under the interpreter) and the shape is the kernels' (`_can_pallas`:
+whole chunks of 128, a state of whole lane rows, a group's heads in whole
+tiles of 128 lanes -- two heads of 64 share one), and plain `jnp` everywhere
+else: narrow heads, short rows, any other backend. The `jnp` form is the
+kernels' oracle (`tests/test_ssd_kernel.py`).
 
 The chunked form. Inside a chunk of Q positions that starts from the state
 S, with L_t the running sum of dt A inside the chunk (float32, <= 0 and
@@ -25,15 +32,28 @@ exp): nothing is a ratio of two exps, so no decay, however strong, leaves
 float32's range. The first sum is two batched products a chunk (the
 [Q, Q] scores C B^T a group, and their masked, decayed copy a head with x);
 the second term and the chunk's own contribution to the state are one
-product each; `lax.scan` over the chunks carries the float32 state and does
-no product at all: S' = exp(L_Q) S + (the chunk's own), emitting the state
-every chunk STARTS from, which the second term reads for all chunks at once.
+product each. In the `jnp` form a `lax.scan` over the chunks carries the
+float32 state and does no product at all: S' = exp(L_Q) S + (the chunk's
+own), emitting the state every chunk STARTS from, which the second term
+reads for all chunks at once; every chunk's [Q, Q] squares and its own
+contribution pass through HBM. In the kernels a program works one chunk of
+one group's heads, the grid's last axis walks a row's chunks in order and
+the state lives in VMEM across the walk: the squares never leave the chip
+(`pallas_ssd`: 14.8 -> 5.6 ms a lone layer-call under `ssd` on the chip, PERF.md
+section 5, PR 48).
 
 Float32 for the time steps, the running sums, the decays and the state; the
 precision policy's dtype for the operands of the four products (float32
-accumulation). The backward pass is autodiff: the scan keeps the one state a
-chunk it emits anyway, and the layer's recomputation block decides what of
-the rest is held (nothing: PERF.md section 6, PR 42).
+accumulation), in both forms. The backward pass of the `jnp` form is
+autodiff: the scan keeps the one state a chunk it emits anyway. The kernels'
+is their own (a `jax.custom_vjp`): the forward rule writes the float32 state
+every chunk started from beside y, the residuals are those and the inputs,
+and the backward kernel walks the chunks in reverse with the state's
+cotangent in VMEM, making the squares again there. The running sums
+themselves, `cumsum(dt A)`, are made here in `jnp` for both forms, so dA and
+the time steps' second path are autodiff's either way. The layer's
+recomputation block decides what of the rest is held (nothing: PERF.md
+section 6, PR 42).
 """
 from __future__ import annotations
 
@@ -41,10 +61,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import precision
+from .lrn import pallas_backend
 
 #: positions a chunk (one step of the sequential scan): the published
 #: `chunk_size`
 CHUNK = 128
+LANES = 128
 
 
 def _by_head(t, heads: int):
@@ -80,10 +102,30 @@ def _mm(spec: str, a, b):
                       preferred_element_type=jnp.float32)
 
 
-def ssd(x, dt, a, b, c, chunk: int = CHUNK):
+def tile_heads(head_dim: int) -> int:
+    """Heads that share a tile of lanes in the kernels: 128 / head_dim of
+    them for narrow heads, one for a head of whole lane rows; 0 for a width
+    that is neither."""
+    if head_dim % LANES == 0:
+        return 1
+    return LANES // head_dim if LANES % head_dim == 0 else 0
+
+
+def _can_pallas(q: int, per: int, p: int, n_state: int, interpret: bool) -> bool:
+    """The kernels' shape: whole chunks of `CHUNK` positions, a state of
+    whole lane rows, a group's heads in whole lane tiles (two heads of 64
+    share one); and a backend a Pallas call may run on."""
+    return pallas_backend(interpret) and q == CHUNK and n_state % LANES == 0 \
+        and tile_heads(p) > 0 and per % tile_heads(p) == 0
+
+
+def ssd(x, dt, a, b, c, chunk: int = CHUNK, *, interpret: bool = False):
     """y [rows, n, heads, P] (float32) of the scan, chunked. A length that is
     no multiple of the chunk is padded at its end with positions whose time
-    step is 0: they neither write nor decay, and their results are cut off."""
+    step is 0: they neither write nor decay, and their results are cut off.
+
+    interpret: run the kernels under the Pallas INTERPRETER (the CPU parity
+      tests of the path the chip runs), as `ops.lrn.lrn` does."""
     rows, n, heads, p = x.shape
     groups, per = b.shape[2], heads // b.shape[2]
     q = min(chunk, n)
@@ -97,6 +139,16 @@ def ssd(x, dt, a, b, c, chunk: int = CHUNK):
     x, b, c = chunks(x), chunks(b), chunks(c)
     dt = jnp.swapaxes(chunks(dt.astype(jnp.float32)), 2, 3)   # [r, nc, h, q]
     run = jnp.cumsum(dt * a.astype(jnp.float32)[:, None], axis=-1)  # L
+    if _can_pallas(q, per, p, b.shape[-1], interpret):
+        from . import pallas_ssd  # (it imports this module)
+        flat = lambda t: precision.cast_in(t).reshape(rows, nc * q, -1)
+        by_group = lambda t: t.reshape(rows, nc, groups, per, q)
+        as_rows = by_group(dt), by_group(run)
+        as_cols = (jnp.swapaxes(t, -1, -2) for t in as_rows)
+        y = pallas_ssd.ssd_chunks(
+            flat(x), flat(b), flat(c), *as_rows, *as_cols, p,
+            precision.compute_dtype(), interpret)
+        return y.reshape(rows, nc * q, heads, p)[:, :n]
     end = run[..., -1]                                        # L_Q [r, nc, h]
 
     # inside a chunk: scores a group, masked and decayed a head. Heads

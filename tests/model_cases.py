@@ -45,6 +45,7 @@ from sparknet_tpu.model.net import CompiledNet
 from sparknet_tpu.model.seq_layers import IP_OUT
 from sparknet_tpu.model.spec import (InputSpec, LayerSpec, MLAttentionParam,
                                      MoEParam, NetSpec, RMSNormParam)
+from sparknet_tpu.ops.ssd import CHUNK
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -201,6 +202,16 @@ def _close(got, want, policy="float32", tol=None):
 def _per_row(fn, x):
     with jax.default_matmul_precision("highest"):
         return jnp.stack([fn(x[r]) for r in range(x.shape[0])])
+
+
+def ssd_without_its_state(real):
+    """A chunked scan's broken twin: every chunk worked alone, from a zero
+    state (`real`: `ops.ssd.ssd`, on either of its forms)."""
+    def ssd(x, dt, a, b, c, chunk=CHUNK, **kw):
+        cut = lambda t, i: t[:, i:i + chunk]
+        return jnp.concatenate([real(cut(x, i), cut(dt, i), a, cut(b, i), cut(c, i), chunk, **kw)
+                                for i in range(0, x.shape[1], chunk)], axis=1)
+    return ssd
 
 
 def norm_err(got, want):

@@ -148,6 +148,29 @@ def test_kda_shape_kernels_compile_for_v5e(v5e, grad, dtype):
     assert ("kda_shape_bwd" if grad else "kda_shape_fwd") in text
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_ssd_kernels_compile_for_v5e(v5e, grad, dtype):
+    """`ops.pallas_ssd`'s pair at the state-space cell's shape (2 rows of
+    8,192 positions, 32 heads of 64 in 2 groups, state 128: a grid of 2 x 2
+    x 64 chunks, sixteen heads -- eight lane tiles -- a program, the float32
+    state [8, 128, 128] in a scratch): Mosaic takes every op of both bodies
+    (the lane and sublane selects, the [128, 1] columns spread over lanes,
+    the reversed walk's index map), and blocks, scratch and temporaries fit
+    the 16 MiB of scoped VMEM a call has unasked, float32 operands too."""
+    from sparknet_tpu.ops.pallas_ssd import ssd_chunks
+    one = SingleDeviceSharding(v5e[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    total = lambda *a: jnp.sum(ssd_chunks(*a, 64, jnp.dtype(dtype), False))
+    fn = jax.grad(total, argnums=tuple(range(7))) if grad else total
+    rows, cols = s((2, 64, 2, 16, 128), jnp.float32), s((2, 64, 2, 128, 16), jnp.float32)
+    text = _compiled_text(fn, s((2, 8192, 2048), dtype), s((2, 8192, 256), dtype),
+                          s((2, 8192, 256), dtype), rows, rows, cols, cols)
+    # the gradient is the forward with its chunk states, then the backward
+    assert text.count("tpu_custom_call") == (2 if grad else 1)
+    assert "ssd_chunk_fwd" in text and ("ssd_chunk_bwd" in text) == grad
+
+
 def test_bf16_row_block_is_the_profiled_one():
     """PERF.md's LRN profile is of the bf16 kernel at these blocks; the f32
     repair states a VMEM need and must never move them."""
@@ -169,6 +192,54 @@ def as_tpu(monkeypatch):
     which the trainer's may_pallas asks too; seq_layers; mesh.scan_unroll)
     down their TPU branch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_ssd_block_compiles_for_v5e_and_walks_its_chunks_in_the_kernels(v5e, as_tpu):
+    """One Mamba-2 mixer at the state-space cell's shape (2 rows, 8,192
+    positions, hidden 4,096, 32 held heads of 64 in 2 groups, state 128) in a
+    recomputation block, forward and backward under the bfloat16 policy, for
+    a v5e (~10 s): the scan is `ops.pallas_ssd`'s kernels (forward in the
+    block, forward again with its chunk states for the backward, backward:
+    three calls under `ssd`, `kernel_calls` of the report) and NO device loop
+    -- the walk over the 64 chunks is the kernels' grid --, no chunk's [128,
+    128] squares and no chunk's own contribution to the state exist outside
+    them (61 and 6 such float32 arrays in the `jnp` form's text), and the
+    block's temporaries are 1.50 GB (2.10 with the scan in `jnp`)."""
+    from sparknet_tpu.model import seq_layers as sl
+    from sparknet_tpu.model.spec import LayerSpec, Mamba2Param
+    from sparknet_tpu.obs import device as obs_device
+    one = SingleDeviceSharding(v5e[0])
+    p = Mamba2Param(num_heads=128, head_dim=64, n_groups=8, state_size=128, taps=4,
+                    chunk_size=128, heads_held=(0, 32), groups_held=(0, 2))
+    layer = LayerSpec(name="m", type="Mamba2", mamba2=p)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda k: sl.init_mamba2(k, layer, ((2, 8192, 4096),)),
+                       jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16, sharding=one)
+
+    def loss(params, x):
+        with jax.named_scope("tau_step"), jax.named_scope("Mamba2/l0_mamba"):
+            y = jax.checkpoint(lambda params, x: sl.mamba2(p, params, x, _seq_ctx()))(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    try:
+        precision.set_policy("bfloat16")
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    finally:
+        precision.set_policy("float32")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.8e9, f"the block's temporaries are {temp / 1e9:.2f} GB"
+    text = compiled.as_text()
+    ops = obs_device.parse_hlo_ops(text)
+    got = obs_device.ssm(ops, sl.SSD_SCOPES)
+    assert got["layers"] == 1 and got["kernel_calls"] == 3, got
+    assert (got["loops"], got["trips"], got["carried_bytes"]) == (0, 0, 0), got
+    kernels = sorted(name.split(".")[0] for name, op in ops.items() if op.get("pallas"))
+    assert kernels == ["%ssd_chunk_bwd", "%ssd_chunk_fwd", "%ssd_chunk_fwd"], kernels
+    assert "f32[2,64,32,128,128]" not in text and "f32[2,64,32,64,128]" not in text
+    # the chunk states the backward reads: one float32 [.., 2048, 128] a chunk
+    assert "f32[2,64,2,1024,128]" in text
 
 
 def _caffenet(batch=BATCH):
@@ -949,16 +1020,20 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     rotary turn at 8 held heads, five LatentMoE layers behind a 512-wide
     router that chooses 22, the MTP module's attention and expert layer, two
     heads) for one described chip: 5.74 GB of state (716,980,192 parameters
-    and their momentum) + 6.26 GB of temporaries: 12.00 GB, under 13 together
-    (6.22 and 11.95 before PR 47, whose head blocks keep their logits, 2 x 537
+    and their momentum) + 5.90 GB of temporaries: 11.64 GB, under 13 together
+    (6.26 and 12.00 before PR 48, whose scans are kernels that keep their
+    chunks' squares in VMEM; 6.22 and 11.95 before PR 47, whose head blocks keep their logits, 2 x 537
     MB a step, the main head's across the MTP module, and the block of the
     projection into that module its result, 134 MB, the next block's input
     either way: three products once a step body; 6.31 and 12.05 before PR
     43, when every weighted sum by token was 22 gathers of 16,384 latent
     rows).
     Both attention cores run as kernels once a step body on their forward
-    path alone; every scan is plain `jnp` (no kernel call) and a device loop
-    over its 64 chunks; no gather or scatter in any mixer touches an
+    path alone; every scan is `ops.pallas_ssd`'s kernel pair (thirty calls:
+    two step bodies x five layers x forward, forward made again with its
+    chunk states, backward) and no device loop -- the kernels' grid walks a
+    row's 64 chunks with the state in VMEM, and no chunk's [128, 128] squares
+    exist outside them; no gather or scatter in any mixer touches an
     activation; the expert layers move rows of the latent's width."""
     compiled, trainer = _sequence_round(v5e, "nemotron3-super-tp4-ep64-tau4")
     total = _round_bytes(compiled)
@@ -976,11 +1051,14 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     scans = ssm(ops, trainer.net.ssd_scopes())
-    assert scans["layers"] == 5 and scans["kernel_calls"] == 0, scans
-    # two step bodies x five layers x (forward, made again, backward)
-    assert scans["loops"] >= 2 * 5 * 3 and scans["trips"] >= 64 * scans["loops"], scans
-    # a trip carries the float32 state [2, 32, 64, 128]
-    assert scans["carried_bytes"] >= 2 * 32 * 64 * 128 * 4, scans
+    print("ssm:", scans)
+    # two step bodies x five layers x (forward, made again with its chunk
+    # states, backward): every scan is `ops.pallas_ssd`'s kernels ...
+    assert scans["layers"] == 5 and scans["kernel_calls"] == 2 * 5 * 3, scans
+    # ... whose grid walks the 64 chunks with the state in VMEM: no device
+    # loop under `ssd`, nothing carried from trip to trip
+    assert (scans["loops"], scans["trips"], scans["carried_bytes"]) == (0, 0, 0), scans
+    assert "f32[2,64,32,128,128]" not in text and "f32[2,64,32,64,128]" not in text
     scopes, width = trainer.net.routing_scopes()
     assert width == 1024
     from sparknet_tpu.model.seq_layers import moe_capacity

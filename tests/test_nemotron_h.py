@@ -21,7 +21,8 @@ from model_cases import _params as glm_params
 from model_cases import (CTX, D, POS, ROWS, SOLVER, _ids, _per_row, _x, case,
                          check_layer, check_loss_and_every_gradient,
                          check_products_kept, check_round, compiled, load,
-                         program_round, tiny_round)
+                         program_round, ssd_without_its_state,
+                         tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.spec import GQAttentionParam, Mamba2Param, MoEParam
@@ -103,19 +104,10 @@ def test_chunked_scan_equals_the_recurrence_and_so_does_its_gradient(n, chunk):
         assert float(jnp.max(jnp.abs(a - b))) < 2e-5 * float(jnp.max(jnp.abs(b)))
 
 
-def _ssd_without_its_state(real):
-    """The scan's broken twin: every chunk worked alone, from a zero state."""
-    def ssd(x, dt, a, b, c, chunk=ssd_ops.CHUNK):
-        cut = lambda t, i: t[:, i:i + chunk]
-        return jnp.concatenate([real(cut(x, i), cut(dt, i), a, cut(b, i), cut(c, i), chunk)
-                                for i in range(0, x.shape[1], chunk)], axis=1)
-    return ssd
-
-
 def test_the_scan_carries_its_state_from_chunk_to_chunk_and_is_causal():
     args = _scan_operands(7, 64)
     got = ssd_ops.ssd(*args, chunk=16)
-    dropped = _ssd_without_its_state(ssd_ops.ssd)(*args, chunk=16)
+    dropped = ssd_without_its_state(ssd_ops.ssd)(*args, chunk=16)
     # the first chunk has nothing to carry; every later one does
     assert np.allclose(dropped[:, :16], got[:, :16], atol=1e-6)
     assert float(jnp.max(jnp.abs(dropped[:, 16:] - got[:, 16:]))) > 0.1
@@ -482,7 +474,7 @@ def test_a_broken_round_fails_the_tiny_limits(tiny_round, monkeypatch, broken):
 
         monkeypatch.setattr(ParallelTrainer, "train_round", lazy_round)
     elif broken == "scan_without_its_state":
-        monkeypatch.setattr(ssd_ops, "ssd", _ssd_without_its_state(ssd_ops.ssd))
+        monkeypatch.setattr(ssd_ops, "ssd", ssd_without_its_state(ssd_ops.ssd))
     elif broken == "experts_as_swiglu":
         monkeypatch.setattr(sl, "_relu2", _swiglu_experts)
     else:
