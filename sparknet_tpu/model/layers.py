@@ -345,6 +345,8 @@ def apply_innerproduct(layer: LayerSpec, params: Params, inputs, ctx: ApplyCtx):
         else:
             y = jnp.dot(x, w, precision=mm_precision,
                         preferred_element_type=mm_out)
+        if layer.inner_product.divisor != 1.0:
+            y = y / layer.inner_product.divisor
     if "b" in params:
         y = y + params["b"].astype(y.dtype)
     if ctx.tp_shards(layer):
@@ -394,26 +396,32 @@ def infer_softmaxwithloss(layer: LayerSpec, in_shapes):
 
 
 def apply_softmaxwithloss(layer: LayerSpec, params, inputs, ctx: ApplyCtx):
-    logits, label = inputs
+    logits, label, *docs = inputs
     if layer.loss is not None:
-        return (_masked_softmax_loss(layer.loss, logits, label),)
+        return (_masked_softmax_loss(layer.loss, logits, label, *docs),)
     label = _squeeze_label(label)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, label[:, None], axis=-1)[:, 0]
     return (jnp.mean(nll),)
 
 
-def _masked_softmax_loss(p, logits, label):
+def _masked_softmax_loss(p, logits, label, docs=None):
     """SoftmaxWithLoss with a LossParam: logits [..., V] against labels of
     the leading shape ([rows, positions] for a sequence model), the mean
-    over the positions that have a target, times `loss_weight`."""
+    over the positions that have a target, times `loss_weight`. `docs`
+    (document ids, the labels' shape; one head): a shifted label that lies
+    in another document than its position is the ignore label."""
     label = label.astype(jnp.int32)
     if label.ndim == logits.ndim:  # Caffe's [N, 1] labels
         label = label[..., 0]
     ignore = p.ignore_label
     if p.label_shift or p.heads > 1:
         ignore = -1 if ignore is None else ignore
-    if p.heads > 1:
+    if docs is not None:
+        assert p.label_shift and p.heads == 1, "document ids cut a shifted label"
+        label = jnp.where(shifted(docs, p.label_shift, fill=-1) == docs,
+                          shifted(label, p.label_shift, fill=ignore), ignore)
+    elif p.heads > 1:
         # [..., heads x V] -> [..., heads, V]; head m's labels beside it
         logits = logits.reshape(logits.shape[:-1] + (p.heads, -1))
         label = jnp.stack([shifted(label, p.label_shift + m, fill=ignore)

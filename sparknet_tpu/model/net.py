@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from .layers import LAYER_IMPLS, ApplyCtx, Params
 from .quant import QuantConfig
-from .spec import InputSpec, NetSpec, validate
+from .spec import InputSpec, LayerSpec, NetSpec, validate
 
 PyTree = Dict[str, Params]
 
@@ -157,13 +157,15 @@ class CompiledNet:
 
     def counter_blobs(self) -> Dict[str, Tuple[str, ...]]:
         """{blob: the names of its entries} for every top that is a
-        layer's vector of counters (an expert layer's, an MTP module's):
+        layer's vector of counters (an expert layer's, an MTP module's, a
+        Mamba-2 mixer's under document ids):
         what a trainer sums over a round's steps and returns with the
         round's scalars. {} for a net of layers that count nothing."""
         from .seq_layers import COUNTER_TOPS
         return {l.tops[COUNTER_TOPS[l.type][0]]: COUNTER_TOPS[l.type][1]
                 for l in self.spec.layers_for_phase("TRAIN")
-                if l.type in COUNTER_TOPS}
+                if l.type in COUNTER_TOPS
+                and len(l.tops) > COUNTER_TOPS[l.type][0]}
 
     def kept_makers(self) -> Dict[str, str]:
         """{name: what marks the device ops that make its values (a Pallas
@@ -208,6 +210,29 @@ class CompiledNet:
                 for l in self.spec.layers_for_phase("TRAIN")
                 if l.type in SSD_SCOPES}
 
+    def ssd_kernel_shape(self, layer: LayerSpec) -> Dict[str, int]:
+        """What `ops.ssd.ssd` walks for a Mamba-2 layer of this net where a
+        Pallas call may run: {"chunk": positions a chunk, "heads_per_program":
+        the heads of a group one kernel program works, "programs_per_group"};
+        {} where the layer's shape is not the kernels' (the `jnp` form)."""
+        from ..ops import ssd as ssd_ops
+        p = layer.mamba2
+        heads, groups = p.held()
+        q = min(p.chunk_size, self.blob_shapes[layer.bottoms[0]][1])
+        at_once = ssd_ops.program_heads(q, heads // groups, p.head_dim,
+                                        p.state_size)
+        return {"chunk": q, "heads_per_program": at_once,
+                "programs_per_group": heads // groups // at_once} if at_once else {}
+
+    def ssd_kernels(self) -> Dict[str, Dict[str, int]]:
+        """{layer: `ssd_kernel_shape` of it, and "documents": 1 where the
+        layer is fed document ids (its scan, taps and counter cut by them)}
+        for this net's Mamba-2 layers; {} for a net without any."""
+        return {l.name: {**self.ssd_kernel_shape(l),
+                         "documents": int(len(l.bottoms) > 1)}
+                for l in self.spec.layers_for_phase("TRAIN")
+                if l.type == "Mamba2"}
+
     def eva_scopes(self) -> Tuple[Dict[str, Tuple[str, str]], Optional[dict]]:
         """({layer type: the scopes under such a layer's own that hold its
         chunk summaries and its core}, what one such layer's core is given:
@@ -244,12 +269,13 @@ class CompiledNet:
         and move rows to and from them, the width of the rows they move:
         the stream's, or the latent's where the experts work in one):
         `seq_layers.ROUTING_SCOPES` for a net with a layer of one of
-        `seq_layers.COUNTER_TOPS`' types; ((), 0) for a net without any."""
-        from .seq_layers import COUNTER_TOPS, ROUTING_SCOPES
+        `seq_layers.COUNTER_TOPS`' types that count routed slots; ((), 0)
+        for a net without any."""
+        from .seq_layers import COUNTER_TOPS, MOE_COUNTERS, ROUTING_SCOPES
         widths = [(l.moe and l.moe.latent_size)
                   or self.blob_shapes[l.bottoms[0]][-1]
                   for l in self.spec.layers_for_phase("TRAIN")
-                  if l.type in COUNTER_TOPS]
+                  if COUNTER_TOPS.get(l.type, (0, ()))[1] == MOE_COUNTERS]
         return (ROUTING_SCOPES, widths[0]) if widths else ((), 0)
 
     # -- execution ----------------------------------------------------------

@@ -131,6 +131,10 @@ SCATTER_COLUMNS = 512
 #: the counters an expert layer returns beside its result, in this order
 MOE_COUNTERS = ("slots_landed", "slots_dropped", "expert_tokens_max",
                 "expert_tokens_min")
+#: the counter a Mamba-2 mixer returns beside its result where its rows hold
+#: several documents: the boundaries a step, as the scan's and the taps' cut
+#: saw them (the positions whose document differs from the one before's)
+SSD_COUNTERS = ("doc_boundaries",)
 #: the name (`jax.ad_checkpoint.checkpoint_name`) on the attention core's
 #: output and softmax statistics, that on a Kimi Delta Attention layer's
 #: result, and that on the dense SwiGLU's two input products `x W_gate` and
@@ -219,7 +223,10 @@ def shifted(ids, k: int, fill=0):
 def apply_embed(layer: LayerSpec, params: Params, inputs, ctx):
     (ids,) = inputs
     ids = shifted(ids.astype(jnp.int32), layer.embed.shift)
-    return (precision.cast_in(jnp.take(params["w"], ids, axis=0)),)
+    x = jnp.take(params["w"], ids, axis=0)
+    if layer.embed.multiplier != 1.0:
+        x = x * layer.embed.multiplier
+    return (precision.cast_in(x),)
 
 
 # -- RMSNorm -----------------------------------------------------------------
@@ -382,7 +389,7 @@ def _blocks_visited(heads: int, positions: int, mask=None) -> int:
         _splash(heads, positions, mask).fwd_mask_info.block_mask)))
 
 
-def attention_core(q, k, v, ctx, mask=None):
+def attention_core(q, k, v, ctx, mask=None, docs=None):
     """Causal softmax(q k^T) v over q [rows, heads, positions, d] and k, v
     [rows, key/value heads, positions, d], heads first as the kernel reads
     and writes them; q comes scaled (its layer folds 1/sqrt(d) into a
@@ -393,17 +400,30 @@ def attention_core(q, k, v, ctx, mask=None):
     queries, and a query reads those the mask grants it. The kernel where
     it applies (queries and key columns whole tiles, head sizes a half or
     whole lane rows); else the exact path, which materialises the
-    scores."""
+    scores. With `docs` (the rows' document ids [rows, positions], as many
+    keys as queries) a query reads the keys of its own document alone: the
+    kernel is handed them as its segment ids and masks inside the tiles its
+    tables send it to -- the tiles it visits are the mask's, wherever the
+    documents fall --; the exact path takes them as a bias."""
     n, group = q.shape[2], q.shape[1] // k.shape[1]
     if (use_kernels(ctx) and n % max(ATTN_BLOCKS) == 0
             and k.shape[2] % max(ATTN_BLOCKS) == 0
             and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0
             and q.dtype == jnp.bfloat16):
-        return jax.vmap(_splash(q.shape[1], n, mask))(q, k, v)
+        kernel = jax.vmap(_splash(q.shape[1], n, mask))
+        if docs is None:
+            return kernel(q, k, v)
+        from jax.experimental.pallas.ops.tpu.splash_attention import (
+            splash_attention_kernel as sk)
+        return kernel(q, k, v, sk.SegmentIds(q=docs, kv=docs))
     # the exact path's positions-first, every query head with its own copy
     swap = lambda x: jnp.swapaxes(x, 1, 2)
     spread = lambda x: swap(x if group == 1 else jnp.repeat(x, group, axis=1))
     bias = None if mask is None else jnp.where(mask.dense(), 0.0, -jnp.inf)
+    if docs is not None:
+        same = jnp.where(docs[:, None, :, None] == docs[:, None, None, :],
+                         0.0, -jnp.inf)                       # [rows, 1, q, k]
+        bias = same if bias is None else same + bias
     return checkpoint_name(swap(attention_ops.attention(
         swap(q), spread(k), spread(v), causal=mask is None, bias=bias,
         scale=1.0)), ATTN_CORE)
@@ -481,7 +501,7 @@ def init_gqattention(key, layer: LayerSpec, in_shapes) -> Params:
             "o": _normal(ks[3], (h, d), p.std)}
 
 
-def gqa(p: GQAttentionParam, params: Params, x, ctx):
+def gqa(p: GQAttentionParam, params: Params, x, ctx, docs=None):
     """Grouped-query attention, laid out as `mla` lays its own out: the
     products of x with views of the stored matrices come out heads first,
     q and k go through their per-head norm (1/sqrt(d) folded into q's scale
@@ -493,11 +513,15 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
     share's result is its part of the sum over all heads. A layer with a
     `window` shorter than the row hands the core a sliding mask
     (`ops.attention.SlidingWindowMask`); one at least as long as the row is
-    plain causal attention, as a layer without one."""
+    plain causal attention, as a layer without one. `score_scale` stands
+    where 1/sqrt(d) would; `docs` (document ids [rows, positions]) go to the
+    core."""
     (h, kv), hd, d = p.held(), p.head_dim, x.shape[-1]
     heads_first = "rnc,chd->rhnd"
     w_q = params["q"].reshape(d, h, hd)
-    q = _project(heads_first, x, w_q if p.qk_norm else w_q / np.sqrt(hd))
+    scaled = (lambda t: t / np.sqrt(hd)) if p.score_scale is None \
+        else (lambda t: t * p.score_scale)
+    q = _project(heads_first, x, w_q if p.qk_norm else scaled(w_q))
     k = _project(heads_first, x, params["k"].reshape(d, kv, hd))
     v = _project(heads_first, x, params["v"].reshape(d, kv, hd))
 
@@ -506,10 +530,10 @@ def gqa(p: GQAttentionParam, params: Params, x, ctx):
             t = _rms(t, scale(), p.eps)
         return rotary(t, p.rope_theta, hd) if p.rotary else t
 
-    q = shaped(q, lambda: params["q_norm"] / np.sqrt(hd))
+    q = shaped(q, lambda: scaled(params["q_norm"]))
     k = shaped(k, lambda: params["k_norm"])
     with jax.named_scope("core"):
-        o = attention_core(q, k, v, ctx, gqa_mask(p, x.shape[1]))
+        o = attention_core(q, k, v, ctx, gqa_mask(p, x.shape[1]), docs)
     return _project("rhnd,hdm->rnm", o, params["o"].reshape(h, hd, d))
 
 
@@ -537,7 +561,7 @@ def gqa_core_blocks(p: GQAttentionParam, positions: int) -> Dict[str, int]:
 
 
 def apply_gqattention(layer: LayerSpec, params: Params, inputs, ctx):
-    return (gqa(layer.gqa, params, inputs[0], ctx),)
+    return (gqa(layer.gqa, params, inputs[0], ctx, *inputs[1:]),)
 
 
 # -- EVAttention -------------------------------------------------------------
@@ -635,7 +659,7 @@ def init_mamba2(key, layer: LayerSpec, in_shapes) -> Params:
             "out_proj": _normal(ks[5], (inner, d), p.std)}
 
 
-def mamba2(p: Mamba2Param, params: Params, u, ctx):
+def mamba2(p: Mamba2Param, params: Params, u, ctx, docs=None):
     """A Mamba-2 mixer over the heads and groups the layer holds
     (`Mamba2Param.held`): one product to gate, x, B, C and time steps; the
     taps, their bias and SiLU over x, B and C in float32 (`conv`); the scan
@@ -647,15 +671,22 @@ def mamba2(p: Mamba2Param, params: Params, u, ctx):
     backward pass of a block makes the layer again once and holds, for one
     layer at a time, the float32 state every chunk started from (134 MB at
     the cell's shape; the `jnp` form's [128, 128] squares a chunk, 268 MB an
-    array, where that form runs: PERF.md section 6, PR 42 and 48)."""
+    array, where that form runs: PERF.md section 6, PR 42 and 48).
+
+    With `docs` (the rows' document ids [rows, positions]) the taps read
+    zeros before a document's first position and the scan's state is zero
+    there (`ops.ssd.document_runs`: the one reading of the ids both cuts are
+    made from), and the layer returns (its result, `SSD_COUNTERS`: the
+    boundaries those runs hold, all rows together)."""
     (h, g), hd, n_state = p.held(), p.head_dim, p.state_size
     (r, n, _), inner = u.shape, h * hd
     f32 = lambda t: t.astype(jnp.float32)
     with jax.named_scope("in_proj"):
         zxbcdt = _dot(u, params["in_proj"])
     z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:-h], zxbcdt[..., -h:])
+    cut = {} if docs is None else {"runs": ssd_ops.document_runs(docs)}
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(causal_taps(f32(xbc), params["conv"])
+        xbc = jax.nn.silu(causal_taps(f32(xbc), params["conv"], **cut)
                           + params["conv_bias"]).astype(zxbcdt.dtype)
     x = xbc[..., :inner].reshape(r, n, h, hd)
     b, c = (t.reshape(r, n, g, n_state)
@@ -664,7 +695,7 @@ def mamba2(p: Mamba2Param, params: Params, u, ctx):
         # (the keyword only where it says something: an accepted benchmark
         # test swaps `ssd` for a twin that takes the six operands alone)
         y = ssd_ops.ssd(x, jax.nn.softplus(f32(dt) + params["dt_bias"]),
-                        -jnp.exp(params["A_log"]), b, c, p.chunk_size,
+                        -jnp.exp(params["A_log"]), b, c, p.chunk_size, **cut,
                         **({"interpret": True} if ctx.interpret else {}))
         y = y + params["D"][:, None] * f32(x)
     with jax.named_scope("gate_norm"):
@@ -673,11 +704,19 @@ def mamba2(p: Mamba2Param, params: Params, u, ctx):
                           + p.eps)
         y = (y.reshape(r, n, inner) * params["norm"]).astype(zxbcdt.dtype)
     with jax.named_scope("out_proj"):
-        return _dot(y, params["out_proj"])
+        out = _dot(y, params["out_proj"])
+    if docs is None:
+        return out
+    return out, jnp.sum(cut["runs"][:, -1]).astype(jnp.float32)[None]
+
+
+def infer_mamba2(layer: LayerSpec, in_shapes):
+    return (in_shapes[0],) + ((len(SSD_COUNTERS),),) * (len(in_shapes) - 1)
 
 
 def apply_mamba2(layer: LayerSpec, params: Params, inputs, ctx):
-    return (mamba2(layer.mamba2, params, inputs[0], ctx),)
+    out = mamba2(layer.mamba2, params, inputs[0], ctx, *inputs[1:])
+    return out if len(inputs) > 1 else (out,)
 
 
 # -- ShortConv ---------------------------------------------------------------
@@ -690,11 +729,14 @@ def init_shortconv(key, layer: LayerSpec, in_shapes) -> Params:
             "out_proj": _normal(k_out, (d, d), p.std)}
 
 
-def causal_taps(s, w):
+def causal_taps(s, w, runs=None):
     """c[.., t, :] = sum_j w[.., j] * s[.., t - (taps - 1) + j, :] over s
     [rows, positions, d] with w [d, taps], or s [rows, heads, positions, d]
     with w [heads, d, taps]: a depthwise causal convolution, zeros before
-    position 0, as shifted sums (a pad and a contiguous slice each)."""
+    position 0, as shifted sums (a pad and a contiguous slice each). With
+    `runs` [rows, positions] (`ops.ssd.document_runs`; s [rows, positions,
+    d]) zeros before a DOCUMENT's first position: a tap reads the position
+    `back` behind where that one is of the same run."""
     taps, n = w.shape[-1], s.shape[-2]
     # a head's taps stand over the positions' axis
     tap = (lambda j: w[:, j]) if w.ndim == 2 else (lambda j: w[:, None, :, j])
@@ -702,7 +744,12 @@ def causal_taps(s, w):
     out = s * tap(taps - 1)
     for j in range(taps - 1):
         back = taps - 1 - j
-        out = out + jnp.pad(s, ahead + ((back, 0), (0, 0)))[..., :n, :] * tap(j)
+        behind = jnp.pad(s, ahead + ((back, 0), (0, 0)))[..., :n, :]
+        if runs is not None:
+            behind = jnp.where((jnp.pad(runs, ((0, 0), (back, 0)),
+                                        constant_values=-1)[:, :n] == runs)[..., None],
+                               behind, 0.0)
+        out = out + behind * tap(j)
     return out
 
 
@@ -1167,8 +1214,10 @@ def apply_mtp(layer: LayerSpec, params: Params, inputs, ctx):
     return _rms(x + y, params["norm"], p.eps), counters, chosen
 
 
-#: layer type -> (which of its tops is its counters, their names)
-COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS)}
+#: layer type -> (which of its tops is its counters, their names); a
+#: Mamba2 layer has that top where it is given document ids
+COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS),
+                "Mamba2": (1, SSD_COUNTERS)}
 #: layer type -> the names its implementation puts on values a recomputation
 #: block keeps for the backward pass (`mla` serves both)
 #: KDAttention names its RESULT (84 MB a layer at [2, 8192, 2560] bf16) and
@@ -1254,7 +1303,7 @@ SEQ_LAYER_IMPLS = {
     "GQAttention": (init_gqattention, apply_gqattention, infer_same),
     "EVAttention": (init_evattention, apply_evattention, infer_same),
     "KDAttention": (init_kdattention, apply_kdattention, infer_same),
-    "Mamba2": (init_mamba2, apply_mamba2, infer_same),
+    "Mamba2": (init_mamba2, apply_mamba2, infer_mamba2),
     "ShortConv": (init_shortconv, apply_shortconv, infer_same),
     "MoE": (init_moe, apply_moe, infer_moe),
     "MTP": (init_mtp, apply_mtp, infer_mtp),

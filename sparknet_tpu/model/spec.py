@@ -94,6 +94,10 @@ class InnerProductParam:
     #: bfloat16: its operands rounded, its accumulators as they are): a
     #: head whose logits are not rounded on their way to the loss
     float32_out: bool = False
+    #: the result is divided by this (a head whose model scales its logits
+    #: down, muP's `logits_scaling`): in the product's own dtype, before the
+    #: bias and before a recomputation block names the result
+    divisor: float = 1.0
     weight_filler: Filler = field(default_factory=Filler)
     bias_filler: Filler = field(default_factory=Filler)
 
@@ -114,7 +118,9 @@ class LossParam:
     `ignore_label`: positions whose label equals it leave the mean (None:
     none do). `label_shift` k: the logits at position i are held against the
     label at position i + k of the same row, and the last k positions have
-    no target (a next-token loss reads the ids it was given as labels).
+    no target (a next-token loss reads the ids it was given as labels). A
+    THIRD bottom, the rows' document ids, takes the target from every
+    position whose label lies in another document (a document's last k).
     `heads` h > 1: the logits' last axis is h heads of V side by side, head
     m held against the label at position i + `label_shift` + m; the loss is
     the mean over the heads of each head's mean over the positions it
@@ -138,12 +144,15 @@ class EltwiseParam:
 @dataclass(frozen=True)
 class EmbedParam:
     """Rows of a table by integer id (Caffe's Embed, no bias). `shift` k
-    looks up the id at position i + k of the same row (0 past the end)."""
+    looks up the id at position i + k of the same row (0 past the end).
+    `multiplier`: the looked-up rows times a constant (muP's
+    `embedding_multiplier`), in float32 before the policy's cast."""
 
     num_embeddings: int = 0
     dim: int = 0
     shift: int = 0
     std: float = 0.02
+    multiplier: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -215,7 +224,10 @@ class GQAttentionParam:
     contiguous halves (no turn where `rotary` is off: a model whose other
     layers carry position). Causal over every key, or -- `window` -- over a
     query's last `window` keys, itself among them (a sliding window: key j
-    where 0 <= p - j < window).
+    where 0 <= p - j < window). The scores are q . k / sqrt(head_dim), or q .
+    k x `score_scale` where the model publishes another (muP's
+    `attention_multiplier`). With a second bottom, the rows' document ids, a
+    query reads the keys of its own document alone.
 
     A layer may hold a SHARE of its heads (tensor parallelism's view from one
     chip): `heads_held` / `kv_heads_held` (first, count) of the published
@@ -234,6 +246,7 @@ class GQAttentionParam:
     heads_held: Optional[Tuple[int, int]] = None
     kv_heads_held: Optional[Tuple[int, int]] = None
     window: Optional[int] = None
+    score_scale: Optional[float] = None
 
     def held(self) -> Tuple[int, int]:
         """(query heads, key/value heads) this layer builds. Every held
@@ -259,7 +272,11 @@ class Mamba2Param:
     [n_groups, state_size] (head h reads group h // (num_heads / n_groups));
     time steps softplus(dt + dt_bias); the scan `ops.ssd` with A =
     -exp(A_log) a scalar a head, plus the skip D x; the result times SiLU(z),
-    RMS-normed over each group's channels (the gate first); W_out.
+    RMS-normed over each group's channels (the gate first); W_out. With a
+    second bottom, the rows' document ids, the taps read zeros before a
+    document's first position and the scan's state is zero there (a row of
+    concatenated documents, none reading another), and the layer's second
+    top is a vector of counters (`seq_layers.SSD_COUNTERS`).
 
     The layer holds a SHARE of its heads and groups (tensor parallelism's
     view from one chip): `heads_held` / `groups_held` (first, count) of the

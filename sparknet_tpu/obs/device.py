@@ -462,6 +462,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     "core_backward_calls", "keys_per_query", "blocks_visited", "blocks",
     "summary_instructions", "summary_bytes"}, "ssm": {"layers", "loops",
     "trips", "kernel_calls", "carried_bytes", "instructions", "bytes"},
+    "ssd": {"kernel_calls", "chunk", "heads_per_program", "programs_per_group",
+    "layers_under_documents"},
     "window": {"layers", "windowed_layers", "blocks_visited",
     "blocks_causal"}}` — see
     `parse_hlo_ops` for the
@@ -471,7 +473,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     computing, `routing_moves` for what its expert layers move around
     their products, `delta_rule` for how its delta rules were compiled and
     `eva` for how its EVA attention layers were, `ssm` for how its
-    state-space scans were and `window` for what its attention cores under
+    state-space scans were, `ssd_kernels` for which kernels they run as and
+    `window` for what its attention cores under
     sliding windows visit
     (each {} for a net without such layers; the first five call
     `moves_under`).
@@ -958,6 +961,26 @@ def ssm(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str]
     return {"layers": len(layers), **_scan_account(ops, scopes)}
 
 
+def ssd_kernels(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
+                layers: Dict[str, Dict[str, int]]) -> Dict[str, int]:
+    """Which kernels a program's state-space scans run as: `{"kernel_calls":
+    the Pallas `custom-call` instructions under the scans' scopes (`ssm`'s
+    count), "chunk": the positions a chunk they walk, "heads_per_program":
+    the heads of a group one program works, "programs_per_group",
+    "layers_under_documents": the layers whose scan is cut by document ids}`
+    -- the shape `ops.ssd.program_heads` gives the net's layers (`layers`:
+    `CompiledNet.ssd_kernels()`; 0s where it is not the kernels' and the
+    `jnp` form runs, the widest layer's where the layers differ). {} for a
+    net without such layers."""
+    if not layers:
+        return {}
+    widest = max(layers.values(), key=lambda s: s.get("heads_per_program", 0))
+    return {"kernel_calls": _scan_account(ops, scopes)["kernel_calls"],
+            **{k: widest.get(k, 0) for k in ("chunk", "heads_per_program",
+                                             "programs_per_group")},
+            "layers_under_documents": sum(s["documents"] for s in layers.values())}
+
+
 def eva(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, Tuple[str, str]],
         core: Optional[Dict[str, int]] = None) -> Dict[str, int]:
     """How a program's EVA attention layers were compiled: of the device ops
@@ -1040,7 +1063,7 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
                        jaxpr=None, attention=({}, 0),
                        routing=((), 0), delta=({}, ()),
                        eva_layers=({}, None), ssd=None,
-                       windows=({}, {})) -> Dict[str, Any]:
+                       windows=({}, {}), ssd_layers=None) -> Dict[str, Any]:
     """The report of one `jax.stages.Compiled` (what a program's provider
     returns): its memory analysis, `parse_hlo_ops` of its text, for the
     names its net's recomputation blocks keep `recompute_report`, for
@@ -1050,9 +1073,9 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
     layers (`delta`: their scopes and the names their blocks keep)
     `delta_rule`, for its EVA attention layers (`eva_layers`: their
     scopes and what a core is given) `eva`, for its state-space mixers
-    (`ssd`: their scans' scopes) `ssm`, and for its attention layers under
-    sliding windows (`windows`: their cores' scopes and what each visits)
-    `window`."""
+    (`ssd`: their scans' scopes) `ssm` and (`ssd_layers`: what each walks)
+    `ssd_kernels`, and for its attention layers under sliding windows
+    (`windows`: their cores' scopes and what each visits) `window`."""
     mem = compiled.memory_analysis()
     ops = parse_hlo_ops(compiled.as_text())
     return {"memory": {k: int(getattr(mem, f"{k}_size_in_bytes"))
@@ -1065,12 +1088,13 @@ def report_of_compiled(compiled, kept_makers: Optional[Dict[str, str]] = None,
                 _named_bytes(jaxpr, name) for name in delta[1]
                 if jaxpr is not None)),
             "eva": eva(ops, *eva_layers), "ssm": ssm(ops, ssd or {}),
+            "ssd": ssd_kernels(ops, ssd or {}, ssd_layers or {}),
             "window": window(ops, *windows)}
 
 
 #: program -> these parts of its report, once `program_report` has run
 REPORT_PARTS = ("memory", "recompute", "attention_moves", "routing_moves",
-                "delta_rule", "eva", "ssm", "window")
+                "delta_rule", "eva", "ssm", "ssd", "window")
 _program_parts: Dict[str, Dict[str, Dict[str, Any]]] = {}
 
 
@@ -1144,15 +1168,18 @@ def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:
     """Show the counters the trainer's net returns with its round's scalars
     (an expert layer's `slots_landed`, `slots_dropped`, `expert_tokens_max`,
     `expert_tokens_min`: sums over a round's steps and workers) as gauges
-    `sparknet_moe_<counter>{layer=...}`: live-read from
-    `trainer.counter_values()`, which waits for nothing. No gauge for a
-    net whose layers count nothing."""
+    `sparknet_moe_<counter>{layer=...}` -- a Mamba-2 mixer's
+    `doc_boundaries` as `sparknet_ssd_doc_boundaries{layer=...}` --:
+    live-read from `trainer.counter_values()`, which waits for nothing. No
+    gauge for a net whose layers count nothing."""
+    from ..model.seq_layers import SSD_COUNTERS
     for blob, names in getattr(trainer, "counter_blobs", {}).items():
         layer = blob[:-len("_counters")] if blob.endswith("_counters") else blob
+        family = "ssd" if tuple(names) == SSD_COUNTERS else "moe"
         for name in names:
             registry.gauge(
-                f"sparknet_moe_{name}",
-                f"an expert layer's {name}, summed over the last finished "
+                f"sparknet_{family}_{name}",
+                f"a layer's {name}, summed over the last finished "
                 f"round's steps and workers", labels=("layer",)
             ).set_fn(lambda blob=blob, name=name:
                      trainer.counter_values()[blob][name], layer=layer)

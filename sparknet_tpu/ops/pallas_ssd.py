@@ -11,7 +11,12 @@ step, and a `lax.scan` over the chunks besides: 14.9 ms a layer-step on the
 chip for work whose least time is 0.89 (PERF.md section 6, PR 48). Nothing
 of that has to leave the chip: a program here works ONE chunk of one group's
 heads, the grid's last axis walks a row's chunks in order, and the float32
-state of the group's heads lives in a VMEM scratch across that walk. A chunk's
+state of the group's heads lives in a VMEM scratch across that walk. (A
+group of more heads than `ops.ssd.PROGRAM_TILES` lane tiles hold is worked
+in equal BLOCKS of its heads, a program each: the grid's middle axis runs
+over groups x blocks, every block reads its group's B and C, and dB and dC
+leave the backward kernel a block apiece in float32 and are summed outside.)
+A chunk's
 x, B, C, time steps and running sums are read once, its y written once; the
 squares, the decays and the chunk's own contribution exist in VMEM alone.
 
@@ -38,6 +43,17 @@ makes the squares again in VMEM and emits dx, dB, dC (a group's heads summed
 in the program that holds them all) and the float32 cotangents of the time
 steps as multipliers and of the running sums. The `cumsum(dt A)` that makes
 the running sums stays outside, in `jnp` under autodiff (`ops.ssd`).
+
+Rows that hold several documents hand both kernels a chunk's document runs
+(`ops.ssd.ssd`: counted from the run the chunk's first position would
+continue, as a row [1, Q] and as a column [Q, 1] of int32). A cut is then a
+mask on four exponents, set before the exp as the diagonal's is (`_Chunk`):
+the square's pairs of one document, exp L_t where position t still reads the
+incoming state, exp(L_Q - L_s) where position s writes into the state handed
+on, exp L_Q where the chunk passes the state through. Every later use of
+them is a product, so the backward kernel's formulas stand as they are: a
+masked factor is 0 and so is what flows through it. No program does less
+for a boundary: the work does not depend on where the documents fall.
 `interpret=True` runs the same kernels under the Pallas interpreter (CPU),
 which the tests use.
 """
@@ -65,7 +81,8 @@ class _Chunk:
     and time steps in both layouts, what is made of them a head, and the
     index masks."""
 
-    def __init__(self, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, p: int):
+    def __init__(self, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref,
+                 relr_ref=None, relc_ref=None, *, p: int):
         self.b, self.c = b_ref[0], c_ref[0]                     # [Q, N]
         self.dt_row, self.l_row = dtr_ref[0, 0, 0], lr_ref[0, 0, 0]   # [per, Q]
         self.dt_col, self.l_col = dtc_ref[0, 0, 0], lc_ref[0, 0, 0]   # [Q, per]
@@ -75,13 +92,22 @@ class _Chunk:
         self.tw = self.hp * p                                   # a tile's lanes
         self.tiles = per // self.hp
         l_end = self.l_col[q - 1:q, :]                          # L_Q [1, per]
-        self.e_col = jnp.exp(self.l_col)                        # exp L_t
-        self.to_end = jnp.exp(l_end - self.l_col)               # exp(L_Q - L_s)
+        if relr_ref is None:
+            cut = lambda keep, e: e
+            same = incoming = tail = whole = None
+        else:
+            # the chunk's document runs, 0 = the incoming document's
+            rel_row, rel_col = relr_ref[0, 0, 0], relc_ref[0, 0, 0]   # [1, Q], [Q, 1]
+            cut = lambda keep, e: jnp.where(keep, e, _MASKED)
+            same, incoming = rel_col == rel_row, rel_col == 0
+            tail, whole = rel_col == rel_col[q - 1:q, :], rel_col[q - 1:q, :] == 0
+        self.e_col = jnp.exp(cut(incoming, self.l_col))         # exp L_t
+        self.to_end = jnp.exp(cut(tail, l_end - self.l_col))    # exp(L_Q - L_s)
         self.w_col = self.to_end * self.dt_col
-        self.e_end = jnp.exp(l_end)                             # exp L_Q
+        self.e_end = jnp.exp(cut(whole, l_end))                 # exp L_Q
         t = lax.broadcasted_iota(jnp.int32, (q, q), 0)
         s = lax.broadcasted_iota(jnp.int32, (q, q), 1)
-        self.lower = t >= s
+        self.lower = t >= s if same is None else (t >= s) & same
         self.last = s[:1, :] == q - 1                           # [1, Q]
         # which of its tile's heads a lane of x, a row of the state, is
         self.lane_head = lax.broadcasted_iota(jnp.int32, (q, self.tw), 1) // p
@@ -121,10 +147,13 @@ class _Chunk:
         return jnp.exp(jnp.where(self.lower, diff, _MASKED))
 
 
-def _fwd_kernel(x_ref, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, y_ref,
-                *rest, p: int, dtype):
-    st_ref, s_ref = rest if len(rest) == 2 else (None,) + rest
-    ch = _Chunk(b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, p)
+def _fwd_kernel(x_ref, *refs, p: int, dtype, cuts: int):
+    """refs: B, C, the time steps and running sums as rows and as columns,
+    `cuts` (0 or 2) arrays of document runs; then y, the chunk states where
+    asked for, and the state's scratch."""
+    y_ref, *rest = refs[6 + cuts:]
+    st_ref, s_ref = rest if len(rest) == 2 else (None,) + tuple(rest)
+    ch = _Chunk(*refs[:6 + cuts], p=p)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -147,10 +176,12 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, y_ref,
             + _pdot(x_end, ch.b, _TN, dtype)
 
 
-def _bwd_kernel(x_ref, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, st_ref,
-                dy_ref, dx_ref, db_ref, dc_ref, ddtr_ref, dlr_ref, ddtc_ref,
-                dlc_ref, ds_ref, *, p: int, dtype):
-    ch = _Chunk(b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, p)
+def _bwd_kernel(x_ref, *refs, p: int, dtype, cuts: int):
+    """refs: as `_fwd_kernel`'s inputs, then the chunk states and dy; the
+    seven cotangents; the scratch of the state's cotangent."""
+    (st_ref, dy_ref, dx_ref, db_ref, dc_ref, ddtr_ref, dlr_ref, ddtc_ref,
+     dlc_ref, ds_ref) = refs[6 + cuts:]
+    ch = _Chunk(*refs[:6 + cuts], p=p)
     per, q = ch.per, ch.q
 
     @pl.when(pl.program_id(2) == 0)  # the row's LAST chunk: nothing reads its end
@@ -217,31 +248,41 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dtr_ref, lr_ref, dtc_ref, lc_ref, st_ref,
     ddtc_ref[0, 0, 0], dlc_ref[0, 0, 0] = cols["dt"], cols["l"]
 
 
-def _specs(xs, p: int, reverse: bool):
-    """(grid, the seven inputs' block specs, the spec of an array laid out as
-    x, the chunk states' spec and aval, the scratch) for x [rows, n, heads x
-    P], B and C [rows, n, groups x N] and the time steps and running sums as
-    rows [rows, chunks, groups, per, Q] and as columns [.., Q, per]. The
-    scratch is the float32 state of a group's heads (or its cotangent), a
-    lane tile of heads at a time: [tiles, tw, N]. `reverse`: the grid's last
-    axis walks the chunks from the last to the first."""
+def _specs(xs, cuts, p: int, n_state: int, reverse: bool):
+    """(grid, the inputs' block specs, the spec of an array laid out as x,
+    that of dB or dC a PROGRAM at a time, the chunk states' spec and aval, the
+    scratch, the blocks a group's heads are worked in) for x [rows, n, heads
+    x P], B and C [rows, n, groups x `n_state`], the time steps and running
+    sums as rows [rows, chunks, programs, per, Q] and as columns [.., Q, per]
+    -- `programs` the groups times the blocks, `per` the heads of one -- and
+    `cuts`: () or a chunk's document runs as a row [rows, chunks, 1, 1, Q]
+    and as a column [.., Q, 1]. A block reads its group's B and C (program g
+    those of group g // blocks). The scratch is the float32 state of a
+    program's heads (or its cotangent), a lane tile of heads at a time:
+    [tiles, tw, N]. `reverse`: the grid's last axis walks the chunks from
+    the last to the first."""
     x, b, _, rows_like, _, cols_like, _ = xs
     rows, _, wide = x.shape
-    _, nc, groups, _, q = rows_like.shape
-    wide, n_state = wide // groups, b.shape[-1] // groups   # a group's
+    _, nc, programs, _, q = rows_like.shape
+    blocks = programs * n_state // b.shape[-1]
+    wide = wide // programs                           # a program's heads x P
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
     vmem = pltpu.VMEM
-    by_pos = lambda d: pl.BlockSpec((1, q, d), lambda r, g, c: (r, at(c), g),
-                                    memory_space=vmem)
+    by_pos = lambda d, of=lambda g: g: pl.BlockSpec(
+        (1, q, d), lambda r, g, c: (r, at(c), of(g)), memory_space=vmem)
     small = lambda *tail: pl.BlockSpec(
         (1, 1, 1) + tail, lambda r, g, c: (r, at(c), g, 0, 0), memory_space=vmem)
-    like_x = by_pos(wide)
-    ins = [like_x, by_pos(n_state), by_pos(n_state)] \
-        + [small(*rows_like.shape[-2:])] * 2 + [small(*cols_like.shape[-2:])] * 2
+    shared = lambda *tail: pl.BlockSpec(
+        (1, 1, 1) + tail, lambda r, g, c: (r, at(c), 0, 0, 0), memory_space=vmem)
+    like_x, per_program = by_pos(wide), by_pos(n_state)
+    group = per_program if blocks == 1 else by_pos(n_state, lambda g: g // blocks)
+    ins = [like_x, group, group] \
+        + [small(*rows_like.shape[-2:])] * 2 + [small(*cols_like.shape[-2:])] * 2 \
+        + [shared(*t.shape[-2:]) for t in cuts]
     tw = tile_heads(p) * p
-    return ((rows, groups, nc), ins, like_x, small(wide, n_state),
-            _struct((rows, nc, groups, wide, n_state), _F32, x),
-            pltpu.VMEM((wide // tw, tw, n_state), _F32))
+    return ((rows, programs, nc), ins, like_x, per_program, small(wide, n_state),
+            _struct((rows, nc, programs, wide, n_state), _F32, x),
+            pltpu.VMEM((wide // tw, tw, n_state), _F32), blocks)
 
 
 _PARAMS = pltpu.CompilerParams(
@@ -251,53 +292,73 @@ _PARAMS = pltpu.CompilerParams(
 # (both calls under a `jax.jit` of their own: jax traces a kernel's body anew
 # at every `pallas_call`, 0.15-0.3 s each for these bodies, and a round holds
 # thirty; jitted, a process traces and lowers each of the three once)
-@functools.partial(jax.jit, static_argnames=("p", "dtype", "interpret", "states"))
-def _forward(xs, p: int, dtype, interpret: bool, states: bool):
-    grid, ins, like_x, st_spec, st_aval, scratch = _specs(xs, p, False)
+@functools.partial(jax.jit, static_argnames=("p", "n_state", "dtype", "interpret",
+                                             "states"))
+def _forward(xs, cuts, p: int, n_state: int, dtype, interpret: bool, states: bool):
+    grid, ins, like_x, _, st_spec, st_aval, scratch, _ = _specs(
+        xs, cuts, p, n_state, False)
     outs = [(like_x, _struct(xs[0].shape, _F32, xs[0]))] + [(st_spec, st_aval)] * states
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, p=p, dtype=dtype),
+        functools.partial(_fwd_kernel, p=p, dtype=dtype, cuts=len(cuts)),
         grid=grid, in_specs=ins, out_specs=[spec for spec, _ in outs],
         out_shape=[aval for _, aval in outs], scratch_shapes=[scratch],
         compiler_params=_PARAMS, interpret=interpret,
         name="ssd_chunk_fwd",  # the kernel's stable name in a device trace
-    )(*xs)
+    )(*xs, *cuts)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def ssd_chunks(x, b, c, dt_row, run_row, dt_col, run_col, p: int, dtype,
-               interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def ssd_chunks(x, b, c, dt_row, run_row, dt_col, run_col, cuts, p: int,
+               n_state: int, dtype, interpret: bool = False):
     """`ops.ssd.ssd` on whole chunks as a kernel: y [rows, n, heads x P]
     float32 from x [rows, n, heads x P] (heads of `p` channels), B and C
-    [rows, n, groups x N], and the float32 time steps and running sums
-    inside a chunk, both as rows [rows, n / Q, groups, per, Q] and as
-    columns [rows, n / Q, groups, Q, per]; the products' operands in `dtype`
-    (the precision policy's)."""
-    return _forward((x, b, c, dt_row, run_row, dt_col, run_col), p=p, dtype=dtype,
-                    interpret=interpret, states=False)[0]
+    [rows, n, groups x N] (N = `n_state`), and the float32 time steps and
+    running sums inside a chunk, both as rows [rows, n / Q, programs, per, Q] and as
+    columns [rows, n / Q, programs, Q, per] (`programs` x `per` the heads,
+    group by group: as many programs a group as its heads are worked in
+    blocks); `cuts` () or the chunks' document runs (`_specs`); the
+    products' operands in `dtype` (the precision policy's)."""
+    return _forward((x, b, c, dt_row, run_row, dt_col, run_col), cuts, p=p,
+                    n_state=n_state, dtype=dtype, interpret=interpret,
+                    states=False)[0]
 
 
-def _ssd_chunks_fwd(x, b, c, dt_row, run_row, dt_col, run_col, p, dtype, interpret):
+def _ssd_chunks_fwd(x, b, c, dt_row, run_row, dt_col, run_col, cuts, p, n_state,
+                    dtype, interpret):
     xs = (x, b, c, dt_row, run_row, dt_col, run_col)
-    y, states = _forward(xs, p=p, dtype=dtype, interpret=interpret, states=True)
+    y, states = _forward(xs, cuts, p=p, n_state=n_state, dtype=dtype,
+                         interpret=interpret, states=True)
     # the residuals: the inputs and the state every chunk started from
-    return y, xs + (states,)
+    return y, (xs, cuts, states)
 
 
-@functools.partial(jax.jit, static_argnames=("p", "dtype", "interpret"))
-def _backward(xs, states, dy, p: int, dtype, interpret: bool):
-    grid, ins, like_x, st_spec, _, scratch = _specs(xs, p, True)
-    return tuple(pl.pallas_call(
-        functools.partial(_bwd_kernel, p=p, dtype=dtype),
-        grid=grid, in_specs=ins + [st_spec, like_x], out_specs=ins,
-        out_shape=[_struct(t.shape, t.dtype, xs[0]) for t in xs],
+@functools.partial(jax.jit, static_argnames=("p", "n_state", "dtype", "interpret"))
+def _backward(xs, cuts, states, dy, p: int, n_state: int, dtype, interpret: bool):
+    grid, ins, like_x, per_program, st_spec, _, scratch, blocks = _specs(
+        xs, cuts, p, n_state, True)
+    outs = [(spec, _struct(t.shape, t.dtype, xs[0])) for spec, t in zip(ins, xs)]
+    if blocks > 1:  # dB and dC a block of the group's heads apiece, float32
+        rows, n, _ = xs[0].shape
+        outs[1:3] = [(per_program, _struct((rows, n, grid[1] * n_state), _F32,
+                                           xs[0]))] * 2
+    got = list(pl.pallas_call(
+        functools.partial(_bwd_kernel, p=p, dtype=dtype, cuts=len(cuts)),
+        grid=grid, in_specs=ins + [st_spec, like_x],
+        out_specs=[spec for spec, _ in outs],
+        out_shape=[aval for _, aval in outs],
         scratch_shapes=[scratch], compiler_params=_PARAMS, interpret=interpret,
         name="ssd_chunk_bwd",
-    )(*xs, states, dy))
+    )(*xs, *cuts, states, dy))
+    if blocks > 1:
+        got[1:3] = [jnp.sum(t.reshape(rows, n, -1, blocks, n_state), axis=3)
+                    .reshape(xs[1].shape).astype(xs[1].dtype) for t in got[1:3]]
+    return tuple(got)
 
 
-def _ssd_chunks_bwd(p, dtype, interpret, res, dy):
-    return _backward(res[:-1], res[-1], dy, p, dtype, interpret)
+def _ssd_chunks_bwd(p, n_state, dtype, interpret, res, dy):
+    xs, cuts, states = res
+    return _backward(xs, cuts, states, dy, p, n_state, dtype, interpret) \
+        + (tuple(None for _ in cuts),)
 
 
 ssd_chunks.defvjp(_ssd_chunks_fwd, _ssd_chunks_bwd)

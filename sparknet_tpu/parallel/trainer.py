@@ -896,7 +896,7 @@ class ParallelTrainer:
                 traced.jaxpr.jaxpr, self.net.attention_scopes(),
                 self.net.routing_scopes(), self.net.delta_scopes(),
                 self.net.eva_scopes(), self.net.ssd_scopes(),
-                self.net.window_scopes())
+                self.net.window_scopes(), self.net.ssd_kernels())
         return self._report
 
     def resized(self, n_devices: int) -> "ParallelTrainer":

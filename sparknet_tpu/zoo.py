@@ -7,7 +7,7 @@ Mirrors the architectures of the reference zoo (reference `models/`):
   - lenet          <- models/tensorflow/mnist/mnist_graph.py (LeNet-style)
   - adult_mlp      <- models/adult/adult.prototxt
 
-and six families of sequence models, each built from a file of its
+and seven families of sequence models, each built from a file of its
 published config:
   - glm4_moe_lite  <- huggingface.co/zai-org/GLM-4.7-Flash config.json
                       (latent attention, routed experts of which this chip
@@ -41,6 +41,16 @@ published config:
                       a rotary turn, or over a sliding window with one;
                       ReGLU experts weighted by a softmax over the chosen
                       logits; no dense layer, no shared expert)
+  - granitemoehybrid <- huggingface.co/ibm-granite/granite-4.0-h-micro
+                      config.json (two sublayers a layer: a Mamba-2 mixer of
+                      ONE group that all 64 heads read, or grouped-query
+                      attention without a rotary turn, by the config's
+                      `layer_types`, then a dense SwiGLU; muP's four
+                      multipliers in the stream, the scores and the logits;
+                      a tied head; rows that hold SEVERAL DOCUMENTS -- a
+                      second input of document ids cuts the taps, the scan,
+                      the attention and the loss at every document's first
+                      position)
 
 Specs are built in code (the TPU-native "declarative model" is data either
 way); the prototxt importer covers file-based definition parity.
@@ -220,12 +230,13 @@ def _rms_layer(name, bottom, block, eps, unit_offset=False) -> LayerSpec:
                      rmsnorm=RMSNormParam(eps=eps, unit_offset=unit_offset))
 
 
-def _sum_layer(name, a, b, top, block, float32=False) -> LayerSpec:
+def _sum_layer(name, a, b, top, block, float32=False, coeff=()) -> LayerSpec:
     """The residual sum (taken and carried in float32 where the model's
-    stream is)."""
+    stream is; `coeff`: a + coeff[1] b where the model scales its branch)."""
     return LayerSpec(name=name, type="Eltwise", bottoms=(a, b), tops=(top,),
                      block=block,
-                     eltwise=EltwiseParam(float32=True) if float32 else None)
+                     eltwise=EltwiseParam(float32=float32, coeff=tuple(coeff))
+                     if float32 or coeff else None)
 
 
 def _ff_layer(l: str, dense: bool, dense_width: int, experts: MoEParam,
@@ -886,8 +897,117 @@ def smallthinker(config: dict, rows: int, positions: int) -> NetSpec:
                    layers=tuple(layers))
 
 
+def granitemoehybrid(config: dict, rows: int, positions: int) -> NetSpec:
+    """A `granitemoehybrid` decoder without experts (Granite-4.0-H-Micro) as
+    ONE PIPELINE STAGE's layers with a share of the tied vocabulary, for
+    training on `[rows, positions]` int32 token ids whose rows hold SEVERAL
+    DOCUMENTS one behind another (inputs `tokens` and `doc_ids`, the same
+    shape: ids equal along a document and changing at every document's first
+    position; the targets are the ids themselves, read one position on,
+    and a document's last position has none).
+
+    `config` holds the keys of the model's published `config.json` as run
+    here -- `num_hidden_layers` layers whose mixer `layer_types` names one by
+    one ("mamba": a Mamba-2 mixer of `mamba_n_heads` heads of `mamba_d_head`
+    over `mamba_n_groups` groups of state `mamba_d_state`, `mamba_d_conv`
+    biased taps, chunks of `mamba_chunk_size`; "attention": grouped-query
+    attention, no rotary turn, no head norm, scores times
+    `attention_multiplier`), `vocab_size` rows of the vocabulary HELD -- and
+    a `share` block: `vocab_rows` [first, count], `first_layer`.
+
+    Every layer is two sublayers, h += residual_multiplier x
+    Mixer(RMSNorm(h)); h += residual_multiplier x SwiGLU(RMSNorm(h)), the
+    SwiGLU `intermediate_size` wide; the stream starts as
+    `embedding_multiplier` x the table's rows and the logits are RMSNorm(h)
+    E^T / `logits_scaling`, on the table itself (tied), over the held rows.
+    No position is carried anywhere, so a packed row needs nothing but the
+    cuts: the document ids go to every mixer (the taps, the scan's state and
+    the keys a query reads stop at a document's first position) and to the
+    loss. Loss = CE(next token), a mean over the positions that have a
+    target. Every layer is a recomputation block.
+
+    Refused: experts (`num_local_experts` > 0: the family's expert block),
+    a `position_embedding_type` other than "nope", any bias but the taps',
+    an untied head."""
+    c, share = config, config["share"]
+    d, eps, std = c["hidden_size"], c["rms_norm_eps"], 0.02
+    kinds, depth = c["layer_types"], c["num_hidden_layers"]
+    vocab = share["vocab_rows"][1]
+    if vocab != c["vocab_size"]:
+        raise ValueError(f"the share block and the held count disagree: "
+                         f"vocab_rows {share['vocab_rows']} against "
+                         f"vocab_size {c['vocab_size']}")
+    if len(kinds) != depth or set(kinds) - {"mamba", "attention"}:
+        raise ValueError(f"layer_types {kinds} does not name the mixer "
+                         f"(mamba | attention) of each of the {depth} layers")
+    if (c.get("num_local_experts") or c.get("num_experts_per_tok")
+            or c.get("position_embedding_type") != "nope"
+            or c.get("attention_bias") or c.get("mamba_proj_bias")
+            or not c.get("mamba_conv_bias") or not c.get("tie_word_embeddings")
+            or c.get("hidden_act") != "silu"
+            or c.get("normalization_function", "rmsnorm") != "rmsnorm"
+            or c.get("shared_intermediate_size") != c["intermediate_size"]
+            or c["mamba_n_heads"] * c["mamba_d_head"] != c["mamba_expand"] * d):
+        raise ValueError("built: no experts, no rotary turn (nope), biased "
+                         "taps and no other bias, a tied head, SiLU, RMS "
+                         "norms, expand x hidden = heads x head; the file "
+                         "asks for something else")
+    mixer = Mamba2Param(
+        num_heads=c["mamba_n_heads"], head_dim=c["mamba_d_head"],
+        n_groups=c["mamba_n_groups"], state_size=c["mamba_d_state"],
+        taps=c["mamba_d_conv"], chunk_size=c["mamba_chunk_size"], eps=eps,
+        std=std)
+    attention = GQAttentionParam(
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        head_dim=d // c["num_attention_heads"], eps=eps, std=std,
+        rotary=False, qk_norm=False,
+        score_scale=float(c["attention_multiplier"]))
+    branch = (1.0, float(c["residual_multiplier"]))
+    norm = lambda name, bottom, block: _rms_layer(name, bottom, block, eps)
+
+    layers = [LayerSpec(name="embed", type="Embed", bottoms=("tokens",),
+                        tops=("x0",), embed=EmbedParam(
+                            num_embeddings=vocab, dim=d, std=std,
+                            multiplier=float(c["embedding_multiplier"])))]
+    for i, kind in enumerate(kinds):
+        l, x = f"l{i}", f"x{i}"
+        mix = f"{l}_mamba" if kind == "mamba" else f"{l}_attn"
+        layers += [
+            norm(f"{l}_norm", x, l),
+            LayerSpec(name=mix, type="Mamba2", bottoms=(f"{l}_norm", "doc_ids"),
+                      tops=(mix, f"{mix}_counters"), mamba2=mixer, block=l)
+            if kind == "mamba" else
+            LayerSpec(name=mix, type="GQAttention",
+                      bottoms=(f"{l}_norm", "doc_ids"), tops=(mix,),
+                      gqa=attention, block=l),
+            _sum_layer(f"{l}_res", x, mix, f"{l}_h", l, coeff=branch),
+            norm(f"{l}_mlp_norm", f"{l}_h", l),
+            LayerSpec(name=f"{l}_mlp", type="GatedMLP",
+                      bottoms=(f"{l}_mlp_norm",), tops=(f"{l}_mlp",), block=l,
+                      gated_mlp=GatedMLPParam(
+                          intermediate_size=c["intermediate_size"], std=std)),
+            _sum_layer(f"{l}_mlp_res", f"{l}_h", f"{l}_mlp", f"x{i + 1}", l,
+                       coeff=branch)]
+    layers += [
+        norm("final_norm", f"x{depth}", "head"),
+        LayerSpec(name="lm_head", type="InnerProduct", bottoms=("final_norm",),
+                  tops=("lm_head",), param_from="embed", block="head",
+                  inner_product=InnerProductParam(
+                      num_output=vocab, bias_term=False, axis=-1,
+                      transposed=True, divisor=float(c["logits_scaling"]))),
+        LayerSpec(name="loss", type="SoftmaxWithLoss",
+                  bottoms=("lm_head", "tokens", "doc_ids"), tops=("loss",),
+                  block="head", loss=LossParam(label_shift=1))]
+    return NetSpec(name="granitemoehybrid",
+                   inputs=(InputSpec("tokens", (rows, positions), "int32"),
+                           InputSpec("doc_ids", (rows, positions), "int32")),
+                   layers=tuple(layers))
+
+
 #: `model_type` of a published config.json -> its builder (config, rows,
 #: positions) -> NetSpec
 SEQUENCE_MODELS = {"glm4_moe_lite": glm4_moe_lite, "lfm2_moe": lfm2_moe,
                    "ling3_flash": ling3_flash, "evabyte": evabyte,
-                   "nemotron_h": nemotron_h, "smallthinker": smallthinker}
+                   "nemotron_h": nemotron_h, "smallthinker": smallthinker,
+                   "granitemoehybrid": granitemoehybrid}

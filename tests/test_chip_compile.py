@@ -148,24 +148,48 @@ def test_kda_shape_kernels_compile_for_v5e(v5e, grad, dtype):
     assert ("kda_shape_bwd" if grad else "kda_shape_fwd") in text
 
 
+#: the scan kernels' shapes: (rows, positions, groups, heads a group, chunk,
+#: document runs): the state-space cell's, and the packed cell's (one group
+#: of 64 heads worked in eight blocks of eight, chunks of 256, rows that
+#: hold several documents)
+SSD_KERNEL_SHAPES = {"nemotron": (2, 8192, 2, 16, 128, False),
+                     "granite-packed": (1, 16384, 1, 64, 256, True)}
+
+
+@pytest.mark.parametrize("shape", sorted(SSD_KERNEL_SHAPES))
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
-def test_ssd_kernels_compile_for_v5e(v5e, grad, dtype):
+def test_ssd_kernels_compile_for_v5e(v5e, grad, dtype, shape):
     """`ops.pallas_ssd`'s pair at the state-space cell's shape (2 rows of
     8,192 positions, 32 heads of 64 in 2 groups, state 128: a grid of 2 x 2
     x 64 chunks, sixteen heads -- eight lane tiles -- a program, the float32
-    state [8, 128, 128] in a scratch): Mosaic takes every op of both bodies
-    (the lane and sublane selects, the [128, 1] columns spread over lanes,
-    the reversed walk's index map), and blocks, scratch and temporaries fit
-    the 16 MiB of scoped VMEM a call has unasked, float32 operands too."""
+    state [8, 128, 128] in a scratch) and at the packed cell's (1 row of
+    16,384, 64 heads in ONE group, chunks of 256: a grid of 1 x 8 x 64, the
+    group's heads in eight blocks of eight, [256, 256] squares, the
+    document runs as a row and a column of int32): Mosaic takes every op of
+    both bodies (the lane and sublane selects, the [Q, 1] columns spread
+    over lanes, the reversed walk's index map, a block's `g // 8` map onto
+    its group's B and C), and blocks, scratch and temporaries fit the 16 MiB
+    of scoped VMEM a call has unasked, float32 operands too."""
+    from sparknet_tpu.ops import ssd as ssd_ops
     from sparknet_tpu.ops.pallas_ssd import ssd_chunks
+    r, n, groups, per, q, docs = SSD_KERNEL_SHAPES[shape]
+    at_once = ssd_ops.program_heads(q, per, 64, 128)
+    assert at_once == 16 * 128 // q
+    programs = groups * per // at_once
     one = SingleDeviceSharding(v5e[0])
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    total = lambda *a: jnp.sum(ssd_chunks(*a, 64, jnp.dtype(dtype), False))
+    cuts = (s((r, n // q, 1, 1, q), jnp.int32),
+            s((r, n // q, 1, q, 1), jnp.int32)) if docs else ()
+    total = lambda *a: jnp.sum(ssd_chunks(*a[:7], tuple(a[7:]), 64, 128,
+                                          jnp.dtype(dtype), False))
     fn = jax.grad(total, argnums=tuple(range(7))) if grad else total
-    rows, cols = s((2, 64, 2, 16, 128), jnp.float32), s((2, 64, 2, 128, 16), jnp.float32)
-    text = _compiled_text(fn, s((2, 8192, 2048), dtype), s((2, 8192, 256), dtype),
-                          s((2, 8192, 256), dtype), rows, rows, cols, cols)
+    rows = s((r, n // q, programs, at_once, q), jnp.float32)
+    cols = s((r, n // q, programs, q, at_once), jnp.float32)
+    text = _compiled_text(fn, s((r, n, groups * per * 64), dtype),
+                          s((r, n, groups * 128), dtype),
+                          s((r, n, groups * 128), dtype), rows, rows, cols, cols,
+                          *cuts)
     # the gradient is the forward with its chunk states, then the backward
     assert text.count("tpu_custom_call") == (2 if grad else 1)
     assert "ssd_chunk_fwd" in text and ("ssd_chunk_bwd" in text) == grad
@@ -819,9 +843,9 @@ def _sequence_round(v5e, config: str):
         key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 1))
         compiled = trainer._round.lower(
             _state_avals(trainer),
-            {"tokens": jax.ShapeDtypeStruct(
+            {name: jax.ShapeDtypeStruct(  # `tokens`; `doc_ids` beside them
                 (c["tau"], c["local_batch"], c["seq_len"]), jnp.int32,
-                sharding=batch)},
+                sharding=batch) for name in trainer.net.input_shapes},
             jax.ShapeDtypeStruct(key.shape, key.dtype,
                                  sharding=NamedSharding(mesh, P(DATA_AXIS))),
             jax.ShapeDtypeStruct((), jnp.float32,
@@ -1068,6 +1092,44 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # rows and add them by token three times (the combine, the combine made
     # again for `latent_up`'s weight gradient, the dispatch's backward)
     _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3, k=22)
+
+
+@pytest.mark.slow
+def test_granite_packed_round_compiles_for_v5e_and_fits(v5e, as_tpu):
+    """The packed state-space hybrid's round (`granite4-h-micro-pp4-tau4`:
+    nine Mamba-2 mixers of 64 heads in one group at chunks of 256, one
+    grouped-query attention without a rotary turn, a dense SwiGLU behind
+    every mixer, a tied head over 12,544 rows, ONE row of 16,384 positions
+    that holds several documents) for one described chip: 6.18 GB of state
+    (772,160,448 parameters and their momentum) + 9.46 GB of temporaries =
+    15.64 GB, under 15.8 together (ten layers' kept SwiGLU products are 5.4
+    GB of them; at a quarter of the vocabulary, 25,088 rows, the compiler
+    refuses the round: 5.94 GiB of state + 10.01 of temporaries against
+    15.75). The attention core runs as a kernel once a step body on its
+    forward path alone, under segment ids; every scan is `ops.pallas_ssd`'s
+    kernel pair under document runs (two step bodies x nine layers x
+    forward, forward made again with its chunk states, backward) and no
+    device loop; no SwiGLU product and no logits are made twice."""
+    compiled, trainer = _sequence_round(v5e, "granite4-h-micro-pp4-tau4")
+    total = _round_bytes(compiled)
+    assert total < 15.8e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
+    text = compiled.as_text()
+    assert "splash_mha" in text and "16384,16384" not in text
+    from sparknet_tpu.obs.device import parse_hlo_ops, recompute_report, ssm
+    ops = parse_hlo_ops(text)
+    kept = recompute_report(ops, trainer.net.kept_makers())
+    assert kept["attn_core"]["step_bodies"] == 2  # the loop's, the peeled
+    assert kept["attn_core"]["forward"] >= 2 and kept["attn_core"]["backward"] == 0
+    _products_made_once(text, kept, "mlp_pre", "GatedMLP", 20)
+    _products_made_once(text, kept, "ip_out", "InnerProduct", 1)
+    scans = ssm(ops, trainer.net.ssd_scopes())
+    print("ssm:", scans)
+    assert scans["layers"] == 9 and scans["kernel_calls"] == 2 * 9 * 3, scans
+    assert (scans["loops"], scans["trips"], scans["carried_bytes"]) == (0, 0, 0), scans
+    assert trainer.net.ssd_kernel_shape(trainer.net.spec.layer_by_name("l0_mamba")) \
+        == {"chunk": 256, "heads_per_program": 8, "programs_per_group": 8}
+    assert set(trainer.counter_blobs) == {f"l{i}_mamba_counters"
+                                          for i in (0, 1, 2, 3, 4, 6, 7, 8, 9)}
 
 
 @pytest.mark.slow

@@ -167,11 +167,11 @@ def test_the_forward_alone_writes_no_state_and_the_rule_keeps_one_a_chunk():
     xs = (args[0].reshape(1, 384, 256), args[3].reshape(1, 384, 256),
           args[4].reshape(1, 384, 256), *rows,
           *(jnp.swapaxes(t, -1, -2) for t in rows))
-    alone = jax.eval_shape(lambda *a: pk._forward(a, 64, jnp.float32, True, False), *xs)
-    kept = jax.eval_shape(lambda *a: pk._forward(a, 64, jnp.float32, True, True), *xs)
+    alone = jax.eval_shape(lambda *a: pk._forward(a, (), 64, 128, jnp.float32, True, False), *xs)
+    kept = jax.eval_shape(lambda *a: pk._forward(a, (), 64, 128, jnp.float32, True, True), *xs)
     assert [o.shape for o in alone] == [(1, 384, 256)]
     assert [o.shape for o in kept] == [(1, 384, 256), (1, 3, 2, 128, 128)]
-    y, states = pk._forward(xs, 64, jnp.float32, True, True)
+    y, states = pk._forward(xs, (), 64, 128, jnp.float32, True, True)
     want, last = ssd_ops.ssd_recurrent(*(t[:, :256] for t in args[:2]), args[2],
                                        *(t[:, :256] for t in args[3:]))
     # the third chunk starts from the state the first 256 positions leave
