@@ -795,8 +795,10 @@ def kda(p: KDAttentionParam, params: Params, x, ctx):
     layout, SiLU after, the L2 norms, the decay and the writing strength,
     all float32: one Pallas kernel forward and one backward where the shape
     is theirs, `causal_taps` and plain `jnp` under a checkpoint elsewhere);
-    the rule itself is `ops.delta_rule.gated_delta_rule`; its result is
-    normed a head, scaled by the head-wise gate and contracted with `o` as
+    the rule itself is `ops.delta_rule.gated_delta_rule` (two kernel pairs
+    where the shape is theirs, the chunk stage's and the walk's over a row's
+    chunks with the state in VMEM; `jnp` and a `lax.scan` elsewhere); its
+    result is normed a head, scaled by the head-wise gate and contracted with `o` as
     it lies.
 
     ONE ROW AT A TIME, in a checkpointed `lax.map`: what the backward pass of

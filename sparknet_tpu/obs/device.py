@@ -888,14 +888,20 @@ def delta_rule(ops: Dict[str, Dict[str, Any]], scopes: Dict[str, str],
     """How a program's delta rules were compiled: of the device ops under
     their scopes (`scopes`: layer type -> the scope under the layer's own
     that holds its rule; `CompiledNet.delta_scopes()`), `{"loops": the
-    `while` instructions among them in the WHOLE program (a layer's rows go
-    through loops of their own, so a step's are spread over several
-    computations; a round holds a step twice, as the scanned body and as the
-    peeled last step), "trips": their trip counts together (a loop whose
-    count the text does not give counts 1), "kernel_calls": the Pallas
-    kernels' `custom-call` instructions among them in the whole program
-    (the chunk stage's, `ops.pallas_delta_rule`: 0 where the `jnp` form
-    was taken), "shape_kernel_calls": those of such layers in the whole
+    `while` instructions among them in the WHOLE program (the `jnp` form's
+    scans over segments and chunks, forward, made again and transposed; a
+    layer's rows go through loops of their own, so a step's are spread over
+    several computations; a round holds a step twice, as the scanned body
+    and as the peeled last step; where the kernels ran the walk over a
+    row's chunks is `ops.pallas_delta_scan`'s grid, the state in VMEM, and
+    the loops left are the backward pass's walk for the state every segment
+    started from, a scan of scans a layer and step body),
+    "trips": their trip counts together (a loop whose count the text does
+    not give counts 1), "kernel_calls": the Pallas kernels' `custom-call`
+    instructions among them in the whole program (the chunk stage's,
+    `ops.pallas_delta_rule`, and the walk's, `ops.pallas_delta_scan`, each
+    forward, forward again by the row and backward: six a layer and step
+    body; 0 where the `jnp` form was taken), "shape_kernel_calls": those of such layers in the whole
     program under the scope of the stage before the rule, which shapes q,
     k, v and the decay (`ops.kda_shape.SCOPE`, the scope its kernel pair
     runs under: `ops.pallas_kda_shape`; 0 where its `jnp` form was taken; a

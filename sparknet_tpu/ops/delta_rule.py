@@ -10,18 +10,21 @@ recurrence a position at a time (the oracle of the tests); `gated_delta_rule`
 is the form that runs: CHUNKED, so that the work is matrix products and the
 sequential part is one step a chunk. WHICH form a layer runs is decided here
 and nowhere else, from what this module can observe (no option, no enum):
-the chunk stage -- what a chunk's step of the scan reads -- is the Pallas
-kernel pair of `pallas_delta_rule` where a Pallas call may run
-(`ops.lrn.pallas_backend`: the TPU, or any backend under the interpreter)
-and the shape is the kernels' (`_can_pallas`: chunks of 64, heads whose
-widths are multiples of the 128 lanes), and `_chunk_operands` in plain `jnp`
-everywhere else: narrow heads, short rows, any other backend. The `jnp` form
-is the kernels' oracle (`tests/test_delta_rule.py`), the scan over chunks is
-one `lax.scan` for both. What stands BEFORE the chunk stage is the layer's
-own (`ops.kda_shape`: the convolutions, norms and gates that make q, k, v, g
-and b from the projections); where that stage runs as its kernel pair it
-writes q, k, v in the policy's dtype and g in float32, whole programs of
-positions a row, so `gated_delta_rule`'s own casts and pads are no-ops and
+both stages -- the chunk stage, what a chunk's step of the walk reads, and
+the WALK over the chunks that carries the state -- are Pallas kernel pairs
+where a Pallas call may run (`ops.lrn.pallas_backend`: the TPU, or any
+backend under the interpreter) and the shape is the kernels' (`_can_pallas`,
+one gate for both: chunks of 64, heads whose widths are multiples of the 128
+lanes): `pallas_delta_rule` makes the six operands of every chunk,
+`pallas_delta_scan` walks them with the float32 state in VMEM. Everywhere
+else -- narrow heads, short rows, any other backend -- `_chunk_operands` in
+plain `jnp` makes the operands and one `lax.scan` walks them. The `jnp` form
+is the kernels' oracle (`tests/test_delta_rule.py`,
+`tests/test_delta_scan_kernel.py`). What stands BEFORE the chunk stage is
+the layer's own (`ops.kda_shape`: the convolutions, norms and gates that make
+q, k, v, g and b from the projections); where that stage runs as its kernel
+pair it writes q, k, v in the policy's dtype and g in float32, whole programs
+of positions a row, so `gated_delta_rule`'s own casts and pads are no-ops and
 nothing stands between that kernel's results and this one's operands.
 
 The chunked (WY) form. With a_t = exp g_t and u_t = b_t (v_t - S_{t-1}^T (a_t
@@ -57,14 +60,17 @@ rests on; the layer's gate keeps g inside it (the published
 
 Float32 for the decays, their running sums, the solve and the state; the
 precision policy's dtype for the operands of the large products (float32
-accumulation). The backward pass of the scan is autodiff under a
-`jax.checkpoint` a segment of chunks: it keeps one state a segment. The
-backward pass of the chunk stage is the kernel's OWN on the kernel path (a
-`jax.custom_vjp` whose residuals are the five inputs: the backward kernel
-makes the forward's intermediates again in VMEM and, with (I + N)^-1 at hand,
-needs no substitution); in the `jnp` form it is autodiff under a second
-`jax.checkpoint` that keeps the inputs and makes the operands again a
-segment at a time.
+accumulation). The backward passes on the kernel path are the kernels' OWN
+(two `jax.custom_vjp`s): the chunk stage's residuals are its five inputs
+(the backward kernel makes the forward's intermediates again in VMEM and,
+with (I + N)^-1 at hand, needs no substitution), the walk's are the six
+operands (its backward pass first makes the state every segment of `SEGMENT`
+chunks started from, `segment_states`, a scan of two products a chunk; its
+backward kernel then makes a segment's chunk-start states again in VMEM and
+walks the segments in reverse). In the `jnp` form both are autodiff: of the
+scan under a `jax.checkpoint` a segment of chunks, which keeps one state a
+segment, and of the chunk stage under a second `jax.checkpoint` that keeps
+the inputs and makes the operands again a segment at a time.
 """
 from __future__ import annotations
 
@@ -82,8 +88,9 @@ from .lrn import pallas_backend
 CHUNK = 64
 SUB = 16
 MIN_LOG_DECAY = -5.0
-#: chunks a segment: whose operands are made together, and which the scan's
-#: backward pass makes again from one kept state
+#: chunks a segment: whose operands are made together (the `jnp` form), and
+#: which the walk's backward pass makes again from the one state it started
+#: from (both forms)
 SEGMENT = 8
 
 
@@ -225,14 +232,18 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
 
     Two stages. What a chunk's step reads (the decays' running sums, the
     factored pair terms, the solve) is made for every leading entry (row,
-    head) together and laid out chunks first, as the scan walks them: by the
-    kernel pair of `pallas_delta_rule` where `_can_pallas` holds (its custom
-    VJP keeps the inputs alone), else by `_chunk_operands` a segment of
-    `SEGMENT` chunks at a time in a checkpointed `lax.map`, whose
-    temporaries -- a dozen tensors of the inputs' size -- the backward pass
-    makes again a segment at a time and never holds whole. The scan over
-    chunks then carries the state and does four products a step; each
-    segment is a checkpoint, so the backward pass keeps one state a segment.
+    head) together and laid out chunks first, as the walk takes them; the
+    walk over chunks then carries the state and does four products a step.
+    Where `_can_pallas` holds both are kernel pairs: `pallas_delta_rule`'s
+    (its custom VJP keeps the inputs alone) and `pallas_delta_scan`'s (the
+    state in VMEM across a row's chunks, the result written as rows; its
+    custom VJP keeps the operands alone and its backward pass makes one
+    state a segment before the kernel walks back). Elsewhere the
+    operands are `_chunk_operands`' a segment of `SEGMENT` chunks at a time
+    in a checkpointed `lax.map`, whose temporaries -- a dozen tensors of the
+    inputs' size -- the backward pass makes again a segment at a time and
+    never holds whole, and the walk is a `lax.scan` whose every segment is a
+    checkpoint, so the backward pass keeps one state a segment.
 
     interpret: run the kernels under the Pallas INTERPRETER (the CPU parity
       tests of the path the chip runs), as `ops.lrn.lrn` does."""
@@ -244,8 +255,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
         c //= 2
     many = int(np.prod(lead, dtype=np.int64))
     kernel = _can_pallas(c, dk, dv, interpret)
-    if kernel:
-        from . import pallas_delta_rule as pk  # (it imports this module)
+    if kernel:  # (they import this module)
+        from . import pallas_delta_rule as pk, pallas_delta_scan as ps
     pad = pk.padding(n) if kernel else -n % c
     nc = (n + pad) // c
     seg = max(d for d in range(1, SEGMENT + 1) if nc % d == 0)
@@ -257,22 +268,33 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
     xs = (flat(q, 1), flat(k, 1), flat(v, 1), flat(g.astype(jnp.float32), 1),
           flat(beta.astype(jnp.float32), 0))
     if kernel:
-        ops = tuple(x.reshape((nc // seg, seg) + x.shape[1:])
-                    for x in pk.chunk_operands(*xs, precision.compute_dtype(),
-                                               interpret))
-    else:
-        xs = tuple(x.reshape((many, nc, c) + x.shape[2:]) for x in xs)
+        dtype = precision.compute_dtype()
+        o = ps.scan_chunks(*pk.chunk_operands(*xs, dtype, interpret), seg,
+                           dtype, interpret)                 # [many, nc * c, dv]
+        return o.reshape(lead + (nc * c, dv))[..., :n, :]
+    xs = tuple(x.reshape((many, nc, c) + x.shape[2:]) for x in xs)
 
-        def operands(i):
-            """Segment i's chunks of every leading entry, chunks leading (as
-            the scan walks them): [seg, many, C, ..]. Sliced out of the
-            closed-over inputs: handing the map the segments as inputs of
-            its own (transposed to lead) was 13 ms a layer-step SLOWER on
-            the chip (PERF.md section 6, PR 33)."""
-            part = (lax.dynamic_slice_in_dim(x, i * seg, seg, axis=1) for x in xs)
-            return tuple(jnp.moveaxis(x, 1, 0) for x in _chunk_operands(*part))
+    def operands(i):
+        """Segment i's chunks of every leading entry, chunks leading (as
+        the scan walks them): [seg, many, C, ..]. Sliced out of the
+        closed-over inputs: handing the map the segments as inputs of
+        its own (transposed to lead) was 13 ms a layer-step SLOWER on
+        the chip (PERF.md section 6, PR 33)."""
+        part = (lax.dynamic_slice_in_dim(x, i * seg, seg, axis=1) for x in xs)
+        return tuple(jnp.moveaxis(x, 1, 0) for x in _chunk_operands(*part))
 
-        ops = lax.map(jax.checkpoint(operands), jnp.arange(nc // seg))
+    ops = lax.map(jax.checkpoint(operands), jnp.arange(nc // seg))
+    return _walk(ops).reshape(lead + (nc * c, dv))[..., :n, :]
+
+
+def _walk(ops):
+    """The walk over chunks in plain `jnp`: o [many, chunks, C, dv] from the
+    six operands of every chunk, segments then chunks first ([segments, seg,
+    many, C, d]; exp G_C [segments, seg, many, dk]), the state zero before
+    the first. A `lax.scan` over segments of a `lax.scan` over a segment's
+    chunks, four products a step; each segment is a `jax.checkpoint`, so
+    autodiff keeps one state a segment."""
+    segments, seg, many, c, dv = ops[1].shape
 
     def step(s, x):
         w_k, w_v, k_end, d_end, q_dec, b_low = x
@@ -284,5 +306,22 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, *,
     s0 = jnp.zeros_like(ops[3][0, 0, :, :, None]) \
         * jnp.zeros_like(ops[1][0, 0, :, :1, :])
     _, o = lax.scan(jax.checkpoint(lambda s, x: lax.scan(step, s, x)), s0, ops)
-    o = jnp.moveaxis(o.reshape((nc, many, c, dv)), 0, 1)
-    return o.reshape(lead + (nc * c, dv))[..., :n, :]
+    return jnp.moveaxis(o.reshape((segments * seg, many, c, dv)), 0, 1)
+
+
+def segment_states(w_k, w_v, k_end, d_end, seg: int):
+    """The float32 state every segment of `seg` chunks starts from,
+    [segments, many, dk, dv], from four of the chunks' operands, chunks first
+    ([chunks, many, C, d]; exp G_C [chunks, many, dk]): `_walk`'s two
+    products that make the next state (U = W_v - W_k S, S' = Diag(exp G_C) S
+    + K_end^T U) and nothing of its result, as the same scan of scans. What
+    the kernels' backward walk starts from (`pallas_delta_scan`)."""
+    def step(s, x):
+        w_k, w_v, k_end, d_end = x
+        u = w_v - _mm("ltk,lkv->ltv", w_k, s)
+        return d_end[..., None] * s + _mm("ltk,ltv->lkv", k_end, u), None
+
+    by_segment = lambda x: x.reshape((x.shape[0] // seg, seg) + x.shape[1:])
+    s0 = jnp.zeros_like(d_end[0, :, :, None]) * jnp.zeros_like(w_v[0, :, :1, :])
+    return lax.scan(lambda s, x: (lax.scan(step, s, x)[0], s), s0,
+                    tuple(map(by_segment, (w_k, w_v, k_end, d_end))))[1]

@@ -6,8 +6,9 @@ near 0; the unit-lower-triangular inverse by hand; and what the sub-chunks
 are for: a chunk-wide factoring of the decay overflows where this one does
 not. And the kernel pair of `ops.pallas_delta_rule` under the Pallas
 interpreter: its six operands and five gradients against `_chunk_operands`
-and `jax.vjp` of it, and `gated_delta_rule` on the kernel path against the
-recurrence; every case above keeps running on the `jnp` form (heads of 16).
+and `jax.vjp` of it, and `gated_delta_rule` on the kernel path (the chunk
+stage's pair and the walk's, `ops.pallas_delta_scan`) against the recurrence;
+every case above keeps running on the `jnp` form (heads of 16).
 """
 from __future__ import annotations
 
@@ -255,11 +256,12 @@ def test_kernel_path_equals_the_recurrence_forward_and_gradient(n, gates):
 def test_which_form_runs_is_decided_by_backend_and_shape_alone():
     """The kernels where a Pallas call may run (here: the interpreter) and
     the heads fill the lanes; the `jnp` form for narrow heads, for a short
-    row's smaller chunk, and on this backend without the interpreter."""
+    row's smaller chunk, and on this backend without the interpreter
+    (`tests/test_delta_scan_kernel.py` holds the same for the walk's pair)."""
     calls = lambda interpret, **kw: str(jax.make_jaxpr(
         lambda *a: dr.gated_delta_rule(*a, interpret=interpret))(
             *_inputs(1, kw.pop("n", 128), "spread", lead=(1,), **kw))).count("pallas_call")
-    assert calls(True, dk=128, dv=128) == 1
+    assert calls(True, dk=128, dv=128) == 2        # the chunk stage's and the walk's
     assert calls(False, dk=128, dv=128) == 0       # the CPU: no Pallas call may run
     assert calls(True, dk=16, dv=8) == 0           # narrow heads
     assert calls(True, dk=128, dv=64) == 0
