@@ -399,6 +399,9 @@ CONTAINERS = ("while", "conditional", "call")
 _INLINED = ("calls", "to_apply", "select", "scatter")
 STEP_SCOPE, OPTIMIZER_SCOPE = "tau_step", "solver_update"
 PHASES = ("forward", "backward", "optimizer", "outside_step")
+#: what jax puts in the path of an op of a recomputation block's forward
+#: pass made again for its backward
+REMATTED = "rematted_computation"
 
 
 def _shapes(types: str) -> List[Tuple[int, Tuple[int, ...]]]:
@@ -450,7 +453,8 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
     """The compiled program's account of itself, by program name
     (`"train_round"`): `{"memory": {"argument", "output", "alias", "temp"}
     (bytes per device, XLA's memory analysis), "ops": {"%fusion.769":
-    {"scope", "phase", "layer_type", "layer", "opcode", "computation"},
+    {"scope", "phase", "recomputed", "layer_type", "layer", "opcode",
+    "computation"},
     ...}, "recompute": {"attn_core": {"kernel", "step_bodies", "forward",
     "backward", "kept_bytes"}, ...}, "attention_moves": {"instructions",
     "bytes", "gathers_scatters"}, "routing_moves": {"instructions", "bytes",
@@ -498,13 +502,28 @@ def program_report(name: str) -> Optional[Dict[str, Any]]:
 
 
 def scope_of(op_name: str) -> Dict[str, Any]:
-    """`{"scope", "phase", "layer_type", "layer"}` of one `op_name` path.
-    Inside `tau_step`: under `solver_update` is optimizer, a path through
-    `transpose(` is backward, everything else (the loss's own arithmetic
-    with it) forward; outside `tau_step` (the scan's slicing of the stack,
-    the peeled step's copy, `tau_boundary`, the health reductions) is
-    `outside_step`. The layer is the `<Type>/<name>` scope `CompiledNet.
-    apply` opened."""
+    """`{"scope", "phase", "recomputed", "layer_type", "layer"}` of one
+    `op_name` path. Inside `tau_step`: under `solver_update` is optimizer, a
+    path through `transpose(` is backward, everything else (the loss's own
+    arithmetic with it) forward; outside `tau_step` (the scan's slicing of
+    the stack, the peeled step's copy, `tau_boundary`, the health reductions)
+    is `outside_step`. The layer is the `<Type>/<name>` scope `CompiledNet.
+    apply` opened.
+
+    `recomputed`: a backward path with `rematted_computation` among its
+    components -- the forward pass a recomputation block (`LayerSpec.block`,
+    `jax.checkpoint`) makes again for the backward it serves, and what a
+    layer's own checkpoints make again inside it (`KDAttention`'s rows). Such
+    an op stays `phase` backward: the flag splits that phase, it is no fifth.
+    It is read of the op's ATTRIBUTED path (`parse_hlo_ops`' rules), so a
+    fusion that mixes a block's recomputed elementwise work with a product
+    of the backward pass proper belongs to the product and is not
+    recomputed (and the other way about). What a kernel's own backward makes
+    again inside itself (`ssd_chunk_bwd`'s chunk states, `delta_scan_bwd`'s
+    chunk-start states, the splash backward's scores) and what a
+    `custom_vjp`'s backward makes again in plain jax
+    (`delta_rule.segment_states`) is backward proper: no block's doing, and
+    no kept name removes it. A net without blocks flags nothing."""
     parts = op_name.split("/")
     if parts and parts[0].startswith("jit("):
         parts = parts[1:]
@@ -519,6 +538,7 @@ def scope_of(op_name: str) -> Dict[str, Any]:
         phase = "forward"
     m = _LAYER.search(scope)
     return {"scope": scope, "phase": phase,
+            "recomputed": phase == "backward" and REMATTED in parts,
             "layer_type": m.group(1) if m else None,
             "layer": m.group(2) if m else None}
 
@@ -752,11 +772,6 @@ def _named_bytes(jaxpr, name: str) -> int:
     return max(here, in_a_loop)
 
 
-#: what jax puts in the path of an op of a recomputation block's forward
-#: pass made again for its backward
-REMATTED = "rematted_computation"
-
-
 def recompute_report(ops: Dict[str, Dict[str, Any]],
                      kept_makers: Dict[str, str],
                      jaxpr=None) -> Dict[str, Dict[str, Any]]:
@@ -767,12 +782,11 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
     `CompiledNet.kept_makers()`): `{"maker", "step_bodies": the
     computations that hold such an op (a loop's body, the peeled step),
     "forward" / "backward": the kernel calls and matrix products so marked
-    on a forward path and on a recomputed one (`phase` of `scope_of`
-    backward, under `rematted_computation`: a block's forward made again;
-    the products of the backward pass proper run under the same scope and
-    do not count) in the step body that has most, "kept_bytes": of the
-    named values in one step (`_named_bytes` of the program's `jaxpr`; None
-    without one)}`. The mechanism is engaged where "backward" is 0. Off the
+    on a forward path and on a recomputed one (`recomputed` of `scope_of`: a
+    block's forward made again; the products of the backward pass proper
+    run under the same scope and do not count) in the step body that has
+    most, "kept_bytes": of the named values in one step (`_named_bytes` of
+    the program's `jaxpr`; None without one)}`. The mechanism is engaged where "backward" is 0. Off the
     chip no kernel runs and a kernel's counts are both 0."""
     out = {}
     for name, maker in kept_makers.items():
@@ -780,8 +794,7 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
         for op in ops.values():
             made = (op["opcode"] == "custom-call" or op["matmul"]) and any(
                 part.startswith(maker) for part in op["scope"].split("/"))
-            again = op["phase"] == "backward" and REMATTED in op["scope"]
-            if made and (again or op["phase"] == "forward"):
+            if made and (op["recomputed"] or op["phase"] == "forward"):
                 body = count.setdefault(op["computation"], {})
                 body[op["phase"]] = (body.get(op["phase"], 0)
                                      + op.get("matmuls", 1))
@@ -1133,41 +1146,6 @@ def attach_program_gauges(registry: MetricsRegistry,
             f"attention, a step, that hold neither a matmul nor a kernel "
             f"(read by program_report)"
         ).set_fn(lambda key=key: part("attention_moves")[key])
-    for key, what in (("core_forward_calls", "forward kernel calls of the "
-                       "cores, a step"),
-                      ("core_backward_calls", "backward kernel calls of the "
-                       "cores, a step"),
-                      ("keys_per_query", "key columns a query row is given"),
-                      ("blocks_visited", "key blocks a core's forward visits"),
-                      ("blocks", "key blocks a core's forward could visit"),
-                      ("summary_bytes", "operand and result bytes of the "
-                       "chunk summaries' device ops, a step")):
-        registry.gauge(
-            f"sparknet_{name}_eva_{key}",
-            f"{what}, of the {name} program's EVA attention layers (read "
-            f"by program_report)"
-        ).set_fn(lambda key=key: part("eva")[key])
-    for key, what in (("windowed_layers", "layers that attend under a sliding "
-                       "window"),
-                      ("blocks_visited", "key blocks the cores' forward "
-                       "kernels visit under their masks, all layers"),
-                      ("blocks_causal", "key blocks a causal mask over every "
-                       "key would send them to")):
-        registry.gauge(
-            f"sparknet_{name}_window_{key}",
-            f"{what}, of the {name} program's grouped-query attention layers "
-            f"(read by program_report)"
-        ).set_fn(lambda key=key: part("window")[key])
-    for key, what in (("layers", "layers that hold a scan"),
-                      ("loops", "device loops of the scans, the whole program"),
-                      ("trips", "trips of those loops together"),
-                      ("bytes", "operand and result bytes of the scans' device "
-                       "ops that hold no product, a step")):
-        registry.gauge(
-            f"sparknet_{name}_ssm_{key}",
-            f"{what}, of the {name} program's state-space mixers (read by "
-            f"program_report)"
-        ).set_fn(lambda key=key: part("ssm")[key])
 
 
 def attach_round_counter_gauges(registry: MetricsRegistry, trainer) -> None:

@@ -6,6 +6,7 @@ layer, and the round program's account of itself
 """
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import re
@@ -16,6 +17,7 @@ import numpy as np
 import pytest
 
 from sparknet_tpu import CompiledNet
+from sparknet_tpu.model.seq_layers import KEPT_NAMES
 from sparknet_tpu.obs import device as obs_device
 from sparknet_tpu.obs import trace as obs_trace
 from sparknet_tpu.parallel import ParallelTrainer, ShardedTrainer, make_mesh
@@ -306,13 +308,14 @@ def test_attribution_rule_by_hand():
     assert obs_device.scope_of(
         "jit(train_round)/while/body/closed_call/tau_step/solver_update/mul")["phase"] == "optimizer"
     assert obs_device.scope_of("jit(train_round)/dynamic_slice") == {
-        "scope": "", "phase": "outside_step", "layer_type": None, "layer": None}
+        "scope": "", "phase": "outside_step", "recomputed": False,
+        "layer_type": None, "layer": None}
+    assert not any(op["recomputed"] for op in ops.values()), "no block made anything again"
 
 
-def test_compiled_round_names_every_caffenet_layer():
-    """A tiny CaffeNet round's compiled text carries every layer's
-    `<Type>/<name>` scope (the published size is compiled for a described
-    v5e by tests/test_chip_compile.py)."""
+@pytest.fixture(scope="module")
+def caffenet_report():
+    """(spec, the report of its compiled round) of a tiny CaffeNet."""
     spec = caffenet(batch=2, crop=67, n_classes=16)
     net = CompiledNet.compile(spec)
     trainer = ParallelTrainer(net, SolverConfig(base_lr=0.001, momentum=0.9),
@@ -323,7 +326,14 @@ def test_compiled_round_names_every_caffenet_layer():
     batches = {"data": r.standard_normal((2, 2, 67, 67, 3)).astype(np.float32),
                "label": r.integers(0, 16, (2, 2, 1)).astype(np.int32)}
     trainer.train_round(state, batches, jax.random.PRNGKey(1))
-    report = trainer.program_report()
+    return spec, trainer.program_report()
+
+
+def test_compiled_round_names_every_caffenet_layer(caffenet_report):
+    """A tiny CaffeNet round's compiled text carries every layer's
+    `<Type>/<name>` scope (the published size is compiled for a described
+    v5e by tests/test_chip_compile.py)."""
+    spec, report = caffenet_report
     named = {(op["layer_type"], op["layer"]) for op in report["ops"].values()}
     want = {(l.type, l.name) for l in spec.layers_for_phase("TRAIN")
             if l.type not in ("Softmax", "Accuracy")}  # off the loss's path
@@ -370,10 +380,8 @@ def test_the_window_part_of_a_compiled_text():
     calls by phase under its own core scope, in the step body that has most;
     the blocks a layer's mask visits of those a causal one would, passed
     through and summed; a layer with no window reports none; a net none of
-    whose layers has one reports nothing at all, and its gauges have no
-    sample."""
+    whose layers has one reports nothing at all."""
     from sparknet_tpu.model import seq_layers as sl
-    from sparknet_tpu.obs import MetricsRegistry
     call = ('custom-call(%p), custom_call_target="tpu_custom_call", '
             'metadata={op_name="jit(train_round)/tau_step/')
     text = "\n".join([
@@ -398,14 +406,143 @@ def test_the_window_part_of_a_compiled_text():
         "windowed_layers": 1, "blocks_visited": 412, "blocks_causal": 544}
     assert obs_device.window(ops, {}, {}) == {}
     assert "window" in obs_device.REPORT_PARTS
-    registry = MetricsRegistry()
-    obs_device.attach_program_gauges(registry, "w_round")
-    obs_device._program_parts["w_round"] = {"window": got}
-    try:
-        assert registry.gauge("sparknet_w_round_window_blocks_visited").value() == 412
-        assert registry.gauge("sparknet_w_round_window_blocks_causal").value() == 544
-        assert registry.gauge("sparknet_w_round_window_windowed_layers").value() == 1
-        obs_device._program_parts["w_round"] = {"window": {}}
-        assert "\nsparknet_w_round_window_blocks_visited " not in registry.render_prometheus()
-    finally:
-        del obs_device._program_parts["w_round"]
+
+
+# -- `recomputed`: a block's forward made again for the backward it serves ----
+
+def test_the_flag_tells_a_kernel_made_again_from_its_backward_neighbour():
+    """The made-up round of `tests/test_seq_model.py`: of five forward
+    kernels one runs under `rematted_computation`; the backward kernel beside
+    it, on the same `transpose(` path under the same block, is backward
+    proper."""
+    from test_seq_model import RECOMPUTE_HLO
+    ops = obs_device.parse_hlo_ops(RECOMPUTE_HLO)
+    assert {n for n, op in ops.items() if op["recomputed"]} == {
+        "%splash_mha_fwd_residuals.3"}
+    again, beside = ops["%splash_mha_fwd_residuals.3"], ops["%splash_mha_dkv_no_residuals.1"]
+    assert again["phase"] == beside["phase"] == "backward", "no fifth phase"
+    assert (again["layer_type"], again["layer"]) == ("MTP", "mtp") == (
+        beside["layer_type"], beside["layer"])
+
+
+_REMAT = "jit(train_round)/while/body/tau_step/transpose(jvp(tau_step))/jvp()/checkpoint/"
+REMAT_HLO = f"""HloModule jit_train_round
+
+%fused_proper (p0: f32[8,4], p1: f32[4,2]) -> f32[8,2] {{
+  %p0 = f32[8,4]{{1,0}} parameter(0)
+  %p1 = f32[4,2]{{1,0}} parameter(1)
+  %dot.1 = f32[8,2]{{1,0}} dot(%p0, %p1), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}, metadata={{op_name="{_REMAT}GatedMLP/l0_mlp/dot_general"}}
+  ROOT %mul.1 = f32[8,2]{{1,0}} multiply(%dot.1, %dot.1), metadata={{op_name="{_REMAT}rematted_computation/GatedMLP/l0_mlp/jit(silu)/mul"}}
+}}
+
+%fused_again (p0: f32[8,4], p1: f32[4,2]) -> f32[8,2] {{
+  %p0 = f32[8,4]{{1,0}} parameter(0)
+  %p1 = f32[4,2]{{1,0}} parameter(1)
+  %dot.2 = f32[8,2]{{1,0}} dot(%p0, %p1), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}, metadata={{op_name="{_REMAT}rematted_computation/GatedMLP/l0_mlp/mlp_pre/dot_general"}}
+  ROOT %mul.2 = f32[8,2]{{1,0}} multiply(%dot.2, %dot.2), metadata={{op_name="{_REMAT}GatedMLP/l0_mlp/mul"}}
+}}
+
+%fused_norm (p0: f32[8,4]) -> f32[8,4] {{
+  %p0 = f32[8,4]{{1,0}} parameter(0)
+  ROOT %mul.3 = f32[8,4]{{1,0}} multiply(%p0, %p0), metadata={{op_name="{_REMAT}rematted_computation/RMSNorm/l0_mlp_norm/mul"}}
+}}
+
+ENTRY %main.1 (x: f32[8,4], w: f32[4,2]) -> f32[8,2] {{
+  %x = f32[8,4]{{1,0}} parameter(0)
+  %w = f32[4,2]{{1,0}} parameter(1)
+  %norm.1 = f32[8,4]{{1,0}} fusion(%x), kind=kLoop, calls=%fused_norm
+  %copy.1 = f32[8,4]{{0,1}} copy(%norm.1)
+  %again.1 = f32[8,2]{{1,0}} fusion(%copy.1, %w), kind=kOutput, calls=%fused_again
+  %copy.2 = f32[8,4]{{0,1}} copy(%x)
+  %proper.1 = f32[8,2]{{1,0}} fusion(%copy.2, %w), kind=kOutput, calls=%fused_proper
+  %outside.1 = f32[8,2]{{1,0}} add(%again.1, %proper.1), metadata={{op_name="jit(train_round)/rematted_computation/add"}}
+  ROOT %update.1 = f32[8,2]{{1,0}} add(%outside.1, %proper.1), metadata={{op_name="jit(train_round)/tau_step/solver_update/rematted_computation/add"}}
+}}
+"""
+
+
+@pytest.mark.parametrize("name,phase,again,why", [
+    ("%norm.1", "backward", True, "a fusion without a product: its root's path"),
+    ("%again.1", "backward", True, "a fusion with a product made again is the "
+     "product's, whatever backward arithmetic was fused behind it"),
+    ("%proper.1", "backward", False, "a product of the backward pass proper with "
+     "a block's recomputed elementwise work fused behind it is the product's"),
+    ("%copy.1", "backward", True, "a nameless copy of what a recomputed op made: "
+     "its maker's"),
+    ("%copy.2", "backward", False, "a nameless copy with no named maker: its "
+     "user's, here backward proper"),
+    ("%outside.1", "outside_step", False, "outside the step nothing is a block's"),
+    ("%update.1", "optimizer", False, "nor under the optimizer"),
+])
+def test_the_flag_follows_the_attribution_rule(name, phase, again, why):
+    op = obs_device.parse_hlo_ops(REMAT_HLO)[name]
+    assert op["phase"] == phase
+    assert op["recomputed"] is again, why
+
+
+def test_a_net_without_blocks_flags_nothing(caffenet_report):
+    _, report = caffenet_report
+    assert {op["phase"] for op in report["ops"].values()} == set(obs_device.PHASES)
+    assert not any(op["recomputed"] for op in report["ops"].values())
+
+
+@functools.cache
+def _two_block_ops(model: str, bare: bool):
+    """(net, `parse_hlo_ops` of its compiled gradient under the step's
+    scope) of a sequence model's tiny file cut to ONE decoder layer and its
+    head: two recomputation blocks. `bare`: every block under the bare
+    `jax.checkpoint` (what a block was before its layers named anything)."""
+    import jax.numpy as jnp
+    from model_cases import POS, ROWS, case
+    from sparknet_tpu.model import net as net_mod
+    net = CompiledNet.compile(case(model).spec(
+        num_hidden_layers=1, num_nextn_predict_layers=0,
+        **({"hybrid_override_pattern": "M"} if model == "nemotron_h" else {})))
+    assert {l.block for l in net.spec.layers} == {None, "l0", "head"}
+    loss = net.loss_fn("loss")
+
+    def grad(p, ids):  # (a fresh function a trace: no policy in jax's key)
+        with jax.named_scope(obs_device.STEP_SCOPE):
+            return jax.value_and_grad(
+                lambda p: loss(p, {"tokens": ids}, None)[0])(p)
+    with pytest.MonkeyPatch.context() as patch:
+        if bare:
+            patch.setattr(net_mod, "_kept_names", lambda layers: ())
+        text = jax.jit(grad).lower(
+            jax.eval_shape(net.init_params, jax.random.PRNGKey(0)),
+            jax.ShapeDtypeStruct((ROWS, POS), jnp.int32)).compile().as_text()
+    return net, obs_device.parse_hlo_ops(text)
+
+
+@pytest.mark.parametrize("kind,model,scope,kept", [
+    ("GatedMLP", "glm4_moe_lite", "mlp_pre", "mlp_pre"),
+    ("MLAttention", "glm4_moe_lite", "core", "attn_core"),
+    ("Mamba2", "nemotron_h", "in_proj", None),
+    ("InnerProduct", "glm4_moe_lite", "ip_out", "ip_out")])
+def test_a_blocks_products_are_flagged_where_it_makes_them_again(
+        kind, model, scope, kept):
+    """Under the bare `jax.checkpoint` every product a layer makes under
+    `scope` on the forward path is there a second time, flagged; with the
+    layer's `KEPT_NAMES` entry no flagged product lies under the maker's
+    scope (off the chip the attention core's maker, a kernel, does not run:
+    there the kept output spares the core one of its two products). A layer
+    type that names nothing (`Mamba2`) reads as under the bare checkpoint."""
+    def products(ops, under, again):
+        return sum(op.get("matmuls", 1) for op in ops.values()
+                   if op["layer_type"] == kind and op["matmul"]
+                   and any(part.startswith(under) for part in op["scope"].split("/"))
+                   and (op["recomputed"] if again else op["phase"] == "forward"))
+    net, built = _two_block_ops(model, bare=False)
+    _, bare = _two_block_ops(model, bare=True)
+    for ops in (built, bare):
+        flagged = [op for op in ops.values() if op["recomputed"]]
+        assert flagged and {op["phase"] for op in flagged} == {"backward"}
+        assert any(not op["matmul"] for op in flagged), "the norms, made again"
+    assert products(bare, scope, True) == products(bare, scope, False) > 0
+    if kept is None:
+        assert kind not in KEPT_NAMES
+        assert products(built, scope, True) == products(built, scope, False) > 0
+    else:
+        assert kept in KEPT_NAMES[kind]
+        assert products(built, net.kept_makers()[kept], True) == 0
+        assert products(built, scope, True) < products(bare, scope, True)
