@@ -52,6 +52,17 @@ def _kept_names(layers) -> Tuple[str, ...]:
         n for l in layers for n in KEPT_NAMES.get(l.type, ())))
 
 
+@functools.cache
+def _keep(names: Tuple[str, ...]):
+    """The policy that keeps the values so named, ONE object a set of names:
+    jax memoises a block's partial evaluation by the policy's identity, so
+    blocks that share the object share the functions their jitted parts
+    lower to (a policy a block: Nemotron's round lowered to 868 functions
+    and 3.8 MB of text where this gives 154 and 2.3; PERF.md section 6,
+    PR 52)."""
+    return jax.checkpoint_policies.save_only_these_names(*names)
+
+
 def _to_nhwc_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
     if len(shape) == 4:
         n, c, h, w = shape
@@ -343,8 +354,7 @@ class CompiledNet:
                          if b in blobs}
                 owners = {l.param_from or l.name for l in layers}
                 names = _kept_names(layers)
-                policy = (jax.checkpoint_policies.save_only_these_names(*names)
-                          if names else None)
+                policy = _keep(names) if names else None
                 tops = jax.checkpoint(functools.partial(run, layers),
                                       policy=policy)(
                     {k: v for k, v in params.items() if k in owners}, needs)

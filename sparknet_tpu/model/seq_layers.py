@@ -69,7 +69,10 @@ sets its input behind an `optimization_barrier`, so that the norm before it
 is a value the backward pass reads and not one its products make again
 (`apply_gatedmlp`). An `InnerProduct` that stands in a block (a head, with
 its norm and loss) names its result, the logits: its block makes the
-model's largest product once a step (`layers.apply_innerproduct`).
+model's largest product once a step (`layers.apply_innerproduct`). An
+expert layer (`moe`) names what its backward pass reads of its routing --
+the chosen experts, their raw scores, the plan's index arrays -- a few MB a
+layer-step: its block makes no score product, sort or select again.
 """
 from __future__ import annotations
 
@@ -147,6 +150,10 @@ MLP_PRE = "mlp_pre"
 #: block (`layers.apply_innerproduct`: a head's logits), and the named scope
 #: its product runs under there
 IP_OUT = "ip_out"
+#: that on what an expert layer's backward pass reads of its routing: the
+#: chosen experts, their raw scores (`route`), the plan's index arrays that
+#: its weighted sums read and the grouped products' group sizes (`moe`)
+MOE_ROUTE = "moe_route"
 
 
 def param_defaults(pname: str) -> ParamSpec:
@@ -947,23 +954,32 @@ def chosen_scores(s, idx):
 chosen_scores.defvjp(_chosen_scores_fwd, _chosen_scores_bwd)
 
 
+def _chosen_logits(z, idx):
+    """(`idx` as int32, z[t, idx[t, j]] float32), both named MOE_ROUTE: all
+    the backward pass reads of a token's choice."""
+    idx = checkpoint_name(idx.astype(jnp.int32), MOE_ROUTE)
+    return idx, checkpoint_name(chosen_scores(z, idx), MOE_ROUTE)
+
+
 def route(p: MoEParam, params: Params, xf):
     """(chosen experts [tokens, k] int32, their weights [tokens, k] f32):
     sigmoid scores in float32, the top k of score + bias -- among all the
     routed experts (`n_group` 1) or among those of the `topk_group` groups
-    whose two best entries of score + bias sum highest -- weights the chosen
-    scores (`chosen_scores`: selected from the scores' columns, not fetched
-    by index) normalised and scaled (`noaux_tc`). Under `softmax_topk`: the
-    top k of the float32 logits, weights the softmax over the chosen logits
-    alone (read the same way); no bias, nothing left to normalise or scale."""
+    whose two best entries of score + bias sum highest -- weights the
+    sigmoids of the chosen logits (`chosen_scores`: selected from the
+    logits' columns, not fetched by index) normalised and scaled
+    (`noaux_tc`). Under `softmax_topk`: the top k of the float32 logits,
+    weights the softmax over the chosen logits alone (read the same way);
+    no bias, nothing left to normalise or scale. The weights are a function
+    of the chosen ids and logits alone, which carry the name MOE_ROUTE: the
+    sigmoid over all the columns feeds only the choice, which has no
+    gradient, so a block that keeps the two never makes the logits again."""
     z = jnp.dot(xf.astype(jnp.float32), params["router"].astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
     if _score_func(p) == "softmax_topk":
-        _, idx = lax.top_k(z, p.num_experts_per_tok)
-        return (idx.astype(jnp.int32),
-                jax.nn.softmax(chosen_scores(z, idx), axis=-1))
-    s = jax.nn.sigmoid(z)
-    choice = s + lax.stop_gradient(params["router_bias"])
+        idx, zc = _chosen_logits(z, lax.top_k(z, p.num_experts_per_tok)[1])
+        return idx, jax.nn.softmax(zc, axis=-1)
+    choice = jax.nn.sigmoid(z) + lax.stop_gradient(params["router_bias"])
     if p.n_group > 1:
         grouped = choice.reshape(choice.shape[0], p.n_group, -1)
         _, best = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1),
@@ -971,11 +987,11 @@ def route(p: MoEParam, params: Params, xf):
         kept = jnp.any(best[:, :, None] == jnp.arange(p.n_group), axis=1)
         choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
             choice.shape)
-    _, idx = lax.top_k(choice, p.num_experts_per_tok)
-    w = chosen_scores(s, idx)
+    idx, zc = _chosen_logits(z, lax.top_k(choice, p.num_experts_per_tok)[1])
+    w = jax.nn.sigmoid(zc)
     if p.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_topk_eps)
-    return idx.astype(jnp.int32), w * p.routed_scaling_factor
+    return idx, w * p.routed_scaling_factor
 
 
 def _grouped_dot(x, w, group_sizes, ctx):
@@ -1003,8 +1019,10 @@ def _grouped_dot(x, w, group_sizes, ctx):
 # alone (`sum_walks_buffer`; SCATTER_ROW_COST holds the chip's readings). A
 # `plan` is the routing's index arrays: `tok` [rows] the token of every
 # buffer row, `row_slot` [rows] its slot, `row_ok`
-# [rows] whether a slot landed in it, and the other way round `slot_row`
-# [tokens, k] (clamped into the buffer) and `slot_ok` [tokens, k].
+# [rows] whether a slot landed in it, and, where the sums go by slot (the k
+# gathers), the other way round `slot_row` [tokens, k] (clamped into the
+# buffer) and `slot_ok` [tokens, k]. Where the sums walk the buffer nothing
+# reads the slot side, and the plan holds none: it costs a second sort.
 
 def _plan(idx, experts_held, rows: int):
     """(the plan, the slots that landed by held expert [held], those of them
@@ -1012,7 +1030,8 @@ def _plan(idx, experts_held, rows: int):
     [tokens, k]), for a buffer of `rows` rows: the slots sorted by held
     expert (stable: by token within an expert), those that land nowhere
     here last; what does not fit is dropped from the END of the sorted
-    slots."""
+    slots. The plan has its slot side where `sum_walks_buffer` says the
+    sums read it."""
     (tokens, k), (first, held) = idx.shape, experts_held
     local = idx.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < held), local, held)
@@ -1023,11 +1042,12 @@ def _plan(idx, experts_held, rows: int):
     kept = ends[-1]
     n = min(rows, tokens * k)
     row_slot = jnp.pad(order[:n], (0, rows - n))
-    slot_row = jnp.argsort(order).astype(jnp.int32).reshape(tokens, k)
     plan = {"tok": row_slot // k, "row_slot": row_slot,
-            "row_ok": jnp.arange(rows, dtype=jnp.int32) < kept,
-            "slot_row": jnp.minimum(slot_row, rows - 1),
-            "slot_ok": slot_row < kept}
+            "row_ok": jnp.arange(rows, dtype=jnp.int32) < kept}
+    if not sum_walks_buffer(rows, tokens, k):
+        slot_row = jnp.argsort(order).astype(jnp.int32).reshape(tokens, k)
+        plan.update(slot_row=jnp.minimum(slot_row, rows - 1),
+                    slot_ok=slot_row < kept)
     return plan, sizes, jnp.diff(ends, prepend=0)
 
 
@@ -1044,54 +1064,63 @@ def sum_walks_buffer(rows: int, tokens: int, k: int) -> bool:
     return SCATTER_ROW_COST * rows < k * tokens
 
 
-def _weighted_sum_by_token(rows, w, plan):
-    """Token t <- sum over its k slots of w[t, j] * rows[row of slot (t, j)]
-    in float32, the slots that did not land left out (their rows may hold
-    anything). Where the buffer is short beside the slots
-    (`sum_walks_buffer`): every landed row times its slot's weight, added
+def _weighted_sum_by_token(rows, w, plan, tokens: int):
+    """Token t of `tokens` <- sum over its k slots of w[t, j] * rows[row of
+    slot (t, j)] in float32, the slots that did not land left out (their
+    rows may hold anything); `w` None: every weight 1 (the dispatch's
+    transpose). Where the buffer is short beside the slots (a plan without
+    a slot side): every landed row times its slot's weight, added
     into its token's row of a float32 zero array by scatter-add, a slab of
     SCATTER_COLUMNS columns at a time (a token's slots that landed in several
     held experts are several rows with one index). Elsewhere: k gathers of
     [tokens] rows and one fused weighted add."""
-    tokens, k = w.shape
-    if sum_walks_buffer(rows.shape[0], tokens, k):
+    if "slot_row" not in plan:
         ok = plan["row_ok"]
-        w_row = jnp.where(ok, _take_rows(w.reshape(-1), plan["row_slot"]), 0.0)
-        landed = jnp.where(ok[:, None], rows.astype(jnp.float32),
-                           0) * w_row[:, None]
+        landed = jnp.where(ok[:, None], rows.astype(jnp.float32), 0)
+        if w is not None:
+            landed = landed * jnp.where(ok, _take_rows(
+                w.reshape(-1), plan["row_slot"]), 0.0)[:, None]
         d = rows.shape[1]
         return jnp.concatenate([
             jnp.zeros((tokens, min(SCATTER_COLUMNS, d - c)), jnp.float32).at[
                 plan["tok"]].add(landed[:, c:c + SCATTER_COLUMNS],
                                  mode="promise_in_bounds").astype(rows.dtype)
             for c in range(0, d, SCATTER_COLUMNS)], axis=1)
-    return sum(
-        jnp.where(plan["slot_ok"][:, j, None],
-                  _take_rows(rows, plan["slot_row"][:, j]).astype(jnp.float32),
-                  0) * w[:, j, None]
-        for j in range(w.shape[1])).astype(rows.dtype)
+
+    def landed(j):
+        row = jnp.where(plan["slot_ok"][:, j, None], _take_rows(
+            rows, plan["slot_row"][:, j]).astype(jnp.float32), 0)
+        return row if w is None else row * w[:, j, None]
+
+    return sum(landed(j) for j in range(plan["slot_row"].shape[1])).astype(
+        rows.dtype)
 
 
-@jax.custom_vjp
 def rows_of_tokens(xf, plan):
     """Buffer row r <- token plan["tok"][r]; rows no slot landed in hold
     some token's row."""
+    return _rows_of_tokens(xf.shape[0], xf, plan)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of_tokens(tokens: int, xf, plan):
     return _take_rows(xf, plan["tok"])
 
 
-def _rows_of_tokens_bwd(plan, g):
-    return sum_by_token(g, plan["slot_ok"].astype(jnp.float32), plan), None
+def _rows_of_tokens_bwd(tokens, plan, g):
+    return _weighted_sum_by_token(g, None, plan, tokens), None
 
 
-rows_of_tokens.defvjp(lambda xf, plan: (_take_rows(xf, plan["tok"]), plan),
-                      _rows_of_tokens_bwd)
+_rows_of_tokens.defvjp(
+    lambda tokens, xf, plan: (_take_rows(xf, plan["tok"]), plan),
+    _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
 def sum_by_token(rows, w, plan):
     """Token t <- sum over its landed slots of w[t, j] * rows[row of slot
     (t, j)], accumulated in float32: `rows_of_tokens`' transpose, weighted."""
-    return _weighted_sum_by_token(rows, w, plan)
+    return _weighted_sum_by_token(rows, w, plan, w.shape[0])
 
 
 def _sum_by_token_bwd(res, g):
@@ -1110,7 +1139,7 @@ def _sum_by_token_bwd(res, g):
 
 
 sum_by_token.defvjp(
-    lambda rows, w, plan: (_weighted_sum_by_token(rows, w, plan),
+    lambda rows, w, plan: (_weighted_sum_by_token(rows, w, plan, w.shape[0]),
                            (rows, w, plan)), _sum_by_token_bwd)
 
 
@@ -1137,6 +1166,10 @@ def moe(p: MoEParam, params: Params, x, ctx, router_x=None):
     with jax.named_scope("dispatch"):
         plan, sizes, kept_sizes = _plan(idx, p.experts_held,
                                         moe_capacity(p, tokens))
+        # the backward pass reads the plan, and of the two counts the
+        # grouped products' group sizes (`sizes` feeds the counters alone)
+        plan = {n: checkpoint_name(a, MOE_ROUTE) for n, a in plan.items()}
+        kept_sizes = checkpoint_name(kept_sizes, MOE_ROUTE)
         xs = rows_of_tokens(lf, plan)
     with jax.named_scope("experts"):
         if gated:
@@ -1258,18 +1291,43 @@ COUNTER_TOPS = {"MoE": (1, MOE_COUNTERS), "MTP": (1, MOE_COUNTERS),
 #: its forward ends -- so in the one-head models the kept logits occupy what
 #: the recomputed ones would at the same moment and the round's memory is
 #: what it was; in the two-head models the main head's logits live across the
-#: MTP module (PERF.md section 6, PR 47)
-KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE,),
+#: MTP module (PERF.md section 6, PR 47).
+#: MoE (and the MTP module, which calls `moe`) names what its backward pass
+#: reads of the ROUTING: the chosen experts and their raw scores (`route`:
+#: [tokens, k] int32 and float32) and the plan (`moe`) -- its three index
+#: arrays by buffer row where the sums walk the buffer (`_plan` makes no
+#: slot side there, and no second sort in either pass), all five where the
+#: k gathers run -- with the grouped products' group sizes. A
+#: layer-step at 16,384 tokens: 3.09 MB at Nemotron's k = 22 (2.88 the
+#: choice, 0.20 the plan by row), 1.09 at Ling's k = 8, 1.83 at
+#: SmallThinker's k = 6, 1.15 at LFM2's and 1.00 at GLM's k = 4 (the plan by
+#: slot with them) -- 5 to 19 MB a step over a cell's four to eight expert
+#: layers. A bare block made all of it again: the float32 `HIGHEST` score
+#: product over every column, the `top_k`s (sorts of [tokens, experts]), the
+#: select, and `_plan`'s two argsorts over tokens x k slots, 74 + 19 ms a
+#: round behind Nemotron's 512-wide router, 79 + 5 behind Ling's. So that the
+#: two [tokens, k] arrays are ALL the backward reads, the sigmoid stands
+#: AFTER the selection: the sigmoid of a chosen logit is the chosen score to
+#: the bit, and `dw w (1 - w)` the same products, but the backward of a
+#: sigmoid over all the columns reads every column's score, and the block
+#: would make the score product again whatever it kept. The logits z are
+#: NOT named ([tokens, experts] float32: 33.6 MB a layer-step at 512
+#: columns, read by nothing once the choice is kept), nor the dispatched
+#: rows nor the grouped products' results (134 to 235 MB a layer-step: a
+#: kept set that large is sized by what a round can hold, not by a layer's
+#: type; PERF.md section 6, PR 52)
+KEPT_NAMES = {"MLAttention": (ATTN_CORE,), "MTP": (ATTN_CORE, MOE_ROUTE),
               "GQAttention": (ATTN_CORE,), "EVAttention": (ATTN_CORE,),
               "KDAttention": (KDA_OUT,), "GatedMLP": (MLP_PRE,),
-              "InnerProduct": (IP_OUT,)}
+              "InnerProduct": (IP_OUT,), "MoE": (MOE_ROUTE,)}
 #: kept name -> what marks the device ops that make its values in a compiled
 #: program's text, a part of their scope: the name of the Pallas kernel
 #: (matched as a prefix: `splash_mha_fwd_residuals`), or the named scope the
 #: layer runs its plain products under. Such an op on a recomputed path
 #: (`rematted_computation`) means the name did not reach its block's policy
 #: (a kept value whose making leaves no such mark has no entry)
-KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE, IP_OUT: IP_OUT}
+KEPT_MAKERS = {ATTN_CORE: "splash_mha_fwd", MLP_PRE: MLP_PRE, IP_OUT: IP_OUT,
+               MOE_ROUTE: "router"}
 #: layer type -> the named scope, under the layer's own, that holds its
 #: attention ("": the whole layer): whose device ops
 #: `obs.device.attention_moves` counts

@@ -781,7 +781,8 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
     named scope a layer runs its plain products under;
     `CompiledNet.kept_makers()`): `{"maker", "step_bodies": the
     computations that hold such an op (a loop's body, the peeled step),
-    "forward" / "backward": the kernel calls and matrix products so marked
+    "forward" / "backward": the kernel calls, custom calls, sorts (a `top_k`
+    is a custom call on a CPU, a sort on a TPU) and matrix products so marked
     on a forward path and on a recomputed one (`recomputed` of `scope_of`: a
     block's forward made again; the products of the backward pass proper
     run under the same scope and do not count) in the step body that has
@@ -792,7 +793,8 @@ def recompute_report(ops: Dict[str, Dict[str, Any]],
     for name, maker in kept_makers.items():
         count: Dict[str, Dict[str, int]] = {}   # computation -> phase -> n
         for op in ops.values():
-            made = (op["opcode"] == "custom-call" or op["matmul"]) and any(
+            made = (op["opcode"] in ("custom-call", "sort")
+                    or op["matmul"]) and any(
                 part.startswith(maker) for part in op["scope"].split("/"))
             if made and (op["recomputed"] or op["phase"] == "forward"):
                 body = count.setdefault(op["computation"], {})

@@ -438,6 +438,29 @@ def check_products_kept(model, report, tau, rows=ROWS):
         "backward": 0, "kept_bytes": rows * POS * sum(columns) * 4}
 
 
+def check_routing_kept(report, tau, experts, rows=ROWS):
+    """A round's account of what its expert blocks keep of their routing
+    (`recompute["moe_route"]`; `experts`: every expert layer's MoEParam, an
+    MTP module's with them): a score product and the choice's `top_k`s (the
+    CPU's `TopK` custom calls: one, or three where the choice is limited to
+    groups) a layer and step on the forward path, none made again, and a
+    step's kept bytes -- the chosen ids and raw scores (int32, float32), the
+    plan's `tok`, `row_slot` (int32 a buffer row), `row_ok` (a byte), the
+    held experts' group sizes and, these tiny layers' sums running as k
+    gathers, `slot_row` and `slot_ok` (int32 and a byte a slot)."""
+    from sparknet_tpu.model.seq_layers import (MOE_ROUTE, moe_capacity,
+                                               sum_walks_buffer)
+    tokens = rows * POS
+    assert not any(sum_walks_buffer(moe_capacity(p, tokens), tokens,
+                                    p.num_experts_per_tok) for p in experts)
+    assert report["recompute"][MOE_ROUTE] == {
+        "maker": "router", "step_bodies": 1,
+        "forward": tau * sum(4 if p.n_group > 1 else 2 for p in experts),
+        "backward": 0, "kept_bytes": sum(
+            13 * tokens * p.num_experts_per_tok + 9 * moe_capacity(p, tokens)
+            + 4 * p.experts_held[1] for p in experts)}
+
+
 def check_round(got, want, rel):
     """The round's loss to 2e-5 and every stored parameter's change and
     momentum, by their norms, to `rel`."""

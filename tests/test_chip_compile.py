@@ -18,6 +18,7 @@ CPU branch (portable LRN, checked shard_map, unrolled τ scan), so the
 whole-program cases steer it from the test; the kernel cases call the kernels
 directly.
 """
+import collections
 import math
 import os
 import re
@@ -787,8 +788,11 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
     finds those arrays: the counter tells the three apart. And no per-slot
     SCALAR travels by an index over the T k slots (`slot_scalars_moved`: 2 R
     where the k gathers run -- a row's weight fetched in the backward pass,
-    its `dw` placed -- and 4 R where the sums walk the buffer, which fetch
-    the rows' weights forward and for the dispatch's backward too; 4 T k + R
+    its `dw` placed -- and 3 R where the sums walk the buffer, which fetch
+    the rows' weights forward too (4 R before PR 52, when the dispatch's
+    backward fetched a weight of 1 a landed row by the plan's slot side,
+    which that form now reads nowhere: `_plan`'s second sort is made in
+    neither pass); 4 T k + R
     and 4 T k + 3 R before the router selected its chosen scores from the
     experts' columns, `seq_layers.chosen_scores`), and that select's [T, k,
     experts] is no array any op under `router` writes."""
@@ -821,7 +825,9 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
     def loss(params, x):
         with jax.named_scope("MoE/lone"):  # as `CompiledNet.apply` opens it
             out = jax.checkpoint(
-                lambda pp, xx: sl.moe(p, pp, xx, _seq_ctx())[0])(params, x)
+                lambda pp, xx: sl.moe(p, pp, xx, _seq_ctx())[0],
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *sl.KEPT_NAMES["MoE"]))(params, x)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     precision.set_policy("bfloat16")
@@ -852,10 +858,11 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         assert moves["row_scatters"] == (sums * slabs if walks else 0), moves
         assert moves["rows_scattered"] == (sums * rows if walks else 0), moves
         # no per-slot scalar travels by an index over the step's slots: a
-        # row's weight is fetched (in the backward pass; forward, backward
-        # and for the dispatch's backward where the sums walk the buffer)
-        # and its `dw` placed, R single elements a move, none of T x k
-        moved = 4 if walks else 2
+        # row's weight is fetched (in the backward pass; forward and
+        # backward where the sums walk the buffer -- the dispatch's backward
+        # weighs every landed row 1 and fetches nothing, since PR 52) and
+        # its `dw` placed, R single elements a move, none of T x k
+        moved = 3 if walks else 2
         assert moves["slot_scalar_moves"] == moved, moves
         assert moves["slot_scalars_moved"] == moved * rows, moves
         _no_scalar_by_slot(ops, sl.ROUTING_SCOPES, slots)
@@ -864,13 +871,19 @@ def test_routing_walks_the_buffers_rows_not_the_steps_slots(
         assert not any(math.prod(dims) == slots * p.n_routed_experts
                        for dims in _made_under(text, ops, ("router",)))
     assert moves["instructions"] > 0 and moves["bytes"] > 0
+    # the block keeps the routing (`moe_route`): what it makes again under
+    # `router` is no product and no sort, under `dispatch` no sort
+    again = re.findall(r'op_name="[^"]*rematted_computation/(?:router|dispatch)'
+                       r'/(?:dot_general|top_k|sort|argsort)"', text)
+    assert not again, again[:2]
+    assert re.search(r'op_name="[^"]*rematted_computation/dispatch/', text)
 
 
 def _sequence_round(v5e, config: str):
-    """(compiled, trainer) of a sequence configuration's benchmark round
-    (`benchmark/configs/<config>.json`: the published widths, 2 x 8,192
-    tokens a step, tau=4, bf16, donated, fused boundary, health off) for one
-    described chip."""
+    """(compiled, trainer, the round's jaxpr) of a sequence configuration's
+    benchmark round (`benchmark/configs/<config>.json`: the published
+    widths, 2 x 8,192 tokens a step, tau=4, bf16, donated, fused boundary,
+    health off) for one described chip."""
     import json
     from sparknet_tpu.apps.train_loop import build_trainer, resolve_spec
     from sparknet_tpu.utils.config import RunConfig
@@ -887,7 +900,7 @@ def _sequence_round(v5e, config: str):
     try:
         batch = NamedSharding(mesh, P(None, DATA_AXIS))
         key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 1))
-        compiled = trainer._round.lower(
+        traced = trainer._round.trace(
             _state_avals(trainer),
             {name: jax.ShapeDtypeStruct(  # `tokens`; `doc_ids` beside them
                 (c["tau"], c["local_batch"], c["seq_len"]), jnp.int32,
@@ -895,10 +908,11 @@ def _sequence_round(v5e, config: str):
             jax.ShapeDtypeStruct(key.shape, key.dtype,
                                  sharding=NamedSharding(mesh, P(DATA_AXIS))),
             jax.ShapeDtypeStruct((), jnp.float32,
-                                 sharding=NamedSharding(mesh, P()))).compile()
+                                 sharding=NamedSharding(mesh, P())))
+        compiled = traced.lower().compile()
     finally:
         precision.set_policy("float32")
-    return compiled, trainer
+    return compiled, trainer, traced.jaxpr.jaxpr
 
 
 def _round_bytes(compiled) -> int:
@@ -931,11 +945,38 @@ def _products_made_once(text: str, kept, name: str, kind: str, n: int) -> None:
     assert not again, again[:2]
 
 
+def _routing_made_once(ops, trainer, jaxpr) -> None:
+    """The round's expert layers (one a counters' top) make their routing
+    once a step (`moe_route`; PR 52): on a step body's forward path a score
+    product a layer, and in the report's count with it the choice's `top_k`s
+    (sorts here) and the compiler's `ConcatBitcast`s around them; on a
+    recomputed path none of them -- no product, sort or custom call under a
+    `router` scope is made again --, and what a step keeps of the choices
+    and the plans at most 5 MB a layer (printed as read)."""
+    from sparknet_tpu.obs.device import recompute_report
+    made = recompute_report(ops, trainer.net.kept_makers(), jaxpr)["moe_route"]
+    layers = len(trainer.net.counter_blobs())
+    routers = [op for op in ops.values() if "router" in op["scope"].split("/")]
+    products = collections.Counter(
+        op["computation"] for op in routers
+        if op["matmul"] and op["phase"] == "forward")
+    print("moe_route", made, "layers", layers, "products", dict(products))
+    assert made["maker"] == "router" and made["step_bodies"] == 2, made
+    assert set(products.values()) == {layers} and len(products) == 2, products
+    assert made["forward"] >= 2 * layers and made["backward"] == 0, made
+    assert 0 < made["kept_bytes"] <= 5e6 * layers, made
+    assert not [op for op in routers if op["recomputed"] and (
+        op["matmul"] or op["opcode"] in ("sort", "topk", "custom-call"))]
+
+
 @pytest.mark.slow
 def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     """The benchmark's sequence-model round (`glm47-flash-ep8-tau4`) for one
     described chip: ~2 min. 5.65 GB of state
-    + 5.65 GB of temporaries = 11.30 GB since PR 47, whose head blocks keep
+    + 5.68 GB of temporaries = 11.33 GB since PR 52, whose four expert
+    blocks and MTP module keep their routing (5.0 MB a step) and make no
+    score product, `top_k` or sort again; 5.65 and 11.30 since PR 47, whose
+    head blocks keep
     their logits (2 x 634 MB a step) and make no head's product twice: the
     compiler packs the round 0.4 GB TIGHTER than the 6.05 GB it took with
     both heads' products made again (the gradient is 2.83 GB of them; what the six
@@ -948,7 +989,7 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     no product of the dense SwiGLU twice, and no gather or scatter in its
     attention touches an activation; both heads' products run once a step
     body (`ip_out` 2 forward, 0 on a recomputed path)."""
-    compiled, trainer = _sequence_round(v5e, "glm47-flash-ep8-tau4")
+    compiled, trainer, jaxpr = _sequence_round(v5e, "glm47-flash-ep8-tau4")
     total = _round_bytes(compiled)
     assert total < 12.0e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -968,6 +1009,7 @@ def test_glm_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # dispatch's backward), the MTP module's a third time (its combine is
     # made again for the norm that follows it)
     _routing_walks_rows(text, ops, trainer, 4 * 2 + 3, 16384)
+    _routing_made_once(ops, trainer, jaxpr)
 
 
 @pytest.mark.slow
@@ -976,13 +1018,15 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     short convolutions, two grouped-query attentions at head width 64, eight
     expert layers of width 1,792, a tied head) for one described chip: 7.37
     GB of state (921,256,448 parameters and their momentum) and the round's
-    temporaries (4.52 GB: 11.89 together, PR 47's kept logits of the tied
+    temporaries (4.57 GB: 11.94 together since PR 52, whose eight expert
+    blocks keep their routing, 9.2 MB a step, and make no score product,
+    `top_k` or sort again; 4.52 and 11.89 before it: PR 47's kept logits of the tied
     head, 537 MB a step, moved nothing: the head's block is the last of the
     forward pass) under the chip's 16 GB beside the benchmark's stacks. The two
     attention cores run as kernels with grouped heads (no [.., 8192, 8192]
     scores), once a step body on its forward path alone, and the head's
     product once."""
-    compiled, trainer = _sequence_round(v5e, "lfm2-8b-a1b-ep4-tau4")
+    compiled, trainer, jaxpr = _sequence_round(v5e, "lfm2-8b-a1b-ep4-tau4")
     total = _round_bytes(compiled)
     assert total < 14.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -998,6 +1042,7 @@ def test_lfm2_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     moves = attention_moves(ops, *trainer.net.attention_scopes())
     assert moves["gathers_scatters"] == 0, moves
     _routing_walks_rows(text, ops, trainer, 8 * 2, 32768)
+    _routing_made_once(ops, trainer, jaxpr)
 
 
 @pytest.mark.slow
@@ -1006,7 +1051,10 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     Delta Attention layers and one latent attention with direct queries,
     six expert layers behind a 512-wide group-limited router, an untied
     head) for one described chip (~4 min): 6.58 GB of state (822,036,416
-    parameters and their momentum) + 6.73 GB of temporaries: 13.31 GB (13.30
+    parameters and their momentum) + 6.67 GB of temporaries: 13.25 GB since
+    PR 52, whose expert blocks keep their routing (6.5 MB a step for the six
+    layers) and make no score product, `top_k` or select again (13.31 and
+    6.73 before it; 13.30
     before PR 50, the
     same with PR 47's kept logits, 644 MB a step, whose product runs once;
     the gradient is 3.29 of the temporaries; 6.57 before PR 44 -- one packing
@@ -1027,7 +1075,7 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     the backward pass walks the chunks once as a `lax.scan` for the state
     every segment started from), and no gather or scatter in any operator
     touches an activation."""
-    compiled, trainer = _sequence_round(v5e, "ling3-flash-ep64-tau4")
+    compiled, trainer, jaxpr = _sequence_round(v5e, "ling3-flash-ep64-tau4")
     total = _round_bytes(compiled)
     assert total < 13.5e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -1057,6 +1105,7 @@ def test_ling_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # them by token twice (the combine, the dispatch's backward): no gather
     # of tokens x k rows is left (2 x 6 x 8 of 16,384 rows before PR 43)
     _routing_walks_rows(text, ops, trainer, 0, 4096, buffer_sums=2, k=8)
+    _routing_made_once(ops, trainer, jaxpr)
 
 
 @pytest.mark.slow
@@ -1072,7 +1121,7 @@ def test_evabyte_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kernel call forward a layer-step over 17,408 key columns (the row's keys
     and 1,024 chunk summaries) and one backward, on its forward path alone,
     and no SwiGLU makes a product twice."""
-    compiled, trainer = _sequence_round(v5e, "evabyte-l4-tau4")
+    compiled, trainer, _ = _sequence_round(v5e, "evabyte-l4-tau4")
     total = _round_bytes(compiled)
     assert total < 15e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -1096,8 +1145,11 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     rotary turn at 8 held heads, five LatentMoE layers behind a 512-wide
     router that chooses 22, the MTP module's attention and expert layer, two
     heads) for one described chip: 5.74 GB of state (716,980,192 parameters
-    and their momentum) + 5.90 GB of temporaries: 11.64 GB, under 13 together
-    (6.26 and 12.00 before PR 48, whose scans are kernels that keep their
+    and their momentum) + 5.85 GB of temporaries: 11.59 GB, under 13 together
+    (5.90 and 11.64 before PR 52, whose expert blocks keep their routing --
+    the chosen ids and raw scores, the plan's index arrays by buffer row: 18.5 MB a step
+    for the six layers -- and make no score product, sort or select again;
+    6.26 and 12.00 before PR 48, whose scans are kernels that keep their
     chunks' squares in VMEM; 6.22 and 11.95 before PR 47, whose head blocks keep their logits, 2 x 537
     MB a step, the main head's across the MTP module, and the block of the
     projection into that module its result, 134 MB, the next block's input
@@ -1111,7 +1163,7 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     row's 64 chunks with the state in VMEM, and no chunk's [128, 128] squares
     exist outside them; no gather or scatter in any mixer touches an
     activation; the expert layers move rows of the latent's width."""
-    compiled, trainer = _sequence_round(v5e, "nemotron3-super-tp4-ep64-tau4")
+    compiled, trainer, jaxpr = _sequence_round(v5e, "nemotron3-super-tp4-ep64-tau4")
     total = _round_bytes(compiled)
     assert total < 13e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -1144,6 +1196,7 @@ def test_nemotron_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     # rows and add them by token three times (the combine, the combine made
     # again for `latent_up`'s weight gradient, the dispatch's backward)
     _routing_walks_rows(text, ops, trainer, 0, rows, buffer_sums=3, k=22)
+    _routing_made_once(ops, trainer, jaxpr)
 
 
 @pytest.mark.slow
@@ -1162,7 +1215,7 @@ def test_granite_packed_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     kernel pair under document runs (two step bodies x nine layers x
     forward, forward made again with its chunk states, backward) and no
     device loop; no SwiGLU product and no logits are made twice."""
-    compiled, trainer = _sequence_round(v5e, "granite4-h-micro-pp4-tau4")
+    compiled, trainer, _ = _sequence_round(v5e, "granite4-h-micro-pp4-tau4")
     total = _round_bytes(compiled)
     assert total < 15.8e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -1193,8 +1246,11 @@ def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     attention, an untied head over 37,984 rows; ONE row of 16,384 positions)
     for one described chip: 5.25 GB of state (656,529,920 parameters and
     their momentum) and the round's temporaries under the chip's 16 GB:
-    10.01 GB of them, 15.26 together, since PR 47 (7.48 and 12.73 before it;
-    bound 14.5 then). The head's block keeps its logits, 1.245 GB a step, and
+    7.97 GB of them, 13.23 together, since PR 52, whose expert blocks keep
+    their routing (7.3 MB a step for the four layers) and make no score
+    product, `top_k` or sort again -- 2.04 GB under the 10.01 and 15.26 the
+    round took since PR 47 (7.48 and 12.73 before it; bound 14.5 then): the
+    compiler's packing again, not live bytes. The head's block keeps its logits, 1.245 GB a step, and
     makes their product once; the loss around them holds three float32
     [16384, 37984] arrays of 2.49 GB, never more than two at once in either
     form (its cast for the labels' gather, the softmax's gradient, that
@@ -1210,7 +1266,7 @@ def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     scores), once a step body on its forward path alone, and the sliding
     cores' tables send them to 140 key blocks where the global core's sends
     it to 272."""
-    compiled, trainer = _sequence_round(v5e, "smallthinker-21b-ep4-tau4")
+    compiled, trainer, jaxpr = _sequence_round(v5e, "smallthinker-21b-ep4-tau4")
     total = _round_bytes(compiled)
     assert total < 15.6e9, f"round needs {total / 1e9:.2f} GB of a 16 GB chip"
     text = compiled.as_text()
@@ -1235,3 +1291,4 @@ def test_smallthinker_round_compiles_for_v5e_and_fits(v5e, as_tpu):
     assert rows == 61440
     # four expert layers: the k = 6 gathers' side (4 x 61,440 > 6 x 16,384)
     _routing_walks_rows(text, ops, trainer, 4 * 2, rows, k=6)
+    _routing_made_once(ops, trainer, jaxpr)

@@ -289,14 +289,21 @@ def test_the_multipliers_leave_a_net_without_them_as_it_was():
 #: addresses struck out: taken from the tree BEFORE this model and its
 #: document cuts came (PR 48's commit), where this PR's tree gave the same
 #: texts. A change to what one of these models traces to changes its digest:
-#: say in the PR that makes it why, and put the new digest here
+#: say in the PR that makes it why, and put the new digest here. PR 52: the
+#: five models with expert layers trace to a new text (`route`'s sigmoid
+#: after the selection, the `moe_route` names, their blocks' policies, a
+#: plan that holds the side its sums read, the dispatch's transpose that
+#: weighs every landed row 1); EvaByte, which has none, traced to the text
+#: it had until its blocks' policies became one object a set of names
+#: (`net._keep`), since when all six print the sub-jaxprs their blocks share
+#: once
 JAXPR_DIGESTS = {
-    "glm4_moe_lite": "2ea8dd597079086bd1f167de35c355a2490a538562a9215eb3aa72b578c063fd",
-    "lfm2_moe": "c666e6862c2fcac71f3078beebb084a775aab05200901a4c56e7cdc708e1a1c3",
-    "ling3_flash": "f9459e242aa573d3677668003cd15f00bb33c51a9cfad28acfd55d6ad45dc22b",
-    "evabyte": "288622b483d3901f50713c4c2214edbacab251fd77661fbe749bc1e769e5b9f3",
-    "nemotron_h": "8ea8a5df8b51ff44290e17f677cc54ba7aec6adeeb0cb3e61753154393aa7600",
-    "smallthinker": "db94b6d545952383fc7adb754808da41981cd16bdfdf2d8b29bf57de7c09bb17",
+    "glm4_moe_lite": "cf39d5cde8159063080350bf4ed670dfcf4529dd7f10c0c4ad46708d8cc91131",
+    "lfm2_moe": "dde13ab2057848f86eaa2a09675079659835e51a356913fd2e555d0715c53765",
+    "ling3_flash": "af48c0eec711a70842bbae9f6efd307a3a6252240736ccd0d55dc73b69d9496d",
+    "evabyte": "a2dd66d1827281f6a7ea89bb495e9bfe1a75fbe94a9422c239b6df8ffadefd0d",
+    "nemotron_h": "c209c2bcc90d8724144320f5e94c4704a76e7ec89466fb9ce57d70a1490da476",
+    "smallthinker": "bc16078fca367b2bffae3d4371a6b5d5ef8b1ed6beb3426215eef838c732e187",
 }
 
 

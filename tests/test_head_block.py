@@ -102,6 +102,26 @@ def test_the_head_block_is_checkpointed_under_a_policy(model):
         assert sum(e.params["policy"] is None for e in remats) >= len(nothing_named)
 
 
+@pytest.mark.parametrize("model", MODELS)
+def test_blocks_that_keep_the_same_names_share_one_policy(model):
+    """The blocks' policies are one object a set of kept names
+    (`net._keep`): jax memoises a block's partial evaluation by the policy's
+    identity, and a policy made anew a block lowers every jitted part of
+    every block to functions of its own (Nemotron's round: 868 functions
+    where shared policies give 154; PR 52)."""
+    net = compiled(model)
+    blocks = {}
+    for l in net.spec.layers_for_phase("TRAIN"):
+        if l.block is not None:
+            blocks.setdefault(l.block, []).append(l)
+    kept = {net_mod._kept_names(ls) for ls in blocks.values()} - {()}
+    policies = {id(e.params["policy"]) for e in _eqns(_gradient_jaxpr(model))
+                if e.primitive.name == "remat2"
+                and e.params["policy"] is not None}
+    assert kept and len(policies) == len(kept), (kept, len(policies))
+    assert net_mod._keep(next(iter(kept))) is net_mod._keep(next(iter(kept)))
+
+
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 @pytest.mark.parametrize("model", MODELS)
 def test_a_step_keeps_every_blocks_product_whole_in_its_own_dtype(model, policy):

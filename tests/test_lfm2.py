@@ -16,8 +16,8 @@ import pytest
 
 from model_cases import (CTX, D, POS, ROWS, _ids, _per_row, _x, case,
                          check_layer, check_loss_and_every_gradient,
-                         check_products_kept, check_round, compiled,
-                         program_round, tiny_round)
+                         check_products_kept, check_round, check_routing_kept,
+                         compiled, program_round, tiny_round)
 from sparknet_tpu import zoo
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.layers import LAYER_IMPLS
@@ -249,6 +249,10 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # ... the tied head its logits, made once a step
     check_products_kept("lfm2_moe", report, tau=2)
     del kept[sl.IP_OUT]
+    # ... the three expert layers their routing: no score product made again
+    check_routing_kept(report, 2, [case_.spec.layer_by_name(n).moe
+                                   for n in sorted(case_.want["chosen"])])
+    del kept[sl.MOE_ROUTE]
     assert kept == {sl.ATTN_CORE: {
         "maker": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": ROWS * POS * GQA_P.num_heads * GQA_P.head_dim * 4}}
@@ -288,7 +292,8 @@ def test_zoo_follows_layer_types_and_names_what_a_block_keeps():
     assert not any(l.type == "MTP" for l in spec.layers)
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "l3", "head"}
     net = _net()
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE, sl.IP_OUT: sl.IP_OUT}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE,
+                                 sl.IP_OUT: sl.IP_OUT, sl.MOE_ROUTE: "router"}
     assert net.attention_scopes() == ({"GQAttention": ""}, POS)
     assert net.routing_scopes() == (sl.ROUTING_SCOPES, TINY["hidden_size"])
     assert sum(int(np.prod(s)) for lp in ref.param_shapes(LAYERS).values()

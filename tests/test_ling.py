@@ -360,7 +360,8 @@ def test_zoo_follows_the_published_period_and_names_what_a_block_keeps():
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "l2", "l3", "head"}
     net = _net()
     assert net.kept_makers() == {  # nothing marks what makes kda_out
-        sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE, sl.IP_OUT: sl.IP_OUT}
+        sl.ATTN_CORE: "splash_mha_fwd", sl.MLP_PRE: sl.MLP_PRE, sl.IP_OUT: sl.IP_OUT,
+        sl.MOE_ROUTE: "router"}
     assert net.attention_scopes() == ({"KDAttention": "", "MLAttention": ""}, POS)
     assert net.delta_scopes() == ({"KDAttention": "delta"}, (sl.KDA_OUT,))
     assert sl.KEPT_NAMES["KDAttention"] == (sl.KDA_OUT,)

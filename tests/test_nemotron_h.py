@@ -426,8 +426,10 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tiny_roun
                                       sl.ROUTING_SCOPES, 32)
     assert walked["row_scatters"] == 2 and walked["rows_scattered"] == 2 * rows
     assert walked["rows_gathered"] == 2 * rows  # no [tokens]-row gather left
+    # one scalar a row by index: its weight (the dispatch's backward weighs
+    # every landed row 1 and fetches nothing, since PR 52; 2 before it)
     assert (walked["slot_scalar_moves"], walked["slot_scalars_moved"]) == (
-        2, 2 * rows)
+        1, rows)
 
 
 @pytest.mark.parametrize("control", ["fp8", "state_dropped"])
@@ -553,7 +555,8 @@ def test_zoo_follows_the_pattern_and_builds_the_mtp_module_of_layer_types():
     head = spec.layer_by_name("lm_head")
     assert head.param_from is None and not head.inner_product.transposed  # untied
     net = _net()
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT,
+                                 sl.MOE_ROUTE: "router"}
     assert "Mamba2" not in sl.KEPT_NAMES
     assert net.attention_scopes() == ({"Mamba2": "", "GQAttention": ""}, POS)
     assert net.ssd_scopes() == {"Mamba2": "ssd"} == sl.SSD_SCOPES

@@ -8,13 +8,16 @@ per-slot scalars that travel by no index.
 """
 from __future__ import annotations
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from model_cases import (CTX, D, MOE_P, POS, ROWS, _close, _params, _per_row,
-                         _x, benchmark_expert_layers, case)
+                         _x, benchmark_expert_layers, case, compiled)
 from sparknet_tpu.model import seq_layers as sl
 from sparknet_tpu.model.spec import MoEParam
 
@@ -143,6 +146,18 @@ _PAIR_CASES = [
     ("every", 16, 2, True, "buffer"), ("every", 40, 4, True, "buffer")]
 
 
+def _slot_side(plan, tokens, k):
+    """(`slot_row`, `slot_ok`) [tokens, k] as numpy: the plan's own where it
+    holds a slot side (the k gathers), else read back from its rows -- a slot
+    found room where a landed row names it, and that row is its row."""
+    if "slot_row" in plan:
+        return np.asarray(plan["slot_row"]), np.asarray(plan["slot_ok"])
+    ok, row_slot = np.asarray(plan["row_ok"]), np.asarray(plan["row_slot"])
+    slot_row, slot_ok = np.zeros(tokens * k, np.int32), np.zeros(tokens * k, bool)
+    slot_row[row_slot[ok]], slot_ok[row_slot[ok]] = np.flatnonzero(ok), True
+    return slot_row.reshape(tokens, k), slot_ok.reshape(tokens, k)
+
+
 @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
 @pytest.mark.parametrize("share,room,k,drops,form", _PAIR_CASES)
 def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
@@ -169,7 +184,11 @@ def test_sum_by_token_is_the_dense_formula_and_rows_of_tokens_its_transpose(
     assert int(jnp.sum(kept_sizes)) == keep.sum() <= int(jnp.sum(sizes))
     assert (keep.sum() == rows < int(jnp.sum(sizes))) if drops else (
         keep.sum() == int(jnp.sum(sizes))), "it drops, or all find room"
-    assert np.array_equal(np.asarray(plan["slot_ok"]), keep)
+    # the plan holds the side its sums read: no slot side, and no second
+    # sort, where they walk the buffer
+    assert sorted(plan) == sorted(("tok", "row_slot", "row_ok") + (
+        ("slot_row", "slot_ok") if form == "gathers" else ()))
+    assert np.array_equal(_slot_side(plan, tokens, k)[1], keep)
     n = int(keep.sum())
     assert np.asarray(plan["row_ok"]).tolist() == [True] * n + [False] * (rows - n)
     # the dense matrix, from the plan's row side alone
@@ -243,7 +262,7 @@ def test_the_buffer_form_adds_a_slab_of_columns_at_a_time(d, monkeypatch):
     (0.5, 1024, "buffer"), (1.0, 1024, "gathers"),
     (0.75, 2048, "buffer"), (1.0, 2048, "gathers")])
 def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(
-        factor, positions, form):
+        factor, positions, form, capsys):
     """The gradients of the whole layer -- the router's (through `dw`), the
     experts' (through `dy`) and the input's (through `dxf` and the router) --
     when the buffer is too small and slots are dropped: the reference's, with
@@ -251,7 +270,12 @@ def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(
     two held experts, so that much more lands than finds room (2,048 or 4,096
     tokens: the buffer is whole tiles of the grouped product, 512 to 2,048
     rows), and the layer's rule takes the weighted sums over the buffer's
-    rows at the shorter buffers and as k gathers at the longer."""
+    rows at the shorter buffers and as k gathers at the longer. A block under
+    the layer's policy keeps the side of the plan that form reads: the ids,
+    their logits and the group sizes either way, with the three arrays by
+    buffer row where the sums walk the buffer (the two by slot are read by
+    nothing there, and their sort is made in neither pass) and all five
+    where the gathers run."""
     tight = MoEParam(**{**MOE_P.__dict__, "capacity_factor": factor})
     p = _params(7)
     p = dict(p, router_bias=jnp.zeros((8,)).at[2].set(0.4).at[3].set(0.3))
@@ -283,6 +307,19 @@ def test_moe_gradients_match_autodiff_of_the_reference_at_a_tight_buffer(
         if name != "router_bias":
             _close(mine[0][name], want[0][name], "float32")
     _close(mine[1], want[1], "float32")
+    block = jax.checkpoint(
+        lambda p, x: sl.moe(tight, p, x, CTX)[0],
+        policy=jax.checkpoint_policies.save_only_these_names(sl.MOE_ROUTE))
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p, x: jnp.sum(block(p, x) ** 2), p, x)
+    kept = [line.split(" ")[0] for line in capsys.readouterr().out.splitlines()
+            if f"named '{sl.MOE_ROUTE}'" in line]
+    slots, by_row = f"[{ROWS * positions},2]", f"[{room}]"
+    assert sorted(kept) == sorted(
+        ["i32" + slots, "i32[2]", "i32" + by_row, "i32" + by_row,
+         "bool" + by_row] + (
+            [] if form == "buffer" else ["i32" + slots, "bool" + slots]))
 
 
 @pytest.mark.parametrize("config,k,rows,form", [
@@ -315,6 +352,24 @@ def _bits(a):
     return np.asarray(a, np.float32).view(np.int32)
 
 
+def _router_case(p):
+    """(a router's weights, 300 tokens of width 48, a cotangent for the
+    weights, the bias as numpy) with exact ties among the scores -- two
+    pairs of columns with one weight vector and one bias, within and across
+    groups -- and a token whose score at column 3 overflows to exactly 1."""
+    tokens, d, k, experts = 300, 48, p.num_experts_per_tok, p.n_routed_experts
+    rng = np.random.default_rng(experts + k)
+    router = rng.standard_normal((d, experts)).astype(np.float32)
+    bias = (0.1 * rng.standard_normal(experts)).astype(np.float32)
+    for a, b in ((1, 5), (experts - 2, 7)):
+        router[:, a], bias[a] = router[:, b], bias[b]
+    params = {"router": jnp.asarray(router), "router_bias": jnp.asarray(bias)}
+    xf = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    xf = xf.at[0].set(40.0 * jnp.sign(params["router"][:, 3]))
+    return (params, xf,
+            jnp.asarray(rng.standard_normal((tokens, k)), jnp.float32), bias)
+
+
 @pytest.mark.parametrize("config,k,experts,n_group,topk_group", [
     ("nemotron3-super-tp4-ep64-tau4", 22, 512, 1, 1),
     ("ling3-flash-ep64-tau4", 8, 512, 8, 4),
@@ -338,16 +393,7 @@ def test_route_selects_the_scores_the_gather_fetched_to_the_bit(
     p = benchmark_expert_layers(config)[0][0]
     assert (p.num_experts_per_tok, p.n_routed_experts, p.n_group,
             p.topk_group) == (k, experts, n_group, topk_group)
-    tokens, d = 300, 48
-    rng = np.random.default_rng(experts + k)
-    router = rng.standard_normal((d, experts)).astype(np.float32)
-    bias = (0.1 * rng.standard_normal(experts)).astype(np.float32)
-    for a, b in ((1, 5), (experts - 2, 7)):  # exact ties, within and across groups
-        router[:, a], bias[a] = router[:, b], bias[b]
-    params = {"router": jnp.asarray(router), "router_bias": jnp.asarray(bias)}
-    xf = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
-    xf = xf.at[0].set(40.0 * jnp.sign(params["router"][:, 3]))  # sigmoid -> 1.0
-    c = jnp.asarray(rng.standard_normal((tokens, k)), jnp.float32)
+    params, xf, c, bias = _router_case(p)
 
     def run():  # a function of its own a form: jax caches traces by function
         def weighed(params, xf):
@@ -380,6 +426,123 @@ def test_route_selects_the_scores_the_gather_fetched_to_the_bit(
         else:
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * float(
                 jnp.max(jnp.abs(b))))
+
+
+def _route_as_it_was(p, params, xf):
+    """`route` before its backward pass read the chosen logits alone: the
+    sigmoid over all the columns, THEN the chosen columns of the scores by
+    `take_along_axis` (whose backward reads every column's score)."""
+    z = jnp.dot(xf.astype(jnp.float32), params["router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    if p.score_func == "softmax_topk":
+        _, idx = jax.lax.top_k(z, p.num_experts_per_tok)
+        return idx.astype(jnp.int32), jax.nn.softmax(
+            jnp.take_along_axis(z, idx, axis=-1), axis=-1)
+    s = jax.nn.sigmoid(z)
+    choice = s + jax.lax.stop_gradient(params["router_bias"])
+    if p.n_group > 1:
+        grouped = choice.reshape(choice.shape[0], p.n_group, -1)
+        _, best = jax.lax.top_k(jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1),
+                                p.topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(p.n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
+            choice.shape)
+    _, idx = jax.lax.top_k(choice, p.num_experts_per_tok)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if p.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + p.norm_topk_eps)
+    return idx.astype(jnp.int32), w * p.routed_scaling_factor
+
+
+@pytest.mark.parametrize("config,score_func,n_group", [
+    ("nemotron3-super-tp4-ep64-tau4", "sigmoid", 1),
+    ("ling3-flash-ep64-tau4", "sigmoid", 8),
+    ("smallthinker-21b-ep4-tau4", "softmax_topk", 1)])
+def test_the_sigmoid_after_the_selection_is_the_one_before_it_to_the_bit(
+        config, score_func, n_group):
+    """`route()` at three configurations' own routers (300 tokens of width
+    48, exact ties among the scores, one score that overflows to 1): the
+    chosen experts, the weights and the gradients of a weighted sum of the
+    weights with respect to the tokens and to the router's matrix equal, BIT
+    FOR BIT, those of the form it replaces, written out above -- the sigmoid
+    of the chosen logits is the chosen sigmoid (one elementwise function of
+    the same float32), and `dz[t, idx[t, j]] = dw[t, j] w (1 - w)` is the
+    same products on the same numbers whichever side of the selection makes
+    them. What the backward pass reads differs: every column's score there,
+    the ids and k logits a token here, both named `moe_route`."""
+    p = benchmark_expert_layers(config)[0][0]
+    assert (sl._score_func(p), p.n_group) == (score_func, n_group)
+    params, xf, c, _ = _router_case(p)
+
+    def both(route):
+        def weighed(params, xf):
+            idx, w = route(p, params, xf)
+            return jnp.sum(w * c), (idx, w)
+        return jax.value_and_grad(weighed, argnums=(0, 1), has_aux=True)
+
+    got, want = both(sl.route)(params, xf), both(_route_as_it_was)(params, xf)
+    (_, (idx, w)), (dparams, dxf) = got
+    assert idx.dtype == jnp.int32 and w.dtype == jnp.float32
+    z = jnp.dot(xf, params["router"], precision="highest")
+    assert float(jax.nn.sigmoid(z)[0, 3]) == 1.0 or score_func == "softmax_topk"
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and np.array_equal(_bits(a), _bits(b))
+    assert np.any(np.asarray(dparams["router"])) and np.any(np.asarray(dxf))
+    assert not np.any(np.asarray(dparams["router_bias"]))
+    jaxpr = str(jax.make_jaxpr(both(sl.route))(params, xf))
+    assert jaxpr.count("name=" + sl.MOE_ROUTE) == 2, "the ids and their logits"
+
+
+@functools.cache
+def _recomputed_routing(kept: bool):
+    """{layer type: the primitives, by routing scope, that the tiny GLM
+    net's step makes again in that type's blocks} as its blocks are built
+    (`kept`) or with `moe_route` struck from their policies."""
+    from sparknet_tpu.model import net as net_mod
+    from sparknet_tpu.obs import device as obs_device
+    net = compiled("glm4_moe_lite")
+    assert {l.type for l in net.spec.layers if l.block} >= {"MoE", "MTP"}
+    loss = net.loss_fn("loss")
+
+    def grad(p, ids):  # (a fresh function a trace: no policy in jax's key)
+        with jax.named_scope(obs_device.STEP_SCOPE):
+            return jax.value_and_grad(
+                lambda p: loss(p, {"tokens": ids}, None)[0])(p)
+    names = net_mod._kept_names
+    with pytest.MonkeyPatch.context() as patch:
+        if not kept:
+            patch.setattr(net_mod, "_kept_names", lambda layers: tuple(
+                n for n in names(layers) if n != sl.MOE_ROUTE))
+        text = jax.jit(grad).lower(
+            jax.eval_shape(net.init_params, jax.random.PRNGKey(0)),
+            jax.ShapeDtypeStruct((ROWS, POS), jnp.int32)).compile().as_text()
+    again = {}
+    for op_name in set(re.findall(r'op_name="([^"]*)"', text)):
+        at = obs_device.scope_of(op_name)
+        under = [s for s in sl.ROUTING_SCOPES if s in at["scope"].split("/")]
+        if at["recomputed"] and under:
+            again.setdefault(at["layer_type"], {}).setdefault(
+                under[0], set()).add(op_name.rsplit("/", 1)[-1])
+    return again
+
+
+@pytest.mark.parametrize("kind", ["MoE", "MTP"])
+def test_a_block_that_keeps_the_routing_makes_none_of_it_again(kind):
+    """The compiled gradient of GLM's tiny net (on the CPU; every
+    instruction's `op_name`, inside a fusion or out): what an expert block
+    -- a decoder's, and the MTP module's, whose policy names the attention
+    core too -- makes again under `router` holds no product, no `top_k` and
+    no sort nor the select over the experts' columns, and under `dispatch`
+    no sort: the k-wide weights from the kept logits, and the row gather. With `moe_route` struck
+    from the policies all of them are there a second time."""
+    sorts = {"sort", "top_k"}
+    bare, built = _recomputed_routing(False)[kind], _recomputed_routing(True)[kind]
+    assert {"dot_general", "top_k", "eq"} <= bare["router"]
+    assert "sort" in bare["dispatch"]
+    assert not built["router"] & (sorts | {"dot_general", "eq"}), built
+    assert not built["dispatch"] & sorts, built
+    assert "gather" in built["dispatch"], "the rows are fetched again"
+    assert built["router"] and built["router"] < bare["router"]
 
 
 @pytest.mark.parametrize("share,room,k,what", [
@@ -417,7 +580,8 @@ def test_dw_is_scattered_from_the_rows_as_the_slots_fetched_it(
     for dy, dw in (grad(y, w), jax.jit(grad)(y, w)):
         dw_row = jnp.sum(y.astype(jnp.float32) * sl.rows_of_tokens(g, plan).astype(
             jnp.float32), axis=-1)
-        by_slot = jnp.where(plan["slot_ok"], jnp.take(dw_row, plan["slot_row"]), 0.0)
+        slot_row, slot_ok = _slot_side(plan, tokens, k)
+        by_slot = jnp.where(slot_ok, jnp.take(dw_row, slot_row), 0.0)
         assert dw.shape == (tokens, k) and dw.dtype == jnp.float32
         assert np.array_equal(_bits(dw), _bits(by_slot))
         assert np.count_nonzero(np.asarray(dw)) == kept

@@ -10,6 +10,7 @@ account of itself (`obs.device`'s reports on made-up texts). The layers are
 from __future__ import annotations
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +19,9 @@ import pytest
 
 from model_cases import (CTX, D, MLA_P, MOE_P, POS, ROWS, _ids, _params, _x,
                          attention_block, case, check_loss_and_every_gradient,
-                         check_products_kept, check_round, compiled, program_loss_and_grads,
-                         program_round, tiny_round)
+                         check_products_kept, check_round, check_routing_kept,
+                         compiled, program_loss_and_grads, program_round,
+                         tiny_round)
 from sparknet_tpu import precision, zoo
 from sparknet_tpu.model import net as net_mod
 from sparknet_tpu.model import seq_layers as sl
@@ -130,6 +132,12 @@ def test_one_tau_round_through_the_trainer_matches_tau_reference_steps(tmp_path)
     # ... both heads their logits (the second runs on the first's matrix)
     check_products_kept("glm4_moe_lite", report, tau=3)
     del kept[sl.IP_OUT]
+    # ... and the two expert blocks and the MTP module's their routing
+    check_routing_kept(report, 3, [
+        case_.spec.layer_by_name("l1_moe").moe,
+        case_.spec.layer_by_name("l2_moe").moe,
+        case_.spec.layer_by_name("mtp").mtp.moe])
+    del kept[sl.MOE_ROUTE]
     assert kept == {sl.ATTN_CORE: {
         "maker": "splash_mha_fwd", "step_bodies": 0, "forward": 0, "backward": 0,
         "kept_bytes": 4 * ROWS * POS * MLA_P.num_heads * MLA_P.v_head_dim * 4}}
@@ -371,9 +379,11 @@ def test_the_report_counts_a_dense_blocks_products_made_again(monkeypatch):
 
 def test_an_expert_layers_shared_expert_names_nothing():
     """The shared expert runs `_swiglu` as the dense layer does but under no
-    name: an expert block's policy is what it was (nothing for `MoE`), and
-    a gradient through the layer names no value."""
-    assert "MoE" not in sl.KEPT_NAMES and sl.KEPT_NAMES["MTP"] == (sl.ATTN_CORE,)
+    name: an expert block's policy names the routing alone (`moe_route`: the
+    ids and their logits, the plan's five arrays, the group sizes), and a
+    gradient through the layer names no other value."""
+    assert sl.KEPT_NAMES["MoE"] == (sl.MOE_ROUTE,)
+    assert sl.KEPT_NAMES["MTP"] == (sl.ATTN_CORE, sl.MOE_ROUTE)
     net = _net()
     by_block = {}
     for l in net.spec.layers_for_phase("TRAIN"):
@@ -381,7 +391,7 @@ def test_an_expert_layers_shared_expert_names_nothing():
     expert = [ls for b, ls in by_block.items() if b is not None
               and any(l.type == "MoE" for l in ls)]
     assert expert and all(
-        net_mod._kept_names(ls) == (sl.ATTN_CORE,) for ls in expert)
+        net_mod._kept_names(ls) == (sl.ATTN_CORE, sl.MOE_ROUTE) for ls in expert)
     dense = [ls for b, ls in by_block.items() if b is not None
              and any(l.type == "GatedMLP" for l in ls)]
     assert [net_mod._kept_names(ls) for ls in dense] == [
@@ -390,7 +400,8 @@ def test_an_expert_layers_shared_expert_names_nothing():
     p, x = _params(1), _x(2)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, x: jnp.sum(sl.moe(MOE_P, p, x, CTX)[0])))(p, x)
-    assert "name=" + sl.MLP_PRE not in str(jaxpr) and " name[" not in str(jaxpr)
+    named = re.findall(r"name\[name=(\w+)\]", str(jaxpr))
+    assert named and set(named) == {sl.MOE_ROUTE} and len(named) % 8 == 0
 
 
 RECOMPUTE_HLO = '''HloModule jit_train_round

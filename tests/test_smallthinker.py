@@ -289,7 +289,8 @@ def test_zoo_follows_the_two_layouts_and_feeds_the_router_the_first_norm():
     assert own.layer_by_name("embed").embed.std == 1.0
     assert {l.block for l in spec.layers} == {None, "l0", "l1", "head"}
     net = compiled("smallthinker")
-    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT}
+    assert net.kept_makers() == {sl.ATTN_CORE: "splash_mha_fwd", sl.IP_OUT: sl.IP_OUT,
+                                 sl.MOE_ROUTE: "router"}
     assert net.attention_scopes() == ({"GQAttention": ""}, POS)
     assert net.routing_scopes() == (sl.ROUTING_SCOPES, TINY["hidden_size"])
     assert net.window_scopes()[0] == {"GQAttention": "core"}
